@@ -8,9 +8,9 @@ pair and prints a ranked table. The winning pair belongs in
 ``flash_attention``'s defaults (with this sweep cited); per-job
 overrides go through HVD_FLASH_BLOCK_Q / HVD_FLASH_BLOCK_K.
 
-The sweep runs on whatever backend jax selects; on CPU the kernel
-falls back to interpret mode, so timings are only meaningful on a
-real TPU.
+The sweep runs on whatever backend jax selects; off the TPU the kernel
+runs in interpret mode (it logs that once), and its timings say
+nothing about the chip.
 """
 from __future__ import annotations
 

@@ -124,7 +124,7 @@ run_tier1() {
 # budget breach on a cold cache taught that lesson; r4 re-measured 26
 # tests at 756-762s cold). The r5 tier is 43 tests (new example
 # smokes, per-binding sweeps, elastic crossovers); a cold-cache run
-# (`rm -rf /tmp/hvd_tpu_jax_cache`, quiet 1-core host) measured
+# (`rm -rf .jax_cache`, quiet 1-core host) measured
 # 1401.27s at 40 tests, plus 78.4s measured for the three elastic
 # shrink/blacklist/reset-limit cases added after ≈ 1480s. 1800s keeps
 # ~21% headroom over that worst cold run. (Final r5 suite, 43 tests,

@@ -83,6 +83,10 @@ def library_path(build_if_missing: bool = True) -> Optional[str]:
                     cmd.append("SANITIZE=" + _sanitize_mode())
                 subprocess.run(cmd, check=True, capture_output=True,
                                text=True)
+        except FileNotFoundError as e:
+            raise RuntimeError(
+                "Failed to build horovod_tpu native core: it needs make "
+                "and g++ on PATH (%s)" % e) from e
         except subprocess.CalledProcessError as e:
             raise RuntimeError(
                 "Failed to build horovod_tpu native core:\n" + e.stderr

@@ -46,25 +46,18 @@ def _use_onehot_embed(cfg) -> bool:
     XLA's PartitionGather CHECK-crashes partitioning a sliced-operand
     gather under manual subgroups, i.e. whenever we trace inside a
     shard_map that leaves the embed's ``model`` axis auto. So: one-hot
-    iff some axis is manual-bound but ``model`` is not (if ``model``
-    itself is manual, params arrive as local shards and no SPMD
-    partitioning of the gather happens). ``cfg.vocab_onehot_lookup``
+    iff some mesh axis is manual and the mesh has a ``model`` axis that
+    is not (if ``model`` itself is manual, or the mesh has none, params
+    arrive as local shards and no SPMD partitioning of the gather
+    happens). ``cfg.vocab_onehot_lookup``
     forces either path (e.g. False for a pure-DP mesh with an
     unsharded embed, where the gather is safe and cheaper).
     """
     if cfg.vocab_onehot_lookup is not None:
         return cfg.vocab_onehot_lookup
-    try:
-        from jax._src import core as _core
-
-        bound = set(_core.get_axis_env().axis_names())
-    except Exception:  # private-API drift: fall back to known DP axes
-        from horovod_tpu.parallel.hierarchical import DCN_AXIS, ICI_AXIS
-        from horovod_tpu.parallel.mesh import DATA_AXIS
-
-        bound = {a for a in (DATA_AXIS, DCN_AXIS, ICI_AXIS)
-                 if _axis_bound(a)}
-    return bool(bound) and "model" not in bound
+    mesh = jax.sharding.get_abstract_mesh()
+    return (bool(mesh.manual_axes) and "model" in mesh.axis_names
+            and "model" not in mesh.manual_axes)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -11,22 +11,30 @@ kernel tiles queries over the grid and walks key/value blocks with a
 running (max, sum, accumulator) triple; the backward pass is two kernels
 (dK/dV tiled over key blocks, dQ tiled over query blocks) using the saved
 log-sum-exp, wired up through ``jax.custom_vjp``. The per-(batch, head)
-K/V panel is VMEM-resident (blocks are sliced from it in-kernel), which
-bounds single-chip sequence length to VMEM — roughly S ≲ 16k at D=128
-bf16. Longer sequences shard S across chips via ring/Ulysses attention
-(``horovod_tpu.parallel.sequence``), keeping each chip's panel small.
+K/V panel is VMEM-resident (blocks are sliced from it in-kernel), and so
+are the backward's whole Q/dO/lse/delta panels, which bounds single-chip
+sequence length to VMEM. Largest power-of-two S for which forward AND
+backward compile on a TPU v5 lite (jax 0.9.0 / libtpu 0.0.34, default
+tiles, head_dim 64 and 128 alike): 16384 in bf16, 8192 in float32; the
+forward alone compiles one power of two further. The first thing to run
+out is the backward's two (S, 1) float32 panels, which Mosaic pads to
+128 lanes. Longer sequences shard S across chips via ring/Ulysses
+attention (``horovod_tpu.parallel.sequence``), keeping each chip's panel
+small.
 
 Causal masking uses the decode convention for rectangular inputs: the
 end of q aligns with the end of kv (query row r has absolute position
 r + kv_len - q_len).
 
 On non-TPU backends (CPU tests, debugging) the kernels run in Pallas
-interpret mode, so the same code path is exercised everywhere.
+interpret mode, so the same code path is exercised everywhere; the
+switch is logged once per backend so a run can tell which it got.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 import os
 from typing import Optional
 
@@ -34,19 +42,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu only imports on builds with TPU support; interpret mode
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAVE_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAVE_PLTPU = False
+logger = logging.getLogger("horovod_tpu")
 
 NEG_INF = -1e30
+
+
+@functools.cache
+def _log_interpret(backend: str) -> None:
+    logger.warning(
+        "flash_attention: backend is %r, not 'tpu' -- the Pallas kernels "
+        "run in INTERPRET mode (not compiled by Mosaic)", backend)
 
 
 def _should_interpret(interpret: Optional[bool]) -> bool:
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    _log_interpret(backend)  # runs at trace time, once per backend
+    return True
 
 
 # --------------------------------------------------------------- forward ---
@@ -229,11 +244,16 @@ def _pad_seq(x, block):
     return x
 
 
-def _pick_block(s: int, want: int) -> int:
+def _pick_block(s: int, want: int, dtype) -> int:
     # Sequences shorter than the tile become a single block; longer
-    # sequences keep the aligned tile and are padded up to a multiple
-    # (padded keys are masked via kv_len, padded query rows sliced off).
-    return s if s <= want else want
+    # sequences keep the tile and are padded up to a multiple. Either
+    # way the tile is rounded up to the dtype's sublane multiple (8
+    # rows of 32-bit words; packed dtypes stack 2 or 4 rows per
+    # sublane), which Mosaic needs to prove its in-panel slices
+    # aligned. Padded keys are masked via kv_len, padded query rows
+    # sliced off.
+    sublane = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return -(-min(s, want) // sublane) * sublane
 
 
 @functools.partial(
@@ -435,7 +455,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    block_q = _pick_block(max(qt.shape[2], 1), block_q)
-    block_k = _pick_block(max(kt.shape[2], 1), block_k)
+    block_q = _pick_block(max(qt.shape[2], 1), block_q, q.dtype)
+    block_k = _pick_block(max(kt.shape[2], 1), block_k, k.dtype)
     out = _flash(qt, kt, vt, causal, block_q, block_k, scale, interpret)
     return jnp.swapaxes(out, 1, 2)

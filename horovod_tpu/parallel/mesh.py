@@ -39,52 +39,31 @@ PIPE_AXIS = "pipe"
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names=None,
                      check_vma=False):
-    """``jax.shard_map`` across the jax API drift.
+    """``jax.shard_map`` with the framework's defaults.
 
-    Newer jax exposes ``shard_map`` at the top level (``check_vma``,
-    partial-manual via ``axis_names``); 0.4.x only has
-    ``jax.experimental.shard_map`` (``check_rep``, and the INVERSE
-    ``auto`` parameter — the axes NOT manual). Replication checking
-    defaults off on both: the framework's collectives use
-    ``axis_index_groups``, which the checkers don't support — but a
+    Replication checking defaults off: the framework's collectives use
+    ``axis_index_groups``, which the checker does not support -- a
     caller shard-mapping plain jax code can opt back in with
-    ``check_vma=True`` (mapped to ``check_rep`` on 0.4.x).
+    ``check_vma=True``. ``axis_names`` makes only those mesh axes
+    manual (partial-manual); ``None`` makes every axis manual.
 
     This is the ONE sanctioned spelling of shard_map outside this
     module: the jaxcompat checker (docs/static_analysis.md#jax-compat)
     flags every direct ``jax.shard_map`` / ``jax.experimental``
     import elsewhere.
     """
-    try:
-        from jax import shard_map as _sm
-
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return _sm(f, **kwargs)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-        if axis_names is not None:
-            kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-        return _sm(f, **kwargs)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=frozenset(axis_names or ()), check_vma=check_vma)
 
 
 def traced_axis_size(axis) -> int:
     """Size of a bound mesh axis (or axis tuple) inside a trace.
 
-    ``lax.axis_size`` with a fallback for jax versions that predate it:
-    ``psum`` of the literal ``1`` constant-folds to the bound axis size
-    at trace time and raises the same ``NameError`` for an unbound
-    name, so every caller's in-scope probe keeps working.
+    ``lax.axis_size``: a Python int at trace time, and ``NameError``
+    for an unbound name, which callers use as their in-scope probe.
     """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return jax.lax.psum(1, axis)
+    return jax.lax.axis_size(axis)
 
 
 # Outer-to-inner mesh order. The hierarchical factorization of the

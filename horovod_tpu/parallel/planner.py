@@ -30,9 +30,10 @@ Three surfaces (docs/planner.md):
 
 Emitted specs stay on the FULL-manual shard_map path
 (``Plan.shard_map`` makes every mesh axis manual via
-``shard_map_compat``): jax 0.4.x's SPMD partitioner dies on
-partial-manual programs, and full-manual is the one composition proven
-on every jax this tree supports.
+``shard_map_compat``): the plan's per-leaf specs describe every axis,
+so nothing is left for XLA to partition. (Partial-manual programs do
+compile on the installed jax -- ``dryrun_multichip`` runs one on the
+chip -- so this is a choice, not a workaround; ROADMAP D5.)
 """
 
 from __future__ import annotations
@@ -188,10 +189,9 @@ class Plan:
                   check_vma: bool = False):
         """FULL-manual ``shard_map`` of ``fn`` over the planned mesh.
 
-        Every mesh axis is manual (no ``axis_names`` subset): the one
-        composition jax 0.4.x's SPMD partitioner accepts (partial-
-        manual dies in ``spmd_partitioner.cc``) — ``shard_map_compat``
-        version-gates the spelling underneath.
+        Every mesh axis is manual (no ``axis_names`` subset): the
+        plan's specs name every axis, so ``fn`` sees local shards only
+        and issues the collectives itself.
         """
         mesh = mesh if mesh is not None else make_mesh(self.mesh_axes)
         return shard_map_compat(fn, mesh=mesh, in_specs=in_specs,

@@ -10,8 +10,8 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-# shard_map via the repo compat shim: this box's jax 0.4.x has no
-# top-level jax.shard_map (the jaxcompat checker enforces this).
+# The one sanctioned spelling of shard_map (the jaxcompat checker
+# enforces it).
 from horovod_tpu.parallel.mesh import shard_map_compat as shard_map
 from jax.sharding import PartitionSpec as P
 

@@ -753,12 +753,10 @@ from jax import lax
 
 def f(fn, mesh, spec):
     sized = lax.axis_size("data")
-    jax.set_mesh(mesh)
     return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec), sized
 ''')
     keys = _keys(run_all(project(tree)), "jaxcompat")
     assert "attr-jax.shard_map:0" in keys
-    assert "attr-jax.set_mesh:0" in keys
     assert "attr-lax.axis_size:0" in keys
 
 
@@ -776,17 +774,11 @@ from jax import lax
 
 
 def traced_axis_size(axis):
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return lax.psum(1, axis)
+    return lax.axis_size(axis)
 
 
 def shard_map_compat(f, **kw):
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
+    from jax import shard_map as _sm
     return _sm(f, **kw)
 ''')
     assert _keys(run_all(project(tree)), "jaxcompat") == []
@@ -794,8 +786,7 @@ def shard_map_compat(f, **kw):
 
 def test_jaxcompat_getattr_probe_is_not_a_finding(tree):
     _seed(tree, "horovod_tpu/probe.py",
-          "import jax\n\nHAS_SM = hasattr(jax, 'shard_map')\n"
-          "SET_MESH = getattr(jax, 'set_mesh', None)\n")
+          "import jax\n\nHAS_SM = hasattr(jax, 'shard_map')\n")
     assert _keys(run_all(project(tree)), "jaxcompat") == []
 
 

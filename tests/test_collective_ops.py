@@ -12,8 +12,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-# shard_map via the repo compat shim: this box's jax 0.4.x has no
-# top-level jax.shard_map (the jaxcompat checker enforces this).
+# The one sanctioned spelling of shard_map (the jaxcompat checker
+# enforces it).
 from horovod_tpu.parallel.mesh import shard_map_compat as shard_map
 
 import horovod_tpu as hvd
@@ -195,16 +195,10 @@ def test_allreduce_differentiable(mesh8):
         return jax.grad(loss)(s)
 
     out = _per_rank(mesh8, per_rank, x)
-    # The gradient of a psum-coupled loss depends on the jax version's
-    # shard_map transpose rule. Newer jax (top-level shard_map, with
-    # replication checking) uses the efficient psum transpose: each
-    # rank sees the partial of its OWN loss, 2*mean/8 = 0.25. On 0.4.x
-    # transpose(psum) = psum, so every rank gets the total derivative
-    # of the GLOBAL summed loss: 8 * 2*mean/8 = 2*mean = 2.0. Both are
-    # internally consistent autodiff semantics; pin whichever this jax
-    # implements (probed, not imported — the jaxcompat checker bans
-    # direct shard_map imports here).
-    expected = 0.25 if hasattr(jax, "shard_map") else 2.0
+    # shard_map's psum transpose: each rank sees the partial of its
+    # OWN loss, 2*mean/8 = 0.25 (not the total derivative of the
+    # global summed loss, 2.0, that transpose(psum) = psum would give).
+    expected = 0.25
     np.testing.assert_allclose(np.asarray(out), np.tile(expected, (8, 2)),
                                rtol=1e-6)
 

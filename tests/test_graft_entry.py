@@ -21,8 +21,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 import optax
-# shard_map via the repo compat shim: this box's jax 0.4.x has no
-# top-level jax.shard_map (the jaxcompat checker enforces this).
+# The one sanctioned spelling of shard_map (the jaxcompat checker
+# enforces it).
 from horovod_tpu.parallel.mesh import shard_map_compat as shard_map
 from jax.sharding import PartitionSpec as P
 
@@ -132,3 +132,16 @@ def test_flagship_adasum_step_contains_gather_tree(flagship):
     counts = introspect.assert_in_graph_gradient_sync(
         fn, params, opt_state, tokens, required=("all_gather",))
     assert counts["all_gather"] >= 1
+
+
+def test_dryrun_with_too_few_real_devices_raises(monkeypatch):
+    """Asked for more devices than jax offers, and not for virtual
+    ones: the dry run raises; it does not switch platform to get
+    them."""
+    import __graft_entry__ as g
+
+    monkeypatch.setenv("XLA_FLAGS", "")
+    want = jax.device_count() + 1
+    with pytest.raises(RuntimeError, match="jax offers %d cpu"
+                       % jax.device_count()):
+        g.dryrun_multichip(want)

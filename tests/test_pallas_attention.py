@@ -10,7 +10,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.ops.pallas_attention import flash_attention
+from horovod_tpu.ops.pallas_attention import _pick_block, flash_attention
 
 
 def dense_reference(q, k, v, causal, scale=None):
@@ -102,6 +102,48 @@ def test_rectangular_causal(b, sq, skv, h, d, causal, bq, bk):
     gd = jax.grad(loss_dense, (0, 1, 2))(q, k, v)
     for a, b_ in zip(gf, gd):
         assert _rel(a, b_) < 1e-5
+
+
+@pytest.mark.parametrize("s,want,dtype,tile", [
+    (100, 256, jnp.float32, 104),    # short ragged: one 8-row-aligned tile
+    (100, 256, jnp.bfloat16, 112),   # bf16 packs 16 rows per sublane tile
+    (100, 256, jnp.int8, 128),
+    (2048, 256, jnp.bfloat16, 256),  # long: the requested tile
+    (1000, 100, jnp.bfloat16, 112),  # a misaligned request is rounded up
+    (1, 512, jnp.float32, 8),
+])
+def test_pick_block_is_sublane_aligned(s, want, dtype, tile):
+    """Mosaic must be able to prove in-panel slices tile-aligned; the
+    raw sequence length as a tile (S=100) does not compile on the chip
+    even though interpret mode accepts it."""
+    assert _pick_block(s, want, dtype) == tile
+
+
+def test_short_ragged_bfloat16_matches_dense():
+    """S=100 in bf16: the tile is padded to 112 rows, the padded keys
+    masked and the padded query rows sliced off."""
+    rng = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rng.randn(1, 100, 2, 64), jnp.bfloat16)
+               for _ in range(3))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v).astype(jnp.float32) ** 2)
+
+    def dense(q, k, v):
+        return dense_reference(q.astype(jnp.float32),
+                               k.astype(jnp.float32),
+                               v.astype(jnp.float32), True)
+
+    out = flash_attention(q, k, v, causal=True)
+    assert out.shape == (1, 100, 2, 64) and out.dtype == jnp.bfloat16
+    assert _rel(out.astype(jnp.float32), dense(q, k, v)) < 2e-2
+    got = jax.grad(loss(lambda *a: flash_attention(*a, causal=True)),
+                   (0, 1, 2))(q, k, v)
+    ref = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
+    for a, b_ in zip(got, ref):
+        assert a.shape == (1, 100, 2, 64)
+        assert _rel(a.astype(jnp.float32), b_.astype(jnp.float32)) < 3e-2
 
 
 def test_bfloat16_inputs():

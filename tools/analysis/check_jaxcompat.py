@@ -1,29 +1,18 @@
 """jax-compat lint: drift-prone jax APIs stay behind the mesh shims.
 
-The repeated tax of jax 0.4.x drift (``jax.shard_map`` vs
-``jax.experimental.shard_map``, missing ``lax.axis_size``, missing
-``jax.set_mesh``) was retired by ``parallel/mesh.py``'s
-``shard_map_compat`` / ``traced_axis_size`` shims (PR 7) — but only in
-the files that were migrated. Everything else kept collecting errors
-on this container's jax. This checker pins the discipline: direct use
-of a drift-prone API anywhere outside ``parallel/mesh.py`` (the one
-place allowed to probe the live jax) is a finding. Flagged patterns:
+``parallel/mesh.py``'s ``shard_map_compat`` / ``traced_axis_size``
+wrappers own the spelling of shard_map and axis sizing (the
+framework's ``check_vma`` default lives there). This checker pins the
+discipline: direct use of those jax APIs anywhere outside
+``parallel/mesh.py`` is a finding. Flagged patterns:
 
 - ``from jax import shard_map`` / ``jax.shard_map`` — even inside a
-  try/except import dance: the dance is what ``shard_map_compat``
-  exists to centralize;
-- ``from jax.experimental.shard_map import ...`` — removed in newer
-  jax, the other side of the same drift;
-- ``lax.axis_size`` / ``jax.lax.axis_size`` — absent on 0.4.x; use
-  ``traced_axis_size``;
-- ``jax.set_mesh`` / ``from jax import set_mesh`` — absent on 0.4.x
-  (``Mesh`` is its own context manager there);
-- ``psum(<literal 1>, axis)`` — bare psum-derived axis sizing; that is
-  ``traced_axis_size``'s fallback, not call-site code.
-
-``getattr(jax, "set_mesh", None)``-style feature probes pass the AST
-scan untouched, which is exactly the point: probing is a deliberate
-compat decision, a bare attribute access is an assumption.
+  try/except import dance;
+- ``from jax.experimental.shard_map import ...`` — gone from the
+  installed jax;
+- ``lax.axis_size`` / ``jax.lax.axis_size`` — use ``traced_axis_size``;
+- ``psum(<literal 1>, axis)`` — bare psum-derived axis sizing; use
+  ``traced_axis_size``.
 """
 
 from __future__ import annotations
@@ -56,39 +45,26 @@ def _scan(tree: ast.Module) -> List[Tuple[str, str, int]]:
             if mod == "jax" and "shard_map" in names:
                 hits.append((
                     "import-shard_map",
-                    "'from jax import shard_map' does not exist on "
-                    "jax 0.4.x — " + _SHIM_HINT % "shard_map_compat",
-                    node.lineno))
-            if mod == "jax" and "set_mesh" in names:
-                hits.append((
-                    "import-set_mesh",
-                    "'from jax import set_mesh' is newer-jax only — "
-                    "probe with getattr and fall back to the Mesh "
-                    "context manager (see __graft_entry__)",
+                    "direct 'from jax import shard_map' — "
+                    + _SHIM_HINT % "shard_map_compat",
                     node.lineno))
             if mod.startswith("jax.experimental.shard_map"):
                 hits.append((
                     "import-experimental-shard_map",
-                    "'jax.experimental.shard_map' is removed in newer "
-                    "jax — " + _SHIM_HINT % "shard_map_compat",
+                    "'jax.experimental.shard_map' is gone from the "
+                    "installed jax — " + _SHIM_HINT % "shard_map_compat",
                     node.lineno))
         elif isinstance(node, ast.Attribute):
             dotted = _dotted(node)
             if dotted in ("jax.shard_map",):
                 hits.append((
                     "attr-jax.shard_map",
-                    "'jax.shard_map' does not exist on jax 0.4.x — "
+                    "direct 'jax.shard_map' — "
                     + _SHIM_HINT % "shard_map_compat", node.lineno))
-            elif dotted in ("jax.set_mesh",):
-                hits.append((
-                    "attr-jax.set_mesh",
-                    "'jax.set_mesh' is newer-jax only — probe with "
-                    "getattr and fall back to the Mesh context manager",
-                    node.lineno))
             elif dotted is not None and dotted.endswith("lax.axis_size"):
                 hits.append((
                     "attr-lax.axis_size",
-                    "'lax.axis_size' is absent on jax 0.4.x — "
+                    "direct 'lax.axis_size' — "
                     + _SHIM_HINT % "traced_axis_size", node.lineno))
         elif isinstance(node, ast.Call):
             f = node.func

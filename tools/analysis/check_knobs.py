@@ -23,14 +23,10 @@ DEFAULT_ALLOWLIST: Dict[str, str] = {
     # never do). The public surface is the hvdrun CLI.
     "HOROVOD_SLOT_KEY": "internal: per-slot identity token minted by the "
                         "elastic driver for worker registration",
-    "HOROVOD_WORKER_PLATFORM": "internal: platform tag the launcher "
-                               "stamps on workers it spawns",
     "HOROVOD_RENDEZVOUS_VERSION": "internal: elastic rendezvous epoch "
                                   "the driver stamps on each world",
     # Benchmark/CI harness tuning, not framework behavior.
     "HVD_BENCH_TIMEOUT": "bench.py harness: per-case subprocess timeout",
-    "HVD_BENCH_TPU_RETRIES": "bench.py harness: TPU-claim retry count",
-    "HVD_BENCH_TPU_BACKOFF": "bench.py harness: TPU-claim retry backoff",
     "HVD_CI_METRICS_BUDGET": "ci/run_tests.sh lane budget",
     "HVD_CI_FLIGHTREC_BUDGET": "ci/run_tests.sh lane budget",
     "HVD_CI_TIER1_BUDGET": "ci/run_tests.sh lane budget",

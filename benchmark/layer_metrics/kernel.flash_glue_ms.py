@@ -1,0 +1,8 @@
+"""Per step, what runs under ``hvd_flash`` outside the three Mosaic calls:
+pads, slices, the delta row sums, layout copies around the kernels."""
+
+from benchmark import scope_view
+
+
+def read(ctx):
+    return scope_view.part_ms(ctx, "flash_glue")

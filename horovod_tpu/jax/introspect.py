@@ -29,6 +29,20 @@ from typing import Dict, List, Sequence
 
 import jax
 
+# Program scopes: the names under which the framework's own work shows
+# in the ``op_name`` metadata of a compiled step, where flax's module
+# scopes (``Transformer/layer_3/attn``) do not reach. ``jax.named_scope``
+# acts at trace time only; docs/timeline.md lists who reads which.
+SCOPE_SYNC = "hvd_sync"          # allreduce_gradients, in-graph branch
+SCOPE_UPDATE = "hvd_update"      # the inner optimizer's update
+SCOPE_FLASH = "hvd_flash"        # ops/pallas_attention.py, kernels + glue
+SCOPE_EMBED = "embed"            # Transformer: lookup + positions
+SCOPE_LOGITS = "logits"          # Transformer: output projection
+# ``name=`` of the three ``pallas_call``s (the Mosaic calls' op_name).
+KERNEL_FLASH_FWD = "hvd_flash_fwd"
+KERNEL_FLASH_DKV = "hvd_flash_dkv"
+KERNEL_FLASH_DQ = "hvd_flash_dq"
+
 # Primitive names the framework's in-graph data plane lowers to.
 # (lax.psum_scatter traces as the "reduce_scatter" primitive.)
 COLLECTIVE_PRIMITIVES = (
@@ -172,3 +186,155 @@ def assert_donation_survives_lowering(
             "fresh gradient/optimizer buffers every step."
             % (donate_argnums, len(donated), min_donated))
     return donated
+
+
+# ------------------------------------------------- compiled-step scopes ---
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = ")
+_OPCODE = re.compile(r" ([a-z][a-z0-9_-]*)\(")
+_OPERAND = re.compile(r"%([^\s,(){}]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_TRANSFORM = re.compile(r"[a-z_]+\(")     # jit(f)/..., pmap(f)/...
+_SHAPE = re.compile(r"\b(pred|token|[a-z]+\d+[a-z0-9]*)\[([\d,]*)\]")
+_BITS = re.compile(r"\d+")
+# Attributes that name a computation whose instructions run (and show in
+# a device trace) as instructions of their own. A fusion's ``calls`` and
+# a reduction's ``to_apply`` do not.
+_CALLED = re.compile(
+    r"\b(?:body|condition|true_computation|false_computation)=%([^\s,(){}]+)"
+    r"|\bbranch_computations=\{([^}]*)\}")
+_CALLED_BY_CALL = re.compile(r"\b(?:to_apply|calls)=%([^\s,(){}]+)")
+
+
+def _type_bytes(text: str) -> int:
+    total = 0
+    for dtype, dims in _SHAPE.findall(text):
+        bits = _BITS.search(dtype)
+        size = max(int(bits.group()) // 8, 1) if bits else 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        total += size
+    return total
+
+
+def _operand_text(line: str, start: int) -> str:
+    """The text between the opcode's parenthesis at ``start`` and its
+    match (layouts such as ``T(8,128)`` nest inside)."""
+    depth = 0
+    for i in range(start, len(line)):
+        c = line[i]
+        if c == "(":
+            depth += 1
+        elif c == ")":
+            depth -= 1
+            if depth == 0:
+                return line[start + 1:i]
+    return line[start + 1:]
+
+
+def _parse_computations(hlo_text: str):
+    """name -> [(instruction, opcode, result bytes, operands, op_name,
+    called computations)] in the text's (scheduled) order; the entry
+    computation's name; and each computation's ROOT instruction."""
+    computations, roots, entry, current = {}, {}, None, None
+    for line in hlo_text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                current = m.group(1)
+                computations[current] = []
+                if line.startswith("ENTRY "):
+                    entry = current
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _INSTRUCTION.match(line)
+        op = m and _OPCODE.search(line, m.end() - 1)
+        if not op:
+            continue
+        rest = line[op.end() - 1:]
+        operands = _OPERAND.findall(_operand_text(rest, 0))
+        attributes = rest.split(", metadata={", 1)[0]
+        called = [c.strip().lstrip("%")
+                  for one, many in _CALLED.findall(attributes)
+                  for c in (one or many).split(",")]
+        if op.group(1) in ("call", "async-start"):
+            called += _CALLED_BY_CALL.findall(attributes)
+        # A parameter, and a copy of one, carries its argument's name
+        # (``params['embed']``) where a scope would be (``jit(step)/...``).
+        scope = _OP_NAME.search(rest)
+        scope = scope.group(1) if scope else ""
+        computations[current].append(
+            (m.group(1), op.group(1), _type_bytes(line[m.end():op.start()]),
+             operands, scope if _TRANSFORM.match(scope) else "", called))
+        if "ROOT %" in line[:m.end()]:
+            roots[current] = m.group(1)
+    return computations, entry, roots
+
+
+def instruction_scopes(hlo_text: str) -> Dict[str, str]:
+    """``{instruction: op_name}`` for the entry computation of a compiled
+    step (``compiled.as_text()``) and every computation it calls as a
+    loop body, a condition or a branch: the instructions a device trace
+    has events for, each with the program scope it ran under
+    (``jit(step)/jvp(Transformer)/layer_3/attn/...``).
+
+    Most instructions the compiler makes itself carry no metadata
+    (``copy-start``/``-done``, ``slice-start``, bitcasts, the ``while``
+    loops it builds to re-tile a large array). They inherit one: a
+    ``-done`` takes its ``-start``'s; a parameter of a called
+    computation takes its caller's; anything else takes the scope of the
+    producer of its largest operand (so a ``tuple``, a
+    ``get-tuple-element``, a ``bitcast`` or a ``copy`` hands on what it
+    carries, and a ``while`` lands on the largest array it loops over),
+    then of its smaller operands; failing all that, its first user's.
+    What still has none maps to ``""``.
+    """
+    computations, entry, roots = _parse_computations(hlo_text)
+    if entry is None:
+        return {}
+    up: Dict[str, str] = {}      # own scope, or inherited from producers
+    size: Dict[str, int] = {}
+    order = []                   # (computation, caller), callers first
+    visited = set()
+
+    def forward(name, caller):
+        visited.add(name)
+        order.append((name, caller))
+        for inst, opcode, nbytes, operands, own, called in \
+                computations[name]:
+            size[inst] = nbytes
+            scope = own
+            if not scope and opcode == "parameter":
+                scope = up.get(caller, "")
+            elif not scope and opcode.endswith("-done") and operands:
+                scope = up.get(operands[0], "")
+            if not scope:
+                for operand in sorted(operands, key=lambda o: -size.get(o, 0)):
+                    scope = up.get(operand, "")
+                    if scope:
+                        break
+            up[inst] = scope
+            for sub in called:
+                if sub in computations and sub not in visited:
+                    forward(sub, inst)
+
+    forward(entry, None)
+    scopes: Dict[str, str] = {}
+    for name, caller in order:
+        first_user: Dict[str, str] = {}
+        instructions = computations[name]
+        for inst, _, _, operands, _, _ in instructions:
+            for operand in operands:
+                first_user.setdefault(operand, inst)
+        root = roots.get(name)
+        for inst, *_ in reversed(instructions):
+            scope = up[inst]
+            if not scope and inst in first_user:
+                scope = scopes.get(first_user[inst], "")
+            if not scope and inst == root and caller is not None:
+                scope = scopes.get(caller, "")
+            scopes[inst] = scope
+    return scopes

@@ -12,9 +12,16 @@ Two execution paths (SURVEY.md §7 "eager enqueue vs XLA tracing"):
   (gradients are tracers), the gradient pytree is split into per-dtype
   fused buckets of ``HVD_GRAD_BUCKET_BYTES`` each (default 4 MiB) and one
   ``psum`` is issued per bucket, in reverse-gradient order — several
-  *independent* collectives XLA's latency-hiding scheduler can overlap
-  with the remaining backprop, the in-graph analog of the reference's
-  fusion buffer + comm/compute overlap (docs/mfu.md).
+  *independent* collectives, the in-graph analog of the reference's
+  fusion buffer (docs/mfu.md). The intent is that XLA's scheduler
+  overlaps them with the remaining backprop; measured on four v5e chips
+  it does not: XLA combines the ~340 buckets of GPT-2-medium into 11
+  synchronous ``all-reduce``s and runs nothing beside them
+  (``sync.exposed_ms`` = ``sync.collective_ms`` = 24.7 ms of a 132 ms
+  step, PERF.md finding 1 of PR 22; ROADMAP D2 holds the work). The
+  whole branch is traced under the ``hvd_sync`` scope, each bucket under
+  ``bucket_<i>_<dtype>`` (jax/introspect.py), so a device trace tells
+  pack and unpack from the collective.
   ``HVD_GRAD_BUCKET_BYTES=0`` restores the legacy single whole-pytree
   ``psum`` bit-exactly. With a two-level ``(dcn, ici)`` axis and
   ``HOROVOD_HIERARCHICAL_ALLREDUCE=1`` each bucket rides the
@@ -43,6 +50,7 @@ import optax
 from horovod_tpu.common import basics
 from horovod_tpu.common.process_sets import global_process_set
 from horovod_tpu.jax.compression import Compression
+from horovod_tpu.jax.introspect import SCOPE_SYNC, SCOPE_UPDATE
 from horovod_tpu.ops import collective_ops as C
 from horovod_tpu.ops import eager
 from horovod_tpu.parallel import bucketing
@@ -87,20 +95,24 @@ def _bucketed_allreduce(wires, op, *, axis, process_set, bucket_bytes,
     keys = [jnp.dtype(w.dtype).name for w in wires]
     buckets = bucketing.assign_buckets(sizes, keys, bucket_bytes)
     outs = [None] * len(wires)
-    for bucket in buckets:
+    for n, bucket in enumerate(buckets):
         leaves = [wires[i] for i in bucket.indices]
-        flat, _ = bucketing.pack_bucket(leaves)
         _M_BUCKETS.labels(bucket.dtype_key).inc()
-        # One single-member group per bucket: grouped_allreduce owns
-        # the flat-vs-hierarchical routing (and the hierarchical
-        # path's ici padding), so this stays in lockstep with every
-        # other collective's dispatch.
-        reduced = C.grouped_allreduce(
-            [flat], op, axis=axis, process_set=process_set,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor)[0]
-        for i, out in zip(bucket.indices,
-                          bucketing.unpack_bucket(reduced, leaves)):
+        # Pack, collective and unpack of one bucket under one scope, so
+        # a device trace tells the copies from the collective and one
+        # bucket from the next (jax/introspect.py).
+        with jax.named_scope("bucket_%d_%s" % (n, bucket.dtype_key)):
+            flat, _ = bucketing.pack_bucket(leaves)
+            # One single-member group per bucket: grouped_allreduce owns
+            # the flat-vs-hierarchical routing (and the hierarchical
+            # path's ici padding), so this stays in lockstep with every
+            # other collective's dispatch.
+            reduced = C.grouped_allreduce(
+                [flat], op, axis=axis, process_set=process_set,
+                prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor)[0]
+            unpacked = bucketing.unpack_bucket(reduced, leaves)
+        for i, out in zip(bucket.indices, unpacked):
             outs[i] = out
     return outs
 
@@ -152,6 +164,19 @@ def allreduce_gradients(
     Eager: grouped submission to the native core, names derived from tree
     paths so every rank agrees on tensor identity.
     """
+    kwargs = dict(op=op, axis=axis, process_set=process_set,
+                  compression=compression, prescale_factor=prescale_factor,
+                  postscale_factor=postscale_factor)
+    if _is_tracing(grads) and _axis_in_scope(axis):
+        # Compress, pack, collective, unpack, decompress: the in-graph
+        # sync as one named scope of the compiled step.
+        with jax.named_scope(SCOPE_SYNC):
+            return _allreduce_gradients(grads, **kwargs)
+    return _allreduce_gradients(grads, **kwargs)
+
+
+def _allreduce_gradients(grads, *, op, axis, process_set, compression,
+                         prescale_factor, postscale_factor):
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     if not leaves:
         return grads
@@ -267,6 +292,19 @@ def allreduce_transformation(
     return optax.GradientTransformation(init_fn, update_fn)
 
 
+def _scoped_update(optimizer) -> optax.GradientTransformationExtraArgs:
+    """``optimizer`` with its ``update`` traced under ``SCOPE_UPDATE``:
+    the same ``init`` and state, the same arithmetic, and a name for its
+    instructions in the compiled step."""
+    inner = optax.with_extra_args_support(optimizer)
+
+    def update_fn(updates, state, params=None, **extra_args):
+        with jax.named_scope(SCOPE_UPDATE):
+            return inner.update(updates, state, params, **extra_args)
+
+    return optax.GradientTransformationExtraArgs(inner.init, update_fn)
+
+
 def DistributedOptimizer(
     optimizer: optax.GradientTransformation,
     *,
@@ -296,7 +334,7 @@ def DistributedOptimizer(
             op, axis=axis, process_set=process_set, compression=compression,
             prescale_factor=prescale_factor, postscale_factor=postscale_factor,
         ),
-        optimizer,
+        _scoped_update(optimizer),
     )
     if backward_passes_per_step == 1:
         return chained

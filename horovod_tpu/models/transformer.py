@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 from flax.linen import partitioning as nn_partitioning
+from horovod_tpu.jax.introspect import SCOPE_EMBED, SCOPE_LOGITS
 from horovod_tpu.parallel.mesh import traced_axis_size
 
 param_with_axes = nn.with_partitioning
@@ -181,35 +182,41 @@ class Transformer(nn.Module):
         pos = self.param(
             "pos", param_with_axes(init, (None, None)),
             (cfg.max_seq_len, cfg.d_model), jnp.float32)
-        if _use_onehot_embed(cfg):
-            # The one-hot contraction partitions cleanly under manual
-            # subgroups (where the gather CHECK-crashes XLA's
-            # partitioner, see _use_onehot_embed) and rides the MXU.
-            # Outside that composition the plain gather is cheaper (no
-            # [b, s, vocab] one-hot activation), so keep it.
-            onehot = jax.nn.one_hot(tokens, cfg.vocab_size,
-                                    dtype=cfg.dtype)
-            x = jnp.einsum("bsv,vm->bsm", onehot, embed.astype(cfg.dtype))
-        else:
-            x = embed.astype(cfg.dtype)[tokens]
-        s_local = tokens.shape[1]
-        if cfg.seq_axis is not None and _axis_bound(cfg.seq_axis):
-            # Sequence-sharded (shard_map): this shard holds positions
-            # [idx * S_local, (idx+1) * S_local).
-            offset = jax.lax.axis_index(cfg.seq_axis) * s_local
-            pos_slice = jax.lax.dynamic_slice_in_dim(
-                pos.astype(cfg.dtype), offset, s_local)
-        else:
-            pos_slice = pos.astype(cfg.dtype)[:s_local]
-        x = x + pos_slice[None]
+        # flax scopes every module call; the lookup and the output
+        # projection sit loose at the root, so they get scopes of their own
+        # (jax/introspect.py lists them).
+        with jax.named_scope(SCOPE_EMBED):
+            if _use_onehot_embed(cfg):
+                # The one-hot contraction partitions cleanly under manual
+                # subgroups (where the gather CHECK-crashes XLA's
+                # partitioner, see _use_onehot_embed) and rides the MXU.
+                # Outside that composition the plain gather is cheaper (no
+                # [b, s, vocab] one-hot activation), so keep it.
+                onehot = jax.nn.one_hot(tokens, cfg.vocab_size,
+                                        dtype=cfg.dtype)
+                x = jnp.einsum("bsv,vm->bsm", onehot,
+                               embed.astype(cfg.dtype))
+            else:
+                x = embed.astype(cfg.dtype)[tokens]
+            s_local = tokens.shape[1]
+            if cfg.seq_axis is not None and _axis_bound(cfg.seq_axis):
+                # Sequence-sharded (shard_map): this shard holds positions
+                # [idx * S_local, (idx+1) * S_local).
+                offset = jax.lax.axis_index(cfg.seq_axis) * s_local
+                pos_slice = jax.lax.dynamic_slice_in_dim(
+                    pos.astype(cfg.dtype), offset, s_local)
+            else:
+                pos_slice = pos.astype(cfg.dtype)[:s_local]
+            x = x + pos_slice[None]
         block = Block
         if cfg.remat:
             block = nn.remat(Block)
         for i in range(cfg.n_layers):
             x = block(cfg, name="layer_%d" % i)(x)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
-        logits = jnp.einsum("bsm,vm->bsv", x, embed.astype(cfg.dtype))
-        return logits.astype(jnp.float32)
+        with jax.named_scope(SCOPE_LOGITS):
+            logits = jnp.einsum("bsm,vm->bsv", x, embed.astype(cfg.dtype))
+            return logits.astype(jnp.float32)
 
 
 def get_param_specs(cfg: TransformerConfig, sample_tokens):

@@ -264,7 +264,26 @@ def _flash(q, k, v, causal, block_q, block_k, scale, interpret):
     return out
 
 
+def _scoped(fn):
+    """Trace ``fn`` under the ``hvd_flash`` scope: the pads, slices and
+    row sums around a kernel as well as the kernel, so a device trace
+    tells them from the rest of the attention module. (The names live
+    in jax/introspect.py, whose package imports this one: hence the
+    imports inside functions.)"""
+    @functools.wraps(fn)
+    def scoped(*args):
+        from horovod_tpu.jax.introspect import SCOPE_FLASH
+
+        with jax.named_scope(SCOPE_FLASH):
+            return fn(*args)
+
+    return scoped
+
+
+@_scoped
 def _flash_fwd_impl(q, k, v, causal, block_q, block_k, scale, interpret):
+    from horovod_tpu.jax.introspect import KERNEL_FLASH_FWD
+
     # q, k, v here are (B, H, S, D).
     b, h, s, d = q.shape
     kv_len = k.shape[2]
@@ -299,6 +318,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, scale, interpret):
             jax.ShapeDtypeStruct((b, h, sq_pad, 1), jnp.float32),
         ],
         interpret=_should_interpret(interpret),
+        name=KERNEL_FLASH_FWD,
     )(qp, kp, vp)
     return out[:, :, :s], (q, k, v, out[:, :, :s], lse[:, :, :s, 0])
 
@@ -308,7 +328,10 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, scale, interpret):
                            interpret)
 
 
+@_scoped
 def _flash_bwd(causal, block_q, block_k, scale, interpret, res, g):
+    from horovod_tpu.jax.introspect import KERNEL_FLASH_DKV, KERNEL_FLASH_DQ
+
     q, k, v, out, lse = res
     b, h, s, d = q.shape
     kv_len = k.shape[2]
@@ -361,6 +384,7 @@ def _flash_bwd(causal, block_q, block_k, scale, interpret, res, g):
             jax.ShapeDtypeStruct((b, h, sk_pad, d), q.dtype),
         ],
         interpret=interp,
+        name=KERNEL_FLASH_DKV,
     )(qp, kp, vp, dop, lsep, deltap)
 
     dq_kernel = functools.partial(
@@ -387,6 +411,7 @@ def _flash_bwd(causal, block_q, block_k, scale, interpret, res, g):
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
         interpret=interp,
+        name=KERNEL_FLASH_DQ,
     )(qp, kp, vp, dop, lsep, deltap)
 
     return dq[:, :, :s], dk[:, :, :kv_len], dv[:, :, :kv_len]

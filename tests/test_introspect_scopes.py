@@ -1,0 +1,206 @@
+"""Program scopes in the compiled step (jax/introspect.py): the names the
+framework gives where flax gives none, the join from instruction to
+scope with its inheritance rule, and that a scope changes no arithmetic.
+"""
+
+import contextlib
+import re
+
+import numpy as np
+import optax
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+import horovod_tpu.jax as hvd_jax
+from horovod_tpu.jax import introspect
+from horovod_tpu.jax.optimizer import allreduce_transformation
+from horovod_tpu.models import Transformer, TransformerConfig
+from horovod_tpu.parallel.mesh import shard_map_compat
+
+N_LAYERS = 2
+CFG = TransformerConfig(vocab_size=256, d_model=32, n_heads=2,
+                        n_layers=N_LAYERS, d_ff=64, max_seq_len=16,
+                        dtype=jnp.float32, attention="flash")
+
+
+def _step_and_args(monkeypatch):
+    """The tiny transformer's data-parallel AdamW step over two virtual
+    devices, several buckets to a step, with concrete arguments."""
+    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", str(16 * 1024))
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("data",))
+    model = Transformer(CFG)
+    tx = hvd_jax.DistributedOptimizer(optax.adamw(1e-2))
+
+    def step(params, opt_state, tokens):
+        def loss_fn(p):
+            logits = model.apply(p, tokens[:, :-1])
+            return optax.softmax_cross_entropy_with_integer_labels(
+                logits, tokens[:, 1:]).mean()
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss[None]
+
+    sharded = jax.jit(shard_map_compat(
+        step, mesh=mesh, in_specs=(P(), P(), P("data")),
+        out_specs=(P(), P(), P("data"))))
+    tokens = jax.device_put(
+        jax.random.randint(jax.random.PRNGKey(1), (4, 17), 0, 256),
+        NamedSharding(mesh, P("data")))
+    params = jax.tree.map(
+        lambda x: getattr(x, "value", x),
+        model.init(jax.random.PRNGKey(0), tokens[:1, :-1]),
+        is_leaf=lambda x: hasattr(x, "value"))
+    return sharded, tx, (params, tx.init(params), tokens)
+
+
+def _equations(jaxpr, outer=""):
+    """(equation, name stack) through the sub-jaxprs; a jitted helper's
+    own equations carry a stack relative to their caller's."""
+    for eqn in jaxpr.eqns:
+        stack = outer + "/" + str(eqn.source_info.name_stack)
+        yield eqn, stack
+        for v in eqn.params.values():
+            for cand in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(cand, "jaxpr", cand)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner, stack)
+
+
+def test_compiled_step_names_sync_update_head_and_kernels(monkeypatch):
+    sharded, _, args = _step_and_args(monkeypatch)
+    text = sharded.lower(*args).compile().as_text()
+    scopes = introspect.instruction_scopes(text)
+
+    # XLA may combine the buckets' psums; whatever it leaves sits in a
+    # bucket's scope, and every bucket's pack and unpack in its own.
+    collectives = re.findall(r"^\s*%(\S+) = .* all-reduce(?:-start)?\(",
+                             text, re.M)
+    assert collectives
+    in_bucket = re.compile(r"/shard_map/hvd_sync/bucket_(\d+)_float32/")
+    assert all(in_bucket.search(scopes[c]) for c in collectives)
+    buckets = {m.group(1) for m in map(in_bucket.search, scopes.values())
+               if m}
+    assert len(buckets) >= 3, buckets
+
+    # Every equation of the inner optimizer is traced under hvd_update
+    # (a compiled fusion shows its root's name only, so count in the
+    # jaxpr): as many there as adamw's update has alone.
+    params, (_, adam_state), _ = args
+    alone = jax.make_jaxpr(optax.adamw(1e-2).update)(
+        params, adam_state, params)
+    in_step = [e for e, stack in _equations(
+        jax.make_jaxpr(sharded)(*args).jaxpr)
+        if introspect.SCOPE_UPDATE in stack]
+    assert len(in_step) == len(list(_equations(alone.jaxpr))) > 100
+    top_level = {s.rpartition("/")[2] for s in scopes.values()
+                 if re.fullmatch(r"jit\(\w+\)/shard_map/[a-z_]+", s)}
+    assert top_level == {"add"}      # optax.apply_updates
+
+    def layers_with(fragment):
+        return {re.search(r"/layer_(\d+)/", s).group(1)
+                for s in scopes.values() if fragment in s}
+
+    flash = "/attn/%s/" % introspect.SCOPE_FLASH
+    for kernel, direction in ((introspect.KERNEL_FLASH_FWD, "/jvp("),
+                              (introspect.KERNEL_FLASH_DKV, "/transpose("),
+                              (introspect.KERNEL_FLASH_DQ, "/transpose(")):
+        assert len(layers_with(flash + kernel + "/")) == N_LAYERS
+        assert all(direction in s for s in scopes.values()
+                   if flash + kernel + "/" in s)
+    for scope in (introspect.SCOPE_EMBED, introspect.SCOPE_LOGITS):
+        assert any("/jvp(Transformer)/%s/" % scope in s
+                   for s in scopes.values())
+        assert any("/transpose(jvp(Transformer))/%s/" % scope in s
+                   for s in scopes.values())
+
+
+HAND_WRITTEN = """\
+HloModule jit_step, is_scheduled=true, entry_computation_layout={()->f32[8]}
+
+%fused_add (p0: f32[8]) -> f32[8] {
+  %p0 = f32[8]{0} parameter(0)
+  ROOT %inside = f32[8]{0} add(%p0, %p0), metadata={op_name="jit(step)/never/seen"}
+}
+
+%body (arg: (s32[], f32[4096,8], f32[8])) -> (s32[], f32[4096,8], f32[8]) {
+  %arg = (s32[], f32[4096,8]{1,0:T(8,128)(2,1)}, f32[8]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%arg), index=0
+  %big = f32[4096,8]{1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=1
+  %dus.1 = f32[4096,8]{1,0:T(8,128)(2,1)} dynamic-update-slice(%big, %big, %i, %i)
+  %small = f32[8]{0} get-tuple-element(%arg), index=2
+  ROOT %out = (s32[], f32[4096,8]{1,0:T(8,128)(2,1)}, f32[8]{0}) tuple(%i, %dus.1, %small)
+}
+
+%cond (arg.1: (s32[], f32[4096,8], f32[8])) -> pred[] {
+  %arg.1 = (s32[], f32[4096,8]{1,0:T(8,128)(2,1)}, f32[8]{0}) parameter(0)
+  %i.1 = s32[] get-tuple-element(%arg.1), index=0
+  ROOT %lt = pred[] compare(%i.1, %i.1), direction=LT
+}
+
+ENTRY %main (w: f32[8], x: f32[4096,8]) -> f32[8] {
+  %w = f32[8]{0} parameter(0), metadata={op_name="params['w']"}
+  %x = f32[4096,8]{1,0} parameter(1), metadata={op_name="batch"}
+  %copy-start.1 = (f32[8]{0:S(1)}, f32[8]{0}, u32[]{:S(2)}) copy-start(%w)
+  %zero = s32[] constant(0)
+  %grad = f32[4096,8]{1,0:T(8,128)(2,1)} fusion(%x), kind=kLoop, calls=%fused_add, metadata={op_name="jit(step)/transpose(jvp())/mul" source_file="loss.py"}
+  %norm = f32[8]{0} fusion(%x), kind=kLoop, calls=%fused_add, metadata={op_name="jit(step)/jvp(Transformer)/ln_f/reduce_sum"}
+  %bitcast.1 = f32[4096,8]{1,0:T(8,128)(2,1)} bitcast(%grad)
+  %tuple.1 = (s32[], f32[4096,8]{1,0:T(8,128)(2,1)}, f32[8]{0}) tuple(%zero, %bitcast.1, %norm)
+  %while.1 = (s32[], f32[4096,8]{1,0:T(8,128)(2,1)}, f32[8]{0}) while(%tuple.1), condition=%cond, body=%body
+  %gte.1 = f32[4096,8]{1,0:T(8,128)(2,1)} get-tuple-element(%while.1), index=1
+  %copy-done.1 = f32[8]{0:S(1)} copy-done(%copy-start.1)
+  %lonely = f32[] constant(1)
+  ROOT %update = f32[8]{0} fusion(%copy-done.1), kind=kLoop, calls=%fused_add, metadata={op_name="jit(step)/hvd_update/add"}
+}
+"""
+
+
+def test_instructions_without_metadata_inherit_a_scope():
+    scopes = introspect.instruction_scopes(HAND_WRITTEN)
+    loss_bwd = "jit(step)/transpose(jvp())/mul"
+    update = "jit(step)/hvd_update/add"
+    # An argument's name is no scope: the prefetch of a weight takes its
+    # first user's, and the -done its -start's.
+    assert scopes["copy-start.1"] == update
+    assert scopes["copy-done.1"] == update
+    # Through bitcast and tuple to the largest array the loop carries,
+    # not the index and not the small LayerNorm row; then into the body
+    # and the condition, and out through get-tuple-element.
+    assert scopes["bitcast.1"] == scopes["tuple.1"] == loss_bwd
+    assert scopes["while.1"] == scopes["gte.1"] == loss_bwd
+    assert scopes["dus.1"] == scopes["out"] == scopes["lt"] == loss_bwd
+    # Own metadata wins; a fusion's inside is no instruction of the step;
+    # what nothing reaches has the empty scope.
+    assert scopes["norm"] == "jit(step)/jvp(Transformer)/ln_f/reduce_sum"
+    assert "inside" not in scopes
+    assert scopes["lonely"] == ""
+    assert introspect.instruction_scopes("") == {}
+
+
+def test_scopes_change_no_arithmetic_and_no_state(monkeypatch):
+    sharded, tx, args = _step_and_args(monkeypatch)
+    scoped = sharded(*args)
+    assert "hvd_update" in sharded.lower(*args).as_text(debug_info=True)
+
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare, _, _ = _step_and_args(monkeypatch)
+    assert "hvd_update" not in bare.lower(*args).as_text(debug_info=True)
+    for a, b in zip(jax.tree.leaves(scoped), jax.tree.leaves(bare(*args))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    plain = optax.chain(allreduce_transformation(), optax.adamw(1e-2))
+    assert (jax.tree.structure(tx.init(args[0]))
+            == jax.tree.structure(plain.init(args[0])))
+    # Extra arguments still reach an inner transformation that takes them.
+    seen = []
+    inner = optax.GradientTransformationExtraArgs(
+        lambda p: optax.EmptyState(),
+        lambda u, s, p=None, *, scale: (seen.append(scale) or u, s))
+    grads = {"w": jnp.ones(3)}
+    wrapped = hvd_jax.DistributedOptimizer(inner)
+    wrapped.update(grads, wrapped.init(grads), grads, scale=2.0)
+    assert seen == [2.0]

@@ -4,9 +4,11 @@
 Usage:  python ci/flash_block_sweep.py [--seq 2048] [--batch 4]
 
 Runs fwd+bwd through ``flash_attention`` for each (block_q, block_k)
-pair and prints a ranked table. The winning pair belongs in
-``flash_attention``'s defaults (with this sweep cited); per-job
-overrides go through HVD_FLASH_BLOCK_Q / HVD_FLASH_BLOCK_K.
+pair and prints a ranked table (host clock, whole step). The default
+is a rule on the sequence length (``pallas_attention._default_blocks``;
+its kernel-alone device timings are in PERF.md, PR 25): a pair that
+beats it across shapes belongs in that rule; per-job overrides go
+through HVD_FLASH_BLOCK_Q / HVD_FLASH_BLOCK_K.
 
 The sweep runs on whatever backend jax selects; off the TPU the kernel
 runs in interpret mode (it logs that once), and its timings say

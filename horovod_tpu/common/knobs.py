@@ -511,9 +511,10 @@ TUNABLE: Dict[str, TunableKnob] = {t.name: t for t in [
                 "— per-rank divergence lowers divergent psum sequences "
                 "(docs/mfu.md), so live search is single-process only"),
     TunableKnob("flash_block_q", 128.0, 512.0, 128.0, "env",
-                "HVD_FLASH_BLOCK_Q", 256.0, False,
-                "flash-attention query tile; trace-time read, same "
-                "rank-divergence hazard as grad_bucket_bytes (the "
+                "HVD_FLASH_BLOCK_Q", 512.0, False,
+                "flash-attention query tile (unset: a rule on the "
+                "sequence length, 512 for long ones); trace-time read, "
+                "same rank-divergence hazard as grad_bucket_bytes (the "
                 "shape-keyed sweep in ops/block_tuner.py is the "
                 "preferred tuner for this one)"),
     TunableKnob("flash_block_k", 128.0, 512.0, 128.0, "env",

@@ -1,9 +1,10 @@
 """Flash-attention VMEM block-size autotuner with a journaled cache.
 
 ``HVD_FLASH_BLOCK_Q/K`` existed since the kernel landed, but nothing
-searched them: every job ran the v5e-seq2048 sweep winner (256/512)
-regardless of its own (seq, head_dim, dtype, causal) shape or chip
-generation (ROADMAP open item #3). This module closes that loop:
+searched them: every job ran one built-in default (now a rule on the
+sequence length, ``pallas_attention._default_blocks``) regardless of
+its own (seq, head_dim, dtype, causal) shape or chip generation
+(ROADMAP open item #3). This module closes that loop:
 
 - ``best_blocks(...)``: consult a persistent cache keyed by
   shape + device; on a miss (and when tuning is allowed) run an

@@ -10,7 +10,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.ops.pallas_attention import _pick_block, flash_attention
+from horovod_tpu.ops import pallas_attention
+from horovod_tpu.ops.pallas_attention import (_Tiles, _pick_block,
+                                              flash_attention)
 
 
 def dense_reference(q, k, v, causal, scale=None):
@@ -36,6 +38,9 @@ CASES = [
     (2, 128, 4, 64, True, 128, 128),  # single block
     (1, 96, 1, 8, True, 64, 32),      # block_q != block_k
     (1, 130, 2, 16, True, 64, 64),    # S > block with padding
+    (1, 320, 2, 16, True, 64, 128),   # full, edge, skipped AND padded tiles
+    (1, 320, 2, 16, True, 128, 64),   # the same, block_q > block_k
+    (1, 320, 2, 16, False, 64, 128),  # non-causal: all full but the last
 ]
 
 
@@ -76,6 +81,7 @@ RECT_CASES = [
     (1, 1, 64, 2, 16, True, 32, 32),    # single-token decode
     (1, 16, 48, 2, 8, True, 16, 16),    # q shorter than kv
     (1, 30, 70, 1, 8, True, 16, 32),    # uneven rectangular
+    (1, 192, 320, 2, 16, True, 64, 128),  # every tile kind, offset 128
 ]
 
 
@@ -102,6 +108,121 @@ def test_rectangular_causal(b, sq, skv, h, d, causal, bq, bk):
     gd = jax.grad(loss_dense, (0, 1, 2))(q, k, v)
     for a, b_ in zip(gf, gd):
         assert _rel(a, b_) < 1e-5
+
+
+TILE_CASES = [
+    # (q_len, kv_len, block_q, block_k, causal)
+    (4096, 4096, 256, 512, True),     # gpt2m-s4096-c1 at the old default
+    (1024, 1024, 256, 512, True),
+    (1024, 1024, 512, 512, True),
+    (320, 320, 64, 128, True),
+    (320, 320, 128, 64, True),
+    (320, 320, 64, 128, False),
+    (256, 256, 64, 64, False),        # non-causal, unpadded: no edge tile
+    (192, 320, 64, 128, True),        # decode alignment
+    (200, 72, 32, 16, True),          # negative offset
+    (1, 64, 8, 32, True),
+    (1000, 1000, 112, 112, True),     # ragged
+    (130, 130, 64, 32, True),
+]
+
+
+def _brute_force_tiles(q_len, kv_len, bq, bk, causal):
+    """Each tile's kind from the mask itself, element by element."""
+    num_qb, num_kb = -(-q_len // bq), -(-kv_len // bk)
+    row = np.arange(num_qb * bq)[:, None] + (kv_len - q_len)
+    col = np.arange(num_kb * bk)[None, :]
+    below = (col <= row) if causal else np.ones((row.size, col.size), bool)
+    visible = below & (col < kv_len)
+    kinds = {}
+    for qi in range(num_qb):
+        for kj in range(num_kb):
+            tile = np.s_[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+            kinds[qi, kj] = ("skipped" if not below[tile].any() else
+                             "full" if visible[tile].all() else "edge")
+    return num_qb, num_kb, kinds
+
+
+@pytest.mark.parametrize("q_len,kv_len,bq,bk,causal", TILE_CASES)
+def test_tile_classes_match_brute_force(q_len, kv_len, bq, bk, causal):
+    """The helper gives every tile the kind the mask gives it, and both
+    walks (by query block: forward and dQ; by key block: dK/dV) skip
+    the same tiles."""
+    num_qb, num_kb, kinds = _brute_force_tiles(q_len, kv_len, bq, bk, causal)
+    tiles = _Tiles(bq, bk, causal, q_len, kv_len)
+    assert (tiles.num_qb, tiles.num_kb) == (num_qb, num_kb)
+    by_query, by_key = {}, {}
+    for qi in range(num_qb):
+        n_full, n_end = tiles.key_full(qi), tiles.key_end(qi)
+        assert 0 <= n_full <= n_end <= num_kb
+        for kj in range(num_kb):
+            by_query[qi, kj] = ("full" if kj < n_full else
+                                "edge" if kj < n_end else "skipped")
+    for kj in range(num_kb):
+        q_from = tiles.query_start(kj)
+        assert 0 <= q_from <= num_qb
+        for qi in range(num_qb):
+            by_key[qi, kj] = qi < q_from
+    assert by_query == kinds
+    assert by_key == {t: kind == "skipped" for t, kind in kinds.items()}
+    want = {kind: sum(1 for k in kinds.values() if k == kind)
+            for kind in ("full", "edge", "skipped")}
+    assert tiles.counts() == want
+    if not causal and not tiles.padded_keys:
+        assert want["edge"] == 0 and tiles.visible(1, 0, 0) is None
+
+
+@pytest.mark.parametrize("shape,blocks,want", [
+    # The benchmark's two one-chip shapes, (B, S, H, D), bf16, causal.
+    ((1, 4096, 16, 64), (256, 512), (56, 16, 56)),
+    ((4, 1024, 16, 64), (256, 512), (2, 4, 2)),
+    ((1, 4096, 16, 64), None, None),     # what the default tiles give
+    ((4, 1024, 16, 64), None, None),
+])
+def test_tile_counter_at_trace_time(shape, blocks, want):
+    """hvd_flash_tiles_total{kernel,kind} moves by one plane's tiles
+    each time a kernel is traced; nothing runs."""
+    from horovod_tpu.jax import introspect
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    if blocks is None:
+        blocks = pallas_attention._default_blocks(shape[1], shape[1])
+        assert blocks == (512, 512)
+        n = shape[1]
+        _, _, kinds = _brute_force_tiles(n, n, *blocks, True)
+        want = tuple(sum(1 for k in kinds.values() if k == kind)
+                     for kind in ("full", "edge", "skipped"))
+    kernels = (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
+               introspect.KERNEL_FLASH_DQ)
+
+    def read():
+        return {(k, kind): pallas_attention._M_TILES.labels(
+            kernel=k, kind=kind).get()
+            for k in kernels for kind in ("full", "edge", "skipped")}
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=blocks[0],
+                               block_k=blocks[1]).astype(jnp.float32).sum()
+
+    before = read()
+    jax.eval_shape(jax.grad(loss, (0, 1, 2)), x, x, x)
+    after = read()
+    for k in kernels:
+        got = tuple(after[k, kind] - before[k, kind]
+                    for kind in ("full", "edge", "skipped"))
+        assert got == want, (k, got)
+
+
+@pytest.mark.parametrize("s,tile", [
+    (4096, 512), (1024, 512), (1000, 512), (1100, 384), (600, 384),
+    (513, 384), (512, 512), (100, 128), (1, 128)])
+def test_default_blocks_split_the_sequence_evenly(s, tile):
+    """At most 512 rows a block, the fewest blocks, whole lane groups:
+    the padding stays under a lane group a block."""
+    assert pallas_attention._default_blocks(s, s) == (tile, tile)
+    assert pallas_attention._default_blocks(s, 4096) == (tile, 512)
+    blocks = -(-s // tile)
+    assert blocks == -(-s // 512) and blocks * tile - s < 128 * blocks
 
 
 @pytest.mark.parametrize("s,want,dtype,tile", [

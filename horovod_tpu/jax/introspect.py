@@ -38,6 +38,12 @@ SCOPE_UPDATE = "hvd_update"      # the inner optimizer's update
 SCOPE_FLASH = "hvd_flash"        # ops/pallas_attention.py, kernels + glue
 SCOPE_EMBED = "embed"            # Transformer: lookup + positions
 SCOPE_LOGITS = "logits"          # Transformer: output projection
+SCOPE_ROPE = "rope"              # SelfAttention: rotary positions on q, k
+# The expert layer (parallel/moe.py), inside the ``moe`` module's scope.
+SCOPE_MOE_ROUTER = "hvd_moe_router"      # logits, softmax, top-k, aux losses
+SCOPE_MOE_DISPATCH = "hvd_moe_dispatch"  # sort, group sizes, gather of rows
+SCOPE_MOE_EXPERTS = "hvd_moe_experts"    # the grouped matmuls and the gate
+SCOPE_MOE_COMBINE = "hvd_moe_combine"    # weighting and the sum per token
 # ``name=`` of the three ``pallas_call``s (the Mosaic calls' op_name).
 KERNEL_FLASH_FWD = "hvd_flash_fwd"
 KERNEL_FLASH_DKV = "hvd_flash_dkv"

@@ -11,6 +11,8 @@ from horovod_tpu.models.resnet import (  # noqa: F401
     ResNet152,
 )
 from horovod_tpu.models.transformer import (  # noqa: F401
+    GPT2_BLOCK,
+    BlockSpec,
     Transformer,
     TransformerConfig,
     get_param_specs,

@@ -6,8 +6,13 @@ how horovod_tpu composes those blocks TPU-first: parameters carry
 partitioning metadata (Megatron-style tensor parallelism over the
 ``model`` axis), activations shard batch over ``data`` and optionally
 sequence over ``seq`` (ring attention / Ulysses,
-``horovod_tpu.parallel.sequence``), and MoE layers route tokens over the
-``expert`` axis with all_to_all.
+``horovod_tpu.parallel.sequence``), and the feed-forward may be a
+mixture of experts (``horovod_tpu.parallel.moe``).
+
+What KIND of block the decoder is made of is data, a ``BlockSpec``:
+GPT-2's (LayerNorm, GELU, learned positions, tied output embedding) is
+the default; OLMoE's is RMSNorm, SwiGLU experts, rotary positions,
+RMSNorm on q and k, an output head of its own.
 
 Param layout (tensor parallel over 'model'):
 - attention QKV projections shard the head dim;
@@ -27,7 +32,11 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 from flax.linen import partitioning as nn_partitioning
-from horovod_tpu.jax.introspect import SCOPE_EMBED, SCOPE_LOGITS
+from horovod_tpu.jax.introspect import (
+    SCOPE_EMBED,
+    SCOPE_LOGITS,
+    SCOPE_ROPE,
+)
 from horovod_tpu.parallel.mesh import traced_axis_size
 
 param_with_axes = nn.with_partitioning
@@ -62,6 +71,27 @@ def _use_onehot_embed(cfg) -> bool:
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockSpec:
+    """The kind of decoder block, as a public model's ``config.json``
+    describes it. The default is GPT-2's."""
+
+    norm: str = "layernorm"          # | 'rmsnorm' (scale only)
+    norm_eps: float = 1e-6
+    ffn: str = "gelu"                # | 'swiglu': silu(gate) * up, then down
+    positions: str = "learned"       # | 'rope' (rotate-half, on q and k)
+    rope_theta: float = 10000.0
+    qk_norm: bool = False            # the norm on q and k, over all heads
+    tied_head: bool = True           # the output projection is ``embed``
+    # 0 = one dense feed-forward; >0 = that many experts, each token
+    # through its ``experts_per_token`` most probable (parallel/moe.py).
+    num_experts: int = 0
+    experts_per_token: int = 1
+
+
+GPT2_BLOCK = BlockSpec()
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -75,13 +105,41 @@ class TransformerConfig:
     # 'ulysses' (all_to_all head/seq re-sharding).
     attention: str = "dense"
     seq_axis: Optional[str] = None  # mesh axis for ring/ulysses attention
-    # MoE: 0 = dense MLP; >0 = top-1 routed experts over the 'expert' axis.
-    num_experts: int = 0
-    expert_axis: Optional[str] = None
     remat: bool = False
     # None = auto (one-hot lookup only under manual subgroups, see
     # _use_onehot_embed); True/False forces the lookup style.
     vocab_onehot_lookup: Optional[bool] = None
+    block: BlockSpec = GPT2_BLOCK
+
+
+def _norm(cfg, name):
+    """The block's norm as a flax module: statistics in float32, the
+    result in the compute dtype."""
+    kind = {"layernorm": nn.LayerNorm, "rmsnorm": nn.RMSNorm}[cfg.block.norm]
+    return kind(epsilon=cfg.block.norm_eps, dtype=cfg.dtype, name=name)
+
+
+def _first_position(cfg, s_local):
+    """The position of this shard's first token: 0, or under a bound
+    ``seq_axis`` (shard_map) this shard's offset into the sequence."""
+    if cfg.seq_axis is not None and _axis_bound(cfg.seq_axis):
+        return jax.lax.axis_index(cfg.seq_axis) * s_local
+    return 0
+
+
+def rope(x, first_position, theta):
+    """Rotary position embedding of x (B, S, H, D), the rotate-half
+    convention: with the head's two halves (a, b) and the angle
+    ``position * theta ** (-2i / D)`` of pair i, (a cos - b sin,
+    b cos + a sin). Computed in float32."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    positions = first_position + jnp.arange(x.shape[1])
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           -1).astype(x.dtype)
 
 
 def _dense_causal_attention(q, k, v, dtype):
@@ -116,6 +174,16 @@ class SelfAttention(nn.Module):
         q = jnp.einsum("bsm,mhd->bshd", x, wqkv[0])
         k = jnp.einsum("bsm,mhd->bshd", x, wqkv[1])
         v = jnp.einsum("bsm,mhd->bshd", x, wqkv[2])
+        if cfg.block.qk_norm:
+            # Over the whole width, before the heads are told apart.
+            b, s = x.shape[:2]
+            q = _norm(cfg, "q_norm")(q.reshape(b, s, h * d)).reshape(q.shape)
+            k = _norm(cfg, "k_norm")(k.reshape(b, s, h * d)).reshape(k.shape)
+        if cfg.block.positions == "rope":
+            with jax.named_scope(SCOPE_ROPE):
+                first = _first_position(cfg, x.shape[1])
+                q = rope(q, first, cfg.block.rope_theta)
+                k = rope(k, first, cfg.block.rope_theta)
         if cfg.attention == "dense":
             ctx = _dense_causal_attention(q, k, v, cfg.dtype)
         elif cfg.attention == "flash":
@@ -147,7 +215,12 @@ class Mlp(nn.Module):
         wo = self.param("wo", param_with_axes(init, ("model", None)),
                         (cfg.d_ff, cfg.d_model), jnp.float32)
         y = x @ wi.astype(cfg.dtype)
-        y = nn.gelu(y)
+        if cfg.block.ffn == "swiglu":
+            wg = self.param("wg", param_with_axes(init, (None, "model")),
+                            (cfg.d_model, cfg.d_ff), jnp.float32)
+            y = nn.silu(x @ wg.astype(cfg.dtype)) * y
+        else:
+            y = nn.gelu(y)
         return y @ wo.astype(cfg.dtype)
 
 
@@ -155,15 +228,15 @@ class Block(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, assignment=None):
         cfg = self.cfg
-        y = nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x)
+        y = _norm(cfg, "ln1")(x)
         x = x + SelfAttention(cfg, name="attn")(y)
-        y = nn.LayerNorm(dtype=cfg.dtype, name="ln2")(x)
-        if cfg.num_experts > 0:
+        y = _norm(cfg, "ln2")(x)
+        if cfg.block.num_experts > 0:
             from horovod_tpu.parallel.moe import MoeMlp
 
-            x = x + MoeMlp(cfg, name="moe")(y)
+            x = x + MoeMlp(cfg, name="moe")(y, assignment)
         else:
             x = x + Mlp(cfg, name="mlp")(y)
         return x
@@ -173,15 +246,25 @@ class Transformer(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, assignments=None):
+        """Logits (B, S, vocab) in float32. ``assignments`` (one entry a
+        layer, each (B * S, experts_per_token) expert indices) forces
+        the experts' routing; what the expert layers sow is in the
+        ``moe`` collection (parallel/moe.py)."""
         cfg = self.cfg
         init = nn.initializers.normal(0.02)
         embed = self.param(
             "embed", param_with_axes(init, ("model", None)),
             (cfg.vocab_size, cfg.d_model), jnp.float32)
-        pos = self.param(
-            "pos", param_with_axes(init, (None, None)),
-            (cfg.max_seq_len, cfg.d_model), jnp.float32)
+        if cfg.block.positions == "learned":
+            pos = self.param(
+                "pos", param_with_axes(init, (None, None)),
+                (cfg.max_seq_len, cfg.d_model), jnp.float32)
+        head = embed
+        if not cfg.block.tied_head:
+            head = self.param(
+                "lm_head", param_with_axes(init, ("model", None)),
+                (cfg.vocab_size, cfg.d_model), jnp.float32)
         # flax scopes every module call; the lookup and the output
         # projection sit loose at the root, so they get scopes of their own
         # (jax/introspect.py lists them).
@@ -198,24 +281,26 @@ class Transformer(nn.Module):
                                embed.astype(cfg.dtype))
             else:
                 x = embed.astype(cfg.dtype)[tokens]
-            s_local = tokens.shape[1]
-            if cfg.seq_axis is not None and _axis_bound(cfg.seq_axis):
-                # Sequence-sharded (shard_map): this shard holds positions
-                # [idx * S_local, (idx+1) * S_local).
-                offset = jax.lax.axis_index(cfg.seq_axis) * s_local
-                pos_slice = jax.lax.dynamic_slice_in_dim(
-                    pos.astype(cfg.dtype), offset, s_local)
-            else:
-                pos_slice = pos.astype(cfg.dtype)[:s_local]
-            x = x + pos_slice[None]
+            if cfg.block.positions == "learned":
+                s_local = tokens.shape[1]
+                if cfg.seq_axis is not None and _axis_bound(cfg.seq_axis):
+                    # Sequence-sharded (shard_map): this shard holds
+                    # positions [idx * S_local, (idx+1) * S_local).
+                    pos_slice = jax.lax.dynamic_slice_in_dim(
+                        pos.astype(cfg.dtype),
+                        _first_position(cfg, s_local), s_local)
+                else:
+                    pos_slice = pos.astype(cfg.dtype)[:s_local]
+                x = x + pos_slice[None]
         block = Block
         if cfg.remat:
             block = nn.remat(Block)
         for i in range(cfg.n_layers):
-            x = block(cfg, name="layer_%d" % i)(x)
-        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
+            x = block(cfg, name="layer_%d" % i)(
+                x, None if assignments is None else assignments[i])
+        x = _norm(cfg, "ln_f")(x)
         with jax.named_scope(SCOPE_LOGITS):
-            logits = jnp.einsum("bsm,vm->bsv", x, embed.astype(cfg.dtype))
+            logits = jnp.einsum("bsm,vm->bsv", x, head.astype(cfg.dtype))
             return logits.astype(jnp.float32)
 
 

@@ -152,7 +152,8 @@ def test_expert_parallel_moe_matches_dense():
 def test_moe_transformer_forward_and_grad():
     cfg = models.TransformerConfig(
         vocab_size=64, d_model=16, n_heads=2, n_layers=1, d_ff=32,
-        max_seq_len=32, dtype=jnp.float32, num_experts=4)
+        max_seq_len=32, dtype=jnp.float32,
+        block=models.BlockSpec(num_experts=4))
     model = models.Transformer(cfg)
     tokens = jnp.zeros((2, 8), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), tokens)

@@ -238,9 +238,9 @@ REGISTRY: Dict[str, Knob] = {k.name: k for k in [
          "size"),
     # In-graph MFU knobs (docs/mfu.md).
     Knob("HVD_GRAD_BUCKET_BYTES", HONORED,
-         "jax/optimizer.py: per-dtype fused gradient-allreduce bucket "
-         "payload; several independent psums overlap with backprop "
-         "(default 4 MiB; 0 = legacy single whole-pytree psum)"),
+         "jax/optimizer.py: per-dtype gradient-allreduce bucket "
+         "payload; a bucket's leaves go to one grouped psum where they "
+         "lie (default 4 MiB; 0 = the whole tree as one group)"),
     Knob("HVD_FLASH_TUNE", HONORED,
          "ops/pallas_attention.py + ops/block_tuner.py: 1 = autotune "
          "flash-attention tiles per shape on first call and journal "

@@ -57,25 +57,31 @@ COLLECTIVE_PRIMITIVES = (
 )
 
 
-def _walk(jaxpr, counts: Dict[str, int]) -> None:
+def equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it
+    (shard_map / scan / cond / custom-vjp bodies), outer first."""
     for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name in COLLECTIVE_PRIMITIVES:
-            counts[name] = counts.get(name, 0) + 1
+        yield eqn
         for v in eqn.params.values():
             for cand in (v if isinstance(v, (list, tuple)) else (v,)):
                 inner = getattr(cand, "jaxpr", cand)
                 if hasattr(inner, "eqns"):
-                    _walk(inner, counts)
+                    yield from equations(inner)
+
+
+def _collective_counts(jaxpr) -> Dict[str, int]:
+    counts: Dict[str, int] = {}
+    for eqn in equations(jaxpr):
+        name = eqn.primitive.name
+        if name in COLLECTIVE_PRIMITIVES:
+            counts[name] = counts.get(name, 0) + 1
+    return counts
 
 
 def collective_counts(fn, *args, **kwargs) -> Dict[str, int]:
     """Trace ``fn`` and count collective primitives in the full jaxpr
     (descending into shard_map / scan / cond / custom-vjp subjaxprs)."""
-    closed = jax.make_jaxpr(fn)(*args, **kwargs)
-    counts: Dict[str, int] = {}
-    _walk(closed.jaxpr, counts)
-    return counts
+    return _collective_counts(jax.make_jaxpr(fn)(*args, **kwargs).jaxpr)
 
 
 def assert_in_graph_gradient_sync(
@@ -102,35 +108,49 @@ def assert_in_graph_gradient_sync(
     return counts
 
 
+# ``hvd_sync/bucket_<i>_<dtype>`` in an equation's name stack
+# (jax/optimizer.py ``_bucketed_allreduce``).
+_BUCKET_SCOPE_RE = re.compile(r"(?:^|/)%s/(bucket_\d+_\w+)" % SCOPE_SYNC)
+
+
 def assert_bucketed_gradient_sync(
     fn, *args,
     min_buckets: int = 2,
     **kwargs,
 ) -> Dict[str, int]:
-    """Assert the traced ``fn`` issues at least ``min_buckets``
-    *independent* reduction collectives.
+    """Assert the traced ``fn`` reduces its gradients in at least
+    ``min_buckets`` buckets.
 
-    This is the overlap tripwire for the bucketed gradient path
-    (docs/mfu.md): XLA's latency-hiding scheduler can only overlap a
-    bucket's collective with remaining backprop if the buckets exist as
-    separate primitives in the program. One monolithic whole-pytree
-    ``psum`` (the ``HVD_GRAD_BUCKET_BYTES=0`` legacy path) counts as a
-    single reduction no matter how many leaves it carries, so a silent
-    regression to it fails here. The bucket count is the MAX of the
-    ``psum`` and ``reduce_scatter`` totals, not their sum: one
-    hierarchical ladder traces as reduce_scatter + psum(dcn) +
-    all_gather, and summing would let a single monolithic ladder
-    masquerade as two buckets.
+    A bucket is what ``jax/optimizer.py`` makes of it: a group of
+    gradient leaves handed to one grouped ``psum`` (jax 0.9.0 binds one
+    ``psum`` equation per leaf of the group) or one hierarchical ladder,
+    traced under the scope ``hvd_sync/bucket_<i>_<dtype>``. So the
+    buckets of a traced step are the distinct bucket scopes that hold a
+    ``psum`` or a ``reduce_scatter``; counting the primitives would take
+    a whole-tree ``psum`` (``HVD_GRAD_BUCKET_BYTES=0``, no bucket scope)
+    for as many buckets as it has leaves. A silent collapse to that
+    single group fails here.
+
+    This is a tripwire for the GROUPING, not for overlap: on four v5e
+    chips XLA combines the buckets into a few synchronous ``all-reduce``s
+    and runs nothing beside them, however many there are (PERF.md,
+    PR 22 and PR 27). Returns ``collective_counts``'s dict.
     """
-    counts = collective_counts(fn, *args, **kwargs)
-    reductions = max(counts.get("psum", 0), counts.get("reduce_scatter", 0))
-    if reductions < min_buckets:
+    jaxpr = jax.make_jaxpr(fn)(*args, **kwargs).jaxpr
+    counts = _collective_counts(jaxpr)
+    buckets = set()
+    for eqn in equations(jaxpr):
+        if eqn.primitive.name in ("psum", "reduce_scatter"):
+            m = _BUCKET_SCOPE_RE.search(str(eqn.source_info.name_stack))
+            if m:
+                buckets.add(m.group(1))
+    if len(buckets) < min_buckets:
         raise AssertionError(
-            "expected >= %d independent bucket collectives in the "
-            "traced step, found %d (%r). Gradient sync has collapsed "
-            "back to a monolithic collective — check "
-            "HVD_GRAD_BUCKET_BYTES and the optimizer's bucket path."
-            % (min_buckets, reductions, counts))
+            "expected >= %d gradient buckets in the traced step, found "
+            "%d (%r; collectives %r). Gradient sync has collapsed back "
+            "to a monolithic group: check HVD_GRAD_BUCKET_BYTES and the "
+            "optimizer's bucket path."
+            % (min_buckets, len(buckets), sorted(buckets), counts))
     return counts
 
 

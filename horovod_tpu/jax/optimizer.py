@@ -10,22 +10,29 @@ Two execution paths (SURVEY.md §7 "eager enqueue vs XLA tracing"):
 
 - **In-graph (the TPU fast path)**: when ``update`` runs under a jit trace
   (gradients are tracers), the gradient pytree is split into per-dtype
-  fused buckets of ``HVD_GRAD_BUCKET_BYTES`` each (default 4 MiB) and one
-  ``psum`` is issued per bucket, in reverse-gradient order — several
-  *independent* collectives, the in-graph analog of the reference's
-  fusion buffer (docs/mfu.md). The intent is that XLA's scheduler
-  overlaps them with the remaining backprop; measured on four v5e chips
-  it does not: XLA combines the ~340 buckets of GPT-2-medium into 11
-  synchronous ``all-reduce``s and runs nothing beside them
-  (``sync.exposed_ms`` = ``sync.collective_ms`` = 24.7 ms of a 132 ms
-  step, PERF.md finding 1 of PR 22; ROADMAP D2 holds the work). The
-  whole branch is traced under the ``hvd_sync`` scope, each bucket under
-  ``bucket_<i>_<dtype>`` (jax/introspect.py), so a device trace tells
-  pack and unpack from the collective.
-  ``HVD_GRAD_BUCKET_BYTES=0`` restores the legacy single whole-pytree
-  ``psum`` bit-exactly. With a two-level ``(dcn, ici)`` axis and
+  buckets of ``HVD_GRAD_BUCKET_BYTES`` each (default 4 MiB), in
+  reverse-gradient order, and each bucket's leaves are reduced WHERE
+  THEY LIE: ``C.grouped_allreduce(leaves)`` is ``lax.psum`` over the
+  tuple of leaves and the division, with no flat buffer packed before
+  it and none unpacked after it. A bucket is a group of leaves handed
+  to the compiler together and a name in the trace
+  (``hvd_sync/bucket_<i>_<dtype>``, jax/introspect.py); it is not an
+  overlap mechanism. Measured on four v5e chips (PERF.md, PR 22 and
+  PR 27): XLA combines GPT-2-medium's bucket reductions into 11
+  ``all-reduce``s with tuple operands in the gradients' own tiled
+  layouts; they are synchronous on libtpu 0.0.34 (asynchronous only
+  under compiler options, which belong to the caller of ``jit``), and
+  nothing runs beside them (``sync.exposed_ms`` =
+  ``sync.collective_ms``). Over an axis of ONE chip the leaves are
+  returned as they came: the compiled step holds no instruction under
+  ``hvd_sync``. ``HVD_GRAD_BUCKET_BYTES=0`` traces the same arithmetic
+  as the default, as one group with no bucket scopes (jax 0.9.0 binds
+  one ``psum`` per leaf either way); deleting the option is ROADMAP
+  D2's. With a two-level ``(dcn, ici)`` axis and
   ``HOROVOD_HIERARCHICAL_ALLREDUCE=1`` each bucket rides the
-  hierarchical ladder (``parallel/hierarchical.py``).
+  hierarchical ladder (``parallel/hierarchical.py``), which keeps its
+  packing: a ``psum_scatter`` needs one buffer divisible by the ``ici``
+  size.
 - **Eager**: with concrete arrays and world size > 1, each leaf is
   submitted to the native core's negotiation queue exactly like the
   reference's per-gradient async enqueue (named tensors, fused by the
@@ -58,63 +65,80 @@ from horovod_tpu.parallel.mesh import DATA_AXIS
 from horovod_tpu.parallel.mesh import traced_axis_size
 from horovod_tpu.utils import metrics as _metrics
 
-# Default fused-bucket payload for the in-graph gradient allreduce.
-# Smaller than the reference's 128 MB fusion threshold on purpose: the
-# point is several independent collectives the XLA scheduler can
-# overlap with backprop, not one late monolith (docs/mfu.md).
+# Default bucket payload for the in-graph gradient allreduce: how many
+# bytes of leaves go to the compiler as one group and share one name in
+# the trace. XLA's all-reduce combiner, not this number, decides how
+# many collectives the step runs (11 for GPT-2-medium; PERF.md).
 DEFAULT_GRAD_BUCKET_BYTES = 4 * 1024 * 1024
 
 # Counted at trace time (in-graph collectives are invisible to Python
-# per step): how many fused buckets each traced train step issues.
+# per step): how many buckets each traced train step issues.
 _M_BUCKETS = _metrics.counter(
     "hvd_grad_buckets_total",
-    "Fused gradient-allreduce buckets issued by the in-graph bucketed "
+    "Gradient-allreduce buckets issued by the in-graph bucketed "
     "path (counted at trace time, per dtype).", ("dtype",))
+
+# Also at trace time: which way each gradient leaf went. ``in_place``:
+# reduced where it lies, no copy; ``packed``: copied into a hierarchical
+# ladder's flat buffer; ``skipped``: returned untouched because the axis
+# has one chip.
+_M_LEAVES = _metrics.counter(
+    "hvd_grad_leaves_total",
+    "Gradient leaves through the in-graph bucketed path, by route "
+    "(counted at trace time).", ("route",))
 
 
 def grad_bucket_bytes() -> int:
-    """Resolved ``HVD_GRAD_BUCKET_BYTES`` (0 = legacy single psum)."""
+    """Resolved ``HVD_GRAD_BUCKET_BYTES`` (0 = one group for the tree)."""
     return int(os.environ.get("HVD_GRAD_BUCKET_BYTES",
                               str(DEFAULT_GRAD_BUCKET_BYTES)))
 
 
 def _bucketed_allreduce(wires, op, *, axis, process_set, bucket_bytes,
                         prescale_factor, postscale_factor):
-    """Per-dtype byte-capped fused allreduce of a leaf list.
+    """Per-dtype byte-capped grouped allreduce of a leaf list.
 
-    Each bucket is one independent collective through
-    ``C.grouped_allreduce`` (which owns the hierarchical (dcn, ici)
-    routing and its padding), issued in reverse-gradient order —
-    backprop produces the last layers'
-    gradients first, so their buckets can start reducing while the
-    early layers are still differentiating. Bit-exact with the legacy
-    grouped psum: bucketing only re-associates *which leaves share a
-    buffer*, never the per-element cross-replica reduction.
+    A bucket is a GROUP of leaves handed to one ``C.grouped_allreduce``
+    as they lie (``lax.psum`` over the tuple, then the division), in
+    reverse-gradient order, under the scope ``bucket_<i>_<dtype>``: no
+    leaf is copied into a buffer or sliced out of one. Per element the
+    same cross-replica sum as the whole-tree ``psum``; bucketing only
+    chooses which leaves travel together. ``C.grouped_allreduce`` owns
+    the routing: on the hierarchical ``(dcn, ici)`` route it packs the
+    group into the one padded buffer a ``psum_scatter`` needs.
+
+    What the buckets do NOT buy is overlap with the backward pass: XLA
+    combines them into a few synchronous ``all-reduce``s (11 for
+    GPT-2-medium on four v5e chips, every microsecond exposed; PERF.md).
     """
     sizes = [w.size * jnp.dtype(w.dtype).itemsize for w in wires]
     keys = [jnp.dtype(w.dtype).name for w in wires]
     buckets = bucketing.assign_buckets(sizes, keys, bucket_bytes)
+    route = ("packed" if C._route_hierarchical(
+        op, process_set, axis, "HOROVOD_HIERARCHICAL_ALLREDUCE")
+        else "in_place")
+    _M_LEAVES.labels(route).inc(len(wires))
     outs = [None] * len(wires)
     for n, bucket in enumerate(buckets):
-        leaves = [wires[i] for i in bucket.indices]
         _M_BUCKETS.labels(bucket.dtype_key).inc()
-        # Pack, collective and unpack of one bucket under one scope, so
-        # a device trace tells the copies from the collective and one
-        # bucket from the next (jax/introspect.py).
+        # One scope a bucket, so a device trace tells one bucket from
+        # the next (jax/introspect.py).
         with jax.named_scope("bucket_%d_%s" % (n, bucket.dtype_key)):
-            flat, _ = bucketing.pack_bucket(leaves)
-            # One single-member group per bucket: grouped_allreduce owns
-            # the flat-vs-hierarchical routing (and the hierarchical
-            # path's ici padding), so this stays in lockstep with every
-            # other collective's dispatch.
             reduced = C.grouped_allreduce(
-                [flat], op, axis=axis, process_set=process_set,
+                [wires[i] for i in bucket.indices], op, axis=axis,
+                process_set=process_set,
                 prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor)[0]
-            unpacked = bucketing.unpack_bucket(reduced, leaves)
-        for i, out in zip(bucket.indices, unpacked):
+                postscale_factor=postscale_factor)
+        for i, out in zip(bucket.indices, reduced):
             outs[i] = out
     return outs
+
+
+def _scaled(wires, factor):
+    """``wires`` as they came, times ``factor`` when that is not 1.0."""
+    if factor == 1.0:
+        return list(wires)
+    return [w * jnp.asarray(factor, w.dtype) for w in wires]
 
 
 def _is_tracing(grads) -> bool:
@@ -158,9 +182,10 @@ def allreduce_gradients(
 ):
     """Allreduce a gradient pytree; dispatches in-graph vs eager.
 
-    In-graph: per-dtype fused buckets of ``HVD_GRAD_BUCKET_BYTES`` each,
-    one psum per bucket in reverse-gradient order (0 = the legacy single
-    whole-pytree psum).
+    In-graph: per-dtype buckets of ``HVD_GRAD_BUCKET_BYTES`` each, every
+    bucket's leaves reduced where they lie by one grouped ``psum`` in
+    reverse-gradient order (0 = the whole tree as one group); nothing at
+    all over an axis of one chip.
     Eager: grouped submission to the native core, names derived from tree
     paths so every rank agrees on tensor identity.
     """
@@ -168,8 +193,8 @@ def allreduce_gradients(
                   compression=compression, prescale_factor=prescale_factor,
                   postscale_factor=postscale_factor)
     if _is_tracing(grads) and _axis_in_scope(axis):
-        # Compress, pack, collective, unpack, decompress: the in-graph
-        # sync as one named scope of the compiled step.
+        # Compress, collective, division, decompress: the in-graph sync
+        # as one named scope of the compiled step.
         with jax.named_scope(SCOPE_SYNC):
             return _allreduce_gradients(grads, **kwargs)
     return _allreduce_gradients(grads, **kwargs)
@@ -187,9 +212,15 @@ def _allreduce_gradients(grads, *, op, axis, process_set, compression,
 
     if _is_tracing(wires) and _axis_in_scope(axis):
         bucket_bytes = grad_bucket_bytes()
-        if (bucket_bytes > 0 and len(wires) > 1
-                and op in (C.Average, C.Sum)
-                and C._is_global_set(process_set)):
+        bucketable = (op in (C.Average, C.Sum)
+                      and C._is_global_set(process_set))
+        if bucketable and traced_axis_size(axis) == 1:
+            # One chip on the axis: the sum of one value and the
+            # division by one. XLA drops a one-device all-reduce itself;
+            # tracing nothing also leaves it nothing to schedule around.
+            _M_LEAVES.labels("skipped").inc(len(wires))
+            outs = _scaled(wires, prescale_factor * postscale_factor)
+        elif bucketable and bucket_bytes > 0 and len(wires) > 1:
             outs = _bucketed_allreduce(
                 wires, op, axis=axis, process_set=process_set,
                 bucket_bytes=bucket_bytes,
@@ -197,10 +228,11 @@ def _allreduce_gradients(grads, *, op, axis, process_set, compression,
                 postscale_factor=postscale_factor,
             )
         else:
-            # Legacy path (HVD_GRAD_BUCKET_BYTES=0), non-fusable ops
-            # (Min/Max/Product/Adasum), restricted process sets, and
-            # single-leaf trees: one grouped collective, bit-exact with
-            # the pre-bucketing behavior.
+            # HVD_GRAD_BUCKET_BYTES=0 (the same arithmetic as the
+            # bucketed branch, as one group with no bucket scopes),
+            # non-fusable ops (Min/Max/Product/Adasum), restricted
+            # process sets, and single-leaf trees: one grouped
+            # collective.
             outs = C.grouped_allreduce(
                 wires, op,
                 axis=axis, process_set=process_set,
@@ -251,11 +283,7 @@ def _allreduce_gradients(grads, *, op, axis, process_set, compression,
         outs = [jnp.asarray(o) for o in outs]
     else:
         # Single process, concrete values: identity semantics.
-        outs = [
-            w * jnp.asarray(prescale_factor * postscale_factor, w.dtype)
-            if prescale_factor * postscale_factor != 1.0 else w
-            for w in wires
-        ]
+        outs = _scaled(wires, prescale_factor * postscale_factor)
 
     outs = [compression.decompress(o, ctx) for o, ctx in zip(outs, ctxs)]
     return jax.tree_util.tree_unflatten(treedef, outs)

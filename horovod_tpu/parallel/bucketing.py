@@ -1,25 +1,30 @@
-"""Per-dtype, byte-capped gradient bucketing for fused collectives.
+"""Per-dtype, byte-capped gradient bucketing for grouped collectives.
 
 The reference earns its overlap from the fusion buffer: gradients are
 packed into large same-dtype buffers and reduced while later gradients
 are still being computed (reference: horovod/common/
-fusion_buffer_manager.h:40, docs/tensor-fusion.rst). The in-graph
-analog (docs/mfu.md) is to split a gradient pytree into several
-independent fused ``psum`` buffers instead of one monolithic
-whole-pytree collective, giving XLA's latency-hiding scheduler
-independent collectives it can interleave with remaining backprop.
+fusion_buffer_manager.h:40, docs/tensor-fusion.rst). In-graph, on the
+v5e, that overlap does not exist to be earned: XLA combines whatever
+buckets it is handed into a few ``all-reduce``s (11 for GPT-2-medium's
+1.42 GB), they are synchronous on libtpu 0.0.34, and nothing runs
+beside them (PERF.md, PR 22 and PR 27). So a bucket here is a GROUP: the
+leaves one grouped collective takes together, and one name in the trace.
 
-This module owns the bucket *math* — shared by
+This module owns the bucket *math*, shared by
 ``horovod_tpu.jax.optimizer`` (byte-capped buckets, reverse-gradient
-issue order) and ``parallel.hierarchical.grouped_hierarchical_allreduce``
-(one uncapped bucket per dtype) so the two fused paths can never drift
-on dtype handling. Buckets are always per-dtype: concatenating a bf16
-leaf into an fp32 buffer would silently upcast the bf16 majority and
-double its bytes on the wire.
+order; on the flat route the leaves of a bucket go to one ``lax.psum``
+as they lie and nothing here copies them) and
+``parallel.hierarchical.grouped_hierarchical_allreduce`` (one uncapped
+bucket per dtype, packed into ONE flat buffer because a ``psum_scatter``
+needs an array divisible by the ici size), so the two paths can never
+drift on dtype handling. Buckets are always per-dtype: a bf16 leaf in
+an fp32 buffer would be upcast and double its bytes on the wire, and
+one all-reduce takes operands of one element type.
 
 The assignment functions are pure Python over ``(nbytes, dtype_key)``
-descriptors — unit-testable without tracing anything — while
-``pack_bucket``/``unpack_bucket`` do the jnp ravel/concat/slice work.
+descriptors, unit-testable without tracing anything;
+``pack_bucket``/``unpack_bucket`` do the jnp ravel/concat/slice work for
+the hierarchical route alone.
 """
 
 from __future__ import annotations
@@ -28,7 +33,7 @@ from typing import Any, List, NamedTuple, Sequence, Tuple
 
 
 class Bucket(NamedTuple):
-    """One fused collective's worth of leaves.
+    """One grouped collective's worth of leaves.
 
     ``indices`` are positions into the caller's leaf list, in issue
     order (reverse-gradient order when ``reverse=True``); ``nbytes`` is
@@ -49,11 +54,10 @@ def assign_buckets(
 ) -> List[Bucket]:
     """Assign leaves to per-dtype buckets capped at ``bucket_bytes``.
 
-    Walks the leaves in reverse order by default — backprop finishes the
-    *last* layers' gradients first, so reverse-flatten order issues the
-    collectives whose inputs are ready earliest (the reference's
-    coordinator achieves the same by negotiating tensors as they become
-    ready). A bucket closes once its payload reaches ``bucket_bytes``;
+    Walks the leaves in reverse order by default: backprop finishes the
+    *last* layers' gradients first, so reverse-flatten order names the
+    groups in the order their inputs become ready (the reference's
+    coordinator negotiates tensors as they become ready). A bucket closes once its payload reaches ``bucket_bytes``;
     a single leaf larger than the cap still gets its own bucket (the
     cap bounds *batching*, it never splits a tensor).
 

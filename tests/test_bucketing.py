@@ -111,3 +111,45 @@ def test_pack_preserves_dtype():
     # The fused buffer must stay bf16 — upcasting would double the
     # bytes on the wire for the bf16 majority.
     assert flat.dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("hierarchical", [False, True])
+def test_only_the_hierarchical_route_packs(hierarchical, monkeypatch):
+    """The optimizer's flat route hands a bucket's leaves to the
+    collective as they lie: ``pack_bucket`` / ``unpack_bucket`` are the
+    hierarchical ladder's alone (one call each per bucket there)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from horovod_tpu.jax.optimizer import allreduce_gradients
+    from horovod_tpu.parallel import bucketing
+    from horovod_tpu.parallel.mesh import shard_map_compat
+
+    calls = {"pack": 0, "unpack": 0}
+    pack, unpack = bucketing.pack_bucket, bucketing.unpack_bucket
+
+    def counting_pack(leaves, **kw):
+        calls["pack"] += 1
+        return pack(leaves, **kw)
+
+    def counting_unpack(flat, leaves):
+        calls["unpack"] += 1
+        return unpack(flat, leaves)
+
+    monkeypatch.setattr(bucketing, "pack_bucket", counting_pack)
+    monkeypatch.setattr(bucketing, "unpack_bucket", counting_unpack)
+    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "64")
+    if hierarchical:
+        monkeypatch.setenv("HOROVOD_HIERARCHICAL_ALLREDUCE", "1")
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                ("data_dcn", "data_ici"))
+    grads = {"a": jnp.ones((3, 5), jnp.float32),
+             "b": jnp.ones((7,), jnp.bfloat16),
+             "c": jnp.ones((33,), jnp.float32)}
+    jax.make_jaxpr(shard_map_compat(
+        lambda g: allreduce_gradients(g, axis=("data_dcn", "data_ici")),
+        mesh=mesh, in_specs=P(), out_specs=P()))(grads)
+    # 64-byte cap: c (132 B), b (14 B), a (60 B) are a bucket each.
+    assert calls == ({"pack": 3, "unpack": 3} if hierarchical
+                     else {"pack": 0, "unpack": 0})

@@ -279,11 +279,10 @@ def _bench_transformer(args, platform, device_kind, long_context=False,
 def _perf_config():
     """In-graph perf knobs + tuner state, embedded in the result JSON.
 
-    Recording the exact bucket/tile configuration a result was
+    Recording the exact tile configuration a result was
     measured under is what lets a later run prove (or falsify) a delta
     against it (docs/mfu.md).
     """
-    from horovod_tpu.jax.optimizer import grad_bucket_bytes
     from horovod_tpu.ops import block_tuner
     from horovod_tpu.utils import metrics
 
@@ -297,12 +296,10 @@ def _perf_config():
 
     tuner = online_tuner.online_tuner()
     return {
-        "grad_bucket_bytes": grad_bucket_bytes(),
         "flash_tune_mode": block_tuner.tune_mode() or "off",
         "flash_block_q_env": os.environ.get("HVD_FLASH_BLOCK_Q"),
         "flash_block_k_env": os.environ.get("HVD_FLASH_BLOCK_K"),
         "flash_tuned": block_tuner.tuned_snapshot(),
-        "hvd_grad_buckets_total": _total("hvd_grad_buckets_total"),
         "hvd_flash_tuner_trials_total": _total(
             "hvd_flash_tuner_trials_total"),
         # Online-tuner movement (docs/autotune.md): final knob state +
@@ -493,10 +490,6 @@ def main():
                         "child: flash-attention workloads autotune "
                         "their VMEM tiles on first call and journal "
                         "the winners (docs/mfu.md).")
-    p.add_argument("--grad-bucket-bytes", type=int, default=None,
-                   help="Export HVD_GRAD_BUCKET_BYTES to the child "
-                        "(0 = legacy single whole-pytree psum; "
-                        "default: the optimizer's 4 MiB buckets).")
     p.add_argument("--tune", action="store_true",
                    help="Export HVD_TUNE=1 to the benchmark child: the "
                         "online tuner (docs/autotune.md) runs during "
@@ -509,8 +502,6 @@ def main():
     # inherits them without plumbing.
     if args.tune_flash:
         os.environ["HVD_FLASH_TUNE"] = "1"
-    if args.grad_bucket_bytes is not None:
-        os.environ["HVD_GRAD_BUCKET_BYTES"] = str(args.grad_bucket_bytes)
     if args.tune:
         os.environ.setdefault("HVD_TUNE", "1")
         # Bench runs are short; a 30 s window would never complete a

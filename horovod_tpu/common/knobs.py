@@ -236,11 +236,6 @@ REGISTRY: Dict[str, Knob] = {k.name: k for k in [
     Knob("HVD_FLASH_BLOCK_K", HONORED,
          "ops/pallas_attention.py: flash-attention key/value tile "
          "size"),
-    # In-graph MFU knobs (docs/mfu.md).
-    Knob("HVD_GRAD_BUCKET_BYTES", HONORED,
-         "jax/optimizer.py: per-dtype gradient-allreduce bucket "
-         "payload; a bucket's leaves go to one grouped psum where they "
-         "lie (default 4 MiB; 0 = the whole tree as one group)"),
     Knob("HVD_FLASH_TUNE", HONORED,
          "ops/pallas_attention.py + ops/block_tuner.py: 1 = autotune "
          "flash-attention tiles per shape on first call and journal "
@@ -462,8 +457,8 @@ REGISTRY: Dict[str, Knob] = {k.name: k for k in [
 #
 # ``live_safe=False`` marks knobs whose LIVE per-rank mutation can
 # lower rank-divergent XLA programs (trace-time reads: divergent
-# gradient-bucket layouts or flash tiles desync the collective
-# sequence across ranks). The tuner only searches them when the
+# flash tiles or meshes desync the collective sequence across
+# ranks). The tuner only searches them when the
 # process is alone in its world; they are still declared here so the
 # schema is the single inventory of the tunable surface.
 
@@ -504,17 +499,12 @@ TUNABLE: Dict[str, TunableKnob] = {t.name: t for t in [
                 "live fds + pins an override for future connects "
                 "(core/session.set_wire_params; 0 = kernel default "
                 "for future sockets only)"),
-    TunableKnob("grad_bucket_bytes", 0.0, float(64 << 20),
-                float(1 << 20), "env", "HVD_GRAD_BUCKET_BYTES",
-                float(4 << 20), False,
-                "in-graph gradient-bucket payload; read at TRACE time "
-                "— per-rank divergence lowers divergent psum sequences "
-                "(docs/mfu.md), so live search is single-process only"),
     TunableKnob("flash_block_q", 128.0, 512.0, 128.0, "env",
                 "HVD_FLASH_BLOCK_Q", 512.0, False,
                 "flash-attention query tile (unset: a rule on the "
-                "sequence length, 512 for long ones); trace-time read, "
-                "same rank-divergence hazard as grad_bucket_bytes (the "
+                "sequence length, 512 for long ones); read at TRACE "
+                "time — per-rank divergence lowers divergent programs, "
+                "so live search is single-process only (the "
                 "shape-keyed sweep in ops/block_tuner.py is the "
                 "preferred tuner for this one)"),
     TunableKnob("flash_block_k", 128.0, 512.0, 128.0, "env",
@@ -540,7 +530,7 @@ TUNABLE: Dict[str, TunableKnob] = {t.name: t for t in [
     # Sharding-planner cost-model weights (parallel/costmodel.py,
     # docs/planner.md): searched OFFLINE only — plans are chosen at
     # setup time and per-rank divergence would pick divergent meshes,
-    # the same trace-time hazard as grad_bucket_bytes. Autotune 2.0
+    # the same hazard as a trace-time read. Autotune 2.0
     # fits them against measured step times (docs/autotune.md).
     TunableKnob("plan_ici_bw_gbps", 10.0, 1010.0, 10.0, "env",
                 "HVD_PLAN_ICI_BW_GBPS", 90.0, False,

@@ -69,19 +69,15 @@ def equations(jaxpr):
                     yield from equations(inner)
 
 
-def _collective_counts(jaxpr) -> Dict[str, int]:
+def collective_counts(fn, *args, **kwargs) -> Dict[str, int]:
+    """Trace ``fn`` and count collective primitives in the full jaxpr
+    (descending into shard_map / scan / cond / custom-vjp subjaxprs)."""
     counts: Dict[str, int] = {}
-    for eqn in equations(jaxpr):
+    for eqn in equations(jax.make_jaxpr(fn)(*args, **kwargs).jaxpr):
         name = eqn.primitive.name
         if name in COLLECTIVE_PRIMITIVES:
             counts[name] = counts.get(name, 0) + 1
     return counts
-
-
-def collective_counts(fn, *args, **kwargs) -> Dict[str, int]:
-    """Trace ``fn`` and count collective primitives in the full jaxpr
-    (descending into shard_map / scan / cond / custom-vjp subjaxprs)."""
-    return _collective_counts(jax.make_jaxpr(fn)(*args, **kwargs).jaxpr)
 
 
 def assert_in_graph_gradient_sync(
@@ -105,52 +101,6 @@ def assert_in_graph_gradient_sync(
             "This usually means the step is running under plain pjit "
             "auto-sharding instead of shard_map over the data axis."
             % (missing, counts))
-    return counts
-
-
-# ``hvd_sync/bucket_<i>_<dtype>`` in an equation's name stack
-# (jax/optimizer.py ``_bucketed_allreduce``).
-_BUCKET_SCOPE_RE = re.compile(r"(?:^|/)%s/(bucket_\d+_\w+)" % SCOPE_SYNC)
-
-
-def assert_bucketed_gradient_sync(
-    fn, *args,
-    min_buckets: int = 2,
-    **kwargs,
-) -> Dict[str, int]:
-    """Assert the traced ``fn`` reduces its gradients in at least
-    ``min_buckets`` buckets.
-
-    A bucket is what ``jax/optimizer.py`` makes of it: a group of
-    gradient leaves handed to one grouped ``psum`` (jax 0.9.0 binds one
-    ``psum`` equation per leaf of the group) or one hierarchical ladder,
-    traced under the scope ``hvd_sync/bucket_<i>_<dtype>``. So the
-    buckets of a traced step are the distinct bucket scopes that hold a
-    ``psum`` or a ``reduce_scatter``; counting the primitives would take
-    a whole-tree ``psum`` (``HVD_GRAD_BUCKET_BYTES=0``, no bucket scope)
-    for as many buckets as it has leaves. A silent collapse to that
-    single group fails here.
-
-    This is a tripwire for the GROUPING, not for overlap: on four v5e
-    chips XLA combines the buckets into a few synchronous ``all-reduce``s
-    and runs nothing beside them, however many there are (PERF.md,
-    PR 22 and PR 27). Returns ``collective_counts``'s dict.
-    """
-    jaxpr = jax.make_jaxpr(fn)(*args, **kwargs).jaxpr
-    counts = _collective_counts(jaxpr)
-    buckets = set()
-    for eqn in equations(jaxpr):
-        if eqn.primitive.name in ("psum", "reduce_scatter"):
-            m = _BUCKET_SCOPE_RE.search(str(eqn.source_info.name_stack))
-            if m:
-                buckets.add(m.group(1))
-    if len(buckets) < min_buckets:
-        raise AssertionError(
-            "expected >= %d gradient buckets in the traced step, found "
-            "%d (%r; collectives %r). Gradient sync has collapsed back "
-            "to a monolithic group: check HVD_GRAD_BUCKET_BYTES and the "
-            "optimizer's bucket path."
-            % (min_buckets, len(buckets), sorted(buckets), counts))
     return counts
 
 

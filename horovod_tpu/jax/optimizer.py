@@ -9,30 +9,26 @@ across the mesh's data axis before the inner optimizer sees it.
 Two execution paths (SURVEY.md §7 "eager enqueue vs XLA tracing"):
 
 - **In-graph (the TPU fast path)**: when ``update`` runs under a jit trace
-  (gradients are tracers), the gradient pytree is split into per-dtype
-  buckets of ``HVD_GRAD_BUCKET_BYTES`` each (default 4 MiB), in
-  reverse-gradient order, and each bucket's leaves are reduced WHERE
-  THEY LIE: ``C.grouped_allreduce(leaves)`` is ``lax.psum`` over the
-  tuple of leaves and the division, with no flat buffer packed before
-  it and none unpacked after it. A bucket is a group of leaves handed
-  to the compiler together and a name in the trace
-  (``hvd_sync/bucket_<i>_<dtype>``, jax/introspect.py); it is not an
-  overlap mechanism. Measured on four v5e chips (PERF.md, PR 22 and
-  PR 27): XLA combines GPT-2-medium's bucket reductions into 11
-  ``all-reduce``s with tuple operands in the gradients' own tiled
-  layouts; they are synchronous on libtpu 0.0.34 (asynchronous only
-  under compiler options, which belong to the caller of ``jit``), and
-  nothing runs beside them (``sync.exposed_ms`` =
-  ``sync.collective_ms``). Over an axis of ONE chip the leaves are
-  returned as they came: the compiled step holds no instruction under
-  ``hvd_sync``. ``HVD_GRAD_BUCKET_BYTES=0`` traces the same arithmetic
-  as the default, as one group with no bucket scopes (jax 0.9.0 binds
-  one ``psum`` per leaf either way); deleting the option is ROADMAP
-  D2's. With a two-level ``(dcn, ici)`` axis and
-  ``HOROVOD_HIERARCHICAL_ALLREDUCE=1`` each bucket rides the
-  hierarchical ladder (``parallel/hierarchical.py``), which keeps its
+  (gradients are tracers), the whole gradient tree goes to ONE
+  ``C.grouped_allreduce`` under the scope ``hvd_sync``: ``lax.psum``
+  over the tuple of leaves WHERE THEY LIE and the division, with no
+  flat buffer packed before it and none unpacked after it, the leaves
+  in the tree's own order (reverse order compiles to the same step;
+  PERF.md, PR 28). How many collectives the step runs is the decision
+  of XLA's all-reduce combiner, not of this module. Measured on four
+  v5e chips (PERF.md, PR 22 and PR 27): GPT-2-medium's 1.42 GB of
+  gradients become 11 ``all-reduce``s with tuple operands in the
+  gradients' own tiled layouts; they are synchronous on libtpu 0.0.34
+  (asynchronous only under compiler options, which belong to the
+  caller of ``jit``), and nothing runs beside them
+  (``sync.exposed_ms`` = ``sync.collective_ms``). Over an axis of ONE
+  chip the leaves are returned as they came: the compiled step holds
+  no instruction under ``hvd_sync``. With a two-level ``(dcn, ici)``
+  axis and ``HOROVOD_HIERARCHICAL_ALLREDUCE=1`` the group rides the
+  hierarchical ladder (``parallel/hierarchical.py``), which owns its
   packing: a ``psum_scatter`` needs one buffer divisible by the ``ici``
-  size.
+  size. (Until PR 28 the tree was cut into 4 MiB "buckets" for an
+  overlap with the backward pass that the chip never showed.)
 - **Eager**: with concrete arrays and world size > 1, each leaf is
   submitted to the native core's negotiation queue exactly like the
   reference's per-gradient async enqueue (named tensors, fused by the
@@ -46,7 +42,6 @@ locally for k steps and the collective fires on the k-th.
 
 from __future__ import annotations
 
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -60,78 +55,19 @@ from horovod_tpu.jax.compression import Compression
 from horovod_tpu.jax.introspect import SCOPE_SYNC, SCOPE_UPDATE
 from horovod_tpu.ops import collective_ops as C
 from horovod_tpu.ops import eager
-from horovod_tpu.parallel import bucketing
 from horovod_tpu.parallel.mesh import DATA_AXIS
 from horovod_tpu.parallel.mesh import traced_axis_size
 from horovod_tpu.utils import metrics as _metrics
 
-# Default bucket payload for the in-graph gradient allreduce: how many
-# bytes of leaves go to the compiler as one group and share one name in
-# the trace. XLA's all-reduce combiner, not this number, decides how
-# many collectives the step runs (11 for GPT-2-medium; PERF.md).
-DEFAULT_GRAD_BUCKET_BYTES = 4 * 1024 * 1024
-
 # Counted at trace time (in-graph collectives are invisible to Python
-# per step): how many buckets each traced train step issues.
-_M_BUCKETS = _metrics.counter(
-    "hvd_grad_buckets_total",
-    "Gradient-allreduce buckets issued by the in-graph bucketed "
-    "path (counted at trace time, per dtype).", ("dtype",))
-
-# Also at trace time: which way each gradient leaf went. ``in_place``:
+# per step): which way each gradient leaf went. ``in_place``:
 # reduced where it lies, no copy; ``packed``: copied into a hierarchical
 # ladder's flat buffer; ``skipped``: returned untouched because the axis
 # has one chip.
 _M_LEAVES = _metrics.counter(
     "hvd_grad_leaves_total",
-    "Gradient leaves through the in-graph bucketed path, by route "
+    "Gradient leaves through the in-graph gradient sync, by route "
     "(counted at trace time).", ("route",))
-
-
-def grad_bucket_bytes() -> int:
-    """Resolved ``HVD_GRAD_BUCKET_BYTES`` (0 = one group for the tree)."""
-    return int(os.environ.get("HVD_GRAD_BUCKET_BYTES",
-                              str(DEFAULT_GRAD_BUCKET_BYTES)))
-
-
-def _bucketed_allreduce(wires, op, *, axis, process_set, bucket_bytes,
-                        prescale_factor, postscale_factor):
-    """Per-dtype byte-capped grouped allreduce of a leaf list.
-
-    A bucket is a GROUP of leaves handed to one ``C.grouped_allreduce``
-    as they lie (``lax.psum`` over the tuple, then the division), in
-    reverse-gradient order, under the scope ``bucket_<i>_<dtype>``: no
-    leaf is copied into a buffer or sliced out of one. Per element the
-    same cross-replica sum as the whole-tree ``psum``; bucketing only
-    chooses which leaves travel together. ``C.grouped_allreduce`` owns
-    the routing: on the hierarchical ``(dcn, ici)`` route it packs the
-    group into the one padded buffer a ``psum_scatter`` needs.
-
-    What the buckets do NOT buy is overlap with the backward pass: XLA
-    combines them into a few synchronous ``all-reduce``s (11 for
-    GPT-2-medium on four v5e chips, every microsecond exposed; PERF.md).
-    """
-    sizes = [w.size * jnp.dtype(w.dtype).itemsize for w in wires]
-    keys = [jnp.dtype(w.dtype).name for w in wires]
-    buckets = bucketing.assign_buckets(sizes, keys, bucket_bytes)
-    route = ("packed" if C._route_hierarchical(
-        op, process_set, axis, "HOROVOD_HIERARCHICAL_ALLREDUCE")
-        else "in_place")
-    _M_LEAVES.labels(route).inc(len(wires))
-    outs = [None] * len(wires)
-    for n, bucket in enumerate(buckets):
-        _M_BUCKETS.labels(bucket.dtype_key).inc()
-        # One scope a bucket, so a device trace tells one bucket from
-        # the next (jax/introspect.py).
-        with jax.named_scope("bucket_%d_%s" % (n, bucket.dtype_key)):
-            reduced = C.grouped_allreduce(
-                [wires[i] for i in bucket.indices], op, axis=axis,
-                process_set=process_set,
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor)
-        for i, out in zip(bucket.indices, reduced):
-            outs[i] = out
-    return outs
 
 
 def _scaled(wires, factor):
@@ -182,10 +118,8 @@ def allreduce_gradients(
 ):
     """Allreduce a gradient pytree; dispatches in-graph vs eager.
 
-    In-graph: per-dtype buckets of ``HVD_GRAD_BUCKET_BYTES`` each, every
-    bucket's leaves reduced where they lie by one grouped ``psum`` in
-    reverse-gradient order (0 = the whole tree as one group); nothing at
-    all over an axis of one chip.
+    In-graph: one grouped ``psum`` of the tree's leaves where they lie;
+    nothing at all over an axis of one chip.
     Eager: grouped submission to the native core, names derived from tree
     paths so every rank agrees on tensor identity.
     """
@@ -211,28 +145,23 @@ def _allreduce_gradients(grads, *, op, axis, process_set, compression,
     ctxs = [c[1] for c in compressed]
 
     if _is_tracing(wires) and _axis_in_scope(axis):
-        bucket_bytes = grad_bucket_bytes()
-        bucketable = (op in (C.Average, C.Sum)
-                      and C._is_global_set(process_set))
-        if bucketable and traced_axis_size(axis) == 1:
+        fusable = (op in (C.Average, C.Sum)
+                   and C._is_global_set(process_set))
+        if fusable and traced_axis_size(axis) == 1:
             # One chip on the axis: the sum of one value and the
             # division by one. XLA drops a one-device all-reduce itself;
             # tracing nothing also leaves it nothing to schedule around.
             _M_LEAVES.labels("skipped").inc(len(wires))
             outs = _scaled(wires, prescale_factor * postscale_factor)
-        elif bucketable and bucket_bytes > 0 and len(wires) > 1:
-            outs = _bucketed_allreduce(
-                wires, op, axis=axis, process_set=process_set,
-                bucket_bytes=bucket_bytes,
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor,
-            )
         else:
-            # HVD_GRAD_BUCKET_BYTES=0 (the same arithmetic as the
-            # bucketed branch, as one group with no bucket scopes),
-            # non-fusable ops (Min/Max/Product/Adasum), restricted
-            # process sets, and single-leaf trees: one grouped
-            # collective.
+            # The whole tree as one group. ``C`` owns the route: the
+            # leaves where they lie, or packed for the ``(dcn, ici)``
+            # ladder.
+            _M_LEAVES.labels(
+                "packed" if C._route_hierarchical(
+                    op, process_set, axis,
+                    "HOROVOD_HIERARCHICAL_ALLREDUCE")
+                else "in_place").inc(len(wires))
             outs = C.grouped_allreduce(
                 wires, op,
                 axis=axis, process_set=process_set,

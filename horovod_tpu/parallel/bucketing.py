@@ -1,30 +1,21 @@
-"""Per-dtype, byte-capped gradient bucketing for grouped collectives.
+"""Per-dtype, byte-capped packing of a tensor group into flat buffers.
 
-The reference earns its overlap from the fusion buffer: gradients are
-packed into large same-dtype buffers and reduced while later gradients
-are still being computed (reference: horovod/common/
-fusion_buffer_manager.h:40, docs/tensor-fusion.rst). In-graph, on the
-v5e, that overlap does not exist to be earned: XLA combines whatever
-buckets it is handed into a few ``all-reduce``s (11 for GPT-2-medium's
-1.42 GB), they are synchronous on libtpu 0.0.34, and nothing runs
-beside them (PERF.md, PR 22 and PR 27). So a bucket here is a GROUP: the
-leaves one grouped collective takes together, and one name in the trace.
+The reference's fusion buffer: tensors are packed into large same-dtype
+buffers and each buffer is reduced by one collective (reference:
+horovod/common/fusion_buffer_manager.h:40, docs/tensor-fusion.rst).
 
-This module owns the bucket *math*, shared by
-``horovod_tpu.jax.optimizer`` (byte-capped buckets, reverse-gradient
-order; on the flat route the leaves of a bucket go to one ``lax.psum``
-as they lie and nothing here copies them) and
-``parallel.hierarchical.grouped_hierarchical_allreduce`` (one uncapped
-bucket per dtype, packed into ONE flat buffer because a ``psum_scatter``
-needs an array divisible by the ici size), so the two paths can never
-drift on dtype handling. Buckets are always per-dtype: a bf16 leaf in
-an fp32 buffer would be upcast and double its bytes on the wire, and
-one all-reduce takes operands of one element type.
+ONE caller is left: ``parallel.hierarchical.
+grouped_hierarchical_allreduce``, whose ``psum_scatter`` needs one
+array divisible by the ici size and so cannot take the leaves where
+they lie. The flat route of the gradient sync (``lax.psum`` over the
+tuple of leaves) copies nothing and does not come here. Buckets are
+always per-dtype: a bf16 leaf in an fp32 buffer would be upcast and
+double its bytes on the wire, and one all-reduce takes operands of one
+element type.
 
-The assignment functions are pure Python over ``(nbytes, dtype_key)``
+``assign_buckets`` is pure Python over ``(nbytes, dtype_key)``
 descriptors, unit-testable without tracing anything;
-``pack_bucket``/``unpack_bucket`` do the jnp ravel/concat/slice work for
-the hierarchical route alone.
+``pack_bucket``/``unpack_bucket`` do the jnp ravel/concat/slice work.
 """
 
 from __future__ import annotations
@@ -33,11 +24,11 @@ from typing import Any, List, NamedTuple, Sequence, Tuple
 
 
 class Bucket(NamedTuple):
-    """One grouped collective's worth of leaves.
+    """One flat buffer's worth of leaves.
 
-    ``indices`` are positions into the caller's leaf list, in issue
-    order (reverse-gradient order when ``reverse=True``); ``nbytes`` is
-    the summed payload of the bucket.
+    ``indices`` are positions into the caller's leaf list, in packing
+    order (reverse order when ``reverse=True``); ``nbytes`` is the
+    summed payload of the bucket.
     """
 
     dtype_key: Any
@@ -55,15 +46,14 @@ def assign_buckets(
     """Assign leaves to per-dtype buckets capped at ``bucket_bytes``.
 
     Walks the leaves in reverse order by default: backprop finishes the
-    *last* layers' gradients first, so reverse-flatten order names the
-    groups in the order their inputs become ready (the reference's
-    coordinator negotiates tensors as they become ready). A bucket closes once its payload reaches ``bucket_bytes``;
-    a single leaf larger than the cap still gets its own bucket (the
-    cap bounds *batching*, it never splits a tensor).
+    *last* layers' gradients first (the reference's coordinator
+    negotiates tensors as they become ready). A bucket closes once its
+    payload reaches ``bucket_bytes``; a single leaf larger than the cap
+    still gets its own bucket (the cap bounds *batching*, it never
+    splits a tensor).
 
     ``bucket_bytes <= 0`` means "no cap": exactly one bucket per dtype,
-    in first-seen (reverse) order — the fusion behavior
-    ``grouped_hierarchical_allreduce`` always had.
+    in first-seen order.
     """
     if len(nbytes_per_leaf) != len(dtype_keys):
         raise ValueError("leaf size/dtype lists disagree: %d vs %d"

@@ -65,15 +65,17 @@ PARAM_STATE_MULT = 4.0
 # Transformer activation footprint per token-dim element across a
 # layer's intermediates (post-attn, MLP hidden, norms), without remat.
 ACT_MULT = 8.0
-# Fraction of gradient-sync time exposed on the critical path: the
-# bucketed reverse-order issue (docs/mfu.md) overlaps most of the
-# allreduce with the remaining backprop, which is exactly why data
-# parallelism beats same-byte-count blocking alternatives. Tunable via
+# Fraction of gradient-sync time the model charges to the critical
+# path. The default assumes three quarters of the allreduce hide behind
+# the remaining backprop; on four v5e chips NONE of it does (the
+# all-reduces are synchronous, ``sync.exposed_ms`` =
+# ``sync.collective_ms``; PERF.md, PR 22 and PR 27), so the measured
+# value is 1.0. Refitting it is ROADMAP S7's. Tunable via
 # HVD_PLAN_GRAD_OVERLAP (Autotune 2.0 schema entry).
 DEFAULT_GRAD_OVERLAP = 0.25
 # Per-collective launch latency for BLOCKING collectives (tensor/
 # sequence/expert/pipeline exchanges sit on the critical path once per
-# layer; gradient buckets are latency-hidden and charged above). All
+# layer; the gradient sync is charged by the fraction above). All
 # blocking collectives here are intra-slice: the data axis absorbs the
 # whole DCN factor, so only the hierarchical grad leg crosses slices.
 LAT_ICI_SEC = 2e-6
@@ -406,9 +408,9 @@ def score(axes: Dict[str, int], workload: Workload,
     mem = per_chip_param * PARAM_STATE_MULT + \
         (w.n_layers / p) * act * ACT_MULT
     # Exposed time: blocking collectives pay full bandwidth + launch
-    # latency; gradient buckets pay only their exposed fraction (they
-    # overlap backprop — docs/mfu.md — which is the reason data
-    # parallelism beats same-byte blocking layouts).
+    # latency; the gradient sync pays only its ``grad_overlap()``
+    # fraction (an assumption the v5e does not bear out: see
+    # DEFAULT_GRAD_OVERLAP).
     overlap = grad_overlap()
     seconds = (ici + overlap * grad_ici) / (topology.ici_bw_gbps * 1e9) \
         + (dcn + overlap * grad_dcn) / (topology.dcn_bw_gbps * 1e9) \

@@ -26,6 +26,10 @@ from horovod_tpu.parallel.mesh import traced_axis_size
 ICI_AXIS = "data_ici"
 DCN_AXIS = "data_dcn"
 
+# The most leaf bytes ``grouped_hierarchical_allreduce`` packs into one
+# flat buffer (one ladder a buffer). A constant: one value is in use.
+PACK_BYTES = 4 * 1024 * 1024
+
 
 def make_hierarchical_axes(ici_size: int, dcn_size: int) -> Dict[str, int]:
     """Axis spec for ``make_mesh``: the data dimension factored into
@@ -56,25 +60,24 @@ def hierarchical_allreduce(x, *, average: bool = True, ici_axis=ICI_AXIS,
 
 def grouped_hierarchical_allreduce(xs, *, average: bool = True,
                                    ici_axis=ICI_AXIS, dcn_axis=DCN_AXIS):
-    """Two-level allreduce of a tensor group through one fused buffer.
+    """Two-level allreduce of a tensor group through fused buffers.
 
     The per-tensor path requires dim 0 divisible by the ici size —
     gradient pytrees rarely oblige (biases, odd leading dims). Instead,
     reproduce the reference's fusion-buffer move
     (reference: horovod/common/fusion_buffer_manager.h:40 + the
     memcpy-in/collective/memcpy-out sequence in
-    ops/nccl_operations.cc:233-440): flatten every tensor into one 1-D
-    buffer per dtype, pad to a multiple of the ici size, run the
+    ops/nccl_operations.cc:233-440): flatten the tensors into 1-D
+    buffers, pad each to a multiple of the ici size, run the
     reduce_scatter(ici) → psum(dcn) → all_gather(ici) ladder once per
-    buffer, and slice the results back out. XLA keeps the pack/unpack
-    as on-chip reshapes, so the fused form costs one collective ladder
-    per dtype instead of one per tensor.
+    buffer, and slice the results back out: one collective ladder per
+    buffer instead of one per tensor.
 
-    Buffers are strictly per-dtype (``parallel.bucketing`` owns the
-    assignment, shared with the optimizer's byte-capped bucket path —
-    which feeds single-buffer groups through here, so the two fused
-    paths cannot drift on dtype handling): mixing a bf16 majority into
-    an fp32 buffer would upcast it and double its bytes on the wire.
+    Buffers are strictly per-dtype (a bf16 leaf in an fp32 buffer would
+    be upcast and double its bytes on the wire) and close once they
+    hold ``PACK_BYTES`` of leaves, in the order given and a leaf never
+    split, so the ladder's temporaries stay a few MiB however large the
+    tree is. ``parallel.bucketing`` does the assignment and the copies.
     """
     xs = [jnp.asarray(x) for x in xs]
     ici = traced_axis_size(ici_axis)
@@ -82,7 +85,7 @@ def grouped_hierarchical_allreduce(xs, *, average: bool = True,
     buckets = bucketing.assign_buckets(
         [x.size * jnp.dtype(x.dtype).itemsize for x in xs],
         [jnp.dtype(x.dtype).name for x in xs],
-        0, reverse=False)
+        PACK_BYTES, reverse=False)
     for bucket in buckets:
         leaves = [xs[i] for i in bucket.indices]
         flat, _ = bucketing.pack_bucket(leaves, pad_multiple=ici)

@@ -1,7 +1,7 @@
 """One cost-model-driven sharding planner over the whole parallel/ stack.
 
-``parallel/`` grew mesh, hierarchical, Adasum, MoE, pipeline, sequence
-and bucketing modules, but composing them was manual: every training
+``parallel/`` grew mesh, hierarchical, Adasum, MoE, pipeline and
+sequence modules, but composing them was manual: every training
 script hand-picked axis sizes and hand-wired the gradient-sync
 strategy. This module is the single owner of layout — the seam
 GSPMD/Alpa-style systems put their auto-sharding pass behind, and the
@@ -12,10 +12,10 @@ reference never needed because it only does data parallelism
 count, batch/seq/model dims, optional MoE/pipeline counts) and a
 device topology (chip count with its ICI x DCN factorization) and
 returns a :class:`Plan`: the mesh axis dict, per-leaf PartitionSpecs,
-and the gradient-sync strategy (flat psum vs the hierarchical ladder,
-bucket bytes via ``parallel/bucketing``). Axis assignment is scored by
-the explicit cost model in ``parallel/costmodel.py`` — every legal
-factorization is enumerated and the report shows the losers and why.
+and the gradient-sync strategy (flat psum vs the hierarchical
+ladder). Axis assignment is scored by the explicit cost model in
+``parallel/costmodel.py`` — every legal factorization is enumerated
+and the report shows the losers and why.
 
 Three surfaces (docs/planner.md):
 
@@ -128,7 +128,7 @@ class Plan:
     def __init__(self, *, mesh_axes: Dict[str, int],
                  data_axes: Tuple[str, ...],
                  grad_axes: Tuple[str, ...], sync: str,
-                 bucket_bytes: int, workload: Workload,
+                 workload: Workload,
                  topology: Topology, chosen: Candidate,
                  rejected: Sequence[Candidate]):
         self.mesh_axes = dict(mesh_axes)
@@ -143,7 +143,6 @@ class Plan:
         # data x seq grid only).
         self.grad_axes = tuple(grad_axes)
         self.sync = sync          # "none" | "psum" | "hierarchical"
-        self.bucket_bytes = int(bucket_bytes)
         self.workload = workload
         self.topology = topology
         self.chosen = chosen
@@ -157,7 +156,7 @@ class Plan:
 
         After ``apply()``, ``DistributedOptimizer(tx,
         axis=plan.data_axes)`` (or :meth:`optimizer`) syncs gradients
-        exactly as planned: one grouped/bucketed psum on a flat data
+        exactly as planned: one grouped psum on a flat data
         axis, the ``grouped_hierarchical_allreduce`` ladder on a
         ``(data_dcn, data_ici)`` factorization.
         """
@@ -257,9 +256,8 @@ class Plan:
         top = next((c for c in self.rejected), None)
         rej = " top-rejected=%s (%s)" % (
             costmodel._compact(top.axes), top.reason) if top else ""
-        return ("mesh=%r sync=%s bucket_bytes=%d step_comm=%.3f ms "
-                "mem/chip=%.2f GB%s"
-                % (self.mesh_axes, self.sync, self.bucket_bytes,
+        return ("mesh=%r sync=%s step_comm=%.3f ms mem/chip=%.2f GB%s"
+                % (self.mesh_axes, self.sync,
                    self.chosen.cost.seconds * 1e3,
                    self.chosen.cost.mem_bytes / 1e9, rej))
 
@@ -310,7 +308,6 @@ class Plan:
             "data_axes": list(self.data_axes),
             "grad_axes": list(self.grad_axes),
             "sync": self.sync,
-            "bucket_bytes": self.bucket_bytes,
             "step_comm_ms": round(self.chosen.cost.seconds * 1e3, 6),
             "mem_per_chip_gb": round(self.chosen.cost.mem_bytes / 1e9, 4),
             "chips": self.topology.chips,
@@ -325,14 +322,6 @@ class Plan:
         return "Plan(%s)" % self.summary()
 
 
-def _grad_bucket_bytes() -> int:
-    # Late import: jax/optimizer owns the HVD_GRAD_BUCKET_BYTES knob
-    # and its default; the planner just records the resolved value.
-    from horovod_tpu.jax.optimizer import grad_bucket_bytes
-
-    return grad_bucket_bytes()
-
-
 def plan(params=None, *, batch: Optional[int] = None, seq_len: int = 1,
          d_model: Optional[int] = None, n_layers: int = 1,
          num_experts: int = 0, pipeline_stages: int = 0,
@@ -342,8 +331,7 @@ def plan(params=None, *, batch: Optional[int] = None, seq_len: int = 1,
          workload: Optional[Workload] = None,
          topology: Optional[Topology] = None,
          chips: Optional[int] = None, dcn: int = 1,
-         require_axes: Optional[Dict[str, int]] = None,
-         bucket_bytes: Optional[int] = None) -> Plan:
+         require_axes: Optional[Dict[str, int]] = None) -> Plan:
     """Choose a composed parallel layout for a workload on a topology.
 
     Workload: pass a ``params`` pytree (real arrays or
@@ -384,13 +372,11 @@ def plan(params=None, *, batch: Optional[int] = None, seq_len: int = 1,
     candidates = costmodel.enumerate_candidates(
         workload, topology, require_axes)
     chosen, rejected = costmodel.choose(candidates)
-    return _plan_from_candidate(chosen, rejected, workload, topology,
-                                bucket_bytes)
+    return _plan_from_candidate(chosen, rejected, workload, topology)
 
 
 def _plan_from_candidate(chosen: Candidate, rejected: List[Candidate],
-                         workload: Workload, topology: Topology,
-                         bucket_bytes: Optional[int]) -> Plan:
+                         workload: Workload, topology: Topology) -> Plan:
     axes = chosen.axes
     d = axes[costmodel.DATA]
     s = axes[costmodel.SEQ]
@@ -427,8 +413,5 @@ def _plan_from_candidate(chosen: Candidate, rejected: List[Candidate],
         sync = "psum"
     return Plan(
         mesh_axes=mesh_axes, data_axes=data_axes, grad_axes=grad_axes,
-        sync=sync,
-        bucket_bytes=bucket_bytes if bucket_bytes is not None
-        else _grad_bucket_bytes(),
-        workload=workload, topology=topology, chosen=chosen,
+        sync=sync, workload=workload, topology=topology, chosen=chosen,
         rejected=rejected)

@@ -252,7 +252,7 @@ class KnobBinding:
         if restore:
             # Restores bypass the grid snap: the launch anchor must be
             # re-applied BYTE-uniform with peers that inherit the raw
-            # job env — snapping an off-grid HVD_GRAD_BUCKET_BYTES
+            # job env — snapping an off-grid HVD_FLASH_BLOCK_Q
             # onto the box would itself diverge from them.
             value = float(value)
             if not self.knob.live_safe and _shared_world():
@@ -919,8 +919,9 @@ _global_tuner: Optional[OnlineTuner] = None
 
 # Default knob sets per role. Training searches the wire + negotiation
 # surface (all live-safe, rank-divergence-free); non-live_safe knobs
-# (grad buckets, flash tiles) are schema-declared but never searched
-# live in a multi-rank world — docs/autotune.md#what-is-not-searched.
+# (flash tiles, the codec, the planner's weights) are schema-declared
+# but never searched live in a multi-rank world —
+# docs/autotune.md#what-is-not-searched.
 TRAINING_KNOBS = ("fusion_threshold_mb", "cycle_time_ms",
                   "ring_chunk_bytes", "socket_buf_bytes")
 SERVE_KNOBS = ("serve_max_batch", "serve_deadline_ms")

@@ -1,8 +1,8 @@
 """Bucket-assignment math units (parallel/bucketing.py).
 
-Pure-Python contracts the in-graph fused paths rely on: per-dtype
-splitting (never upcast a bf16 majority into an fp32 buffer), byte
-caps, reverse-gradient issue order, and pack/unpack round-trips. No
+Pure-Python contracts the hierarchical ladder's packing relies on:
+per-dtype splitting (never upcast a bf16 majority into an fp32
+buffer), byte caps, order, and pack/unpack round-trips. No
 mesh, no sweeps — seconds-fast (docs/mfu.md).
 """
 
@@ -115,15 +115,15 @@ def test_pack_preserves_dtype():
 
 @pytest.mark.parametrize("hierarchical", [False, True])
 def test_only_the_hierarchical_route_packs(hierarchical, monkeypatch):
-    """The optimizer's flat route hands a bucket's leaves to the
+    """The optimizer's flat route hands the tree's leaves to the
     collective as they lie: ``pack_bucket`` / ``unpack_bucket`` are the
-    hierarchical ladder's alone (one call each per bucket there)."""
+    hierarchical ladder's alone (one call each per buffer there)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
     from horovod_tpu.jax.optimizer import allreduce_gradients
-    from horovod_tpu.parallel import bucketing
+    from horovod_tpu.parallel import bucketing, hierarchical as hier
     from horovod_tpu.parallel.mesh import shard_map_compat
 
     calls = {"pack": 0, "unpack": 0}
@@ -139,7 +139,7 @@ def test_only_the_hierarchical_route_packs(hierarchical, monkeypatch):
 
     monkeypatch.setattr(bucketing, "pack_bucket", counting_pack)
     monkeypatch.setattr(bucketing, "unpack_bucket", counting_unpack)
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "64")
+    monkeypatch.setattr(hier, "PACK_BYTES", 64)
     if hierarchical:
         monkeypatch.setenv("HOROVOD_HIERARCHICAL_ALLREDUCE", "1")
     mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
@@ -150,6 +150,7 @@ def test_only_the_hierarchical_route_packs(hierarchical, monkeypatch):
     jax.make_jaxpr(shard_map_compat(
         lambda g: allreduce_gradients(g, axis=("data_dcn", "data_ici")),
         mesh=mesh, in_specs=P(), out_specs=P()))(grads)
-    # 64-byte cap: c (132 B), b (14 B), a (60 B) are a bucket each.
-    assert calls == ({"pack": 3, "unpack": 3} if hierarchical
+    # 64-byte buffers: a (60 B) and c (132 B) share one, b (bf16) has
+    # its own.
+    assert calls == ({"pack": 2, "unpack": 2} if hierarchical
                      else {"pack": 0, "unpack": 0})

@@ -230,6 +230,30 @@ def test_report_names_chosen_and_rejected():
     assert "top-rejected=" in p.summary()
 
 
+@pytest.mark.parametrize("surface", ["summary", "to_json", "attribute",
+                                     "parameter"])
+def test_plan_carries_no_bucket_size(surface):
+    """The gradient sync has one shape, so a plan has nothing to record
+    about it beyond ``sync`` (``bucket_bytes`` left every surface with
+    the option it mirrored)."""
+    from horovod_tpu.parallel import planner
+
+    kwargs = dict(param_bytes=4 << 20, batch=16, seq_len=32, d_model=64,
+                  n_layers=2, chips=8)
+    p = planner.plan(**kwargs)
+    if surface == "summary":
+        assert "bucket" not in p.summary() and "bucket" not in p.report()
+        assert "sync=psum" in p.summary()
+    elif surface == "to_json":
+        assert "bucket_bytes" not in p.to_json()
+        assert p.to_json()["sync"] == "psum"
+    elif surface == "attribute":
+        assert not hasattr(p, "bucket_bytes")
+    else:
+        with pytest.raises(TypeError, match="bucket_bytes"):
+            planner.plan(bucket_bytes=1 << 20, **kwargs)
+
+
 def test_plan_scenarios_choose_distinct_meshes():
     """The MULTICHIP sweep's scenario table (pure Python, the same
     data the dryrun prints into its JSON tail): >= 4 distinct

@@ -1,19 +1,17 @@
-"""Bucketed gradient allreduce: equality, overlap structure, donation.
+"""In-graph gradient sync: equality, traced shape, donation.
 
-The ISSUE 7 acceptance tests (docs/mfu.md):
-
-- ``HVD_GRAD_BUCKET_BYTES=0`` restores the legacy single-psum path
-  bit-exactly (equality at np=2 on the virtual mesh);
-- the lowered train step contains >= N *independent* bucket
-  collectives, not one whole-pytree psum (introspect-based);
+- the sync IS ``lax.psum(tree)`` times the scales, bit for bit, with
+  the leaves reduced where they lie (no pack, no unpack, one ``psum``
+  and at most one division a leaf, all directly under ``hvd_sync``);
+- nothing in the environment changes what is traced (the bucket
+  option left with PR 28);
+- the hierarchical ``(dcn, ici)`` ladder owns its own packing;
 - donated buffers survive lowering (``tf.aliasing_output`` in the
   StableHLO).
 
 Runs on the 8-device virtual CPU mesh via shard_map (compat import:
 this jax predates ``jax.shard_map``).
 """
-
-import os
 
 import numpy as np
 import pytest
@@ -32,11 +30,9 @@ def shard_map(f, mesh, in_specs, out_specs):
 
 import horovod_tpu.jax as hvd_jax
 from horovod_tpu.jax import introspect
-from horovod_tpu.jax.optimizer import (
-    DEFAULT_GRAD_BUCKET_BYTES,
-    allreduce_gradients,
-    grad_bucket_bytes,
-)
+from horovod_tpu.jax.optimizer import allreduce_gradients
+from horovod_tpu.ops import collective_ops as C
+from horovod_tpu.parallel import hierarchical
 
 
 @pytest.fixture
@@ -79,16 +75,11 @@ def _primitive_counts(fn, *args):
     return counts
 
 
-def _bucket_scopes(fn, *args):
-    """{bucket scope: psum equations traced under it}."""
-    scopes = {}
-    for eqn in introspect.equations(jax.make_jaxpr(fn)(*args).jaxpr):
-        if eqn.primitive.name == "psum":
-            stack = str(eqn.source_info.name_stack)
-            assert stack.startswith("hvd_sync/bucket_"), stack
-            scope = stack.split("/")[1]
-            scopes[scope] = scopes.get(scope, 0) + 1
-    return scopes
+def _psums(fn, *args):
+    """The ``psum`` equations of the traced ``fn``, in order."""
+    return [eqn for eqn in
+            introspect.equations(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == "psum"]
 
 
 def _leaves_by_route():
@@ -99,70 +90,8 @@ def _leaves_by_route():
             for v in fam.get("values", [])}
 
 
-def test_default_bucket_bytes():
-    assert DEFAULT_GRAD_BUCKET_BYTES == 4 * 1024 * 1024
-    assert grad_bucket_bytes() in (DEFAULT_GRAD_BUCKET_BYTES,
-                                   int(os.environ.get(
-                                       "HVD_GRAD_BUCKET_BYTES", -1)))
-
-
-def test_zero_restores_legacy_bit_exactly_np2(mesh2, monkeypatch):
-    grads = _grads()
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "0")
-    legacy = _reduce_on(mesh2, grads)
-    for cap in ("1024", str(DEFAULT_GRAD_BUCKET_BYTES), "1073741824"):
-        monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", cap)
-        bucketed = _reduce_on(mesh2, grads)
-        for k in grads:
-            assert bucketed[k].dtype == grads[k].dtype
-            assert np.array_equal(np.asarray(legacy[k]),
-                                  np.asarray(bucketed[k])), \
-                "cap=%s leaf=%s" % (cap, k)
-
-
-def test_legacy_is_single_psum(mesh2, monkeypatch):
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "0")
-    counts = introspect.collective_counts(
-        shard_map(lambda g: allreduce_gradients(g, axis="data"),
-                  mesh2, P(), P()), _grads())
-    assert counts == {"psum": 1}
-
-
-def test_bucketed_issues_independent_collectives(mesh2, monkeypatch):
-    # 1 KiB cap over ~6 KiB of leaves: fp32 splits into 2 buckets and
-    # bf16 into 2 -> 4 independent psums for XLA to overlap.
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "1024")
-    counts = introspect.assert_bucketed_gradient_sync(
-        shard_map(lambda g: allreduce_gradients(g, axis="data"),
-                  mesh2, P(), P()), _grads(), min_buckets=4)
-    assert counts["psum"] == 4
-
-
-def test_per_dtype_buckets_at_large_cap(mesh2, monkeypatch):
-    # A cap bigger than the whole tree still yields one bucket PER
-    # DTYPE (bf16 never rides an fp32 group). jax 0.9.0 binds one
-    # ``psum`` equation per leaf of a group, so the buckets are read
-    # from their scopes and the psums counted per leaf.
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "1073741824")
-    fn = shard_map(lambda g: allreduce_gradients(g, axis="data"),
-                   mesh2, P(), P())
-    assert _bucket_scopes(fn, _grads()) == {
-        "bucket_0_bfloat16": 2, "bucket_1_float32": 2}
-    assert introspect.collective_counts(fn, _grads())["psum"] == 4
-    assert "convert_element_type" not in _primitive_counts(fn, _grads())
-
-
-def test_assert_bucketed_rejects_monolith(mesh2, monkeypatch):
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "0")
-    with pytest.raises(AssertionError, match="monolithic"):
-        introspect.assert_bucketed_gradient_sync(
-            shard_map(lambda g: allreduce_gradients(g, axis="data"),
-                      mesh2, P(), P()), _grads(), min_buckets=2)
-
-
-def test_bucketed_values_correct_np2(mesh2, monkeypatch):
+def test_bucketed_values_correct_np2(mesh2):
     # Average over 2 identical replicas == the input, bit for bit.
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "1024")
     grads = _grads()
     out = _reduce_on(mesh2, grads)
     for k in grads:
@@ -172,66 +101,47 @@ def test_bucketed_values_correct_np2(mesh2, monkeypatch):
 
 
 def test_hierarchical_bucket_routing(mesh4_hier, monkeypatch):
-    # (dcn, ici) axis tuple + env toggle: every bucket rides the
-    # reduce_scatter -> psum -> all_gather ladder.
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "1024")
+    # (dcn, ici) axis tuple + env toggle: the tree rides the
+    # reduce_scatter -> psum -> all_gather ladder and comes back whole.
     monkeypatch.setenv("HOROVOD_HIERARCHICAL_ALLREDUCE", "1")
     grads = _grads()
     axis = ("data_dcn", "data_ici")
-    counts = introspect.collective_counts(
-        shard_map(lambda g: allreduce_gradients(g, axis=axis),
-                  mesh4_hier, P(), P()), grads)
-    assert counts["reduce_scatter"] == 4
-    assert counts["all_gather"] == 4
-    assert counts["psum"] == 4  # dcn hop per bucket
     out = jax.jit(shard_map(
         lambda g: allreduce_gradients(g, axis=axis),
         mesh4_hier, P(), P()))(grads)
     for k in grads:
+        assert out[k].dtype == grads[k].dtype
         np.testing.assert_allclose(
             np.asarray(out[k], np.float32),
             np.asarray(grads[k], np.float32), rtol=1e-5)
 
 
-def test_assert_bucketed_rejects_hierarchical_monolith(mesh4_hier,
-                                                       monkeypatch):
-    # One whole-pytree hierarchical ladder traces as 1 reduce_scatter
-    # + 1 dcn psum; summing those would fake 2 "buckets" (review
-    # catch) — the max-based count must still call it a monolith.
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "0")
+@pytest.mark.parametrize("pack_bytes,ladders", [(1024, 3), (None, 2)])
+def test_hierarchical_ladder_owns_its_pack_size(mesh4_hier, monkeypatch,
+                                                pack_bytes, ladders):
+    # The ladder packs its own buffers, closed at ``PACK_BYTES`` of
+    # leaves: at 1 KiB w1 and w2 (fp32, 1.2 and 2 KB) are a buffer each
+    # and b1 waits for w3 (bf16); the default (4 MiB) is one buffer a
+    # dtype. One ladder a buffer.
+    assert hierarchical.PACK_BYTES == 4 * 1024 * 1024
+    if pack_bytes is not None:
+        monkeypatch.setattr(hierarchical, "PACK_BYTES", pack_bytes)
     monkeypatch.setenv("HOROVOD_HIERARCHICAL_ALLREDUCE", "1")
-    grads = {"a": jnp.ones((8,), jnp.float32),
-             "b": jnp.ones((8,), jnp.float32)}
     axis = ("data_dcn", "data_ici")
-    with pytest.raises(AssertionError, match="monolithic"):
-        introspect.assert_bucketed_gradient_sync(
-            shard_map(lambda g: allreduce_gradients(g, axis=axis),
-                      mesh4_hier, P(), P()), grads, min_buckets=2)
+    counts = introspect.collective_counts(
+        shard_map(lambda g: allreduce_gradients(g, axis=axis),
+                  mesh4_hier, P(), P()), _grads())
+    assert counts == {"reduce_scatter": ladders, "psum": ladders,
+                      "all_gather": ladders}
 
 
-def test_bucket_counter_increments_at_trace(mesh2, monkeypatch):
-    from horovod_tpu.utils import metrics
-
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "1024")
-
-    def total():
-        fam = metrics.REGISTRY.snapshot().get("hvd_grad_buckets_total", {})
-        return sum(v["value"] for v in fam.get("values", []))
-
-    before = total()
-    introspect.collective_counts(
-        shard_map(lambda g: allreduce_gradients(g, axis="data"),
-                  mesh2, P(), P()), _grads())
-    assert total() - before == 4
-
-
-def test_full_train_step_buckets_and_donates(mesh2, monkeypatch):
+def test_full_train_step_buckets_and_donates(mesh2):
     """End-to-end shape of the acceptance criterion: a jitted
-    DistributedOptimizer train step lowers with >= N independent bucket
-    collectives AND donated weight/optimizer buffers."""
+    DistributedOptimizer train step traces the framework's ``psum`` of
+    both gradient leaves AND lowers with donated weight/optimizer
+    buffers."""
     import optax
 
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "1024")
     tx = hvd_jax.DistributedOptimizer(optax.sgd(0.1))
     params = {"w": jnp.asarray(np.random.RandomState(1).randn(64, 17),
                                jnp.float32),
@@ -249,8 +159,8 @@ def test_full_train_step_buckets_and_donates(mesh2, monkeypatch):
                                       updates), opt_state
 
     sm = shard_map(step, mesh2, (P(), P(), P("data")), (P(), P()))
-    introspect.assert_bucketed_gradient_sync(
-        sm, params, opt_state, x, min_buckets=2)
+    assert introspect.collective_counts(
+        sm, params, opt_state, x)["psum"] >= 2
     donated = introspect.assert_donation_survives_lowering(
         sm, (0, 1), params, opt_state, x, min_donated=2)
     # params has 2 leaves; sgd momentum-less state may be empty, so
@@ -315,11 +225,8 @@ def test_donation_negative_case():
             step, (), jnp.ones(3), jnp.ones(3))
 
 
-def test_min_max_ops_keep_legacy_path(mesh2, monkeypatch):
+def test_min_max_ops_keep_legacy_path(mesh2):
     # Non-fusable reductions must not be concatenated across leaves.
-    from horovod_tpu.ops import collective_ops as C
-
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "1024")
     grads = {"a": jnp.ones((4,), jnp.float32),
              "b": jnp.full((4,), 2.0, jnp.float32)}
     counts = introspect.collective_counts(
@@ -329,7 +236,7 @@ def test_min_max_ops_keep_legacy_path(mesh2, monkeypatch):
     assert counts.get("pmax", 0) == 2
 
 
-# ---- ISSUE 27: a bucket's leaves are reduced where they lie ------------
+# ---- the flat route: the tree's leaves are reduced where they lie -----
 
 def _odd_grads(n):
     """Per-replica DIFFERENT gradients, stacked over a leading axis of
@@ -349,30 +256,40 @@ def _odd_grads(n):
     }
 
 
-@pytest.mark.parametrize("cap", ["64", "1024", str(DEFAULT_GRAD_BUCKET_BYTES)])
+@pytest.mark.parametrize("scale", [(1.0, 1.0), (0.5, 4.0)])
+@pytest.mark.parametrize("op", ["Average", "Sum"])
 @pytest.mark.parametrize("n", [2, 4])
-def test_in_place_equals_whole_tree_psum_bit_for_bit(n, cap, monkeypatch):
-    """The bucketed result IS the whole-tree ``psum / n``: the same
-    float sums of the same n values, element for element, and every
-    replica holds the same bits."""
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", cap)
+def test_sync_equals_whole_tree_psum_bit_for_bit(n, op, scale):
+    """The sync IS the whole-tree ``psum`` (``/ n`` for Average) with
+    the scales before and behind it: the same float sums of the same n
+    values, element for element, and every replica holds the same
+    bits."""
     mesh = Mesh(np.asarray(jax.devices()[:n]), ("data",))
     grads = _odd_grads(n)
+    pre, post = scale
 
     def squeeze(g):
         return jax.tree_util.tree_map(lambda x: x[0], g)
 
-    def bucketed(g):
-        out = allreduce_gradients(squeeze(g), axis="data")
+    def synced(g):
+        out = allreduce_gradients(
+            squeeze(g), op=getattr(C, op), axis="data",
+            prescale_factor=pre, postscale_factor=post)
         return jax.tree_util.tree_map(lambda x: x[None], out)
+
+    def times(x, factor):
+        return x if factor == 1.0 else x * jnp.asarray(factor, x.dtype)
 
     def whole_tree(g):
-        out = jax.tree_util.tree_map(
-            lambda x: x / jnp.asarray(n, x.dtype),
-            jax.lax.psum(squeeze(g), "data"))
-        return jax.tree_util.tree_map(lambda x: x[None], out)
+        summed = jax.lax.psum(jax.tree_util.tree_map(
+            lambda x: times(x, pre), squeeze(g)), "data")
+        if op == "Average":
+            summed = jax.tree_util.tree_map(
+                lambda x: x / jnp.asarray(n, x.dtype), summed)
+        return jax.tree_util.tree_map(
+            lambda x: times(x, post)[None], summed)
 
-    got = jax.jit(shard_map(bucketed, mesh, P("data"), P("data")))(grads)
+    got = jax.jit(shard_map(synced, mesh, P("data"), P("data")))(grads)
     want = jax.jit(shard_map(whole_tree, mesh, P("data"), P("data")))(grads)
     for k in grads:
         g, w = np.asarray(got[k]), np.asarray(want[k])
@@ -381,32 +298,85 @@ def test_in_place_equals_whole_tree_psum_bit_for_bit(n, cap, monkeypatch):
         assert np.array_equal(g, w), k
         for r in range(1, n):
             assert np.array_equal(g[0], g[r]), "replica %d leaf %s" % (r, k)
-        # And it is the average: float64 of the n values, to the dtype.
-        mean = np.asarray(grads[k], np.float64).mean(axis=0)
+        # And it is the mean (or the sum) of the n values, scaled:
+        # float64 of them, to the dtype.
+        ref = np.asarray(grads[k], np.float64).sum(axis=0) * pre * post
+        if op == "Average":
+            ref = ref / n
         np.testing.assert_allclose(
-            np.asarray(g[0], np.float64), mean,
-            rtol=2e-2 if g.dtype != np.float32 else 1e-6, atol=1e-6)
+            np.asarray(g[0], np.float64), ref,
+            rtol=4e-2 if g.dtype != np.float32 else 1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("cap,buckets", [("1024", 4), ("1073741824", 2)])
-def test_flat_route_copies_no_leaf(mesh2, monkeypatch, cap, buckets):
-    """No leaf is packed or unpacked on the flat route: the traced
-    program is one ``psum`` and one division a leaf, each under its
-    bucket's scope, and nothing else."""
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", cap)
-    grads = _grads()
-    fn = shard_map(lambda g: allreduce_gradients(g, axis="data"),
-                   mesh2, P(), P())
-    assert _primitive_counts(fn, grads) == {"psum": len(grads),
-                                            "div": len(grads)}
-    scopes = _bucket_scopes(fn, grads)
-    assert len(scopes) == buckets
-    assert sum(scopes.values()) == len(grads)
+def _trees():
+    rng = np.random.RandomState(28)
+    return {
+        "mixed-dtype": _grads(),
+        "one-leaf": {"w": jnp.asarray(rng.randn(33, 5), jnp.float32)},
+        "40-leaf": {"l%02d" % i: jnp.asarray(
+            rng.randn(1 + i % 7, 3 + i % 5),
+            jnp.bfloat16 if i % 3 == 0 else jnp.float32)
+            for i in range(40)},
+    }
+
+
+@pytest.mark.parametrize("tree", ["mixed-dtype", "one-leaf", "40-leaf"])
+def test_flat_route_is_one_psum_a_leaf(mesh2, tree):
+    """No leaf is packed, unpacked or converted on the flat route: the
+    traced program is one ``psum`` a leaf, one division a leaf for
+    Average and none for Sum, every ``psum`` directly under
+    ``hvd_sync``, and nothing else."""
+    grads = _trees()[tree]
+
+    def fn(op):
+        return shard_map(
+            lambda g: allreduce_gradients(g, op=op, axis="data"),
+            mesh2, P(), P())
+
+    assert _primitive_counts(fn(C.Average), grads) == {
+        "psum": len(grads), "div": len(grads)}
+    assert _primitive_counts(fn(C.Sum), grads) == {"psum": len(grads)}
+    psums = _psums(fn(C.Average), grads)
+    for eqn in psums:
+        assert str(eqn.source_info.name_stack) == "hvd_sync"
     # Each psum takes a gradient leaf as it came and gives its shape back.
     shapes = sorted((e.invars[0].aval.shape, str(e.invars[0].aval.dtype))
-                    for e in introspect.equations(jax.make_jaxpr(fn)(grads).jaxpr)
-                    if e.primitive.name == "psum")
+                    for e in psums)
     assert shapes == sorted((v.shape, str(v.dtype)) for v in grads.values())
+
+
+def test_sync_issues_leaves_in_flatten_order(mesh2):
+    """The group is handed over in the tree's own order, whatever the
+    dtypes (nothing regroups the leaves by dtype or size any more), and
+    the tree comes back in that order."""
+    grads = {"l%d" % i: jnp.full((i + 1,), float(i),
+                                 jnp.bfloat16 if i % 2 else jnp.float32)
+             for i in range(6)}
+    fn = shard_map(lambda g: allreduce_gradients(g, axis="data"),
+                   mesh2, P(), P())
+    assert [e.invars[0].aval.shape for e in _psums(fn, grads)] == \
+        [(i + 1,) for i in range(6)]
+    out = jax.jit(fn)(grads)
+    for k in grads:
+        assert out[k].dtype == grads[k].dtype
+        assert np.array_equal(np.asarray(out[k]), np.asarray(grads[k])), k
+
+
+def test_env_cannot_change_the_traced_sync(mesh2, monkeypatch):
+    """The bucket option is gone: its name in the environment changes
+    nothing that is traced, and the registry does not know it."""
+    from horovod_tpu.common import knobs
+
+    fn = shard_map(lambda g: allreduce_gradients(g, axis="data"),
+                   mesh2, P(), P())
+    monkeypatch.delenv("HVD_GRAD_BUCKET_BYTES", raising=False)
+    texts = {str(jax.make_jaxpr(fn)(_grads()))}
+    for value in ("0", "64"):
+        monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", value)
+        texts.add(str(jax.make_jaxpr(fn)(_grads())))
+    assert len(texts) == 1
+    assert "HVD_GRAD_BUCKET_BYTES" not in knobs.REGISTRY
+    assert "grad_bucket_bytes" not in knobs.TUNABLE
 
 
 @pytest.mark.parametrize("scale,expected", [
@@ -414,12 +384,9 @@ def test_flat_route_copies_no_leaf(mesh2, monkeypatch, cap, buckets):
     ((0.5, 4.0), {"mul": 4}),
 ])
 @pytest.mark.parametrize("op", ["Average", "Sum"])
-def test_one_chip_axis_traces_nothing(monkeypatch, op, scale, expected):
+def test_one_chip_axis_traces_nothing(op, scale, expected):
     """Axis size 1: the leaves come back as they came (times prescale x
     postscale when that is not 1.0): no collective, no copy, no scope."""
-    from horovod_tpu.ops import collective_ops as C
-
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "1024")
     mesh1 = Mesh(np.asarray(jax.devices()[:1]), ("data",))
     grads = _grads()
     fn = shard_map(
@@ -436,7 +403,7 @@ def test_one_chip_axis_traces_nothing(monkeypatch, op, scale, expected):
         assert np.array_equal(np.asarray(out[k]), np.asarray(want)), k
 
 
-def test_one_chip_optimizer_step_holds_no_sync(monkeypatch):
+def test_one_chip_optimizer_step_holds_no_sync():
     """A DistributedOptimizer step over a one-device mesh lowers with no
     ``hvd_sync`` location and no all-reduce at all."""
     import optax
@@ -465,22 +432,21 @@ def test_one_chip_optimizer_step_holds_no_sync(monkeypatch):
 def test_hierarchical_route_still_packs_and_pads(mesh4_hier, monkeypatch):
     """The (dcn, ici) ladder keeps its flat buffer: a ``psum_scatter``
     needs ONE array divisible by the ici size."""
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "1024")
+    monkeypatch.setattr(hierarchical, "PACK_BYTES", 1024)
     monkeypatch.setenv("HOROVOD_HIERARCHICAL_ALLREDUCE", "1")
-    grads = _grads()  # b1 has 7 elements, w2 501: both need padding
+    grads = _grads()  # b1 + w3 hold 4103 elements, w2 501: both padded
     axis = ("data_dcn", "data_ici")
     fn = shard_map(lambda g: allreduce_gradients(g, axis=axis),
                    mesh4_hier, P(), P())
     prims = _primitive_counts(fn, grads)
     assert prims["pad"] == 2
     assert prims["reshape"] >= len(grads)
-    assert prims["reduce_scatter"] == prims["all_gather"] == 4
+    assert prims["reduce_scatter"] == prims["all_gather"] == 3
 
 
 @pytest.mark.parametrize("route", ["in_place", "packed", "skipped"])
 def test_leaf_counter_counts_each_leaf_once(route, mesh2, mesh4_hier,
                                             monkeypatch):
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", "1024")
     mesh, axis = mesh2, "data"
     if route == "packed":
         monkeypatch.setenv("HOROVOD_HIERARCHICAL_ALLREDUCE", "1")

@@ -25,10 +25,9 @@ CFG = TransformerConfig(vocab_size=256, d_model=32, n_heads=2,
                         dtype=jnp.float32, attention="flash")
 
 
-def _step_and_args(monkeypatch):
+def _step_and_args():
     """The tiny transformer's data-parallel AdamW step over two virtual
-    devices, several buckets to a step, with concrete arguments."""
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", str(16 * 1024))
+    devices, with concrete arguments."""
     mesh = Mesh(np.asarray(jax.devices()[:2]), ("data",))
     model = Transformer(CFG)
     tx = hvd_jax.DistributedOptimizer(optax.adamw(1e-2))
@@ -69,21 +68,18 @@ def _equations(jaxpr, outer=""):
                     yield from _equations(inner, stack)
 
 
-def test_compiled_step_names_sync_update_head_and_kernels(monkeypatch):
-    sharded, _, args = _step_and_args(monkeypatch)
+def test_compiled_step_names_sync_update_head_and_kernels():
+    sharded, _, args = _step_and_args()
     text = sharded.lower(*args).compile().as_text()
     scopes = introspect.instruction_scopes(text)
 
-    # XLA may combine the buckets' psums; whatever it leaves sits in a
-    # bucket's scope, and every bucket's pack and unpack in its own.
+    # XLA may combine the leaves' psums; whatever it leaves sits
+    # directly under hvd_sync.
     collectives = re.findall(r"^\s*%(\S+) = .* all-reduce(?:-start)?\(",
                              text, re.M)
     assert collectives
-    in_bucket = re.compile(r"/shard_map/hvd_sync/bucket_(\d+)_float32/")
-    assert all(in_bucket.search(scopes[c]) for c in collectives)
-    buckets = {m.group(1) for m in map(in_bucket.search, scopes.values())
-               if m}
-    assert len(buckets) >= 3, buckets
+    assert all("/shard_map/hvd_sync/psum" in scopes[c]
+               for c in collectives), [scopes[c] for c in collectives]
 
     # Every equation of the inner optimizer is traced under hvd_update
     # (a compiled fusion shows its root's name only, so count in the
@@ -181,13 +177,13 @@ def test_instructions_without_metadata_inherit_a_scope():
 
 
 def test_scopes_change_no_arithmetic_and_no_state(monkeypatch):
-    sharded, tx, args = _step_and_args(monkeypatch)
+    sharded, tx, args = _step_and_args()
     scoped = sharded(*args)
     assert "hvd_update" in sharded.lower(*args).as_text(debug_info=True)
 
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    bare, _, _ = _step_and_args(monkeypatch)
+    bare, _, _ = _step_and_args()
     assert "hvd_update" not in bare.lower(*args).as_text(debug_info=True)
     for a, b in zip(jax.tree.leaves(scoped), jax.tree.leaves(bare(*args))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

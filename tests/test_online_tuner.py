@@ -24,8 +24,16 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # Every apply() mirrors the value into the backing env knob — exactly
 # what a later test would then read back as its starting point. Scrub
 # the mirrors (and the tuner's own knobs) around every test.
+# The tests' own specimen of a live-unsafe, env-bound tunable (a
+# trace-time read: 0 to 64 MiB in 1 MiB steps, default 4 MiB), put into
+# the schema by the ``specimen`` fixture, so that no option's removal
+# touches these tests.
+SPECIMEN = TunableKnob("specimen_bytes", 0.0, float(64 << 20),
+                       float(1 << 20), "env", "TUNER_TEST_SPECIMEN_BYTES",
+                       float(4 << 20), False, "trace-time read")
+
 _TUNER_ENVS = sorted({k.env for k in TUNABLE.values() if k.env} | {
-    "HVD_TUNE", "HVD_TUNE_FREEZE", "HVD_TUNE_JOURNAL_DIR",
+    SPECIMEN.env, "HVD_TUNE", "HVD_TUNE_FREEZE", "HVD_TUNE_JOURNAL_DIR",
     "HVD_TUNE_WINDOW_SEC", "HVD_TUNE_GUARD_PCT"})
 
 
@@ -37,6 +45,12 @@ def _clean_tuner_env():
     for n in _TUNER_ENVS:
         os.environ.pop(n, None)
     os.environ.update(saved)
+
+
+@pytest.fixture
+def specimen(monkeypatch):
+    monkeypatch.setitem(TUNABLE, SPECIMEN.name, SPECIMEN)
+    return SPECIMEN
 
 
 class Sim:
@@ -107,8 +121,7 @@ def test_schema_covers_required_surface():
     knob surface plus the reference pair."""
     required = {"fusion_threshold_mb", "cycle_time_ms",
                 "ring_chunk_bytes", "socket_buf_bytes",
-                "grad_bucket_bytes", "serve_max_batch",
-                "serve_deadline_ms"}
+                "serve_max_batch", "serve_deadline_ms"}
     assert required <= set(TUNABLE)
     for knob in TUNABLE.values():
         assert knob.lo <= knob.hi
@@ -118,7 +131,6 @@ def test_schema_covers_required_surface():
 def test_schema_trace_time_knobs_are_not_live_safe():
     """Trace-time reads lower rank-divergent programs: the schema must
     say so, and the default training set must exclude them."""
-    assert not TUNABLE["grad_bucket_bytes"].live_safe
     assert not TUNABLE["flash_block_q"].live_safe
     for name in ot.TRAINING_KNOBS:
         assert TUNABLE[name].live_safe
@@ -432,7 +444,7 @@ def test_start_online_tuner_off_and_all_frozen(monkeypatch):
     ot.stop_online_tuner()
 
 
-def test_live_unsafe_knobs_dropped_in_multi_rank_world(monkeypatch):
+def test_live_unsafe_knobs_dropped_in_multi_rank_world(monkeypatch, specimen):
     """Runtime half of the spmd live_safe contract (the static half is
     tools/analysis/check_spmd.py): if the composed knob set ever grows
     a live_safe=False entry — a trace-time read whose per-rank search
@@ -444,7 +456,7 @@ def test_live_unsafe_knobs_dropped_in_multi_rank_world(monkeypatch):
     monkeypatch.setattr(basics, "is_initialized", lambda: True)
     monkeypatch.setattr(basics, "size", lambda: 2)
     monkeypatch.setattr(ot, "TRAINING_KNOBS",
-                        ("ring_chunk_bytes", "grad_bucket_bytes"))
+                        ("ring_chunk_bytes", "specimen_bytes"))
     ot.stop_online_tuner()
     try:
         tuner = ot.start_online_tuner(role="training")
@@ -454,12 +466,12 @@ def test_live_unsafe_knobs_dropped_in_multi_rank_world(monkeypatch):
     finally:
         ot.stop_online_tuner()
     # Alone in its world the same set stays searchable (single-process
-    # flash/bucket tuning is legitimate — docs/autotune.md).
+    # flash-tile tuning is legitimate — docs/autotune.md).
     monkeypatch.setattr(basics, "size", lambda: 1)
     try:
         tuner = ot.start_online_tuner(role="training")
         assert {b.name for b in tuner.bindings} == \
-            {"ring_chunk_bytes", "grad_bucket_bytes"}
+            {"ring_chunk_bytes", "specimen_bytes"}
     finally:
         ot.stop_online_tuner()
 
@@ -592,7 +604,7 @@ def test_tuner_moves_ring_chunk_live_np2(tmp_path):
     assert procs.stdout.count("TUNER_E2E_OK") == 2, procs.stdout
 
 
-def test_live_unsafe_apply_refused_after_world_grows(monkeypatch):
+def test_live_unsafe_apply_refused_after_world_grows(monkeypatch, specimen):
     """Review fix: the start-time live_safe filter samples world size
     once, but an ELASTIC world can grow after the tuner thread is
     running (size 1 at start, peers join via reinit). The apply path
@@ -602,21 +614,21 @@ def test_live_unsafe_apply_refused_after_world_grows(monkeypatch):
     from horovod_tpu.common import basics
     from horovod_tpu.common.knobs import TUNABLE
 
-    monkeypatch.delenv("HVD_GRAD_BUCKET_BYTES", raising=False)
-    b = ot.KnobBinding(TUNABLE["grad_bucket_bytes"])
+    monkeypatch.delenv("TUNER_TEST_SPECIMEN_BYTES", raising=False)
+    b = ot.KnobBinding(TUNABLE["specimen_bytes"])
     # Alone in its world: the apply lands and mirrors to env.
     monkeypatch.setattr(basics, "is_initialized", lambda: True)
     monkeypatch.setattr(basics, "size", lambda: 1)
     applied = b.apply(float(8 << 20))
     assert applied == float(8 << 20)
-    assert os.environ["HVD_GRAD_BUCKET_BYTES"] == str(8 << 20)
+    assert os.environ["TUNER_TEST_SPECIMEN_BYTES"] == str(8 << 20)
     # World grew: the apply is refused, env mirror untouched, and the
     # returned value reports the LIVE state so tuner bookkeeping
     # stays coherent.
     monkeypatch.setattr(basics, "size", lambda: 2)
     refused = b.apply(float(16 << 20))
     assert refused == float(8 << 20)
-    assert os.environ["HVD_GRAD_BUCKET_BYTES"] == str(8 << 20)
+    assert os.environ["TUNER_TEST_SPECIMEN_BYTES"] == str(8 << 20)
     # The guardrail's REVERT is exempt (restore=True): blocking it
     # would strand the knob at the mid-search value the guard just
     # rejected. In the shared world it lands the LAUNCH anchor —
@@ -624,16 +636,17 @@ def test_live_unsafe_apply_refused_after_world_grows(monkeypatch):
     # (what an absent mirror means) is reported.
     restored = b.apply(float(4 << 20), restore=True)
     assert restored == float(4 << 20)  # launch anchor == default
-    assert "HVD_GRAD_BUCKET_BYTES" not in os.environ
+    assert "TUNER_TEST_SPECIMEN_BYTES" not in os.environ
     # live_safe=True knobs are untouched by the gate.
     monkeypatch.delenv("HVD_RING_CHUNK_BYTES", raising=False)
     safe = ot.KnobBinding(TUNABLE["ring_chunk_bytes"])
     assert safe.apply(float(2 << 20)) == float(2 << 20)
-    monkeypatch.delenv("HVD_GRAD_BUCKET_BYTES", raising=False)
+    monkeypatch.delenv("TUNER_TEST_SPECIMEN_BYTES", raising=False)
     monkeypatch.delenv("HVD_RING_CHUNK_BYTES", raising=False)
 
 
-def test_live_unsafe_apply_gate_is_atomic_with_the_write(monkeypatch):
+def test_live_unsafe_apply_gate_is_atomic_with_the_write(
+        monkeypatch, specimen):
     """Review fix (TOCTOU): the live_safe gate check and the env
     write run as one atomic unit under ot._apply_lock — the same lock
     every restore takes. A search-thread apply that raced an elastic
@@ -647,11 +660,11 @@ def test_live_unsafe_apply_gate_is_atomic_with_the_write(monkeypatch):
     from horovod_tpu.common import basics
     from horovod_tpu.common.knobs import TUNABLE
 
-    monkeypatch.delenv("HVD_GRAD_BUCKET_BYTES", raising=False)
+    monkeypatch.delenv("TUNER_TEST_SPECIMEN_BYTES", raising=False)
     monkeypatch.setattr(basics, "is_initialized", lambda: True)
     size = {"v": 1}
     monkeypatch.setattr(basics, "size", lambda: size["v"])
-    b = ot.KnobBinding(TUNABLE["grad_bucket_bytes"])
+    b = ot.KnobBinding(TUNABLE["specimen_bytes"])
 
     results = []
     t = threading.Thread(
@@ -665,11 +678,11 @@ def test_live_unsafe_apply_gate_is_atomic_with_the_write(monkeypatch):
     assert not t.is_alive()
     # The parked apply re-read the gate under the lock and refused:
     # no env write, live (default) value returned.
-    assert "HVD_GRAD_BUCKET_BYTES" not in os.environ
-    assert results == [TUNABLE["grad_bucket_bytes"].default]
+    assert "TUNER_TEST_SPECIMEN_BYTES" not in os.environ
+    assert results == [TUNABLE["specimen_bytes"].default]
 
 
-def test_shared_world_revert_clamps_to_launch_anchor(monkeypatch):
+def test_shared_world_revert_clamps_to_launch_anchor(monkeypatch, specimen):
     """Review fix (revert-side TOCTOU): restore=True bypasses the
     live_safe gate, and the revert TARGET (the incumbent) is computed
     outside _apply_lock — so a guardrail revert racing an elastic
@@ -681,8 +694,8 @@ def test_shared_world_revert_clamps_to_launch_anchor(monkeypatch):
     from horovod_tpu.common import basics
     from horovod_tpu.common.knobs import TUNABLE
 
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", str(6 << 20))
-    b = ot.KnobBinding(TUNABLE["grad_bucket_bytes"])  # launch = 6 MiB
+    monkeypatch.setenv("TUNER_TEST_SPECIMEN_BYTES", str(6 << 20))
+    b = ot.KnobBinding(TUNABLE["specimen_bytes"])  # launch = 6 MiB
     monkeypatch.setattr(basics, "is_initialized", lambda: True)
     size = {"v": 1}
     monkeypatch.setattr(basics, "size", lambda: size["v"])
@@ -692,14 +705,14 @@ def test_shared_world_revert_clamps_to_launch_anchor(monkeypatch):
     # land the launch anchor instead.
     size["v"] = 2
     assert b.apply(float(16 << 20), restore=True) == float(6 << 20)
-    assert os.environ["HVD_GRAD_BUCKET_BYTES"] == str(6 << 20)
+    assert os.environ["TUNER_TEST_SPECIMEN_BYTES"] == str(6 << 20)
     # Alone again (shrunk world): restores keep the caller's target —
     # the incumbent revert is the correct single-process behavior.
     size["v"] = 1
     assert b.apply(float(8 << 20), restore=True) == float(8 << 20)
 
 
-def test_live_unsafe_binding_pruned_when_world_grows(monkeypatch):
+def test_live_unsafe_binding_pruned_when_world_grows(monkeypatch, specimen):
     """Review fix: when an elastic world grows mid-search, a
     live_safe=False binding must be dropped from the searched set
     ONCE (optimizer box rebuilt over the survivors, measured samples
@@ -708,11 +721,11 @@ def test_live_unsafe_binding_pruned_when_world_grows(monkeypatch):
     from horovod_tpu.common import basics
 
     sim = Sim(lambda v: 100.0)
-    tuner = _make_tuner(sim, ["ring_chunk_bytes", "grad_bucket_bytes"],
+    tuner = _make_tuner(sim, ["ring_chunk_bytes", "specimen_bytes"],
                         max_samples=3)
     # Alone in its world: both knobs searched.
     assert {b.name for b in tuner.bindings} == \
-        {"ring_chunk_bytes", "grad_bucket_bytes"}
+        {"ring_chunk_bytes", "specimen_bytes"}
     rec = tuner.step()
     assert rec is not None
     # The world grows: the next round prunes to the safe survivor and
@@ -725,50 +738,50 @@ def test_live_unsafe_binding_pruned_when_world_grows(monkeypatch):
     # The prune restored the dropped knob to its START-TIME value,
     # KEPT it visible in state() (bench JSON reports what is live),
     # and journaled the decision.
-    assert sim.values["grad_bucket_bytes"] == \
-        TUNABLE["grad_bucket_bytes"].default
-    assert tuner.state()["values"]["grad_bucket_bytes"] == \
-        TUNABLE["grad_bucket_bytes"].default
+    assert sim.values["specimen_bytes"] == \
+        TUNABLE["specimen_bytes"].default
+    assert tuner.state()["values"]["specimen_bytes"] == \
+        TUNABLE["specimen_bytes"].default
     assert any(r["type"] == "tune_prune" and
-               r["dropped"] == ["grad_bucket_bytes"]
+               r["dropped"] == ["specimen_bytes"]
                for r in tuner.trajectory())
     # With ONLY unsafe knobs, the prune freezes the search outright —
     # at the restored values, with a journaled freeze record.
     sim2 = Sim(lambda v: 100.0)
-    t2 = _make_tuner(sim2, ["grad_bucket_bytes"], max_samples=3)
+    t2 = _make_tuner(sim2, ["specimen_bytes"], max_samples=3)
     assert t2.step() is None and t2.state()["frozen"]
     [frz] = [r for r in t2.trajectory() if r["type"] == "tune_freeze"]
-    assert frz["pruned"] == ["grad_bucket_bytes"]
+    assert frz["pruned"] == ["specimen_bytes"]
     assert t2.state()["values"] == frz["values"]
 
 
 def test_pruned_knob_restores_job_env_value_not_schema_default(
-        monkeypatch):
+        monkeypatch, specimen):
     """Review fix: a fleet launched with an explicit env value for a
     live-unsafe knob must be restored to THAT value on prune — fresh
     elastic peers inherit the job env, so the launch value (not the
     schema default) is the rank-uniform anchor."""
     from horovod_tpu.common import basics
 
-    monkeypatch.setenv("HVD_GRAD_BUCKET_BYTES", str(8 << 20))
+    monkeypatch.setenv("TUNER_TEST_SPECIMEN_BYTES", str(8 << 20))
     sim = Sim(lambda v: 100.0)
-    tuner = _make_tuner(sim, ["ring_chunk_bytes", "grad_bucket_bytes"],
+    tuner = _make_tuner(sim, ["ring_chunk_bytes", "specimen_bytes"],
                         max_samples=3)
-    assert tuner.state()["values"]["grad_bucket_bytes"] == \
+    assert tuner.state()["values"]["specimen_bytes"] == \
         float(8 << 20)
     # A mid-search move lands while the process is alone in its world.
-    [b] = [b for b in tuner.bindings if b.name == "grad_bucket_bytes"]
+    [b] = [b for b in tuner.bindings if b.name == "specimen_bytes"]
     b.apply(float(16 << 20))
-    assert os.environ["HVD_GRAD_BUCKET_BYTES"] == str(16 << 20)
+    assert os.environ["TUNER_TEST_SPECIMEN_BYTES"] == str(16 << 20)
     # The world grows: prune restores the LAUNCH value, not 4 MiB.
     monkeypatch.setattr(basics, "is_initialized", lambda: True)
     monkeypatch.setattr(basics, "size", lambda: 2)
     assert tuner.step() is not None
-    assert os.environ["HVD_GRAD_BUCKET_BYTES"] == str(8 << 20)
+    assert os.environ["TUNER_TEST_SPECIMEN_BYTES"] == str(8 << 20)
 
 
 def test_journal_replays_across_live_safe_recomposition(
-        tmp_path, monkeypatch):
+        tmp_path, monkeypatch, specimen):
     """Review fix: a journal written by the full composed knob set
     (size-1 world) must replay after a restart whose live_safe drop
     narrowed the SEARCHED set — the fence hashes the composition, not
@@ -778,7 +791,7 @@ def test_journal_replays_across_live_safe_recomposition(
 
     jp = str(tmp_path / "tuner_journal.jsonl")
     sim = Sim(lambda v: 100.0)
-    both = ["ring_chunk_bytes", "grad_bucket_bytes"]
+    both = ["ring_chunk_bytes", "specimen_bytes"]
     t1 = _make_tuner(sim, both, journal_path=jp, max_samples=2)
     t1._attach_journal()
     t1.replay()
@@ -801,7 +814,7 @@ def test_journal_replays_across_live_safe_recomposition(
 
 
 def test_frozen_live_unsafe_value_restored_on_world_change(
-        monkeypatch):
+        monkeypatch, specimen):
     """Review fix: freeze is the terminal state of every search and
     exits the tuner thread, so a live-unsafe value frozen while the
     process was alone would outlive any in-loop protection. The
@@ -809,24 +822,24 @@ def test_frozen_live_unsafe_value_restored_on_world_change(
     restore the launch value even on a frozen tuner."""
     from horovod_tpu.common import basics
 
-    monkeypatch.delenv("HVD_GRAD_BUCKET_BYTES", raising=False)
-    # Rate rewards bigger buckets, so the size-1 search freezes at a
+    monkeypatch.delenv("TUNER_TEST_SPECIMEN_BYTES", raising=False)
+    # Rate rewards bigger values, so the size-1 search freezes at a
     # NON-default value.
-    sim = Sim(lambda v: 1.0 + v.get("grad_bucket_bytes", 0.0))
-    tuner = _make_tuner(sim, ["grad_bucket_bytes"], max_samples=3)
+    sim = Sim(lambda v: 1.0 + v.get("specimen_bytes", 0.0))
+    tuner = _make_tuner(sim, ["specimen_bytes"], max_samples=3)
     _drive(tuner)
     assert tuner.state()["frozen"]
-    frozen_val = sim.values["grad_bucket_bytes"]
-    assert frozen_val != TUNABLE["grad_bucket_bytes"].default
+    frozen_val = sim.values["specimen_bytes"]
+    assert frozen_val != TUNABLE["specimen_bytes"].default
     # The world grows; the elastic worker's reinit hook fires.
     monkeypatch.setattr(basics, "is_initialized", lambda: True)
     monkeypatch.setattr(basics, "size", lambda: 2)
     monkeypatch.setattr(ot, "_global_tuner", tuner)
     ot.on_world_change()
-    assert sim.values["grad_bucket_bytes"] == \
-        TUNABLE["grad_bucket_bytes"].default
-    assert tuner.state()["values"]["grad_bucket_bytes"] == \
-        TUNABLE["grad_bucket_bytes"].default
+    assert sim.values["specimen_bytes"] == \
+        TUNABLE["specimen_bytes"].default
+    assert tuner.state()["values"]["specimen_bytes"] == \
+        TUNABLE["specimen_bytes"].default
     # Recorded as a prune (the search was already frozen), and a
     # second world change is a no-op.
     assert any(r["type"] == "tune_prune" for r in tuner.trajectory())
@@ -837,7 +850,8 @@ def test_frozen_live_unsafe_value_restored_on_world_change(
     assert ot.on_world_change() is None  # no tuner: no-op
 
 
-def test_live_search_world_change_restores_values_inline(monkeypatch):
+def test_live_search_world_change_restores_values_inline(
+        monkeypatch, specimen):
     """Review fix: with the search thread LIVE, on_world_change must
     restore live-unsafe VALUES immediately (the worker retraces right
     after the reset) while leaving bindings/_bo to the loop's own
@@ -845,11 +859,11 @@ def test_live_search_world_change_restores_values_inline(monkeypatch):
     concurrently built proposal."""
     from horovod_tpu.common import basics
 
-    monkeypatch.delenv("HVD_GRAD_BUCKET_BYTES", raising=False)
+    monkeypatch.delenv("TUNER_TEST_SPECIMEN_BYTES", raising=False)
     sim = Sim(lambda v: 100.0)
-    tuner = _make_tuner(sim, ["ring_chunk_bytes", "grad_bucket_bytes"],
+    tuner = _make_tuner(sim, ["ring_chunk_bytes", "specimen_bytes"],
                         max_samples=3)
-    [b] = [b for b in tuner.bindings if b.name == "grad_bucket_bytes"]
+    [b] = [b for b in tuner.bindings if b.name == "specimen_bytes"]
     b.apply(float(16 << 20))  # legal mid-search move while alone
 
     class _FakeThread:
@@ -863,12 +877,12 @@ def test_live_search_world_change_restores_values_inline(monkeypatch):
     tuner._thread = _FakeThread()
     ot.on_world_change()
     # Values restored to launch state NOW...
-    assert sim.values["grad_bucket_bytes"] == \
-        TUNABLE["grad_bucket_bytes"].default
+    assert sim.values["specimen_bytes"] == \
+        TUNABLE["specimen_bytes"].default
     assert any(r["type"] == "tune_restore" for r in tuner.trajectory())
     # ...but the structural drop is left to the search thread.
     assert {b.name for b in tuner.bindings} == \
-        {"ring_chunk_bytes", "grad_bucket_bytes"}
+        {"ring_chunk_bytes", "specimen_bytes"}
     tuner._thread = None
     monkeypatch.setattr(ot, "_global_tuner", None)
 

@@ -61,12 +61,16 @@ def test_stalled_cache_entry_invalidation():
 def test_abort_cascade_execution_phase():
     """Rank 2 dies with a 4 MB allreduce in flight; survivors error out
     promptly through the connection-abort cascade instead of blocking in
-    the ring."""
+    the ring. A process that dies with unread bytes in its socket sends
+    RST where an idle one sends FIN, and a reset link is first redialled
+    for ``HVD_WIRE_RECONNECT_SEC`` (docs/wire.md#reconnect) before the
+    typed abort: the window holds that budget, given here, and slack."""
     codes, outputs = _launch(
         3, _WORKER,
         extra_env={
             "STALL_MODE": "execution",
             "STALL_EXPECT_WINDOW": "30",
+            "HVD_WIRE_RECONNECT_SEC": "5",
         },
         timeout=120)
     for r in (0, 1):

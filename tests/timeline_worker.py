@@ -66,6 +66,11 @@ def main():
     out_dir = os.environ["HVD_TL_DIR"]
     path = os.path.join(out_dir, "tl_rank%d.json" % r)
     hvd.start_timeline(path)
+    # Every rank's writer is on before any rank submits ``tlh.x``: a
+    # request that reaches the coordinator before ITS timeline started
+    # leaves no rank-ready instant there (a fast rank 1 on a loaded
+    # machine).
+    hvd.barrier()
     hvd.allreduce(np.ones(16, np.float32), name="tlh.x", op=hvd.Sum)
     outs = hvd.grouped_allreduce(
         [np.ones(8, np.float32), np.full(8, 2.0, np.float32)],

@@ -506,13 +506,25 @@ def error_propagation(r, n):
                        else torch.float64)
         hvd.allreduce(t, name="err.dtype", op=hvd.Sum)
     # Duplicate name: second submission errors, the first completes.
-    h1 = hvd.allreduce_async(torch.ones(4), name="err.dup", op=hvd.Sum)
-    with _expect_internal_error("duplicate"):
-        h2 = hvd.allreduce_async(torch.ones(4), name="err.dup",
-                                 op=hvd.Sum)
-        hvd.synchronize(h2)
-    np.testing.assert_allclose(hvd.synchronize(h1).numpy(),
-                               np.full(4, float(n)))
+    # The first completes once EVERY rank has submitted it, so one rank
+    # (``holder``) submits last, behind a barrier the others reach after
+    # their duplicate was refused: no rank's first submission can be
+    # done before its second, however loaded the machine is (a rank
+    # whose first had completed would have its second ACCEPTED and wait
+    # for peers that refused theirs). Each rank is held once.
+    for holder in (n - 1, 0):
+        name = "err.dup.%d" % holder
+        if r == holder:
+            hvd.barrier()
+        h1 = hvd.allreduce_async(torch.ones(4), name=name, op=hvd.Sum)
+        if r != holder:
+            with _expect_internal_error("duplicate"):
+                h2 = hvd.allreduce_async(torch.ones(4), name=name,
+                                         op=hvd.Sum)
+                hvd.synchronize(h2)
+            hvd.barrier()
+        np.testing.assert_allclose(hvd.synchronize(h1).numpy(),
+                                   np.full(4, float(n)))
     # Session still healthy.
     out = hvd.allreduce(torch.ones(2), name="err.after", op=hvd.Sum)
     np.testing.assert_allclose(out.numpy(), np.full(2, float(n)))

@@ -135,19 +135,30 @@ def main():
 
     # --- grouped (fused) submission: the segment-list wire path ----------
     # Ragged sizes so segment boundaries never line up with chunk
-    # boundaries; all submitted before one cycle, so they fuse.
+    # boundaries; one explicit group, as ``NativeBackend.
+    # allreduce_async`` submits it, so the members wait for each other
+    # in the core's group table. WHICH tensors share a fused buffer is
+    # still decided cycle by cycle from what has arrived (a loaded
+    # machine ticks between two submits), and a lossy codec's blocks
+    # follow the buffer's layout: under a codec these bytes are held to
+    # the tolerance, not to the digest.
+    import zlib
+
     sizes = [129, 1, 2047, 513]
     for round_ in range(3):
         group = _Group(len(sizes))
         arrs = [np.full(sz, float(i + 1 + r + round_), np.float32)
                 for i, sz in enumerate(sizes)]
+        names = ["eq.fused.%d.%d" % (round_, i) for i in range(len(sizes))]
+        group_id = zlib.crc32("|".join(names).encode())
         for i, a in enumerate(arrs):
-            session.submit(OP_ALLREDUCE, "eq.fused.%d.%d" % (round_, i), a,
-                           group=group, index=i, op=OP_SUM)
+            session.submit(OP_ALLREDUCE, names[i], a, group=group, index=i,
+                           op=OP_SUM, group_id=group_id)
         outs = group.future.result(timeout=120)
         for i, out in enumerate(outs):
             expect = sum(float(i + 1 + k + round_) for k in range(n))
-            digest.update(np.asarray(out).tobytes())
+            if CODEC == "none":
+                digest.update(np.asarray(out).tobytes())
             if CODEC != "none":
                 np.testing.assert_allclose(
                     out, np.full(sizes[i], expect),

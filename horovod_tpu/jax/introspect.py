@@ -41,9 +41,16 @@ SCOPE_LOGITS = "logits"          # Transformer: output projection
 SCOPE_ROPE = "rope"              # SelfAttention: rotary positions on q, k
 # The expert layer (parallel/moe.py), inside the ``moe`` module's scope.
 SCOPE_MOE_ROUTER = "hvd_moe_router"      # logits, softmax, top-k, aux losses
-SCOPE_MOE_DISPATCH = "hvd_moe_dispatch"  # sort, group sizes, gather of rows
-SCOPE_MOE_EXPERTS = "hvd_moe_experts"    # the grouped matmuls and the gate
-SCOPE_MOE_COMBINE = "hvd_moe_combine"    # weighting and the sum per token
+# The sorts (the gates ride one into row order); rows gathered from the
+# (T, M) tokens; backward a gather of rows and the sum over each
+# token's k.
+SCOPE_MOE_DISPATCH = "hvd_moe_dispatch"
+# The grouped matmuls and their activation, which the ROUTER's gate
+# multiplies (its gradient is that fusion's reduction over F).
+SCOPE_MOE_EXPERTS = "hvd_moe_experts"
+# A gather of rows and the plain sum over each token's k; backward one
+# gather from the (T, M) cotangent. No weighting here.
+SCOPE_MOE_COMBINE = "hvd_moe_combine"
 # ``name=`` of the three ``pallas_call``s (the Mosaic calls' op_name).
 KERNEL_FLASH_FWD = "hvd_flash_fwd"
 KERNEL_FLASH_DKV = "hvd_flash_dkv"

@@ -3,11 +3,19 @@
 - ``MoeMlp`` — what ``models.Transformer`` calls: top-k routing that
   drops no token. Softmax over the router logits in float32, the k most
   probable experts of each token, a stable sort of the T x k
-  (token, expert) pairs by expert, one gather of rows, grouped matmuls
-  over the ragged groups, a gate-weighted sum back per token. No
-  ``(T, E, C)`` tensor and no capacity: an expert takes whatever the
-  router sends it. GPT-2's block gets GELU experts and one expert a
-  token, OLMoE's SwiGLU experts and 8 of 64 (``BlockSpec``).
+  (token, expert) pairs by expert, then everything in TOKEN-MAJOR form:
+  sorted row r is gathered straight from the (T, M) tokens
+  (``tokens[order[r] // k]``), the grouped matmuls run over the ragged
+  groups with the row's gate multiplied into their activation (by
+  linearity ``sum_j g_j (h_j Wo) = sum_j (g_j h_j) Wo``), and a token's
+  output is the plain float32 sum of its k rows. An array of T x k rows
+  exists only as an operand or a cotangent of a grouped matmul: nothing
+  is broadcast, and backward every gather is a gather (two of the four
+  read the (T, M) array), never a scatter-add. No ``(T, E, C)`` tensor
+  and no capacity: an expert takes whatever the router sends it.
+  GPT-2's block gets GELU experts and one expert a token, where the
+  sum over k is the identity; OLMoE's SwiGLU experts and 8 of 64
+  (``BlockSpec``).
 - ``top1_dispatch`` / ``moe_ffn`` / ``expert_parallel_moe`` — the older
   Switch-style top-1 form with a capacity, which DROPS overflow tokens,
   and its explicit shard_map formulation over the ``expert`` axis (two
@@ -18,7 +26,7 @@
 
 from __future__ import annotations
 
-from typing import Optional
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +41,17 @@ from horovod_tpu.jax.introspect import (
 )
 from horovod_tpu.parallel.mesh import EXPERT_AXIS
 from horovod_tpu.parallel.mesh import traced_axis_size
+from horovod_tpu.utils import metrics as _metrics
+
+# Counted at trace time: the gathers of M-wide rows one traced expert
+# layer makes, by where (``dispatch_fwd`` / ``combine_fwd`` /
+# ``combine_bwd`` / ``dispatch_bwd``) and from what they read:
+# ``tokens`` (the (T, M) array) or ``rows`` (a (T x k, M) one).
+_M_ROW_GATHERS = _metrics.counter(
+    "hvd_moe_row_gathers_total",
+    "Gathers of rows per traced expert layer, by site and by the array "
+    "they read (counted at trace time, not per device step).",
+    ("site", "source"))
 
 
 def top1_dispatch(router_logits, capacity: int):
@@ -107,13 +126,16 @@ def route(logits, k, assignment=None):
     softmax in float32 over all experts, then the k largest
     probabilities of each token and their indices, NOT renormalised.
     ``assignment`` (T, k) forces the experts; the gates are still this
-    router's probabilities of them."""
+    router's probabilities of them.
+
+    The gates are read off ``probs`` by a one-hot sum (exact: one term
+    and zeros), so their gradient reaches ``probs`` as a select per
+    (token, slot, expert) where ``top_k``'s own would be a scatter-add
+    of T x k scalars."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    if assignment is None:
-        gates, experts = lax.top_k(probs, k)
-    else:
-        experts = assignment
-        gates = jnp.take_along_axis(probs, experts, axis=-1)
+    experts = lax.top_k(probs, k)[1] if assignment is None else assignment
+    chosen = jax.nn.one_hot(experts, probs.shape[-1], dtype=probs.dtype)
+    gates = jnp.sum(probs[:, None, :] * chosen, axis=-1)
     return probs, gates, experts
 
 
@@ -132,23 +154,82 @@ def aux_losses(logits, probs, counts):
     return load_balance, z_loss
 
 
+def _sorted_by(keys, values):
+    """``values`` in the order that sorts the permutation ``keys``."""
+    return lax.sort((keys, values), num_keys=1)[1]
+
+
 @jax.custom_vjp
-def _permute(rows, index, inverse):
-    """``rows[index]`` for a permutation ``index`` whose inverse is
-    ``inverse``: the backward pass is a gather too, not a scatter-add."""
-    return rows[index]
+def _permute(values, index, inverse):
+    """``values[index]`` of a vector, for a permutation ``index`` whose
+    inverse is ``inverse``: carries the T x k gates into sorted order.
+    Both ways as a sort on the other permutation's keys: on the chip a
+    sort of 32,768 pairs takes 0.025 ms, a gather of as many scalars
+    0.28 (PERF.md, PR 29). Rows go through ``_dispatch`` and
+    ``_combine``."""
+    return _sorted_by(inverse, values)
 
 
-def _permute_fwd(rows, index, inverse):
-    return rows[index], (index, inverse)
+def _permute_fwd(values, index, inverse):
+    return _sorted_by(inverse, values), index
 
 
-def _permute_bwd(res, d_out):
-    index, inverse = res
-    return d_out[inverse], None, None
+def _permute_bwd(index, d_out):
+    return _sorted_by(index, d_out), None, None
 
 
 _permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _rows_of_tokens(site, tokens, order, k):
+    """(T x k, M): sorted row r is the row of token ``order[r] // k``."""
+    _M_ROW_GATHERS.labels(site=site, source="tokens").inc()
+    return tokens[order // k]
+
+
+def _sum_per_token(site, rows, inverse, k):
+    """(T, M): the float32 sum of each token's k sorted rows, rounded
+    once to the rows' dtype."""
+    _M_ROW_GATHERS.labels(site=site, source="rows").inc()
+    pairs = rows[inverse].reshape(-1, k, rows.shape[-1])
+    return jnp.sum(pairs, axis=1, dtype=jnp.float32).astype(rows.dtype)
+
+
+# Dispatch and combine are each other's transposes, so each one's
+# backward pass is the other's forward: two gathers from the (T, M)
+# array, two from (T x k, M) rows, no broadcast and no scatter-add.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(tokens, order, inverse, k):
+    """The tokens' rows in sorted order, (T x k, M)."""
+    return _rows_of_tokens("dispatch_fwd", tokens, order, k)
+
+
+def _dispatch_fwd(tokens, order, inverse, k):
+    return _rows_of_tokens("dispatch_fwd", tokens, order, k), inverse
+
+
+def _dispatch_bwd(k, inverse, d_rows):
+    return _sum_per_token("dispatch_bwd", d_rows, inverse, k), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(rows, order, inverse, k):
+    """Each token's sum over its k sorted rows, (T, M)."""
+    return _sum_per_token("combine_fwd", rows, inverse, k)
+
+
+def _combine_fwd(rows, order, inverse, k):
+    return _sum_per_token("combine_fwd", rows, inverse, k), order
+
+
+def _combine_bwd(k, order, d_out):
+    return _rows_of_tokens("combine_bwd", d_out, order, k), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def sorted_by_expert(experts):
@@ -157,21 +238,24 @@ def sorted_by_expert(experts):
     pair j in sorted row ``inverse[j]``; pair j is token ``j // k``."""
     flat = experts.reshape(-1)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=jnp.int32))
-    return order, inverse
+    return order, jnp.argsort(order).astype(jnp.int32)
 
 
-def grouped_ffn(rows, group_sizes, wi, wo, wg=None):
-    """Each expert's feed-forward over its own rows: ``rows`` (N, M)
-    sorted by expert, ``group_sizes`` (E,) rows each; weights (E, M, F),
-    (E, F, M). With ``wg`` the experts are gated (SwiGLU:
-    ``silu(rows wg) * (rows wi)``), else GELU."""
-    up = lax.ragged_dot(rows, wi, group_sizes)
+def grouped_ffn(rows, row_gates, group_sizes, wi, wo, wg=None):
+    """Each expert's feed-forward over its own rows, times the row's
+    gate: ``rows`` (N, M) sorted by expert, ``row_gates`` (N,) float32,
+    ``group_sizes`` (E,) rows each; weights (E, M, F), (E, F, M). With
+    ``wg`` the experts are gated (SwiGLU: ``silu(rows wg) * (rows
+    wi)``), else GELU. The gate multiplies the activation, F wide, in
+    float32, and the product is rounded once to the rows' dtype; the
+    gates' gradient is that fusion's reduction over F."""
+    up = lax.ragged_dot(rows, wi, group_sizes).astype(jnp.float32)
     if wg is None:
         hidden = nn.gelu(up)
     else:
-        hidden = nn.silu(lax.ragged_dot(rows, wg, group_sizes)) * up
+        hidden = nn.silu(lax.ragged_dot(rows, wg, group_sizes)
+                         .astype(jnp.float32)) * up
+    hidden = (hidden * row_gates[:, None]).astype(rows.dtype)
     return lax.ragged_dot(hidden, wo, group_sizes)
 
 
@@ -218,20 +302,19 @@ class MoeMlp(nn.Module):
             load_balance, z_loss = aux_losses(logits, probs, counts)
         with jax.named_scope(SCOPE_MOE_DISPATCH):
             order, inverse = sorted_by_expert(experts)
-            pairs = jnp.broadcast_to(tokens[:, None], (t, k, m))
-            rows = _permute(pairs.reshape(t * k, m), order, inverse)
+            rows = _dispatch(tokens, order, inverse, k)
+            row_gates = _permute(gates.reshape(-1), order, inverse)
         with jax.named_scope(SCOPE_MOE_EXPERTS):
-            out = grouped_ffn(rows, counts, wi.astype(cfg.dtype),
+            out = grouped_ffn(rows, row_gates, counts, wi.astype(cfg.dtype),
                               wo.astype(cfg.dtype), wg)
         with jax.named_scope(SCOPE_MOE_COMBINE):
-            out = _permute(out, inverse, order).reshape(t, k, m)
-            out = jnp.einsum("tk,tkm->tm", gates, out.astype(jnp.float32))
+            out = _combine(out, order, inverse, k)
         if not self.is_initializing():
             self.sow("moe", "load_balance", load_balance)
             self.sow("moe", "z_loss", z_loss)
             self.sow("moe", "tokens_per_expert", counts)
             self.sow("moe", "experts", experts)
-        return out.astype(cfg.dtype).reshape(b, s, m)
+        return out.reshape(b, s, m)
 
 
 def sown_stats(variables):

@@ -120,3 +120,46 @@ def test_gradient_sync_in_the_compiled_step(topo, monkeypatch, cell_name):
     for opcode in ("reshape", "copy", "concatenate", "dynamic-update-slice",
                    "dynamic-slice", "slice", "pad", "bitcast"):
         assert opcode not in named, named
+
+
+def test_expert_layer_materialises_no_float32_rows(one_chip):
+    """``MoeMlp`` forward + backward compiled for a described v5e, bf16
+    compute: the jaxpr's float32 operand of the sum over k is fused into
+    its reduction, so no instruction of the entry computation holds a
+    float32 array of T x k x M elements, and nothing is broadcast to
+    (T x k, M) rows; the rows move by four gathers."""
+    from flax.core import meta
+    from horovod_tpu import models
+    from horovod_tpu.parallel.moe import MoeMlp
+
+    t, k, m, f, e = 512, 2, 256, 128, 4
+    layer = MoeMlp(models.TransformerConfig(
+        d_model=m, n_heads=2, d_ff=f, dtype=jnp.bfloat16,
+        block=models.BlockSpec(ffn="swiglu", num_experts=e,
+                               experts_per_token=k)))
+    x = jax.ShapeDtypeStruct((1, t, m), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: meta.unbox(layer.init(
+            jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype)))))
+
+    def step(p, x_, ct):
+        out, vjp = jax.vjp(layer.apply, p, x_)
+        return (out,) + vjp(ct)
+
+    text = jax.jit(step).lower(params, x, x).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    rows = "[%d,%d]" % (t * k, m)
+    assert "bf16" + rows in entry            # the sizes are told apart
+    assert "f32" + rows not in entry
+    assert "f32[%d,%d,%d]" % (t, k, m) not in entry
+    for line in entry.splitlines():
+        if "= bf16" + rows in line:
+            assert "broadcast" not in line.split("=")[0], line
+    # Each row gather by the rows of the array it reads.
+    read = []
+    for operand in re.findall(r"= bf16\[%d,%d\]\S* gather\(%%([\w.\-]+),"
+                              % (t * k, m), text):
+        read += re.findall(r"%%%s = bf16\[(\d+),%d\]"
+                           % (re.escape(operand), m), text)
+    assert sorted(int(n) for n in read) == [t, t, t * k, t * k], read

@@ -171,3 +171,155 @@ def test_moe_transformer_forward_and_grad():
     flat = jax.tree_util.tree_flatten_with_path(g)[0]
     router_grads = [v for k, v in flat if "router" in str(k)]
     assert router_grads and float(np.abs(np.asarray(router_grads[0])).sum()) > 0
+
+
+# ---------------------------------------------- MoeMlp, token-major -------
+
+def _moe_layer(ffn, e, k, dtype=jnp.float32, d_model=16, d_ff=24):
+    cfg = models.TransformerConfig(
+        d_model=d_model, n_heads=2, d_ff=d_ff, dtype=dtype,
+        block=models.BlockSpec(ffn=ffn, num_experts=e, experts_per_token=k))
+    return moe_mod.MoeMlp(cfg)
+
+
+def _dense_moe_reference(params, x, k, assignment):
+    """Every token through EVERY expert, then the gate-weighted sum over
+    the k it was sent to: no sort, no gather, no grouped matmul."""
+    p = params["params"]
+    tokens = x.reshape(-1, x.shape[-1])
+    probs = jax.nn.softmax(tokens @ p["router"], axis=-1)
+    experts = jax.lax.top_k(probs, k)[1] if assignment is None \
+        else assignment
+    up = jnp.einsum("tm,emf->tef", tokens, p["wi"])
+    if "wg" in p:
+        hidden = jax.nn.silu(
+            jnp.einsum("tm,emf->tef", tokens, p["wg"])) * up
+    else:
+        hidden = jax.nn.gelu(up)
+    every = jnp.einsum("tef,efm->tem", hidden, p["wo"])
+    sent = jax.nn.one_hot(experts, probs.shape[-1]).sum(1)     # (T, E)
+    return jnp.einsum("te,tem->tm", probs * sent, every).reshape(x.shape)
+
+
+def _skewed(t, e, k):
+    """A routing in which expert 0 receives a row of every token (most
+    of the rows, or all but a few when k is 1) and the last expert
+    none."""
+    rest = 1 + (np.arange(t)[:, None] + np.arange(k - 1)[None]) % (e - 2)
+    table = np.concatenate([np.zeros((t, 1), np.int64), rest], axis=1)
+    if k == 1:
+        table[::7, 0] = 1 + np.arange(len(table[::7])) % (e - 2)
+    return jnp.asarray(table[:, :k], jnp.int32)
+
+
+@pytest.mark.parametrize("routing", ["free", "forced", "skewed"])
+@pytest.mark.parametrize("ffn,e,k", [("swiglu", 8, 2), ("gelu", 4, 1)])
+def test_moe_mlp_matches_dense_per_token_reference(ffn, e, k, routing):
+    """Output and EVERY gradient leaf (the router's and the input's
+    included) against the dense reference, float32, to 1e-5."""
+    from flax.core import meta
+
+    layer = _moe_layer(ffn, e, k)
+    rng = jax.random.split(jax.random.PRNGKey(29), 3)
+    x = jax.random.normal(rng[0], (2, 20, 16), jnp.float32)
+    ct = jax.random.normal(rng[1], x.shape, jnp.float32)
+    params = meta.unbox(layer.init(rng[2], x))
+    # Weights large enough that the gates differ from 1/E and a wrong
+    # one shows.
+    params = jax.tree.map(lambda a: 10.0 * a, params)
+    t = x.shape[0] * x.shape[1]
+    assignment = {
+        "free": None,
+        "forced": ((3 * jnp.arange(t)[:, None] + 5 * jnp.arange(k)[None])
+                   % e).astype(jnp.int32),
+        "skewed": _skewed(t, e, k),
+    }[routing]
+    if routing == "skewed":
+        counts = np.bincount(np.asarray(assignment).reshape(-1), minlength=e)
+        assert counts[-1] == 0 and counts[0] > t * k / 2 - 1
+
+    def program(p, x_):
+        out, sown = layer.apply(p, x_, assignment, mutable=["moe"])
+        return jnp.sum(out * ct), (out, sown["moe"]["tokens_per_expert"][0])
+
+    def reference(p, x_):
+        out = _dense_moe_reference(p, x_, k, assignment)
+        return jnp.sum(out * ct), out
+
+    (_, (out, counts)), grads = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True))(params, x)
+    (_, want), want_grads = jax.jit(jax.value_and_grad(
+        reference, argnums=(0, 1), has_aux=True))(params, x)
+    assert int(counts.sum()) == t * k
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    assert len(flat) == (5 if ffn == "swiglu" else 4)
+    for (path, got), ref in zip(flat, jax.tree.leaves(want_grads)):
+        scale = float(jnp.max(jnp.abs(ref)))
+        assert scale > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(
+            got, ref, rtol=1e-5, atol=1e-5 * scale,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_moe_mlp_moves_rows_only_by_four_gathers():
+    """Token-major dispatch and combine, read off the traced layer
+    (bf16 compute over float32 parameters, forward + backward): nothing
+    is broadcast to T x k rows, no row is scatter-added, a float32
+    value of T x k x M elements exists only as the operand of its own
+    reduction over k, and the rows move by four gathers, two of them
+    from the (T, M) array."""
+    import jax.extend
+    from flax.core import meta
+    from horovod_tpu.jax import introspect
+    from horovod_tpu.utils import metrics
+
+    e, k, m, f = 4, 2, 40, 24
+    layer = _moe_layer("swiglu", e, k, dtype=jnp.bfloat16, d_model=m, d_ff=f)
+    x = jnp.ones((1, 16, m), jnp.bfloat16)
+    t, rows = 16, 16 * k
+    params = meta.unbox(layer.init(jax.random.PRNGKey(0), x))
+
+    sites = {("dispatch_fwd", "tokens"), ("combine_fwd", "rows"),
+             ("combine_bwd", "tokens"), ("dispatch_bwd", "rows")}
+
+    def row_gathers():
+        return {key: metrics.REGISTRY.value(
+            "hvd_moe_row_gathers_total", site=key[0], source=key[1]) or 0
+            for key in sites | {("dispatch_fwd", "rows"),
+                                ("combine_bwd", "rows")}}
+
+    before = row_gathers()
+    jaxpr = jax.make_jaxpr(lambda p, x_: jax.vjp(layer.apply, p, x_)[1](x_))(
+        params, x)
+    moved = {key: n - before[key] for key, n in row_gathers().items()}
+    assert {key for key, n in moved.items() if n} == sites
+    assert set(moved.values()) == {0, 1}
+
+    eqns = list(introspect.equations(jaxpr.jaxpr))
+    users = {}
+    for eqn in eqns:
+        for var in eqn.invars:
+            if not isinstance(var, jax.extend.core.Literal):
+                users.setdefault(var, []).append(eqn)
+    row_gathers = []
+    for eqn in eqns:
+        name = eqn.primitive.name
+        for out in eqn.outvars:
+            shape = getattr(out.aval, "shape", ())
+            if int(np.prod(shape)) != rows * m or not shape or shape[-1] != m:
+                continue
+            assert name != "broadcast_in_dim", eqn
+            assert not name.startswith("scatter"), eqn
+            if name == "gather":
+                row_gathers.append(eqn.invars[0].aval.shape)
+            if out.aval.dtype == jnp.float32:
+                assert name == "convert_element_type", eqn
+                assert [u.primitive.name for u in users[out]] == [
+                    "reduce_sum"], eqn
+                assert users[out][0].outvars[0].aval.shape == (t, m)
+    assert sorted(row_gathers) == [(t, m), (t, m), (rows, m), (rows, m)]
+    # Nor anything else: the gates' gradient reaches the probabilities
+    # by a select, the permutations are sorts.
+    assert not [eqn for eqn in eqns
+                if eqn.primitive.name.startswith("scatter")]

@@ -38,7 +38,10 @@ SCOPE_UPDATE = "hvd_update"      # the inner optimizer's update
 SCOPE_FLASH = "hvd_flash"        # ops/pallas_attention.py, kernels + glue
 SCOPE_EMBED = "embed"            # Transformer: lookup + positions
 SCOPE_LOGITS = "logits"          # Transformer: output projection
-SCOPE_ROPE = "rope"              # SelfAttention: rotary positions on q, k
+SCOPE_ROPE = "rope"              # attention: rotary positions on q, k
+# LatentAttention: both down-projections with their norms, both
+# up-projections, and q, k, v put together (RoPE keeps ``rope``).
+SCOPE_MLA_LATENT = "hvd_mla_latent"
 # The expert layer (parallel/moe.py), inside the ``moe`` module's scope.
 SCOPE_MOE_ROUTER = "hvd_moe_router"      # logits, softmax, top-k, aux losses
 # The sorts (the gates ride one into row order); rows gathered from the
@@ -51,6 +54,8 @@ SCOPE_MOE_EXPERTS = "hvd_moe_experts"
 # A gather of rows and the plain sum over each token's k; backward one
 # gather from the (T, M) cotangent. No weighting here.
 SCOPE_MOE_COMBINE = "hvd_moe_combine"
+# The shared expert's three matmuls, beside the routed sum.
+SCOPE_MOE_SHARED = "hvd_moe_shared"
 # ``name=`` of the three ``pallas_call``s (the Mosaic calls' op_name).
 KERNEL_FLASH_FWD = "hvd_flash_fwd"
 KERNEL_FLASH_DKV = "hvd_flash_dkv"

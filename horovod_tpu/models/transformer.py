@@ -12,7 +12,10 @@ mixture of experts (``horovod_tpu.parallel.moe``).
 What KIND of block the decoder is made of is data, a ``BlockSpec``:
 GPT-2's (LayerNorm, GELU, learned positions, tied output embedding) is
 the default; OLMoE's is RMSNorm, SwiGLU experts, rotary positions,
-RMSNorm on q and k, an output head of its own.
+RMSNorm on q and k, an output head of its own; GLM-4.7-Flash's is
+latent attention (``LatentAttention``), leading dense blocks of their
+own width, then expert blocks with a sigmoid router under a correction
+bias, a shared expert, and only this chip's share of the experts held.
 
 Param layout (tensor parallel over 'model'):
 - attention QKV projections shard the head dim;
@@ -35,6 +38,7 @@ from flax.linen import partitioning as nn_partitioning
 from horovod_tpu.jax.introspect import (
     SCOPE_EMBED,
     SCOPE_LOGITS,
+    SCOPE_MLA_LATENT,
     SCOPE_ROPE,
 )
 from horovod_tpu.parallel.mesh import traced_axis_size
@@ -86,6 +90,32 @@ class BlockSpec:
     # through its ``experts_per_token`` most probable (parallel/moe.py).
     num_experts: int = 0
     experts_per_token: int = 1
+    # 'heads': q, k, v straight from the block's input. 'latent': q and
+    # k/v through low-rank latents with norms of their own, a rotary
+    # part of k that all heads share (``LatentAttention``); then the
+    # ranks and the three head dims are the config's keys.
+    attention_kind: str = "heads"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The first blocks carry a dense feed-forward of this width, whatever
+    # the rest carry.
+    first_dense_layers: int = 0
+    dense_ff: int = 0
+    # The expert layer's router (parallel/moe.py ``route``): 'softmax'
+    # over all experts, or 'sigmoid_bias': sigmoid scores, the choice by
+    # score + a correction bias that is carried state, the gates from
+    # the scores alone.
+    router: str = "softmax"
+    norm_topk: bool = False          # gates / their sum over the chosen
+    routed_scale: float = 1.0        # then times this
+    shared_experts: int = 0          # experts every token goes through
+    # The experts whose weights live here, ``first_expert_held`` onward;
+    # the router still scores all ``num_experts``. 0 = all of them.
+    experts_held: int = 0
+    first_expert_held: int = 0
 
 
 GPT2_BLOCK = BlockSpec()
@@ -153,6 +183,25 @@ def _dense_causal_attention(q, k, v, dtype):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _attend(cfg, q, k, v):
+    """Causal attention of q, k, v (B, S, H, D) by ``cfg.attention``."""
+    if cfg.attention == "dense":
+        return _dense_causal_attention(q, k, v, cfg.dtype)
+    if cfg.attention == "flash":
+        from horovod_tpu.ops.pallas_attention import flash_attention
+
+        return flash_attention(q, k, v, causal=True).astype(cfg.dtype)
+    if cfg.attention == "ring":
+        from horovod_tpu.parallel.sequence import ring_attention
+
+        return ring_attention(q, k, v, axis=cfg.seq_axis, causal=True)
+    if cfg.attention == "ulysses":
+        from horovod_tpu.parallel.sequence import ulysses_attention
+
+        return ulysses_attention(q, k, v, axis=cfg.seq_axis, causal=True)
+    raise ValueError("Unknown attention impl %r" % (cfg.attention,))
+
+
 class SelfAttention(nn.Module):
     cfg: TransformerConfig
 
@@ -184,40 +233,88 @@ class SelfAttention(nn.Module):
                 first = _first_position(cfg, x.shape[1])
                 q = rope(q, first, cfg.block.rope_theta)
                 k = rope(k, first, cfg.block.rope_theta)
-        if cfg.attention == "dense":
-            ctx = _dense_causal_attention(q, k, v, cfg.dtype)
-        elif cfg.attention == "flash":
-            from horovod_tpu.ops.pallas_attention import flash_attention
-
-            ctx = flash_attention(q, k, v, causal=True).astype(cfg.dtype)
-        elif cfg.attention == "ring":
-            from horovod_tpu.parallel.sequence import ring_attention
-
-            ctx = ring_attention(q, k, v, axis=cfg.seq_axis, causal=True)
-        elif cfg.attention == "ulysses":
-            from horovod_tpu.parallel.sequence import ulysses_attention
-
-            ctx = ulysses_attention(q, k, v, axis=cfg.seq_axis, causal=True)
-        else:
-            raise ValueError("Unknown attention impl %r" % (cfg.attention,))
-        return jnp.einsum("bshd,hdm->bsm", ctx, wo)
+        return jnp.einsum("bshd,hdm->bsm", _attend(cfg, q, k, v), wo)
 
 
-class Mlp(nn.Module):
+def _to_every_head(k_pe, n_heads):
+    """The one rotary key part (B, S, 1, R) as every head's."""
+    return jnp.broadcast_to(
+        k_pe, k_pe.shape[:2] + (n_heads,) + k_pe.shape[3:])
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention as trained (k and v computed, no
+    weight absorption): ``c_q = norm(x Wqa)``, ``q = c_q Wqb`` split per
+    head into a plain and a rotary part; ``[c_kv | k_pe] = x Wkva``,
+    ``c_kv = norm(c_kv)``, ``c_kv Wkvb`` split per head into the plain
+    part of k and v; RoPE on q's rotary part and on ``k_pe``, which ALL
+    heads share. q.k is ``qk_nope_head_dim + qk_rope_head_dim`` wide and
+    has to equal ``v_head_dim``: the kernels take one ``d``."""
+
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x):
+        cfg, spec = self.cfg, self.cfg.block
+        h, m = cfg.n_heads, cfg.d_model
+        nope, rot, dv = (spec.qk_nope_head_dim, spec.qk_rope_head_dim,
+                         spec.v_head_dim)
+        if nope + rot != dv:
+            raise ValueError(
+                "latent attention with q.k %d + %d wide and v %d: the "
+                "attention kernels take one head_dim" % (nope, rot, dv))
+        init = nn.initializers.normal(0.02)
+
+        def weight(name, axes, shape):
+            return self.param(name, param_with_axes(init, axes), shape,
+                              jnp.float32).astype(cfg.dtype)
+
+        with jax.named_scope(SCOPE_MLA_LATENT):
+            c_q = _norm(cfg, "q_a_norm")(
+                x @ weight("q_a", (None, None), (m, spec.q_lora_rank)))
+            q = jnp.einsum("bsr,rhd->bshd", c_q, weight(
+                "q_b", (None, "model", None),
+                (spec.q_lora_rank, h, nope + rot)))
+            down = x @ weight("kv_a", (None, None),
+                              (m, spec.kv_lora_rank + rot))
+            c_kv = _norm(cfg, "kv_a_norm")(down[..., :spec.kv_lora_rank])
+            kv = jnp.einsum("bsr,rhd->bshd", c_kv, weight(
+                "kv_b", (None, "model", None),
+                (spec.kv_lora_rank, h, nope + dv)))
+        with jax.named_scope(SCOPE_ROPE):
+            first = _first_position(cfg, x.shape[1])
+            q_pe = rope(q[..., nope:], first, spec.rope_theta)
+            k_pe = rope(down[..., None, spec.kv_lora_rank:], first,
+                        spec.rope_theta)
+        with jax.named_scope(SCOPE_MLA_LATENT):
+            q = jnp.concatenate([q[..., :nope], q_pe], -1)
+            k = jnp.concatenate([kv[..., :nope], _to_every_head(k_pe, h)],
+                                -1)
+            v = kv[..., nope:]
+        wo = weight("wo", ("model", None, None), (h, dv, m))
+        return jnp.einsum("bshd,hdm->bsm", _attend(cfg, q, k, v), wo)
+
+
+class Mlp(nn.Module):
+    """The dense feed-forward, ``cfg.d_ff`` wide unless ``width`` says
+    otherwise (a leading dense block, a shared expert)."""
+
+    cfg: TransformerConfig
+    width: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, x):
         cfg = self.cfg
+        d_ff = self.width or cfg.d_ff
         init = nn.initializers.normal(0.02)
         wi = self.param("wi", param_with_axes(init, (None, "model")),
-                        (cfg.d_model, cfg.d_ff), jnp.float32)
+                        (cfg.d_model, d_ff), jnp.float32)
         wo = self.param("wo", param_with_axes(init, ("model", None)),
-                        (cfg.d_ff, cfg.d_model), jnp.float32)
+                        (d_ff, cfg.d_model), jnp.float32)
         y = x @ wi.astype(cfg.dtype)
         if cfg.block.ffn == "swiglu":
             wg = self.param("wg", param_with_axes(init, (None, "model")),
-                            (cfg.d_model, cfg.d_ff), jnp.float32)
+                            (cfg.d_model, d_ff), jnp.float32)
             y = nn.silu(x @ wg.astype(cfg.dtype)) * y
         else:
             y = nn.gelu(y)
@@ -225,20 +322,32 @@ class Mlp(nn.Module):
 
 
 class Block(nn.Module):
+    """One decoder block. ``dense_width`` makes its feed-forward a dense
+    one of that width whatever the model's other blocks carry."""
+
     cfg: TransformerConfig
+    dense_width: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, assignment=None):
         cfg = self.cfg
+        attention = {"heads": SelfAttention,
+                     "latent": LatentAttention}[cfg.block.attention_kind]
         y = _norm(cfg, "ln1")(x)
-        x = x + SelfAttention(cfg, name="attn")(y)
+        x = x + attention(cfg, name="attn")(y)
         y = _norm(cfg, "ln2")(x)
-        if cfg.block.num_experts > 0:
+        if cfg.block.num_experts > 0 and self.dense_width is None:
             from horovod_tpu.parallel.moe import MoeMlp
 
-            x = x + MoeMlp(cfg, name="moe")(y, assignment)
+            shared = None
+            if cfg.block.shared_experts:
+                # Adopted by the expert layer: its weights are
+                # ``moe/shared``, its time the ``moe`` scope's.
+                shared = Mlp(cfg, cfg.block.shared_experts * cfg.d_ff,
+                             parent=None)
+            x = x + MoeMlp(cfg, shared, name="moe")(y, assignment)
         else:
-            x = x + Mlp(cfg, name="mlp")(y)
+            x = x + Mlp(cfg, self.dense_width, name="mlp")(y)
         return x
 
 
@@ -248,9 +357,10 @@ class Transformer(nn.Module):
     @nn.compact
     def __call__(self, tokens, assignments=None):
         """Logits (B, S, vocab) in float32. ``assignments`` (one entry a
-        layer, each (B * S, experts_per_token) expert indices) forces
-        the experts' routing; what the expert layers sow is in the
-        ``moe`` collection (parallel/moe.py)."""
+        layer, each (B * S, experts_per_token) expert indices; a dense
+        block's is ignored) forces the experts' routing; what the
+        expert layers sow is in the ``moe`` collection, the routers'
+        correction biases in ``moe_state`` (parallel/moe.py)."""
         cfg = self.cfg
         init = nn.initializers.normal(0.02)
         embed = self.param(
@@ -296,7 +406,9 @@ class Transformer(nn.Module):
         if cfg.remat:
             block = nn.remat(Block)
         for i in range(cfg.n_layers):
-            x = block(cfg, name="layer_%d" % i)(
+            dense = i < cfg.block.first_dense_layers
+            x = block(cfg, cfg.block.dense_ff if dense else None,
+                      name="layer_%d" % i)(
                 x, None if assignments is None else assignments[i])
         x = _norm(cfg, "ln_f")(x)
         with jax.named_scope(SCOPE_LOGITS):

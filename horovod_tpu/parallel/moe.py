@@ -15,7 +15,17 @@
   and no capacity: an expert takes whatever the router sends it.
   GPT-2's block gets GELU experts and one expert a token, where the
   sum over k is the identity; OLMoE's SwiGLU experts and 8 of 64
-  (``BlockSpec``).
+  (``BlockSpec``). The layer may HOLD fewer experts than it routes over
+  (one chip's share of an expert-parallel group, GLM-4.7-Flash's 8 of
+  64): the router, its top-k and the gates are over all experts, the
+  pairs of the held experts sort first, their rows are the ragged
+  groups, and every row past them is a dead row: never multiplied,
+  never read back. The row arrays keep their full static length T x k:
+  no bound short of it drops nothing whatever the router does.
+  What absent experts would have added is left out; the exchange that
+  brings other chips' rows here wraps this layer later (ROADMAP, D14).
+  A ``sigmoid_bias`` router (``route``) and shared experts beside the
+  routed sum are the same layer's options.
 - ``top1_dispatch`` / ``moe_ffn`` / ``expert_parallel_moe`` — the older
   Switch-style top-1 form with a capacity, which DROPS overflow tokens,
   and its explicit shard_map formulation over the ``expert`` axis (two
@@ -27,6 +37,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -38,8 +49,9 @@ from horovod_tpu.jax.introspect import (
     SCOPE_MOE_DISPATCH,
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_ROUTER,
+    SCOPE_MOE_SHARED,
 )
-from horovod_tpu.parallel.mesh import EXPERT_AXIS
+from horovod_tpu.parallel.mesh import DATA_AXIS, EXPERT_AXIS
 from horovod_tpu.parallel.mesh import traced_axis_size
 from horovod_tpu.utils import metrics as _metrics
 
@@ -52,6 +64,12 @@ _M_ROW_GATHERS = _metrics.counter(
     "Gathers of rows per traced expert layer, by site and by the array "
     "they read (counted at trace time, not per device step).",
     ("site", "source"))
+# Also at trace time: the experts one traced expert layer holds
+# (``held``) and routes over (``routed``).
+_M_EXPERTS = _metrics.counter(
+    "hvd_moe_experts_total",
+    "Experts per traced expert layer: those whose weights it holds and "
+    "those its router scores (counted at trace time).", ("kind",))
 
 
 def top1_dispatch(router_logits, capacity: int):
@@ -121,22 +139,51 @@ def expert_parallel_moe(x, router_w, wi_local, wo_local, capacity: int,
     return jnp.einsum("tec,ecm->tm", combine.astype(dtype), expert_out)
 
 
-def route(logits, k, assignment=None):
-    """(probs (T, E), gates (T, k), experts (T, k)) from router logits:
-    softmax in float32 over all experts, then the k largest
-    probabilities of each token and their indices, NOT renormalised.
+def route(logits, k, assignment=None, *, scoring="softmax", bias=None,
+          norm_topk=False, scale=1.0):
+    """(scores (T, E), gates (T, k), experts (T, k)) from router logits,
+    in float32 over all experts. ``scoring`` 'softmax': the k largest
+    probabilities of each token and their indices. 'sigmoid_bias': the
+    scores are sigmoids, the CHOICE is the top k of ``scores + bias``
+    (``bias`` (E,), carried state, no gradient), the gates are the
+    chosen experts' scores WITHOUT the bias. ``norm_topk`` divides a
+    token's gates by their sum, ``scale`` multiplies them.
     ``assignment`` (T, k) forces the experts; the gates are still this
-    router's probabilities of them.
+    router's scores of them.
 
-    The gates are read off ``probs`` by a one-hot sum (exact: one term
-    and zeros), so their gradient reaches ``probs`` as a select per
+    The gates are read off the scores by a one-hot sum (exact: one term
+    and zeros), so their gradient reaches the scores as a select per
     (token, slot, expert) where ``top_k``'s own would be a scatter-add
     of T x k scalars."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    experts = lax.top_k(probs, k)[1] if assignment is None else assignment
-    chosen = jax.nn.one_hot(experts, probs.shape[-1], dtype=probs.dtype)
-    gates = jnp.sum(probs[:, None, :] * chosen, axis=-1)
-    return probs, gates, experts
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError("Unknown router scoring %r" % (scoring,))
+    if assignment is None:
+        experts = lax.top_k(scores if bias is None else scores + bias, k)[1]
+    else:
+        experts = assignment
+    chosen = jax.nn.one_hot(experts, scores.shape[-1], dtype=scores.dtype)
+    gates = jnp.sum(scores[:, None, :] * chosen, axis=-1)
+    if norm_topk:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        gates = gates * scale
+    return scores, gates, experts
+
+
+def corrected_bias(bias, tokens_per_expert, rate):
+    """The router's correction bias after one step (DeepSeek-V3's
+    auxiliary-loss-free balancing): up by ``rate`` for an expert that
+    received fewer (token, slot) pairs than the mean over ALL experts,
+    down for one that received more. ``tokens_per_expert`` is what the
+    layer sowed, summed over the replicas where there are several
+    (``updated_router_bias`` does that)."""
+    load = tokens_per_expert.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(load) - load)
 
 
 def aux_losses(logits, probs, counts):
@@ -187,75 +234,102 @@ def _rows_of_tokens(site, tokens, order, k):
     return tokens[order // k]
 
 
-def _sum_per_token(site, rows, inverse, k):
+def _sum_per_token(site, rows, inverse, k, live):
     """(T, M): the float32 sum of each token's k sorted rows, rounded
-    once to the rows' dtype."""
+    once to the rows' dtype. Only the first ``live`` sorted rows count
+    (None: all of them): what a dead row holds is never read."""
     _M_ROW_GATHERS.labels(site=site, source="rows").inc()
-    pairs = rows[inverse].reshape(-1, k, rows.shape[-1])
+    pairs = rows[inverse]
+    if live is not None:
+        pairs = jnp.where((inverse < live)[:, None], pairs, 0)
+    pairs = pairs.reshape(-1, k, rows.shape[-1])
     return jnp.sum(pairs, axis=1, dtype=jnp.float32).astype(rows.dtype)
 
 
 # Dispatch and combine are each other's transposes, so each one's
 # backward pass is the other's forward: two gathers from the (T, M)
 # array, two from (T x k, M) rows, no broadcast and no scatter-add.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(tokens, order, inverse, k):
+# ``live`` (a traced scalar, or None for "every row") is the number of
+# sorted rows that belong to an expert held here.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(tokens, order, inverse, live, k):
     """The tokens' rows in sorted order, (T x k, M)."""
     return _rows_of_tokens("dispatch_fwd", tokens, order, k)
 
 
-def _dispatch_fwd(tokens, order, inverse, k):
-    return _rows_of_tokens("dispatch_fwd", tokens, order, k), inverse
+def _dispatch_fwd(tokens, order, inverse, live, k):
+    return _rows_of_tokens("dispatch_fwd", tokens, order, k), (inverse, live)
 
 
-def _dispatch_bwd(k, inverse, d_rows):
-    return _sum_per_token("dispatch_bwd", d_rows, inverse, k), None, None
+def _dispatch_bwd(k, res, d_rows):
+    inverse, live = res
+    return (_sum_per_token("dispatch_bwd", d_rows, inverse, k, live),
+            None, None, None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine(rows, order, inverse, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine(rows, order, inverse, live, k):
     """Each token's sum over its k sorted rows, (T, M)."""
-    return _sum_per_token("combine_fwd", rows, inverse, k)
+    return _sum_per_token("combine_fwd", rows, inverse, k, live)
 
 
-def _combine_fwd(rows, order, inverse, k):
-    return _sum_per_token("combine_fwd", rows, inverse, k), order
+def _combine_fwd(rows, order, inverse, live, k):
+    return _sum_per_token("combine_fwd", rows, inverse, k, live), order
 
 
 def _combine_bwd(k, order, d_out):
-    return _rows_of_tokens("combine_bwd", d_out, order, k), None, None
+    return (_rows_of_tokens("combine_bwd", d_out, order, k), None, None,
+            None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def sorted_by_expert(experts):
-    """The T x k (token, slot) pairs in a stable order by expert.
+def sorted_by_expert(experts, first=0, num_experts=None):
+    """The T x k (token, slot) pairs in a stable order by expert, the
+    experts counted from ``first`` round ``num_experts`` (so that the
+    experts a layer holds, ``first`` onward, sort first).
     Returns (order, inverse): pair ``order[r]`` sits in sorted row r,
     pair j in sorted row ``inverse[j]``; pair j is token ``j // k``."""
     flat = experts.reshape(-1)
+    if first:
+        flat = (flat - first) % num_experts
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     return order, jnp.argsort(order).astype(jnp.int32)
 
 
-def grouped_ffn(rows, row_gates, group_sizes, wi, wo, wg=None):
+def grouped_ffn(rows, row_gates, group_sizes, wi, wo, wg=None, live=None):
     """Each expert's feed-forward over its own rows, times the row's
     gate: ``rows`` (N, M) sorted by expert, ``row_gates`` (N,) float32,
     ``group_sizes`` (E,) rows each; weights (E, M, F), (E, F, M). With
     ``wg`` the experts are gated (SwiGLU: ``silu(rows wg) * (rows
     wi)``), else GELU. The gate multiplies the activation, F wide, in
     float32, and the product is rounded once to the rows' dtype; the
-    gates' gradient is that fusion's reduction over F."""
-    up = lax.ragged_dot(rows, wi, group_sizes).astype(jnp.float32)
+    gates' gradient is that fusion's reduction over F.
+
+    ``live`` = ``sum(group_sizes)`` where that is below N: the rows past
+    it belong to no group and a grouped matmul leaves its result there
+    UNWRITTEN, forward and in its input's gradient. So both up
+    projections are zeroed there before the activation reads them, and
+    so is ``hidden``, whose select's transpose zeroes the gradient the
+    down projection hands back. The caller masks the two ends
+    (``_combine`` forward, ``_dispatch`` backward)."""
+    def live_rows(x):
+        if live is None:
+            return x
+        row = lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
+        return jnp.where(row < live, x, 0)
+
+    up = live_rows(lax.ragged_dot(rows, wi, group_sizes)).astype(jnp.float32)
     if wg is None:
         hidden = nn.gelu(up)
     else:
-        hidden = nn.silu(lax.ragged_dot(rows, wg, group_sizes)
+        hidden = nn.silu(live_rows(lax.ragged_dot(rows, wg, group_sizes))
                          .astype(jnp.float32)) * up
-    hidden = (hidden * row_gates[:, None]).astype(rows.dtype)
+    hidden = live_rows((hidden * row_gates[:, None]).astype(rows.dtype))
     return lax.ragged_dot(hidden, wo, group_sizes)
 
 
@@ -264,72 +338,128 @@ class MoeMlp(nn.Module):
     (module docstring). Expert weights carry ``expert``-axis
     partitioning metadata. ``assignment`` (T, k) forces the routing.
 
+    With ``BlockSpec.experts_held`` the weights are those of experts
+    ``first_expert_held`` onward and the output is THEIR part of the
+    routed sum. ``shared`` is a feed-forward that every token goes
+    through beside the routed sum (the model's block hands in a dense
+    one of ``shared_experts`` experts' width, made with ``parent=None``
+    so that its weights live under this layer's ``shared``). A
+    ``sigmoid_bias`` router reads its correction bias from the
+    ``moe_state`` collection (``router_bias`` (E,), zeros at ``init``);
+    ``updated_router_bias`` makes the next step's.
+
     Sown into the ``moe`` collection on every call outside ``init``:
-    ``load_balance`` and ``z_loss`` (``aux_losses``),
     ``tokens_per_expert`` (E,), which sums to T x k whatever the
-    imbalance, and ``experts`` (T, k), the choice made. ``sown_stats``
+    imbalance, ``rows_held``, the pairs whose expert is held here,
+    ``experts`` (T, k), the choice made, and under the softmax router
+    ``load_balance`` and ``z_loss`` (``aux_losses``). ``sown_stats``
     stacks them over layers; what a step does not use costs nothing."""
 
     cfg: object  # TransformerConfig
+    shared: Optional[nn.Module] = None
 
     @nn.compact
     def __call__(self, x, assignment=None):
-        cfg = self.cfg
-        e, k = cfg.block.num_experts, cfg.block.experts_per_token
+        cfg, spec = self.cfg, self.cfg.block
+        e, k = spec.num_experts, spec.experts_per_token
+        held, first = spec.experts_held or e, spec.first_expert_held
         b, s, m = x.shape
         t = b * s
         init = nn.initializers.normal(0.02)
         experts_init = nn.with_partitioning(init, ("expert", None, None))
+        _M_EXPERTS.labels(kind="held").inc(held)
+        _M_EXPERTS.labels(kind="routed").inc(e)
 
         wr = self.param("router", nn.with_partitioning(init, (None, None)),
                         (m, e), jnp.float32)
-        wi = self.param("wi", experts_init, (e, m, cfg.d_ff), jnp.float32)
-        wo = self.param("wo", experts_init, (e, cfg.d_ff, m), jnp.float32)
+        wi = self.param("wi", experts_init, (held, m, cfg.d_ff), jnp.float32)
+        wo = self.param("wo", experts_init, (held, cfg.d_ff, m), jnp.float32)
         wg = None
-        if cfg.block.ffn == "swiglu":
-            wg = self.param("wg", experts_init, (e, m, cfg.d_ff),
+        if spec.ffn == "swiglu":
+            wg = self.param("wg", experts_init, (held, m, cfg.d_ff),
                             jnp.float32).astype(cfg.dtype)
+        bias = None
+        if spec.router == "sigmoid_bias":
+            bias = self.variable("moe_state", "router_bias", jnp.zeros,
+                                 (e,), jnp.float32).value
 
         tokens = x.reshape(t, m)
         with jax.named_scope(SCOPE_MOE_ROUTER):
-            # The choice of 8 among 64 is discrete: the logits are made
+            # The choice of k among E is discrete: the logits are made
             # in float32 whatever the compute dtype.
             logits = jnp.dot(tokens.astype(jnp.float32), wr,
                              precision=lax.Precision.HIGHEST)
-            probs, gates, experts = route(logits, k, assignment)
+            scores, gates, experts = route(
+                logits, k, assignment, scoring=spec.router, bias=bias,
+                norm_topk=spec.norm_topk, scale=spec.routed_scale)
             counts = jnp.sum(jax.nn.one_hot(experts.reshape(-1), e,
                                             dtype=jnp.int32), axis=0)
-            load_balance, z_loss = aux_losses(logits, probs, counts)
+            if spec.router == "softmax":
+                load_balance, z_loss = aux_losses(logits, scores, counts)
+            # The held experts' rows are the first ``live`` sorted rows,
+            # in ``held`` ragged groups; all T x k where all are held.
+            sizes, live, rows_held = counts, None, t * k
+            if held < e:
+                sizes = counts[first:first + held]
+                live = rows_held = jnp.sum(sizes)
         with jax.named_scope(SCOPE_MOE_DISPATCH):
-            order, inverse = sorted_by_expert(experts)
-            rows = _dispatch(tokens, order, inverse, k)
+            order, inverse = sorted_by_expert(experts, first, e)
+            rows = _dispatch(tokens, order, inverse, live, k)
             row_gates = _permute(gates.reshape(-1), order, inverse)
         with jax.named_scope(SCOPE_MOE_EXPERTS):
-            out = grouped_ffn(rows, row_gates, counts, wi.astype(cfg.dtype),
-                              wo.astype(cfg.dtype), wg)
+            out = grouped_ffn(rows, row_gates, sizes, wi.astype(cfg.dtype),
+                              wo.astype(cfg.dtype), wg, live)
         with jax.named_scope(SCOPE_MOE_COMBINE):
-            out = _combine(out, order, inverse, k)
+            out = _combine(out, order, inverse, live, k)
+        if self.shared is not None:
+            with jax.named_scope(SCOPE_MOE_SHARED):
+                out = out + self.shared(tokens)
         if not self.is_initializing():
-            self.sow("moe", "load_balance", load_balance)
-            self.sow("moe", "z_loss", z_loss)
+            if spec.router == "softmax":
+                self.sow("moe", "load_balance", load_balance)
+                self.sow("moe", "z_loss", z_loss)
             self.sow("moe", "tokens_per_expert", counts)
+            self.sow("moe", "rows_held", rows_held)
             self.sow("moe", "experts", experts)
         return out.reshape(b, s, m)
 
 
-def sown_stats(variables):
-    """What the expert layers of one ``Transformer.apply(...,
-    mutable=["moe"])`` sowed, stacked over layers in order:
-    ``{"load_balance": (L,), "z_loss": (L,), "tokens_per_expert":
-    (L, E), "experts": (L, T, k)}``."""
+def _layer_number(path):     # ('layer_10', 'moe', <name>): 10
+    return int(path[0].rsplit("_", 1)[1])
+
+
+def updated_router_bias(state, tokens_per_expert, rate, axis=DATA_AXIS):
+    """The ``moe_state`` collection after one step: each expert layer's
+    ``router_bias`` through ``corrected_bias`` with that layer's row of
+    ``tokens_per_expert`` ((L, E), ``sown_stats``' order). The rule is
+    over the step's whole batch: where the trace binds ``axis`` (the
+    mesh axes the batch is split over) with more than one chip, the
+    counts are summed over it first, so that every replica carries the
+    same bias on; otherwise nothing is traced for it."""
     from flax import traverse_util
 
-    def layer_number(item):     # ('layer_10', 'moe', <name>): 10
-        return int(item[0][0].rsplit("_", 1)[1])
+    try:
+        if traced_axis_size(axis) > 1:
+            tokens_per_expert = lax.psum(tokens_per_expert, axis)
+    except NameError:       # not inside a shard_map over ``axis``
+        pass
+    flat = traverse_util.flatten_dict(state)
+    for row, path in enumerate(sorted(flat, key=_layer_number)):
+        flat[path] = corrected_bias(flat[path], tokens_per_expert[row], rate)
+    return traverse_util.unflatten_dict(flat)
+
+
+def sown_stats(variables):
+    """What the expert layers of one ``Transformer.apply(...,
+    mutable=["moe"])`` sowed, stacked over the expert layers in order:
+    ``{"tokens_per_expert": (L, E), "rows_held": (L,), "experts":
+    (L, T, k)}`` and, under the softmax router, ``{"load_balance":
+    (L,), "z_loss": (L,)}``."""
+    from flax import traverse_util
 
     by_name = {}
     for path, (value,) in sorted(
             traverse_util.flatten_dict(variables["moe"]).items(),
-            key=layer_number):
+            key=lambda item: _layer_number(item[0])):
         by_name.setdefault(path[-1], []).append(value)
     return {name: jnp.stack(values) for name, values in by_name.items()}

@@ -61,6 +61,8 @@ def one_chip(topo):
     ((1, 16384, 8, 64), jnp.bfloat16),    # panels past the default VMEM
     ((1, 8192, 8, 64), jnp.float32),      # limit: the kernels ask for more
     ((2, 1000, 8, 64), jnp.bfloat16),     # ragged: padded keys and rows
+    ((1, 8192, 20, 256), jnp.bfloat16),   # glm47f-s8192-ep8-c1: head_dim
+    ((1, 1100, 4, 256), jnp.bfloat16),    # 256, and with padded keys
 ])
 def test_kernels_lower_for_v5e(one_chip, shape, dtype):
     """Forward, dK/dV and dQ at the default tiles, (B, S, H, D) causal:
@@ -163,3 +165,32 @@ def test_expert_layer_materialises_no_float32_rows(one_chip):
         read += re.findall(r"%%%s = bf16\[(\d+),%d\]"
                            % (re.escape(operand), m), text)
     assert sorted(int(n) for n in read) == [t, t, t * k, t * k], read
+
+
+def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
+    """GLM-4.7-Flash's step at its tiny sizes (latent attention, a dense
+    block, two expert blocks that hold 2 of 16 experts, every block
+    recomputed), compiled for a described v5e: the forward kernel runs
+    TWICE a layer, the backward kernels once, and the dead rows of the
+    grouped matmuls are masked by selects, not by a second path."""
+    from benchmark import cell as cells
+
+    monkeypatch.setattr(pallas_attention, "_should_interpret",
+                        lambda interpret: False)
+    cell = cells.load("glm47f-s8192-ep8-c1", tiny=True)
+    asm = cells.assemble(cell, topo.devices)
+    text = asm.step.lower(*cells.abstract_step_args(asm)).compile().as_text()
+    layers = cell.config["num_hidden_layers"]
+    calls = {name: len(re.findall(r"^\s*(?:ROOT )?%%%s[.\d]* = " % name, text,
+                                  re.M))
+             for name in (introspect.KERNEL_FLASH_FWD,
+                          introspect.KERNEL_FLASH_DKV,
+                          introspect.KERNEL_FLASH_DQ)}
+    assert calls == {introspect.KERNEL_FLASH_FWD: 2 * layers,
+                     introspect.KERNEL_FLASH_DKV: layers,
+                     introspect.KERNEL_FLASH_DQ: layers}, calls
+    assert asm.model.kernels(1)["fwd"][0] == 2 * layers
+    for scope in (introspect.SCOPE_MLA_LATENT, introspect.SCOPE_MOE_SHARED,
+                  introspect.SCOPE_MOE_EXPERTS, "rematted_computation"):
+        assert scope in text, scope
+    assert "all-reduce" not in text

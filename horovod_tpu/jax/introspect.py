@@ -60,6 +60,11 @@ SCOPE_MOE_SHARED = "hvd_moe_shared"
 KERNEL_FLASH_FWD = "hvd_flash_fwd"
 KERNEL_FLASH_DKV = "hvd_flash_dkv"
 KERNEL_FLASH_DQ = "hvd_flash_dq"
+# ``checkpoint_name``s of what the forward kernel made, as the backward
+# kernels read it: the (B, H, S, D) output and the (B, H, S) float32
+# log-sum-exp. ``Transformer``'s ``remat`` keeps exactly these.
+SAVED_FLASH_OUT = "hvd_flash_out"
+SAVED_FLASH_LSE = "hvd_flash_lse"
 
 # Primitive names the framework's in-graph data plane lowers to.
 # (lax.psum_scatter traces as the "reduce_scatter" primitive.)

@@ -29,6 +29,8 @@ from the shardings.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import logging
 from typing import Any, Optional
 
 import jax
@@ -36,14 +38,27 @@ import jax.numpy as jnp
 import flax.linen as nn
 from flax.linen import partitioning as nn_partitioning
 from horovod_tpu.jax.introspect import (
+    SAVED_FLASH_LSE,
+    SAVED_FLASH_OUT,
     SCOPE_EMBED,
     SCOPE_LOGITS,
     SCOPE_MLA_LATENT,
     SCOPE_ROPE,
 )
 from horovod_tpu.parallel.mesh import traced_axis_size
+from horovod_tpu.utils import metrics as _metrics
+
+logger = logging.getLogger("horovod_tpu")
 
 param_with_axes = nn.with_partitioning
+
+# Counted at trace time: the blocks traced under ``cfg.remat``, by what
+# the recomputation keeps from forward to backward.
+_M_REMAT_BLOCKS = _metrics.counter(
+    "hvd_remat_blocks_total",
+    "Decoder blocks traced under recomputation, by what they keep from "
+    "forward to backward (counted at trace time, not per device step).",
+    ("keeps",))
 
 
 def _axis_bound(axis) -> bool:
@@ -135,6 +150,10 @@ class TransformerConfig:
     # 'ulysses' (all_to_all head/seq re-sharding).
     attention: str = "dense"
     seq_axis: Optional[str] = None  # mesh axis for ring/ulysses attention
+    # Recompute each block in the backward pass, except what its flash
+    # kernel made: the output and the log-sum-exp stay from forward to
+    # backward (B x S x H x D of ``dtype`` + B x H x S float32 a layer),
+    # so the kernel runs once. Any other ``attention`` keeps nothing.
     remat: bool = False
     # None = auto (one-hot lookup only under manual subgroups, see
     # _use_onehot_embed); True/False forces the lookup style.
@@ -351,6 +370,28 @@ class Block(nn.Module):
         return x
 
 
+@functools.cache
+def _log_remat(cfg, keeps):
+    logger.info(
+        "Transformer remat: %d blocks recomputed in the backward pass, "
+        "each keeps %s (attention=%r)", cfg.n_layers, keeps, cfg.attention)
+
+
+def _remat_block(cfg):
+    """``Block`` under recomputation that keeps what the attention
+    kernel made (the two names ops/pallas_attention.py gives its
+    output and log-sum-exp): the backward kernels read them, so the
+    recomputed forward has no use for a second run of the kernel.
+    Where nothing carries the names the policy saves nothing, which is
+    plain recomputation. Counted and logged at trace time."""
+    keeps = "flash_out+lse" if cfg.attention == "flash" else "nothing"
+    _M_REMAT_BLOCKS.labels(keeps=keeps).inc(cfg.n_layers)
+    _log_remat(cfg, keeps)
+    policy = jax.checkpoint_policies.save_only_these_names(
+        SAVED_FLASH_OUT, SAVED_FLASH_LSE)
+    return nn.remat(Block, policy=policy)
+
+
 class Transformer(nn.Module):
     cfg: TransformerConfig
 
@@ -402,9 +443,7 @@ class Transformer(nn.Module):
                 else:
                     pos_slice = pos.astype(cfg.dtype)[:s_local]
                 x = x + pos_slice[None]
-        block = Block
-        if cfg.remat:
-            block = nn.remat(Block)
+        block = _remat_block(cfg) if cfg.remat else Block
         for i in range(cfg.n_layers):
             dense = i < cfg.block.first_dense_layers
             x = block(cfg, cfg.block.dense_ff if dense else None,

@@ -44,6 +44,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -416,7 +417,11 @@ def _compiler_params(panel_rows, d, dtype, block_q, block_k):
 
 @_scoped
 def _flash_fwd_impl(q, k, v, causal, block_q, block_k, scale, interpret):
-    from horovod_tpu.jax.introspect import KERNEL_FLASH_FWD
+    from horovod_tpu.jax.introspect import (
+        KERNEL_FLASH_FWD,
+        SAVED_FLASH_LSE,
+        SAVED_FLASH_OUT,
+    )
 
     # q, k, v here are (B, H, S, D).
     b, h, s, d = q.shape
@@ -443,7 +448,13 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, scale, interpret):
         interpret=_should_interpret(interpret),
         name=KERNEL_FLASH_FWD,
     )(qp, kp, vp)
-    return out[:, :, :s], (q, k, v, out[:, :, :s], lse[:, :, :s, 0])
+    # Named, and ONE value as the output and as the residual: a
+    # recomputation that saves these names (models/transformer.py) has
+    # no reader left for a second run of the kernel. Outside one the
+    # name is an identity that lowers to nothing.
+    out = checkpoint_name(out[:, :, :s], SAVED_FLASH_OUT)
+    lse = checkpoint_name(lse[:, :, :s, 0], SAVED_FLASH_LSE)
+    return out, (q, k, v, out, lse)
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, scale, interpret):

@@ -171,8 +171,19 @@ def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
     """GLM-4.7-Flash's step at its tiny sizes (latent attention, a dense
     block, two expert blocks that hold 2 of 16 experts, every block
     recomputed), compiled for a described v5e: the forward kernel runs
-    TWICE a layer, the backward kernels once, and the dead rows of the
-    grouped matmuls are masked by selects, not by a second path."""
+    ONCE a layer, as the backward kernels do, because the recomputation
+    keeps its output and log-sum-exp (``models/transformer.py``
+    ``_remat_block``); the rest of a block is still recomputed; and the
+    dead rows of the grouped matmuls are masked by selects, not by a
+    second path.
+
+    The benchmark's builder still DECLARES two forward calls a layer
+    under ``remat`` (``benchmark/builders/glm4_moe_lite.py``
+    ``kernels()``, written when plain recomputation ran the kernel
+    twice): twice what runs, so ``kernel.flash_roofline`` in the GLM
+    cell over-reads until that count comes from the trace (ROADMAP D13
+    (9)); the per-kernel rooflines divide by the calls the trace holds
+    and are right. Both numbers are held here so that the repair shows."""
     from benchmark import cell as cells
 
     monkeypatch.setattr(pallas_attention, "_should_interpret",
@@ -186,10 +197,15 @@ def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
              for name in (introspect.KERNEL_FLASH_FWD,
                           introspect.KERNEL_FLASH_DKV,
                           introspect.KERNEL_FLASH_DQ)}
-    assert calls == {introspect.KERNEL_FLASH_FWD: 2 * layers,
-                     introspect.KERNEL_FLASH_DKV: layers,
-                     introspect.KERNEL_FLASH_DQ: layers}, calls
-    assert asm.model.kernels(1)["fwd"][0] == 2 * layers
+    assert calls == dict.fromkeys(calls, layers), calls
+    assert asm.model.kernels(1)["fwd"][0] == 2 * layers    # declared, stale
+    assert asm.model.kernels(1)["dkv"][0] == layers
+    # No forward kernel under the recomputed forward any more.
+    scopes = introspect.instruction_scopes(text)
+    forward = [scope for name, scope in scopes.items()
+               if name.startswith(introspect.KERNEL_FLASH_FWD)]
+    assert len(forward) == layers, forward
+    assert not [s for s in forward if "rematted_computation" in s], forward
     for scope in (introspect.SCOPE_MLA_LATENT, introspect.SCOPE_MOE_SHARED,
                   introspect.SCOPE_MOE_EXPERTS, "rematted_computation"):
         assert scope in text, scope

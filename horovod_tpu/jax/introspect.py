@@ -42,6 +42,10 @@ SCOPE_ROPE = "rope"              # attention: rotary positions on q, k
 # LatentAttention: both down-projections with their norms, both
 # up-projections, and q, k, v put together (RoPE keeps ``rope``).
 SCOPE_MLA_LATENT = "hvd_mla_latent"
+# SelfAttention with ``BlockSpec.attn_gate``: the sigmoid of the gate
+# projection times the attention output (the projection itself is the
+# module's own).
+SCOPE_ATTN_GATE = "hvd_attn_gate"
 # The expert layer (parallel/moe.py), inside the ``moe`` module's scope.
 SCOPE_MOE_ROUTER = "hvd_moe_router"      # logits, softmax, top-k, aux losses
 # The sorts (the gates ride one into row order); rows gathered from the

@@ -16,6 +16,12 @@ RMSNorm on q and k, an output head of its own; GLM-4.7-Flash's is
 latent attention (``LatentAttention``), leading dense blocks of their
 own width, then expert blocks with a sigmoid router under a correction
 bias, a shared expert, and only this chip's share of the experts held.
+A ``BlockSpec`` may also give the heads a width of their own, fewer
+key/value heads than query heads, a per-layer pattern of sliding-window
+and full attention (with rotary positions in some kinds only), a norm
+on q and k per head, a sigmoid gate on the attention output, norms on
+each branch's output and a multiplier on the embedding: what the
+``afmoe`` family's ``config.json`` keys say.
 
 Param layout (tensor parallel over 'model'):
 - attention QKV projections shard the head dim;
@@ -40,6 +46,7 @@ from flax.linen import partitioning as nn_partitioning
 from horovod_tpu.jax.introspect import (
     SAVED_FLASH_LSE,
     SAVED_FLASH_OUT,
+    SCOPE_ATTN_GATE,
     SCOPE_EMBED,
     SCOPE_LOGITS,
     SCOPE_MLA_LATENT,
@@ -51,6 +58,18 @@ from horovod_tpu.utils import metrics as _metrics
 logger = logging.getLogger("horovod_tpu")
 
 param_with_axes = nn.with_partitioning
+
+# The two kinds of attention layer, as ``config.json`` ``layer_types``
+# spells them.
+FULL_ATTENTION, SLIDING_ATTENTION = "full_attention", "sliding_attention"
+
+# Counted at trace time: the attention layers one traced model makes, by
+# kind.
+_M_ATTN_LAYERS = _metrics.counter(
+    "hvd_attn_layers_total",
+    "Attention layers per traced model, by kind (full_attention / "
+    "sliding_attention; counted at trace time, not per device step).",
+    ("kind",))
 
 # Counted at trace time: the blocks traced under ``cfg.remat``, by what
 # the recomputation keeps from forward to backward.
@@ -100,7 +119,24 @@ class BlockSpec:
     positions: str = "learned"       # | 'rope' (rotate-half, on q and k)
     rope_theta: float = 10000.0
     qk_norm: bool = False            # the norm on q and k, over all heads
+    qk_norm_per_head: bool = False   # ... over each head's dims instead
     tied_head: bool = True           # the output projection is ``embed``
+    # The heads: 0 = ``d_model // n_heads`` wide, and as many key/value
+    # heads as query heads. With fewer, query head h reads key/value
+    # head ``h // (n_heads // n_kv_heads)`` and the projections are
+    # ``wq`` and ``wkv`` instead of ``wqkv``.
+    head_dim: int = 0
+    n_kv_heads: int = 0
+    # One kind a layer, FULL_ATTENTION or SLIDING_ATTENTION (a query
+    # sees the ``sliding_window`` keys up to itself); () = all full.
+    layer_types: tuple = ()
+    sliding_window: int = 0
+    # The kinds whose q and k carry the rotary positions; None = every
+    # layer, if ``positions`` is 'rope'.
+    rope_layers: Optional[tuple] = None
+    attn_gate: bool = False          # attention out * sigmoid(x Wg), per dim
+    post_norms: bool = False         # a norm on each branch's OUTPUT too
+    embed_scale: float = 1.0         # the embedding's output times this
     # 0 = one dense feed-forward; >0 = that many experts, each token
     # through its ``experts_per_token`` most probable (parallel/moe.py).
     num_experts: int = 0
@@ -191,25 +227,38 @@ def rope(x, first_position, theta):
                            -1).astype(x.dtype)
 
 
-def _dense_causal_attention(q, k, v, dtype):
-    # q, k, v: (B, S, H, D)
+def _dense_causal_attention(q, k, v, dtype, window=None):
+    # q: (B, S, H, D); k, v: (B, S, H_kv, D), H_kv a divisor of H.
     d = q.shape[-1]
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(d).astype(q.dtype)
     s = scores.shape[-1]
     causal = jnp.tril(jnp.ones((s, s), bool))
+    if window is not None:
+        causal &= ~jnp.tril(jnp.ones((s, s), bool), -window)
     scores = jnp.where(causal[None, None], scores, jnp.asarray(-1e9, scores.dtype))
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _attend(cfg, q, k, v):
-    """Causal attention of q, k, v (B, S, H, D) by ``cfg.attention``."""
+def _attend(cfg, q, k, v, window=None):
+    """Causal attention of q (B, S, H, D) over k, v (B, S, H_kv, D) by
+    ``cfg.attention``; under ``window`` a query sees that many keys up
+    to itself."""
     if cfg.attention == "dense":
-        return _dense_causal_attention(q, k, v, cfg.dtype)
+        return _dense_causal_attention(q, k, v, cfg.dtype, window)
     if cfg.attention == "flash":
         from horovod_tpu.ops.pallas_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=True).astype(cfg.dtype)
+        return flash_attention(q, k, v, causal=True,
+                               window=window).astype(cfg.dtype)
+    if window is not None or k.shape[2] != q.shape[2]:
+        raise ValueError(
+            "attention=%r has no sliding window and no grouped key/value "
+            "heads (window %r, %d query heads over %d): use 'flash' or "
+            "'dense'" % (cfg.attention, window, q.shape[2], k.shape[2]))
     if cfg.attention == "ring":
         from horovod_tpu.parallel.sequence import ring_attention
 
@@ -222,37 +271,66 @@ def _attend(cfg, q, k, v):
 
 
 class SelfAttention(nn.Module):
+    """Multi-head attention by ``cfg.block``. ``window`` makes this
+    layer a sliding one; ``rotary`` says whether this layer's q and k
+    carry the rotary positions (``Block`` reads both off the layer's
+    kind)."""
+
     cfg: TransformerConfig
+    window: Optional[int] = None
+    rotary: bool = True
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.cfg
-        h, d = cfg.n_heads, cfg.d_model // cfg.n_heads
+        cfg, spec = self.cfg, self.cfg.block
+        h, m = cfg.n_heads, cfg.d_model
+        d = spec.head_dim or m // h
+        h_kv = spec.n_kv_heads or h
         init = nn.initializers.normal(0.02)
-        wqkv = self.param(
-            "wqkv",
-            param_with_axes(init, (None, None, "model", None)),
-            (3, cfg.d_model, h, d), jnp.float32)
-        wo = self.param(
-            "wo",
-            param_with_axes(init, ("model", None, None)),
-            (h, d, cfg.d_model), jnp.float32)
-        wqkv = wqkv.astype(cfg.dtype)
-        wo = wo.astype(cfg.dtype)
-        q = jnp.einsum("bsm,mhd->bshd", x, wqkv[0])
-        k = jnp.einsum("bsm,mhd->bshd", x, wqkv[1])
-        v = jnp.einsum("bsm,mhd->bshd", x, wqkv[2])
-        if cfg.block.qk_norm:
+
+        def weight(name, axes, shape):
+            return self.param(name, param_with_axes(init, axes), shape,
+                              jnp.float32).astype(cfg.dtype)
+
+        if h_kv == h:
+            wqkv = weight("wqkv", (None, None, "model", None), (3, m, h, d))
+        else:
+            wq = weight("wq", (None, "model", None), (m, h, d))
+            wkv = weight("wkv", (None, None, "model", None), (2, m, h_kv, d))
+        wo = weight("wo", ("model", None, None), (h, d, m))
+
+        def projected(i):
+            # 0, 1, 2: q, k, v. The slice of ``wqkv`` stays inside its
+            # projection: the order of operations in the lowered step
+            # of every configuration with one ``wqkv``.
+            if h_kv == h:
+                w = wqkv[i]
+            else:
+                w = wq if i == 0 else wkv[i - 1]
+            return jnp.einsum("bsm,mhd->bshd", x, w)
+
+        q, k, v = projected(0), projected(1), projected(2)
+        if spec.qk_norm_per_head:
+            # One scale vector of ``d`` for all heads of q, one for k.
+            q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
+        elif spec.qk_norm:
             # Over the whole width, before the heads are told apart.
             b, s = x.shape[:2]
             q = _norm(cfg, "q_norm")(q.reshape(b, s, h * d)).reshape(q.shape)
-            k = _norm(cfg, "k_norm")(k.reshape(b, s, h * d)).reshape(k.shape)
-        if cfg.block.positions == "rope":
+            k = _norm(cfg, "k_norm")(k.reshape(b, s, h_kv * d)).reshape(
+                k.shape)
+        if spec.positions == "rope" and self.rotary:
             with jax.named_scope(SCOPE_ROPE):
                 first = _first_position(cfg, x.shape[1])
-                q = rope(q, first, cfg.block.rope_theta)
-                k = rope(k, first, cfg.block.rope_theta)
-        return jnp.einsum("bshd,hdm->bsm", _attend(cfg, q, k, v), wo)
+                q = rope(q, first, spec.rope_theta)
+                k = rope(k, first, spec.rope_theta)
+        out = _attend(cfg, q, k, v, self.window)
+        if spec.attn_gate:
+            gate = jnp.einsum("bsm,mhd->bshd", x, weight(
+                "wgate", (None, "model", None), (m, h, d)))
+            with jax.named_scope(SCOPE_ATTN_GATE):
+                out = out * nn.sigmoid(gate)
+        return jnp.einsum("bshd,hdm->bsm", out, wo)
 
 
 def _to_every_head(k_pe, n_heads):
@@ -342,18 +420,44 @@ class Mlp(nn.Module):
 
 class Block(nn.Module):
     """One decoder block. ``dense_width`` makes its feed-forward a dense
-    one of that width whatever the model's other blocks carry."""
+    one of that width whatever the model's other blocks carry;
+    ``attention_type`` is its entry of ``BlockSpec.layer_types``. With
+    ``post_norms`` each branch's output passes a norm of its own before
+    it joins the residual stream."""
 
     cfg: TransformerConfig
     dense_width: Optional[int] = None
+    attention_type: str = FULL_ATTENTION
+
+    def _attention(self):
+        cfg, spec, kind = self.cfg, self.cfg.block, self.attention_type
+        if kind not in (FULL_ATTENTION, SLIDING_ATTENTION):
+            raise ValueError("Unknown attention layer type %r" % (kind,))
+        _M_ATTN_LAYERS.labels(kind=kind).inc()
+        sliding = kind == SLIDING_ATTENTION
+        if spec.attention_kind == "latent":
+            if sliding:
+                raise ValueError("latent attention has no sliding window")
+            return LatentAttention(cfg, name="attn")
+        if sliding and spec.sliding_window < 1:
+            raise ValueError("a sliding_attention layer needs "
+                             "BlockSpec.sliding_window")
+        return SelfAttention(
+            cfg, spec.sliding_window if sliding else None,
+            spec.rope_layers is None or kind in spec.rope_layers,
+            name="attn")
 
     @nn.compact
     def __call__(self, x, assignment=None):
         cfg = self.cfg
-        attention = {"heads": SelfAttention,
-                     "latent": LatentAttention}[cfg.block.attention_kind]
+
+        def joined(x, name, branch):
+            if cfg.block.post_norms:
+                branch = _norm(cfg, name)(branch)
+            return x + branch
+
         y = _norm(cfg, "ln1")(x)
-        x = x + attention(cfg, name="attn")(y)
+        x = joined(x, "post_attn_norm", self._attention()(y))
         y = _norm(cfg, "ln2")(x)
         if cfg.block.num_experts > 0 and self.dense_width is None:
             from horovod_tpu.parallel.moe import MoeMlp
@@ -364,10 +468,10 @@ class Block(nn.Module):
                 # ``moe/shared``, its time the ``moe`` scope's.
                 shared = Mlp(cfg, cfg.block.shared_experts * cfg.d_ff,
                              parent=None)
-            x = x + MoeMlp(cfg, shared, name="moe")(y, assignment)
-        else:
-            x = x + Mlp(cfg, self.dense_width, name="mlp")(y)
-        return x
+            return joined(x, "post_mlp_norm",
+                          MoeMlp(cfg, shared, name="moe")(y, assignment))
+        return joined(x, "post_mlp_norm",
+                      Mlp(cfg, self.dense_width, name="mlp")(y))
 
 
 @functools.cache
@@ -432,6 +536,8 @@ class Transformer(nn.Module):
                                embed.astype(cfg.dtype))
             else:
                 x = embed.astype(cfg.dtype)[tokens]
+            if cfg.block.embed_scale != 1.0:
+                x = x * jnp.asarray(cfg.block.embed_scale, cfg.dtype)
             if cfg.block.positions == "learned":
                 s_local = tokens.shape[1]
                 if cfg.seq_axis is not None and _axis_bound(cfg.seq_axis):
@@ -444,9 +550,13 @@ class Transformer(nn.Module):
                     pos_slice = pos.astype(cfg.dtype)[:s_local]
                 x = x + pos_slice[None]
         block = _remat_block(cfg) if cfg.remat else Block
+        kinds = cfg.block.layer_types or (FULL_ATTENTION,) * cfg.n_layers
+        if len(kinds) != cfg.n_layers:
+            raise ValueError("BlockSpec.layer_types names %d layers, the "
+                             "model has %d" % (len(kinds), cfg.n_layers))
         for i in range(cfg.n_layers):
             dense = i < cfg.block.first_dense_layers
-            x = block(cfg, cfg.block.dense_ff if dense else None,
+            x = block(cfg, cfg.block.dense_ff if dense else None, kinds[i],
                       name="layer_%d" % i)(
                 x, None if assignments is None else assignments[i])
         x = _norm(cfg, "ln_f")(x)

@@ -28,7 +28,16 @@ Longer sequences shard S across chips via ring/Ulysses attention
 
 Causal masking uses the decode convention for rectangular inputs: the
 end of q aligns with the end of kv (query row r has absolute position
-r + kv_len - q_len).
+r + kv_len - q_len). A ``window`` of W keeps, of the keys at or before
+a query at position p, those after ``p - W``: the loops then leave out
+the tiles below the window as well as those above the diagonal.
+
+Grouped-query heads: ``k`` and ``v`` may carry fewer heads than ``q``
+(``H % H_kv == 0``; query head h reads key/value head ``h // (H //
+H_kv)``). They stay ``H_kv`` heads wide in HBM: the forward and dQ index
+the K/V panel by the query head's group, and dK/dV walks the group's
+query heads as a grid axis, adding into a float32 dK/dV panel that
+stays in VMEM until the group is done.
 
 On non-TPU backends (CPU tests, debugging) the kernels run in Pallas
 interpret mode, so the same code path is exercised everywhere; the
@@ -104,9 +113,14 @@ class _Tiles(NamedTuple):
 
     * skipped: wholly above the causal diagonal; the loop bounds leave
       them out;
+    * below: wholly below the ``window`` (every key at or before
+      ``position - window`` of the tile's first row); the loop bounds
+      leave them out too. Counted only where there is a window;
     * full: no masked element (the tile's last key is at or before its
-      first row's position, and it holds no padded key);
-    * edge: the rest (crosses the diagonal, or holds padded keys).
+      first row's position, its first key inside its last row's window,
+      and it holds no padded key);
+    * edge: the rest (crosses the diagonal or the window's lower edge,
+      or holds padded keys).
 
     The kernels compute full and edge tiles with ONE body: on the chip
     the mask's compares and selects ride in vector slots the body
@@ -121,14 +135,17 @@ class _Tiles(NamedTuple):
     follows the decode convention: query row r sits at position
     ``r + q_offset``, ``q_offset = kv_len - q_len``.
 
-    ``key_end`` and ``query_start`` take a Python int (the counts) or a
-    program id (inside a kernel).
+    ``key_start``, ``key_end``, ``query_start`` and ``query_end`` take a
+    Python int (the counts) or a program id (inside a kernel). Without
+    a window the new bounds are the Python ints 0 and ``num_qb``: the
+    program is the one it was.
     """
     block_q: int
     block_k: int
     causal: bool
     q_len: int
     kv_len: int
+    window: Optional[int] = None   # keys after position - window; causal
 
     @property
     def num_qb(self):
@@ -146,18 +163,37 @@ class _Tiles(NamedTuple):
     def padded_keys(self):
         return self.kv_len % self.block_k != 0
 
-    def key_end(self, qi):
+    def key_start(self, qi):
         """Query block ``qi`` against the key blocks (forward, dQ): the
-        first key block it skips; it computes [0, key_end)."""
+        first key block any of its rows sees; those before lie below
+        the window of its first row, and so of every row."""
+        if self.window is None:
+            return 0
+        lowest = qi * self.block_q + self.q_offset - self.window + 1
+        return _least(self.num_kb, _div(_most(lowest, 0), self.block_k))
+
+    def key_end(self, qi):
+        """The first key block past the diagonal that ``qi`` skips; it
+        computes [key_start, key_end)."""
         if not self.causal:
             return self.num_kb
         last_pos = qi * self.block_q + self.q_offset + self.block_q - 1
         return _least(self.num_kb,
                       _div(_most(last_pos + self.block_k, 0), self.block_k))
 
+    def key_inside(self, qi):
+        """The first key block wholly inside the window of EVERY row of
+        ``qi`` (its last row's, padded rows included): [key_start,
+        key_inside) cross the window's lower edge."""
+        if self.window is None:
+            return 0
+        last_pos = qi * self.block_q + self.q_offset + self.block_q - 1
+        return _div(_most(last_pos - self.window + self.block_k, 0),
+                    self.block_k)
+
     def key_full(self, qi):
-        """How many of those are full tiles: [0, key_full) full,
-        [key_full, key_end) edge."""
+        """Where the full tiles end: [key_inside, key_full) full (none
+        if that is empty), the rest of [key_start, key_end) edge."""
         n_full = self.kv_len // self.block_k
         if self.causal:
             first_pos = qi * self.block_q + self.q_offset
@@ -175,14 +211,33 @@ class _Tiles(NamedTuple):
             _div(_most(kj * self.block_k - self.q_offset, 0), self.block_q),
             self.num_qb)
 
+    def query_end(self, kj):
+        """The first query block past the window of key block ``kj``:
+        its first row's window starts after the block's last key. dK/dV
+        computes [query_start, query_end)."""
+        if self.window is None:
+            return self.num_qb
+        last_row = (kj * self.block_k + self.block_k - 1 + self.window - 1
+                    - self.q_offset)
+        return _least(self.num_qb,
+                      _div(_most(last_row + self.block_q, 0), self.block_q))
+
     def counts(self):
-        """{"full", "edge", "skipped"}: tiles of one plane."""
-        full = edge = 0
+        """{"full", "edge", "skipped"} and, under a window, "below":
+        tiles of one plane."""
+        full = edge = below = 0
         for qi in range(self.num_qb):
-            full += self.key_full(qi)
-            edge += self.key_end(qi) - self.key_full(qi)
-        return {"full": full, "edge": edge,
-                "skipped": self.num_qb * self.num_kb - full - edge}
+            start, end = self.key_start(qi), self.key_end(qi)
+            n_full = max(0, self.key_full(qi)
+                         - max(start, self.key_inside(qi)))
+            full += n_full
+            edge += end - start - n_full
+            below += start
+        out = {"full": full, "edge": edge,
+               "skipped": self.num_qb * self.num_kb - full - edge - below}
+        if self.window is not None:
+            out["below"] = below
+        return out
 
     def visible(self, key_axis, q_start, k_start):
         """The mask of the score tile whose first row is ``q_start`` and
@@ -197,8 +252,12 @@ class _Tiles(NamedTuple):
             mask = key < self.kv_len - k_start
         if self.causal:
             row = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - key_axis)
-            below = key - row <= q_start + self.q_offset - k_start
+            behind = key - row      # a key's distance past its row's start
+            ahead = q_start + self.q_offset - k_start
+            below = behind <= ahead
             mask = below if mask is None else mask & below
+            if self.window is not None:
+                mask = mask & (behind > ahead - self.window)
         return mask
 
 
@@ -210,18 +269,19 @@ _M_TILES = _metrics.counter(
 
 
 @functools.cache
-def _log_tiles(kernel, tiles, shape, dtype):
+def _log_tiles(kernel, tiles, shape, dtype, group):
     logger.info(
-        "flash_attention %s %s %s: %d x %d tiles, a plane has %s", kernel,
-        shape, dtype, tiles.block_q, tiles.block_k, tiles.counts())
+        "flash_attention %s %s %s: %d x %d tiles, window %s, %d query "
+        "head(s) a key/value head, a plane has %s", kernel, shape, dtype,
+        tiles.block_q, tiles.block_k, tiles.window, group, tiles.counts())
 
 
-def _count_tiles(kernel, tiles, shape, dtype):
+def _count_tiles(kernel, tiles, shape, dtype, group):
     """Trace time: one plane's tiles by kind into the counter, and one
-    log line per kernel and shape."""
+    log line per kernel, shape, window and group."""
     for kind, n in tiles.counts().items():
         _M_TILES.labels(kernel=kernel, kind=kind).inc(n)
-    _log_tiles(kernel, tiles, tuple(shape), jnp.dtype(dtype).name)
+    _log_tiles(kernel, tiles, tuple(shape), jnp.dtype(dtype).name, group)
 
 
 _NT = (((1,), (1,)), ((), ()))   # a . b^T
@@ -262,7 +322,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, tiles, scale):
     acc = jnp.zeros((block_q, q.shape[1]), jnp.float32)
     m = jnp.full((block_q,), NEG_INF, jnp.float32)
     l = jnp.zeros((block_q,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, tiles.key_end(qi), body, (acc, m, l))
+    acc, m, l = jax.lax.fori_loop(tiles.key_start(qi), tiles.key_end(qi),
+                                  body, (acc, m, l))
 
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[...] = (acc / l_safe[:, None]).astype(o_ref.dtype)
@@ -273,14 +334,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, tiles, scale):
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, tiles, scale):
-    """Grid: (B, H, num_kb). One k/v block vs streamed q blocks. The
-    score tile is KEY-major, (block_k, block_q) = k . q^T, so that p and
-    ds are born in the orientation their matmuls consume (p . dO and
-    ds . q are plain contractions, no tile transpose), and the
-    log-sum-exp and delta come as lane-major (1, block_q) rows."""
+                    dk_ref, dv_ref, *, tiles, scale, group):
+    """Grid: (B, H, num_kb), or (B, H_kv, group, num_kb) where ``group``
+    query heads share a key/value head. One k/v block vs streamed q
+    blocks of ONE query head. The score tile is KEY-major, (block_k,
+    block_q) = k . q^T, so that p and ds are born in the orientation
+    their matmuls consume (p . dO and ds . q are plain contractions, no
+    tile transpose), and the log-sum-exp and delta come as lane-major
+    (1, block_q) rows.
+
+    Grouped: ``dk_ref`` / ``dv_ref`` are the key/value head's whole
+    float32 panels, resident in VMEM while the grid walks the group's
+    query heads; the first head writes its block, the others add."""
     block_q, block_k = tiles.block_q, tiles.block_k
-    kj = pl.program_id(2)
+    kj = pl.program_id(2 if group == 1 else 3)
     k_start = kj * block_k
     k = k_ref[...].astype(jnp.float32)  # (block_k, D)
     v = v_ref[...].astype(jnp.float32)
@@ -303,12 +370,26 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     dk = jnp.zeros(k.shape, jnp.float32)
     dv = jnp.zeros(v.shape, jnp.float32)
-    dk, dv = jax.lax.fori_loop(tiles.query_start(kj), tiles.num_qb, body,
-                               (dk, dv))
+    dk, dv = jax.lax.fori_loop(tiles.query_start(kj), tiles.query_end(kj),
+                               body, (dk, dv))
     # q was pre-scaled at load, so dk = Σ ds (scale·q) is already the
     # gradient of s = scale·q·kᵀ w.r.t. k — no extra scale factor here.
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    if group == 1:
+        dk_ref[...] = dk.astype(dk_ref.dtype)
+        dv_ref[...] = dv.astype(dv_ref.dtype)
+        return
+    member = pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(k_start, block_k), block_k)
+
+    @pl.when(member == 0)
+    def _():
+        dk_ref[rows, :] = dk
+        dv_ref[rows, :] = dv
+
+    @pl.when(member > 0)
+    def _():
+        dk_ref[rows, :] += dk
+        dv_ref[rows, :] += dv
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -336,7 +417,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return dq + _dot(ds, k, _NN)
 
     dq = jnp.zeros(q.shape, jnp.float32)
-    dq = jax.lax.fori_loop(0, tiles.key_end(qi), body, dq)
+    dq = jax.lax.fori_loop(tiles.key_start(qi), tiles.key_end(qi), body, dq)
     dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
 
 
@@ -364,10 +445,10 @@ def _pick_block(s: int, want: int, dtype) -> int:
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, block_q, block_k, scale, interpret):
-    out, _ = _flash_fwd_impl(q, k, v, causal, block_q, block_k, scale,
-                             interpret)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, window, block_q, block_k, scale, interpret):
+    out, _ = _flash_fwd_impl(q, k, v, causal, window, block_q, block_k,
+                             scale, interpret)
     return out
 
 
@@ -394,21 +475,29 @@ def _plane_spec(rows, d):
                         lambda bi, hi, i: (bi, hi, i, 0))
 
 
-def _panel_spec(rows, d):
-    """The whole (S, d) panel of one (batch, head), VMEM-resident."""
+def _panel_spec(rows, d, group=1):
+    """The whole (S, d) panel of one (batch, head), VMEM-resident; with
+    ``group`` query heads a key/value head, the panel of query head
+    ``hi``'s key/value head (the same block for the whole group, so it
+    is fetched once)."""
+    if group == 1:
+        return pl.BlockSpec((None, None, rows, d),
+                            lambda bi, hi, i: (bi, hi, 0, 0))
     return pl.BlockSpec((None, None, rows, d),
-                        lambda bi, hi, i: (bi, hi, 0, 0))
+                        lambda bi, hi, i: (bi, hi // group, 0, 0))
 
 
-def _compiler_params(panel_rows, d, dtype, block_q, block_k):
+def _compiler_params(panel_rows, d, dtype, block_q, block_k, out_rows=0):
     """Mosaic's scoped-VMEM limit for a kernel that keeps two (S, D)
     panels resident: the default (16 MiB on a v5e) wherever the kernel
     fits, because asking for more takes VMEM from XLA's own prefetches
     around the call (1 ms a step at S=4096, PERF.md PR 25); what it
     needs, once it does not. Each panel is double buffered and padded
     to 128 lanes; half a dozen float32 score tiles cover the loop
-    body's temporaries and spills."""
+    body's temporaries and spills. ``out_rows``: the rows of the two
+    float32 output panels the grouped dK/dV keeps resident besides."""
     panels = 2 * 2 * panel_rows * max(d, 128) * jnp.dtype(dtype).itemsize
+    panels += 2 * 2 * out_rows * max(d, 128) * 4
     need = panels + 6 * 4 * block_q * block_k + (2 << 20)
     if need <= (16 << 20):
         return None
@@ -416,28 +505,29 @@ def _compiler_params(panel_rows, d, dtype, block_q, block_k):
 
 
 @_scoped
-def _flash_fwd_impl(q, k, v, causal, block_q, block_k, scale, interpret):
+def _flash_fwd_impl(q, k, v, causal, window, block_q, block_k, scale,
+                    interpret):
     from horovod_tpu.jax.introspect import (
         KERNEL_FLASH_FWD,
         SAVED_FLASH_LSE,
         SAVED_FLASH_OUT,
     )
 
-    # q, k, v here are (B, H, S, D).
+    # q here is (B, H, S, D); k and v (B, H_kv, S_kv, D).
     b, h, s, d = q.shape
-    kv_len = k.shape[2]
+    kv_len, group = k.shape[2], h // k.shape[1]
     qp = _pad_seq(q, block_q)
     kp = _pad_seq(k, block_k)
     vp = _pad_seq(v, block_k)
     sq_pad, sk_pad = qp.shape[2], kp.shape[2]
-    tiles = _Tiles(block_q, block_k, causal, s, kv_len)
-    _count_tiles(KERNEL_FLASH_FWD, tiles, q.shape, q.dtype)
+    tiles = _Tiles(block_q, block_k, causal, s, kv_len, window)
+    _count_tiles(KERNEL_FLASH_FWD, tiles, q.shape, q.dtype, group)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, tiles=tiles, scale=scale),
         grid=(b, h, tiles.num_qb),
-        in_specs=[_plane_spec(block_q, d), _panel_spec(sk_pad, d),
-                  _panel_spec(sk_pad, d)],
+        in_specs=[_plane_spec(block_q, d), _panel_spec(sk_pad, d, group),
+                  _panel_spec(sk_pad, d, group)],
         out_specs=[_plane_spec(block_q, d), _plane_spec(block_q, 1)],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
@@ -457,18 +547,64 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, scale, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, scale, interpret):
-    return _flash_fwd_impl(q, k, v, causal, block_q, block_k, scale,
+def _flash_fwd(q, k, v, causal, window, block_q, block_k, scale, interpret):
+    return _flash_fwd_impl(q, k, v, causal, window, block_q, block_k, scale,
                            interpret)
 
 
+def _dkv_call(tiles, group, b, h, d, sq_pad, sk_pad, dtype, scale, interp):
+    """The dK/dV ``pallas_call``. One query head a key/value head: a
+    (B, H, num_kb) grid, each step writes its own (block_k, D) block.
+    Grouped: a (B, H_kv, group, num_kb) grid; the key/value head's
+    float32 dK/dV panels are the output blocks of all ``group x
+    num_kb`` steps, so they stay in VMEM while every query head of the
+    group adds its part and go to HBM once, ``H_kv`` heads wide."""
+    from horovod_tpu.jax.introspect import KERNEL_FLASH_DKV
+
+    block_q, block_k = tiles.block_q, tiles.block_k
+    kernel = functools.partial(_bwd_dkv_kernel, tiles=tiles, scale=scale,
+                               group=group)
+    rows = (None, None, tiles.num_qb, 1, block_q)
+    if group == 1:
+        rows_panel = pl.BlockSpec(rows, lambda bi, hi, kj: (bi, hi, 0, 0, 0))
+        return pl.pallas_call(
+            kernel, grid=(b, h, tiles.num_kb),
+            in_specs=[_panel_spec(sq_pad, d), _plane_spec(block_k, d),
+                      _plane_spec(block_k, d), _panel_spec(sq_pad, d),
+                      rows_panel, rows_panel],
+            out_specs=[_plane_spec(block_k, d), _plane_spec(block_k, d)],
+            out_shape=[jax.ShapeDtypeStruct((b, h, sk_pad, d), dtype)] * 2,
+            compiler_params=_compiler_params(sq_pad, d, dtype, block_q,
+                                             block_k),
+            interpret=interp, name=KERNEL_FLASH_DKV)
+
+    def of_query_head(*block):
+        return lambda bi, hk, gi, kj: (bi, hk * group + gi) + block
+
+    q_panel = pl.BlockSpec((None, None, sq_pad, d), of_query_head(0, 0))
+    rows_panel = pl.BlockSpec(rows, of_query_head(0, 0, 0))
+    kv_block = pl.BlockSpec((None, None, block_k, d),
+                            lambda bi, hk, gi, kj: (bi, hk, kj, 0))
+    kv_panel = pl.BlockSpec((None, None, sk_pad, d),
+                            lambda bi, hk, gi, kj: (bi, hk, 0, 0))
+    params = _compiler_params(sq_pad, d, dtype, block_q, block_k, sk_pad)
+    return pl.pallas_call(
+        kernel, grid=(b, h // group, group, tiles.num_kb),
+        in_specs=[q_panel, kv_block, kv_block, q_panel, rows_panel,
+                  rows_panel],
+        out_specs=[kv_panel, kv_panel],
+        out_shape=[jax.ShapeDtypeStruct((b, h // group, sk_pad, d),
+                                        jnp.float32)] * 2,
+        compiler_params=params, interpret=interp, name=KERNEL_FLASH_DKV)
+
+
 @_scoped
-def _flash_bwd(causal, block_q, block_k, scale, interpret, res, g):
+def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
     from horovod_tpu.jax.introspect import KERNEL_FLASH_DKV, KERNEL_FLASH_DQ
 
     q, k, v, out, lse = res
     b, h, s, d = q.shape
-    kv_len = k.shape[2]
+    kv_len, group = k.shape[2], h // k.shape[1]
     do = g.astype(jnp.float32)
     delta = jnp.sum(do * out.astype(jnp.float32), axis=-1)  # (B, H, S)
 
@@ -477,7 +613,7 @@ def _flash_bwd(causal, block_q, block_k, scale, interpret, res, g):
     vp = _pad_seq(v, block_k)
     dop = _pad_seq(g.astype(q.dtype), block_q)
     sq_pad, sk_pad = qp.shape[2], kp.shape[2]
-    tiles = _Tiles(block_q, block_k, causal, s, kv_len)
+    tiles = _Tiles(block_q, block_k, causal, s, kv_len, window)
     # Padded query rows: lse=0, delta=0 → p = exp(-0)=1 rows would pollute
     # dk/dv; guard with lse=+inf so exp(s - lse) = 0.
     pad_q = ((0, 0), (0, 0), (0, sq_pad - s))
@@ -494,33 +630,16 @@ def _flash_bwd(causal, block_q, block_k, scale, interpret, res, g):
     lse_col, delta_col = lsep[..., None], deltap[..., None]
 
     interp = _should_interpret(interpret)
-    rows_panel = pl.BlockSpec((None, None, tiles.num_qb, 1, block_q),
-                              lambda bi, hi, kj: (bi, hi, 0, 0, 0))
+    _count_tiles(KERNEL_FLASH_DKV, tiles, q.shape, q.dtype, group)
+    dk, dv = _dkv_call(tiles, group, b, h, d, sq_pad, sk_pad, q.dtype, scale,
+                       interp)(qp, kp, vp, dop, lse_rows, delta_rows)
 
-    _count_tiles(KERNEL_FLASH_DKV, tiles, q.shape, q.dtype)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, tiles=tiles, scale=scale),
-        grid=(b, h, tiles.num_kb),
-        in_specs=[_panel_spec(sq_pad, d), _plane_spec(block_k, d),
-                  _plane_spec(block_k, d), _panel_spec(sq_pad, d),
-                  rows_panel, rows_panel],
-        out_specs=[_plane_spec(block_k, d), _plane_spec(block_k, d)],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sk_pad, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sk_pad, d), q.dtype),
-        ],
-        compiler_params=_compiler_params(sq_pad, d, q.dtype, block_q,
-                                         block_k),
-        interpret=interp,
-        name=KERNEL_FLASH_DKV,
-    )(qp, kp, vp, dop, lse_rows, delta_rows)
-
-    _count_tiles(KERNEL_FLASH_DQ, tiles, q.shape, q.dtype)
+    _count_tiles(KERNEL_FLASH_DQ, tiles, q.shape, q.dtype, group)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, tiles=tiles, scale=scale),
         grid=(b, h, tiles.num_qb),
-        in_specs=[_plane_spec(block_q, d), _panel_spec(sk_pad, d),
-                  _panel_spec(sk_pad, d), _plane_spec(block_q, d),
+        in_specs=[_plane_spec(block_q, d), _panel_spec(sk_pad, d, group),
+                  _panel_spec(sk_pad, d, group), _plane_spec(block_q, d),
                   _plane_spec(block_q, 1), _plane_spec(block_q, 1)],
         out_specs=_plane_spec(block_q, d),
         out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
@@ -530,6 +649,8 @@ def _flash_bwd(causal, block_q, block_k, scale, interpret, res, g):
         name=KERNEL_FLASH_DQ,
     )(qp, kp, vp, dop, lse_col, delta_col)
 
+    if group > 1:   # the group's float32 sums, rounded once
+        dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
     return dq[:, :, :s], dk[:, :, :kv_len], dv[:, :, :kv_len]
 
 
@@ -559,6 +680,7 @@ def _default_blocks(q_len, kv_len):
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
+                    window: Optional[int] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     scale: Optional[float] = None,
@@ -567,8 +689,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
     Args:
       q, k, v: (batch, seq, heads, head_dim) arrays (the layout used by
-        ``horovod_tpu.models.transformer``).
+        ``horovod_tpu.models.transformer``). ``k`` and ``v`` may carry
+        fewer heads than ``q``, a divisor of its count: query head h
+        then reads key/value head ``h // (H // H_kv)``, and dK/dV come
+        back that many heads wide.
       causal: apply a causal (lower-triangular) mask.
+      window: with ``causal``, a query at position p sees the keys j
+        with ``p - window < j <= p`` (None: all of ``j <= p``).
       block_q / block_k: VMEM tile sizes (clamped to the sequence and
         rounded to the dtype's sublane multiple; the sequence is padded
         to a multiple). The default comes from the sequence lengths
@@ -587,6 +714,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     if q.ndim != 4:
         raise ValueError("expected (B, S, H, D) inputs, got %r"
                          % (q.shape,))
+    if k.shape != v.shape or q.shape[2] % k.shape[2]:
+        raise ValueError(
+            "q has %d heads, k %r and v %r: k and v need one shape and a "
+            "head count that divides q's" % (q.shape[2], k.shape, v.shape))
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window (%r) needs causal=True and at least "
+                         "one key" % (window,))
     d = q.shape[-1]
     if scale is None:
         scale = float(d) ** -0.5
@@ -623,5 +757,6 @@ def flash_attention(q, k, v, *, causal: bool = True,
     vt = jnp.swapaxes(v, 1, 2)
     block_q = _pick_block(max(qt.shape[2], 1), block_q, q.dtype)
     block_k = _pick_block(max(kt.shape[2], 1), block_k, k.dtype)
-    out = _flash(qt, kt, vt, causal, block_q, block_k, scale, interpret)
+    out = _flash(qt, kt, vt, causal, window, block_q, block_k, scale,
+                 interpret)
     return jnp.swapaxes(out, 1, 2)

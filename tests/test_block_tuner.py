@@ -309,10 +309,10 @@ def test_flash_attention_consults_synced_view_without_local_env(
     picked = {}
     real_flash = pallas_attention._flash
 
-    def spy(qt, kt, vt, causal, block_q, block_k, scale, interpret):
+    def spy(qt, kt, vt, causal, window, block_q, block_k, scale, interpret):
         picked["blocks"] = (block_q, block_k)
-        return real_flash(qt, kt, vt, causal, block_q, block_k, scale,
-                          interpret)
+        return real_flash(qt, kt, vt, causal, window, block_q, block_k,
+                          scale, interpret)
 
     monkeypatch.setattr(pallas_attention, "_flash", spy)
     rng = np.random.RandomState(0)

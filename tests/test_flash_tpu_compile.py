@@ -81,6 +81,42 @@ def test_kernels_lower_for_v5e(one_chip, shape, dtype):
         assert "%" + name in text, name
 
 
+@pytest.mark.parametrize("window", [2048, None])
+def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, window):
+    """trinity-s8192-ep8-c1's attention, a sliding and a full layer: 32
+    query heads over 4 key/value heads of 128. K and V enter all three
+    Mosaic calls 4 heads wide and dK/dV leave 4 heads wide (float32, the
+    group's sum): nothing is repeated to 32 heads in HBM."""
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def step(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, interpret=False), q, k, v)
+        return (out,) + vjp(g)
+
+    text = jax.jit(step).lower(q, kv, kv, q).compile().as_text()
+    shape_of = dict(re.findall(r"%([\w.\-]+) = (\S+\[[\d,]*\])", text))
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    narrow, wide = "[1,4,8192,128]", "[1,32,8192,128]"
+    for line in calls:
+        name = line.split(" = ")[0].lstrip("ROOT ").lstrip("%")
+        operands = re.findall(
+            r"%([\w.\-]+)", line.split(" custom-call(")[1].split("), ")[0])
+        shapes = [shape_of[o] for o in operands]
+        assert shapes[0] == "bf16" + wide, (name, shapes)
+        assert shapes[1] == shapes[2] == "bf16" + narrow, (name, shapes)
+        if name.startswith(introspect.KERNEL_FLASH_DKV):
+            assert line.split(" = ")[1].startswith(
+                "(f32" + narrow), line[:200]
+    assert wide not in "".join(
+        line for line in text.splitlines() if " broadcast(" in line)
+
+
 _OPCODE_RE = re.compile(r"^(?:ROOT )?%?[\w.\-]+ = .*?\s([a-z][\w\-]*)\(")
 
 

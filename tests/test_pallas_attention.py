@@ -394,3 +394,143 @@ def test_transformer_flash_matches_dense():
     out_dense = dense_model.apply(params, tokens)
     out_flash = flash_model.apply(params, tokens)
     assert _rel(out_flash, out_dense) < 1e-4
+
+
+# ------------------------------- a window, and grouped key/value heads -----
+
+def masked_reference(q, k, v, window):
+    """Dense float32 attention: causal in the decode alignment, a query
+    at position p sees the keys j with ``p - window < j <= p``; k and v
+    repeated to the query heads (head h reads ``h // group``)."""
+    d, group = q.shape[-1], q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    pos = jnp.arange(q.shape[1])[:, None] + (k.shape[1] - q.shape[1])
+    key = jnp.arange(k.shape[1])[None, :]
+    visible = key <= pos
+    if window is not None:
+        visible &= key > pos - window
+    s = jnp.where(visible[None, None], s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+def _brute_force_window_tiles(q_len, kv_len, bq, bk, window):
+    """Each tile's kind from the mask itself, element by element, over
+    the padded rows too (the kernels compute them): ``below`` / ``skipped``
+    where no element is inside the window / under the diagonal, ``full``
+    where every element is visible and no key is padded."""
+    num_qb, num_kb = -(-q_len // bq), -(-kv_len // bk)
+    row = np.arange(num_qb * bq)[:, None] + (kv_len - q_len)
+    col = np.arange(num_kb * bk)[None, :]
+    under = col <= row
+    inside = np.ones_like(under) if window is None else col > row - window
+    visible = under & inside & (col < kv_len)
+    kinds = {}
+    for qi in range(num_qb):
+        for kj in range(num_kb):
+            tile = np.s_[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+            kinds[qi, kj] = ("skipped" if not under[tile].any() else
+                             "below" if not inside[tile].any() else
+                             "full" if visible[tile].all() else "edge")
+    return num_qb, num_kb, kinds
+
+
+@pytest.mark.parametrize("q_len,kv_len", [(200, 200), (100, 200)])
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("window", [None, 1, 3, 64, 500])
+def test_window_and_grouped_heads_match_dense(window, group, q_len, kv_len):
+    """Forward and all three gradients against the dense masked
+    attention: S no multiple of the 64 x 32 tiles, ``kv_len > q_len``,
+    K/V ``H // group`` heads wide going in and coming back; and the
+    tiles' classes and counts against the mask itself, by both walks."""
+    bq, bk, heads, d = 64, 32, 8, 16
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(rng.randn(1, q_len, heads, d), jnp.float32)
+    k = jnp.asarray(rng.randn(1, kv_len, heads // group, d), jnp.float32)
+    v = jnp.asarray(rng.randn(1, kv_len, heads // group, d), jnp.float32)
+    weight = jnp.asarray(rng.randn(1, q_len, heads, d), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=bq, block_k=bk)
+
+    out, vjp = jax.vjp(flash, q, k, v)
+    ref, ref_vjp = jax.vjp(lambda q, k, v: masked_reference(q, k, v, window),
+                           q, k, v)
+    assert out.shape == ref.shape and _rel(out, ref) < 1e-5
+    for got, want in zip(vjp(weight), ref_vjp(weight)):
+        assert got.shape == want.shape          # dK, dV: H // group heads
+        assert float(jnp.max(jnp.abs(got - want))) \
+            < 1e-5 * max(float(jnp.max(jnp.abs(want))), 1.0)
+
+    num_qb, num_kb, kinds = _brute_force_window_tiles(q_len, kv_len, bq, bk,
+                                                      window)
+    tiles = _Tiles(bq, bk, True, q_len, kv_len, window)
+    by_query, by_key = {}, {}
+    for qi in range(num_qb):
+        start, end = tiles.key_start(qi), tiles.key_end(qi)
+        first_full = max(start, tiles.key_inside(qi))
+        assert 0 <= start <= end <= num_kb
+        for kj in range(num_kb):
+            by_query[qi, kj] = (
+                "below" if kj < start else "skipped" if kj >= end else
+                "full" if first_full <= kj < tiles.key_full(qi) else "edge")
+    for kj in range(num_kb):
+        q_from, q_to = tiles.query_start(kj), tiles.query_end(kj)
+        assert 0 <= q_from <= q_to <= num_qb
+        for qi in range(num_qb):
+            by_key[qi, kj] = q_from <= qi < q_to
+    assert by_query == kinds
+    assert by_key == {t: kind in ("full", "edge")
+                      for t, kind in kinds.items()}
+    want = {kind: sum(1 for k_ in kinds.values() if k_ == kind)
+            for kind in ("full", "edge", "skipped", "below")}
+    if window is None:
+        assert want.pop("below") == 0      # the class exists under a window
+    assert tiles.counts() == want
+    if window in (1, 3, 64):
+        assert want["below"] > 0 and want["edge"] > 0
+
+
+def test_window_tile_counts_at_the_benchmarks_shape():
+    """trinity-s8192-ep8-c1's planes, 512 x 512 tiles: a sliding layer
+    (window 2048) computes 70 of a full layer's 136 tiles."""
+    full = _Tiles(512, 512, True, 8192, 8192).counts()
+    sliding = _Tiles(512, 512, True, 8192, 8192, 2048).counts()
+    assert full == {"full": 120, "edge": 16, "skipped": 120}
+    assert sliding == {"full": 42, "edge": 28, "skipped": 120, "below": 66}
+
+
+def test_a_window_needs_causal_and_the_heads_have_to_divide():
+    x = jnp.zeros((1, 16, 4, 8))
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(x, x, x, causal=False, window=4)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(x, x, x, causal=True, window=0)
+    with pytest.raises(ValueError, match="heads"):
+        flash_attention(x, x[:, :, :3], x[:, :, :3])
+
+
+def test_tile_counter_and_log_line_carry_window_and_group(caplog):
+    """The tile counter's ``below`` kind moves only under a window, and
+    the per-shape log line names the window and the group."""
+    import logging
+
+    from horovod_tpu.jax import introspect
+
+    below = pallas_attention._M_TILES.labels(
+        kernel=introspect.KERNEL_FLASH_FWD, kind="below")
+    before = below.get()
+    q = jax.ShapeDtypeStruct((1, 1024, 8, 16), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 1024, 2, 16), jnp.bfloat16)
+    with caplog.at_level(logging.INFO, logger="horovod_tpu"):
+        jax.eval_shape(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128), q, kv, kv)
+        assert below.get() == before
+        jax.eval_shape(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=256, block_q=128, block_k=128),
+            q, kv, kv)
+    # Rows 2.. of 8 leave 0, 1, .. 5 key blocks below their window.
+    assert below.get() - before == sum(range(6))
+    assert "window 256, 4 query head(s) a key/value head" in caplog.text
+    assert "window None, 4 query head(s) a key/value head" in caplog.text

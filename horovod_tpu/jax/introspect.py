@@ -58,6 +58,12 @@ SCOPE_MOE_EXPERTS = "hvd_moe_experts"
 # A gather of rows and the plain sum over each token's k; backward one
 # gather from the (T, M) cotangent. No weighting here.
 SCOPE_MOE_COMBINE = "hvd_moe_combine"
+# Round the three above where the layer holds a share of the experts and
+# chooses its row arrays' length on the device: the ``lax.cond`` and,
+# once more, each branch as a whole
+# (``moe/hvd_moe_rows/cond/branch_<i>_fun/hvd_moe_rows/hvd_moe_<part>/...``:
+# inside the backward rule a transform's name wraps the inner one).
+SCOPE_MOE_ROWS = "hvd_moe_rows"
 # The shared expert's three matmuls, beside the routed sum.
 SCOPE_MOE_SHARED = "hvd_moe_shared"
 # ``name=`` of the three ``pallas_call``s (the Mosaic calls' op_name).
@@ -69,6 +75,12 @@ KERNEL_FLASH_DQ = "hvd_flash_dq"
 # log-sum-exp. ``Transformer``'s ``remat`` keeps exactly these.
 SAVED_FLASH_OUT = "hvd_flash_out"
 SAVED_FLASH_LSE = "hvd_flash_lse"
+# The same for what an expert layer that chooses its row arrays' length
+# returns (parallel/moe.py ``_held_rows``): its backward rule recomputes
+# from its INPUTS, so a block needs the layer's forward again only where
+# it reads the OUTPUT once more (a norm on it); kept, that run is dead
+# code. Saved only where the backward pass reads it.
+SAVED_MOE_OUT = "hvd_moe_out"
 
 # Primitive names the framework's in-graph data plane lowers to.
 # (lax.psum_scatter traces as the "reduce_scatter" primitive.)

@@ -46,6 +46,7 @@ from flax.linen import partitioning as nn_partitioning
 from horovod_tpu.jax.introspect import (
     SAVED_FLASH_LSE,
     SAVED_FLASH_OUT,
+    SAVED_MOE_OUT,
     SCOPE_ATTN_GATE,
     SCOPE_EMBED,
     SCOPE_LOGITS,
@@ -486,13 +487,18 @@ def _remat_block(cfg):
     kernel made (the two names ops/pallas_attention.py gives its
     output and log-sum-exp): the backward kernels read them, so the
     recomputed forward has no use for a second run of the kernel.
-    Where nothing carries the names the policy saves nothing, which is
-    plain recomputation. Counted and logged at trace time."""
+    Likewise what an expert layer that holds a share of the experts
+    returns, where the block reads it again (``post_norms``: the norm on
+    the feed-forward's output); that layer's backward rule recomputes
+    from its inputs (parallel/moe.py ``_held_rows``). Where nothing
+    carries the names, or the backward pass reads none of them, the
+    policy saves nothing, which is plain recomputation. Counted and
+    logged at trace time."""
     keeps = "flash_out+lse" if cfg.attention == "flash" else "nothing"
     _M_REMAT_BLOCKS.labels(keeps=keeps).inc(cfg.n_layers)
     _log_remat(cfg, keeps)
     policy = jax.checkpoint_policies.save_only_these_names(
-        SAVED_FLASH_OUT, SAVED_FLASH_LSE)
+        SAVED_FLASH_OUT, SAVED_FLASH_LSE, SAVED_MOE_OUT)
     return nn.remat(Block, policy=policy)
 
 

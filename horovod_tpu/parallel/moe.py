@@ -20,8 +20,16 @@
   64): the router, its top-k and the gates are over all experts, the
   pairs of the held experts sort first, their rows are the ragged
   groups, and every row past them is a dead row: never multiplied,
-  never read back. The row arrays keep their full static length T x k:
-  no bound short of it drops nothing whatever the router does.
+  never read back. Such a layer's row arrays are C rows long, a static
+  PREFIX of the sorted rows: C is twice the balanced share,
+  ``2 T k held / E`` rounded up to a whole 512-row tile (``prefix_rows``;
+  arithmetic on the layer's shapes, not a setting). A top-k router CAN
+  send every pair to the held experts, so no bound short of T x k drops
+  nothing whatever it does: each step, on the device, the layer compares
+  the live rows it counted with C (``lax.cond``) and runs the same body
+  over the whole length T x k when they overflow the prefix. Nothing is
+  dropped or clipped in either, and where both apply they add the same
+  numbers in the same order.
   What absent experts would have added is left out; the exchange that
   brings other chips' rows here wraps this layer later (ROADMAP, D14).
   A ``sigmoid_bias`` router (``route``) and shared experts beside the
@@ -42,13 +50,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 import flax.linen as nn
 
 from horovod_tpu.jax.introspect import (
+    SAVED_MOE_OUT,
     SCOPE_MOE_COMBINE,
     SCOPE_MOE_DISPATCH,
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_ROUTER,
+    SCOPE_MOE_ROWS,
     SCOPE_MOE_SHARED,
 )
 from horovod_tpu.parallel.mesh import DATA_AXIS, EXPERT_AXIS
@@ -61,9 +72,17 @@ from horovod_tpu.utils import metrics as _metrics
 # ``tokens`` (the (T, M) array) or ``rows`` (a (T x k, M) one).
 _M_ROW_GATHERS = _metrics.counter(
     "hvd_moe_row_gathers_total",
-    "Gathers of rows per traced expert layer, by site and by the array "
-    "they read (counted at trace time, not per device step).",
-    ("site", "source"))
+    "Gathers of rows per traced expert layer, by site, by the array "
+    "they read and by the length of the sorted-row arrays (counted at "
+    "trace time, not per device step).",
+    ("site", "source", "rows"))
+# The traced bodies of the expert layer by the length of their sorted-row
+# arrays: ``whole`` (T x k rows) or ``prefix`` (``prefix_rows``). A layer
+# that holds a share of the experts traces both, forward and backward.
+_M_ROW_ARRAYS = _metrics.counter(
+    "hvd_moe_row_arrays_total",
+    "Traced bodies of the expert layer by the length of their sorted-row "
+    "arrays: whole (T x k) or prefix (counted at trace time).", ("rows",))
 # Also at trace time: the experts one traced expert layer holds
 # (``held``) and routes over (``routed``).
 _M_EXPERTS = _metrics.counter(
@@ -228,18 +247,31 @@ def _permute_bwd(index, d_out):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
+def _row_arrays(n, pairs):
+    """The counters' name for sorted-row arrays ``n`` long of ``pairs``."""
+    return "whole" if n == pairs else "prefix"
+
+
 def _rows_of_tokens(site, tokens, order, k):
-    """(T x k, M): sorted row r is the row of token ``order[r] // k``."""
-    _M_ROW_GATHERS.labels(site=site, source="tokens").inc()
+    """(n, M): sorted row r is the row of token ``order[r] // k``, for
+    the ``n`` sorted rows ``order`` names."""
+    _M_ROW_GATHERS.labels(
+        site=site, source="tokens",
+        rows=_row_arrays(order.shape[0], tokens.shape[0] * k)).inc()
     return tokens[order // k]
 
 
 def _sum_per_token(site, rows, inverse, k, live):
     """(T, M): the float32 sum of each token's k sorted rows, rounded
     once to the rows' dtype. Only the first ``live`` sorted rows count
-    (None: all of them): what a dead row holds is never read."""
-    _M_ROW_GATHERS.labels(site=site, source="rows").inc()
-    pairs = rows[inverse]
+    (None: all of them): what a dead row holds is never read. ``rows``
+    may be a prefix of the T x k sorted rows that holds every live one:
+    a pair sorted past it is a dead pair, and reads the prefix's last
+    row before it is masked."""
+    n, total = rows.shape[0], inverse.shape[0]
+    _M_ROW_GATHERS.labels(site=site, source="rows",
+                          rows=_row_arrays(n, total)).inc()
+    pairs = rows[inverse if n == total else jnp.minimum(inverse, n - 1)]
     if live is not None:
         pairs = jnp.where((inverse < live)[:, None], pairs, 0)
     pairs = pairs.reshape(-1, k, rows.shape[-1])
@@ -250,10 +282,12 @@ def _sum_per_token(site, rows, inverse, k, live):
 # backward pass is the other's forward: two gathers from the (T, M)
 # array, two from (T x k, M) rows, no broadcast and no scatter-add.
 # ``live`` (a traced scalar, or None for "every row") is the number of
-# sorted rows that belong to an expert held here.
+# sorted rows that belong to an expert held here; ``order`` names the
+# sorted rows the arrays hold (all T x k, or a prefix with every live
+# one), ``inverse`` is always T x k long.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _dispatch(tokens, order, inverse, live, k):
-    """The tokens' rows in sorted order, (T x k, M)."""
+    """The tokens' rows in sorted order, (n, M)."""
     return _rows_of_tokens("dispatch_fwd", tokens, order, k)
 
 
@@ -333,6 +367,108 @@ def grouped_ffn(rows, row_gates, group_sizes, wi, wo, wg=None, live=None):
     return lax.ragged_dot(hidden, wo, group_sizes)
 
 
+# The grouped matmuls' row tile: the prefix is a whole number of them.
+_ROW_TILE = 512
+
+
+def prefix_rows(t, k, held, e):
+    """C, the static length of the sorted-row arrays of a layer that
+    holds ``held`` of ``e`` experts: twice the balanced share of the
+    ``t * k`` pairs, rounded up to a whole row tile; ``t * k`` where that
+    is no shorter (every expert held, or too few rows for a tile)."""
+    return min(-(-2 * t * k * held // (e * _ROW_TILE)) * _ROW_TILE, t * k)
+
+
+def _expert_rows(n, k, tokens, order, inverse, gates, sizes, live, wi, wo,
+                 wg):
+    """(T, M): each token's gate-weighted sum over the experts held
+    here, through sorted-row arrays ``n`` long: all T x k pairs, or a
+    prefix that holds the ``live`` ones. ``gates`` (T, k) float32;
+    ``sizes`` the held experts' rows; the weights are used in the
+    tokens' dtype."""
+    _M_ROW_ARRAYS.labels(rows=_row_arrays(n, order.shape[0])).inc()
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        head = order[:n]      # (a slice of the whole length traces nothing)
+        rows = _dispatch(tokens, head, inverse, live, k)
+        row_gates = _permute(gates.reshape(-1), order, inverse)[:n]
+    with jax.named_scope(SCOPE_MOE_EXPERTS):
+        out = grouped_ffn(rows, row_gates, sizes, wi.astype(rows.dtype),
+                          wo.astype(rows.dtype), wg, live)
+    with jax.named_scope(SCOPE_MOE_COMBINE):
+        return _combine(out, head, inverse, live, k)
+
+
+def _rows_branch(n, k):
+    """``_expert_rows`` over ``n`` rows as one branch of the choice.
+
+    Two names a device trace's readers need (``instruction_scopes``).
+    The weights pass a barrier under the experts' scope: a grouped
+    matmul's Mosaic call carries no name of its own and is filed under
+    its largest operand's, which at the prefix's length is an expert
+    panel, and what enters a branch from outside would bring the
+    ``cond``'s name, no part's (the barrier runs nothing). And the whole
+    is under the choice's scope once more: differentiated inside
+    ``_held_rows_bwd``, the transform's name wraps THIS segment
+    (``transpose(jvp(hvd_moe_rows))``) and the parts' below it stay
+    what those readers look for."""
+    def branch(tokens, order, inverse, gates, sizes, live, *weights):
+        with jax.named_scope(SCOPE_MOE_ROWS):
+            with jax.named_scope(SCOPE_MOE_EXPERTS):
+                weights = lax.optimization_barrier(weights)
+            return _expert_rows(n, k, tokens, order, inverse, gates, sizes,
+                                live, *weights)
+    return branch
+
+
+# A layer that holds a share of the experts chooses its row arrays'
+# length on the device, each step: the prefix where the live rows fit
+# it, the whole length where they overflow. ONE differentiation rule
+# round the choice, whose residuals are its inputs: a ``lax.cond``
+# differentiated from outside hands back the union of both branches'
+# residuals, the untaken branch's as freshly written zeros, which would
+# put whole-length writes back into the prefix's path. The backward
+# pass makes the choice again and recomputes the branch it takes (under
+# a block's recomputation the outer recomputed forward is then dead
+# code; where the block reads the layer's output again, a norm on it, it
+# keeps that output: ``SAVED_MOE_OUT``).
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_rows(c, k, tokens, order, inverse, gates, sizes, live, wi, wo, wg):
+    with jax.named_scope(SCOPE_MOE_ROWS):
+        return lax.cond(
+            live <= c, _rows_branch(c, k), _rows_branch(order.shape[0], k),
+            tokens, order, inverse, gates, sizes, live, wi, wo, wg)
+
+
+def _held_rows_fwd(c, k, *operands):
+    return _held_rows(c, k, *operands), operands
+
+
+def _held_rows_bwd(c, k, operands, d_out):
+    tokens, order, inverse, gates, sizes, live, *weights = operands
+
+    def gradients(n):
+        def rows(tokens, gates, *weights):
+            return _rows_branch(n, k)(tokens, order, inverse, gates, sizes,
+                                      live, *weights)
+        return lambda d_out, *floating: jax.vjp(rows, *floating)[1](d_out)
+
+    with jax.named_scope(SCOPE_MOE_ROWS):
+        d_tokens, d_gates, *d_weights = lax.cond(
+            live <= c, gradients(c), gradients(order.shape[0]),
+            d_out, tokens, gates, *weights)
+    # The weight gradients leave the choice as the grouped matmuls made
+    # them, in the compute dtype. Without the barrier the compiler moves
+    # their conversion to the parameters' float32 INTO both branches, a
+    # pass over each (held, M, F) array that the optimizer's fusion
+    # otherwise does as it reads (the compiled GLM-4.7-Flash step handed
+    # each gradient back twice, 50 + 100 MB).
+    return (d_tokens, None, None, d_gates, None, None,
+            *lax.optimization_barrier(d_weights))
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
 class MoeMlp(nn.Module):
     """The expert feed-forward of a transformer block: top-k, dropless
     (module docstring). Expert weights carry ``expert``-axis
@@ -351,6 +487,8 @@ class MoeMlp(nn.Module):
     Sown into the ``moe`` collection on every call outside ``init``:
     ``tokens_per_expert`` (E,), which sums to T x k whatever the
     imbalance, ``rows_held``, the pairs whose expert is held here,
+    ``rows_overflow``, 1 where they overflowed the prefix and the step
+    ran the whole length (0 else, and wherever no choice is made),
     ``experts`` (T, k), the choice made, and under the softmax router
     ``load_balance`` and ``z_loss`` (``aux_losses``). ``sown_stats``
     stacks them over layers; what a step does not use costs nothing."""
@@ -374,6 +512,9 @@ class MoeMlp(nn.Module):
                         (m, e), jnp.float32)
         wi = self.param("wi", experts_init, (held, m, cfg.d_ff), jnp.float32)
         wo = self.param("wo", experts_init, (held, cfg.d_ff, m), jnp.float32)
+        # The sorted-row arrays' length, and whether it is chosen each
+        # step between it and the whole T x k.
+        c = prefix_rows(t, k, held, e)
         wg = None
         if spec.ffn == "swiglu":
             wg = self.param("wg", experts_init, (held, m, cfg.d_ff),
@@ -398,19 +539,26 @@ class MoeMlp(nn.Module):
                 load_balance, z_loss = aux_losses(logits, scores, counts)
             # The held experts' rows are the first ``live`` sorted rows,
             # in ``held`` ragged groups; all T x k where all are held.
-            sizes, live, rows_held = counts, None, t * k
+            sizes, live, rows_held, rows_overflow = counts, None, t * k, 0
             if held < e:
                 sizes = counts[first:first + held]
                 live = rows_held = jnp.sum(sizes)
         with jax.named_scope(SCOPE_MOE_DISPATCH):
             order, inverse = sorted_by_expert(experts, first, e)
-            rows = _dispatch(tokens, order, inverse, live, k)
-            row_gates = _permute(gates.reshape(-1), order, inverse)
-        with jax.named_scope(SCOPE_MOE_EXPERTS):
-            out = grouped_ffn(rows, row_gates, sizes, wi.astype(cfg.dtype),
-                              wo.astype(cfg.dtype), wg, live)
-        with jax.named_scope(SCOPE_MOE_COMBINE):
-            out = _combine(out, order, inverse, live, k)
+        if c == t * k:
+            out = _expert_rows(t * k, k, tokens, order, inverse, gates,
+                               sizes, live, wi, wo, wg)
+        else:
+            # Cast before the choice: a branch hands its weight
+            # gradients back in the compute dtype.
+            with jax.named_scope(SCOPE_MOE_EXPERTS):
+                wi, wo = wi.astype(cfg.dtype), wo.astype(cfg.dtype)
+            # Named for a block's recomputation to keep where it reads
+            # the output again (models/transformer.py ``_remat_block``).
+            out = checkpoint_name(
+                _held_rows(c, k, tokens, order, inverse, gates, sizes, live,
+                           wi, wo, wg), SAVED_MOE_OUT)
+            rows_overflow = (live > c).astype(jnp.int32)
         if self.shared is not None:
             with jax.named_scope(SCOPE_MOE_SHARED):
                 out = out + self.shared(tokens)
@@ -420,6 +568,7 @@ class MoeMlp(nn.Module):
                 self.sow("moe", "z_loss", z_loss)
             self.sow("moe", "tokens_per_expert", counts)
             self.sow("moe", "rows_held", rows_held)
+            self.sow("moe", "rows_overflow", rows_overflow)
             self.sow("moe", "experts", experts)
         return out.reshape(b, s, m)
 
@@ -452,9 +601,9 @@ def updated_router_bias(state, tokens_per_expert, rate, axis=DATA_AXIS):
 def sown_stats(variables):
     """What the expert layers of one ``Transformer.apply(...,
     mutable=["moe"])`` sowed, stacked over the expert layers in order:
-    ``{"tokens_per_expert": (L, E), "rows_held": (L,), "experts":
-    (L, T, k)}`` and, under the softmax router, ``{"load_balance":
-    (L,), "z_loss": (L,)}``."""
+    ``{"tokens_per_expert": (L, E), "rows_held": (L,), "rows_overflow":
+    (L,), "experts": (L, T, k)}`` and, under the softmax router,
+    ``{"load_balance": (L,), "z_loss": (L,)}``."""
     from flax import traverse_util
 
     by_name = {}

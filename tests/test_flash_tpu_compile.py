@@ -203,15 +203,152 @@ def test_expert_layer_materialises_no_float32_rows(one_chip):
     assert sorted(int(n) for n in read) == [t, t, t * k, t * k], read
 
 
+def _branches(text):
+    """[(conditional, [[(instruction, opcode, HLO line)] per branch])]
+    of a compiled step."""
+    computations, _, _ = introspect._parse_computations(text)
+    line_of = {}
+    for raw in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", raw)
+        if m:
+            line_of.setdefault(m.group(1), raw)
+    found = []
+    for instructions in computations.values():
+        for inst, opcode, _, _, _, called in instructions:
+            if opcode == "conditional":
+                found.append((inst, [
+                    [(i, op, line_of[i]) for i, op, *_ in computations[c]]
+                    for c in called]))
+    return found
+
+
+def test_held_expert_layer_chooses_its_row_arrays(one_chip):
+    """``MoeMlp`` that holds 2 of 16 experts, forward + recomputed
+    forward + backward (``cfg.remat``'s policy) compiled for a described
+    v5e: T x k = 4096 pairs, the prefix C = 1024 rows. TWO conditionals
+    (the recomputed forward's is dead code: the backward rule's
+    residuals are the layer's inputs). In each the prefix branch's
+    grouped matmuls take and make C rows, its row gathers are C rows
+    from the tokens or T x k pairs from C rows, and nothing in it is
+    (T x k, F): no select over the whole length. Every instruction of
+    both branches keeps an ``hvd_moe_*`` scope through
+    ``instruction_scopes``, own or inherited; the grouped matmuls carry
+    none of their own and read one part's off their largest operand, as
+    they do where all experts are held: at the prefix's length that is
+    an expert's panel wherever a panel is an operand, and the branch
+    names it itself (a barrier under the experts' scope, compiled to
+    named ``get-tuple-element``s: no instruction runs for it), for what
+    enters a branch brings the ``cond``'s name and no part's."""
+    import flax.linen as nn
+    from flax.core import meta
+    from horovod_tpu import models
+    from horovod_tpu.models import transformer
+    from horovod_tpu.parallel.moe import MoeMlp, prefix_rows
+
+    # M and F above C / held rows, as in the cells: an expert's panel is
+    # then a grouped matmul's largest operand.
+    t, k, m, f, e, held = 2048, 2, 768, 640, 16, 2
+    pairs, c = t * k, prefix_rows(t, k, held, e)
+    assert c == 1024 < held * min(m, f)
+    cfg = models.TransformerConfig(
+        d_model=m, n_heads=2, d_ff=f, dtype=jnp.bfloat16,
+        block=models.BlockSpec(ffn="swiglu", num_experts=e,
+                               experts_per_token=k, experts_held=held))
+    layer = nn.remat(MoeMlp, policy=jax.checkpoint_policies
+                     .save_only_these_names(transformer.SAVED_FLASH_OUT,
+                                            transformer.SAVED_FLASH_LSE))(cfg)
+    x = jax.ShapeDtypeStruct((1, t, m), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: meta.unbox(MoeMlp(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype)))))
+
+    def step(p, x_, ct):
+        out, vjp = jax.vjp(layer.apply, p, x_)
+        return (out,) + vjp(ct)
+
+    text = jax.jit(step).lower(params, x, x).compile().as_text()
+    scopes = introspect.instruction_scopes(text)
+    conditionals = _branches(text)
+    assert len(conditionals) == 2, [name for name, _ in conditionals]
+    assert " opt-barrier(" not in text      # the barriers run nothing
+
+    def result(line):       # the type an instruction's line gives it
+        return line.split(" = ")[1].split("{")[0].split("(")[0]
+
+    for name, branches in conditionals:
+        assert len(branches) == 2
+        for branch in branches:
+            for inst, _, _ in branch:
+                assert "hvd_moe_" in scopes[inst], (name, inst, scopes[inst])
+        # The prefix's is the branch with no (T x k, F) array.
+        prefix = [b for b in branches if not any(
+            "[%d,%d]" % (pairs, f) in result(line) for _, _, line in b)]
+        assert len(prefix) == 1, name
+        (prefix,) = prefix
+        shape_of = {inst: result(line) for inst, _, line in prefix}
+        matmuls = [(inst, line) for inst, _, line in prefix
+                   if inst.startswith("ragged-dot-none")]
+        # Forward 3; backward the 2 up projections again and 6 more.
+        assert len(matmuls) in (3, 8), (name, len(matmuls))
+        for inst, line in matmuls:
+            operands = re.findall(r"%([\w.\-]+)", line.split(
+                " custom-call(")[1].split("), ")[0])
+            shapes = [shape_of[inst]] + [shape_of.get(o, "") for o in operands]
+            assert not [s for s in shapes if "[%d," % pairs in s], shapes
+            assert [s for s in shapes if "[%d," % c in s], shapes
+            # Its own name is bare; the scope is read off an operand: a
+            # part of the layer, never the bare ``hvd_moe_rows/cond``,
+            # and the experts' where a panel is an operand (the three
+            # forward matmuls and the three input gradients; a weight
+            # gradient reads its rows').
+            assert 'op_name="ragged-dot-none"' in line
+            part = [p for p in (introspect.SCOPE_MOE_EXPERTS,
+                                introspect.SCOPE_MOE_DISPATCH,
+                                introspect.SCOPE_MOE_COMBINE)
+                    if p in scopes[inst]]
+            assert len(part) == 1, (inst, scopes[inst])
+            if shapes[0].startswith("bf16[%d," % c):
+                assert part == [introspect.SCOPE_MOE_EXPERTS], (inst, part)
+                assert scopes[inst].endswith("optimization_barrier")
+        gathers = sorted(
+            (int(rows), int(source))
+            for inst, opcode, line in prefix
+            if opcode == "fusion" and scopes[inst].endswith("/gather")
+            for rows in re.findall(r"^bf16\[(\d+),%d\]" % m, shape_of[inst])
+            for source in re.findall(
+                r"bf16\[(\d+),%d\]" % m, shape_of.get(re.findall(
+                    r" fusion\(%([\w.\-]+)", line)[0], "")))
+        assert gathers in ([(c, t), (pairs, c)],
+                           [(c, t), (c, t), (pairs, c)]), (name, gathers)
+
+
+def test_the_layer_that_holds_every_expert_chooses_nothing(topo, monkeypatch):
+    """``olmoe-s4096-c1``'s step at its tiny sizes, compiled for a
+    described v5e: all experts held, so one body over all T x k rows
+    and no ``conditional`` anywhere in the step."""
+    from benchmark import cell as cells
+
+    monkeypatch.setattr(pallas_attention, "_should_interpret",
+                        lambda interpret: False)
+    cell = cells.load("olmoe-s4096-c1", tiny=True)
+    asm = cells.assemble(cell, topo.devices)
+    text = asm.step.lower(*cells.abstract_step_args(asm)).compile().as_text()
+    assert introspect.SCOPE_MOE_EXPERTS in text
+    assert not _branches(text)
+    assert introspect.SCOPE_MOE_ROWS not in text
+
+
 def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
     """GLM-4.7-Flash's step at its tiny sizes (latent attention, a dense
     block, two expert blocks that hold 2 of 16 experts, every block
     recomputed), compiled for a described v5e: the forward kernel runs
     ONCE a layer, as the backward kernels do, because the recomputation
     keeps its output and log-sum-exp (``models/transformer.py``
-    ``_remat_block``); the rest of a block is still recomputed; and the
-    dead rows of the grouped matmuls are masked by selects, not by a
-    second path.
+    ``_remat_block``); the rest of a block is still recomputed; and at
+    these sizes (T x k = 254 pairs, under one row tile) an expert layer
+    runs one body over all of them: no ``conditional``, the dead rows
+    of the grouped matmuls masked by selects.
 
     The benchmark's builder still DECLARES two forward calls a layer
     under ``remat`` (``benchmark/builders/glm4_moe_lite.py``
@@ -246,3 +383,4 @@ def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
                   introspect.SCOPE_MOE_EXPERTS, "rematted_computation"):
         assert scope in text, scope
     assert "all-reduce" not in text
+    assert not _branches(text)

@@ -176,6 +176,66 @@ def test_instructions_without_metadata_inherit_a_scope():
     assert introspect.instruction_scopes("") == {}
 
 
+CONDITIONAL = """\
+HloModule jit_step, is_scheduled=true, entry_computation_layout={()->f32[8,8]}
+
+%prefix (arg: (f32[64,8], f32[512,8], f32[512,8], f32[8,8])) -> f32[8,8] {
+  %arg = (f32[64,8]{1,0}, f32[512,8]{1,0}, f32[512,8]{1,0}, f32[8,8]{1,0}) parameter(0)
+  %tokens = f32[64,8]{1,0} get-tuple-element(%arg), index=0
+  %rows = f32[64,8]{1,0} fusion(%tokens, %tokens), kind=kLoop, calls=%fused, metadata={op_name="jit(step)/moe/cond/branch_1_fun/hvd_moe_dispatch/gather"}
+  %panel = f32[512,8]{1,0} get-tuple-element(%arg), index=1, metadata={op_name="jit(step)/moe/cond/branch_1_fun/hvd_moe_experts/optimization_barrier"}
+  %bare = f32[512,8]{1,0} get-tuple-element(%arg), index=2
+  %turned = f32[512,8]{0,1} copy(%panel)
+  %matmul = f32[64,8]{1,0} custom-call(%rows, %turned), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %unnamed = f32[64,8]{1,0} custom-call(%rows, %bare), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  ROOT %sum = f32[8,8]{1,0} fusion(%matmul, %unnamed), kind=kLoop, calls=%fused, metadata={op_name="jit(step)/moe/cond/branch_1_fun/hvd_moe_combine/reduce_sum"}
+}
+
+%whole (arg.1: (f32[64,8], f32[512,8], f32[512,8], f32[8,8])) -> f32[8,8] {
+  %arg.1 = (f32[64,8]{1,0}, f32[512,8]{1,0}, f32[512,8]{1,0}, f32[8,8]{1,0}) parameter(0)
+  ROOT %bare.1 = f32[8,8]{1,0} get-tuple-element(%arg.1), index=3
+}
+
+%fused (p0: f32[64,8], p1: f32[8,8]) -> f32[8,8] {
+  %p0 = f32[64,8]{1,0} parameter(0)
+  ROOT %p1 = f32[8,8]{1,0} parameter(1)
+}
+
+ENTRY %main (w: f32[512,8], x: f32[64,8], b: f32[8,8]) -> f32[8,8] {
+  %w = f32[512,8]{1,0} parameter(0), metadata={op_name="params['w']"}
+  %x = f32[64,8]{1,0} parameter(1), metadata={op_name="batch"}
+  %b = f32[8,8]{1,0} parameter(2), metadata={op_name="params['b']"}
+  %fits = pred[] constant(true)
+  %gathered = f32[64,8]{1,0} fusion(%x, %b), kind=kLoop, calls=%fused, metadata={op_name="jit(step)/moe/hvd_moe_dispatch/gather"}
+  %cast = f32[512,8]{1,0} fusion(%w, %b), kind=kLoop, calls=%fused, metadata={op_name="jit(step)/moe/hvd_moe_experts/convert_element_type"}
+  %operands = (f32[64,8]{1,0}, f32[512,8]{1,0}, f32[512,8]{1,0}, f32[8,8]{1,0}) tuple(%gathered, %cast, %cast, %b)
+  ROOT %choice = f32[8,8]{1,0} conditional(%fits, %operands, %operands), branch_computations={%whole, %prefix}, metadata={op_name="jit(step)/moe/cond"}
+}
+"""
+
+
+def test_a_branch_parameter_takes_the_conditionals_scope():
+    """What a ``conditional`` hands a branch comes in under the
+    ``conditional``'s OWN name, whatever named it outside: a grouped
+    matmul, whose own name is bare, whose panel is its largest operand
+    and comes straight from the branch's argument is filed under
+    ``.../cond`` and no part of the layer. So the program names the
+    panel INSIDE the branch (``parallel/moe.py`` ``_rows_branch``: a
+    barrier under the experts' scope, which the compiler turns into the
+    named ``get-tuple-element``), and the matmul, with the compiler's
+    re-laid copy of the panel, reads that."""
+    scopes = introspect.instruction_scopes(CONDITIONAL)
+    barrier = ("jit(step)/moe/cond/branch_1_fun/hvd_moe_experts/"
+               "optimization_barrier")
+    assert scopes["panel"] == scopes["turned"] == scopes["matmul"] == barrier
+    assert scopes["arg"] == scopes["bare"] == scopes["unnamed"] \
+        == scopes["tokens"] == "jit(step)/moe/cond"
+    assert scopes["rows"].endswith("hvd_moe_dispatch/gather")
+    assert scopes["sum"].endswith("hvd_moe_combine/reduce_sum")
+    assert scopes["bare.1"] == scopes["choice"] == "jit(step)/moe/cond"
+    assert scopes["operands"].endswith("hvd_moe_experts/convert_element_type")
+
+
 def test_scopes_change_no_arithmetic_and_no_state(monkeypatch):
     sharded, tx, args = _step_and_args()
     scoped = sharded(*args)

@@ -225,3 +225,39 @@ def test_the_names_cost_nothing_outside_a_recomputation():
     lowered = jax.jit(jax.grad(loss, (0, 1, 2))).lower(x, x, x).as_text()
     assert introspect.SAVED_FLASH_OUT not in lowered
     assert introspect.SAVED_FLASH_LSE not in lowered
+
+
+@pytest.mark.parametrize("post_norms,kept,choices", [
+    (True, True, 2),     # the output is kept: forward and backward rule
+    (True, False, 3),    # not kept: the recomputed forward chooses again
+    (False, True, 2),    # nothing reads the output again: dead either way
+])
+def test_a_recomputed_block_keeps_the_held_expert_layers_output(
+        post_norms, kept, choices, monkeypatch):
+    """An expert layer that holds a share of the experts chooses its row
+    arrays' length in a ``lax.cond`` whose backward rule recomputes from
+    the layer's INPUTS (parallel/moe.py ``_held_rows``), so a recomputed
+    block runs the layer's forward again only where it reads the OUTPUT
+    once more: the norm on it (``post_norms``). The output carries
+    ``introspect.SAVED_MOE_OUT`` and the recomputation keeps it: one
+    expert layer's compiled gradient holds two ``conditional``s, not
+    three (512 tokens x 2 slots, 1 of 8 experts held: a prefix of 512
+    rows)."""
+    if not kept:    # the policy lists another name than the layer gives
+        monkeypatch.setattr(transformer_module, "SAVED_MOE_OUT", "unlisted")
+    model = Transformer(TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=1, d_ff=16,
+        max_seq_len=512, attention="dense", remat=True,
+        block=BlockSpec(norm="rmsnorm", ffn="swiglu", num_experts=8,
+                        experts_per_token=2, experts_held=1,
+                        post_norms=post_norms)))
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (1, 512), 0, 64)
+    variables = meta.unbox(model.init(jax.random.PRNGKey(0), tokens))
+    rest = {k: v for k, v in variables.items() if k != "params"}
+
+    def loss(params):
+        return jnp.mean(model.apply({"params": params, **rest}, tokens) ** 2)
+
+    text = jax.jit(jax.grad(loss)).lower(
+        variables["params"]).compile().as_text()
+    assert len(re.findall(r" conditional\(", text)) == choices

@@ -182,9 +182,11 @@ def _moe_layer(ffn, e, k, dtype=jnp.float32, d_model=16, d_ff=24):
     return moe_mod.MoeMlp(cfg)
 
 
-def _dense_moe_reference(params, x, k, assignment):
-    """Every token through EVERY expert, then the gate-weighted sum over
-    the k it was sent to: no sort, no gather, no grouped matmul."""
+def _dense_moe_reference(params, x, k, assignment, first=0):
+    """Every token through EVERY expert whose weights ``params`` hold
+    (``first`` onward of those the router scores), then the
+    gate-weighted sum over those of the k it was sent to: no sort, no
+    gather, no grouped matmul."""
     p = params["params"]
     tokens = x.reshape(-1, x.shape[-1])
     probs = jax.nn.softmax(tokens @ p["router"], axis=-1)
@@ -198,7 +200,8 @@ def _dense_moe_reference(params, x, k, assignment):
         hidden = jax.nn.gelu(up)
     every = jnp.einsum("tef,efm->tem", hidden, p["wo"])
     sent = jax.nn.one_hot(experts, probs.shape[-1]).sum(1)     # (T, E)
-    return jnp.einsum("te,tem->tm", probs * sent, every).reshape(x.shape)
+    here = (probs * sent)[:, first:first + p["wi"].shape[0]]
+    return jnp.einsum("te,tem->tm", here, every).reshape(x.shape)
 
 
 def _skewed(t, e, k):
@@ -262,39 +265,205 @@ def test_moe_mlp_matches_dense_per_token_reference(ffn, e, k, routing):
             err_msg=jax.tree_util.keystr(path))
 
 
-def test_moe_mlp_moves_rows_only_by_four_gathers():
+# ------------------------- a layer that holds a share of the experts -----
+
+# 2 of 8 experts held, 512 tokens, 2 experts a token: T x k = 1024 pairs,
+# the prefix C = 2 x 1024 x 2 / 8 = 512 rows, one tile.
+_HELD = dict(t=512, k=2, e=8, held=2, first=2)
+
+
+def _held_layer(remat=False):
+    import flax.linen as nn
+    from horovod_tpu.models import transformer
+
+    cfg = models.TransformerConfig(
+        d_model=16, n_heads=2, d_ff=24, dtype=jnp.float32,
+        block=models.BlockSpec(
+            ffn="swiglu", num_experts=_HELD["e"],
+            experts_per_token=_HELD["k"], experts_held=_HELD["held"],
+            first_expert_held=_HELD["first"]))
+    if not remat:
+        return moe_mod.MoeMlp(cfg)
+    # ``cfg.remat``'s policy (PR 31): nothing of this layer is kept.
+    return nn.remat(moe_mod.MoeMlp, policy=jax.checkpoint_policies
+                    .save_only_these_names(transformer.SAVED_FLASH_OUT,
+                                           transformer.SAVED_FLASH_LSE))(cfg)
+
+
+def _held_assignment(live):
+    """(T, k) distinct experts a token of which exactly ``live`` pairs
+    fall to the held experts 2 and 3, three in four of them to 2; the
+    rest to experts outside the share."""
+    t, k = _HELD["t"], _HELD["k"]
+    assert k == 2 and 0 <= live <= t * k
+    token = np.arange(t)
+    table = np.stack([4 + token % 4, token % 2], axis=1)      # none held
+    first = token < min(live, t)
+    table[first, 0] = np.where(token[first] % 4, 2, 3)
+    both = token < live - t
+    table[both] = [2, 3]
+    assert np.isin(table, [2, 3]).sum() == live
+    return jnp.asarray(table, jnp.int32)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("live", [0, 100, 512, 513, 1024])
+def test_held_moe_mlp_prefix_and_whole_rows(live, remat, monkeypatch):
+    """The layer that holds 2 of 8 experts, its row arrays a prefix of
+    C = 512 sorted rows where the live rows fit it and the whole 1024
+    where they do not, by forced assignments that send exactly ``live``
+    pairs to the held experts: none, fewer than C, exactly C, one more,
+    all. Output and every gradient leaf against the dense reference
+    (float32, 1e-5), ``rows_overflow`` 0 or 1 as it should be, and
+    against the SAME layer made to run the whole length alone: equal
+    bit for bit, for the two bodies add the same numbers in the same
+    order. Then the core by itself, where the gates' gradient shows."""
+    from flax.core import meta
+    from horovod_tpu.utils import metrics
+
+    t, k, e = _HELD["t"], _HELD["k"], _HELD["e"]
+    c = moe_mod.prefix_rows(t, k, _HELD["held"], e)
+    assert c == 512 < t * k
+    layer = _held_layer(remat)
+    rng = jax.random.split(jax.random.PRNGKey(33), 3)
+    x = jax.random.normal(rng[0], (1, t, 16), jnp.float32)
+    ct = jax.random.normal(rng[1], x.shape, jnp.float32)
+    params = jax.tree.map(lambda a: 10.0 * a,
+                          meta.unbox(_held_layer().init(rng[2], x)))
+    assignment = _held_assignment(live)
+
+    def program(p, x_):
+        out, sown = layer.apply(p, x_, assignment, mutable=["moe"])
+        return jnp.sum(out * ct), (out, sown["moe"])
+
+    def reference(p, x_):
+        out = _dense_moe_reference(p, x_, k, assignment, _HELD["first"])
+        return jnp.sum(out * ct), out
+
+    def run(fn):
+        return jax.jit(jax.value_and_grad(fn, argnums=(0, 1), has_aux=True))(
+            params, x)
+
+    def row_arrays():
+        return {rows: metrics.REGISTRY.value(
+            "hvd_moe_row_arrays_total", rows=rows) or 0
+            for rows in ("prefix", "whole")}
+
+    before = row_arrays()
+    (_, (out, stats)), grads = run(program)
+    traced = {rows: n - before[rows] for rows, n in row_arrays().items()}
+    # Both bodies are traced, forward and backward, whatever runs.
+    assert traced["prefix"] == traced["whole"] >= 2, traced
+    assert int(stats["rows_held"][0]) == live
+    assert int(stats["rows_overflow"][0]) == int(live > c)
+
+    (_, want), want_grads = run(reference)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                jax.tree.leaves(want_grads)):
+        scale = float(jnp.max(jnp.abs(ref)))
+        assert scale > 0 or not live, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(
+            got, ref, rtol=1e-5, atol=1e-5 * scale,
+            err_msg=jax.tree_util.keystr(path))
+
+    # The whole length alone: the layer as it is where no prefix is
+    # shorter than T x k.
+    monkeypatch.setattr(moe_mod, "prefix_rows", lambda t, k, held, e: t * k)
+    (_, (whole_out, whole_stats)), whole_grads = run(program)
+    assert int(whole_stats["rows_overflow"][0]) == 0
+    np.testing.assert_array_equal(out, whole_out)
+    for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                jax.tree.leaves(whole_grads)):
+        np.testing.assert_array_equal(
+            got, ref, err_msg=jax.tree_util.keystr(path))
+    if remat:
+        return
+
+    # The core: the choice against the whole-length body, on the gates
+    # (T, k) of this routing as on tokens and weights.
+    p = params["params"]
+    tokens = x.reshape(t, 16)
+    scores = jax.nn.softmax(tokens @ p["router"], axis=-1)
+    gates = jnp.take_along_axis(scores, assignment, axis=1)
+    order, inverse = moe_mod.sorted_by_expert(assignment, _HELD["first"], e)
+    sizes = jnp.bincount(assignment.reshape(-1), length=e)[2:4]
+    fixed = (order, inverse, sizes.astype(jnp.int32),
+             jnp.sum(sizes).astype(jnp.int32))
+
+    def core(body):
+        def f(tokens, gates, wi, wo, wg):
+            return body(k, tokens, fixed[0], fixed[1], gates, fixed[2],
+                        fixed[3], wi, wo, wg)
+        out, vjp = jax.vjp(f, tokens, gates, p["wi"], p["wo"], p["wg"])
+        return (out,) + vjp(ct.reshape(t, 16))
+
+    chosen = jax.jit(lambda: core(
+        lambda *a: moe_mod._held_rows(c, *a)))()
+    whole = jax.jit(lambda: core(
+        lambda *a: moe_mod._expert_rows(t * k, *a)))()
+    assert len(chosen) == 6
+    for name, got, ref in zip(("out", "tokens", "gates", "wi", "wo", "wg"),
+                              chosen, whole):
+        assert live == 0 or float(jnp.max(jnp.abs(ref))) > 0, name
+        np.testing.assert_array_equal(got, ref, err_msg=name)
+
+
+@pytest.mark.parametrize("held", [None, 1], ids=["all-held", "share-held"])
+def test_moe_mlp_moves_rows_only_by_four_gathers(held):
     """Token-major dispatch and combine, read off the traced layer
     (bf16 compute over float32 parameters, forward + backward): nothing
     is broadcast to T x k rows, no row is scatter-added, a float32
     value of T x k x M elements exists only as the operand of its own
     reduction over k, and the rows move by four gathers, two of them
-    from the (T, M) array."""
+    from the (T, M) array.
+
+    The same of a layer that holds 1 of its 8 experts, in EACH of its
+    two bodies (the prefix of C = 512 sorted rows and the whole 2048):
+    the four sites, and the forward pair traced once more where the
+    backward pass recomputes the body it takes (its combine is dead
+    code there)."""
     import jax.extend
     from flax.core import meta
     from horovod_tpu.jax import introspect
     from horovod_tpu.utils import metrics
 
-    e, k, m, f = 4, 2, 40, 24
-    layer = _moe_layer("swiglu", e, k, dtype=jnp.bfloat16, d_model=m, d_ff=f)
-    x = jnp.ones((1, 16, m), jnp.bfloat16)
-    t, rows = 16, 16 * k
+    if held is None:
+        e, k, m, f, t = 4, 2, 40, 24, 16
+        lengths = {"whole": t * k}
+    else:
+        e, k, m, f, t = 8, 2, 40, 24, 1024
+        lengths = {"whole": t * k,
+                   "prefix": moe_mod.prefix_rows(t, k, held, e)}
+        assert lengths["prefix"] == 512
+    cfg = models.TransformerConfig(
+        d_model=m, n_heads=2, d_ff=f, dtype=jnp.bfloat16,
+        block=models.BlockSpec(ffn="swiglu", num_experts=e,
+                               experts_per_token=k, experts_held=held or 0))
+    layer = moe_mod.MoeMlp(cfg)
+    x = jnp.ones((1, t, m), jnp.bfloat16)
     params = meta.unbox(layer.init(jax.random.PRNGKey(0), x))
 
-    sites = {("dispatch_fwd", "tokens"), ("combine_fwd", "rows"),
-             ("combine_bwd", "tokens"), ("dispatch_bwd", "rows")}
+    # (site, source): times traced in one body, forward + backward.
+    sites = {("dispatch_fwd", "tokens"): 1, ("combine_fwd", "rows"): 1,
+             ("combine_bwd", "tokens"): 1, ("dispatch_bwd", "rows"): 1}
+    if held:
+        sites[("dispatch_fwd", "tokens")] = sites[("combine_fwd", "rows")] = 2
 
     def row_gathers():
-        return {key: metrics.REGISTRY.value(
-            "hvd_moe_row_gathers_total", site=key[0], source=key[1]) or 0
-            for key in sites | {("dispatch_fwd", "rows"),
-                                ("combine_bwd", "rows")}}
+        return {key + (rows,): metrics.REGISTRY.value(
+            "hvd_moe_row_gathers_total", site=key[0], source=key[1],
+            rows=rows) or 0
+            for rows in ("whole", "prefix")
+            for key in set(sites) | {("dispatch_fwd", "rows"),
+                                     ("combine_bwd", "rows")}}
 
     before = row_gathers()
     jaxpr = jax.make_jaxpr(lambda p, x_: jax.vjp(layer.apply, p, x_)[1](x_))(
         params, x)
     moved = {key: n - before[key] for key, n in row_gathers().items()}
-    assert {key for key, n in moved.items() if n} == sites
-    assert set(moved.values()) == {0, 1}
+    assert {key: n for key, n in moved.items() if n} == {
+        key + (rows,): n for rows in lengths for key, n in sites.items()}
 
     eqns = list(introspect.equations(jaxpr.jaxpr))
     users = {}
@@ -307,18 +476,33 @@ def test_moe_mlp_moves_rows_only_by_four_gathers():
         name = eqn.primitive.name
         for out in eqn.outvars:
             shape = getattr(out.aval, "shape", ())
-            if int(np.prod(shape)) != rows * m or not shape or shape[-1] != m:
+            if (not shape or shape[-1] != m or int(np.prod(shape))
+                    not in [n * m for n in lengths.values()]):
+                continue
+            # Where all experts are held nothing is broadcast to rows at
+            # all; a share-held body masks its dead rows by selects: a
+            # select's mask and its scalar zero are no rows.
+            if held and (out.aval.dtype == jnp.bool_ or (
+                    name == "broadcast_in_dim"
+                    and eqn.invars[0].aval.shape == ())):
                 continue
             assert name != "broadcast_in_dim", eqn
             assert not name.startswith("scatter"), eqn
             if name == "gather":
-                row_gathers.append(eqn.invars[0].aval.shape)
+                row_gathers.append((shape[0], eqn.invars[0].aval.shape[0]))
             if out.aval.dtype == jnp.float32:
                 assert name == "convert_element_type", eqn
                 assert [u.primitive.name for u in users[out]] == [
                     "reduce_sum"], eqn
                 assert users[out][0].outvars[0].aval.shape == (t, m)
-    assert sorted(row_gathers) == [(t, m), (t, m), (rows, m), (rows, m)]
+    # (rows gathered, rows of the array read), each body's.
+    want = []
+    for n in lengths.values():
+        want += [(n, t)] * (sites[("dispatch_fwd", "tokens")] + 1)
+        want += [(t * k, n)] * (sites[("combine_fwd", "rows")] + 1)
+    assert sorted(row_gathers) == sorted(want)
+    assert len([eqn for eqn in eqns if eqn.primitive.name == "cond"]) == (
+        2 if held else 0)
     # Nor anything else: the gates' gradient reaches the probabilities
     # by a select, the permutations are sorts.
     assert not [eqn for eqn in eqns

@@ -18,6 +18,10 @@ Top-level usage mirrors Horovod::
     g = hvd.allreduce_ingraph(g, op=hvd.Average, axis="data")
 """
 
+import time as _time
+
+_IMPORT_BEGAN = _time.time()   # the launch's `import` span, filed below
+
 __version__ = "0.2.0"
 
 from horovod_tpu.common import (  # noqa: F401
@@ -104,6 +108,10 @@ from horovod_tpu.parallel import (  # noqa: F401
     make_mesh,
     set_global_mesh,
 )
+from horovod_tpu.utils.timeline import (  # noqa: F401
+    LAUNCH_LOG as _LAUNCH_LOG,
+    launch_spans,
+)
 
 
 def run(*args, **kwargs):
@@ -135,3 +143,6 @@ def __getattr__(name):
         return getattr(_parallel, name)
     raise AttributeError("module %r has no attribute %r"
                          % (__name__, name))
+
+
+_LAUNCH_LOG.record("import", _IMPORT_BEGAN, _time.time())

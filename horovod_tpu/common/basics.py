@@ -22,6 +22,7 @@ reference's Gloo env contract, reference: horovod/runner/gloo_run.py:65-76):
 from __future__ import annotations
 
 import atexit
+import contextlib
 import logging
 import os
 import threading
@@ -29,6 +30,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from horovod_tpu.common.exceptions import HorovodInternalError
+from horovod_tpu.utils.timeline import LAUNCH_LOG
 
 logger = logging.getLogger("horovod_tpu")
 
@@ -167,102 +169,128 @@ def init(process_sets=None):
         process_sets: optional list of ``ProcessSet`` objects to register at
             init time (analog of the reference's ``process_sets`` argument).
     """
-    with _ctx.lock:
-        if _ctx.initialized:
-            return
-        # Env-knob registry: translate reference-named aliases
-        # (HOROVOD_GLOO_*) and warn about set-but-meaningless knobs
-        # (reference knob surface: horovod/common/common.h:107-139).
-        from horovod_tpu.common import knobs
+    from horovod_tpu.utils.compile_cache import install_compile_listeners
 
-        knobs.apply_aliases()
-        knobs.warn_rejected()
-        # Unnamed-collective sequence numbers are per-world: reset so
-        # elastic-reset survivors and fresh respawns start aligned.
-        from horovod_tpu.ops import eager
-
-        eager._reset_name_counters()
-        _ctx.topology = _topology_from_env()
+    with contextlib.ExitStack() as launch:
+        with _ctx.lock:
+            if _ctx.initialized:
+                return
+            # The launch is recorded always (docs/timeline.md#launch):
+            # this init and its parts as spans, and from here on every
+            # program jax compiles or reads from its cache.
+            LAUNCH_LOG.begin_launch()
+            launch.enter_context(LAUNCH_LOG.span("init"))
+            install_compile_listeners()
+            # analysis: blocking-ok(once-per-process bootstrap:
+            # init() must be atomic under _ctx.lock — a second
+            # thread calling init()/shutdown() mid-negotiation has
+            # to wait for a fully built core either way, and the
+            # rendezvous poll IS the init work)
+            _start_world(process_sets)
+        # Outside the init lock, inside the `init` span.
         if _ctx.topology.size > 1:
-            from horovod_tpu.core import CoreSession
+            with LAUNCH_LOG.span("init/flash_tile_sync"):
+                _sync_flash_tiles()
 
-            # Elastic runs publish controller_port 0 (= negotiated):
-            # the launcher's free_port() probes the wrong host — only
-            # the rank-0 WORKER host knows what it can bind. Rank 0
-            # picks a port there and reports it through the rendezvous
-            # KV; everyone else polls it before dialing
-            # (elastic/worker.negotiate_controller_port).
-            if (os.environ.get("HOROVOD_CONTROLLER_PORT", "0") in ("", "0")
-                    and os.environ.get("HOROVOD_ELASTIC")
-                    and os.environ.get("HOROVOD_RENDEZVOUS_ADDR")):
-                from horovod_tpu.elastic.worker import (
-                    negotiate_controller_port,
-                )
 
-                # analysis: blocking-ok(once-per-process bootstrap:
-                # init() must be atomic under _ctx.lock — a second
-                # thread calling init()/shutdown() mid-negotiation has
-                # to wait for a fully built core either way, and the
-                # rendezvous poll IS the init work)
+def _start_world(process_sets):
+    """``init()``'s part under ``_ctx.lock``."""
+    # Env-knob registry: translate reference-named aliases
+    # (HOROVOD_GLOO_*) and warn about set-but-meaningless knobs
+    # (reference knob surface: horovod/common/common.h:107-139).
+    from horovod_tpu.common import knobs
+
+    knobs.apply_aliases()
+    knobs.warn_rejected()
+    # Unnamed-collective sequence numbers are per-world: reset so
+    # elastic-reset survivors and fresh respawns start aligned.
+    from horovod_tpu.ops import eager
+
+    eager._reset_name_counters()
+    _ctx.topology = _topology_from_env()
+    if _ctx.topology.size > 1:
+        from horovod_tpu.core import CoreSession
+
+        # Elastic runs publish controller_port 0 (= negotiated):
+        # the launcher's free_port() probes the wrong host — only
+        # the rank-0 WORKER host knows what it can bind. Rank 0
+        # picks a port there and reports it through the rendezvous
+        # KV; everyone else polls it before dialing
+        # (elastic/worker.negotiate_controller_port).
+        if (os.environ.get("HOROVOD_CONTROLLER_PORT", "0") in ("", "0")
+                and os.environ.get("HOROVOD_ELASTIC")
+                and os.environ.get("HOROVOD_RENDEZVOUS_ADDR")):
+            from horovod_tpu.elastic.worker import (
+                negotiate_controller_port,
+            )
+
+            # Blocks under init()'s lock, by design (see its call).
+            with LAUNCH_LOG.span("init/negotiate_port"):
                 negotiate_controller_port(_ctx.topology.rank)
+        with LAUNCH_LOG.span("init/core_start", size=_ctx.topology.size):
             _ctx.core = CoreSession.start(_ctx.topology)
-        _ctx.generation += 1
-        if _ctx.topology.size > 1:
-            _ctx.shared_high_water = True
-        _ctx.initialized = True
-        timeline_path = os.environ.get("HOROVOD_TIMELINE")
-        if timeline_path:
-            # "{rank}" placeholder gives per-rank files on shared storage.
-            timeline_path = timeline_path.replace(
-                "{rank}", str(_ctx.topology.rank))
-            mark = os.environ.get(
-                "HOROVOD_TIMELINE_MARK_CYCLES", "") not in ("", "0")
-            from horovod_tpu.utils.timeline import Timeline
+    _ctx.generation += 1
+    if _ctx.topology.size > 1:
+        _ctx.shared_high_water = True
+    _ctx.initialized = True
+    timeline_path = os.environ.get("HOROVOD_TIMELINE")
+    if timeline_path:
+        # "{rank}" placeholder gives per-rank files on shared storage.
+        timeline_path = timeline_path.replace(
+            "{rank}", str(_ctx.topology.rank))
+        mark = os.environ.get(
+            "HOROVOD_TIMELINE_MARK_CYCLES", "") not in ("", "0")
+        from horovod_tpu.utils.timeline import Timeline
 
-            _ctx.timeline = Timeline(timeline_path, mark_cycles=mark)
-            # The env-initiated timeline starts BOTH writers, exactly
-            # like hvd.start_timeline (the native one carries the
-            # per-tensor phase lanes and cycle marks).
-            if _ctx.core is not None:
-                _ctx.core.attach_timeline(_ctx.timeline)
-                _ctx.core.start_core_timeline(
-                    timeline_path + ".core.json", mark_cycles=mark)
-        if process_sets:
-            from horovod_tpu.common import process_sets as ps_mod
+        _set_timeline(Timeline(timeline_path, mark_cycles=mark))
+        # The env-initiated timeline starts BOTH writers, exactly
+        # like hvd.start_timeline (the native one carries the
+        # per-tensor phase lanes and cycle marks).
+        if _ctx.core is not None:
+            _ctx.core.attach_timeline(_ctx.timeline)
+            _ctx.core.start_core_timeline(
+                timeline_path + ".core.json", mark_cycles=mark)
+    if process_sets:
+        from horovod_tpu.common import process_sets as ps_mod
 
-            for ps in process_sets:
-                ps_mod.add_process_set(ps)
-        # Stall/health reporter: keeps hvd_seconds_since_last_collective
-        # and the core's pending/stalled gauges fresh between scrapes
-        # (docs/metrics.md). Registry and counters deliberately survive
-        # shutdown/init cycles (elastic resets are themselves counted).
-        from horovod_tpu.utils import metrics as metrics_mod
+        for ps in process_sets:
+            ps_mod.add_process_set(ps)
+    # Stall/health reporter: keeps hvd_seconds_since_last_collective
+    # and the core's pending/stalled gauges fresh between scrapes
+    # (docs/metrics.md). Registry and counters deliberately survive
+    # shutdown/init cycles (elastic resets are themselves counted).
+    from horovod_tpu.utils import metrics as metrics_mod
 
-        metrics_mod.start_health_reporter()
-        # Flight recorder (docs/flightrec.md): dump-on-SIGTERM so a
-        # wedge-cull's SIGTERM->SIGKILL grace window leaves evidence
-        # behind. Best-effort: init off the main thread (or
-        # HVD_FLIGHTREC_SIGNAL=0 / HVD_FLIGHTREC=0) just skips it.
-        from horovod_tpu.utils import flightrec as flightrec_mod
+    metrics_mod.start_health_reporter()
+    # Flight recorder (docs/flightrec.md): dump-on-SIGTERM so a
+    # wedge-cull's SIGTERM->SIGKILL grace window leaves evidence
+    # behind. Best-effort: init off the main thread (or
+    # HVD_FLIGHTREC_SIGNAL=0 / HVD_FLIGHTREC=0) just skips it.
+    from horovod_tpu.utils import flightrec as flightrec_mod
 
-        flightrec_mod.install_signal_handler()
-        port_env = os.environ.get("HVD_METRICS_PORT")
-        if port_env not in (None, ""):
+    flightrec_mod.install_signal_handler()
+    port_env = os.environ.get("HVD_METRICS_PORT")
+    if port_env not in (None, ""):
+        with LAUNCH_LOG.span("init/metrics_server"):
             _try_start_metrics_server(
                 port_env, "HVD_METRICS_PORT=%s" % port_env,
                 offset_local_rank=True)
-            _ctx.metrics_restart_port = None
-        elif _ctx.metrics_restart_port is not None:
-            # A server the user started programmatically before an
-            # elastic reset: rebind the same (already rank-offset)
-            # port so scrapers keep working across the new world. A
-            # transient bind failure keeps the port remembered so the
-            # NEXT reset retries instead of going dark for good.
+        _ctx.metrics_restart_port = None
+    elif _ctx.metrics_restart_port is not None:
+        # A server the user started programmatically before an
+        # elastic reset: rebind the same (already rank-offset)
+        # port so scrapers keep working across the new world. A
+        # transient bind failure keeps the port remembered so the
+        # NEXT reset retries instead of going dark for good.
+        with LAUNCH_LOG.span("init/metrics_server"):
             if _try_start_metrics_server(
                     _ctx.metrics_restart_port,
                     "metrics server restart after reset") is not None:
                 _ctx.metrics_restart_port = None
-        atexit.register(shutdown)
+    atexit.register(shutdown)
+
+
+def _sync_flash_tiles():
     # Flash-tile cache sync (ops/block_tuner.py): multi-rank tile
     # decisions come from rank 0's cache view, shipped ONCE per world
     # formation — here, where every rank (elastic survivors and
@@ -271,22 +299,21 @@ def init(process_sets=None):
     # (it issues an eager broadcast on the now-live world). Every rank
     # participates unconditionally — rank 0's env decides the payload,
     # so per-rank HVD_FLASH_TUNE divergence cannot wedge init.
-    if _ctx.topology.size > 1:
-        from horovod_tpu.ops import block_tuner
+    from horovod_tpu.ops import block_tuner
 
-        try:
-            block_tuner.sync_cache_across_world()
-        except Exception as e:  # analysis: allow-broad-except — this
-            # init runs on the ELASTIC RESET path (reinit_for_version),
-            # OUTSIDE the worker's recovery try/except: a peer dying
-            # mid-broadcast must degrade to "no synced view this
-            # world" (all ranks fail the cascade together and fall
-            # back to defaults uniformly; the next in-loop collective
-            # triggers normal rollback/rejoin), never kill survivors
-            # that still have failure budget.
-            logger.warning(
-                "flash tuner cache sync failed (%s); continuing "
-                "without a synced view for this world", e)
+    try:
+        block_tuner.sync_cache_across_world()
+    except Exception as e:  # analysis: allow-broad-except — this
+        # init runs on the ELASTIC RESET path (reinit_for_version),
+        # OUTSIDE the worker's recovery try/except: a peer dying
+        # mid-broadcast must degrade to "no synced view this
+        # world" (all ranks fail the cascade together and fall
+        # back to defaults uniformly; the next in-loop collective
+        # triggers normal rollback/rejoin), never kill survivors
+        # that still have failure budget.
+        logger.warning(
+            "flash tuner cache sync failed (%s); continuing "
+            "without a synced view for this world", e)
 
 
 def shutdown():
@@ -314,11 +341,7 @@ def shutdown():
                 _ctx.core.shutdown()
             finally:
                 _ctx.core = None
-        if _ctx.timeline is not None:
-            try:
-                _ctx.timeline.close()
-            finally:
-                _ctx.timeline = None
+        _set_timeline(None)
         # Preserve the bound port across the stop so an elastic
         # shutdown/init cycle re-serves on it (stop_metrics_server
         # clears it — an explicit user stop means stay stopped).
@@ -488,6 +511,16 @@ def _timeline():
     return _ctx.timeline
 
 
+def _set_timeline(timeline):
+    """Swap the process's ``Timeline`` under ``_ctx.lock`` (None: only
+    close the one open). The launch's span log writes to whichever is
+    open (utils/timeline.py ``SpanLog.attach``)."""
+    old, _ctx.timeline = _ctx.timeline, timeline
+    LAUNCH_LOG.attach(timeline)
+    if old is not None:
+        old.close()
+
+
 def metrics_snapshot():
     """JSON-able snapshot of the process-wide metrics registry: native
     core counters (negotiation responses, cache hits, fusion), eager
@@ -611,9 +644,7 @@ def start_timeline(file_path: str, mark_cycles: bool = False):
     from horovod_tpu.utils.timeline import Timeline
 
     with _ctx.lock:
-        if _ctx.timeline is not None:
-            _ctx.timeline.close()
-        _ctx.timeline = Timeline(file_path, mark_cycles=mark_cycles)
+        _set_timeline(Timeline(file_path, mark_cycles=mark_cycles))
         if _ctx.core is not None:
             _ctx.core.attach_timeline(_ctx.timeline)
             # The native loop writes its own spans (negotiation, fused op
@@ -627,9 +658,7 @@ def start_timeline(file_path: str, mark_cycles: bool = False):
 def stop_timeline():
     _check_initialized()
     with _ctx.lock:
-        if _ctx.timeline is not None:
-            _ctx.timeline.close()
-            _ctx.timeline = None
+        _set_timeline(None)
         if _ctx.core is not None:
             _ctx.core.attach_timeline(None)
             _ctx.core.stop_core_timeline()

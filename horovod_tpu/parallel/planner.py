@@ -60,6 +60,7 @@ from horovod_tpu.parallel.mesh import (
     set_global_mesh,
     shard_map_compat,
 )
+from horovod_tpu.utils.timeline import LAUNCH_LOG
 
 __all__ = [
     "Plan", "PlanError", "Topology", "Workload", "plan",
@@ -160,8 +161,9 @@ class Plan:
         axis, the ``grouped_hierarchical_allreduce`` ladder on a
         ``(data_dcn, data_ici)`` factorization.
         """
-        mesh = make_mesh(self.mesh_axes, devices=devices)
-        set_global_mesh(mesh)
+        with LAUNCH_LOG.span("plan/apply", axes=dict(self.mesh_axes)):
+            mesh = make_mesh(self.mesh_axes, devices=devices)
+            set_global_mesh(mesh)
         # apply() OWNS the routing toggle, in both directions: the
         # same flag a manual user sets (docs/configuration.md) arms
         # the (dcn, ici) ladder in collective_ops, and a later
@@ -344,35 +346,38 @@ def plan(params=None, *, batch: Optional[int] = None, seq_len: int = 1,
     Returns a :class:`Plan`; raises :class:`PlanError` when no legal
     feasible layout exists.
     """
-    if workload is None:
-        if batch is None:
-            raise ValueError("plan() needs batch= (or a prebuilt "
-                             "workload=)")
-        if params is not None:
-            workload = workload_from_params(
-                params, batch=batch, seq_len=seq_len, d_model=d_model,
-                n_layers=n_layers, num_experts=num_experts,
-                pipeline_stages=pipeline_stages,
-                dtype_bytes=dtype_bytes)
-        else:
-            workload = Workload(
-                param_bytes=int(param_bytes or 0), batch=batch,
-                seq_len=seq_len, d_model=d_model or 1,
-                n_layers=n_layers, num_experts=num_experts,
-                expert_param_bytes=int(expert_param_bytes),
-                dtype_bytes=int(dtype_bytes) if dtype_bytes else 4,
-                pipeline_stages=pipeline_stages)
-    if topology is None:
-        if chips is None:
-            import jax
+    with LAUNCH_LOG.span("plan") as span:
+        if workload is None:
+            if batch is None:
+                raise ValueError("plan() needs batch= (or a prebuilt "
+                                 "workload=)")
+            if params is not None:
+                workload = workload_from_params(
+                    params, batch=batch, seq_len=seq_len, d_model=d_model,
+                    n_layers=n_layers, num_experts=num_experts,
+                    pipeline_stages=pipeline_stages,
+                    dtype_bytes=dtype_bytes)
+            else:
+                workload = Workload(
+                    param_bytes=int(param_bytes or 0), batch=batch,
+                    seq_len=seq_len, d_model=d_model or 1,
+                    n_layers=n_layers, num_experts=num_experts,
+                    expert_param_bytes=int(expert_param_bytes),
+                    dtype_bytes=int(dtype_bytes) if dtype_bytes else 4,
+                    pipeline_stages=pipeline_stages)
+        if topology is None:
+            if chips is None:
+                import jax
 
-            chips = jax.device_count()
-        topology = Topology.make(chips, dcn=dcn)
+                chips = jax.device_count()
+            topology = Topology.make(chips, dcn=dcn)
 
-    candidates = costmodel.enumerate_candidates(
-        workload, topology, require_axes)
-    chosen, rejected = costmodel.choose(candidates)
-    return _plan_from_candidate(chosen, rejected, workload, topology)
+        candidates = costmodel.enumerate_candidates(
+            workload, topology, require_axes)
+        chosen, rejected = costmodel.choose(candidates)
+        made = _plan_from_candidate(chosen, rejected, workload, topology)
+        span.update(chips=topology.chips, axes=dict(made.mesh_axes))
+    return made
 
 
 def _plan_from_candidate(chosen: Candidate, rejected: List[Candidate],

@@ -7,14 +7,28 @@ and operation phases written as chrome://tracing JSON, toggled by
 span per submitted tensor from enqueue to completion; like the
 reference, the file is a JSON event array left open for streaming
 (chrome://tracing accepts an unterminated array).
+
+The launch has a span log of its own (``SpanLog``, ``LAUNCH_LOG``):
+what happens between ``import horovod_tpu`` and the first step --
+``hvd.init()`` and its parts, ``hvd.plan()``, ``Plan.apply()``, and
+every program jax traces, lowers and compiles or reads from its cache
+(``utils/compile_cache.py`` listens to jax's own events) -- is kept in
+memory on ``time.time()`` and read with ``hvd.launch_spans()``; an open
+``Timeline`` receives each span as a ``B``/``E`` pair under category
+``launch`` (docs/timeline.md#launch).
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import itertools
 import json
 import threading
 import time
-from typing import Optional
+from typing import Dict, List, Optional
+
+from horovod_tpu.utils import metrics as _metrics
 
 
 class Timeline:
@@ -27,6 +41,9 @@ class Timeline:
         self._f = open(file_path, "w")
         self._f.write("[\n")
         self._t0 = time.perf_counter()
+        # The same instant on the wall clock: a launch span keeps
+        # ``time.time()`` and is placed by it (``span``).
+        self._t0_wall = time.time()
         self._closed = False
         self._buf = []
         self._stop_flusher = threading.Event()
@@ -102,6 +119,19 @@ class Timeline:
         self._write({"name": name, "ph": "i", "ts": self._now_us(),
                      "pid": self._pid if pid is None else pid, "s": "p"})
 
+    def span(self, name: str, category: str, start: float, end: float,
+             args: Optional[dict] = None, tid: Optional[str] = None):
+        """A FINISHED span as a ``B``/``E`` pair, placed by its
+        ``time.time()`` edges (before this file was opened: a negative
+        ``ts``, which the viewers accept)."""
+        for ph, wall in (("B", start), ("E", end)):
+            ev = {"name": name, "cat": category, "ph": ph,
+                  "ts": (wall - self._t0_wall) * 1e6, "pid": self._pid,
+                  "tid": category if tid is None else tid}
+            if args and ph == "B":
+                ev["args"] = args
+            self._write(ev)
+
     def write_raw(self, event: dict):
         """Append one pre-built Chrome-trace event (tools/trace's
         merged-trace path: events carry their own ts/pid/tid)."""
@@ -132,3 +162,135 @@ class Timeline:
                 self._closed = True
                 self._flush_locked()
                 self._f.close()
+
+
+# --- the launch's span log ---------------------------------------------------
+
+LAUNCH_CATEGORY = "launch"
+# The program's own phases of a launch, span name -> label of
+# hvd_launch_phase_seconds.
+LAUNCH_PHASES = {"import": "import", "init": "init", "plan": "plan",
+                 "plan/apply": "apply"}
+
+_G_LAUNCH_PHASE = _metrics.gauge(
+    "hvd_launch_phase_seconds",
+    "Seconds the newest launch spent in the program's own phases: "
+    "import (horovod_tpu's import, once a process), init (hvd.init()), "
+    "plan (every hvd.plan() call since that init), apply "
+    "(Plan.apply()). The spans behind them: hvd.launch_spans().",
+    ("phase",))
+
+
+class SpanLog:
+    """Spans of a process's launches, in memory: the newest
+    ``capacity`` of them, each a dict of ``id``, ``parent`` (the span
+    open on the same thread when this one began, else None), ``launch``
+    (the count of ``hvd.init()`` calls begun in this process, at least
+    1), ``name``, ``start`` and ``end`` on ``time.time()`` (``end`` None
+    while the span is open) and ``args``.
+
+    Written to from the few places a launch passes through, never from
+    a traced function or a training loop; an attached ``Timeline``
+    receives every span that closes (and, when attached, those that
+    closed before)."""
+
+    def __init__(self, capacity: int = 1024, phase_gauge=None):
+        self._lock = threading.Lock()
+        self._spans = collections.deque(maxlen=capacity)
+        self._ids = itertools.count(1)
+        self._launches = 0
+        self._open = threading.local()
+        self._sink: Optional[Timeline] = None
+        self._phase_gauge = phase_gauge
+
+    def begin_launch(self) -> int:
+        """Called by ``hvd.init()`` as it begins: spans from here on
+        belong to the next launch. Returns its number."""
+        with self._lock:
+            self._launches += 1
+            launch = self._launches
+        if self._phase_gauge is not None:
+            for phase in LAUNCH_PHASES.values():
+                if phase != "import":   # once a process, not a launch
+                    self._phase_gauge.labels(phase=phase).set(0.0)
+        return launch
+
+    def _stack(self) -> List[dict]:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def _new(self, name, start, args) -> dict:
+        stack = self._stack()
+        # The innermost span open on this thread when this one began
+        # (a span filed after the fact may have begun before it).
+        parent = next((s["id"] for s in reversed(stack)
+                       if s["start"] <= start), None)
+        with self._lock:
+            span = {"id": next(self._ids), "parent": parent,
+                    "launch": max(self._launches, 1), "name": name,
+                    "start": start, "end": None, "args": args}
+            self._spans.append(span)
+        return span
+
+    def _close(self, span: dict, end: float):
+        with self._lock:   # against attach(): emitted once, by one side
+            span["end"] = end
+            sink = self._sink
+        if self._phase_gauge is not None and span["name"] in LAUNCH_PHASES:
+            self._phase_gauge.labels(
+                phase=LAUNCH_PHASES[span["name"]]).inc(end - span["start"])
+        if sink is not None:
+            self._emit(sink, span)
+
+    @staticmethod
+    def _emit(sink: "Timeline", span: dict):
+        args = dict(span["args"], id=span["id"], launch=span["launch"])
+        if span["parent"] is not None:
+            args["parent"] = span["parent"]
+        sink.span(span["name"], LAUNCH_CATEGORY, span["start"],
+                  span["end"], args=args)
+
+    @contextlib.contextmanager
+    def span(self, name: str, **args):
+        """Open a span round the ``with`` body; yields its ``args``
+        dict, which the body may add to."""
+        span = self._new(name, time.time(), args)
+        stack = self._stack()
+        stack.append(span)
+        try:
+            yield args
+        finally:
+            stack.remove(span)
+            self._close(span, time.time())
+
+    def record(self, name: str, start: float, end: float, **args):
+        """File a span that has already ended (jax reports a compile
+        phase when it is over)."""
+        self._close(self._new(name, start, args), end)
+
+    def spans(self) -> List[Dict]:
+        """Copies of the spans kept, oldest first."""
+        with self._lock:
+            return [dict(s, args=dict(s["args"])) for s in self._spans]
+
+    def attach(self, timeline: Optional["Timeline"]):
+        """Send every span that closes from now on to ``timeline`` too,
+        and first those kept that closed before it was opened (the
+        import, the ``init`` under way); None detaches."""
+        with self._lock:
+            self._sink = timeline
+            closed = [s for s in self._spans if s["end"] is not None]
+        if timeline is not None:
+            for span in closed:
+                self._emit(timeline, span)
+
+
+LAUNCH_LOG = SpanLog(phase_gauge=_G_LAUNCH_PHASE)
+
+
+def launch_spans() -> List[Dict]:
+    """``hvd.launch_spans()``: what this process's launches were made
+    of (docs/timeline.md#launch)."""
+    return LAUNCH_LOG.spans()

@@ -72,9 +72,28 @@ KERNEL_FLASH_DKV = "hvd_flash_dkv"
 KERNEL_FLASH_DQ = "hvd_flash_dq"
 # ``checkpoint_name``s of what the forward kernel made, as the backward
 # kernels read it: the (B, H, S, D) output and the (B, H, S) float32
-# log-sum-exp. ``Transformer``'s ``remat`` keeps exactly these.
+# log-sum-exp; and of what it READ, its (B, H, S, D) / (B, H_kv, S, D)
+# operands, which are the backward kernels' too. ``Transformer``'s
+# ``remat`` keeps these, so a recomputed block neither runs the kernel
+# again nor makes its operands again.
 SAVED_FLASH_OUT = "hvd_flash_out"
 SAVED_FLASH_LSE = "hvd_flash_lse"
+SAVED_FLASH_Q = "hvd_flash_q"
+SAVED_FLASH_K = "hvd_flash_k"
+SAVED_FLASH_V = "hvd_flash_v"
+# What else a recomputed block keeps (models/transformer.py
+# ``_remat_block`` holds the rule and the bytes): the matmul products
+# that the backward pass reads. In the attention module the latent
+# down-projections' products, which a NORM reads (a norm's backward
+# reads its input), the output gate's projection, and the output
+# projection's product; in the dense feed-forward (a shared expert is
+# one) the up and gate products and, where a norm reads it, the output.
+SAVED_ATTN_PRENORM = "hvd_attn_prenorm"
+SAVED_ATTN_GATE = "hvd_attn_gate_proj"
+SAVED_ATTN_OUT = "hvd_attn_out"
+SAVED_MLP_UP = "hvd_mlp_up"
+SAVED_MLP_GATE = "hvd_mlp_gate"
+SAVED_MLP_OUT = "hvd_mlp_out"
 # The same for what an expert layer that chooses its row arrays' length
 # returns (parallel/moe.py ``_held_rows``): its backward rule recomputes
 # from its INPUTS, so a block needs the layer's forward again only where

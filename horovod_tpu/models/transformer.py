@@ -43,9 +43,19 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 from flax.linen import partitioning as nn_partitioning
+from jax.ad_checkpoint import checkpoint_name
 from horovod_tpu.jax.introspect import (
+    SAVED_ATTN_GATE,
+    SAVED_ATTN_OUT,
+    SAVED_ATTN_PRENORM,
+    SAVED_FLASH_K,
     SAVED_FLASH_LSE,
     SAVED_FLASH_OUT,
+    SAVED_FLASH_Q,
+    SAVED_FLASH_V,
+    SAVED_MLP_GATE,
+    SAVED_MLP_OUT,
+    SAVED_MLP_UP,
     SAVED_MOE_OUT,
     SCOPE_ATTN_GATE,
     SCOPE_EMBED,
@@ -187,10 +197,16 @@ class TransformerConfig:
     # 'ulysses' (all_to_all head/seq re-sharding).
     attention: str = "dense"
     seq_axis: Optional[str] = None  # mesh axis for ring/ulysses attention
-    # Recompute each block in the backward pass, except what its flash
-    # kernel made: the output and the log-sum-exp stay from forward to
-    # backward (B x S x H x D of ``dtype`` + B x H x S float32 a layer),
-    # so the kernel runs once. Any other ``attention`` keeps nothing.
+    # Recompute each block in the backward pass, except what a matmul
+    # or the flash kernel made and the backward pass reads
+    # (``_remat_block`` lists it): the recomputed forward is norms,
+    # activations, rotations and adds (and, where a norm stands on q
+    # and k, their two projections). Kept a layer, T tokens of
+    # ``dtype``: about T x (3 H D + 2 M + 2 F) for a block of H heads of
+    # D, width M and a feed-forward F wide, less with grouped key/value
+    # heads, more with a gate or a norm on a branch's output; 350-440
+    # MB at 8192 tokens of 2048 in bf16, 490-730 MB for a layer with a
+    # dense feed-forward of 3 to 5 times the width.
     remat: bool = False
     # None = auto (one-hot lookup only under manual subgroups, see
     # _use_onehot_embed); True/False forces the lookup style.
@@ -311,6 +327,10 @@ class SelfAttention(nn.Module):
             return jnp.einsum("bsm,mhd->bshd", x, w)
 
         q, k, v = projected(0), projected(1), projected(2)
+        # q and k carry no name here, before their norms, although a
+        # norm's backward reads its input: measured, a recomputed block
+        # that holds the kernel's operands is faster multiplying these
+        # two again than holding a second copy of them (``_REMAT_KEEPS``).
         if spec.qk_norm_per_head:
             # One scale vector of ``d`` for all heads of q, one for k.
             q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
@@ -327,11 +347,14 @@ class SelfAttention(nn.Module):
                 k = rope(k, first, spec.rope_theta)
         out = _attend(cfg, q, k, v, self.window)
         if spec.attn_gate:
-            gate = jnp.einsum("bsm,mhd->bshd", x, weight(
-                "wgate", (None, "model", None), (m, h, d)))
+            gate = checkpoint_name(
+                jnp.einsum("bsm,mhd->bshd", x, weight(
+                    "wgate", (None, "model", None), (m, h, d))),
+                SAVED_ATTN_GATE)
             with jax.named_scope(SCOPE_ATTN_GATE):
                 out = out * nn.sigmoid(gate)
-        return jnp.einsum("bshd,hdm->bsm", out, wo)
+        return checkpoint_name(jnp.einsum("bshd,hdm->bsm", out, wo),
+                               SAVED_ATTN_OUT)
 
 
 def _to_every_head(k_pe, n_heads):
@@ -368,13 +391,18 @@ class LatentAttention(nn.Module):
                               jnp.float32).astype(cfg.dtype)
 
         with jax.named_scope(SCOPE_MLA_LATENT):
-            c_q = _norm(cfg, "q_a_norm")(
-                x @ weight("q_a", (None, None), (m, spec.q_lora_rank)))
+            # The two down-projections' products, each read by a norm
+            # (as ``SelfAttention``'s q and k before theirs).
+            c_q = _norm(cfg, "q_a_norm")(checkpoint_name(
+                x @ weight("q_a", (None, None), (m, spec.q_lora_rank)),
+                SAVED_ATTN_PRENORM))
             q = jnp.einsum("bsr,rhd->bshd", c_q, weight(
                 "q_b", (None, "model", None),
                 (spec.q_lora_rank, h, nope + rot)))
-            down = x @ weight("kv_a", (None, None),
-                              (m, spec.kv_lora_rank + rot))
+            down = checkpoint_name(
+                x @ weight("kv_a", (None, None),
+                           (m, spec.kv_lora_rank + rot)),
+                SAVED_ATTN_PRENORM)
             c_kv = _norm(cfg, "kv_a_norm")(down[..., :spec.kv_lora_rank])
             kv = jnp.einsum("bsr,rhd->bshd", c_kv, weight(
                 "kv_b", (None, "model", None),
@@ -390,7 +418,9 @@ class LatentAttention(nn.Module):
                                 -1)
             v = kv[..., nope:]
         wo = weight("wo", ("model", None, None), (h, dv, m))
-        return jnp.einsum("bshd,hdm->bsm", _attend(cfg, q, k, v), wo)
+        return checkpoint_name(
+            jnp.einsum("bshd,hdm->bsm", _attend(cfg, q, k, v), wo),
+            SAVED_ATTN_OUT)
 
 
 class Mlp(nn.Module):
@@ -409,14 +439,18 @@ class Mlp(nn.Module):
                         (cfg.d_model, d_ff), jnp.float32)
         wo = self.param("wo", param_with_axes(init, ("model", None)),
                         (d_ff, cfg.d_model), jnp.float32)
-        y = x @ wi.astype(cfg.dtype)
+        # The three products carry names: a recomputed block keeps the
+        # two the activation's backward reads, and the third where a
+        # norm reads it (``_remat_block``), and runs the activation.
+        y = checkpoint_name(x @ wi.astype(cfg.dtype), SAVED_MLP_UP)
         if cfg.block.ffn == "swiglu":
             wg = self.param("wg", param_with_axes(init, (None, "model")),
                             (cfg.d_model, d_ff), jnp.float32)
-            y = nn.silu(x @ wg.astype(cfg.dtype)) * y
+            y = nn.silu(checkpoint_name(x @ wg.astype(cfg.dtype),
+                                        SAVED_MLP_GATE)) * y
         else:
             y = nn.gelu(y)
-        return y @ wo.astype(cfg.dtype)
+        return checkpoint_name(y @ wo.astype(cfg.dtype), SAVED_MLP_OUT)
 
 
 class Block(nn.Module):
@@ -475,30 +509,81 @@ class Block(nn.Module):
                       Mlp(cfg, self.dense_width, name="mlp")(y))
 
 
+# What a recomputed block keeps from forward to backward: the one place
+# that says it. The rule: keep what a MATMUL (or the attention kernel)
+# made and the backward pass reads, if it is T rows by a few model
+# widths; remake what an elementwise pass makes (norms, residual adds,
+# activations, casts, rotations, concatenations, transposes: HBM-bound
+# passes of 0.05-0.2 ms). A name is saved only where the backward pass
+# reads its array, so a block without the module, the norm or the gate
+# in question keeps nothing for that entry. The routed experts' row
+# arrays are not on the list: parallel/moe.py's backward rule recomputes
+# them from the layer's inputs. Bytes a layer at T = 8192 tokens in
+# bf16, GLM-4.7-Flash (20 heads of 256, M 2048) | Trinity-Mini (32 query
+# over 4 key/value heads of 128, gated, head norms, post norms); PERF.md
+# section 6 (PR 35) has the ms each entry spares a layer on a v5e.
+_REMAT_KEEPS = (
+    # The flash kernel's results: output 83.9 | 67.1 MB, log-sum-exp
+    # 0.7 | 1.0 MB (float32). Without them the kernel runs twice.
+    SAVED_FLASH_OUT, SAVED_FLASH_LSE,
+    # Its operands as the backward kernels read them, (B, H, S, D):
+    # 252 | 84 MB. Spares GLM's two up-projections, RoPE and the three
+    # copies that put q, k and v together; Trinity's v projection and,
+    # of q and k, the head norms, RoPE and transposes (not their two
+    # projections, which the norms' backward needs: below).
+    SAVED_FLASH_Q, SAVED_FLASH_K, SAVED_FLASH_V,
+    # A projection's product that a norm reads: GLM's two latent
+    # down-projections, 12.6 + 9.4 MB. Without it the projection is
+    # multiplied again for the norm's backward, whatever else is kept.
+    # NOT Trinity's q and k before their head norms (67.1 + 8.4 MB):
+    # beside the kernel's operands that second copy cost 1.1 ms a step
+    # more than it spared, so ``SelfAttention`` multiplies them again.
+    SAVED_ATTN_PRENORM,
+    # The output gate's projection, Trinity 67.1 MB: the multiply's
+    # backward and the output projection's weight gradient read it.
+    SAVED_ATTN_GATE,
+    # The attention branch's output, 33.5 MB: from it the recomputed
+    # forward reaches the feed-forward's input by a norm and an add.
+    SAVED_ATTN_OUT,
+    # ``Mlp``'s up and gate products (the dense layer 336 | 201 MB, a
+    # shared expert 50 | 33.5 MB) and its output where a norm reads it
+    # (Trinity 33.5 MB): the recomputed feed-forward is its activation.
+    SAVED_MLP_UP, SAVED_MLP_GATE, SAVED_MLP_OUT,
+    # What a share-held expert layer returns, where a norm reads it
+    # (Trinity 33.5 MB): that layer's choice is not made a second time.
+    SAVED_MOE_OUT,
+)
+
+
 @functools.cache
 def _log_remat(cfg, keeps):
     logger.info(
         "Transformer remat: %d blocks recomputed in the backward pass, "
-        "each keeps %s (attention=%r)", cfg.n_layers, keeps, cfg.attention)
+        "each keeps %s (attention=%r): what its matmuls made and the "
+        "backward pass reads, about T x (3 H D + 2 M + 2 F) of %s a "
+        "layer; norms, activations and adds are made again",
+        cfg.n_layers, keeps, cfg.attention, jnp.dtype(cfg.dtype).name)
 
 
 def _remat_block(cfg):
-    """``Block`` under recomputation that keeps what the attention
-    kernel made (the two names ops/pallas_attention.py gives its
-    output and log-sum-exp): the backward kernels read them, so the
-    recomputed forward has no use for a second run of the kernel.
-    Likewise what an expert layer that holds a share of the experts
-    returns, where the block reads it again (``post_norms``: the norm on
-    the feed-forward's output); that layer's backward rule recomputes
-    from its inputs (parallel/moe.py ``_held_rows``). Where nothing
-    carries the names, or the backward pass reads none of them, the
-    policy saves nothing, which is plain recomputation. Counted and
-    logged at trace time."""
-    keeps = "flash_out+lse" if cfg.attention == "flash" else "nothing"
+    """``Block`` under recomputation that keeps ``_REMAT_KEEPS``: the
+    attention kernel's operands and results (the names
+    ops/pallas_attention.py gives them: the backward kernels read all
+    five, so the recomputed forward has no use for a second run of the
+    kernel or of what made its operands) and the narrow matmul products
+    of the attention module and the dense feed-forward that the backward
+    pass reads, so that the recomputed forward multiplies nothing but
+    the router's logits and, under a norm on q and k, those two
+    projections. Likewise what an expert layer that holds a
+    share of the experts returns, where the block reads it again
+    (``post_norms``); that layer's backward rule recomputes from its
+    inputs (parallel/moe.py ``_held_rows``). Any other ``attention``
+    than 'flash' carries no kernel names and keeps the products alone.
+    Counted and logged at trace time."""
+    keeps = "flash+products" if cfg.attention == "flash" else "products"
     _M_REMAT_BLOCKS.labels(keeps=keeps).inc(cfg.n_layers)
     _log_remat(cfg, keeps)
-    policy = jax.checkpoint_policies.save_only_these_names(
-        SAVED_FLASH_OUT, SAVED_FLASH_LSE, SAVED_MOE_OUT)
+    policy = jax.checkpoint_policies.save_only_these_names(*_REMAT_KEEPS)
     return nn.remat(Block, policy=policy)
 
 

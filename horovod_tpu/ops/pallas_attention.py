@@ -751,10 +751,23 @@ def flash_attention(q, k, v, *, causal: bool = True,
             block_q = int(os.environ.get("HVD_FLASH_BLOCK_Q", rule_q))
         if block_k is None:
             block_k = int(os.environ.get("HVD_FLASH_BLOCK_K", rule_k))
-    # Kernel layout is (B, H, S, D).
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
+    from horovod_tpu.jax.introspect import (
+        SAVED_FLASH_K,
+        SAVED_FLASH_Q,
+        SAVED_FLASH_V,
+    )
+
+    # Kernel layout is (B, H, S, D). Named like the kernel's results,
+    # ONE value as its operand and as the residual: a recomputation that
+    # saves these names has the backward kernels' operands as the
+    # forward made them, after whatever norms, rotations,
+    # concatenations and transposes stand before this call. Named HERE,
+    # outside the ``hvd_flash`` scope: a kept array is written by the
+    # instruction that carries its name's scope, and what makes q, k
+    # and v is the attention module's work, not the kernel's glue.
+    qt = checkpoint_name(jnp.swapaxes(q, 1, 2), SAVED_FLASH_Q)
+    kt = checkpoint_name(jnp.swapaxes(k, 1, 2), SAVED_FLASH_K)
+    vt = checkpoint_name(jnp.swapaxes(v, 1, 2), SAVED_FLASH_V)
     block_q = _pick_block(max(qt.shape[2], 1), block_q, q.dtype)
     block_k = _pick_block(max(kt.shape[2], 1), block_k, k.dtype)
     out = _flash(qt, kt, vt, causal, window, block_q, block_k, scale,

@@ -339,16 +339,39 @@ def test_the_layer_that_holds_every_expert_chooses_nothing(topo, monkeypatch):
     assert introspect.SCOPE_MOE_ROWS not in text
 
 
+def _matmuls_recomputed(text):
+    """The scopes of the compiled step's ``dot`` / ``convolution``
+    instructions (in whatever computation: a fusion's body carries its
+    own) that lie under a block's recomputed forward."""
+    return [scope for scope in (
+        raw.partition('op_name="')[2].partition('"')[0]
+        for raw in text.splitlines()
+        if re.search(r" = \S+ (?:dot|convolution)\(", raw))
+        if "rematted_computation" in scope]
+
+
+def _held_bytes(compiled):
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+
+
 def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
     """GLM-4.7-Flash's step at its tiny sizes (latent attention, a dense
     block, two expert blocks that hold 2 of 16 experts, every block
     recomputed), compiled for a described v5e: the forward kernel runs
     ONCE a layer, as the backward kernels do, because the recomputation
-    keeps its output and log-sum-exp (``models/transformer.py``
-    ``_remat_block``); the rest of a block is still recomputed; and at
-    these sizes (T x k = 254 pairs, under one row tile) an expert layer
-    runs one body over all of them: no ``conditional``, the dead rows
-    of the grouped matmuls masked by selects.
+    keeps its operands, output and log-sum-exp (``models/transformer.py``
+    ``_REMAT_KEEPS``); no matmul of the attention module or of a dense
+    feed-forward (the dense block's, the shared expert's) stands under
+    the recomputed forward, because their products are kept too: what
+    is multiplied there again is each router's logits, the rest of a
+    block is norms, activations and adds; for that the step holds no
+    more than the kept arrays' own bytes beyond what it held with the
+    kernel's results alone; and at these sizes (T x k = 254 pairs,
+    under one row tile) an expert layer runs one body over all of them:
+    no ``conditional``, the dead rows of the grouped matmuls masked by
+    selects.
 
     The benchmark's builder still DECLARES two forward calls a layer
     under ``remat`` (``benchmark/builders/glm4_moe_lite.py``
@@ -362,8 +385,13 @@ def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
     monkeypatch.setattr(pallas_attention, "_should_interpret",
                         lambda interpret: False)
     cell = cells.load("glm47f-s8192-ep8-c1", tiny=True)
-    asm = cells.assemble(cell, topo.devices)
-    text = asm.step.lower(*cells.abstract_step_args(asm)).compile().as_text()
+
+    def compiled_step():
+        asm = cells.assemble(cell, topo.devices)
+        return asm, asm.step.lower(*cells.abstract_step_args(asm)).compile()
+
+    asm, compiled = compiled_step()
+    text = compiled.as_text()
     layers = cell.config["num_hidden_layers"]
     calls = {name: len(re.findall(r"^\s*(?:ROOT )?%%%s[.\d]* = " % name, text,
                                   re.M))
@@ -384,3 +412,33 @@ def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
         assert scope in text, scope
     assert "all-reduce" not in text
     assert not _branches(text)
+    # Multiplied a second time: the expert layers' routers, nothing of
+    # ``attn``, ``mlp`` or the shared expert.
+    recomputed = _matmuls_recomputed(text)
+    assert len(recomputed) == layers - 1, recomputed
+    assert all(introspect.SCOPE_MOE_ROUTER in s for s in recomputed)
+    # The control, and the bill: with the kernel's and the expert
+    # layer's results alone on the list, every projection is there
+    # again, and the step holds less by at most the kept arrays: a
+    # layer's T rows (bf16) of q, k, v, the two latent down-products,
+    # the branch's output and the feed-forward's up and gate.
+    from horovod_tpu.models import transformer as transformer_module
+
+    monkeypatch.setattr(
+        transformer_module, "_REMAT_KEEPS",
+        (introspect.SAVED_FLASH_OUT, introspect.SAVED_FLASH_LSE,
+         introspect.SAVED_MOE_OUT))
+    before = compiled_step()[1]
+    made_twice = _matmuls_recomputed(before.as_text())
+    # Four latent projections and the output projection a layer, the
+    # dense block's up and gate, each shared expert's, each router.
+    assert len(made_twice) == 5 * layers + 2 + 3 * (layers - 1), made_twice
+    c = cell.config
+    heads, tokens = c["num_attention_heads"], cell.traffic["seq_len"]
+    per_token = (3 * heads * c["v_head_dim"] + c["q_lora_rank"]
+                 + c["kv_lora_rank"] + c["qk_rope_head_dim"]
+                 + c["hidden_size"])
+    widths = (2 * c["intermediate_size"]
+              + (layers - 1) * 2 * c["moe_intermediate_size"])
+    kept = 2 * tokens * (layers * per_token + widths)
+    assert _held_bytes(compiled) - _held_bytes(before) <= 1.1 * kept

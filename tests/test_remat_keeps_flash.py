@@ -1,10 +1,14 @@
 """``TransformerConfig.remat`` recomputes a block in the backward pass
-EXCEPT what its flash kernel made: the kernel's output and log-sum-exp
-carry ``checkpoint_name``s (``introspect.SAVED_FLASH_OUT`` / ``_LSE``)
-and the recomputation's policy saves exactly those, so the forward
-kernel is traced once a layer. Everything here is the CPU, Pallas in
-interpret mode, at tiny sizes."""
+EXCEPT what its flash kernel or a matmul made and the backward pass
+reads: the kernel's operands, output and log-sum-exp and the narrow
+projections' products carry ``checkpoint_name``s (``introspect.SAVED_*``)
+and the recomputation's policy saves exactly those
+(``models/transformer.py`` ``_REMAT_KEEPS``), so the forward kernel is
+traced once a layer and the recomputed forward multiplies nothing but
+a router's logits (and q and k where a norm per head stands on them).
+Everything here is the CPU, Pallas in interpret mode, at tiny sizes."""
 
+import collections
 import logging
 import re
 
@@ -39,6 +43,25 @@ LATENT_HELD_EXPERTS = BlockSpec(
     first_dense_layers=1, dense_ff=96, num_experts=8, experts_per_token=2,
     router="sigmoid_bias", norm_topk=True, routed_scale=1.8,
     shared_experts=1, experts_held=2)
+# Trinity-Mini's at tiny widths: 4 query heads over 2 key/value heads,
+# sliding and full layers, a norm on q and k per head, rotary positions
+# in the sliding kind only, the output gate, a norm on each branch's
+# output, a leading dense block, held experts with a shared one.
+WINDOWED_GATED_HELD_EXPERTS = BlockSpec(
+    norm="rmsnorm", ffn="swiglu", positions="rope", tied_head=False,
+    head_dim=16, n_kv_heads=2, sliding_window=8,
+    layer_types=("sliding_attention", "sliding_attention",
+                 "full_attention"),
+    rope_layers=("sliding_attention",), qk_norm_per_head=True,
+    attn_gate=True, post_norms=True, first_dense_layers=1, dense_ff=96,
+    num_experts=8, experts_per_token=2, router="sigmoid_bias",
+    norm_topk=True, routed_scale=2.8, shared_experts=1, experts_held=2)
+# The three names PR 31 and PR 33 kept, before the products joined them.
+KERNEL_RESULTS_ONLY = (introspect.SAVED_FLASH_OUT, introspect.SAVED_FLASH_LSE,
+                       introspect.SAVED_MOE_OUT)
+SPECS = {"plain": PLAIN, "latent": LATENT,
+         "latent_held_experts": LATENT_HELD_EXPERTS,
+         "windowed_gated_held_experts": WINDOWED_GATED_HELD_EXPERTS}
 
 
 def _model(remat, attention="flash", block=PLAIN, dtype=jnp.float32):
@@ -83,6 +106,82 @@ def _kernel_calls(jaxpr_text):
             for name in KERNELS}
 
 
+def _matmuls(jaxpr, recomputation=None, inside=False):
+    """Every ``dot_general`` of ``jaxpr`` by (operand shapes, dimension
+    numbers), a kernel's own left out: all of them, or
+    (``recomputation`` True / False) those inside / outside a
+    ``checkpoint`` equation."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if eqn.primitive.name == "dot_general" and recomputation in (
+                None, inside):
+            yield (tuple(v.aval.shape for v in eqn.invars),
+                   str(eqn.params["dimension_numbers"]))
+        within = inside or eqn.primitive.name == "remat2"
+        for value in eqn.params.values():
+            for cand in value if isinstance(value, (list, tuple)) else (
+                    value,):
+                inner = getattr(cand, "jaxpr", cand)
+                if hasattr(inner, "eqns"):
+                    yield from _matmuls(inner, recomputation, within)
+
+
+def _recomputed_forward_matmuls(block, attention="flash"):
+    """The matmuls of the model's FORWARD pass (by operand shapes and
+    dimension numbers, which tell x W from the two matmuls of its
+    backward) that stand inside the gradient's ``checkpoint``
+    equations: what a recomputed block multiplies a second time."""
+    variables = _variables(block)
+    forward = set(_matmuls(jax.make_jaxpr(
+        lambda v: _model(False, attention, block).apply(
+            v, _tokens()[:, :-1]))(variables).jaxpr))
+    gradient = jax.make_jaxpr(jax.grad(_loss(
+        _model(True, attention, block), variables)))(variables["params"])
+    if attention == "flash":
+        assert _kernel_calls(str(gradient)) == dict.fromkeys(KERNELS, LAYERS)
+    return collections.Counter(
+        m for m in _matmuls(gradient.jaxpr, recomputation=True)
+        if m in forward)
+
+
+# A block's forward matmuls that the backward pass needs again, one
+# entry a layer. q / k / v or the four latent projections, the gate,
+# the output projection (its product is the feed-forward's input's
+# input); the feed-forward's up and gate (its down-projection only
+# where a norm reads the output); the router's logits. The last column:
+# the q and k projections that stand before a norm per head, which the
+# list leaves to be multiplied again (the norm's backward reads them;
+# kept BESIDE the kernel's operands they cost more than they spared).
+@pytest.mark.parametrize("name,made_twice_before,routers,q_k_under_norm", [
+    ("plain", 3 * (3 + 1 + 1), 0, 0),
+    ("latent", 3 * (4 + 1 + 2), 0, 0),
+    ("latent_held_experts", 3 * (4 + 1 + 2) + 2, 2, 0),
+    ("windowed_gated_held_experts", (5 + 3) + 2 * (5 + 3 + 1), 2, 2 * 3),
+])
+def test_a_recomputed_block_multiplies_only_its_router(
+        name, made_twice_before, routers, q_k_under_norm, monkeypatch):
+    """Read off the gradient's jaxpr: with the three names of the
+    kernel's and the expert layer's results alone, every projection of
+    a block stands a second time inside its ``checkpoint`` equation;
+    with ``_REMAT_KEEPS`` only the expert layers' router logits do
+    (float32, T x E: parallel/moe.py's own, not on the list) and, in a
+    block with a norm on q and k, those two projections (4 query and 2
+    key heads of 16: the value's, of the key's shape, is not among
+    them). Each kernel is traced once a layer either way."""
+    after = _recomputed_forward_matmuls(SPECS[name])
+    by_weight = collections.Counter()
+    for (shapes, _), n in after.items():
+        by_weight[shapes[1]] += n
+    expected = {(64, 8): routers, (64, 4, 16): q_k_under_norm // 2,
+                (64, 2, 16): q_k_under_norm // 2}
+    assert by_weight == {w: n for w, n in expected.items() if n}, after
+    monkeypatch.setattr(transformer_module, "_REMAT_KEEPS",
+                        KERNEL_RESULTS_ONLY)
+    before = _recomputed_forward_matmuls(SPECS[name])
+    assert sum(before.values()) == made_twice_before, before
+
+
 @pytest.mark.parametrize("remat", [True, False])
 @pytest.mark.parametrize("block", [PLAIN, LATENT],
                          ids=["plain", "latent"])
@@ -112,8 +211,7 @@ def test_plain_recomputation_would_run_the_forward_kernel_twice(monkeypatch):
                      introspect.KERNEL_FLASH_DQ: LAYERS}
 
 
-@pytest.mark.parametrize("block", [PLAIN, LATENT, LATENT_HELD_EXPERTS],
-                         ids=["plain", "latent", "latent_held_experts"])
+@pytest.mark.parametrize("block", list(SPECS.values()), ids=list(SPECS))
 @pytest.mark.parametrize("dtype,loss_rtol,leaf_rel_l2", [
     (jnp.float32, 1e-6, 1e-5),
     # XLA:CPU keeps float32 inside a fusion where the program says
@@ -124,8 +222,8 @@ def test_plain_recomputation_would_run_the_forward_kernel_twice(monkeypatch):
 def test_recomputed_gradients_are_the_plain_ones(block, dtype, loss_rtol,
                                                  leaf_rel_l2):
     """Loss and every gradient leaf of ``remat=True`` against
-    ``remat=False`` on the same weights: the kept output is the array a
-    second run of the kernel would have made."""
+    ``remat=False`` on the same weights: each kept array is the one a
+    second run of its kernel or matmul would have made."""
     variables = _variables(block)
     got, want = (
         jax.jit(jax.value_and_grad(_loss(
@@ -143,41 +241,50 @@ def test_recomputed_gradients_are_the_plain_ones(block, dtype, loss_rtol,
         assert rel <= leaf_rel_l2, (name, rel)
 
 
-def test_dense_attention_under_remat_keeps_nothing(monkeypatch):
-    """Nothing in a dense-attention block carries the names, so the
-    policy saves nothing: the gradient is, equation for equation, the
-    one of ``nn.remat(Block)`` without a policy, and the softmax's
-    ``exp`` is made twice a layer (forward, recomputed) where the model
+def test_dense_attention_under_remat_keeps_no_kernel_operand(monkeypatch):
+    """A dense-attention block has no kernel and nothing in it carries
+    the five ``hvd_flash_*`` names. What the model's own names keep
+    there: the attention branch's output and the feed-forward's up
+    product (``PLAIN`` has no norm on q or k, no gate, and nothing reads
+    its feed-forward's output again). So of the 5 matmuls a layer that
+    plain recomputation makes twice beside the attention's own two
+    (q / k / v, the output projection, the feed-forward's up), the
+    three that make q, k and v are left, and the softmax's ``exp`` is
+    still made twice a layer (forward, recomputed) where the model
     without ``remat`` makes it once."""
     variables = _variables()
-    with_policy = _gradient_jaxpr(_model(True, "dense"), variables)
-    assert introspect.SAVED_FLASH_OUT not in with_policy
-    assert introspect.SAVED_FLASH_LSE not in with_policy
+    text = _gradient_jaxpr(_model(True, "dense"), variables)
+    for name in (introspect.SAVED_FLASH_Q, introspect.SAVED_FLASH_K,
+                 introspect.SAVED_FLASH_V, introspect.SAVED_FLASH_OUT,
+                 introspect.SAVED_FLASH_LSE):
+        assert name not in text
+    for name in (introspect.SAVED_ATTN_OUT, introspect.SAVED_MLP_UP):
+        assert len(re.findall(r"name\[name=%s\]" % name, text)) >= LAYERS
 
     def exps(text):
         return len(re.findall(r"= exp ", text))
 
     # (The loss's own log-softmax holds one more in both.)
-    assert exps(with_policy) - LAYERS == exps(
+    assert exps(text) - LAYERS == exps(
         _gradient_jaxpr(_model(False, "dense"), variables)) == LAYERS + 1
 
+    def projections_made_twice():
+        # q k^T and p v carry batch dimensions; the projections are
+        # the rest of the forward's matmuls.
+        return sum(n for (_, dims), n in _recomputed_forward_matmuls(
+            PLAIN, "dense").items() if dims.endswith("((), ()))"))
+
+    assert projections_made_twice() == 3 * LAYERS
     monkeypatch.setattr(transformer_module, "_remat_block",
                         lambda cfg: nn.remat(transformer_module.Block))
-    without = _gradient_jaxpr(_model(True, "dense"), variables)
-
-    def shape(text):
-        # Equations only: the policy's own repr rides in the
-        # ``checkpoint`` equation's parameters.
-        return re.sub(r"policy=[^\n]*", "policy=", text)
-
-    assert shape(with_policy) == shape(without)
+    assert projections_made_twice() == 5 * LAYERS
 
 
 @pytest.mark.parametrize("attention,remat,moved", [
-    ("flash", True, {"flash_out+lse": LAYERS, "nothing": 0}),
-    ("dense", True, {"flash_out+lse": 0, "nothing": LAYERS}),
-    ("flash", False, {"flash_out+lse": 0, "nothing": 0}),
-    ("dense", False, {"flash_out+lse": 0, "nothing": 0}),
+    ("flash", True, {"flash+products": LAYERS, "products": 0}),
+    ("dense", True, {"flash+products": 0, "products": LAYERS}),
+    ("flash", False, {"flash+products": 0, "products": 0}),
+    ("dense", False, {"flash+products": 0, "products": 0}),
 ])
 def test_remat_counter_at_trace_time(attention, remat, moved):
     """hvd_remat_blocks_total{keeps} moves by the model's blocks each
@@ -206,13 +313,13 @@ def test_remat_is_logged_once_a_model(caplog):
         jax.eval_shape(lambda v: model.apply(v, tokens), variables)
     lines = [r.getMessage() for r in caplog.records
              if "Transformer remat" in r.getMessage()]
-    assert len(lines) == 1 and "flash_out+lse" in lines[0], lines
+    assert len(lines) == 1 and "flash+products" in lines[0], lines
 
 
 def test_the_names_cost_nothing_outside_a_recomputation():
-    """Outside a ``remat`` the two names are identities: the lowered
-    forward and gradient of ``flash_attention`` hold no trace of them
-    (StableHLO has no op for a name)."""
+    """Outside a ``remat`` the kernel's five names are identities: the
+    lowered forward and gradient of ``flash_attention`` hold no trace of
+    them (StableHLO has no op for a name)."""
     from horovod_tpu.ops.pallas_attention import flash_attention
 
     x = jax.ShapeDtypeStruct((1, 32, 2, 16), jnp.float32)
@@ -221,10 +328,44 @@ def test_the_names_cost_nothing_outside_a_recomputation():
         return flash_attention(q, k, v).sum()
 
     jaxpr = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(x, x, x))
-    assert introspect.SAVED_FLASH_OUT in jaxpr
     lowered = jax.jit(jax.grad(loss, (0, 1, 2))).lower(x, x, x).as_text()
-    assert introspect.SAVED_FLASH_OUT not in lowered
-    assert introspect.SAVED_FLASH_LSE not in lowered
+    for name in (introspect.SAVED_FLASH_Q, introspect.SAVED_FLASH_K,
+                 introspect.SAVED_FLASH_V, introspect.SAVED_FLASH_OUT,
+                 introspect.SAVED_FLASH_LSE):
+        assert name in jaxpr and name not in lowered, name
+
+
+@pytest.mark.parametrize("name", list(SPECS))
+def test_a_step_without_remat_lowers_the_same_without_the_names(
+        name, monkeypatch):
+    """Every name of ``_REMAT_KEEPS`` is traced by a model that does
+    not recompute, too. There each lowers to nothing: the lowered
+    gradient of ``remat=False`` is, operation for operation, the one
+    traced with ``checkpoint_name`` taken out of the three modules that
+    call it (but for the counter jax puts behind the names of the
+    private functions it lowers: one more distinct equation traced,
+    one more used up)."""
+    from horovod_tpu.ops import pallas_attention
+    from horovod_tpu.parallel import moe
+
+    variables = _variables(SPECS[name])
+
+    def lowered():
+        text = jax.jit(jax.grad(_loss(_model(False, block=SPECS[name]),
+                                      variables))).lower(
+            variables["params"]).as_text()
+        return re.sub(r"(@[A-Za-z_][\w.]*?)_\d+\b", r"\1", text)
+
+    with_names = lowered()
+    traced = str(jax.make_jaxpr(jax.grad(_loss(
+        _model(False, block=SPECS[name]), variables)))(variables["params"]))
+    listed = [n for n in transformer_module._REMAT_KEEPS
+              if "name=%s]" % n in traced]
+    assert len(listed) >= 7, listed     # the kernel's five, two products
+    assert not [n for n in listed if n in with_names]
+    for module in (transformer_module, pallas_attention, moe):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+    assert lowered() == with_names
 
 
 @pytest.mark.parametrize("post_norms,kept,choices", [
@@ -243,8 +384,11 @@ def test_a_recomputed_block_keeps_the_held_expert_layers_output(
     expert layer's compiled gradient holds two ``conditional``s, not
     three (512 tokens x 2 slots, 1 of 8 experts held: a prefix of 512
     rows)."""
-    if not kept:    # the policy lists another name than the layer gives
-        monkeypatch.setattr(transformer_module, "SAVED_MOE_OUT", "unlisted")
+    if not kept:    # the policy's list without the name the layer gives
+        monkeypatch.setattr(
+            transformer_module, "_REMAT_KEEPS",
+            tuple(n for n in transformer_module._REMAT_KEEPS
+                  if n != introspect.SAVED_MOE_OUT))
     model = Transformer(TransformerConfig(
         vocab_size=64, d_model=32, n_heads=2, n_layers=1, d_ff=16,
         max_seq_len=512, attention="dense", remat=True,

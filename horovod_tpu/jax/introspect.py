@@ -46,6 +46,10 @@ SCOPE_MLA_LATENT = "hvd_mla_latent"
 # projection times the attention output (the projection itself is the
 # module's own).
 SCOPE_ATTN_GATE = "hvd_attn_gate"
+# ShortConv (a ``conv`` layer's mixer, flax scope ``conv``): the two gate
+# multiplies and the causal taps between the in- and out-projections
+# (the projections themselves are the module's own).
+SCOPE_CONV_GATE = "hvd_conv_gate"
 # The expert layer (parallel/moe.py), inside the ``moe`` module's scope.
 SCOPE_MOE_ROUTER = "hvd_moe_router"      # logits, softmax, top-k, aux losses
 # The sorts (the gates ride one into row order); rows gathered from the
@@ -91,6 +95,10 @@ SAVED_FLASH_V = "hvd_flash_v"
 SAVED_ATTN_PRENORM = "hvd_attn_prenorm"
 SAVED_ATTN_GATE = "hvd_attn_gate_proj"
 SAVED_ATTN_OUT = "hvd_attn_out"
+# A block without a kernel (ShortConv): its in-projection's product,
+# which the gates' and taps' backward reads, and the branch's output.
+SAVED_CONV_IN = "hvd_conv_in"
+SAVED_CONV_OUT = "hvd_conv_out"
 SAVED_MLP_UP = "hvd_mlp_up"
 SAVED_MLP_GATE = "hvd_mlp_gate"
 SAVED_MLP_OUT = "hvd_mlp_out"

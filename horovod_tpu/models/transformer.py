@@ -21,7 +21,10 @@ key/value heads than query heads, a per-layer pattern of sliding-window
 and full attention (with rotary positions in some kinds only), a norm
 on q and k per head, a sigmoid gate on the attention output, norms on
 each branch's output and a multiplier on the embedding: what the
-``afmoe`` family's ``config.json`` keys say.
+``afmoe`` family's ``config.json`` keys say. The pattern's third kind of
+layer is no attention at all: ``conv``, the ``lfm2`` family's gated
+short convolution (``ShortConv``), which mixes each token with the few
+before it and has neither heads nor positions.
 
 Param layout (tensor parallel over 'model'):
 - attention QKV projections shard the head dim;
@@ -48,6 +51,8 @@ from horovod_tpu.jax.introspect import (
     SAVED_ATTN_GATE,
     SAVED_ATTN_OUT,
     SAVED_ATTN_PRENORM,
+    SAVED_CONV_IN,
+    SAVED_CONV_OUT,
     SAVED_FLASH_K,
     SAVED_FLASH_LSE,
     SAVED_FLASH_OUT,
@@ -58,6 +63,7 @@ from horovod_tpu.jax.introspect import (
     SAVED_MLP_UP,
     SAVED_MOE_OUT,
     SCOPE_ATTN_GATE,
+    SCOPE_CONV_GATE,
     SCOPE_EMBED,
     SCOPE_LOGITS,
     SCOPE_MLA_LATENT,
@@ -70,16 +76,20 @@ logger = logging.getLogger("horovod_tpu")
 
 param_with_axes = nn.with_partitioning
 
-# The two kinds of attention layer, as ``config.json`` ``layer_types``
-# spells them.
+# The kinds of layer, by their token mixer, as ``config.json``
+# ``layer_types`` spells them: two of attention, and the gated short
+# convolution.
 FULL_ATTENTION, SLIDING_ATTENTION = "full_attention", "sliding_attention"
+CONV = "conv"
 
-# Counted at trace time: the attention layers one traced model makes, by
-# kind.
+# Counted at trace time: the layers one traced model makes, by the kind
+# of their token mixer (the name dates from when every mixer was an
+# attention).
 _M_ATTN_LAYERS = _metrics.counter(
     "hvd_attn_layers_total",
-    "Attention layers per traced model, by kind (full_attention / "
-    "sliding_attention; counted at trace time, not per device step).",
+    "Layers per traced model, by the kind of their token mixer "
+    "(full_attention / sliding_attention / conv; counted at trace time, "
+    "not per device step).",
     ("kind",))
 
 # Counted at trace time: the blocks traced under ``cfg.remat``, by what
@@ -138,10 +148,13 @@ class BlockSpec:
     # ``wq`` and ``wkv`` instead of ``wqkv``.
     head_dim: int = 0
     n_kv_heads: int = 0
-    # One kind a layer, FULL_ATTENTION or SLIDING_ATTENTION (a query
-    # sees the ``sliding_window`` keys up to itself); () = all full.
+    # One kind a layer: FULL_ATTENTION, SLIDING_ATTENTION (a query sees
+    # the ``sliding_window`` keys up to itself) or CONV (no attention: a
+    # gated causal convolution over ``conv_taps`` tokens, ``ShortConv``);
+    # () = all full.
     layer_types: tuple = ()
     sliding_window: int = 0
+    conv_taps: int = 0
     # The kinds whose q and k carry the rotary positions; None = every
     # layer, if ``positions`` is 'rope'.
     rope_layers: Optional[tuple] = None
@@ -423,6 +436,58 @@ class LatentAttention(nn.Module):
             SAVED_ATTN_OUT)
 
 
+def _causal_taps(x, w):
+    """``z_t = sum_j w[:, j] * x_(t - L + 1 + j)`` per channel, zeros
+    before position 0: x (B, S, M), w (M, L), L shifted multiplies."""
+    taps, s = w.shape[1], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + s] * w[:, j] for j in range(taps))
+
+
+def _gated_taps(bcu, w):
+    """``c * taps(b * u)`` of the in-projection's product ``bcu`` (B, S,
+    3 M), its thirds b, c, u in that order, in ``bcu``'s dtype: what the
+    compiler writes between these passes is then as narrow as the
+    product itself."""
+    b, c, u = jnp.split(bcu, 3, axis=-1)
+    return c * _causal_taps(b * u, w.astype(bcu.dtype))
+
+
+class ShortConv(nn.Module):
+    """The ``lfm2`` family's token mixer, a double-gated short
+    convolution: ``[b, c, u] = split3(x W_in)``, ``z`` the depthwise
+    causal convolution of ``b * u`` over ``BlockSpec.conv_taps`` tokens
+    (no bias, no activation), ``(c * z) W_out``. No heads, no
+    positions, no state beyond ``conv_taps - 1`` tokens. Matmuls,
+    gates and taps all run in the compute dtype."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, m = self.cfg, self.cfg.d_model
+        taps = cfg.block.conv_taps
+        if taps < 1:
+            raise ValueError("a conv layer needs BlockSpec.conv_taps")
+        if cfg.seq_axis is not None:
+            raise ValueError("a conv layer reads the tokens before its "
+                             "own and exchanges none over seq_axis=%r"
+                             % (cfg.seq_axis,))
+        init = nn.initializers.normal(0.02)
+        w_in = self.param("w_in", param_with_axes(init, (None, "model")),
+                          (m, 3 * m), jnp.float32)
+        w = self.param("w", param_with_axes(init, ("model", None)),
+                       (m, taps), jnp.float32)
+        w_out = self.param("w_out", param_with_axes(init, ("model", None)),
+                           (m, m), jnp.float32)
+        # Both products carry names: a recomputed block keeps them
+        # (``_REMAT_KEEPS``) and makes the gates and the taps again.
+        bcu = checkpoint_name(x @ w_in.astype(cfg.dtype), SAVED_CONV_IN)
+        with jax.named_scope(SCOPE_CONV_GATE):
+            y = _gated_taps(bcu, w)
+        return checkpoint_name(y @ w_out.astype(cfg.dtype), SAVED_CONV_OUT)
+
+
 class Mlp(nn.Module):
     """The dense feed-forward, ``cfg.d_ff`` wide unless ``width`` says
     otherwise (a leading dense block, a shared expert)."""
@@ -456,19 +521,25 @@ class Mlp(nn.Module):
 class Block(nn.Module):
     """One decoder block. ``dense_width`` makes its feed-forward a dense
     one of that width whatever the model's other blocks carry;
-    ``attention_type`` is its entry of ``BlockSpec.layer_types``. With
-    ``post_norms`` each branch's output passes a norm of its own before
-    it joins the residual stream."""
+    ``layer_type`` is its entry of ``BlockSpec.layer_types`` and says
+    which token mixer it carries. With ``post_norms`` each branch's
+    output passes a norm of its own before it joins the residual
+    stream."""
 
     cfg: TransformerConfig
     dense_width: Optional[int] = None
-    attention_type: str = FULL_ATTENTION
+    layer_type: str = FULL_ATTENTION
 
-    def _attention(self):
-        cfg, spec, kind = self.cfg, self.cfg.block, self.attention_type
-        if kind not in (FULL_ATTENTION, SLIDING_ATTENTION):
-            raise ValueError("Unknown attention layer type %r" % (kind,))
+    def _mixer(self):
+        cfg, spec, kind = self.cfg, self.cfg.block, self.layer_type
+        if kind not in (FULL_ATTENTION, SLIDING_ATTENTION, CONV):
+            raise ValueError("Unknown attention layer type %r (layer_types "
+                             "knows %s, %s and %s)" % (
+                                 kind, FULL_ATTENTION, SLIDING_ATTENTION,
+                                 CONV))
         _M_ATTN_LAYERS.labels(kind=kind).inc()
+        if kind == CONV:
+            return ShortConv(cfg, name="conv")
         sliding = kind == SLIDING_ATTENTION
         if spec.attention_kind == "latent":
             if sliding:
@@ -492,7 +563,7 @@ class Block(nn.Module):
             return x + branch
 
         y = _norm(cfg, "ln1")(x)
-        x = joined(x, "post_attn_norm", self._attention()(y))
+        x = joined(x, "post_attn_norm", self._mixer()(y))
         y = _norm(cfg, "ln2")(x)
         if cfg.block.num_experts > 0 and self.dense_width is None:
             from horovod_tpu.parallel.moe import MoeMlp
@@ -552,6 +623,12 @@ _REMAT_KEEPS = (
     # What a share-held expert layer returns, where a norm reads it
     # (Trinity 33.5 MB): that layer's choice is not made a second time.
     SAVED_MOE_OUT,
+    # A block WITHOUT a kernel, the gated short convolution
+    # (``ShortConv``; LFM2-8B-A1B at T = 16,384, M 2048): its
+    # in-projection's product, 3 M wide, 201 MB, which the gates' and
+    # the taps' backward reads, and the branch's output, 67 MB; the
+    # recomputed mixer is its gates and taps, one elementwise pass.
+    SAVED_CONV_IN, SAVED_CONV_OUT,
 )
 
 
@@ -559,10 +636,22 @@ _REMAT_KEEPS = (
 def _log_remat(cfg, keeps):
     logger.info(
         "Transformer remat: %d blocks recomputed in the backward pass, "
-        "each keeps %s (attention=%r): what its matmuls made and the "
-        "backward pass reads, about T x (3 H D + 2 M + 2 F) of %s a "
-        "layer; norms, activations and adds are made again",
-        cfg.n_layers, keeps, cfg.attention, jnp.dtype(cfg.dtype).name)
+        "they keep %s (attention=%r): what the mixer's and the "
+        "feed-forward's matmuls made and the backward pass reads, about "
+        "T x (3 H D + 2 M + 2 F) of %s a layer (T x 4 M + 2 F under a "
+        "convolution); norms, activations, gates and adds are made again",
+        cfg.n_layers, ", ".join("%s in %d" % kc for kc in keeps),
+        cfg.attention, jnp.dtype(cfg.dtype).name)
+
+
+def _layer_kinds(cfg):
+    """One entry of ``BlockSpec.layer_types`` a layer; all full
+    attention where the spec names none."""
+    kinds = cfg.block.layer_types or (FULL_ATTENTION,) * cfg.n_layers
+    if len(kinds) != cfg.n_layers:
+        raise ValueError("BlockSpec.layer_types names %d layers, the "
+                         "model has %d" % (len(kinds), cfg.n_layers))
+    return kinds
 
 
 def _remat_block(cfg):
@@ -571,17 +660,23 @@ def _remat_block(cfg):
     ops/pallas_attention.py gives them: the backward kernels read all
     five, so the recomputed forward has no use for a second run of the
     kernel or of what made its operands) and the narrow matmul products
-    of the attention module and the dense feed-forward that the backward
+    of the mixer and the dense feed-forward that the backward
     pass reads, so that the recomputed forward multiplies nothing but
     the router's logits and, under a norm on q and k, those two
     projections. Likewise what an expert layer that holds a
     share of the experts returns, where the block reads it again
     (``post_norms``); that layer's backward rule recomputes from its
-    inputs (parallel/moe.py ``_held_rows``). Any other ``attention``
-    than 'flash' carries no kernel names and keeps the products alone.
+    inputs (parallel/moe.py ``_held_rows``). A block without a kernel
+    (a ``conv`` layer, or any layer under another ``attention`` than
+    'flash') carries no kernel names and keeps the products alone.
     Counted and logged at trace time."""
-    keeps = "flash+products" if cfg.attention == "flash" else "products"
-    _M_REMAT_BLOCKS.labels(keeps=keeps).inc(cfg.n_layers)
+    kinds = _layer_kinds(cfg)
+    kernel = sum(kind != CONV for kind in kinds) \
+        if cfg.attention == "flash" else 0
+    keeps = tuple((label, n) for label, n in (
+        ("flash+products", kernel), ("products", len(kinds) - kernel)) if n)
+    for label, n in keeps:
+        _M_REMAT_BLOCKS.labels(keeps=label).inc(n)
     _log_remat(cfg, keeps)
     policy = jax.checkpoint_policies.save_only_these_names(*_REMAT_KEEPS)
     return nn.remat(Block, policy=policy)
@@ -640,11 +735,8 @@ class Transformer(nn.Module):
                 else:
                     pos_slice = pos.astype(cfg.dtype)[:s_local]
                 x = x + pos_slice[None]
+        kinds = _layer_kinds(cfg)
         block = _remat_block(cfg) if cfg.remat else Block
-        kinds = cfg.block.layer_types or (FULL_ATTENTION,) * cfg.n_layers
-        if len(kinds) != cfg.n_layers:
-            raise ValueError("BlockSpec.layer_types names %d layers, the "
-                             "model has %d" % (len(kinds), cfg.n_layers))
         for i in range(cfg.n_layers):
             dense = i < cfg.block.first_dense_layers
             x = block(cfg, cfg.block.dense_ff if dense else None, kinds[i],

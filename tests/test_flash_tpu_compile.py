@@ -81,15 +81,20 @@ def test_kernels_lower_for_v5e(one_chip, shape, dtype):
         assert "%" + name in text, name
 
 
-@pytest.mark.parametrize("window", [2048, None])
-def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, window):
+@pytest.mark.parametrize("seq,n_kv,head_dim,window", [
+    (8192, 4, 128, 2048), (8192, 4, 128, None), (16384, 8, 64, None)])
+def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, seq, n_kv,
+                                                    head_dim, window):
     """trinity-s8192-ep8-c1's attention, a sliding and a full layer: 32
-    query heads over 4 key/value heads of 128. K and V enter all three
-    Mosaic calls 4 heads wide and dK/dV leave 4 heads wide (float32, the
-    group's sum): nothing is repeated to 32 heads in HBM."""
-    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
+    query heads over 4 key/value heads of 128; and lfm2-s16384-ep4-c1's
+    one attention layer: 32 over 8 heads of 64 at twice the rows (the
+    dK/dV grid's float32 panels then pass the default scoped VMEM). K
+    and V enter all three Mosaic calls ``n_kv`` heads wide and dK/dV
+    leave ``n_kv`` heads wide (float32, the group's sum): nothing is
+    repeated to 32 heads in HBM; the three names once a layer."""
+    q = jax.ShapeDtypeStruct((1, seq, 32, head_dim), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((1, seq, n_kv, head_dim), jnp.bfloat16,
                               sharding=one_chip)
 
     def step(q, k, v, g):
@@ -102,7 +107,12 @@ def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, window):
     calls = [line.strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 3
-    narrow, wide = "[1,4,8192,128]", "[1,32,8192,128]"
+    narrow = "[1,%d,%d,%d]" % (n_kv, seq, head_dim)
+    wide = "[1,32,%d,%d]" % (seq, head_dim)
+    for name in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
+                 introspect.KERNEL_FLASH_DQ):
+        assert len(re.findall(r"^\s*(?:ROOT )?%%%s[.\d]* = " % name, text,
+                              re.M)) == 1, name
     for line in calls:
         name = line.split(" = ")[0].lstrip("ROOT ").lstrip("%")
         operands = re.findall(
@@ -442,3 +452,46 @@ def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
               + (layers - 1) * 2 * c["moe_intermediate_size"])
     kept = 2 * tokens * (layers * per_token + widths)
     assert _held_bytes(compiled) - _held_bytes(before) <= 1.1 * kept
+
+
+def test_a_recomputed_conv_block_multiplies_nothing_but_its_router(
+        topo, monkeypatch):
+    """LFM2-8B-A1B's step at its tiny sizes (a dense conv block, then an
+    attention and a conv expert block that hold 2 of 8 experts, no
+    shared expert, every block recomputed), compiled for a described
+    v5e: the three flash kernels ONCE (one attention layer; a conv
+    layer calls none); under the recomputed forward no ``dot`` /
+    ``convolution`` of a ``conv`` module or of the dense feed-forward
+    (their products are kept: ``_REMAT_KEEPS``), only each router's
+    logits and the q and k projections that stand before the attention
+    layer's head norms; the gates' and taps' scope survives the
+    compiler's fusion (``conv.gate_ms`` has something to read)."""
+    from benchmark import cell as cells
+
+    monkeypatch.setattr(pallas_attention, "_should_interpret",
+                        lambda interpret: False)
+    cell = cells.load("lfm2-s16384-ep4-c1", tiny=True)
+    asm = cells.assemble(cell, topo.devices)
+    text = asm.step.lower(*cells.abstract_step_args(asm)).compile().as_text()
+    calls = {name: len(re.findall(r"^\s*(?:ROOT )?%%%s[.\d]* = " % name, text,
+                                  re.M))
+             for name in (introspect.KERNEL_FLASH_FWD,
+                          introspect.KERNEL_FLASH_DKV,
+                          introspect.KERNEL_FLASH_DQ)}
+    assert calls == dict.fromkeys(calls, 1), calls
+    assert asm.model.kernels(1)["fwd"][0] == 1
+    recomputed = _matmuls_recomputed(text)
+    routers = [s for s in recomputed if introspect.SCOPE_MOE_ROUTER in s]
+    attention = [s for s in recomputed if "/attn/" in s]
+    assert len(routers) == 2 and len(attention) == 2, recomputed
+    assert len(recomputed) == 4, recomputed
+    assert not [s for s in recomputed if "/conv/" in s or "/mlp/" in s]
+    scopes = introspect.instruction_scopes(text)
+    entry = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = ",
+                       text[text.index("\nENTRY "):], re.M)
+    gate = [scopes.get(name, "") for name in entry
+            if introspect.SCOPE_CONV_GATE in scopes.get(name, "")]
+    assert [s for s in gate if "layer_0/conv/" in s]
+    assert [s for s in gate if "layer_2/conv/" in s]
+    assert not [s for s in gate if "layer_1/" in s]
+    assert "all-reduce" not in text and introspect.SCOPE_MOE_SHARED not in text

@@ -56,12 +56,23 @@ WINDOWED_GATED_HELD_EXPERTS = BlockSpec(
     attn_gate=True, post_norms=True, first_dense_layers=1, dense_ff=96,
     num_experts=8, experts_per_token=2, router="sigmoid_bias",
     norm_topk=True, routed_scale=2.8, shared_experts=1, experts_held=2)
+# LFM2-8B-A1B's at tiny widths: gated short-convolution layers (no
+# kernel to keep) round one grouped-query attention layer with a norm
+# per head, a tied head, a leading dense block, held experts with no
+# shared one.
+CONV_HELD_EXPERTS = BlockSpec(
+    norm="rmsnorm", ffn="swiglu", positions="rope", head_dim=16,
+    n_kv_heads=2, layer_types=("conv", "full_attention", "conv"),
+    conv_taps=3, qk_norm_per_head=True, first_dense_layers=1, dense_ff=96,
+    num_experts=8, experts_per_token=2, router="sigmoid_bias",
+    norm_topk=True, experts_held=2)
 # The three names PR 31 and PR 33 kept, before the products joined them.
 KERNEL_RESULTS_ONLY = (introspect.SAVED_FLASH_OUT, introspect.SAVED_FLASH_LSE,
                        introspect.SAVED_MOE_OUT)
 SPECS = {"plain": PLAIN, "latent": LATENT,
          "latent_held_experts": LATENT_HELD_EXPERTS,
-         "windowed_gated_held_experts": WINDOWED_GATED_HELD_EXPERTS}
+         "windowed_gated_held_experts": WINDOWED_GATED_HELD_EXPERTS,
+         "conv_held_experts": CONV_HELD_EXPERTS}
 
 
 def _model(remat, attention="flash", block=PLAIN, dtype=jnp.float32):
@@ -136,10 +147,18 @@ def _recomputed_forward_matmuls(block, attention="flash"):
     forward = set(_matmuls(jax.make_jaxpr(
         lambda v: _model(False, attention, block).apply(
             v, _tokens()[:, :-1]))(variables).jaxpr))
+    # The output head (x E^T, vocabulary = width = 64 here) stands
+    # outside every block and is never made again; with its shapes and
+    # dimension numbers a square projection's INPUT GRADIENT (a conv
+    # block's out-projection) would be taken for it.
+    forward.discard((((2, 31, 64), (64, 64)), "(((2,), (1,)), ((), ()))"))
     gradient = jax.make_jaxpr(jax.grad(_loss(
         _model(True, attention, block), variables)))(variables["params"])
     if attention == "flash":
-        assert _kernel_calls(str(gradient)) == dict.fromkeys(KERNELS, LAYERS)
+        # Each kernel once a layer that HAS one: a conv layer calls none.
+        kernel_layers = LAYERS - block.layer_types.count("conv")
+        assert _kernel_calls(str(gradient)) == dict.fromkeys(KERNELS,
+                                                             kernel_layers)
     return collections.Counter(
         m for m in _matmuls(gradient.jaxpr, recomputation=True)
         if m in forward)
@@ -158,6 +177,9 @@ def _recomputed_forward_matmuls(block, attention="flash"):
     ("latent", 3 * (4 + 1 + 2), 0, 0),
     ("latent_held_experts", 3 * (4 + 1 + 2) + 2, 2, 0),
     ("windowed_gated_held_experts", (5 + 3) + 2 * (5 + 3 + 1), 2, 2 * 3),
+    # A conv block: its two projections; the attention block: q, k, v
+    # and the output projection; the dense feed-forward's up and gate.
+    ("conv_held_experts", (2 + 2) + (4 + 1) + (2 + 1), 2, 2 * 1),
 ])
 def test_a_recomputed_block_multiplies_only_its_router(
         name, made_twice_before, routers, q_k_under_norm, monkeypatch):

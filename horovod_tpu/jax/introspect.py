@@ -53,14 +53,14 @@ SCOPE_CONV_GATE = "hvd_conv_gate"
 # The expert layer (parallel/moe.py), inside the ``moe`` module's scope.
 SCOPE_MOE_ROUTER = "hvd_moe_router"      # logits, softmax, top-k, aux losses
 # The sorts (the gates ride one into row order); rows gathered from the
-# (T, M) tokens; backward a gather of rows and the sum over each
-# token's k.
+# (T, M) tokens; the visits of the layer's two sums; backward the sum
+# of each token's rows (the kernel below).
 SCOPE_MOE_DISPATCH = "hvd_moe_dispatch"
 # The grouped matmuls and their activation, which the ROUTER's gate
 # multiplies (its gradient is that fusion's reduction over F).
 SCOPE_MOE_EXPERTS = "hvd_moe_experts"
-# A gather of rows and the plain sum over each token's k; backward one
-# gather from the (T, M) cotangent. No weighting here.
+# The plain sum of each token's live sorted rows (the kernel below);
+# backward one gather from the (T, M) cotangent. No weighting here.
 SCOPE_MOE_COMBINE = "hvd_moe_combine"
 # Round the three above where the layer holds a share of the experts and
 # chooses its row arrays' length on the device: the ``lax.cond`` and,
@@ -74,6 +74,10 @@ SCOPE_MOE_SHARED = "hvd_moe_shared"
 KERNEL_FLASH_FWD = "hvd_flash_fwd"
 KERNEL_FLASH_DKV = "hvd_flash_dkv"
 KERNEL_FLASH_DQ = "hvd_flash_dq"
+# ``name=`` of the expert layer's sum over a token's sorted rows
+# (ops/pallas_gather_sum.py; under ``hvd_moe_combine`` forward and
+# ``hvd_moe_dispatch`` backward, where ``_sum_per_token`` stands).
+KERNEL_MOE_GATHER_SUM = "hvd_moe_gather_sum"
 # ``checkpoint_name``s of what the forward kernel made, as the backward
 # kernels read it: the (B, H, S, D) output and the (B, H, S) float32
 # log-sum-exp; and of what it READ, its (B, H, S, D) / (B, H_kv, S, D)
@@ -117,16 +121,20 @@ COLLECTIVE_PRIMITIVES = (
 )
 
 
-def equations(jaxpr):
+def equations(jaxpr, skip=()):
     """Every equation of ``jaxpr`` and of the jaxprs inside it
-    (shard_map / scan / cond / custom-vjp bodies), outer first."""
+    (shard_map / scan / cond / custom-vjp bodies), outer first; not
+    what is inside an equation of a primitive named in ``skip`` (the
+    body of a ``pallas_call``)."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name in skip:
+            continue
         for v in eqn.params.values():
             for cand in (v if isinstance(v, (list, tuple)) else (v,)):
                 inner = getattr(cand, "jaxpr", cand)
                 if hasattr(inner, "eqns"):
-                    yield from equations(inner)
+                    yield from equations(inner, skip)
 
 
 def collective_counts(fn, *args, **kwargs) -> Dict[str, int]:

@@ -8,11 +8,15 @@
   (``tokens[order[r] // k]``), the grouped matmuls run over the ragged
   groups with the row's gate multiplied into their activation (by
   linearity ``sum_j g_j (h_j Wo) = sum_j (g_j h_j) Wo``), and a token's
-  output is the plain float32 sum of its k rows. An array of T x k rows
-  exists only as an operand or a cotangent of a grouped matmul: nothing
-  is broadcast, and backward every gather is a gather (two of the four
-  read the (T, M) array), never a scatter-add. No ``(T, E, C)`` tensor
-  and no capacity: an expert takes whatever the router sends it.
+  output is the plain float32 sum of its LIVE sorted rows, rounded once,
+  made by one Pallas kernel (``ops/pallas_gather_sum.py``) that reads
+  the chunks of sorted rows a block of tokens owns rows in and adds
+  them through a 0/1 matrix on the MXU. An array of T x k rows exists
+  only as an operand or a cotangent of a grouped matmul: nothing is
+  broadcast, no array of a token's k pairs is made to be summed, and
+  backward a gather's transpose is that kernel and the kernel's a
+  gather from the (T, M) array, never a scatter-add. No ``(T, E, C)``
+  tensor and no capacity: an expert takes whatever the router sends it.
   GPT-2's block gets GELU experts and one expert a token, where the
   sum over k is the identity; OLMoE's SwiGLU experts and 8 of 64
   (``BlockSpec``). The layer may HOLD fewer experts than it routes over
@@ -29,7 +33,7 @@
   the live rows it counted with C (``lax.cond``) and runs the same body
   over the whole length T x k when they overflow the prefix. Nothing is
   dropped or clipped in either, and where both apply they add the same
-  numbers in the same order.
+  numbers in the same order (a token's rows in sorted order, by expert).
   What absent experts would have added is left out; the exchange that
   brings other chips' rows here wraps this layer later (ROADMAP, D14).
   A ``sigmoid_bias`` router (``route``) and shared experts beside the
@@ -62,20 +66,32 @@ from horovod_tpu.jax.introspect import (
     SCOPE_MOE_ROWS,
     SCOPE_MOE_SHARED,
 )
+from horovod_tpu.ops import pallas_gather_sum
 from horovod_tpu.parallel.mesh import DATA_AXIS, EXPERT_AXIS
 from horovod_tpu.parallel.mesh import traced_axis_size
 from horovod_tpu.utils import metrics as _metrics
 
 # Counted at trace time: the gathers of M-wide rows one traced expert
-# layer makes, by where (``dispatch_fwd`` / ``combine_fwd`` /
-# ``combine_bwd`` / ``dispatch_bwd``) and from what they read:
-# ``tokens`` (the (T, M) array) or ``rows`` (a (T x k, M) one).
+# layer makes, by where (``dispatch_fwd`` / ``combine_bwd``) and from
+# what they read: ``tokens`` (the (T, M) array; nothing gathers from
+# sorted ``rows`` since the sums below are a kernel).
 _M_ROW_GATHERS = _metrics.counter(
     "hvd_moe_row_gathers_total",
     "Gathers of rows per traced expert layer, by site, by the array "
     "they read and by the length of the sorted-row arrays (counted at "
     "trace time, not per device step).",
     ("site", "source", "rows"))
+# Also at trace time: the sums over a token's sorted rows one traced
+# expert layer makes, by where (``combine_fwd`` / ``dispatch_bwd``), by
+# the length of the row arrays and by what makes them: ``kernel``
+# (ops/pallas_gather_sum.py, the one form there is; ``xla`` would be a
+# gather of T x k rows, a mask and a reduction, and reads 0).
+_M_ROW_SUMS = _metrics.counter(
+    "hvd_moe_row_sums_total",
+    "Sums over a token's sorted rows per traced expert layer, by site, "
+    "by the length of the sorted-row arrays and by what makes them "
+    "(counted at trace time, not per device step).",
+    ("site", "rows", "via"))
 # The traced bodies of the expert layer by the length of their sorted-row
 # arrays: ``whole`` (T x k rows) or ``prefix`` (``prefix_rows``). A layer
 # that holds a share of the experts traces both, forward and backward.
@@ -261,62 +277,53 @@ def _rows_of_tokens(site, tokens, order, k):
     return tokens[order // k]
 
 
-def _sum_per_token(site, rows, inverse, k, live):
-    """(T, M): the float32 sum of each token's k sorted rows, rounded
-    once to the rows' dtype. Only the first ``live`` sorted rows count
-    (None: all of them): what a dead row holds is never read. ``rows``
-    may be a prefix of the T x k sorted rows that holds every live one:
-    a pair sorted past it is a dead pair, and reads the prefix's last
-    row before it is masked."""
-    n, total = rows.shape[0], inverse.shape[0]
-    _M_ROW_GATHERS.labels(site=site, source="rows",
-                          rows=_row_arrays(n, total)).inc()
-    pairs = rows[inverse if n == total else jnp.minimum(inverse, n - 1)]
-    if live is not None:
-        pairs = jnp.where((inverse < live)[:, None], pairs, 0)
-    pairs = pairs.reshape(-1, k, rows.shape[-1])
-    return jnp.sum(pairs, axis=1, dtype=jnp.float32).astype(rows.dtype)
+def _sum_per_token(site, rows, visits, t, k):
+    """(T, M): the float32 sum of each token's live sorted rows, rounded
+    once to the rows' dtype, by the kernel of ops/pallas_gather_sum.py
+    over ``visits`` (its ``plan`` of these rows): what a dead row holds
+    is never added, and no array of T x k rows is made. ``rows`` may be
+    a prefix of the T x k sorted rows that holds every live one."""
+    _M_ROW_SUMS.labels(site=site, rows=_row_arrays(rows.shape[0], t * k),
+                       via="kernel").inc()
+    return pallas_gather_sum.gather_sum(rows, visits, t)
 
 
 # Dispatch and combine are each other's transposes, so each one's
 # backward pass is the other's forward: two gathers from the (T, M)
-# array, two from (T x k, M) rows, no broadcast and no scatter-add.
-# ``live`` (a traced scalar, or None for "every row") is the number of
-# sorted rows that belong to an expert held here; ``order`` names the
-# sorted rows the arrays hold (all T x k, or a prefix with every live
-# one), ``inverse`` is always T x k long.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch(tokens, order, inverse, live, k):
-    """The tokens' rows in sorted order, (n, M)."""
+# array, two sums over a token's sorted rows, no broadcast and no
+# scatter-add. ``order`` names the sorted rows the arrays hold (all
+# T x k, or a prefix with every live one); ``visits`` is the sums' plan
+# over them, which knows the live rows.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _dispatch(tokens, order, visits, k, t):
+    """The ``t`` tokens' rows in sorted order, (n, M)."""
     return _rows_of_tokens("dispatch_fwd", tokens, order, k)
 
 
-def _dispatch_fwd(tokens, order, inverse, live, k):
-    return _rows_of_tokens("dispatch_fwd", tokens, order, k), (inverse, live)
+def _dispatch_fwd(tokens, order, visits, k, t):
+    return _rows_of_tokens("dispatch_fwd", tokens, order, k), visits
 
 
-def _dispatch_bwd(k, res, d_rows):
-    inverse, live = res
-    return (_sum_per_token("dispatch_bwd", d_rows, inverse, k, live),
-            None, None, None)
+def _dispatch_bwd(k, t, visits, d_rows):
+    return (_sum_per_token("dispatch_bwd", d_rows, visits, t, k), None,
+            None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _combine(rows, order, inverse, live, k):
-    """Each token's sum over its k sorted rows, (T, M)."""
-    return _sum_per_token("combine_fwd", rows, inverse, k, live)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _combine(rows, order, visits, k, t):
+    """Each of the ``t`` tokens' sum over its sorted rows, (T, M)."""
+    return _sum_per_token("combine_fwd", rows, visits, t, k)
 
 
-def _combine_fwd(rows, order, inverse, live, k):
-    return _sum_per_token("combine_fwd", rows, inverse, k, live), order
+def _combine_fwd(rows, order, visits, k, t):
+    return _sum_per_token("combine_fwd", rows, visits, t, k), order
 
 
-def _combine_bwd(k, order, d_out):
-    return (_rows_of_tokens("combine_bwd", d_out, order, k), None, None,
-            None)
+def _combine_bwd(k, t, order, d_out):
+    return _rows_of_tokens("combine_bwd", d_out, order, k), None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -387,15 +394,20 @@ def _expert_rows(n, k, tokens, order, inverse, gates, sizes, live, wi, wo,
     ``sizes`` the held experts' rows; the weights are used in the
     tokens' dtype."""
     _M_ROW_ARRAYS.labels(rows=_row_arrays(n, order.shape[0])).inc()
+    t, m = tokens.shape
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         head = order[:n]      # (a slice of the whole length traces nothing)
-        rows = _dispatch(tokens, head, inverse, live, k)
+        # Shared by the two sums over a token's rows: combine forward
+        # and dispatch backward.
+        visits = pallas_gather_sum.plan(head, k, live, t, m, tokens.dtype,
+                                        sizes.shape[0])
+        rows = _dispatch(tokens, head, visits, k, t)
         row_gates = _permute(gates.reshape(-1), order, inverse)[:n]
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         out = grouped_ffn(rows, row_gates, sizes, wi.astype(rows.dtype),
                           wo.astype(rows.dtype), wg, live)
     with jax.named_scope(SCOPE_MOE_COMBINE):
-        return _combine(out, head, inverse, live, k)
+        return _combine(out, head, visits, k, t)
 
 
 def _rows_branch(n, k):

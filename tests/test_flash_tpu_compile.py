@@ -170,16 +170,23 @@ def test_gradient_sync_in_the_compiled_step(topo, monkeypatch, cell_name):
         assert opcode not in named, named
 
 
-def test_expert_layer_materialises_no_float32_rows(one_chip):
+def test_expert_layer_materialises_no_float32_rows(one_chip, monkeypatch):
     """``MoeMlp`` forward + backward compiled for a described v5e, bf16
-    compute: the jaxpr's float32 operand of the sum over k is fused into
-    its reduction, so no instruction of the entry computation holds a
-    float32 array of T x k x M elements, and nothing is broadcast to
-    (T x k, M) rows; the rows move by four gathers."""
+    compute: no instruction of the entry computation holds a float32
+    array of T x k x M elements, nothing is broadcast to (T x k, M)
+    rows, the rows move by two gathers from the (T, M) tokens, and a
+    token's rows are summed by two Mosaic calls under the kernel's name
+    (``ops/pallas_gather_sum.py``), which ``trace_reduce.flash_kernel``
+    takes for no flash kernel."""
+    from benchmark import trace_reduce
     from flax.core import meta
     from horovod_tpu import models
     from horovod_tpu.parallel.moe import MoeMlp
 
+    # (The default backend here is the CPU: the described chip needs the
+    # Mosaic kernel, not its interpret mode.)
+    monkeypatch.setattr(pallas_attention, "_should_interpret",
+                        lambda interpret: False)
     t, k, m, f, e = 512, 2, 256, 128, 4
     layer = MoeMlp(models.TransformerConfig(
         d_model=m, n_heads=2, d_ff=f, dtype=jnp.bfloat16,
@@ -210,7 +217,73 @@ def test_expert_layer_materialises_no_float32_rows(one_chip):
                               % (t * k, m), text):
         read += re.findall(r"%%%s = bf16\[(\d+),%d\]"
                            % (re.escape(operand), m), text)
-    assert sorted(int(n) for n in read) == [t, t, t * k, t * k], read
+    assert sorted(int(n) for n in read) == [t, t], read
+    sums = _gather_sum_calls(text)
+    assert len(sums) == 2, sums
+    scopes = introspect.instruction_scopes(text)
+    assert sorted(
+        part for name, _ in sums
+        for part in (introspect.SCOPE_MOE_COMBINE,
+                     introspect.SCOPE_MOE_DISPATCH) if part in scopes[name]
+    ) == [introspect.SCOPE_MOE_COMBINE, introspect.SCOPE_MOE_DISPATCH]
+    for _, line in sums:
+        assert trace_reduce.flash_kernel(line) == ""
+
+
+def _gather_sum_calls(text):
+    """[(instruction, HLO line as a trace names it)] of the Mosaic calls
+    of ``ops/pallas_gather_sum.py`` in a compiled step."""
+    return [(m.group(1), m.group(0).strip().removeprefix("ROOT "))
+            for m in re.finditer(
+                r"^\s*(?:ROOT )?%%(%s[.\d]*) = .*$"
+                % introspect.KERNEL_MOE_GATHER_SUM, text, re.M)]
+
+
+@pytest.mark.parametrize("n,t,k,groups", [
+    (32768, 16384, 4, 8),     # lfm2-s16384-ep4-c1, the prefix
+    (16384, 8192, 8, 16),     # trinity-s8192-ep8-c1
+    (8192, 8192, 4, 8),       # glm47f-s8192-ep8-c1
+    (32768, 4096, 8, 64),     # olmoe-s4096-c1: every row live
+    (65536, 16384, 4, 8),     # the whole length: LFM2's, Trinity's,
+    (65536, 8192, 8, 16),     # (a step whose routers overflow the prefix)
+    (32768, 8192, 4, 8),      # GLM's
+])
+def test_gather_sum_lowers_for_v5e(one_chip, monkeypatch, n, t, k, groups):
+    """The sum over a token's sorted rows at the four expert cells'
+    shapes, M = 2048 in bf16, and over the whole T x k of the three
+    that hold a share: ONE Mosaic call under its name, whose operand
+    count (three scalar-prefetch arrays, the rows' tokens, the rows) is
+    neither the flash forward's 3 nor the backward kernels' 6, by which
+    ``benchmark/trace_reduce.py`` ``flash_kernel`` tells those from
+    other calls; no (T x k, M) array is made, and the plan of its
+    visits holds no sort."""
+    from benchmark import trace_reduce
+    from horovod_tpu.ops import pallas_gather_sum
+
+    m = 2048
+    rows = jax.ShapeDtypeStruct((n, m), jnp.bfloat16, sharding=one_chip)
+    order = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    live = (None if groups == 64 else
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+
+    def sums(rows, order, live):
+        visits = pallas_gather_sum.plan(order, k, live, t, m, rows.dtype,
+                                        groups)
+        return pallas_gather_sum.gather_sum(rows, visits, t)
+
+    monkeypatch.setattr(pallas_attention, "_should_interpret",
+                        lambda interpret: False)
+    text = jax.jit(sums).lower(rows, order, live).compile().as_text()
+    calls = _gather_sum_calls(text)
+    assert len(calls) == 1, calls
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    (_, line), = calls
+    operands = line.split(" custom-call(")[1].split("), custom_call_target=")[
+        0].count("%")
+    assert operands == 5 and trace_reduce.flash_kernel(line) == "", line
+    assert " sort(" not in text
+    if n != t * k:
+        assert "[%d,%d]" % (t * k, m) not in text
 
 
 def _branches(text):
@@ -232,15 +305,17 @@ def _branches(text):
     return found
 
 
-def test_held_expert_layer_chooses_its_row_arrays(one_chip):
+def test_held_expert_layer_chooses_its_row_arrays(one_chip, monkeypatch):
     """``MoeMlp`` that holds 2 of 16 experts, forward + recomputed
     forward + backward (``cfg.remat``'s policy) compiled for a described
     v5e: T x k = 4096 pairs, the prefix C = 1024 rows. TWO conditionals
     (the recomputed forward's is dead code: the backward rule's
     residuals are the layer's inputs). In each the prefix branch's
     grouped matmuls take and make C rows, its row gathers are C rows
-    from the tokens or T x k pairs from C rows, and nothing in it is
-    (T x k, F): no select over the whole length. Every instruction of
+    from the tokens, a token's rows are summed by ONE call of the
+    gather-and-sum kernel (under the combine's scope forward, the
+    dispatch's backward), and nothing in it is (T x k, F) or
+    (T x k, M): no select and no pairs over the whole length. Every instruction of
     both branches keeps an ``hvd_moe_*`` scope through
     ``instruction_scopes``, own or inherited; the grouped matmuls carry
     none of their own and read one part's off their largest operand, as
@@ -255,6 +330,8 @@ def test_held_expert_layer_chooses_its_row_arrays(one_chip):
     from horovod_tpu.models import transformer
     from horovod_tpu.parallel.moe import MoeMlp, prefix_rows
 
+    monkeypatch.setattr(pallas_attention, "_should_interpret",
+                        lambda interpret: False)
     # M and F above C / held rows, as in the cells: an expert's panel is
     # then a grouped matmul's largest operand.
     t, k, m, f, e, held = 2048, 2, 768, 640, 16, 2
@@ -329,8 +406,19 @@ def test_held_expert_layer_chooses_its_row_arrays(one_chip):
             for source in re.findall(
                 r"bf16\[(\d+),%d\]" % m, shape_of.get(re.findall(
                     r" fusion\(%([\w.\-]+)", line)[0], "")))
-        assert gathers in ([(c, t), (pairs, c)],
-                           [(c, t), (c, t), (pairs, c)]), (name, gathers)
+        # Forward the dispatch; backward the dispatch again and the
+        # combine's. NO gather reads the sorted rows: a token's rows are
+        # summed by the kernel, once in each (the recomputed combine is
+        # dead code), and nothing in the prefix's branch is (T x k, M).
+        assert gathers in ([(c, t)], [(c, t), (c, t)]), (name, gathers)
+        sums = [inst for inst, _, _ in prefix
+                if inst.startswith(introspect.KERNEL_MOE_GATHER_SUM)]
+        assert len(sums) == 1, (name, sums)
+        part = (introspect.SCOPE_MOE_COMBINE if len(gathers) == 1
+                else introspect.SCOPE_MOE_DISPATCH)
+        assert part in scopes[sums[0]], (name, scopes[sums[0]])
+        assert not [line for _, _, line in prefix
+                    if "[%d,%d]" % (pairs, m) in result(line)], name
 
 
 def test_the_layer_that_holds_every_expert_chooses_nothing(topo, monkeypatch):

@@ -426,4 +426,8 @@ def test_a_recomputed_block_keeps_the_held_expert_layers_output(
 
     text = jax.jit(jax.grad(loss)).lower(
         variables["params"]).compile().as_text()
-    assert len(re.findall(r" conditional\(", text)) == choices
+    # (Off the chip the sums' Pallas kernel runs in interpret mode, a
+    # loop whose ``pl.when``s are conditionals under the kernel's name.)
+    assert len([line for line in text.splitlines()
+                if " conditional(" in line
+                and introspect.KERNEL_MOE_GATHER_SUM not in line]) == choices

@@ -410,13 +410,16 @@ def test_held_moe_mlp_prefix_and_whole_rows(live, remat, monkeypatch):
 
 
 @pytest.mark.parametrize("held", [None, 1], ids=["all-held", "share-held"])
-def test_moe_mlp_moves_rows_only_by_four_gathers(held):
+def test_moe_mlp_moves_rows_by_two_gathers_and_two_kernel_sums(held):
     """Token-major dispatch and combine, read off the traced layer
     (bf16 compute over float32 parameters, forward + backward): nothing
-    is broadcast to T x k rows, no row is scatter-added, a float32
-    value of T x k x M elements exists only as the operand of its own
-    reduction over k, and the rows move by four gathers, two of them
-    from the (T, M) array.
+    is broadcast to T x k rows, no row is scatter-added, the rows move
+    by two gathers from the (T, M) array (dispatch forward, combine
+    backward), and a token's rows are summed by the Pallas kernel of
+    ``ops/pallas_gather_sum.py`` (combine forward, dispatch backward):
+    NO gather reads the sorted rows, no array of T x k pairs of rows is
+    made, and outside the kernels no float32 value has as many elements
+    as a row array.
 
     The same of a layer that holds 1 of its 8 experts, in EACH of its
     two bodies (the prefix of C = 512 sorted rows and the whole 2048):
@@ -444,36 +447,47 @@ def test_moe_mlp_moves_rows_only_by_four_gathers(held):
     x = jnp.ones((1, t, m), jnp.bfloat16)
     params = meta.unbox(layer.init(jax.random.PRNGKey(0), x))
 
-    # (site, source): times traced in one body, forward + backward.
-    sites = {("dispatch_fwd", "tokens"): 1, ("combine_fwd", "rows"): 1,
-             ("combine_bwd", "tokens"): 1, ("dispatch_bwd", "rows"): 1}
+    # site: times traced in one body, forward + backward.
+    gathers = {"dispatch_fwd": 1, "combine_bwd": 1}
+    sums = {"combine_fwd": 1, "dispatch_bwd": 1}
     if held:
-        sites[("dispatch_fwd", "tokens")] = sites[("combine_fwd", "rows")] = 2
+        gathers["dispatch_fwd"] = sums["combine_fwd"] = 2
 
-    def row_gathers():
-        return {key + (rows,): metrics.REGISTRY.value(
-            "hvd_moe_row_gathers_total", site=key[0], source=key[1],
-            rows=rows) or 0
-            for rows in ("whole", "prefix")
-            for key in set(sites) | {("dispatch_fwd", "rows"),
-                                     ("combine_bwd", "rows")}}
+    def counted():
+        found = {}
+        for rows in ("whole", "prefix"):
+            for site in ("dispatch_fwd", "combine_fwd", "combine_bwd",
+                         "dispatch_bwd"):
+                for source in ("tokens", "rows"):
+                    found["gather", site, source, rows] = (
+                        metrics.REGISTRY.value(
+                            "hvd_moe_row_gathers_total", site=site,
+                            source=source, rows=rows) or 0)
+                for via in ("kernel", "xla"):
+                    found["sum", site, via, rows] = metrics.REGISTRY.value(
+                        "hvd_moe_row_sums_total", site=site, rows=rows,
+                        via=via) or 0
+        return found
 
-    before = row_gathers()
+    before = counted()
     jaxpr = jax.make_jaxpr(lambda p, x_: jax.vjp(layer.apply, p, x_)[1](x_))(
         params, x)
-    moved = {key: n - before[key] for key, n in row_gathers().items()}
-    assert {key: n for key, n in moved.items() if n} == {
-        key + (rows,): n for rows in lengths for key, n in sites.items()}
+    moved = {key: n - before[key] for key, n in counted().items()}
+    want = {}
+    for rows in lengths:
+        for site, n in gathers.items():
+            want["gather", site, "tokens", rows] = n
+        for site, n in sums.items():
+            want["sum", site, "kernel", rows] = n
+    assert {key: n for key, n in moved.items() if n} == want
 
-    eqns = list(introspect.equations(jaxpr.jaxpr))
-    users = {}
-    for eqn in eqns:
-        for var in eqn.invars:
-            if not isinstance(var, jax.extend.core.Literal):
-                users.setdefault(var, []).append(eqn)
-    row_gathers = []
+    eqns = list(introspect.equations(jaxpr.jaxpr, skip=("pallas_call",)))
+    row_gathers, kernels = [], []
     for eqn in eqns:
         name = eqn.primitive.name
+        if name == "pallas_call":
+            kernels.append((eqn.invars[-1].aval.shape[0],
+                            eqn.outvars[0].aval.shape))
         for out in eqn.outvars:
             shape = getattr(out.aval, "shape", ())
             if (not shape or shape[-1] != m or int(np.prod(shape))
@@ -488,19 +502,17 @@ def test_moe_mlp_moves_rows_only_by_four_gathers(held):
                 continue
             assert name != "broadcast_in_dim", eqn
             assert not name.startswith("scatter"), eqn
+            assert out.aval.dtype != jnp.float32, eqn
             if name == "gather":
                 row_gathers.append((shape[0], eqn.invars[0].aval.shape[0]))
-            if out.aval.dtype == jnp.float32:
-                assert name == "convert_element_type", eqn
-                assert [u.primitive.name for u in users[out]] == [
-                    "reduce_sum"], eqn
-                assert users[out][0].outvars[0].aval.shape == (t, m)
-    # (rows gathered, rows of the array read), each body's.
-    want = []
+    # (rows gathered, rows of the array read) and (rows summed, the
+    # result's shape), each body's.
+    want_gathers, want_kernels = [], []
     for n in lengths.values():
-        want += [(n, t)] * (sites[("dispatch_fwd", "tokens")] + 1)
-        want += [(t * k, n)] * (sites[("combine_fwd", "rows")] + 1)
-    assert sorted(row_gathers) == sorted(want)
+        want_gathers += [(n, t)] * (gathers["dispatch_fwd"] + 1)
+        want_kernels += [(n, (t, m))] * (sums["combine_fwd"] + 1)
+    assert sorted(row_gathers) == sorted(want_gathers)
+    assert sorted(kernels) == sorted(want_kernels)
     assert len([eqn for eqn in eqns if eqn.primitive.name == "cond"]) == (
         2 if held else 0)
     # Nor anything else: the gates' gradient reaches the probabilities

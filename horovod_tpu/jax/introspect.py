@@ -50,6 +50,13 @@ SCOPE_ATTN_GATE = "hvd_attn_gate"
 # multiplies and the causal taps between the in- and out-projections
 # (the projections themselves are the module's own).
 SCOPE_CONV_GATE = "hvd_conv_gate"
+# SelfAttention with ``BlockSpec.index_topk`` (a learned key selection,
+# DeepSeek sparse attention's indexer): under the first the indexer's
+# three projections, its norm, RoPE and the (S, S) float32 scores; under
+# the second each row's ``index_topk``-th largest score and the mask
+# it makes (the packed planes are ops/pallas_attention.py's).
+SCOPE_DSA_INDEX = "hvd_dsa_index"
+SCOPE_DSA_SELECT = "hvd_dsa_select"
 # The expert layer (parallel/moe.py), inside the ``moe`` module's scope.
 SCOPE_MOE_ROUTER = "hvd_moe_router"      # logits, softmax, top-k, aux losses
 # The sorts (the gates ride one into row order); rows gathered from the
@@ -74,6 +81,11 @@ SCOPE_MOE_SHARED = "hvd_moe_shared"
 KERNEL_FLASH_FWD = "hvd_flash_fwd"
 KERNEL_FLASH_DKV = "hvd_flash_dkv"
 KERNEL_FLASH_DQ = "hvd_flash_dq"
+# The same three kernels under a mask that is DATA (``select=``): a
+# fourth operand in the forward, a seventh in the two backward kernels.
+KERNEL_DSA_FWD = "hvd_dsa_fwd"
+KERNEL_DSA_DKV = "hvd_dsa_dkv"
+KERNEL_DSA_DQ = "hvd_dsa_dq"
 # ``name=`` of the expert layer's sum over a token's sorted rows
 # (ops/pallas_gather_sum.py; under ``hvd_moe_combine`` forward and
 # ``hvd_moe_dispatch`` backward, where ``_sum_per_token`` stands).
@@ -89,6 +101,11 @@ SAVED_FLASH_LSE = "hvd_flash_lse"
 SAVED_FLASH_Q = "hvd_flash_q"
 SAVED_FLASH_K = "hvd_flash_k"
 SAVED_FLASH_V = "hvd_flash_v"
+# The learned selection as the kernels read it: two bit planes of the
+# (S, S) mask, packed along the keys (forward, dQ) and along the
+# queries (dK/dV), S x S / 8 bytes each. Kept, a recomputed block
+# neither scores nor selects again.
+SAVED_FLASH_SELECT = "hvd_flash_select"
 # What else a recomputed block keeps (models/transformer.py
 # ``_remat_block`` holds the rule and the bytes): the matmul products
 # that the backward pass reads. In the attention module the latent

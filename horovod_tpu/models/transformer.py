@@ -24,7 +24,12 @@ each branch's output and a multiplier on the embedding: what the
 ``afmoe`` family's ``config.json`` keys say. The pattern's third kind of
 layer is no attention at all: ``conv``, the ``lfm2`` family's gated
 short convolution (``ShortConv``), which mixes each token with the few
-before it and has neither heads nor positions.
+before it and has neither heads nor positions. The fourth,
+``sparse_attention``, is full attention over the keys each query CHOSE:
+a small indexer scores every causal pair, a query keeps its
+``index_topk`` best (DeepSeek sparse attention, as ``Keye-VL-2.0``'s
+language model carries it), and the attention kernels take that choice
+as data.
 
 Param layout (tensor parallel over 'model'):
 - attention QKV projections shard the head dim;
@@ -57,6 +62,7 @@ from horovod_tpu.jax.introspect import (
     SAVED_FLASH_LSE,
     SAVED_FLASH_OUT,
     SAVED_FLASH_Q,
+    SAVED_FLASH_SELECT,
     SAVED_FLASH_V,
     SAVED_MLP_GATE,
     SAVED_MLP_OUT,
@@ -64,6 +70,8 @@ from horovod_tpu.jax.introspect import (
     SAVED_MOE_OUT,
     SCOPE_ATTN_GATE,
     SCOPE_CONV_GATE,
+    SCOPE_DSA_INDEX,
+    SCOPE_DSA_SELECT,
     SCOPE_EMBED,
     SCOPE_LOGITS,
     SCOPE_MLA_LATENT,
@@ -78,9 +86,12 @@ param_with_axes = nn.with_partitioning
 
 # The kinds of layer, by their token mixer, as ``config.json``
 # ``layer_types`` spells them: two of attention, and the gated short
-# convolution.
+# convolution; and full attention over a learned selection of the keys,
+# which is what every layer of a spec with ``index_topk`` and no
+# ``layer_types`` is.
 FULL_ATTENTION, SLIDING_ATTENTION = "full_attention", "sliding_attention"
 CONV = "conv"
+SPARSE_ATTENTION = "sparse_attention"
 
 # Counted at trace time: the layers one traced model makes, by the kind
 # of their token mixer (the name dates from when every mixer was an
@@ -88,8 +99,18 @@ CONV = "conv"
 _M_ATTN_LAYERS = _metrics.counter(
     "hvd_attn_layers_total",
     "Layers per traced model, by the kind of their token mixer "
-    "(full_attention / sliding_attention / conv; counted at trace time, "
-    "not per device step).",
+    "(full_attention / sliding_attention / conv / sparse_attention; "
+    "counted at trace time, not per device step).",
+    ("kind",))
+
+# Counted at trace time: the (query, key) pairs of one traced sparse
+# layer, all causal ones and those a selection of ``index_topk`` keeps
+# (ties at a row's threshold apart, which the sown ``dsa_kept`` counts).
+_M_DSA_PAIRS = _metrics.counter(
+    "hvd_dsa_pairs_total",
+    "Query-key pairs per traced sparse_attention layer: causal = B S (S "
+    "+ 1) / 2, kept = B sum_t min(t + 1, index_topk) (counted at trace "
+    "time, not per device step).",
     ("kind",))
 
 # Counted at trace time: the blocks traced under ``cfg.remat``, by what
@@ -155,6 +176,13 @@ class BlockSpec:
     layer_types: tuple = ()
     sliding_window: int = 0
     conv_taps: int = 0
+    # A SPARSE_ATTENTION layer's indexer (0 = none, and no such layer):
+    # ``index_heads`` heads of ``index_head_dim`` over ONE key head
+    # score each causal pair; a query attends to its ``index_topk``
+    # best keys (all of them while it has no more).
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # The kinds whose q and k carry the rotary positions; None = every
     # layer, if ``positions`` is 'rope'.
     rope_layers: Optional[tuple] = None
@@ -257,8 +285,9 @@ def rope(x, first_position, theta):
                            -1).astype(x.dtype)
 
 
-def _dense_causal_attention(q, k, v, dtype, window=None):
-    # q: (B, S, H, D); k, v: (B, S, H_kv, D), H_kv a divisor of H.
+def _dense_causal_attention(q, k, v, dtype, window=None, select=None):
+    # q: (B, S, H, D); k, v: (B, S, H_kv, D), H_kv a divisor of H;
+    # select: (B, S, S), True where the query keeps the key.
     d = q.shape[-1]
     group = q.shape[2] // k.shape[2]
     if group > 1:
@@ -268,27 +297,40 @@ def _dense_causal_attention(q, k, v, dtype, window=None):
     causal = jnp.tril(jnp.ones((s, s), bool))
     if window is not None:
         causal &= ~jnp.tril(jnp.ones((s, s), bool), -window)
-    scores = jnp.where(causal[None, None], scores, jnp.asarray(-1e9, scores.dtype))
+    causal = causal[None, None]
+    if select is not None:
+        causal = causal & select[:, None]
+    scores = jnp.where(causal, scores, jnp.asarray(-1e9, scores.dtype))
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _attend(cfg, q, k, v, window=None):
+def _attend(cfg, q, k, v, window=None, select=None):
     """Causal attention of q (B, S, H, D) over k, v (B, S, H_kv, D) by
     ``cfg.attention``; under ``window`` a query sees that many keys up
-    to itself."""
+    to itself, under ``select`` (B, S, S) those it keeps."""
     if cfg.attention == "dense":
-        return _dense_causal_attention(q, k, v, cfg.dtype, window)
+        return _dense_causal_attention(q, k, v, cfg.dtype, window, select)
     if cfg.attention == "flash":
-        from horovod_tpu.ops.pallas_attention import flash_attention
+        from horovod_tpu.ops.pallas_attention import (
+            flash_attention,
+            pack_selection,
+        )
 
+        if select is not None:
+            with jax.named_scope(SCOPE_DSA_SELECT):
+                select = pack_selection(select)
+            return flash_attention(q, k, v, causal=True,
+                                   select=select).astype(cfg.dtype)
         return flash_attention(q, k, v, causal=True,
                                window=window).astype(cfg.dtype)
-    if window is not None or k.shape[2] != q.shape[2]:
+    if window is not None or select is not None \
+            or k.shape[2] != q.shape[2]:
         raise ValueError(
             "attention=%r has no sliding window and no grouped key/value "
-            "heads (window %r, %d query heads over %d): use 'flash' or "
-            "'dense'" % (cfg.attention, window, q.shape[2], k.shape[2]))
+            "heads, nor a learned selection (window %r, %d query heads "
+            "over %d): use 'flash' or 'dense'"
+            % (cfg.attention, window, q.shape[2], k.shape[2]))
     if cfg.attention == "ring":
         from horovod_tpu.parallel.sequence import ring_attention
 
@@ -300,18 +342,140 @@ def _attend(cfg, q, k, v, window=None):
     raise ValueError("Unknown attention impl %r" % (cfg.attention,))
 
 
+def kth_largest(scores, k):
+    """Each row's EXACT ``k``-th largest of float32 ``scores`` (..., n),
+    ``-inf`` entries included (so ``-inf`` where a row has fewer than k
+    others). No sort: the floats' bits, made to order as unsigned
+    integers do, are bisected from the top bit down, 32 counts of the
+    entries at or above a candidate; the largest candidate that still
+    has ``k`` is the k-th largest itself."""
+    scores = scores + 0.0      # -0.0 orders as +0.0, as floats compare
+    k = min(k, scores.shape[-1])
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    keys = jnp.where(bits >= top, ~bits, bits | top)
+
+    def narrow(i, found):
+        candidate = found | (top >> i.astype(jnp.uint32))
+        count = jnp.sum(keys >= candidate[..., None], axis=-1,
+                        dtype=jnp.int32)
+        return jnp.where(count >= k, candidate, found)
+
+    found = jax.lax.fori_loop(
+        0, 32, narrow, jnp.zeros(scores.shape[:-1], jnp.uint32))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(found >= top, found ^ top, ~found), jnp.float32)
+
+
+def select_rows(scores, topk):
+    """(..., n) bool: of each row of float32 ``scores``, the entries at
+    or above its ``topk``-th largest, ties included; never a ``-inf``
+    one (a key after its query). All of a row's finite entries while
+    there are no more than ``topk``."""
+    return (scores >= kth_largest(scores, topk)[..., None]) \
+        & (scores > -jnp.inf)
+
+
+def index_scores(q_i, k_i, w_i, first_query):
+    """The indexer's score of each (query, key) pair, float32:
+    ``I[t, s] = sum_j w[t, j] relu(q_i[t, j] . k_i[s])`` for queries
+    q_i (B, C, J, D) at positions ``first_query``.., keys k_i (B, S, D)
+    and weights w_i (B, C, J); ``-inf`` for a key after its query."""
+    dots = jnp.einsum("bcjd,bsd->bcjs", q_i, k_i,
+                      preferred_element_type=jnp.float32)
+    scores = jnp.sum(w_i[..., None] * nn.relu(dots), axis=2)
+    rows = first_query + jnp.arange(q_i.shape[1])
+    causal = jnp.arange(k_i.shape[1])[None, :] <= rows[:, None]
+    return jnp.where(causal[None], scores, -jnp.inf)
+
+
+# Queries a pass of the indexer: its (C, J, S) float32 products are
+# what exists at once, 268 MB at 16 heads over 8192 keys; never (J, S, S).
+_INDEX_CHUNK = 512
+
+
+def learned_selection(q_i, k_i, w_i, topk):
+    """(B, S, S) bool: the keys each query keeps, its ``topk`` highest
+    ``index_scores`` among those at or before it and whatever ties the
+    last of them; every such key while there are no more than ``topk``.
+    Exact, and evaluated a block of queries at a time."""
+    b, s, j, d = q_i.shape
+    chunk = _INDEX_CHUNK if s % _INDEX_CHUNK == 0 else s
+
+    def of_chunk(args):
+        q_c, w_c, first = args
+        with jax.named_scope(SCOPE_DSA_INDEX):
+            scores = index_scores(q_c, k_i, w_c, first)
+        with jax.named_scope(SCOPE_DSA_SELECT):
+            return select_rows(scores, topk)
+
+    kept = jax.lax.map(of_chunk, (
+        jnp.swapaxes(q_i.reshape(b, s // chunk, chunk, j, d), 0, 1),
+        jnp.swapaxes(w_i.reshape(b, s // chunk, chunk, j), 0, 1),
+        jnp.arange(0, s, chunk)))
+    return jnp.swapaxes(kept, 0, 1).reshape(b, s, s)
+
+
 class SelfAttention(nn.Module):
     """Multi-head attention by ``cfg.block``. ``window`` makes this
     layer a sliding one; ``rotary`` says whether this layer's q and k
-    carry the rotary positions (``Block`` reads both off the layer's
-    kind)."""
+    carry the rotary positions; ``sparse`` gives it the indexer, and
+    its queries the keys they choose (``Block`` reads all three off the
+    layer's kind)."""
 
     cfg: TransformerConfig
     window: Optional[int] = None
     rotary: bool = True
+    sparse: bool = False
+
+    @nn.nowrap
+    def _selection(self, x, weight):
+        """The learned choice of keys for the block's input x (B, S, M):
+        ``q_i = x W_q`` (``index_heads`` of ``index_head_dim``), ``k_i =
+        LayerNorm(x W_k)`` (one head), ``w = x W_w / sqrt(heads x dim)``,
+        rotary positions on all of q_i and k_i, then
+        ``learned_selection``. Nothing here carries a gradient: the
+        choice is piecewise constant in the four leaves, which stay in
+        the tree and receive zeros (DeepSeek sparse attention trains
+        them by a term of their own, which this model has not)."""
+        cfg, spec = self.cfg, self.cfg.block
+        j, d, topk = spec.index_heads, spec.index_head_dim, spec.index_topk
+        if min(j, d, topk) < 1:
+            raise ValueError("a sparse_attention layer needs BlockSpec."
+                             "index_heads, index_head_dim and index_topk")
+        if cfg.seq_axis is not None:
+            raise ValueError("a sparse_attention layer chooses among all the "
+                             "keys and exchanges none over seq_axis=%r"
+                             % (cfg.seq_axis,))
+        m, (b, s) = cfg.d_model, x.shape[:2]
+        causal = s * (s + 1) // 2
+        _M_DSA_PAIRS.labels(kind="causal").inc(b * causal)
+        _M_DSA_PAIRS.labels(kind="kept").inc(b * (
+            causal if topk >= s else topk * s - topk * (topk - 1) // 2))
+        u = jax.lax.stop_gradient(x)
+        with jax.named_scope(SCOPE_DSA_INDEX):
+            q_i = jnp.einsum("bsm,mjd->bsjd", u, weight(
+                "index_wq", (None, None, None), (m, j, d)))
+            k_i = nn.LayerNorm(epsilon=spec.norm_eps, dtype=cfg.dtype,
+                               name="index_k_norm")(
+                u @ weight("index_wk", (None, None), (m, d)))
+            w_i = (u @ weight("index_ww", (None, None), (m, j))).astype(
+                jnp.float32) * float(j * d) ** -0.5
+            q_i = rope(q_i, 0, spec.rope_theta)
+            k_i = rope(k_i[:, :, None, :], 0, spec.rope_theta)[:, :, 0]
+        select = jax.lax.stop_gradient(learned_selection(q_i, k_i, w_i, topk))
+        with jax.named_scope(SCOPE_DSA_SELECT):
+            # The pairs this step's mask keeps: the count above plus ties.
+            self.sow("dsa", "dsa_kept", jnp.sum(select, dtype=jnp.int32))
+        # The mask itself, for whoever asks (a probe, a test): a
+        # collection nothing else makes mutable.
+        self.sow("dsa_mask", "select", select)
+        return select
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, selection=None):
+        """``selection`` (B, S, S) forces a sparse layer's choice of
+        keys (its indexer then runs nothing)."""
         cfg, spec = self.cfg, self.cfg.block
         h, m = cfg.n_heads, cfg.d_model
         d = spec.head_dim or m // h
@@ -358,7 +522,12 @@ class SelfAttention(nn.Module):
                 first = _first_position(cfg, x.shape[1])
                 q = rope(q, first, spec.rope_theta)
                 k = rope(k, first, spec.rope_theta)
-        out = _attend(cfg, q, k, v, self.window)
+        select = None
+        if self.sparse:
+            select = selection
+            if select is None:
+                select = self._selection(x, weight)
+        out = _attend(cfg, q, k, v, self.window, select)
         if spec.attn_gate:
             gate = checkpoint_name(
                 jnp.einsum("bsm,mhd->bshd", x, weight(
@@ -532,18 +701,20 @@ class Block(nn.Module):
 
     def _mixer(self):
         cfg, spec, kind = self.cfg, self.cfg.block, self.layer_type
-        if kind not in (FULL_ATTENTION, SLIDING_ATTENTION, CONV):
+        if kind not in (FULL_ATTENTION, SLIDING_ATTENTION, CONV,
+                        SPARSE_ATTENTION):
             raise ValueError("Unknown attention layer type %r (layer_types "
-                             "knows %s, %s and %s)" % (
+                             "knows %s, %s, %s and %s)" % (
                                  kind, FULL_ATTENTION, SLIDING_ATTENTION,
-                                 CONV))
+                                 CONV, SPARSE_ATTENTION))
         _M_ATTN_LAYERS.labels(kind=kind).inc()
         if kind == CONV:
             return ShortConv(cfg, name="conv")
         sliding = kind == SLIDING_ATTENTION
         if spec.attention_kind == "latent":
-            if sliding:
-                raise ValueError("latent attention has no sliding window")
+            if sliding or kind == SPARSE_ATTENTION:
+                raise ValueError("latent attention has no sliding window "
+                                 "and no learned selection")
             return LatentAttention(cfg, name="attn")
         if sliding and spec.sliding_window < 1:
             raise ValueError("a sliding_attention layer needs "
@@ -551,10 +722,10 @@ class Block(nn.Module):
         return SelfAttention(
             cfg, spec.sliding_window if sliding else None,
             spec.rope_layers is None or kind in spec.rope_layers,
-            name="attn")
+            kind == SPARSE_ATTENTION, name="attn")
 
     @nn.compact
-    def __call__(self, x, assignment=None):
+    def __call__(self, x, assignment=None, selection=None):
         cfg = self.cfg
 
         def joined(x, name, branch):
@@ -563,7 +734,10 @@ class Block(nn.Module):
             return x + branch
 
         y = _norm(cfg, "ln1")(x)
-        x = joined(x, "post_attn_norm", self._mixer()(y))
+        mixer = self._mixer()
+        x = joined(x, "post_attn_norm",
+                   mixer(y, selection)
+                   if self.layer_type == SPARSE_ATTENTION else mixer(y))
         y = _norm(cfg, "ln2")(x)
         if cfg.block.num_experts > 0 and self.dense_width is None:
             from horovod_tpu.parallel.moe import MoeMlp
@@ -603,6 +777,12 @@ _REMAT_KEEPS = (
     # of q and k, the head norms, RoPE and transposes (not their two
     # projections, which the norms' backward needs: below).
     SAVED_FLASH_Q, SAVED_FLASH_K, SAVED_FLASH_V,
+    # A learned selection as the kernels read it (a ``sparse_attention``
+    # layer, Keye-VL-2.0: 16 index heads of 64, top 2048): two bit planes
+    # of the mask, 8.4 MB each. All three kernels read them, so the
+    # recomputed forward has no reader left for the indexer's three
+    # projections, its (S, S) scores or the 8192 row selections.
+    SAVED_FLASH_SELECT,
     # A projection's product that a norm reads: GLM's two latent
     # down-projections, 12.6 + 9.4 MB. Without it the projection is
     # multiplied again for the norm's backward, whatever else is kept.
@@ -645,9 +825,11 @@ def _log_remat(cfg, keeps):
 
 
 def _layer_kinds(cfg):
-    """One entry of ``BlockSpec.layer_types`` a layer; all full
-    attention where the spec names none."""
-    kinds = cfg.block.layer_types or (FULL_ATTENTION,) * cfg.n_layers
+    """One entry of ``BlockSpec.layer_types`` a layer; where the spec
+    names none, all full attention, over a learned selection if the
+    spec has an indexer (``index_topk``)."""
+    every = SPARSE_ATTENTION if cfg.block.index_topk else FULL_ATTENTION
+    kinds = cfg.block.layer_types or (every,) * cfg.n_layers
     if len(kinds) != cfg.n_layers:
         raise ValueError("BlockSpec.layer_types names %d layers, the "
                          "model has %d" % (len(kinds), cfg.n_layers))
@@ -686,12 +868,16 @@ class Transformer(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens, assignments=None):
+    def __call__(self, tokens, assignments=None, selections=None):
         """Logits (B, S, vocab) in float32. ``assignments`` (one entry a
         layer, each (B * S, experts_per_token) expert indices; a dense
         block's is ignored) forces the experts' routing; what the
         expert layers sow is in the ``moe`` collection, the routers'
-        correction biases in ``moe_state`` (parallel/moe.py)."""
+        correction biases in ``moe_state`` (parallel/moe.py).
+        ``selections`` (one entry a layer, each (B, S, S) bool; read by
+        ``sparse_attention`` layers alone) forces the keys each query
+        keeps; the pairs a free choice kept are sown as ``dsa_kept`` in
+        the ``dsa`` collection."""
         cfg = self.cfg
         init = nn.initializers.normal(0.02)
         embed = self.param(
@@ -741,7 +927,8 @@ class Transformer(nn.Module):
             dense = i < cfg.block.first_dense_layers
             x = block(cfg, cfg.block.dense_ff if dense else None, kinds[i],
                       name="layer_%d" % i)(
-                x, None if assignments is None else assignments[i])
+                x, None if assignments is None else assignments[i],
+                None if selections is None else selections[i])
         x = _norm(cfg, "ln_f")(x)
         with jax.named_scope(SCOPE_LOGITS):
             logits = jnp.einsum("bsm,vm->bsv", x, head.astype(cfg.dtype))

@@ -39,6 +39,18 @@ the K/V panel by the query head's group, and dK/dV walks the group's
 query heads as a grid axis, adding into a float32 dK/dV panel that
 stays in VMEM until the group is done.
 
+A learned selection (``select=``, DeepSeek sparse attention): which keys
+a query keeps is then DATA the step computed, not a fact of the trace.
+It comes as two bit planes of the (S, S) mask (``pack_selection``): one
+packed along the keys, which the query-major forward and dQ read a
+query block at a time, one along the queries for the key-major dK/dV.
+A bit stands for a whole lane: bit b of word ``[m, r, j]`` is column
+``(32 m + b) 128 + j`` of row r, so a tile's mask is a shift and an AND
+of (rows, 128) words, 128-lane pieces side by side, no gather and no
+relayout. The loops run every causal tile (``_Tiles.learned``); the
+static causal mask is still applied, the planes may say anything above
+the diagonal.
+
 On non-TPU backends (CPU tests, debugging) the kernels run in Pallas
 interpret mode, so the same code path is exercised everywhere; the
 switch is logged once per backend so a run can tell which it got.
@@ -146,6 +158,9 @@ class _Tiles(NamedTuple):
     q_len: int
     kv_len: int
     window: Optional[int] = None   # keys after position - window; causal
+    # The kernel ANDs a mask it reads from an operand into every tile it
+    # computes: no tile is known to be full, all count as ``learned``.
+    learned: bool = False
 
     @property
     def num_qb(self):
@@ -224,7 +239,8 @@ class _Tiles(NamedTuple):
 
     def counts(self):
         """{"full", "edge", "skipped"} and, under a window, "below":
-        tiles of one plane."""
+        tiles of one plane; under a learned mask {"learned", "skipped"}
+        (and "below")."""
         full = edge = below = 0
         for qi in range(self.num_qb):
             start, end = self.key_start(qi), self.key_end(qi)
@@ -235,6 +251,8 @@ class _Tiles(NamedTuple):
             below += start
         out = {"full": full, "edge": edge,
                "skipped": self.num_qb * self.num_kb - full - edge - below}
+        if self.learned:
+            out = {"learned": full + edge, "skipped": out["skipped"]}
         if self.window is not None:
             out["below"] = below
         return out
@@ -293,11 +311,66 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
+# ------------------------------------------------------------- selection ---
+
+_LANES, _WORD = 128, 32
+
+
+class Selection(NamedTuple):
+    """A (B, S_q, S_kv) mask as the kernels read it (``pack_selection``):
+    ``by_query`` (B, W_kv, S_q, 128) packs each query's row along the
+    keys, ``by_key`` (B, W_q, S_kv, 128) each key's column along the
+    queries; int32, W = ceil(S / 4096)."""
+    by_query: jax.Array
+    by_key: jax.Array
+
+
+def _pack_bits(mask):
+    """(B, R, C) bool -> (B, W, R, 128) int32: bit b of ``[m, r, j]`` is
+    ``mask[r, (32 m + b) 128 + j]``; columns past C read 0."""
+    b, r, c = mask.shape
+    w = -(-c // (_LANES * _WORD))
+    mask = jnp.pad(mask, ((0, 0), (0, 0), (0, w * _LANES * _WORD - c)))
+    bits = mask.reshape(b, r, w, _WORD, _LANES).astype(jnp.int32)
+    shifts = jnp.arange(_WORD, dtype=jnp.int32)[:, None]
+    # Disjoint bits: the sum is their OR (bit 31 wraps to the sign).
+    return jnp.swapaxes(jnp.sum(bits << shifts, axis=3), 1, 2)
+
+
+def pack_selection(mask) -> Selection:
+    """The two bit planes of ``mask`` (B, S_q, S_kv), True where the
+    query keeps the key. Named where they are made: a recomputation
+    that saves the name (models/transformer.py) holds the planes, and
+    neither the mask nor what chose it is made a second time."""
+    from horovod_tpu.jax.introspect import SAVED_FLASH_SELECT
+
+    return Selection(*(
+        checkpoint_name(_pack_bits(m), SAVED_FLASH_SELECT)
+        for m in (mask, jnp.swapaxes(mask, 1, 2))))
+
+
+def _learned(sel_ref, first, block):
+    """The (rows, block) mask tile whose columns start at lane group
+    ``first`` (a program value), from the plane block ``sel_ref`` (W,
+    rows, 128): ``block // 128`` pieces side by side."""
+    pieces = []
+    for i in range(block // _LANES):
+        # lax on the program value, as ``_scalar`` above: no jitted call.
+        n = jax.lax.add(jnp.int32(first), jnp.int32(i))
+        word = sel_ref[jax.lax.div(n, jnp.int32(_WORD))]
+        pieces.append((word >> jax.lax.rem(n, jnp.int32(_WORD))) & 1)
+    return jnp.concatenate(pieces, axis=1) != 0
+
+
 # --------------------------------------------------------------- forward ---
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, tiles, scale):
-    """Grid: (B, H, num_qb). q block vs streamed k/v blocks."""
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, tiles, scale):
+    """Grid: (B, H, num_qb). q block vs streamed k/v blocks. Under a
+    learned mask a fourth operand: the query block's rows of
+    ``Selection.by_query``."""
+    sel_ref = rest[0] if tiles.learned else None
+    o_ref, lse_ref = rest[-2:]
     block_q, block_k = tiles.block_q, tiles.block_k
     qi = pl.program_id(2)
     q_start = qi * block_q
@@ -310,6 +383,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, tiles, scale):
         v = v_ref[pl.ds(k_start, block_k), :].astype(jnp.float32)
         s = _dot(q, k, _NT)  # (block_q, block_k)
         mask = tiles.visible(1, q_start, k_start)
+        if sel_ref is not None:
+            mask = mask & _learned(sel_ref, kj * (block_k // _LANES),
+                                   block_k)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
@@ -334,7 +410,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, tiles, scale):
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, tiles, scale, group):
+                    *rest, tiles, scale, group):
     """Grid: (B, H, num_kb), or (B, H_kv, group, num_kb) where ``group``
     query heads share a key/value head. One k/v block vs streamed q
     blocks of ONE query head. The score tile is KEY-major, (block_k,
@@ -345,7 +421,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     Grouped: ``dk_ref`` / ``dv_ref`` are the key/value head's whole
     float32 panels, resident in VMEM while the grid walks the group's
-    query heads; the first head writes its block, the others add."""
+    query heads; the first head writes its block, the others add.
+
+    Under a learned mask a seventh operand: the key block's rows of
+    ``Selection.by_key``."""
+    sel_ref = rest[0] if tiles.learned else None
+    dk_ref, dv_ref = rest[-2:]
     block_q, block_k = tiles.block_q, tiles.block_k
     kj = pl.program_id(2 if group == 1 else 3)
     k_start = kj * block_k
@@ -361,6 +442,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[qi]
         p = jnp.exp(_dot(k, q, _NT) - lse)  # (block_k, block_q)
         mask = tiles.visible(0, q_start, k_start)
+        if sel_ref is not None:
+            mask = mask & _learned(sel_ref, qi * (block_q // _LANES),
+                                   block_q)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
         dv = dv + _dot(p, do, _NN)
@@ -393,10 +477,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, *, tiles, scale):
+                   *rest, tiles, scale):
     """Grid: (B, H, num_qb). One q block vs streamed k/v blocks;
     query-major, with the log-sum-exp and delta as (block_q, 1)
-    columns."""
+    columns. Under a learned mask a seventh operand, as the forward's
+    fourth."""
+    sel_ref = rest[0] if tiles.learned else None
+    dq_ref = rest[-1]
     block_q, block_k = tiles.block_q, tiles.block_k
     qi = pl.program_id(2)
     q_start = qi * block_q
@@ -411,6 +498,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         v = v_ref[pl.ds(k_start, block_k), :].astype(jnp.float32)
         p = jnp.exp(_dot(q, k, _NT) - lse)
         mask = tiles.visible(1, q_start, k_start)
+        if sel_ref is not None:
+            mask = mask & _learned(sel_ref, kj * (block_k // _LANES),
+                                   block_k)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
         ds = p * (_dot(do, v, _NT) - delta)
@@ -487,7 +577,20 @@ def _panel_spec(rows, d, group=1):
                         lambda bi, hi, i: (bi, hi // group, 0, 0))
 
 
-def _compiler_params(panel_rows, d, dtype, block_q, block_k, out_rows=0):
+def _select_spec(words, rows, index=lambda bi, hi, i: (bi, 0, i, 0)):
+    """``rows`` rows of a (B, W, S, 128) bit plane, all ``words`` of
+    them: the grid's last axis picks the rows, every head reads the
+    same."""
+    return pl.BlockSpec((None, words, rows, _LANES), index)
+
+
+def _pad_rows(plane, rows):
+    return jnp.pad(plane, ((0, 0), (0, 0), (0, rows - plane.shape[2]),
+                           (0, 0)))
+
+
+def _compiler_params(panel_rows, d, dtype, block_q, block_k, out_rows=0,
+                     select_rows=0):
     """Mosaic's scoped-VMEM limit for a kernel that keeps two (S, D)
     panels resident: the default (16 MiB on a v5e) wherever the kernel
     fits, because asking for more takes VMEM from XLA's own prefetches
@@ -495,9 +598,14 @@ def _compiler_params(panel_rows, d, dtype, block_q, block_k, out_rows=0):
     needs, once it does not. Each panel is double buffered and padded
     to 128 lanes; half a dozen float32 score tiles cover the loop
     body's temporaries and spills. ``out_rows``: the rows of the two
-    float32 output panels the grouped dK/dV keeps resident besides."""
+    float32 output panels the grouped dK/dV keeps resident besides.
+    ``select_rows``: the (rows, 128) int32 slabs of a learned mask's
+    plane block (words x rows), double buffered, and two more score
+    tiles for its pieces."""
     panels = 2 * 2 * panel_rows * max(d, 128) * jnp.dtype(dtype).itemsize
     panels += 2 * 2 * out_rows * max(d, 128) * 4
+    if select_rows:
+        panels += 2 * select_rows * _LANES * 4 + 2 * 4 * block_q * block_k
     need = panels + 6 * 4 * block_q * block_k + (2 << 20)
     if need <= (16 << 20):
         return None
@@ -506,8 +614,9 @@ def _compiler_params(panel_rows, d, dtype, block_q, block_k, out_rows=0):
 
 @_scoped
 def _flash_fwd_impl(q, k, v, causal, window, block_q, block_k, scale,
-                    interpret):
+                    interpret, select=None):
     from horovod_tpu.jax.introspect import (
+        KERNEL_DSA_FWD,
         KERNEL_FLASH_FWD,
         SAVED_FLASH_LSE,
         SAVED_FLASH_OUT,
@@ -520,31 +629,40 @@ def _flash_fwd_impl(q, k, v, causal, window, block_q, block_k, scale,
     kp = _pad_seq(k, block_k)
     vp = _pad_seq(v, block_k)
     sq_pad, sk_pad = qp.shape[2], kp.shape[2]
-    tiles = _Tiles(block_q, block_k, causal, s, kv_len, window)
-    _count_tiles(KERNEL_FLASH_FWD, tiles, q.shape, q.dtype, group)
+    tiles = _Tiles(block_q, block_k, causal, s, kv_len, window,
+                   select is not None)
+    name = KERNEL_DSA_FWD if tiles.learned else KERNEL_FLASH_FWD
+    _count_tiles(name, tiles, q.shape, q.dtype, group)
+    in_specs = [_plane_spec(block_q, d), _panel_spec(sk_pad, d, group),
+                _panel_spec(sk_pad, d, group)]
+    operands, words = (qp, kp, vp), 0
+    if tiles.learned:
+        words = select.by_query.shape[1]
+        in_specs.append(_select_spec(words, block_q))
+        operands += (_pad_rows(select.by_query, sq_pad),)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, tiles=tiles, scale=scale),
         grid=(b, h, tiles.num_qb),
-        in_specs=[_plane_spec(block_q, d), _panel_spec(sk_pad, d, group),
-                  _panel_spec(sk_pad, d, group)],
+        in_specs=in_specs,
         out_specs=[_plane_spec(block_q, d), _plane_spec(block_q, 1)],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq_pad, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(sk_pad, d, q.dtype, block_q,
-                                         block_k),
+        compiler_params=_compiler_params(
+            sk_pad, d, q.dtype, block_q, block_k,
+            select_rows=words * block_q),
         interpret=_should_interpret(interpret),
-        name=KERNEL_FLASH_FWD,
-    )(qp, kp, vp)
+        name=name,
+    )(*operands)
     # Named, and ONE value as the output and as the residual: a
     # recomputation that saves these names (models/transformer.py) has
     # no reader left for a second run of the kernel. Outside one the
     # name is an identity that lowers to nothing.
     out = checkpoint_name(out[:, :, :s], SAVED_FLASH_OUT)
     lse = checkpoint_name(lse[:, :, :s, 0], SAVED_FLASH_LSE)
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, out, lse, select)
 
 
 def _flash_fwd(q, k, v, causal, window, block_q, block_k, scale, interpret):
@@ -552,31 +670,39 @@ def _flash_fwd(q, k, v, causal, window, block_q, block_k, scale, interpret):
                            interpret)
 
 
-def _dkv_call(tiles, group, b, h, d, sq_pad, sk_pad, dtype, scale, interp):
+def _dkv_call(tiles, group, b, h, d, sq_pad, sk_pad, dtype, scale, interp,
+              words=0):
     """The dK/dV ``pallas_call``. One query head a key/value head: a
     (B, H, num_kb) grid, each step writes its own (block_k, D) block.
     Grouped: a (B, H_kv, group, num_kb) grid; the key/value head's
     float32 dK/dV panels are the output blocks of all ``group x
     num_kb`` steps, so they stay in VMEM while every query head of the
-    group adds its part and go to HBM once, ``H_kv`` heads wide."""
-    from horovod_tpu.jax.introspect import KERNEL_FLASH_DKV
+    group adds its part and go to HBM once, ``H_kv`` heads wide.
+    ``words``: of a learned mask's ``by_key`` plane, the seventh
+    operand."""
+    from horovod_tpu.jax.introspect import KERNEL_DSA_DKV, KERNEL_FLASH_DKV
 
+    name = KERNEL_DSA_DKV if tiles.learned else KERNEL_FLASH_DKV
+    learnt = dict(select_rows=words * tiles.block_k)
     block_q, block_k = tiles.block_q, tiles.block_k
     kernel = functools.partial(_bwd_dkv_kernel, tiles=tiles, scale=scale,
                                group=group)
     rows = (None, None, tiles.num_qb, 1, block_q)
     if group == 1:
         rows_panel = pl.BlockSpec(rows, lambda bi, hi, kj: (bi, hi, 0, 0, 0))
+        in_specs = [_panel_spec(sq_pad, d), _plane_spec(block_k, d),
+                    _plane_spec(block_k, d), _panel_spec(sq_pad, d),
+                    rows_panel, rows_panel]
+        if words:
+            in_specs.append(_select_spec(words, block_k))
         return pl.pallas_call(
             kernel, grid=(b, h, tiles.num_kb),
-            in_specs=[_panel_spec(sq_pad, d), _plane_spec(block_k, d),
-                      _plane_spec(block_k, d), _panel_spec(sq_pad, d),
-                      rows_panel, rows_panel],
+            in_specs=in_specs,
             out_specs=[_plane_spec(block_k, d), _plane_spec(block_k, d)],
             out_shape=[jax.ShapeDtypeStruct((b, h, sk_pad, d), dtype)] * 2,
             compiler_params=_compiler_params(sq_pad, d, dtype, block_q,
-                                             block_k),
-            interpret=interp, name=KERNEL_FLASH_DKV)
+                                             block_k, **learnt),
+            interpret=interp, name=name)
 
     def of_query_head(*block):
         return lambda bi, hk, gi, kj: (bi, hk * group + gi) + block
@@ -587,22 +713,31 @@ def _dkv_call(tiles, group, b, h, d, sq_pad, sk_pad, dtype, scale, interp):
                             lambda bi, hk, gi, kj: (bi, hk, kj, 0))
     kv_panel = pl.BlockSpec((None, None, sk_pad, d),
                             lambda bi, hk, gi, kj: (bi, hk, 0, 0))
-    params = _compiler_params(sq_pad, d, dtype, block_q, block_k, sk_pad)
+    params = _compiler_params(sq_pad, d, dtype, block_q, block_k, sk_pad,
+                              **learnt)
+    in_specs = [q_panel, kv_block, kv_block, q_panel, rows_panel, rows_panel]
+    if words:
+        in_specs.append(_select_spec(
+            words, block_k, lambda bi, hk, gi, kj: (bi, 0, kj, 0)))
     return pl.pallas_call(
         kernel, grid=(b, h // group, group, tiles.num_kb),
-        in_specs=[q_panel, kv_block, kv_block, q_panel, rows_panel,
-                  rows_panel],
+        in_specs=in_specs,
         out_specs=[kv_panel, kv_panel],
         out_shape=[jax.ShapeDtypeStruct((b, h // group, sk_pad, d),
                                         jnp.float32)] * 2,
-        compiler_params=params, interpret=interp, name=KERNEL_FLASH_DKV)
+        compiler_params=params, interpret=interp, name=name)
 
 
 @_scoped
 def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
-    from horovod_tpu.jax.introspect import KERNEL_FLASH_DKV, KERNEL_FLASH_DQ
+    from horovod_tpu.jax.introspect import (
+        KERNEL_DSA_DKV,
+        KERNEL_DSA_DQ,
+        KERNEL_FLASH_DKV,
+        KERNEL_FLASH_DQ,
+    )
 
-    q, k, v, out, lse = res
+    q, k, v, out, lse, select = res
     b, h, s, d = q.shape
     kv_len, group = k.shape[2], h // k.shape[1]
     do = g.astype(jnp.float32)
@@ -613,7 +748,8 @@ def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
     vp = _pad_seq(v, block_k)
     dop = _pad_seq(g.astype(q.dtype), block_q)
     sq_pad, sk_pad = qp.shape[2], kp.shape[2]
-    tiles = _Tiles(block_q, block_k, causal, s, kv_len, window)
+    tiles = _Tiles(block_q, block_k, causal, s, kv_len, window,
+                   select is not None)
     # Padded query rows: lse=0, delta=0 → p = exp(-0)=1 rows would pollute
     # dk/dv; guard with lse=+inf so exp(s - lse) = 0.
     pad_q = ((0, 0), (0, 0), (0, sq_pad - s))
@@ -630,24 +766,39 @@ def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
     lse_col, delta_col = lsep[..., None], deltap[..., None]
 
     interp = _should_interpret(interpret)
-    _count_tiles(KERNEL_FLASH_DKV, tiles, q.shape, q.dtype, group)
+    dkv_name, dq_name = (KERNEL_DSA_DKV, KERNEL_DSA_DQ) if tiles.learned \
+        else (KERNEL_FLASH_DKV, KERNEL_FLASH_DQ)
+    dkv_operands = (qp, kp, vp, dop, lse_rows, delta_rows)
+    dq_operands = (qp, kp, vp, dop, lse_col, delta_col)
+    dq_specs = [_plane_spec(block_q, d), _panel_spec(sk_pad, d, group),
+                _panel_spec(sk_pad, d, group), _plane_spec(block_q, d),
+                _plane_spec(block_q, 1), _plane_spec(block_q, 1)]
+    key_words = query_words = 0
+    if tiles.learned:
+        # dK/dV reads the plane packed along the queries, dQ the one
+        # packed along the keys.
+        key_words = select.by_key.shape[1]
+        query_words = select.by_query.shape[1]
+        dkv_operands += (_pad_rows(select.by_key, sk_pad),)
+        dq_operands += (_pad_rows(select.by_query, sq_pad),)
+        dq_specs.append(_select_spec(query_words, block_q))
+    _count_tiles(dkv_name, tiles, q.shape, q.dtype, group)
     dk, dv = _dkv_call(tiles, group, b, h, d, sq_pad, sk_pad, q.dtype, scale,
-                       interp)(qp, kp, vp, dop, lse_rows, delta_rows)
+                       interp, key_words)(*dkv_operands)
 
-    _count_tiles(KERNEL_FLASH_DQ, tiles, q.shape, q.dtype, group)
+    _count_tiles(dq_name, tiles, q.shape, q.dtype, group)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, tiles=tiles, scale=scale),
         grid=(b, h, tiles.num_qb),
-        in_specs=[_plane_spec(block_q, d), _panel_spec(sk_pad, d, group),
-                  _panel_spec(sk_pad, d, group), _plane_spec(block_q, d),
-                  _plane_spec(block_q, 1), _plane_spec(block_q, 1)],
+        in_specs=dq_specs,
         out_specs=_plane_spec(block_q, d),
         out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
-        compiler_params=_compiler_params(sk_pad, d, q.dtype, block_q,
-                                         block_k),
+        compiler_params=_compiler_params(
+            sk_pad, d, q.dtype, block_q, block_k,
+            select_rows=query_words * block_q),
         interpret=interp,
-        name=KERNEL_FLASH_DQ,
-    )(qp, kp, vp, dop, lse_col, delta_col)
+        name=dq_name,
+    )(*dq_operands)
 
     if group > 1:   # the group's float32 sums, rounded once
         dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
@@ -655,6 +806,28 @@ def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_select(q, k, v, select, block_q, block_k, scale, interpret):
+    """``_flash`` under a learned mask, causal, no window. The planes are
+    data, not static facts, so they are an argument of the rule; they
+    carry no gradient."""
+    return _flash_select_fwd(q, k, v, select, block_q, block_k, scale,
+                             interpret)[0]
+
+
+def _flash_select_fwd(q, k, v, select, block_q, block_k, scale, interpret):
+    return _flash_fwd_impl(q, k, v, True, None, block_q, block_k, scale,
+                           interpret, select)
+
+
+def _flash_select_bwd(block_q, block_k, scale, interpret, res, g):
+    return _flash_bwd(True, None, block_q, block_k, scale, interpret, res,
+                      g) + (None,)
+
+
+_flash_select.defvjp(_flash_select_fwd, _flash_select_bwd)
 
 
 def _default_blocks(q_len, kv_len):
@@ -681,6 +854,7 @@ def _default_blocks(q_len, kv_len):
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
+                    select: Optional[Selection] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     scale: Optional[float] = None,
@@ -696,6 +870,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
       causal: apply a causal (lower-triangular) mask.
       window: with ``causal``, a query at position p sees the keys j
         with ``p - window < j <= p`` (None: all of ``j <= p``).
+      select: with ``causal`` and no window, the keys each query keeps
+        of those at or before it, as ``pack_selection`` packs a (B,
+        S_q, S_kv) mask: forward, dK/dV and dQ all honour it (no
+        gradient reaches it). The tiles are then whole 128-lane groups.
       block_q / block_k: VMEM tile sizes (clamped to the sequence and
         rounded to the dtype's sublane multiple; the sequence is padded
         to a multiple). The default comes from the sequence lengths
@@ -721,6 +899,18 @@ def flash_attention(q, k, v, *, causal: bool = True,
     if window is not None and (not causal or window < 1):
         raise ValueError("a window (%r) needs causal=True and at least "
                          "one key" % (window,))
+    if select is not None:
+        planes = tuple(
+            (q.shape[0], -(-cols // (_LANES * _WORD)), rows, _LANES)
+            for rows, cols in ((q.shape[1], k.shape[1]),
+                               (k.shape[1], q.shape[1])))
+        if not causal or window is not None or planes != (
+                select.by_query.shape, select.by_key.shape):
+            raise ValueError(
+                "select needs causal=True, no window (%r) and planes %r "
+                "(pack_selection of a (B, S_q, S_kv) mask), not %r"
+                % (window, planes, (select.by_query.shape,
+                                    select.by_key.shape)))
     d = q.shape[-1]
     if scale is None:
         scale = float(d) ** -0.5
@@ -770,6 +960,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     vt = checkpoint_name(jnp.swapaxes(v, 1, 2), SAVED_FLASH_V)
     block_q = _pick_block(max(qt.shape[2], 1), block_q, q.dtype)
     block_k = _pick_block(max(kt.shape[2], 1), block_k, k.dtype)
+    if select is not None:
+        # A bit of the planes is a lane group of the other axis.
+        block_q, block_k = (-(-n // _LANES) * _LANES
+                            for n in (block_q, block_k))
+        out = _flash_select(qt, kt, vt, select, block_q, block_k, scale,
+                            interpret)
+        return jnp.swapaxes(out, 1, 2)
     out = _flash(qt, kt, vt, causal, window, block_q, block_k, scale,
                  interpret)
     return jnp.swapaxes(out, 1, 2)

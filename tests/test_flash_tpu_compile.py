@@ -127,6 +127,56 @@ def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, seq, n_kv,
         line for line in text.splitlines() if " broadcast(" in line)
 
 
+def test_masked_kernels_lower_for_v5e(one_chip):
+    """keye-s8192-dsa-ep8-c1's attention: the three kernels under a mask
+    that is DATA, 1 x 8192 x 32 query heads over 4 key/value heads of
+    128, beside Trinity's case above. Three Mosaic calls under their own
+    names; the selection enters as the FOURTH operand of the forward and
+    the SEVENTH of dK/dV and dQ, an int32 bit plane of S x S / 8 bytes;
+    K and V stay 4 heads wide; and ``benchmark/trace_reduce.py``, which
+    takes a Mosaic call of 3 or 6 operands for a static flash kernel,
+    takes none of these for one."""
+    from benchmark import trace_reduce as tr
+    from horovod_tpu.ops.pallas_attention import Selection
+
+    seq = 8192
+    q = jax.ShapeDtypeStruct((1, seq, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, seq, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    plane = jax.ShapeDtypeStruct((1, 2, seq, 128), jnp.int32,
+                                 sharding=one_chip)
+
+    def step(q, k, v, g, by_query, by_key):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, select=Selection(by_query, by_key),
+            interpret=False), q, k, v)
+        return (out,) + vjp(g)
+
+    text = jax.jit(step).lower(q, kv, kv, q, plane, plane).compile().as_text()
+    shape_of = dict(re.findall(r"%([\w.\-]+) = (\S+\[[\d,]*\])", text))
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    operands_of = {}
+    for line in calls:
+        name = line.split(" = ")[0].lstrip("ROOT ").lstrip("%")
+        operands = re.findall(
+            r"%([\w.\-]+)", line.split(" custom-call(")[1].split("), ")[0])
+        shapes = [shape_of[o] for o in operands]
+        operands_of[name.split(".")[0]] = len(shapes)
+        assert shapes[0] == "bf16[1,32,%d,128]" % seq, (name, shapes)
+        assert shapes[1] == shapes[2] == "bf16[1,4,%d,128]" % seq
+        assert shapes[-1] == "s32[1,2,%d,128]" % seq, (name, shapes)
+        assert tr.is_mosaic_call(line) and tr.flash_kernel(line) == "", name
+    assert operands_of == {introspect.KERNEL_DSA_FWD: 4,
+                           introspect.KERNEL_DSA_DKV: 7,
+                           introspect.KERNEL_DSA_DQ: 7}
+    for static in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
+                   introspect.KERNEL_FLASH_DQ):
+        assert "%" + static not in text
+
+
 _OPCODE_RE = re.compile(r"^(?:ROOT )?%?[\w.\-]+ = .*?\s([a-z][\w\-]*)\(")
 
 

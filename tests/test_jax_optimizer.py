@@ -68,6 +68,47 @@ def test_distributed_optimizer_matches_mean_gradient(mesh8):
             rtol=1e-5, atol=1e-6)
 
 
+def test_a_leaf_whose_gradient_is_zero_goes_through_like_any_other(mesh8):
+    """A parameter the loss does not move (a sparse-attention indexer
+    under a language-model loss): its exactly-zero gradient is reduced
+    and counted with the others, AdamW keeps moments for it (zeros) and
+    its update is the weight decay alone."""
+    from horovod_tpu.utils import metrics
+
+    def in_place():
+        fam = metrics.REGISTRY.snapshot().get("hvd_grad_leaves_total", {})
+        return sum(v["value"] for v in fam.get("values", [])
+                   if v["labels"]["route"] == "in_place")
+
+    params = {"w": jnp.ones((4, 2), jnp.float32),
+              "dead": jnp.full((3,), 2.0, jnp.float32)}
+    x = jax.random.normal(jax.random.PRNGKey(1), (16, 4), jnp.float32)
+    tx = hvd_jax.DistributedOptimizer(
+        optax.adamw(1e-2, b1=0.9, b2=0.95, weight_decay=0.1))
+
+    def step(params, opt_state, batch):
+        grads = jax.grad(lambda p, b: jnp.mean(jnp.square(
+            b @ p["w"] * (jax.lax.stop_gradient(p["dead"]).sum() > 0))))(
+                params, batch)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return grads, updates, opt_state
+
+    before = in_place()
+    grads, updates, opt_state = jax.jit(shard_map(
+        step, mesh=mesh8, in_specs=(P(), P(), P("data")),
+        out_specs=(P(), P(), P()), check_vma=False))(
+            params, tx.init(params), x)
+    assert in_place() - before == 2          # both leaves, the dead one too
+    assert float(jnp.abs(grads["dead"]).max()) == 0.0
+    assert float(jnp.abs(grads["w"]).max()) > 0.0
+    np.testing.assert_allclose(np.asarray(updates["dead"]),
+                               -1e-2 * 0.1 * 2.0 * np.ones(3), rtol=1e-6)
+    mu = optax.tree_utils.tree_get(opt_state, "mu")
+    nu = optax.tree_utils.tree_get(opt_state, "nu")
+    assert mu["dead"].shape == (3,) and not np.asarray(mu["dead"]).any()
+    assert not np.asarray(nu["dead"]).any() and np.asarray(nu["w"]).any()
+
+
 def test_distributed_optimizer_compression(mesh8):
     params = {"w": jnp.ones((8, 8), jnp.float32)}
     grads = {"w": jnp.full((8, 8), 0.123456789, jnp.float32)}

@@ -431,3 +431,83 @@ def test_a_recomputed_block_keeps_the_held_expert_layers_output(
     assert len([line for line in text.splitlines()
                 if " conditional(" in line
                 and introspect.KERNEL_MOE_GATHER_SUM not in line]) == choices
+
+
+# Keye-VL-2.0's at tiny widths: every layer full attention over a
+# learned selection (an indexer of 4 heads of 12 that keeps 8 keys of 31),
+# 4 query heads over 2 key/value heads, a norm per head, softmax-routed
+# held experts with no shared one.
+SPARSE_HELD_EXPERTS = BlockSpec(
+    norm="rmsnorm", ffn="swiglu", positions="rope", tied_head=False,
+    head_dim=16, n_kv_heads=2, qk_norm_per_head=True, index_heads=4,
+    index_head_dim=12, index_topk=8, num_experts=8, experts_per_token=2,
+    norm_topk=True, experts_held=2)
+MASKED_KERNELS = (introspect.KERNEL_DSA_FWD, introspect.KERNEL_DSA_DKV,
+                  introspect.KERNEL_DSA_DQ)
+
+
+def _loops_inside(jaxpr, inside=False):
+    """The ``while`` and ``scan`` equations inside a ``checkpoint``
+    equation of ``jaxpr``, a kernel's own left out."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if inside and eqn.primitive.name in ("while", "scan"):
+            yield eqn.primitive.name
+        within = inside or eqn.primitive.name == "remat2"
+        for value in eqn.params.values():
+            for cand in value if isinstance(value, (list, tuple)) else (
+                    value,):
+                inner = getattr(cand, "jaxpr", cand)
+                if hasattr(inner, "eqns"):
+                    yield from _loops_inside(inner, within)
+
+
+@pytest.mark.parametrize("keeps_the_planes", [True, False])
+def test_a_recomputed_sparse_block_neither_scores_nor_selects(
+        keeps_the_planes, monkeypatch):
+    """A block whose queries choose their keys: the two bit planes of
+    the choice carry ``SAVED_FLASH_SELECT`` and all three masked kernels
+    read them, so the recomputed forward holds no indexer matmul (its
+    three projections, its dot products), no loop (the passes over
+    blocks of queries, the bisection) and no ``hvd_dsa_select`` work;
+    what it multiplies again is each router's logits and q and k before
+    their norms. Without the name on the list the indexer runs twice a
+    layer. Each masked kernel is traced once a layer either way, the
+    static ones never."""
+    if not keeps_the_planes:
+        monkeypatch.setattr(
+            transformer_module, "_REMAT_KEEPS", tuple(
+                name for name in transformer_module._REMAT_KEEPS
+                if name != introspect.SAVED_FLASH_SELECT))
+    block = SPARSE_HELD_EXPERTS
+    variables = _variables(block)
+    gradient = jax.make_jaxpr(jax.grad(_loss(
+        _model(True, "flash", block), variables)))(variables["params"])
+    text = str(gradient)
+    assert {name: len(re.findall(r"name=%s\b" % name, text))
+            for name in MASKED_KERNELS + KERNELS} == dict(
+        dict.fromkeys(MASKED_KERNELS, LAYERS), **dict.fromkeys(KERNELS, 0))
+    by_weight = collections.Counter(
+        shapes[1] for shapes, _ in _matmuls(gradient.jaxpr,
+                                            recomputation=True))
+    indexer = {"index_wq": (64, 4, 12), "index_wk": (64, 12),
+               "index_ww": (64, 4), "dots": (2, 31, 12)}
+    again = {name: by_weight[shape] for name, shape in indexer.items()}
+    loops = list(_loops_inside(gradient.jaxpr))
+    lowered = jax.jit(jax.grad(_loss(
+        _model(True, "flash", block), variables))).lower(
+        variables["params"]).as_text(debug_info=True)
+    redone = re.findall(
+        r"rematted_computation/layer_\d/attn/[^\"]*hvd_dsa_(?:select|index)",
+        lowered)
+    if keeps_the_planes:
+        assert again == dict.fromkeys(indexer, 0), by_weight
+        assert not loops and not redone
+        # The routers, and q and k under their norms, as a Trinity block.
+        assert by_weight[(64, 8)] >= LAYERS
+        assert by_weight[(64, 4, 16)] >= LAYERS
+    else:
+        assert again == dict.fromkeys(indexer, LAYERS), by_weight
+        assert loops and redone
+    assert introspect.SCOPE_DSA_SELECT in lowered

@@ -86,6 +86,10 @@ KERNEL_FLASH_DQ = "hvd_flash_dq"
 KERNEL_DSA_FWD = "hvd_dsa_fwd"
 KERNEL_DSA_DKV = "hvd_dsa_dkv"
 KERNEL_DSA_DQ = "hvd_dsa_dq"
+# ``name=`` of the call that CHOOSES the keys (ops/pallas_selection.py;
+# under ``hvd_dsa_select``): scores, each query's ``index_topk``-th
+# largest and both bit planes, a block of queries a pass. FOUR operands.
+KERNEL_DSA_CHOOSE = "hvd_dsa_choose"
 # ``name=`` of the expert layer's sum over a token's sorted rows
 # (ops/pallas_gather_sum.py; under ``hvd_moe_combine`` forward and
 # ``hvd_moe_dispatch`` backward, where ``_sum_per_token`` stands).

@@ -113,6 +113,16 @@ _M_DSA_PAIRS = _metrics.counter(
     "time, not per device step).",
     ("kind",))
 
+# Counted at trace time: the sparse layers of one traced model, by what
+# made their choice of keys.
+_M_DSA_SELECTIONS = _metrics.counter(
+    "hvd_dsa_selections_total",
+    "Traced sparse_attention layers by what chose their keys: kernel (one "
+    "Pallas call, ops/pallas_selection.py), plain (learned_selection and "
+    "pack_selection in XLA) or forced (the caller's mask; counted at trace "
+    "time, not per device step).",
+    ("via",))
+
 # Counted at trace time: the blocks traced under ``cfg.remat``, by what
 # the recomputation keeps from forward to backward.
 _M_REMAT_BLOCKS = _metrics.counter(
@@ -308,18 +318,21 @@ def _dense_causal_attention(q, k, v, dtype, window=None, select=None):
 def _attend(cfg, q, k, v, window=None, select=None):
     """Causal attention of q (B, S, H, D) over k, v (B, S, H_kv, D) by
     ``cfg.attention``; under ``window`` a query sees that many keys up
-    to itself, under ``select`` (B, S, S) those it keeps."""
+    to itself, under ``select`` those it keeps: (B, S, S) bool, or for
+    'flash' the planes already packed."""
     if cfg.attention == "dense":
         return _dense_causal_attention(q, k, v, cfg.dtype, window, select)
     if cfg.attention == "flash":
         from horovod_tpu.ops.pallas_attention import (
+            Selection,
             flash_attention,
             pack_selection,
         )
 
         if select is not None:
-            with jax.named_scope(SCOPE_DSA_SELECT):
-                select = pack_selection(select)
+            if not isinstance(select, Selection):
+                with jax.named_scope(SCOPE_DSA_SELECT):
+                    select = pack_selection(select)
             return flash_attention(q, k, v, causal=True,
                                    select=select).astype(cfg.dtype)
         return flash_attention(q, k, v, causal=True,
@@ -434,10 +447,15 @@ class SelfAttention(nn.Module):
         ``q_i = x W_q`` (``index_heads`` of ``index_head_dim``), ``k_i =
         LayerNorm(x W_k)`` (one head), ``w = x W_w / sqrt(heads x dim)``,
         rotary positions on all of q_i and k_i, then
-        ``learned_selection``. Nothing here carries a gradient: the
-        choice is piecewise constant in the four leaves, which stay in
-        the tree and receive zeros (DeepSeek sparse attention trains
-        them by a term of their own, which this model has not)."""
+        ``learned_selection``: the (B, S, S) mask. Where the flash
+        kernels will read it and the queries divide into passes of
+        ``_INDEX_CHUNK``, the same choice by ONE Pallas call a pass of
+        queries (``ops/pallas_selection.py``), which returns the two
+        bit planes and makes no (S, S) array. Nothing here carries a
+        gradient: the choice is piecewise constant in the four leaves,
+        which stay in the tree and receive zeros (DeepSeek sparse
+        attention trains them by a term of their own, which this model
+        has not)."""
         cfg, spec = self.cfg, self.cfg.block
         j, d, topk = spec.index_heads, spec.index_head_dim, spec.index_topk
         if min(j, d, topk) < 1:
@@ -463,6 +481,22 @@ class SelfAttention(nn.Module):
                 jnp.float32) * float(j * d) ** -0.5
             q_i = rope(q_i, 0, spec.rope_theta)
             k_i = rope(k_i[:, :, None, :], 0, spec.rope_theta)[:, :, 0]
+        if cfg.attention == "flash" and s % _INDEX_CHUNK == 0:
+            # Imported where a sparse layer is first built, not with the
+            # package: a ``pallas`` import costs every launch.
+            from horovod_tpu.ops.pallas_attention import unpack_selection
+            from horovod_tpu.ops.pallas_selection import choose
+
+            _M_DSA_SELECTIONS.labels(via="kernel").inc()
+            with jax.named_scope(SCOPE_DSA_SELECT):
+                select = choose(q_i, k_i, w_i, topk, _INDEX_CHUNK)
+                self.sow("dsa", "dsa_kept", jnp.sum(
+                    jax.lax.population_count(select.by_query),
+                    dtype=jnp.int32))
+            if self.is_mutable_collection("dsa_mask"):
+                self.sow("dsa_mask", "select", unpack_selection(select, s))
+            return select
+        _M_DSA_SELECTIONS.labels(via="plain").inc()
         select = jax.lax.stop_gradient(learned_selection(q_i, k_i, w_i, topk))
         with jax.named_scope(SCOPE_DSA_SELECT):
             # The pairs this step's mask keeps: the count above plus ties.
@@ -527,6 +561,8 @@ class SelfAttention(nn.Module):
             select = selection
             if select is None:
                 select = self._selection(x, weight)
+            else:
+                _M_DSA_SELECTIONS.labels(via="forced").inc()
         out = _attend(cfg, q, k, v, self.window, select)
         if spec.attn_gate:
             gate = checkpoint_name(

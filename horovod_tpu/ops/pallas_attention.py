@@ -349,6 +349,15 @@ def pack_selection(mask) -> Selection:
         for m in (mask, jnp.swapaxes(mask, 1, 2))))
 
 
+def unpack_selection(select: Selection, s_kv):
+    """``pack_selection``'s mask back from its ``by_query`` plane: (B,
+    S_q, ``s_kv``) bool."""
+    b, _, r, _ = select.by_query.shape
+    shifts = jnp.arange(_WORD, dtype=jnp.int32)[:, None]
+    bits = (select.by_query[:, :, :, None, :] >> shifts) & 1
+    return jnp.swapaxes(bits, 1, 2).reshape(b, r, -1)[..., :s_kv] != 0
+
+
 def _learned(sel_ref, first, block):
     """The (rows, block) mask tile whose columns start at lane group
     ``first`` (a program value), from the plane block ``sel_ref`` (W,

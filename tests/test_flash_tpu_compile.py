@@ -177,6 +177,53 @@ def test_masked_kernels_lower_for_v5e(one_chip):
         assert "%" + static not in text
 
 
+def test_the_selection_kernel_lowers_for_v5e(one_chip, monkeypatch):
+    """keye-s8192-dsa-ep8-c1's choice of keys (``ops/pallas_selection``):
+    1 x 8192 queries of 16 index heads of 64 over one key head, top
+    2048, blocks of 512. ONE Mosaic call under its name, of FOUR
+    operands (so ``trace_reduce.flash_kernel`` takes it for no flash
+    kernel), that returns the two int32 bit planes; around it no loop
+    and no array of S x S elements of any type: scores, mask and its
+    transpose live in the call's VMEM, 16 MB of scratch and the
+    resident plane, which the call asks Mosaic for."""
+    from benchmark import trace_reduce as tr
+    from horovod_tpu.ops import pallas_selection
+
+    seq, heads, dim, topk = 8192, 16, 64, 2048
+    q_i = jax.ShapeDtypeStruct((1, seq, heads, dim), jnp.bfloat16,
+                               sharding=one_chip)
+    k_i = jax.ShapeDtypeStruct((1, seq, dim), jnp.bfloat16,
+                               sharding=one_chip)
+    w_i = jax.ShapeDtypeStruct((1, seq, heads), jnp.float32,
+                               sharding=one_chip)
+
+    def step(q_i, k_i, w_i):
+        planes = pallas_selection.choose(q_i, k_i, w_i, topk, 512)
+        return planes.by_query, planes.by_key
+
+    # The default backend here is the CPU: see the cells' test below.
+    monkeypatch.setattr(pallas_attention, "_should_interpret",
+                        lambda interpret: False)
+    text = jax.jit(step).lower(q_i, k_i, w_i).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines() if tr.is_mosaic_call(line)]
+    assert len(calls) == 1
+    (line,) = calls
+    assert line.split(" = ")[0].lstrip("%").split(".")[0] \
+        == introspect.KERNEL_DSA_CHOOSE == "hvd_dsa_choose"
+    operands = re.findall(
+        r"%([\w.\-]+)", line.split(" custom-call(")[1].split("), ")[0])
+    assert len(operands) == 4 and tr.flash_kernel(line) == ""
+    plane = "s32[1,2,%d,128]" % seq
+    assert line.split(" = ")[1].startswith("(%s" % plane)
+    assert line.count(plane) == 2
+    limit = int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                          line).group(1))
+    assert (16 << 20) + 2 * (8 << 20) < limit <= (100 << 20)
+    assert not re.search(r"= \S+ while\(", text)
+    assert not re.search(r"\[(?:\d+,)*%d,%d\]" % (seq, seq), text)
+
+
 _OPCODE_RE = re.compile(r"^(?:ROOT )?%?[\w.\-]+ = .*?\s([a-z][\w\-]*)\(")
 
 

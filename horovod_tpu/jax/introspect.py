@@ -94,6 +94,13 @@ KERNEL_DSA_CHOOSE = "hvd_dsa_choose"
 # (ops/pallas_gather_sum.py; under ``hvd_moe_combine`` forward and
 # ``hvd_moe_dispatch`` backward, where ``_sum_per_token`` stands).
 KERNEL_MOE_GATHER_SUM = "hvd_moe_gather_sum"
+# ``name=`` of the expert layer's grouped matmuls
+# (ops/pallas_grouped_matmul.py; under ``hvd_moe_experts``, where
+# ``grouped_ffn`` stands): a group's rows times its expert's panel,
+# forward and (the panel read transposed) the input gradient; and the
+# weight gradient's. FIVE operands each.
+KERNEL_MOE_GROUPED = "hvd_moe_gmm"
+KERNEL_MOE_GROUPED_DW = "hvd_moe_gmm_dw"
 # ``checkpoint_name``s of what the forward kernel made, as the backward
 # kernels read it: the (B, H, S, D) output and the (B, H, S) float32
 # log-sum-exp; and of what it READ, its (B, H, S, D) / (B, H_kv, S, D)

@@ -15,7 +15,11 @@
   only as an operand or a cotangent of a grouped matmul: nothing is
   broadcast, no array of a token's k pairs is made to be summed, and
   backward a gather's transpose is that kernel and the kernel's a
-  gather from the (T, M) array, never a scatter-add. No ``(T, E, C)``
+  gather from the (T, M) array, never a scatter-add. What multiplies a
+  group's rows by its expert's panel is a Pallas kernel of ours
+  (``ops/pallas_grouped_matmul.py``: row tiles walked group by group,
+  the live ones only, named where they are called) wherever its tiles
+  divide the shape, and ``jax.lax.ragged_dot`` elsewhere. No ``(T, E, C)``
   tensor and no capacity: an expert takes whatever the router sends it.
   GPT-2's block gets GELU experts and one expert a token, where the
   sum over k is the identity; OLMoE's SwiGLU experts and 8 of 64
@@ -66,7 +70,7 @@ from horovod_tpu.jax.introspect import (
     SCOPE_MOE_ROWS,
     SCOPE_MOE_SHARED,
 )
-from horovod_tpu.ops import pallas_gather_sum
+from horovod_tpu.ops import pallas_gather_sum, pallas_grouped_matmul
 from horovod_tpu.parallel.mesh import DATA_AXIS, EXPERT_AXIS
 from horovod_tpu.parallel.mesh import traced_axis_size
 from horovod_tpu.utils import metrics as _metrics
@@ -342,6 +346,19 @@ def sorted_by_expert(experts, first=0, num_experts=None):
     return order, jnp.argsort(order).astype(jnp.int32)
 
 
+def _grouped_matmul(lhs, rhs, group_sizes):
+    """Each group's rows of ``lhs`` (N, K) times its panel of ``rhs``
+    (E, K, F), the rows past the last group left unwritten: the kernels
+    of ops/pallas_grouped_matmul.py where their tiles divide the shape
+    (every cell of the benchmark), ``lax.ragged_dot`` where they do not
+    (odd widths, a few rows: the definition they are tested against)."""
+    if pallas_grouped_matmul.divides(lhs.shape, rhs.shape):
+        return pallas_grouped_matmul.grouped_matmul(lhs, rhs, group_sizes)
+    pallas_grouped_matmul.M_GROUPED_MATMULS.labels(kind="forward",
+                                                   via="xla").inc()
+    return lax.ragged_dot(lhs, rhs, group_sizes)
+
+
 def grouped_ffn(rows, row_gates, group_sizes, wi, wo, wg=None, live=None):
     """Each expert's feed-forward over its own rows, times the row's
     gate: ``rows`` (N, M) sorted by expert, ``row_gates`` (N,) float32,
@@ -364,14 +381,14 @@ def grouped_ffn(rows, row_gates, group_sizes, wi, wo, wg=None, live=None):
         row = lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
         return jnp.where(row < live, x, 0)
 
-    up = live_rows(lax.ragged_dot(rows, wi, group_sizes)).astype(jnp.float32)
+    up = live_rows(_grouped_matmul(rows, wi, group_sizes)).astype(jnp.float32)
     if wg is None:
         hidden = nn.gelu(up)
     else:
-        hidden = nn.silu(live_rows(lax.ragged_dot(rows, wg, group_sizes))
+        hidden = nn.silu(live_rows(_grouped_matmul(rows, wg, group_sizes))
                          .astype(jnp.float32)) * up
     hidden = live_rows((hidden * row_gates[:, None]).astype(rows.dtype))
-    return lax.ragged_dot(hidden, wo, group_sizes)
+    return _grouped_matmul(hidden, wo, group_sizes)
 
 
 # The grouped matmuls' row tile: the prefix is a whole number of them.
@@ -405,7 +422,8 @@ def _expert_rows(n, k, tokens, order, inverse, gates, sizes, live, wi, wo,
         row_gates = _permute(gates.reshape(-1), order, inverse)[:n]
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         out = grouped_ffn(rows, row_gates, sizes, wi.astype(rows.dtype),
-                          wo.astype(rows.dtype), wg, live)
+                          wo.astype(rows.dtype),
+                          wg if wg is None else wg.astype(rows.dtype), live)
     with jax.named_scope(SCOPE_MOE_COMBINE):
         return _combine(out, head, visits, k, t)
 
@@ -414,11 +432,12 @@ def _rows_branch(n, k):
     """``_expert_rows`` over ``n`` rows as one branch of the choice.
 
     Two names a device trace's readers need (``instruction_scopes``).
-    The weights pass a barrier under the experts' scope: a grouped
-    matmul's Mosaic call carries no name of its own and is filed under
-    its largest operand's, which at the prefix's length is an expert
-    panel, and what enters a branch from outside would bring the
-    ``cond``'s name, no part's (the barrier runs nothing). And the whole
+    The weights pass a barrier under the experts' scope: what enters a
+    branch from outside would bring the ``cond``'s name, no part's, to
+    whatever the compiler names after an operand (a cast it moves into
+    the branch; ``lax.ragged_dot``'s own Mosaic calls, which carry no
+    name and are filed under their largest operand's, where a shape
+    keeps that path); the barrier runs nothing. And the whole
     is under the choice's scope once more: differentiated inside
     ``_held_rows_bwd``, the transform's name wraps THIS segment
     (``transpose(jvp(hvd_moe_rows))``) and the parts' below it stay
@@ -530,7 +549,7 @@ class MoeMlp(nn.Module):
         wg = None
         if spec.ffn == "swiglu":
             wg = self.param("wg", experts_init, (held, m, cfg.d_ff),
-                            jnp.float32).astype(cfg.dtype)
+                            jnp.float32)
         bias = None
         if spec.router == "sigmoid_bias":
             bias = self.variable("moe_state", "router_bias", jnp.zeros,
@@ -564,7 +583,8 @@ class MoeMlp(nn.Module):
             # Cast before the choice: a branch hands its weight
             # gradients back in the compute dtype.
             with jax.named_scope(SCOPE_MOE_EXPERTS):
-                wi, wo = wi.astype(cfg.dtype), wo.astype(cfg.dtype)
+                wi, wo, wg = (w if w is None else w.astype(cfg.dtype)
+                              for w in (wi, wo, wg))
             # Named for a block's recomputation to keep where it reads
             # the output again (models/transformer.py ``_remat_block``).
             out = checkpoint_name(

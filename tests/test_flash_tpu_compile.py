@@ -383,6 +383,65 @@ def test_gather_sum_lowers_for_v5e(one_chip, monkeypatch, n, t, k, groups):
         assert "[%d,%d]" % (t * k, m) not in text
 
 
+@pytest.mark.parametrize("n,groups,f", [
+    (32768, 8, 1792),     # lfm2-s16384-ep4-c1, the prefix
+    (65536, 8, 1792),     # and the whole length
+    (8192, 8, 1536),      # glm47f-s8192-ep8-c1
+    (32768, 8, 1536),
+    (16384, 16, 1024),    # trinity-s8192-ep8-c1
+    (65536, 16, 1024),
+    (16384, 16, 768),     # keye-s8192-dsa-ep8-c1
+    (65536, 16, 768),
+    (32768, 64, 1024),    # olmoe-s4096-c1: every row live
+])
+def test_grouped_matmuls_lower_for_v5e(one_chip, monkeypatch, n, groups, f):
+    """The expert layer's grouped matmuls at the five expert cells'
+    shapes (both row lengths of a layer that holds a share), M = 2048 in
+    bf16, an up and a down projection with their gradients: the tiles
+    divide every one; each is ONE Mosaic call under its name, the
+    forward and the input gradient ``hvd_moe_gmm``, the weight gradient
+    ``hvd_moe_gmm_dw``; each has FIVE operands (two prefetched arrays of
+    the walk, its extent, the two matrices), which
+    ``benchmark/trace_reduce.py`` ``flash_kernel`` takes for no flash
+    kernel; the compiler's own ``ragged-dot`` is nowhere, and the walk's
+    plan holds no sort and no gather."""
+    from benchmark import trace_reduce
+    from horovod_tpu.ops import pallas_grouped_matmul
+
+    m = 2048
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip)
+    monkeypatch.setattr(pallas_attention, "_should_interpret",
+                        lambda interpret: False)
+    for k, width in ((m, f), (f, m)):
+        assert pallas_grouped_matmul.divides((n, k), (groups, k, width))
+        lhs = jax.ShapeDtypeStruct((n, k), jnp.bfloat16, sharding=one_chip)
+        rhs = jax.ShapeDtypeStruct((groups, k, width), jnp.bfloat16,
+                                   sharding=one_chip)
+        d_out = jax.ShapeDtypeStruct((n, width), jnp.bfloat16,
+                                     sharding=one_chip)
+
+        def step(lhs, rhs, d_out, sizes):
+            out, vjp = jax.vjp(
+                lambda l, r: pallas_grouped_matmul.grouped_matmul(l, r, sizes),
+                lhs, rhs)
+            return (out,) + vjp(d_out)
+
+        text = jax.jit(step).lower(lhs, rhs, d_out, sizes).compile().as_text()
+        calls = [line.strip().removeprefix("ROOT ")
+                 for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        names = sorted(line.split(" = ")[0].lstrip("%").split(".")[0]
+                       for line in calls)
+        assert names == [introspect.KERNEL_MOE_GROUPED] * 2 + [
+            introspect.KERNEL_MOE_GROUPED_DW], names
+        for line in calls:
+            operands = line.split(" custom-call(")[1].split(
+                "), custom_call_target=")[0].count("%")
+            assert operands == 5 and trace_reduce.flash_kernel(line) == "", line
+        assert "ragged-dot" not in text
+        assert " sort(" not in text and " gather(" not in text
+
+
 def _branches(text):
     """[(conditional, [[(instruction, opcode, HLO line)] per branch])]
     of a compiled step."""
@@ -414,12 +473,15 @@ def test_held_expert_layer_chooses_its_row_arrays(one_chip, monkeypatch):
     dispatch's backward), and nothing in it is (T x k, F) or
     (T x k, M): no select and no pairs over the whole length. Every instruction of
     both branches keeps an ``hvd_moe_*`` scope through
-    ``instruction_scopes``, own or inherited; the grouped matmuls carry
-    none of their own and read one part's off their largest operand, as
-    they do where all experts are held: at the prefix's length that is
-    an expert's panel wherever a panel is an operand, and the branch
-    names it itself (a barrier under the experts' scope, compiled to
-    named ``get-tuple-element``s: no instruction runs for it), for what
+    ``instruction_scopes``, own or inherited. The grouped matmuls are
+    the kernels of ops/pallas_grouped_matmul.py (these widths divide
+    into their tiles): ``hvd_moe_gmm`` / ``hvd_moe_gmm_dw`` under the
+    experts' scope where they are called, FIVE operands each (never the
+    3 or 6 that ``benchmark/trace_reduce.py`` takes for a flash
+    kernel), and no ``ragged-dot`` call of the compiler's is left in
+    either branch. The branch still names its weights itself (a
+    barrier under the experts' scope, compiled to named
+    ``get-tuple-element``s: no instruction runs for it), for what
     enters a branch brings the ``cond``'s name and no part's."""
     import flax.linen as nn
     from flax.core import meta
@@ -471,30 +533,33 @@ def test_held_expert_layer_chooses_its_row_arrays(one_chip, monkeypatch):
         assert len(prefix) == 1, name
         (prefix,) = prefix
         shape_of = {inst: result(line) for inst, _, line in prefix}
+        for branch in branches:
+            assert not [inst for inst, _, line in branch
+                        if "ragged-dot" in inst or "ragged-dot" in line], name
         matmuls = [(inst, line) for inst, _, line in prefix
-                   if inst.startswith("ragged-dot-none")]
-        # Forward 3; backward the 2 up projections again and 6 more.
-        assert len(matmuls) in (3, 8), (name, len(matmuls))
+                   if inst.startswith(introspect.KERNEL_MOE_GROUPED)]
+        weight_grads = [inst for inst, _ in matmuls if inst.startswith(
+            introspect.KERNEL_MOE_GROUPED_DW)]
+        # Forward 3; backward the 2 up projections again, 3 input
+        # gradients (the same kernel, the panel read transposed) and 3
+        # weight gradients.
+        assert (len(matmuls), len(weight_grads)) in ((3, 0), (8, 3)), (
+            name, len(matmuls), len(weight_grads))
         for inst, line in matmuls:
             operands = re.findall(r"%([\w.\-]+)", line.split(
-                " custom-call(")[1].split("), ")[0])
+                " custom-call(")[1].split("), custom_call_target=")[0])
+            assert len(operands) == 5, (inst, operands)
             shapes = [shape_of[inst]] + [shape_of.get(o, "") for o in operands]
             assert not [s for s in shapes if "[%d," % pairs in s], shapes
             assert [s for s in shapes if "[%d," % c in s], shapes
-            # Its own name is bare; the scope is read off an operand: a
-            # part of the layer, never the bare ``hvd_moe_rows/cond``,
-            # and the experts' where a panel is an operand (the three
-            # forward matmuls and the three input gradients; a weight
-            # gradient reads its rows').
-            assert 'op_name="ragged-dot-none"' in line
-            part = [p for p in (introspect.SCOPE_MOE_EXPERTS,
-                                introspect.SCOPE_MOE_DISPATCH,
-                                introspect.SCOPE_MOE_COMBINE)
-                    if p in scopes[inst]]
-            assert len(part) == 1, (inst, scopes[inst])
-            if shapes[0].startswith("bf16[%d," % c):
-                assert part == [introspect.SCOPE_MOE_EXPERTS], (inst, part)
-                assert scopes[inst].endswith("optimization_barrier")
+            # Its own name, under the scope it is called in.
+            kernel = (introspect.KERNEL_MOE_GROUPED_DW if inst in weight_grads
+                      else introspect.KERNEL_MOE_GROUPED)
+            assert scopes[inst].endswith("/%s/pallas_call" % kernel), (
+                inst, scopes[inst])
+            assert introspect.SCOPE_MOE_EXPERTS in scopes[inst]
+            assert introspect.SCOPE_MOE_DISPATCH not in scopes[inst]
+            assert introspect.SCOPE_MOE_COMBINE not in scopes[inst]
         gathers = sorted(
             (int(rows), int(source))
             for inst, opcode, line in prefix

@@ -13,7 +13,7 @@ jax. It runs, one after another, each in a process group of its own and
 under a wall-clock limit:
 
 1. ``--legs``: ONE child that drives every chip of the host --
-   leg 1 *device* (all TPU, a kind ``bench.CHIP_PEAK_BF16`` lists),
+   leg 1 *device* (all TPU, a kind ``benchmark/peaks.json`` lists),
    leg 2 *resnet50* (``DistributedOptimizer``, donated plain-jit step),
    leg 3 *transformer_flash* (the Pallas kernel against the float32
    dense reference, then the d_model-768 decoder with it compiled in),
@@ -58,8 +58,8 @@ LEGS_LIMIT_S = 660
 DRYRUN_LIMIT_S = 180
 HVDRUN_LIMIT_S = 300
 
-# The widest decoder the repo defines (bench.py transformer_big) with
-# the flash kernel at its long-context length, and the reference's
+# A decoder of GPT-2-small's scale (d_model 768, 12 heads, 12 layers)
+# with the flash kernel at a 2048-token context, and the reference's
 # headline ResNet-50 batch.
 SIZES = dict(
     resnet_batch=128, image_size=224,
@@ -153,11 +153,6 @@ def verdict_line(device) -> str:
 
 
 def parent_main() -> int:
-    if os.environ.get("HVD_FLASH_TUNE"):
-        # The tile tuner keeps winners under ~/.cache/horovod_tpu; the
-        # smoke reads no state from outside the checkout.
-        log("HVD_FLASH_TUNE is set; unset it for the smoke")
-        return 1
     me = os.path.join(HERE, "chip_smoke.py")
     summary = {"ok": False}
 
@@ -217,12 +212,24 @@ def parent_main() -> int:
 # Children. Everything below imports jax and may hold the chip.
 # --------------------------------------------------------------------------
 
+def chip_peak_flops(device_kind: str) -> float:
+    """The chip's published dense bf16 FLOP/s from the benchmark's own
+    table, keyed by the exact ``jax.Device.device_kind``. A kind the
+    table lacks is an error, not a default."""
+    with open(os.path.join(HERE, "benchmark", "peaks.json")) as f:
+        peaks = json.load(f)["by_device_kind"]
+    if device_kind not in peaks:
+        raise KeyError(
+            "device_kind %r is not in benchmark/peaks.json (known: %s); "
+            "add it with the source of its peak"
+            % (device_kind, sorted(peaks)))
+    return peaks[device_kind]["bf16_flops"]
+
+
 def _check_devices():
     """Leg 1: every device jax finds (no platform forced in code) is a
     TPU of a kind the peak table lists."""
     import jax
-
-    import bench
 
     devices = jax.devices()
     found = sorted({d.platform for d in devices})
@@ -232,7 +239,7 @@ def _check_devices():
             "device(s)); this smoke passes only on a TPU"
             % ("/".join(found), devices[0].device_kind, len(devices)))
     kind = devices[0].device_kind
-    bench.chip_peak_flops(kind)  # raises on a kind the table lacks
+    chip_peak_flops(kind)  # raises on a kind the table lacks
     return devices
 
 

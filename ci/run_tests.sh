@@ -86,15 +86,13 @@ run_tier1() {
         python -m pytest \
         "tests/test_online_tuner.py::test_guardrail_reverts_injected_regression" \
         tests/test_online_tuner.py -q -p no:cacheprovider
-    echo "=== tier 1: MFU fast-fail (bucketing math + block-tuner cache) ==="
-    # The bucketed gradient path and the flash-block tuner cache are
-    # pure-Python contracts (docs/mfu.md) that every in-graph training
-    # run leans on; a broken bucket assignment or a corrupted winner
-    # journal should fail in seconds, before the full tier burns its
-    # wall budget. The jax-sweep acceptance test runs here too — it is
-    # the proof the tuner actually picks winners on this host.
+    echo "=== tier 1: MFU fast-fail (bucketing math) ==="
+    # The bucketed gradient path is a pure-Python contract
+    # (docs/mfu.md) that every in-graph training run leans on; a
+    # broken bucket assignment should fail in seconds, before the full
+    # tier burns its wall budget.
     timeout "${HVD_CI_MFU_BUDGET:-240}" \
-        python -m pytest tests/test_bucketing.py tests/test_block_tuner.py \
+        python -m pytest tests/test_bucketing.py \
         -q -p no:cacheprovider
     echo "=== tier 1: wire-compression fast-fail (codec math + lossy equality) ==="
     # The quantized wire (docs/wire.md#compression) rewrites every fp32

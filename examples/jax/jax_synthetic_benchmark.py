@@ -54,7 +54,7 @@ def main():
 
     # Donated buffers: the weight/batch-stat/optimizer arrays are
     # updated in place by XLA rather than copied every step, the same
-    # donation bench.py uses (docs/mfu.md).
+    # donation the benchmark's step uses (docs/mfu.md).
     @partial(jax.jit, donate_argnums=(0, 1, 2))
     def train_step(params, batch_stats, opt_state):
         def loss_fn(p, bs):

@@ -22,7 +22,6 @@ reference's Gloo env contract, reference: horovod/runner/gloo_run.py:65-76):
 from __future__ import annotations
 
 import atexit
-import contextlib
 import logging
 import os
 import threading
@@ -51,11 +50,6 @@ class _Context:
     reference: horovod/common/global_state.h:39-126)."""
 
     initialized: bool = False
-    # Bumped on every (re)init: world-scoped caches (e.g. the flash
-    # tuner's synced winner view) key off it so an elastic reset
-    # invalidates them in lockstep with the collective name/sequence
-    # counters.
-    generation: int = 0
     # True once this process has EVER formed a multi-rank world; never
     # cleared. is_shared_world() stays conservatively True during the
     # shutdown->reinit window of an elastic reset, so per-rank
@@ -171,15 +165,14 @@ def init(process_sets=None):
     """
     from horovod_tpu.utils.compile_cache import install_compile_listeners
 
-    with contextlib.ExitStack() as launch:
-        with _ctx.lock:
-            if _ctx.initialized:
-                return
-            # The launch is recorded always (docs/timeline.md#launch):
-            # this init and its parts as spans, and from here on every
-            # program jax compiles or reads from its cache.
-            LAUNCH_LOG.begin_launch()
-            launch.enter_context(LAUNCH_LOG.span("init"))
+    with _ctx.lock:
+        if _ctx.initialized:
+            return
+        # The launch is recorded always (docs/timeline.md#launch):
+        # this init and its parts as spans, and from here on every
+        # program jax compiles or reads from its cache.
+        LAUNCH_LOG.begin_launch()
+        with LAUNCH_LOG.span("init"):
             install_compile_listeners()
             # analysis: blocking-ok(once-per-process bootstrap:
             # init() must be atomic under _ctx.lock — a second
@@ -187,10 +180,6 @@ def init(process_sets=None):
             # to wait for a fully built core either way, and the
             # rendezvous poll IS the init work)
             _start_world(process_sets)
-        # Outside the init lock, inside the `init` span.
-        if _ctx.topology.size > 1:
-            with LAUNCH_LOG.span("init/flash_tile_sync"):
-                _sync_flash_tiles()
 
 
 def _start_world(process_sets):
@@ -229,7 +218,6 @@ def _start_world(process_sets):
                 negotiate_controller_port(_ctx.topology.rank)
         with LAUNCH_LOG.span("init/core_start", size=_ctx.topology.size):
             _ctx.core = CoreSession.start(_ctx.topology)
-    _ctx.generation += 1
     if _ctx.topology.size > 1:
         _ctx.shared_high_water = True
     _ctx.initialized = True
@@ -290,32 +278,6 @@ def _start_world(process_sets):
     atexit.register(shutdown)
 
 
-def _sync_flash_tiles():
-    # Flash-tile cache sync (ops/block_tuner.py): multi-rank tile
-    # decisions come from rank 0's cache view, shipped ONCE per world
-    # formation — here, where every rank (elastic survivors and
-    # respawns alike) passes symmetrically, never at trace time where
-    # only a subset of ranks may re-trace. Runs outside the init lock
-    # (it issues an eager broadcast on the now-live world). Every rank
-    # participates unconditionally — rank 0's env decides the payload,
-    # so per-rank HVD_FLASH_TUNE divergence cannot wedge init.
-    from horovod_tpu.ops import block_tuner
-
-    try:
-        block_tuner.sync_cache_across_world()
-    except Exception as e:  # analysis: allow-broad-except — this
-        # init runs on the ELASTIC RESET path (reinit_for_version),
-        # OUTSIDE the worker's recovery try/except: a peer dying
-        # mid-broadcast must degrade to "no synced view this
-        # world" (all ranks fail the cascade together and fall
-        # back to defaults uniformly; the next in-loop collective
-        # triggers normal rollback/rejoin), never kill survivors
-        # that still have failure budget.
-        logger.warning(
-            "flash tuner cache sync failed (%s); continuing "
-            "without a synced view for this world", e)
-
-
 def shutdown():
     """Shut down background machinery (idempotent)."""
     with _ctx.lock:
@@ -359,22 +321,13 @@ def is_initialized() -> bool:
     return _ctx.initialized
 
 
-def init_generation() -> int:
-    """Monotone per-process init epoch (bumped by every init/reinit).
-    World-scoped caches compare it to decide "is my memo from THIS
-    world?" — every rank of a freshly formed world has just bumped,
-    so epoch-keyed memos start empty on every member in lockstep."""
-    return _ctx.generation
-
-
 def is_shared_world() -> bool:
     """True when this process is one rank of an initialized
     multi-rank world — the condition under which per-rank decisions
     that feed traced programs or collective sequences become SPMD
-    hazards (docs/static_analysis.md#spmd). One definition, shared by
-    the flash-tile tuner and the online knob tuner, and checked at
-    decision time rather than cached: elastic worlds grow and shrink
-    across a process lifetime. During the shutdown->reinit window of
+    hazards (docs/static_analysis.md#spmd). The online knob tuner reads
+    it, at decision time rather than cached: elastic worlds grow and
+    shrink across a process lifetime. During the shutdown->reinit window of
     an elastic reset (not initialized, but the process HAS been part
     of a multi-rank world) this answers conservatively True, so a
     concurrent thread cannot slip a per-rank mutation through

@@ -231,31 +231,6 @@ REGISTRY: Dict[str, Knob] = {k.name: k for k in [
     Knob("HVD_CORE_SANITIZE", HONORED,
          "core/build.py: build/load a sanitizer-instrumented core "
          "(thread|address|undefined; docs/static_analysis.md)"),
-    Knob("HVD_FLASH_BLOCK_Q", HONORED,
-         "ops/pallas_attention.py: flash-attention query tile size"),
-    Knob("HVD_FLASH_BLOCK_K", HONORED,
-         "ops/pallas_attention.py: flash-attention key/value tile "
-         "size"),
-    Knob("HVD_FLASH_TUNE", HONORED,
-         "ops/pallas_attention.py + ops/block_tuner.py: 1 = autotune "
-         "flash-attention tiles per shape on first call and journal "
-         "winners; cache = use cached winners only; unset = off"),
-    Knob("HVD_FLASH_TUNE_CACHE", HONORED,
-         "ops/block_tuner.py: tuned-winner JSONL journal path "
-         "(default ~/.cache/horovod_tpu/flash_blocks.jsonl)"),
-    Knob("HVD_FLASH_TUNE_CANDIDATES", HONORED,
-         "ops/block_tuner.py: comma list of candidate tile sizes the "
-         "sweep crosses for block_q x block_k (default 128,256,512)"),
-    Knob("HVD_FLASH_TUNE_ITERS", HONORED,
-         "ops/block_tuner.py: timed fwd+bwd iterations per candidate "
-         "after the untimed compile/warmup call (default 3)"),
-    Knob("HVD_FLASH_TUNE_SYNC", HONORED,
-         "ops/block_tuner.py: 0 ON RANK 0 disables the init-time "
-         "rank-0 cache sync for the whole world (best_blocks reads "
-         "the per-host cache file again; the opt-out rides the sync "
-         "broadcast, so other ranks' settings are ignored); the "
-         "divergence hazard then falls back on the docs/mfu.md "
-         "multi-host rule"),
     # Wire path (core/src/comm.cc + collectives.cc; docs/wire.md).
     Knob("HVD_RING_CHUNK_BYTES", HONORED,
          "core/src/comm.cc + collectives.cc: pipelined-ring sub-chunk "
@@ -457,7 +432,7 @@ REGISTRY: Dict[str, Knob] = {k.name: k for k in [
 #
 # ``live_safe=False`` marks knobs whose LIVE per-rank mutation can
 # lower rank-divergent XLA programs (trace-time reads: divergent
-# flash tiles or meshes desync the collective sequence across
+# meshes or wire codecs desync the collective sequence across
 # ranks). The tuner only searches them when the
 # process is alone in its world; they are still declared here so the
 # schema is the single inventory of the tunable surface.
@@ -499,17 +474,6 @@ TUNABLE: Dict[str, TunableKnob] = {t.name: t for t in [
                 "live fds + pins an override for future connects "
                 "(core/session.set_wire_params; 0 = kernel default "
                 "for future sockets only)"),
-    TunableKnob("flash_block_q", 128.0, 512.0, 128.0, "env",
-                "HVD_FLASH_BLOCK_Q", 512.0, False,
-                "flash-attention query tile (unset: a rule on the "
-                "sequence length, 512 for long ones); read at TRACE "
-                "time — per-rank divergence lowers divergent programs, "
-                "so live search is single-process only (the "
-                "shape-keyed sweep in ops/block_tuner.py is the "
-                "preferred tuner for this one)"),
-    TunableKnob("flash_block_k", 128.0, 512.0, 128.0, "env",
-                "HVD_FLASH_BLOCK_K", 512.0, False,
-                "flash-attention key/value tile; see flash_block_q"),
     TunableKnob("serve_max_batch", 1.0, 64.0, 1.0, "setter",
                 "HVD_SERVE_MAX_BATCH", 8.0, True,
                 "serving micro-batch size trigger; tuned DOWN from the "

@@ -60,7 +60,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -840,10 +839,10 @@ _flash_select.defvjp(_flash_select_fwd, _flash_select_bwd)
 
 
 def _default_blocks(q_len, kv_len):
-    """The tiles where the caller, the environment and the tuned cache
-    give none: the fewest blocks of at most 512 rows that cover the
-    sequence, evenly, in whole 128-lane groups (1024 -> 512, 1100 ->
-    3 x 384, not 3 x 512 with 436 rows of padding).
+    """The tiles where the caller gives none: the fewest blocks of at
+    most 512 rows that cover the sequence, evenly, in whole 128-lane
+    groups (1024 -> 512, 1100 -> 3 x 384, not 3 x 512 with 436 rows of
+    padding).
 
     Why 512 (v5e, kernel alone; PERF.md, PR 25): a score tile costs
     0.4-0.6 us almost whatever its width, so few large tiles beat many
@@ -853,7 +852,7 @@ def _default_blocks(q_len, kv_len):
     diagonal tiles grows faster than the saving (1024 x 1024: 1.05).
     The times do not depend on head_dim up to 128 nor on the dtype (the
     kernels compute in float32 on 128 lanes), so neither does the
-    rule; per-shape tuning stays ops/block_tuner.py's job."""
+    rule."""
     def even(s):
         n = -(-s // 512)
         return min(512, -(-(-(-s // n)) // 128) * 128)
@@ -886,12 +885,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
       block_q / block_k: VMEM tile sizes (clamped to the sequence and
         rounded to the dtype's sublane multiple; the sequence is padded
         to a multiple). The default comes from the sequence lengths
-        (``_default_blocks``: blocks of at most 512, split evenly);
-        overridable per-job via HVD_FLASH_BLOCK_Q / HVD_FLASH_BLOCK_K,
-        or autotuned per (seq, head_dim, dtype, causal) shape with
-        HVD_FLASH_TUNE=1 (ops/block_tuner.py caches winners across
-        processes; docs/mfu.md). Precedence: explicit argument >
-        HVD_FLASH_BLOCK_Q/K env > tuned cache > default.
+        (``_default_blocks``: blocks of at most 512, split evenly)
+        and from nothing else: no option and no cache names a tile. An
+        explicit tile is for a test of the kernels' independence of
+        their tiling and for a kernel-alone probe on the chip; a probe
+        that finds a better rule changes ``_default_blocks``
+        (docs/mfu.md).
       scale: score scaling; defaults to 1/sqrt(head_dim).
       interpret: force Pallas interpret mode (defaults to True off-TPU).
 
@@ -923,33 +922,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     d = q.shape[-1]
     if scale is None:
         scale = float(d) ** -0.5
-    if block_q is None and block_k is None and \
-            "HVD_FLASH_BLOCK_Q" not in os.environ and \
-            "HVD_FLASH_BLOCK_K" not in os.environ:
-        from horovod_tpu.ops import block_tuner
-
-        if block_tuner.tune_mode() \
-                or block_tuner.world_synced_view_active():
-            # On-first-call autotuning: the sweep (or a cache hit from
-            # an earlier process) picks the tiles for this live shape.
-            # Runs at trace time on synthetic same-shape inputs, so a
-            # jitted caller tunes exactly once per shape. The second
-            # arm matters when THIS rank has HVD_FLASH_TUNE unset but
-            # the world synced rank 0's tile view at init: rank 0's
-            # settings are authoritative, and skipping the lookup
-            # here would trace default tiles against rank 0's tuned
-            # ones — the per-rank env divergence docs/mfu.md forbids.
-            picked = block_tuner.best_blocks(
-                q.shape[1], k.shape[1], d, q.dtype, causal,
-                interpret=interpret)
-            if picked is not None:
-                block_q, block_k = picked
-    if block_q is None or block_k is None:
-        rule_q, rule_k = _default_blocks(q.shape[1], k.shape[1])
-        if block_q is None:
-            block_q = int(os.environ.get("HVD_FLASH_BLOCK_Q", rule_q))
-        if block_k is None:
-            block_k = int(os.environ.get("HVD_FLASH_BLOCK_K", rule_k))
+    rule_q, rule_k = _default_blocks(q.shape[1], k.shape[1])
+    block_q = rule_q if block_q is None else block_q
+    block_k = rule_k if block_k is None else block_k
     from horovod_tpu.jax.introspect import (
         SAVED_FLASH_K,
         SAVED_FLASH_Q,

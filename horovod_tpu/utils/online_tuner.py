@@ -198,9 +198,9 @@ class KnobBinding:
         # elastic reinit cannot land a stale per-rank incumbent.
         # Presence matters as much as the value: when the env mirror
         # was UNSET at launch, the uniform restore must DELETE it —
-        # e.g. flash_attention's tuner gate triggers on the mere
-        # presence of HVD_FLASH_BLOCK_Q/K, so a left-behind mirror
-        # would flip this rank out of the rank-0 synced tile view.
+        # a respawned peer reads the job env the launcher gave it, so
+        # a left-behind HVD_PLAN_GRAD_OVERLAP mirror would leave this
+        # rank planning with a weight no peer has.
         self._launch = float(self.current())
         self._launch_env_set = bool(knob.env) and knob.env in os.environ
 
@@ -252,7 +252,7 @@ class KnobBinding:
         if restore:
             # Restores bypass the grid snap: the launch anchor must be
             # re-applied BYTE-uniform with peers that inherit the raw
-            # job env — snapping an off-grid HVD_FLASH_BLOCK_Q
+            # job env — snapping an off-grid HVD_PLAN_GRAD_OVERLAP
             # onto the box would itself diverge from them.
             value = float(value)
             if not self.knob.live_safe and _shared_world():
@@ -264,9 +264,9 @@ class KnobBinding:
                 # world the only uniform target for a live-unsafe
                 # knob is the launch anchor — including its ABSENCE:
                 # a mirror the job never set must be deleted, not
-                # written back as the default (peers gate on the
-                # var's mere presence, e.g. flash_attention skipping
-                # the synced tile view for HVD_FLASH_BLOCK_Q/K).
+                # written back as the default (peers that inherit
+                # the job env have it unset, and a knob's reader may
+                # tell unset from set to the default).
                 value = self._launch
                 unset_env = not self._launch_env_set
         else:
@@ -275,7 +275,7 @@ class KnobBinding:
                 logger.warning(
                     "online tuner: refusing to apply live-unsafe knob "
                     "%s in a multi-rank world (trace-time divergence "
-                    "hazard, docs/mfu.md)", self.knob.name)
+                    "hazard, docs/autotune.md)", self.knob.name)
                 return tunable_snap(self.knob, self.current())
         if self._setter is not None:
             self._setter(value)
@@ -638,7 +638,7 @@ class OnlineTuner:
         logger.warning(
             "online tuner: world grew mid-search — dropping "
             "live-unsafe knob(s) %s and restoring their launch values "
-            "(trace-time divergence hazard, docs/mfu.md)",
+            "(trace-time divergence hazard, docs/autotune.md)",
             ", ".join(dropped))
         restored = self._restore_unsafe_to_launch()
         keep = [b for b in self.bindings if b.knob.live_safe]
@@ -906,8 +906,8 @@ class OnlineTuner:
 
     def trajectory(self) -> List[dict]:
         """Every decision record this incarnation produced (the same
-        records the journal holds) — bench.py/bench_serve.py embed
-        this in their JSON."""
+        records the journal holds) — bench_serve.py embeds this in
+        its JSON."""
         with self._lock:
             return list(self._trajectory)
 
@@ -981,7 +981,7 @@ def start_online_tuner(role: str = "training",
                     "online tuner: dropping live-unsafe knob(s) %s in "
                     "a multi-rank world — per-rank search of "
                     "trace-time knobs desyncs the collective sequence "
-                    "(docs/mfu.md)", ", ".join(dropped_unsafe))
+                    "(docs/autotune.md)", ", ".join(dropped_unsafe))
                 bindings = [b for b in bindings if b.knob.live_safe]
         if not bindings:
             if dropped_unsafe:

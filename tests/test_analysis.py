@@ -698,21 +698,18 @@ def persist(path, line):
 '''
     _seed(tree, "horovod_tpu/sidecar.py", body)
     assert _keys(run_all(project(tree)), "journal") == []
-    # The journal primitives themselves may append (that is their job).
+    # The journal primitive itself may append (that is its job).
     _seed(tree, "horovod_tpu/runner/journal.py",
           "def attach(path):\n    return open(path, 'a')\n")
-    _seed(tree, "horovod_tpu/ops/block_tuner.py",
-          "import os\n\n\ndef rec(path):\n"
-          "    return os.open(path, os.O_APPEND)\n")
     assert _keys(run_all(project(tree)), "journal") == []
 
 
 def test_journal_online_tuner_is_not_a_primitive_owner(tree):
     """The online tuner's decision log must go through
     runner/journal.DriverJournal — utils/online_tuner.py is a journal
-    CONSUMER, not a third primitive owner, so a hand-rolled append-mode
+    CONSUMER, not a second primitive owner, so a hand-rolled append-mode
     open seeded there is a finding like anywhere else (ISSUE 11: no
-    third append-fsync implementation)."""
+    further append-fsync implementation)."""
     _seed(tree, "horovod_tpu/utils/online_tuner.py", '''
 import json
 

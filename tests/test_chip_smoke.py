@@ -11,6 +11,7 @@ import subprocess
 import sys
 
 import jax
+import pytest
 
 from horovod_tpu.utils import compile_cache
 
@@ -48,6 +49,21 @@ def test_verdict_line_holds_exactly_ok_and_device():
     assert json.loads(line) == {
         "ok": True,
         "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+
+def test_peak_is_the_benchmarks_own_table():
+    import chip_smoke
+
+    assert chip_smoke.chip_peak_flops("TPU v5 lite") == 197e12
+
+
+def test_a_kind_the_table_lacks_is_refused_with_the_known_kinds():
+    import chip_smoke
+
+    with pytest.raises(KeyError) as e:
+        chip_smoke.chip_peak_flops("TPU v9 imaginary")
+    assert "TPU v9 imaginary" in str(e.value)
+    assert "'TPU v5 lite'" in str(e.value)
 
 
 def test_compile_cache_env_set_is_left_to_jax(monkeypatch, tmp_path):

@@ -237,6 +237,53 @@ def test_init_files_its_parts_under_the_next_launch(tmp_path):
         hvd.shutdown()
 
 
+_TWO_RANK_INIT = """
+import json
+import numpy as np
+import horovod_tpu as hvd
+from horovod_tpu.ops import eager
+
+sent = []
+broadcast = eager.broadcast
+def recording(tensor, root_rank, name=None, **kw):
+    sent.append(name)
+    return broadcast(tensor, root_rank, name=name, **kw)
+eager.broadcast = recording
+hvd.init()
+eager.broadcast = broadcast
+spans = hvd.launch_spans()
+total = hvd.allreduce(np.ones(4, np.float32), op=hvd.Sum, name="formed")
+print("TWO_RANK_INIT " + json.dumps({
+    "size": hvd.size(), "sum": float(np.asarray(total)[0]), "sent": sent,
+    "spans": [[s["name"], s["parent"], s["id"]] for s in spans]}))
+hvd.shutdown()
+"""
+
+
+def test_a_two_rank_init_is_the_core_start_and_sends_nothing(tmp_path):
+    """A world of two forms under the ``init`` span with the core's start
+    as its part and no eager broadcast: no ``init/flash_tile_sync``, no
+    object named ``flash_tune.cache_sync`` (the tile tuner's, which every
+    multi-rank init once paid for)."""
+    from tests.test_native_core import _launch
+
+    script = tmp_path / "two_rank_init.py"
+    script.write_text(_TWO_RANK_INIT)
+    codes, outputs = _launch(2, str(script))
+    for rank, (code, out) in enumerate(zip(codes, outputs)):
+        assert code == 0, "rank %d failed:\n%s" % (rank, out)
+        (line,) = [ln for ln in out.splitlines()
+                   if ln.startswith("TWO_RANK_INIT ")]
+        got = json.loads(line[len("TWO_RANK_INIT "):])
+        assert (got["size"], got["sum"]) == (2, 2.0)
+        assert got["sent"] == []
+        (init,) = [s for s in got["spans"] if s[0] == "init"]
+        parts = [name for name, parent, _ in got["spans"]
+                 if parent == init[2]]
+        assert parts == ["init/core_start"]
+        assert not any("flash" in name for name, _, _ in got["spans"])
+
+
 # --- the listener, fed by hand --------------------------------------------------
 
 def _fed():

@@ -131,7 +131,7 @@ def test_schema_covers_required_surface():
 def test_schema_trace_time_knobs_are_not_live_safe():
     """Trace-time reads lower rank-divergent programs: the schema must
     say so, and the default training set must exclude them."""
-    assert not TUNABLE["flash_block_q"].live_safe
+    assert not TUNABLE["plan_grad_overlap"].live_safe
     for name in ot.TRAINING_KNOBS:
         assert TUNABLE[name].live_safe
 
@@ -890,32 +890,31 @@ def test_live_search_world_change_restores_values_inline(
 def test_shared_world_restore_deletes_env_mirror_unset_at_launch(
         monkeypatch):
     """Review fix: the env mirror must restore launch PRESENCE, not
-    just the launch value — flash_attention's tuner gate triggers on
-    the mere existence of HVD_FLASH_BLOCK_Q/K, so a shared-world
-    restore that wrote the default back (instead of deleting a mirror
-    the job never set) would keep this rank out of the rank-0 synced
-    tile view while its peers adopt it: divergent traced tiles, the
-    exact wedge the sync closes."""
+    just the launch value — peers that inherit the job env have the
+    variable unset, so a shared-world restore that wrote the default
+    back (instead of deleting a mirror the job never set) would leave
+    this rank alone with a trace-time knob in its environment."""
     from horovod_tpu.common import basics
     from horovod_tpu.common.knobs import TUNABLE
 
-    monkeypatch.delenv("HVD_FLASH_BLOCK_Q", raising=False)
-    b = ot.KnobBinding(TUNABLE["flash_block_q"])  # launch: UNSET
+    monkeypatch.delenv("HVD_PLAN_GRAD_OVERLAP", raising=False)
+    b = ot.KnobBinding(TUNABLE["plan_grad_overlap"])  # launch: UNSET
     monkeypatch.setattr(basics, "is_initialized", lambda: True)
     size = {"v": 1}
     monkeypatch.setattr(basics, "size", lambda: size["v"])
     # Alone: a search apply lands and mirrors to env.
-    assert b.apply(384.0) == 384.0
-    assert os.environ["HVD_FLASH_BLOCK_Q"] == "384"
+    assert b.apply(0.5) == 0.5
+    assert os.environ["HVD_PLAN_GRAD_OVERLAP"] == "0.5"
     # World grows: the uniform restore DELETES the mirror (launch
     # state was absent) and reports the launch value.
     size["v"] = 2
-    assert b.apply(384.0, restore=True) == TUNABLE["flash_block_q"].default
-    assert "HVD_FLASH_BLOCK_Q" not in os.environ
+    assert b.apply(0.5, restore=True) == \
+        TUNABLE["plan_grad_overlap"].default
+    assert "HVD_PLAN_GRAD_OVERLAP" not in os.environ
     # A mirror the job DID set at launch is written back, not deleted
     # (test_shared_world_revert_clamps_to_launch_anchor pins the
     # value side).
-    monkeypatch.setenv("HVD_FLASH_BLOCK_K", "256")
-    bk = ot.KnobBinding(TUNABLE["flash_block_k"])
-    assert bk.apply(512.0, restore=True) == 256.0
-    assert os.environ["HVD_FLASH_BLOCK_K"] == "256"
+    monkeypatch.setenv("HVD_PLAN_DCN_BW_GBPS", "25")
+    bk = ot.KnobBinding(TUNABLE["plan_dcn_bw_gbps"])
+    assert bk.apply(50.0, restore=True) == 25.0
+    assert os.environ["HVD_PLAN_DCN_BW_GBPS"] == "25"

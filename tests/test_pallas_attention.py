@@ -172,6 +172,44 @@ def test_tile_classes_match_brute_force(q_len, kv_len, bq, bk, causal):
         assert want["edge"] == 0 and tiles.visible(1, 0, 0) is None
 
 
+def _tiles_moved(trace):
+    """What ``trace()`` adds to hvd_flash_tiles_total, by kernel and
+    kind."""
+    from horovod_tpu.jax import introspect
+
+    def read():
+        return {(k, kind): pallas_attention._M_TILES.labels(
+            kernel=k, kind=kind).get()
+            for k in (introspect.KERNEL_FLASH_FWD,
+                      introspect.KERNEL_FLASH_DKV,
+                      introspect.KERNEL_FLASH_DQ,
+                      introspect.KERNEL_DSA_FWD, introspect.KERNEL_DSA_DKV,
+                      introspect.KERNEL_DSA_DQ)
+            for kind in ("full", "edge", "skipped", "below")}
+
+    before = read()
+    trace()
+    return {key: n - before[key] for key, n in read().items()}
+
+
+def _trace_gradient(b, s, h, h_kv, d, window=None, selected=False, **tiles):
+    """Trace forward, dK/dV and dQ at the shape; nothing runs."""
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, s, h_kv, d), jnp.bfloat16)
+    select = None
+    if selected:
+        plane = jax.ShapeDtypeStruct((b, -(-s // (128 * 32)), s, 128),
+                                     jnp.int32)
+        select = pallas_attention.Selection(plane, plane)
+
+    def loss(q, k, v, select):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               select=select,
+                               **tiles).astype(jnp.float32).sum()
+
+    jax.eval_shape(jax.grad(loss, (0, 1, 2)), q, kv, kv, select)
+
+
 @pytest.mark.parametrize("shape,blocks,want", [
     # The benchmark's two one-chip shapes, (B, S, H, D), bf16, causal.
     ((1, 4096, 16, 64), (256, 512), (56, 16, 56)),
@@ -184,7 +222,6 @@ def test_tile_counter_at_trace_time(shape, blocks, want):
     each time a kernel is traced; nothing runs."""
     from horovod_tpu.jax import introspect
 
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     if blocks is None:
         blocks = pallas_attention._default_blocks(shape[1], shape[1])
         assert blocks == (512, 512)
@@ -192,30 +229,105 @@ def test_tile_counter_at_trace_time(shape, blocks, want):
         _, _, kinds = _brute_force_tiles(n, n, *blocks, True)
         want = tuple(sum(1 for k in kinds.values() if k == kind)
                      for kind in ("full", "edge", "skipped"))
-    kernels = (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
-               introspect.KERNEL_FLASH_DQ)
-
-    def read():
-        return {(k, kind): pallas_attention._M_TILES.labels(
-            kernel=k, kind=kind).get()
-            for k in kernels for kind in ("full", "edge", "skipped")}
-
-    def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True, block_q=blocks[0],
-                               block_k=blocks[1]).astype(jnp.float32).sum()
-
-    before = read()
-    jax.eval_shape(jax.grad(loss, (0, 1, 2)), x, x, x)
-    after = read()
-    for k in kernels:
-        got = tuple(after[k, kind] - before[k, kind]
-                    for kind in ("full", "edge", "skipped"))
+    b, s, h, d = shape
+    moved = _tiles_moved(lambda: _trace_gradient(
+        b, s, h, h, d, block_q=blocks[0], block_k=blocks[1]))
+    for k in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
+              introspect.KERNEL_FLASH_DQ):
+        got = tuple(moved[k, kind] for kind in ("full", "edge", "skipped"))
         assert got == want, (k, got)
+
+
+def _cell_attention_shapes():
+    """Every distinct flash call the benchmark's cells trace, as (cell,
+    B, S, H, H_kv, D, window, selected): read from ``BENCHMARK.json``,
+    each cell's configuration and its traffic, so that a new cell joins
+    by itself."""
+    from benchmark.cell import HERE, ROOT, read_json
+
+    bench = read_json(ROOT, "BENCHMARK.json")
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    seen, shapes = set(), []
+    for cell in bench["workloads"]:
+        config = read_json(ROOT, files[cell["config"]])
+        traffic = read_json(HERE, "workloads", cell["traffic"] + ".json")
+        if config.get("attention") != "flash":
+            continue
+        heads = config.get("n_head") or config["num_attention_heads"]
+        kv_heads = config.get("num_key_value_heads") or heads
+        if "qk_nope_head_dim" in config:    # latent attention
+            dim = config["qk_nope_head_dim"] + config["qk_rope_head_dim"]
+        else:
+            dim = config.get("head_dim") or (
+                config.get("n_embd") or config["hidden_size"]) // heads
+        kinds = set(config.get("layer_types") or ["full_attention"])
+        calls = []
+        if "sa_config" in config:
+            calls.append((None, True))
+        elif "full_attention" in kinds:
+            calls.append((None, False))
+        if "sliding_attention" in kinds:
+            calls.append((config["sliding_window"], False))
+        for window, selected in calls:
+            shape = (traffic["per_chip_batch"], traffic["seq_len"], heads,
+                     kv_heads, dim, window, selected)
+            if shape not in seen:
+                seen.add(shape)
+                shapes.append(pytest.param(
+                    *shape, id="%s-%s" % (cell["name"], (
+                        "select" if selected else
+                        "window" if window else "full"))))
+    return shapes
+
+
+@pytest.mark.parametrize("b,s,h,h_kv,d,window,selected",
+                         _cell_attention_shapes())
+def test_no_tiles_given_takes_the_rule_at_the_cells_shapes(
+        b, s, h, h_kv, d, window, selected):
+    """A call that names no tile, as the model's is, traces the three
+    kernels with ``_default_blocks``' tiles and nothing else's."""
+    shape = (b, s, h, h_kv, d, window, selected)
+    block_q, block_k = pallas_attention._default_blocks(s, s)
+    by_rule = _tiles_moved(lambda: _trace_gradient(
+        *shape, block_q=block_q, block_k=block_k))
+    assert sum(by_rule.values()) > 0
+    assert _tiles_moved(lambda: _trace_gradient(*shape)) == by_rule
+    other = _tiles_moved(lambda: _trace_gradient(
+        *shape, block_q=block_q // 2, block_k=block_k))
+    assert other != by_rule     # the counter tells tiles apart
+
+
+@pytest.mark.parametrize("env,cached", [
+    ({"HVD_FLASH_BLOCK_Q": "128", "HVD_FLASH_BLOCK_K": "256"}, False),
+    ({"HVD_FLASH_TUNE": "1"}, False),
+    ({"HVD_FLASH_TUNE": "cache"}, True),
+], ids=["block_q_and_k", "tune", "tune_from_a_cache"])
+def test_the_environment_names_no_tile(env, cached, monkeypatch, tmp_path):
+    """The options the tile tuner had are dead: set, they change no
+    tile, start no sweep, and the cache file they named is neither read
+    nor written."""
+    import json
+
+    shape = (1, 1024, 2, 2, 64)
+    by_rule = _tiles_moved(lambda: _trace_gradient(
+        *shape, block_q=512, block_k=512))
+    cache = tmp_path / "flash_blocks.jsonl"
+    if cached:
+        cache.write_text(json.dumps({
+            "version": 1, "block_q": 128, "block_k": 128,
+            "key": "q1024.kv1024.d64.bfloat16.causal.cpu-cpu"}) + "\n")
+    was = cache.read_text() if cached else None
+    monkeypatch.setenv("HVD_FLASH_TUNE_CACHE", str(cache))
+    for name, value in env.items():
+        monkeypatch.setenv(name, value)
+    assert _tiles_moved(lambda: _trace_gradient(*shape)) == by_rule
+    assert (cache.read_text() if cache.exists() else None) == was
 
 
 @pytest.mark.parametrize("s,tile", [
     (4096, 512), (1024, 512), (1000, 512), (1100, 384), (600, 384),
-    (513, 384), (512, 512), (100, 128), (1, 128)])
+    (513, 384), (512, 512), (100, 128), (1, 128),
+    (2048, 512), (8192, 512), (16384, 512), (65536, 512)])
 def test_default_blocks_split_the_sequence_evenly(s, tile):
     """At most 512 rows a block, the fewest blocks, whole lane groups:
     the padding stays under a lane group a block."""
@@ -324,57 +436,6 @@ def test_block_k_variation_tight_tolerance():
     for bk in (16, 32, 64):
         out = flash_attention(q, k, v, causal=True, block_q=64, block_k=bk)
         assert _rel(out, ref) < 1e-6, bk
-
-
-def test_env_block_override_matches_explicit(monkeypatch):
-    """HVD_FLASH_BLOCK_Q/K (what the tuner historically fed) must be
-    bit-identical to passing the same blocks explicitly."""
-    rng = np.random.RandomState(9)
-    q = jnp.asarray(rng.randn(1, 100, 2, 8), jnp.float32)
-    explicit = flash_attention(q, q, q, causal=True, block_q=32,
-                               block_k=64)
-    monkeypatch.setenv("HVD_FLASH_BLOCK_Q", "32")
-    monkeypatch.setenv("HVD_FLASH_BLOCK_K", "64")
-    via_env = flash_attention(q, q, q, causal=True)
-    assert np.array_equal(np.asarray(explicit), np.asarray(via_env))
-
-
-def test_tuned_cache_blocks_match_default_numerics(tmp_path, monkeypatch):
-    """A journaled tuner winner must change performance only: outputs
-    and gradients at the tuned blocks stay within rounding of the
-    default blocks (bit-level on the q-tile axis per the test above)."""
-    import json
-
-    from horovod_tpu.ops import block_tuner
-
-    path = str(tmp_path / "cache.jsonl")
-    monkeypatch.setenv("HVD_FLASH_TUNE_CACHE", path)
-    monkeypatch.setenv("HVD_FLASH_TUNE", "cache")
-    monkeypatch.delenv("HVD_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("HVD_FLASH_BLOCK_K", raising=False)
-    block_tuner._mem_cache = {}
-    block_tuner._mem_cache_path = None
-    key = block_tuner.shape_key(96, 96, 8, "float32", True,
-                                block_tuner._device_kind())
-    with open(path, "w") as fh:
-        fh.write(json.dumps({"version": 1, "key": key, "block_q": 32,
-                             "block_k": 32}) + "\n")
-
-    rng = np.random.RandomState(10)
-    q = jnp.asarray(rng.randn(1, 96, 1, 8), jnp.float32)
-    tuned = flash_attention(q, q, q, causal=True)       # cache hit 32/32
-    default = flash_attention(q, q, q, causal=True, block_q=96,
-                              block_k=96)
-
-    def loss_tuned(q):
-        return jnp.sum(flash_attention(q, q, q, causal=True) ** 2)
-
-    def loss_default(q):
-        return jnp.sum(flash_attention(q, q, q, causal=True, block_q=96,
-                                       block_k=96) ** 2)
-
-    assert _rel(tuned, default) < 1e-6
-    assert _rel(jax.grad(loss_tuned)(q), jax.grad(loss_default)(q)) < 1e-6
 
 
 def test_transformer_flash_matches_dense():

@@ -1,28 +1,24 @@
 """Journal-discipline lint: no ad-hoc append-mode persistence.
 
 The crash-safety story of PRs 5-8 (driver restart replay, serve-router
-recovery, the flash-tuner cache) rests on exactly two implementations
-of the append-only JSONL journal discipline — fsync-after-append,
-newline/torn-tail guard before appending, torn-tail-tolerant fold on
-read:
+recovery) rests on ONE implementation of the append-only JSONL journal
+discipline — fsync-after-append, newline/torn-tail guard before
+appending, torn-tail-tolerant fold on read: ``runner/journal.py``
+(``DriverJournal``: attach-truncate + fsync'd append + snapshot/event
+replay).
 
-- ``runner/journal.py`` (``DriverJournal``: attach-truncate + fsync'd
-  append + snapshot/event replay);
-- ``ops/block_tuner.py`` (``append_record``/``load_cache``: O_APPEND
-  whole-line interleaving for concurrent writers).
-
-Consumers route through them: the online tuner's decision log
+Consumers route through it: the online tuner's decision log
 (``utils/online_tuner.py``) appends exclusively through
 ``DriverJournal`` — its replay fold only READS the file — so it is
-deliberately NOT a third primitive owner and stays inside this
+deliberately NOT a second primitive owner and stays inside this
 checker's scope like everything else.
 
-A third hand-rolled ``open(path, "a")`` + ``json.dumps`` persistence
-path would re-import every bug those two already fixed (welded torn
+A second hand-rolled ``open(path, "a")`` + ``json.dumps`` persistence
+path would re-import every bug that one already fixed (welded torn
 tails, lost records after a mid-file garbage line, appends that never
 reach disk). This checker flags every append-mode open — ``open``
 with an ``a`` mode or ``os.open`` with ``O_APPEND`` — in
-``horovod_tpu/`` outside the two primitive owners. Rare legitimate
+``horovod_tpu/`` outside the primitive's owner. Rare legitimate
 non-journal appends carry ``# analysis: allow-append`` on (or one line
 above) the ``open`` call, with a reason.
 """
@@ -106,8 +102,8 @@ def check(project: Project) -> List[Finding]:
                 "journal", rel, node.lineno,
                 "direct-append:%s:%d" % (what, ordinal),
                 "%s — append-mode persistence outside the journal "
-                "primitives; route through runner/journal.DriverJournal "
-                "or ops/block_tuner.append_record (fsync-after-append, "
-                "torn-tail guard), or tag the line with "
+                "primitive; route through runner/journal.DriverJournal "
+                "(fsync-after-append, torn-tail guard), or tag the "
+                "line with "
                 "'# %s' and a reason" % (what, ALLOW_TAG)))
     return findings

@@ -26,7 +26,6 @@ DEFAULT_ALLOWLIST: Dict[str, str] = {
     "HOROVOD_RENDEZVOUS_VERSION": "internal: elastic rendezvous epoch "
                                   "the driver stamps on each world",
     # Benchmark/CI harness tuning, not framework behavior.
-    "HVD_BENCH_TIMEOUT": "bench.py harness: per-case subprocess timeout",
     "HVD_CI_METRICS_BUDGET": "ci/run_tests.sh lane budget",
     "HVD_CI_FLIGHTREC_BUDGET": "ci/run_tests.sh lane budget",
     "HVD_CI_TIER1_BUDGET": "ci/run_tests.sh lane budget",
@@ -37,9 +36,6 @@ DEFAULT_ALLOWLIST: Dict[str, str] = {
     "HVD_CI_OPS_BUDGET": "ci/run_tests.sh lane budget",
     # Test-suite internals (set and read only by tests/).
     "HVD_FUZZ_SEED": "tests/fuzz_worker.py reproducibility seed",
-    "HVD_FLASH_SYNC_CACHE_DIR": "tests/flash_sync_worker.py per-rank "
-                                "cache directory (set by the np=2 "
-                                "flash-tile lockstep regression test)",
     "HVD_WIRE_BENCH_SIZES": "tests/wire_bench_worker.py payload sweep "
                             "(set by the bench_wire.py harness)",
     "HVD_WIRE_BENCH_ITERS": "tests/wire_bench_worker.py timed "

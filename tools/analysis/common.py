@@ -49,7 +49,7 @@ class Project:
                  python_scan_dirs: Sequence[str] = (
                      "horovod_tpu", "bin", "ci", "tests", "tools"),
                  python_scan_files: Sequence[str] = (
-                     "bench.py", "bench_scaling.py", "setup.py",
+                     "bench_scaling.py", "setup.py",
                      # Extensionless python launcher: _walk()'s .py
                      # filter misses it, and launch-time knobs are
                      # exactly what it would read.
@@ -59,8 +59,7 @@ class Project:
                  lock_scan_dirs: Sequence[str] = ("horovod_tpu",),
                  journal_scan_dirs: Sequence[str] = ("horovod_tpu",),
                  journal_allowed_files: Sequence[str] = (
-                     "horovod_tpu/runner/journal.py",
-                     "horovod_tpu/ops/block_tuner.py"),
+                     "horovod_tpu/runner/journal.py",),
                  jax_allowed_files: Sequence[str] = (
                      "horovod_tpu/parallel/mesh.py",),
                  jax_scan_files: Sequence[str] = ("__graft_entry__.py",),
@@ -68,7 +67,7 @@ class Project:
                  spmd_scan_dirs: Sequence[str] = ("horovod_tpu",
                                                   "examples"),
                  spmd_scan_files: Sequence[str] = (
-                     "bench.py", "bench_scaling.py", "bench_wire.py",
+                     "bench_scaling.py", "bench_wire.py",
                      "bench_serve.py", "__graft_entry__.py"),
                  tuner_py: str = "horovod_tpu/utils/online_tuner.py",
                  knob_allowlist: Optional[Dict[str, str]] = None):

@@ -57,6 +57,21 @@ SCOPE_CONV_GATE = "hvd_conv_gate"
 # it makes (the packed planes are ops/pallas_attention.py's).
 SCOPE_DSA_INDEX = "hvd_dsa_index"
 SCOPE_DSA_SELECT = "hvd_dsa_select"
+# Mamba (a ``mamba`` layer's mixer, flax scope ``mamba``): under the first
+# the selective scan, its two kernels (ops/pallas_scan.py) and the pads
+# and transposes round them; under the second the causal taps, their bias
+# and the ``silu`` that make the scan's input. The four projections are
+# the module's own.
+SCOPE_SSM_SCAN = "hvd_ssm_scan"
+SCOPE_SSM_CONV = "hvd_ssm_conv"
+# MemoryUnit (a ``memory_unit`` layer's mixer, flax scope ``gmu``): the
+# ``silu`` of the gate projection times the scan output another layer
+# published (the two projections are the module's own).
+SCOPE_GMU = "hvd_gmu"
+# Differential attention (``BlockSpec.diff_attention``): the two maps'
+# halves put side by side, lambda, the subtraction, the norm over each
+# head's 2 D dims and the factor ``1 - lambda_init``.
+SCOPE_DIFF_ATTN = "hvd_diff_attn"
 # The expert layer (parallel/moe.py), inside the ``moe`` module's scope.
 SCOPE_MOE_ROUTER = "hvd_moe_router"      # logits, softmax, top-k, aux losses
 # The sorts (the gates ride one into row order); rows gathered from the
@@ -101,6 +116,10 @@ KERNEL_MOE_GATHER_SUM = "hvd_moe_gather_sum"
 # weight gradient's. FIVE operands each.
 KERNEL_MOE_GROUPED = "hvd_moe_gmm"
 KERNEL_MOE_GROUPED_DW = "hvd_moe_gmm_dw"
+# ``name=`` of the selective scan's two calls (ops/pallas_scan.py; under
+# ``hvd_ssm_scan``): forward SIX operands, backward EIGHT.
+KERNEL_SSM_SCAN_FWD = "hvd_ssm_scan_fwd"
+KERNEL_SSM_SCAN_BWD = "hvd_ssm_scan_bwd"
 # ``checkpoint_name``s of what the forward kernel made, as the backward
 # kernels read it: the (B, H, S, D) output and the (B, H, S) float32
 # log-sum-exp; and of what it READ, its (B, H, S, D) / (B, H_kv, S, D)
@@ -131,6 +150,21 @@ SAVED_ATTN_OUT = "hvd_attn_out"
 # which the gates' and taps' backward reads, and the branch's output.
 SAVED_CONV_IN = "hvd_conv_in"
 SAVED_CONV_OUT = "hvd_conv_out"
+# A ``mamba`` block: the in-projection's product ``[x, z]``, the scan's
+# input ``x'`` (taps, bias and ``silu`` done), the ``[r, B, C]`` product,
+# the state at each chunk's start (the scan's backward remakes a chunk
+# from it), the scan's output y (which a publishing layer also hands to
+# its readers: one array), and the branch's output.
+SAVED_SSM_IN = "hvd_ssm_in"
+SAVED_SSM_X = "hvd_ssm_x"
+SAVED_SSM_PROJ = "hvd_ssm_proj"
+SAVED_SSM_STATES = "hvd_ssm_states"
+SAVED_SSM_Y = "hvd_ssm_y"
+SAVED_SSM_OUT = "hvd_ssm_out"
+# A ``memory_unit`` block: its gate projection's product and the
+# branch's output.
+SAVED_GMU_GATE = "hvd_gmu_gate_proj"
+SAVED_GMU_OUT = "hvd_gmu_out"
 SAVED_MLP_UP = "hvd_mlp_up"
 SAVED_MLP_GATE = "hvd_mlp_gate"
 SAVED_MLP_OUT = "hvd_mlp_out"

@@ -29,7 +29,15 @@ before it and has neither heads nor positions. The fourth,
 a small indexer scores every causal pair, a query keeps its
 ``index_topk`` best (DeepSeek sparse attention, as ``Keye-VL-2.0``'s
 language model carries it), and the attention kernels take that choice
-as data.
+as data. The fifth is ``mamba``, a selective scan over the WHOLE
+sequence (``Mamba``; the scan is two Pallas kernels of ours,
+``ops/pallas_scan.py``). And two kinds READ what an earlier layer
+published instead of making it (``Phi-4-mini-flash-reasoning``'s
+decoder-hybrid-decoder): ``memory_unit`` gates the scan output of layer
+``BlockSpec.scan_from``, ``cross_attention`` attends with its own
+queries over the keys and values of layer ``BlockSpec.kv_from``. With
+``BlockSpec.diff_attention`` every attention is differential: two
+softmax maps over paired heads, the second subtracted.
 
 Param layout (tensor parallel over 'model'):
 - attention QKV projections shard the head dim;
@@ -45,6 +53,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import math
 from typing import Any, Optional
 
 import jax
@@ -64,18 +73,29 @@ from horovod_tpu.jax.introspect import (
     SAVED_FLASH_Q,
     SAVED_FLASH_SELECT,
     SAVED_FLASH_V,
+    SAVED_GMU_GATE,
+    SAVED_GMU_OUT,
     SAVED_MLP_GATE,
     SAVED_MLP_OUT,
     SAVED_MLP_UP,
     SAVED_MOE_OUT,
+    SAVED_SSM_IN,
+    SAVED_SSM_OUT,
+    SAVED_SSM_PROJ,
+    SAVED_SSM_STATES,
+    SAVED_SSM_X,
+    SAVED_SSM_Y,
     SCOPE_ATTN_GATE,
     SCOPE_CONV_GATE,
+    SCOPE_DIFF_ATTN,
     SCOPE_DSA_INDEX,
     SCOPE_DSA_SELECT,
     SCOPE_EMBED,
+    SCOPE_GMU,
     SCOPE_LOGITS,
     SCOPE_MLA_LATENT,
     SCOPE_ROPE,
+    SCOPE_SSM_CONV,
 )
 from horovod_tpu.parallel.mesh import traced_axis_size
 from horovod_tpu.utils import metrics as _metrics
@@ -92,6 +112,16 @@ param_with_axes = nn.with_partitioning
 FULL_ATTENTION, SLIDING_ATTENTION = "full_attention", "sliding_attention"
 CONV = "conv"
 SPARSE_ATTENTION = "sparse_attention"
+# A selective scan (``Mamba``), and the two kinds that read what an
+# earlier layer PUBLISHED: a gate on layer ``scan_from``'s scan output,
+# and attention over layer ``kv_from``'s keys and values.
+MAMBA = "mamba"
+MEMORY_UNIT = "memory_unit"
+CROSS_ATTENTION = "cross_attention"
+_KINDS = (FULL_ATTENTION, SLIDING_ATTENTION, CONV, SPARSE_ATTENTION, MAMBA,
+          MEMORY_UNIT, CROSS_ATTENTION)
+# What a reading kind reads, by the name ``Transformer`` keeps it under.
+_READS = {MEMORY_UNIT: "scan", CROSS_ATTENTION: "kv"}
 
 # Counted at trace time: the layers one traced model makes, by the kind
 # of their token mixer (the name dates from when every mixer was an
@@ -99,9 +129,21 @@ SPARSE_ATTENTION = "sparse_attention"
 _M_ATTN_LAYERS = _metrics.counter(
     "hvd_attn_layers_total",
     "Layers per traced model, by the kind of their token mixer "
-    "(full_attention / sliding_attention / conv / sparse_attention; "
-    "counted at trace time, not per device step).",
+    "(full_attention / sliding_attention / conv / sparse_attention / "
+    "mamba / memory_unit / cross_attention; counted at trace time, not "
+    "per device step).",
     ("kind",))
+
+# Counted at trace time: the arrays one traced model's layers publish to
+# later layers (a scan output, a layer's keys and values), and the
+# layers that read one.
+_M_SHARED_ARRAYS = _metrics.counter(
+    "hvd_shared_arrays_total",
+    "Arrays a layer of one traced model hands to later layers "
+    "(role=published: the scan output of BlockSpec.scan_from, the keys "
+    "and values of BlockSpec.kv_from) and the layers that read one "
+    "(role=read); counted at trace time, not per device step.",
+    ("role",))
 
 # Counted at trace time: the (query, key) pairs of one traced sparse
 # layer, all causal ones and those a selection of ``index_topk`` keeps
@@ -186,6 +228,22 @@ class BlockSpec:
     layer_types: tuple = ()
     sliding_window: int = 0
     conv_taps: int = 0
+    # A MAMBA layer: ``ssm_expand * d_model`` channels of ``ssm_state``
+    # states each (0 = no such layer) behind ``conv_taps`` causal taps;
+    # the step's rank is ``ceil(d_model / 16)``.
+    ssm_state: int = 0
+    ssm_expand: int = 2
+    # The layers whose scan output the MEMORY_UNIT layers read, and
+    # whose keys and values the CROSS_ATTENTION layers; -1 = none.
+    scan_from: int = -1
+    kv_from: int = -1
+    # Differential attention in every attention layer: consecutive
+    # heads are pairs, ``softmax(q1 k1) [v1 v2] - lambda softmax(q2 k2)
+    # [v1 v2]``, a norm over the 2 D dims, times ``1 - lambda_init``;
+    # ``lambda_init = 0.8 - 0.6 exp(-0.3 l)`` at the layer's index l in
+    # ``layer_ids`` (the PUBLISHED index of each layer; () = its own).
+    diff_attention: bool = False
+    layer_ids: tuple = ()
     # A SPARSE_ATTENTION layer's indexer (0 = none, and no such layer):
     # ``index_heads`` heads of ``index_head_dim`` over ONE key head
     # score each causal pair; a query attends to its ``index_topk``
@@ -355,6 +413,14 @@ def _attend(cfg, q, k, v, window=None, select=None):
     raise ValueError("Unknown attention impl %r" % (cfg.attention,))
 
 
+def _differential_output(first, second, lam, lambda_init, scale):
+    """``RMSNorm(first - lam second) (1 - lambda_init)`` over the last
+    dimension (a pair's 2 D dims), eps 1e-5, in float32."""
+    out = first.astype(jnp.float32) - lam * second.astype(jnp.float32)
+    out = out * jax.lax.rsqrt(jnp.mean(out * out, -1, keepdims=True) + 1e-5)
+    return out * (scale * (1.0 - lambda_init))
+
+
 def kth_largest(scores, k):
     """Each row's EXACT ``k``-th largest of float32 ``scores`` (..., n),
     ``-inf`` entries included (so ``-inf`` where a row has fewer than k
@@ -434,12 +500,59 @@ class SelfAttention(nn.Module):
     layer a sliding one; ``rotary`` says whether this layer's q and k
     carry the rotary positions; ``sparse`` gives it the indexer, and
     its queries the keys they choose (``Block`` reads all three off the
-    layer's kind)."""
+    layer's kind). ``diff_layer`` makes the attention differential, with
+    ``lambda_init`` of that layer index; ``cross`` leaves this layer
+    with its queries alone, over the keys and values it is handed;
+    ``publish`` returns the layer's own keys and values beside its
+    result."""
 
     cfg: TransformerConfig
     window: Optional[int] = None
     rotary: bool = True
     sparse: bool = False
+    diff_layer: Optional[int] = None
+    cross: bool = False
+    publish: bool = False
+
+    @nn.nowrap
+    def _differential(self, q, k, v):
+        """Differential attention of q (B, S, H, D) over k, v (B, S,
+        H_kv, D), consecutive heads pairs: ``softmax(q1 k1) [v1 v2] -
+        lambda softmax(q2 k2) [v1 v2]`` (four runs of ``_attend`` at
+        half the heads: the kernels take one width for q.k and v),
+        ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``, an
+        RMSNorm over each pair's 2 D dims, times ``1 - lambda_init``.
+        Returns (B, S, H, D): a pair's 2 D dims as two heads again.
+
+        The layer's six small vectors are ONE (6, D) leaf, ``diff``: lq1,
+        lk1, lq2, lk2 (normal(0.1)) and the norm's scale in two halves
+        (ones). The four lambda vectors' gradients are all one SCALAR,
+        d loss / d lambda, times a fixed vector: a sum over every row with
+        no sign of its own, which comes out near zero on some seeds, and
+        a leaf of them alone then reads any relative distance at all
+        against a float32 reference (0.04 on two seeds, 0.23 on a third:
+        PERF.md section 6, PR 45). Beside the scale's 2 D gradients the
+        leaf has a size that does not vanish, and a wrong lambda gradient
+        still reads 0.8 of it."""
+        cfg, d = self.cfg, q.shape[-1]
+        lambda_init = 0.8 - 0.6 * math.exp(-0.3 * self.diff_layer)
+        diff = self.param(
+            "diff", lambda key, shape, dtype: jnp.concatenate([
+                nn.initializers.normal(0.1)(key, (4, d), dtype),
+                jnp.ones((2, d), dtype)]), (6, d), jnp.float32)
+        vectors, scale = diff[:4], diff[4:].reshape(2 * d)
+        (q1, q2), (k1, k2), (v1, v2) = (
+            (t[:, :, 0::2], t[:, :, 1::2]) for t in (q, k, v))
+        maps = [[_attend(cfg, qi, ki, vj, self.window) for vj in (v1, v2)]
+                for qi, ki in ((q1, k1), (q2, k2))]
+        with jax.named_scope(SCOPE_DIFF_ATTN):
+            lq1, lk1, lq2, lk2 = vectors
+            lam = (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
+                   + lambda_init)
+            first, second = (jnp.concatenate(pair, -1) for pair in maps)
+            out = _differential_output(first, second, lam, lambda_init,
+                                       scale)
+            return out.astype(cfg.dtype).reshape(q.shape)
 
     @nn.nowrap
     def _selection(self, x, weight):
@@ -507,9 +620,10 @@ class SelfAttention(nn.Module):
         return select
 
     @nn.compact
-    def __call__(self, x, selection=None):
+    def __call__(self, x, selection=None, kv=None):
         """``selection`` (B, S, S) forces a sparse layer's choice of
-        keys (its indexer then runs nothing)."""
+        keys (its indexer then runs nothing); ``kv`` is the (k, v)
+        another layer published, which a ``cross`` layer reads."""
         cfg, spec = self.cfg, self.cfg.block
         h, m = cfg.n_heads, cfg.d_model
         d = spec.head_dim or m // h
@@ -520,7 +634,9 @@ class SelfAttention(nn.Module):
             return self.param(name, param_with_axes(init, axes), shape,
                               jnp.float32).astype(cfg.dtype)
 
-        if h_kv == h:
+        if self.cross:
+            wq = weight("wq", (None, "model", None), (m, h, d))
+        elif h_kv == h:
             wqkv = weight("wqkv", (None, None, "model", None), (3, m, h, d))
         else:
             wq = weight("wq", (None, "model", None), (m, h, d))
@@ -537,7 +653,10 @@ class SelfAttention(nn.Module):
                 w = wq if i == 0 else wkv[i - 1]
             return jnp.einsum("bsm,mhd->bshd", x, w)
 
-        q, k, v = projected(0), projected(1), projected(2)
+        if self.cross:
+            q, (k, v) = jnp.einsum("bsm,mhd->bshd", x, wq), kv
+        else:
+            q, k, v = projected(0), projected(1), projected(2)
         # q and k carry no name here, before their norms, although a
         # norm's backward reads its input: measured, a recomputed block
         # that holds the kernel's operands is faster multiplying these
@@ -563,7 +682,10 @@ class SelfAttention(nn.Module):
                 select = self._selection(x, weight)
             else:
                 _M_DSA_SELECTIONS.labels(via="forced").inc()
-        out = _attend(cfg, q, k, v, self.window, select)
+        if self.diff_layer is not None:
+            out = self._differential(q, k, v)
+        else:
+            out = _attend(cfg, q, k, v, self.window, select)
         if spec.attn_gate:
             gate = checkpoint_name(
                 jnp.einsum("bsm,mhd->bshd", x, weight(
@@ -571,8 +693,9 @@ class SelfAttention(nn.Module):
                 SAVED_ATTN_GATE)
             with jax.named_scope(SCOPE_ATTN_GATE):
                 out = out * nn.sigmoid(gate)
-        return checkpoint_name(jnp.einsum("bshd,hdm->bsm", out, wo),
-                               SAVED_ATTN_OUT)
+        out = checkpoint_name(jnp.einsum("bshd,hdm->bsm", out, wo),
+                              SAVED_ATTN_OUT)
+        return (out, (k, v)) if self.publish else out
 
 
 def _to_every_head(k_pe, n_heads):
@@ -693,6 +816,114 @@ class ShortConv(nn.Module):
         return checkpoint_name(y @ w_out.astype(cfg.dtype), SAVED_CONV_OUT)
 
 
+def _step_bias(key, shape, dtype=jnp.float32):
+    """Mamba's initial ``b_dt``: the inverse softplus of a step drawn
+    log-uniformly from [1e-3, 1e-1]."""
+    step = jnp.exp(jax.random.uniform(key, shape, dtype)
+                   * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+def _published_scan(y, gated):
+    """What a publishing ``mamba`` layer hands its readers: the scan's
+    output y, BEFORE the gate (``gated`` = ``y * silu(z)`` is the other
+    reading of the family's code)."""
+    return y
+
+
+class Mamba(nn.Module):
+    """A selective state-space mixer (Mamba-1): ``[x, z] = u W_in`` to E
+    = ``ssm_expand * d_model`` channels each; ``x' = silu(taps(x) +
+    b_c)``, a depthwise causal convolution of ``conv_taps``; ``[r, B, C]
+    = x' W_x`` (rank ``ceil(d_model / 16)`` and twice ``ssm_state``);
+    ``Delta = softplus(r W_dt + b_dt)``, ``A = -exp(A_log)``; the scan
+    ``h_t = exp(Delta_t A) h_(t-1) + (Delta_t x'_t) outer B_t``, ``y_t =
+    h_t C_t + D x'_t`` in float32 (``ops/pallas_scan.py``); ``(y *
+    silu(z)) W_out``. ``publish`` returns y, the scan's output before
+    the gate, beside the result."""
+
+    cfg: TransformerConfig
+    publish: bool = False
+
+    @nn.compact
+    def __call__(self, u):
+        cfg, spec, m = self.cfg, self.cfg.block, self.cfg.d_model
+        e, n, taps = spec.ssm_expand * m, spec.ssm_state, spec.conv_taps
+        rank = -(-m // 16)
+        if min(n, taps) < 1:
+            raise ValueError("a mamba layer needs BlockSpec.ssm_state and "
+                             "conv_taps")
+        if cfg.seq_axis is not None:
+            raise ValueError("a mamba layer carries its state over the "
+                             "whole sequence and exchanges none over "
+                             "seq_axis=%r" % (cfg.seq_axis,))
+        # Imported where a mamba layer is first built, not with the
+        # package: a ``pallas`` import costs every launch.
+        from horovod_tpu.ops.pallas_scan import selective_scan
+
+        init = nn.initializers.normal(0.02)
+
+        def param(name, axes, shape, init=init):
+            return self.param(name, param_with_axes(init, axes), shape,
+                              jnp.float32)
+
+        w_in = param("w_in", (None, "model"), (m, 2 * e))
+        w = param("w", ("model", None), (e, taps))
+        b_c = param("b", ("model",), (e,), nn.initializers.zeros)
+        w_x = param("w_x", ("model", None), (e, rank + 2 * n))
+        w_dt = param("w_dt", (None, "model"), (rank, e))
+        b_dt = param("b_dt", ("model",), (e,), _step_bias)
+        a_log = param("a_log", ("model", None), (e, n), lambda *_: jnp.log(
+            jnp.broadcast_to(jnp.arange(1.0, n + 1.0), (e, n))))
+        d = param("d", ("model",), (e,), nn.initializers.ones)
+        w_out = param("w_out", ("model", None), (e, m))
+        # The products, the scan's input and its output carry names: a
+        # recomputed block keeps them (``_REMAT_KEEPS``) and makes the
+        # taps, the activations, Delta and the gate again.
+        xz = checkpoint_name(u @ w_in.astype(cfg.dtype), SAVED_SSM_IN)
+        x, z = jnp.split(xz, 2, axis=-1)
+        with jax.named_scope(SCOPE_SSM_CONV):
+            x = checkpoint_name(
+                nn.silu(_causal_taps(x, w.astype(cfg.dtype))
+                        + b_c.astype(cfg.dtype)), SAVED_SSM_X)
+        # ``[r, B, C]`` stays float32 (T x 192 numbers): B and C enter the
+        # scan's float32 recurrence 8,192 steps deep as they are summed.
+        proj = checkpoint_name(jnp.dot(
+            x, w_x.astype(cfg.dtype), preferred_element_type=jnp.float32),
+            SAVED_SSM_PROJ)
+        r, b, c = jnp.split(proj, (rank, rank + n), axis=-1)
+        delta = nn.softplus(jnp.dot(
+            r.astype(cfg.dtype), w_dt.astype(cfg.dtype),
+            preferred_element_type=jnp.float32) + b_dt)
+        y = checkpoint_name(
+            selective_scan(x, delta, -jnp.exp(a_log), b, c, d).astype(
+                cfg.dtype), SAVED_SSM_Y)
+        gated = y * nn.silu(z)
+        out = checkpoint_name(gated @ w_out.astype(cfg.dtype), SAVED_SSM_OUT)
+        return (out, _published_scan(y, gated)) if self.publish else out
+
+
+class MemoryUnit(nn.Module):
+    """A gated memory unit: ``(memory * silu(u W_1)) W_2`` with
+    ``memory`` (B, S, E) the scan output an earlier ``mamba`` layer
+    published. No state, no positions, no pairs."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, memory):
+        cfg, m, e = self.cfg, self.cfg.d_model, memory.shape[-1]
+        init = nn.initializers.normal(0.02)
+        w_1 = self.param("w_1", param_with_axes(init, (None, "model")),
+                         (m, e), jnp.float32)
+        w_2 = self.param("w_2", param_with_axes(init, ("model", None)),
+                         (e, m), jnp.float32)
+        gate = checkpoint_name(u @ w_1.astype(cfg.dtype), SAVED_GMU_GATE)
+        with jax.named_scope(SCOPE_GMU):
+            y = memory * nn.silu(gate)
+        return checkpoint_name(y @ w_2.astype(cfg.dtype), SAVED_GMU_OUT)
+
+
 class Mlp(nn.Module):
     """The dense feed-forward, ``cfg.d_ff`` wide unless ``width`` says
     otherwise (a leading dense block, a shared expert)."""
@@ -729,23 +960,29 @@ class Block(nn.Module):
     ``layer_type`` is its entry of ``BlockSpec.layer_types`` and says
     which token mixer it carries. With ``post_norms`` each branch's
     output passes a norm of its own before it joins the residual
-    stream."""
+    stream. ``layer_id`` is the layer's published index where the
+    attention is differential. A ``publish``ing block returns (x,
+    published): its mixer's scan output, or keys and values; a reading
+    kind (``_READS``) is handed one as ``reads``."""
 
     cfg: TransformerConfig
     dense_width: Optional[int] = None
     layer_type: str = FULL_ATTENTION
+    layer_id: Optional[int] = None
+    publish: bool = False
 
     def _mixer(self):
         cfg, spec, kind = self.cfg, self.cfg.block, self.layer_type
-        if kind not in (FULL_ATTENTION, SLIDING_ATTENTION, CONV,
-                        SPARSE_ATTENTION):
+        if kind not in _KINDS:
             raise ValueError("Unknown attention layer type %r (layer_types "
-                             "knows %s, %s, %s and %s)" % (
-                                 kind, FULL_ATTENTION, SLIDING_ATTENTION,
-                                 CONV, SPARSE_ATTENTION))
+                             "knows %s)" % (kind, ", ".join(_KINDS)))
         _M_ATTN_LAYERS.labels(kind=kind).inc()
         if kind == CONV:
             return ShortConv(cfg, name="conv")
+        if kind == MAMBA:
+            return Mamba(cfg, self.publish, name="mamba")
+        if kind == MEMORY_UNIT:
+            return MemoryUnit(cfg, name="gmu")
         sliding = kind == SLIDING_ATTENTION
         if spec.attention_kind == "latent":
             if sliding or kind == SPARSE_ATTENTION:
@@ -758,10 +995,11 @@ class Block(nn.Module):
         return SelfAttention(
             cfg, spec.sliding_window if sliding else None,
             spec.rope_layers is None or kind in spec.rope_layers,
-            kind == SPARSE_ATTENTION, name="attn")
+            kind == SPARSE_ATTENTION, self.layer_id,
+            kind == CROSS_ATTENTION, self.publish, name="attn")
 
     @nn.compact
-    def __call__(self, x, assignment=None, selection=None):
+    def __call__(self, x, assignment=None, selection=None, reads=None):
         cfg = self.cfg
 
         def joined(x, name, branch):
@@ -771,9 +1009,18 @@ class Block(nn.Module):
 
         y = _norm(cfg, "ln1")(x)
         mixer = self._mixer()
-        x = joined(x, "post_attn_norm",
-                   mixer(y, selection)
-                   if self.layer_type == SPARSE_ATTENTION else mixer(y))
+        if self.layer_type == MEMORY_UNIT:
+            branch = mixer(y, reads)
+        elif self.layer_type == CROSS_ATTENTION:
+            branch = mixer(y, kv=reads)
+        elif self.layer_type == SPARSE_ATTENTION:
+            branch = mixer(y, selection)
+        else:
+            branch = mixer(y)
+        published = None
+        if self.publish:
+            branch, published = branch
+        x = joined(x, "post_attn_norm", branch)
         y = _norm(cfg, "ln2")(x)
         if cfg.block.num_experts > 0 and self.dense_width is None:
             from horovod_tpu.parallel.moe import MoeMlp
@@ -784,10 +1031,12 @@ class Block(nn.Module):
                 # ``moe/shared``, its time the ``moe`` scope's.
                 shared = Mlp(cfg, cfg.block.shared_experts * cfg.d_ff,
                              parent=None)
-            return joined(x, "post_mlp_norm",
-                          MoeMlp(cfg, shared, name="moe")(y, assignment))
-        return joined(x, "post_mlp_norm",
-                      Mlp(cfg, self.dense_width, name="mlp")(y))
+            x = joined(x, "post_mlp_norm",
+                       MoeMlp(cfg, shared, name="moe")(y, assignment))
+        else:
+            x = joined(x, "post_mlp_norm",
+                       Mlp(cfg, self.dense_width, name="mlp")(y))
+        return (x, published) if self.publish else x
 
 
 # What a recomputed block keeps from forward to backward: the one place
@@ -845,7 +1094,28 @@ _REMAT_KEEPS = (
     # the taps' backward reads, and the branch's output, 67 MB; the
     # recomputed mixer is its gates and taps, one elementwise pass.
     SAVED_CONV_IN, SAVED_CONV_OUT,
+    # A ``mamba`` block (Phi-4-mini-flash-reasoning at T = 8,192, M 2560,
+    # E 5120, 16 states): the in-projection's product 168 MB, the scan's
+    # input x' 84 MB (the taps' and ``silu``'s backward reads the
+    # product, the scan's and the ``[r, B, C]`` projection's read x'),
+    # that projection's product 6 MB (float32), the state at each chunk's start
+    # 10.5 MB (float32; without it the forward kernel runs twice), the
+    # scan's output y 84 MB (the gate's backward and the out-projection's
+    # weight gradient read it; a publishing layer's readers hold the
+    # same array) and the branch's output 42 MB. Delta (168 MB in
+    # float32) is NOT kept: a 160-deep matmul and a ``softplus`` again.
+    SAVED_SSM_IN, SAVED_SSM_X, SAVED_SSM_PROJ, SAVED_SSM_STATES,
+    SAVED_SSM_Y, SAVED_SSM_OUT,
+    # A ``memory_unit`` block: its gate projection's product 84 MB and
+    # the branch's output 42 MB; the memory itself is the block's INPUT
+    # (the publisher's y above, one array however many readers).
+    SAVED_GMU_GATE, SAVED_GMU_OUT,
 )
+# A ``cross_attention`` block keeps all of these but the kernels' k and
+# v operands: they are the publisher's keys and values, which the block
+# holds as its input, transposed; a copy a reader would be 42 MB each.
+_READER_KEEPS = tuple(name for name in _REMAT_KEEPS
+                      if name not in (SAVED_FLASH_K, SAVED_FLASH_V))
 
 
 @functools.cache
@@ -889,7 +1159,7 @@ def _remat_block(cfg):
     'flash') carries no kernel names and keeps the products alone.
     Counted and logged at trace time."""
     kinds = _layer_kinds(cfg)
-    kernel = sum(kind != CONV for kind in kinds) \
+    kernel = sum(kind not in (CONV, MAMBA, MEMORY_UNIT) for kind in kinds) \
         if cfg.attention == "flash" else 0
     keeps = tuple((label, n) for label, n in (
         ("flash+products", kernel), ("products", len(kinds) - kernel)) if n)
@@ -898,6 +1168,15 @@ def _remat_block(cfg):
     _log_remat(cfg, keeps)
     policy = jax.checkpoint_policies.save_only_these_names(*_REMAT_KEEPS)
     return nn.remat(Block, policy=policy)
+
+
+def _remat_reader():
+    """``Block`` under recomputation for a ``cross_attention`` layer:
+    ``_READER_KEEPS``. A recomputed reader multiplies no key or value
+    and runs no scan: what it reads is its input, kept once, by the
+    layer that published it."""
+    return nn.remat(Block, policy=jax.checkpoint_policies
+                    .save_only_these_names(*_READER_KEEPS))
 
 
 class Transformer(nn.Module):
@@ -958,13 +1237,42 @@ class Transformer(nn.Module):
                     pos_slice = pos.astype(cfg.dtype)[:s_local]
                 x = x + pos_slice[None]
         kinds = _layer_kinds(cfg)
-        block = _remat_block(cfg) if cfg.remat else Block
+        block = reader = _remat_block(cfg) if cfg.remat else Block
+        if cfg.remat and CROSS_ATTENTION in kinds:
+            reader = _remat_reader()
+        spec = cfg.block
+        published = {}     # "scan" / "kv": what a layer handed on
         for i in range(cfg.n_layers):
             dense = i < cfg.block.first_dense_layers
-            x = block(cfg, cfg.block.dense_ff if dense else None, kinds[i],
-                      name="layer_%d" % i)(
+            made, extra, args = block, {}, ()
+            if spec.diff_attention:
+                extra["layer_id"] = (spec.layer_ids or range(cfg.n_layers))[i]
+            publishes = {spec.scan_from: "scan", spec.kv_from: "kv"}.get(i)
+            if publishes:
+                if (kinds[i] == MAMBA) != (publishes == "scan") or kinds[i] \
+                        not in (MAMBA, FULL_ATTENTION, SLIDING_ATTENTION):
+                    raise ValueError(
+                        "BlockSpec.scan_from names a mamba layer and kv_from "
+                        "a full or sliding attention layer; layer %d is a %s"
+                        % (i, kinds[i]))
+                extra["publish"] = True
+            if kinds[i] in _READS:
+                if _READS[kinds[i]] not in published:
+                    raise ValueError(
+                        "layer %d is a %s and no earlier layer published "
+                        "(BlockSpec.scan_from %d, kv_from %d)" % (
+                            i, kinds[i], spec.scan_from, spec.kv_from))
+                _M_SHARED_ARRAYS.labels(role="read").inc()
+                args = (published[_READS[kinds[i]]],)
+                if kinds[i] == CROSS_ATTENTION:
+                    made = reader
+            x = made(cfg, cfg.block.dense_ff if dense else None, kinds[i],
+                     name="layer_%d" % i, **extra)(
                 x, None if assignments is None else assignments[i],
-                None if selections is None else selections[i])
+                None if selections is None else selections[i], *args)
+            if publishes:
+                _M_SHARED_ARRAYS.labels(role="published").inc()
+                x, published[publishes] = x
         x = _norm(cfg, "ln_f")(x)
         with jax.named_scope(SCOPE_LOGITS):
             logits = jnp.einsum("bsm,vm->bsv", x, head.astype(cfg.dtype))

@@ -518,8 +518,9 @@ class SelfAttention(nn.Module):
     def _differential(self, q, k, v):
         """Differential attention of q (B, S, H, D) over k, v (B, S,
         H_kv, D), consecutive heads pairs: ``softmax(q1 k1) [v1 v2] -
-        lambda softmax(q2 k2) [v1 v2]`` (four runs of ``_attend`` at
-        half the heads: the kernels take one width for q.k and v),
+        lambda softmax(q2 k2) [v1 v2]`` (two runs of ``_attend`` at
+        half the heads, each map over a V of 2 D: a pair's ``[v1 v2]``
+        is v's two consecutive heads as they lie, a reshape),
         ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``, an
         RMSNorm over each pair's 2 D dims, times ``1 - lambda_init``.
         Returns (B, S, H, D): a pair's 2 D dims as two heads again.
@@ -541,15 +542,16 @@ class SelfAttention(nn.Module):
                 nn.initializers.normal(0.1)(key, (4, d), dtype),
                 jnp.ones((2, d), dtype)]), (6, d), jnp.float32)
         vectors, scale = diff[:4], diff[4:].reshape(2 * d)
-        (q1, q2), (k1, k2), (v1, v2) = (
-            (t[:, :, 0::2], t[:, :, 1::2]) for t in (q, k, v))
-        maps = [[_attend(cfg, qi, ki, vj, self.window) for vj in (v1, v2)]
-                for qi, ki in ((q1, k1), (q2, k2))]
+        (q1, q2), (k1, k2) = (
+            (t[:, :, 0::2], t[:, :, 1::2]) for t in (q, k))
+        b, s, h_kv, _ = v.shape
+        vv = v.reshape(b, s, h_kv // 2, 2 * d)
+        first = _attend(cfg, q1, k1, vv, self.window)
+        second = _attend(cfg, q2, k2, vv, self.window)
         with jax.named_scope(SCOPE_DIFF_ATTN):
             lq1, lk1, lq2, lk2 = vectors
             lam = (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
                    + lambda_init)
-            first, second = (jnp.concatenate(pair, -1) for pair in maps)
             out = _differential_output(first, second, lam, lambda_init,
                                        scale)
             return out.astype(cfg.dtype).reshape(q.shape)
@@ -711,7 +713,7 @@ class LatentAttention(nn.Module):
     ``c_kv = norm(c_kv)``, ``c_kv Wkvb`` split per head into the plain
     part of k and v; RoPE on q's rotary part and on ``k_pe``, which ALL
     heads share. q.k is ``qk_nope_head_dim + qk_rope_head_dim`` wide and
-    has to equal ``v_head_dim``: the kernels take one ``d``."""
+    has to equal ``v_head_dim`` here ('flash' alone takes two widths)."""
 
     cfg: TransformerConfig
 

@@ -39,6 +39,13 @@ the K/V panel by the query head's group, and dK/dV walks the group's
 query heads as a grid axis, adding into a float32 dK/dV panel that
 stays in VMEM until the group is done.
 
+Two widths: q, k, dQ and dK are ``d`` wide (``q.shape[-1]``); v, dO, the
+output and dV are ``d_v`` wide (``v.shape[-1]``), which need not be
+``d``. The score tile, the mask and the softmax depend on ``d`` alone,
+so a map over a V of two heads side by side (differential attention's
+``[v1 | v2]``) is ONE call, not one a half. On a 128-wide MXU a p.v
+product 128 wide costs what one 64 wide costs (docs/mfu.md).
+
 A learned selection (``select=``, DeepSeek sparse attention): which keys
 a query keeps is then DATA the step computed, not a fact of the trace.
 It comes as two bit planes of the (S, S) mask (``pack_selection``): one
@@ -285,20 +292,33 @@ _M_TILES = _metrics.counter(
     "not per device step).", ("kernel", "kind"))
 
 
+_M_CALLS = _metrics.counter(
+    "hvd_flash_calls_total",
+    "Traced flash-attention kernel calls by the widths of q.k and of v "
+    "(\"64\" where they are equal, \"64+128\" where they are not; "
+    "counted at trace time, not per device step).", ("kernel", "widths"))
+
+
 @functools.cache
-def _log_tiles(kernel, tiles, shape, dtype, group):
+def _log_tiles(kernel, tiles, shape, widths, dtype, group):
     logger.info(
-        "flash_attention %s %s %s: %d x %d tiles, window %s, %d query "
-        "head(s) a key/value head, a plane has %s", kernel, shape, dtype,
-        tiles.block_q, tiles.block_k, tiles.window, group, tiles.counts())
+        "flash_attention %s %s (widths %s) %s: %d x %d tiles, window %s, "
+        "%d query head(s) a key/value head, a plane has %s", kernel, shape,
+        widths, dtype, tiles.block_q, tiles.block_k, tiles.window, group,
+        tiles.counts())
 
 
-def _count_tiles(kernel, tiles, shape, dtype, group):
-    """Trace time: one plane's tiles by kind into the counter, and one
-    log line per kernel, shape, window and group."""
+def _count_tiles(kernel, tiles, shape, d_v, dtype, group):
+    """Trace time: one plane's tiles by kind and the call by its widths
+    into the counters, and one log line per kernel, shape, widths,
+    window and group."""
     for kind, n in tiles.counts().items():
         _M_TILES.labels(kernel=kernel, kind=kind).inc(n)
-    _log_tiles(kernel, tiles, tuple(shape), jnp.dtype(dtype).name, group)
+    d = shape[-1]
+    widths = "%d" % d if d_v == d else "%d+%d" % (d, d_v)
+    _M_CALLS.labels(kernel=kernel, widths=widths).inc()
+    _log_tiles(kernel, tiles, tuple(shape), widths, jnp.dtype(dtype).name,
+               group)
 
 
 _NT = (((1,), (1,)), ((), ()))   # a . b^T
@@ -403,7 +423,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, tiles, scale):
         acc = acc * alpha[:, None] + _dot(p, v, _NN)
         return acc, m_new, l_new
 
-    acc = jnp.zeros((block_q, q.shape[1]), jnp.float32)
+    acc = jnp.zeros((block_q, v_ref.shape[1]), jnp.float32)
     m = jnp.full((block_q,), NEG_INF, jnp.float32)
     l = jnp.zeros((block_q,), jnp.float32)
     acc, m, l = jax.lax.fori_loop(tiles.key_start(qi), tiles.key_end(qi),
@@ -439,7 +459,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     kj = pl.program_id(2 if group == 1 else 3)
     k_start = kj * block_k
     k = k_ref[...].astype(jnp.float32)  # (block_k, D)
-    v = v_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)  # (block_k, D_v)
 
     def body(qi, carry):
         dk, dv = carry
@@ -597,21 +617,23 @@ def _pad_rows(plane, rows):
                            (0, 0)))
 
 
-def _compiler_params(panel_rows, d, dtype, block_q, block_k, out_rows=0,
+def _compiler_params(panel_rows, d, d_v, dtype, block_q, block_k, out_rows=0,
                      select_rows=0):
-    """Mosaic's scoped-VMEM limit for a kernel that keeps two (S, D)
-    panels resident: the default (16 MiB on a v5e) wherever the kernel
-    fits, because asking for more takes VMEM from XLA's own prefetches
-    around the call (1 ms a step at S=4096, PERF.md PR 25); what it
-    needs, once it does not. Each panel is double buffered and padded
+    """Mosaic's scoped-VMEM limit for a kernel that keeps two panels
+    resident, one (S, D) and one (S, D_v) (k and v, or q and dO): the
+    default (16 MiB on a v5e) wherever the kernel fits, because asking
+    for more takes VMEM from XLA's own prefetches around the call (1 ms
+    a step at S=4096, PERF.md PR 25); what it needs, once it does not. Each panel is double buffered and padded
     to 128 lanes; half a dozen float32 score tiles cover the loop
     body's temporaries and spills. ``out_rows``: the rows of the two
-    float32 output panels the grouped dK/dV keeps resident besides.
+    float32 output panels (dK and dV, the same two widths) the grouped
+    dK/dV keeps resident besides.
     ``select_rows``: the (rows, 128) int32 slabs of a learned mask's
     plane block (words x rows), double buffered, and two more score
     tiles for its pieces."""
-    panels = 2 * 2 * panel_rows * max(d, 128) * jnp.dtype(dtype).itemsize
-    panels += 2 * 2 * out_rows * max(d, 128) * 4
+    lanes = max(d, 128) + max(d_v, 128)
+    panels = 2 * panel_rows * lanes * jnp.dtype(dtype).itemsize
+    panels += 2 * out_rows * lanes * 4
     if select_rows:
         panels += 2 * select_rows * _LANES * 4 + 2 * 4 * block_q * block_k
     need = panels + 6 * 4 * block_q * block_k + (2 << 20)
@@ -630,8 +652,9 @@ def _flash_fwd_impl(q, k, v, causal, window, block_q, block_k, scale,
         SAVED_FLASH_OUT,
     )
 
-    # q here is (B, H, S, D); k and v (B, H_kv, S_kv, D).
+    # q here is (B, H, S, D); k (B, H_kv, S_kv, D), v (B, H_kv, S_kv, D_v).
     b, h, s, d = q.shape
+    d_v = v.shape[3]
     kv_len, group = k.shape[2], h // k.shape[1]
     qp = _pad_seq(q, block_q)
     kp = _pad_seq(k, block_k)
@@ -640,9 +663,9 @@ def _flash_fwd_impl(q, k, v, causal, window, block_q, block_k, scale,
     tiles = _Tiles(block_q, block_k, causal, s, kv_len, window,
                    select is not None)
     name = KERNEL_DSA_FWD if tiles.learned else KERNEL_FLASH_FWD
-    _count_tiles(name, tiles, q.shape, q.dtype, group)
+    _count_tiles(name, tiles, q.shape, d_v, q.dtype, group)
     in_specs = [_plane_spec(block_q, d), _panel_spec(sk_pad, d, group),
-                _panel_spec(sk_pad, d, group)]
+                _panel_spec(sk_pad, d_v, group)]
     operands, words = (qp, kp, vp), 0
     if tiles.learned:
         words = select.by_query.shape[1]
@@ -653,13 +676,13 @@ def _flash_fwd_impl(q, k, v, causal, window, block_q, block_k, scale,
         functools.partial(_fwd_kernel, tiles=tiles, scale=scale),
         grid=(b, h, tiles.num_qb),
         in_specs=in_specs,
-        out_specs=[_plane_spec(block_q, d), _plane_spec(block_q, 1)],
+        out_specs=[_plane_spec(block_q, d_v), _plane_spec(block_q, 1)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq_pad, d_v), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq_pad, 1), jnp.float32),
         ],
         compiler_params=_compiler_params(
-            sk_pad, d, q.dtype, block_q, block_k,
+            sk_pad, d, d_v, q.dtype, block_q, block_k,
             select_rows=words * block_q),
         interpret=_should_interpret(interpret),
         name=name,
@@ -678,10 +701,11 @@ def _flash_fwd(q, k, v, causal, window, block_q, block_k, scale, interpret):
                            interpret)
 
 
-def _dkv_call(tiles, group, b, h, d, sq_pad, sk_pad, dtype, scale, interp,
-              words=0):
-    """The dK/dV ``pallas_call``. One query head a key/value head: a
-    (B, H, num_kb) grid, each step writes its own (block_k, D) block.
+def _dkv_call(tiles, group, b, h, d, d_v, sq_pad, sk_pad, dtype, scale,
+              interp, words=0):
+    """The dK/dV ``pallas_call``; q, k and dK ``d`` wide, v, dO and dV
+    ``d_v``. One query head a key/value head: a (B, H, num_kb) grid,
+    each step writes its own (block_k, D) and (block_k, D_v) blocks.
     Grouped: a (B, H_kv, group, num_kb) grid; the key/value head's
     float32 dK/dV panels are the output blocks of all ``group x
     num_kb`` steps, so they stay in VMEM while every query head of the
@@ -699,40 +723,50 @@ def _dkv_call(tiles, group, b, h, d, sq_pad, sk_pad, dtype, scale, interp,
     if group == 1:
         rows_panel = pl.BlockSpec(rows, lambda bi, hi, kj: (bi, hi, 0, 0, 0))
         in_specs = [_panel_spec(sq_pad, d), _plane_spec(block_k, d),
-                    _plane_spec(block_k, d), _panel_spec(sq_pad, d),
+                    _plane_spec(block_k, d_v), _panel_spec(sq_pad, d_v),
                     rows_panel, rows_panel]
         if words:
             in_specs.append(_select_spec(words, block_k))
         return pl.pallas_call(
             kernel, grid=(b, h, tiles.num_kb),
             in_specs=in_specs,
-            out_specs=[_plane_spec(block_k, d), _plane_spec(block_k, d)],
-            out_shape=[jax.ShapeDtypeStruct((b, h, sk_pad, d), dtype)] * 2,
-            compiler_params=_compiler_params(sq_pad, d, dtype, block_q,
+            out_specs=[_plane_spec(block_k, d), _plane_spec(block_k, d_v)],
+            out_shape=[jax.ShapeDtypeStruct((b, h, sk_pad, w), dtype)
+                       for w in (d, d_v)],
+            compiler_params=_compiler_params(sq_pad, d, d_v, dtype, block_q,
                                              block_k, **learnt),
             interpret=interp, name=name)
 
     def of_query_head(*block):
         return lambda bi, hk, gi, kj: (bi, hk * group + gi) + block
 
-    q_panel = pl.BlockSpec((None, None, sq_pad, d), of_query_head(0, 0))
+    def q_panel(w):
+        return pl.BlockSpec((None, None, sq_pad, w), of_query_head(0, 0))
+
+    def kv_spec(n_rows, w, index):
+        return pl.BlockSpec((None, None, n_rows, w), index)
+
+    def block(bi, hk, gi, kj):
+        return bi, hk, kj, 0
+
+    def panel(bi, hk, gi, kj):
+        return bi, hk, 0, 0
+
     rows_panel = pl.BlockSpec(rows, of_query_head(0, 0, 0))
-    kv_block = pl.BlockSpec((None, None, block_k, d),
-                            lambda bi, hk, gi, kj: (bi, hk, kj, 0))
-    kv_panel = pl.BlockSpec((None, None, sk_pad, d),
-                            lambda bi, hk, gi, kj: (bi, hk, 0, 0))
-    params = _compiler_params(sq_pad, d, dtype, block_q, block_k, sk_pad,
-                              **learnt)
-    in_specs = [q_panel, kv_block, kv_block, q_panel, rows_panel, rows_panel]
+    params = _compiler_params(sq_pad, d, d_v, dtype, block_q, block_k,
+                              sk_pad, **learnt)
+    in_specs = [q_panel(d), kv_spec(block_k, d, block),
+                kv_spec(block_k, d_v, block), q_panel(d_v),
+                rows_panel, rows_panel]
     if words:
         in_specs.append(_select_spec(
             words, block_k, lambda bi, hk, gi, kj: (bi, 0, kj, 0)))
     return pl.pallas_call(
         kernel, grid=(b, h // group, group, tiles.num_kb),
         in_specs=in_specs,
-        out_specs=[kv_panel, kv_panel],
-        out_shape=[jax.ShapeDtypeStruct((b, h // group, sk_pad, d),
-                                        jnp.float32)] * 2,
+        out_specs=[kv_spec(sk_pad, d, panel), kv_spec(sk_pad, d_v, panel)],
+        out_shape=[jax.ShapeDtypeStruct((b, h // group, sk_pad, w),
+                                        jnp.float32) for w in (d, d_v)],
         compiler_params=params, interpret=interp, name=name)
 
 
@@ -747,6 +781,7 @@ def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
 
     q, k, v, out, lse, select = res
     b, h, s, d = q.shape
+    d_v = v.shape[3]
     kv_len, group = k.shape[2], h // k.shape[1]
     do = g.astype(jnp.float32)
     delta = jnp.sum(do * out.astype(jnp.float32), axis=-1)  # (B, H, S)
@@ -779,7 +814,7 @@ def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
     dkv_operands = (qp, kp, vp, dop, lse_rows, delta_rows)
     dq_operands = (qp, kp, vp, dop, lse_col, delta_col)
     dq_specs = [_plane_spec(block_q, d), _panel_spec(sk_pad, d, group),
-                _panel_spec(sk_pad, d, group), _plane_spec(block_q, d),
+                _panel_spec(sk_pad, d_v, group), _plane_spec(block_q, d_v),
                 _plane_spec(block_q, 1), _plane_spec(block_q, 1)]
     key_words = query_words = 0
     if tiles.learned:
@@ -790,11 +825,11 @@ def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
         dkv_operands += (_pad_rows(select.by_key, sk_pad),)
         dq_operands += (_pad_rows(select.by_query, sq_pad),)
         dq_specs.append(_select_spec(query_words, block_q))
-    _count_tiles(dkv_name, tiles, q.shape, q.dtype, group)
-    dk, dv = _dkv_call(tiles, group, b, h, d, sq_pad, sk_pad, q.dtype, scale,
-                       interp, key_words)(*dkv_operands)
+    _count_tiles(dkv_name, tiles, q.shape, d_v, q.dtype, group)
+    dk, dv = _dkv_call(tiles, group, b, h, d, d_v, sq_pad, sk_pad, q.dtype,
+                       scale, interp, key_words)(*dkv_operands)
 
-    _count_tiles(dq_name, tiles, q.shape, q.dtype, group)
+    _count_tiles(dq_name, tiles, q.shape, d_v, q.dtype, group)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, tiles=tiles, scale=scale),
         grid=(b, h, tiles.num_qb),
@@ -802,7 +837,7 @@ def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
         out_specs=_plane_spec(block_q, d),
         out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
         compiler_params=_compiler_params(
-            sk_pad, d, q.dtype, block_q, block_k,
+            sk_pad, d, d_v, q.dtype, block_q, block_k,
             select_rows=query_words * block_q),
         interpret=interp,
         name=dq_name,
@@ -874,7 +909,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
         ``horovod_tpu.models.transformer``). ``k`` and ``v`` may carry
         fewer heads than ``q``, a divisor of its count: query head h
         then reads key/value head ``h // (H // H_kv)``, and dK/dV come
-        back that many heads wide.
+        back that many heads wide. ``v`` equals ``k`` in every dimension
+        but the last: its width ``d_v`` is the output's, dO's and dV's,
+        apart from the ``head_dim`` of q, k, dQ and dK.
       causal: apply a causal (lower-triangular) mask.
       window: with ``causal``, a query at position p sees the keys j
         with ``p - window < j <= p`` (None: all of ``j <= p``).
@@ -891,19 +928,20 @@ def flash_attention(q, k, v, *, causal: bool = True,
         their tiling and for a kernel-alone probe on the chip; a probe
         that finds a better rule changes ``_default_blocks``
         (docs/mfu.md).
-      scale: score scaling; defaults to 1/sqrt(head_dim).
+      scale: score scaling; defaults to 1/sqrt(head_dim), q's width.
       interpret: force Pallas interpret mode (defaults to True off-TPU).
 
     Returns:
-      (batch, seq, heads, head_dim) attention output in q.dtype.
+      (batch, seq, heads, d_v) attention output in q.dtype.
     """
     if q.ndim != 4:
         raise ValueError("expected (B, S, H, D) inputs, got %r"
                          % (q.shape,))
-    if k.shape != v.shape or q.shape[2] % k.shape[2]:
+    if k.shape[:-1] != v.shape[:-1] or q.shape[2] % k.shape[2]:
         raise ValueError(
-            "q has %d heads, k %r and v %r: k and v need one shape and a "
-            "head count that divides q's" % (q.shape[2], k.shape, v.shape))
+            "q has %d heads, k %r and v %r: k and v need one shape up to "
+            "their widths and a head count that divides q's"
+            % (q.shape[2], k.shape, v.shape))
     if window is not None and (not causal or window < 1):
         raise ValueError("a window (%r) needs causal=True and at least "
                          "one key" % (window,))

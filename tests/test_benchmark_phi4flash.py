@@ -13,3 +13,65 @@ test of its own, as ``tests/test_benchmark_keye.py`` collects
 Keye-VL-2.0's."""
 
 from benchmark.tests.test_phi4flash import *  # noqa: F401,F403
+
+
+def test_a_recomputed_reader_runs_no_scan_and_no_key_or_value_projection(  # noqa: F811,E501
+):
+    """``benchmark/tests/test_phi4flash.py``'s test of this name with
+    today's count of flash calls: it holds TWELVE forward calls (four a
+    differential layer), which a PR that changes the program cannot
+    repair there (a perf_opt PR may not edit a file the benchmark
+    already has; ROADMAP D13 (16)). The checks are its own, word for
+    word; since PR 46 a differential layer runs each map over a V of
+    two heads side by side, so the three layers make SIX forward calls,
+    none of them again inside a ``checkpoint``."""
+    import jax
+
+    from benchmark.tests.test_phi4flash import _assembled, _work
+    from horovod_tpu.jax import introspect
+    from horovod_tpu.models import transformer
+
+    cell, model, params, state, tokens = _assembled()
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: model.loss(p, state, tokens)[0]))(params)
+    work = list(_work(jaxpr.jaxpr))
+    fwd, bwd = introspect.KERNEL_SSM_SCAN_FWD, introspect.KERNEL_SSM_SCAN_BWD
+    assert work.count((fwd, False)) == 2 and work.count((fwd, True)) == 0
+    assert work.count((bwd, True)) == 2
+    recomputed = [what for what, inside in work if inside]
+    x, m, e = (2, 128, 64), 64, 128
+    forward = {
+        "mamba in": (x, (m, 2 * e)),
+        "mamba [r, B, C]": ((2, 128, e), (e, 4 + 32)),
+        "memory unit gate": (x, (m, e)),
+        "k or v": (x, (m, 2, 16)),
+        "q": (x, (m, 4, 16)),
+        "dense up or gate": (x, (m, 96)),
+        "step": ((2, 128, 4), (4, e)),
+    }
+    count = {name: recomputed.count(shapes)
+             for name, shapes in forward.items()}
+    assert count["step"] == 2, recomputed        # one a mamba layer
+    assert count["mamba in"] == count["mamba [r, B, C]"] == 0
+    assert count["memory unit gate"] == 0 and count["k or v"] == 0
+    assert count["q"] == 0 and count["dense up or gate"] == 0
+    # Six flash forward calls (two a differential layer), none again.
+    flash = [what for what in work if what[0] == introspect.KERNEL_FLASH_FWD]
+    assert flash == [(introspect.KERNEL_FLASH_FWD, False)] * 6
+    # A cross-attention block keeps no copy of the keys and values.
+    assert introspect.SAVED_FLASH_K in transformer._REMAT_KEEPS
+    assert set(transformer._REMAT_KEEPS) - set(transformer._READER_KEEPS) \
+        == {introspect.SAVED_FLASH_K, introspect.SAVED_FLASH_V}
+    # The control: with nothing kept, every product is made again and
+    # the forward scan runs a second time.
+    kept = transformer._REMAT_KEEPS, transformer._READER_KEEPS
+    transformer._REMAT_KEEPS = transformer._READER_KEEPS = ()
+    try:
+        bare = jax.make_jaxpr(jax.grad(lambda p: cell.builder.build(
+            cell.config, cell.traffic).loss(p, state, tokens)[0]))(params)
+    finally:
+        transformer._REMAT_KEEPS, transformer._READER_KEEPS = kept
+    again = [what for what, inside in _work(bare.jaxpr) if inside]
+    assert again.count(fwd) == 2 and again.count(forward["mamba in"]) == 2
+    assert again.count(forward["k or v"]) == 4
+    assert again.count(forward["memory unit gate"]) == 1

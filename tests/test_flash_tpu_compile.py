@@ -81,34 +81,44 @@ def test_kernels_lower_for_v5e(one_chip, shape, dtype):
         assert "%" + name in text, name
 
 
-@pytest.mark.parametrize("seq,n_kv,head_dim,window", [
-    (8192, 4, 128, 2048), (8192, 4, 128, None), (16384, 8, 64, None)])
-def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, seq, n_kv,
-                                                    head_dim, window):
+@pytest.mark.parametrize("seq,n_q,n_kv,head_dim,d_v,window", [
+    (8192, 32, 4, 128, 128, 2048), (8192, 32, 4, 128, 128, None),
+    (16384, 32, 8, 64, 64, None),
+    (8192, 20, 10, 64, 128, 512), (8192, 20, 10, 64, 128, None)])
+def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, seq, n_q, n_kv,
+                                                    head_dim, d_v, window):
     """trinity-s8192-ep8-c1's attention, a sliding and a full layer: 32
-    query heads over 4 key/value heads of 128; and lfm2-s16384-ep4-c1's
-    one attention layer: 32 over 8 heads of 64 at twice the rows (the
-    dK/dV grid's float32 panels then pass the default scoped VMEM). K
-    and V enter all three Mosaic calls ``n_kv`` heads wide and dK/dV
-    leave ``n_kv`` heads wide (float32, the group's sum): nothing is
-    repeated to 32 heads in HBM; the three names once a layer."""
-    q = jax.ShapeDtypeStruct((1, seq, 32, head_dim), jnp.bfloat16,
+    query heads over 4 key/value heads of 128; lfm2-s16384-ep4-c1's one
+    attention layer: 32 over 8 heads of 64 at twice the rows (the dK/dV
+    grid's float32 panels then pass the default scoped VMEM); and
+    phi4flash-s8192-yoco-c1's maps, a sliding and a full layer: 20 over
+    10 heads, q.k 64 wide over a V of 128 (a differential pair's two
+    heads side by side). K and V enter all three Mosaic calls ``n_kv``
+    heads wide and dK/dV leave ``n_kv`` heads wide (float32, the
+    group's sum), each at its own width: nothing is repeated to the
+    query heads in HBM; the three names once a layer."""
+    q = jax.ShapeDtypeStruct((1, seq, n_q, head_dim), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, seq, n_kv, head_dim), jnp.bfloat16,
-                              sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, seq, n_kv, head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, seq, n_kv, d_v), jnp.bfloat16,
+                             sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, seq, n_q, d_v), jnp.bfloat16,
+                             sharding=one_chip)
 
     def step(q, k, v, g):
         out, vjp = jax.vjp(lambda q, k, v: flash_attention(
             q, k, v, causal=True, window=window, interpret=False), q, k, v)
         return (out,) + vjp(g)
 
-    text = jax.jit(step).lower(q, kv, kv, q).compile().as_text()
+    text = jax.jit(step).lower(q, k, v, g).compile().as_text()
     shape_of = dict(re.findall(r"%([\w.\-]+) = (\S+\[[\d,]*\])", text))
     calls = [line.strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 3
     narrow = "[1,%d,%d,%d]" % (n_kv, seq, head_dim)
-    wide = "[1,32,%d,%d]" % (seq, head_dim)
+    narrow_v = "[1,%d,%d,%d]" % (n_kv, seq, d_v)
+    wide = "[1,%d,%d,%d]" % (n_q, seq, head_dim)
     for name in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
                  introspect.KERNEL_FLASH_DQ):
         assert len(re.findall(r"^\s*(?:ROOT )?%%%s[.\d]* = " % name, text,
@@ -119,10 +129,13 @@ def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, seq, n_kv,
             r"%([\w.\-]+)", line.split(" custom-call(")[1].split("), ")[0])
         shapes = [shape_of[o] for o in operands]
         assert shapes[0] == "bf16" + wide, (name, shapes)
-        assert shapes[1] == shapes[2] == "bf16" + narrow, (name, shapes)
+        assert shapes[1] == "bf16" + narrow, (name, shapes)
+        assert shapes[2] == "bf16" + narrow_v, (name, shapes)
         if name.startswith(introspect.KERNEL_FLASH_DKV):
-            assert line.split(" = ")[1].startswith(
-                "(f32" + narrow), line[:200]
+            assert re.match(
+                r"\(f32%s\S*, f32%s" % (re.escape(narrow),
+                                        re.escape(narrow_v)),
+                line.split(" = ")[1]), line[:200]
     assert wide not in "".join(
         line for line in text.splitlines() if " broadcast(" in line)
 

@@ -192,10 +192,25 @@ def _tiles_moved(trace):
     return {key: n - before[key] for key, n in read().items()}
 
 
-def _trace_gradient(b, s, h, h_kv, d, window=None, selected=False, **tiles):
+def _calls_moved(trace):
+    """What ``trace()`` adds to hvd_flash_calls_total, by kernel and
+    widths; labels that did not move are left out."""
+    def read():
+        return {(e["labels"]["kernel"], e["labels"]["widths"]): e["value"]
+                for e in pallas_attention._M_CALLS.snapshot_values()}
+
+    before = read()
+    trace()
+    return {key: n - before.get(key, 0) for key, n in read().items()
+            if n != before.get(key, 0)}
+
+
+def _trace_gradient(b, s, h, h_kv, d, window=None, selected=False, d_v=None,
+                    **tiles):
     """Trace forward, dK/dV and dQ at the shape; nothing runs."""
     q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((b, s, h_kv, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, s, h_kv, d_v or d), jnp.bfloat16)
     select = None
     if selected:
         plane = jax.ShapeDtypeStruct((b, -(-s // (128 * 32)), s, 128),
@@ -207,7 +222,7 @@ def _trace_gradient(b, s, h, h_kv, d, window=None, selected=False, **tiles):
                                select=select,
                                **tiles).astype(jnp.float32).sum()
 
-    jax.eval_shape(jax.grad(loss, (0, 1, 2)), q, kv, kv, select)
+    return jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, kv, v, select)
 
 
 @pytest.mark.parametrize("shape,blocks,want", [
@@ -297,6 +312,133 @@ def test_no_tiles_given_takes_the_rule_at_the_cells_shapes(
     assert other != by_rule     # the counter tells tiles apart
 
 
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, inner jaxprs included,
+    as name -> (grid, block shapes of operands then results, result
+    shapes, scoped-VMEM limit or None)."""
+    found = {}
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                mapping = eqn.params["grid_mapping"]
+                params = eqn.params["compiler_params"].get("mosaic_tpu")
+                assert eqn.params["name"] not in found
+                found[eqn.params["name"]] = (
+                    tuple(mapping.grid),
+                    [tuple(getattr(dim, "block_size", None)
+                           for dim in spec.block_shape)
+                     for spec in mapping.block_mappings],
+                    [(aval.shape, aval.dtype.name)
+                     for aval in eqn.params["out_avals"]],
+                    params and params.vmem_limit_bytes)
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) \
+                        else (value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
+def _one_width_limit(panel_rows, d, block, out_rows=0, select_rows=0):
+    """The scoped-VMEM limit as the kernels reckoned it while they had
+    ONE width (two bf16 panels and, grouped, two float32 output panels,
+    all ``max(d, 128)`` lanes): what ``d_v == d`` must still give."""
+    panels = 2 * 2 * panel_rows * max(d, 128) * 2
+    panels += 2 * 2 * out_rows * max(d, 128) * 4
+    if select_rows:
+        panels += 2 * select_rows * 128 * 4 + 2 * 4 * block * block
+    need = panels + 6 * 4 * block * block + (2 << 20)
+    return None if need <= (16 << 20) else min(need, 100 << 20)
+
+
+@pytest.mark.parametrize("b,s,h,h_kv,d,window,selected,d_v", [
+    pytest.param(*case.values, case.values[4], id=case.id)
+    for case in _cell_attention_shapes()] + [
+    # The calls phi4flash's differential layers make: a pair's maps.
+    pytest.param(1, 8192, 20, 10, 64, 512, False, 128, id="pair-window"),
+    pytest.param(1, 8192, 20, 10, 64, None, False, 128, id="pair-full"),
+    pytest.param(1, 16384, 8, 2, 64, None, False, 256, id="past-16-MiB"),
+    pytest.param(1, 8192, 32, 4, 128, None, True, 64, id="select-narrow"),
+])
+def test_the_calls_by_their_shapes_and_limits(b, s, h, h_kv, d, window,
+                                              selected, d_v):
+    """The three ``pallas_call``s of a traced gradient at the cells'
+    shapes: grid, every block, every result and the scoped-VMEM limit.
+    With ``d_v == d`` they are what the kernels gave while one ``d``
+    built every spec (the limit by that rule, written out above): the
+    older cells' programs do not move. With another ``d_v`` the blocks
+    of v, dO, the output and dV alone take it, and the limit takes each
+    panel at its own width."""
+    calls = _pallas_calls(_trace_gradient(b, s, h, h_kv, d, window, selected,
+                                          d_v=d_v))
+    prefix = "hvd_dsa_" if selected else "hvd_flash_"
+    assert sorted(calls) == [prefix + k for k in ("dkv", "dq", "fwd")]
+    block, group = 512, h // h_kv
+    n, words = s // block, -(-s // 4096)
+    # Block shapes as the specs give them: None where a dimension is
+    # squeezed (batch and head; a bit plane has no head).
+    plane = [(None, words, block, 128)] if selected else []
+    (q_blk, k_blk, v_blk, q_pan, k_pan, v_pan, do_pan, col) = (
+        (None, None) + shape for shape in (
+            (block, d), (block, d), (block, d_v), (s, d), (s, d), (s, d_v),
+            (s, d_v), (block, 1)))
+    rows = (None, None, n, 1, block)
+
+    def limit(panel_d, panel_dv, out_rows=0, select_rows=0):
+        if d_v == d:
+            return _one_width_limit(s, d, block, out_rows, select_rows)
+        lanes = max(panel_d, 128) + max(panel_dv, 128)
+        need = 2 * s * lanes * 2 + 2 * out_rows * lanes * 4 + (
+            2 * select_rows * 128 * 4 + 2 * 4 * block * block
+            if select_rows else 0) + 6 * 4 * block * block + (2 << 20)
+        return None if need <= (16 << 20) else min(need, 100 << 20)
+
+    sel_rows = words * block if selected else 0
+    grid, blocks, results, vmem = calls[prefix + "fwd"]
+    assert grid == (b, h, n)
+    assert blocks == [q_blk, k_pan, v_pan] + plane + [v_blk, col]
+    assert results == [((b, h, s, d_v), "bfloat16"),
+                       ((b, h, s, 1), "float32")]
+    assert vmem == limit(d, d_v, select_rows=sel_rows)
+
+    grid, blocks, results, vmem = calls[prefix + "dq"]
+    assert grid == (b, h, n)
+    assert blocks == [q_blk, k_pan, v_pan, v_blk, col, col] + plane + [q_blk]
+    assert results == [((b, h, s, d), "bfloat16")]
+    assert vmem == limit(d, d_v, select_rows=sel_rows)
+
+    grid, blocks, results, vmem = calls[prefix + "dkv"]
+    if group == 1:
+        assert grid == (b, h, n)
+        outs, out_rows, kind = [k_blk, v_blk], 0, "bfloat16"
+    else:       # the key/value head's float32 panels stay resident
+        assert grid == (b, h_kv, group, n)
+        outs, out_rows, kind = [k_pan, v_pan], s, "float32"
+    assert blocks == [q_pan, k_blk, v_blk, do_pan, rows, rows] + plane + outs
+    assert results == [((b, h_kv, s, d), kind), ((b, h_kv, s, d_v), kind)]
+    assert vmem == limit(d, d_v, out_rows, sel_rows)
+
+
+def test_the_call_counter_tells_the_widths():
+    """hvd_flash_calls_total{kernel,widths} moves by one a traced
+    kernel: under ``"64"`` where v is as wide as q.k, under
+    ``"64+128"`` where it is not, and under no other label."""
+    names = ("hvd_flash_fwd", "hvd_flash_dkv", "hvd_flash_dq")
+    assert _calls_moved(lambda: _trace_gradient(1, 1024, 4, 2, 64)) \
+        == {(name, "64"): 1 for name in names}
+    assert _calls_moved(lambda: _trace_gradient(
+        1, 1024, 4, 2, 64, window=512, d_v=128)) \
+        == {(name, "64+128"): 1 for name in names}
+    assert _calls_moved(lambda: _trace_gradient(
+        1, 1024, 4, 2, 64, selected=True, d_v=32)) \
+        == {(name, "64+32"): 1
+            for name in ("hvd_dsa_fwd", "hvd_dsa_dkv", "hvd_dsa_dq")}
+
+
 @pytest.mark.parametrize("env,cached", [
     ({"HVD_FLASH_BLOCK_Q": "128", "HVD_FLASH_BLOCK_K": "256"}, False),
     ({"HVD_FLASH_TUNE": "1"}, False),
@@ -352,12 +494,14 @@ def test_pick_block_is_sublane_aligned(s, want, dtype, tile):
     assert _pick_block(s, want, dtype) == tile
 
 
-def test_short_ragged_bfloat16_matches_dense():
+@pytest.mark.parametrize("d_v", [64, 128, 32])
+def test_short_ragged_bfloat16_matches_dense(d_v):
     """S=100 in bf16: the tile is padded to 112 rows, the padded keys
-    masked and the padded query rows sliced off."""
+    masked and the padded query rows sliced off; v as wide as q.k,
+    twice as wide and half as wide."""
     rng = np.random.RandomState(7)
-    q, k, v = (jnp.asarray(rng.randn(1, 100, 2, 64), jnp.bfloat16)
-               for _ in range(3))
+    q, k, v = (jnp.asarray(rng.randn(1, 100, 2, d), jnp.bfloat16)
+               for d in (64, 64, d_v))
 
     def loss(fn):
         return lambda q, k, v: jnp.sum(
@@ -369,13 +513,13 @@ def test_short_ragged_bfloat16_matches_dense():
                                v.astype(jnp.float32), True)
 
     out = flash_attention(q, k, v, causal=True)
-    assert out.shape == (1, 100, 2, 64) and out.dtype == jnp.bfloat16
+    assert out.shape == (1, 100, 2, d_v) and out.dtype == jnp.bfloat16
     assert _rel(out.astype(jnp.float32), dense(q, k, v)) < 2e-2
     got = jax.grad(loss(lambda *a: flash_attention(*a, causal=True)),
                    (0, 1, 2))(q, k, v)
     ref = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
-    for a, b_ in zip(got, ref):
-        assert a.shape == (1, 100, 2, 64)
+    for a, b_, width in zip(got, ref, (64, 64, d_v)):
+        assert a.shape == (1, 100, 2, width) and a.dtype == jnp.bfloat16
         assert _rel(a.astype(jnp.float32), b_.astype(jnp.float32)) < 3e-2
 
 
@@ -457,6 +601,124 @@ def test_transformer_flash_matches_dense():
     assert _rel(out_flash, out_dense) < 1e-4
 
 
+# ------------------------------------ the caller that has two widths -----
+
+def _four_call_differential(self, q, k, v):
+    """``SelfAttention._differential`` as it stood while the kernels had
+    one width (PR 45): FOUR runs of ``_attend`` at half the heads, each
+    map over v's even and over its odd heads, the halves put side by
+    side afterwards."""
+    import math
+
+    from flax import linen as nn
+    from horovod_tpu.models import transformer
+
+    cfg, d = self.cfg, q.shape[-1]
+    lambda_init = 0.8 - 0.6 * math.exp(-0.3 * self.diff_layer)
+    diff = self.param(
+        "diff", lambda key, shape, dtype: jnp.concatenate([
+            nn.initializers.normal(0.1)(key, (4, d), dtype),
+            jnp.ones((2, d), dtype)]), (6, d), jnp.float32)
+    vectors, scale = diff[:4], diff[4:].reshape(2 * d)
+    (q1, q2), (k1, k2), (v1, v2) = (
+        (t[:, :, 0::2], t[:, :, 1::2]) for t in (q, k, v))
+    maps = [[transformer._attend(cfg, qi, ki, vj, self.window)
+             for vj in (v1, v2)] for qi, ki in ((q1, k1), (q2, k2))]
+    lq1, lk1, lq2, lk2 = vectors
+    lam = (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
+           + lambda_init)
+    first, second = (jnp.concatenate(pair, -1) for pair in maps)
+    out = transformer._differential_output(first, second, lam, lambda_init,
+                                           scale)
+    return out.astype(cfg.dtype).reshape(q.shape)
+
+
+def _differential_layer(attention, window):
+    from horovod_tpu.models import TransformerConfig
+    from horovod_tpu.models.transformer import BlockSpec, SelfAttention
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=8, n_layers=1, d_ff=64,
+        max_seq_len=1024, dtype=jnp.float32, attention=attention,
+        block=BlockSpec(head_dim=8, n_kv_heads=4, diff_attention=True))
+    return SelfAttention(cfg, window=window, diff_layer=3)
+
+
+@pytest.mark.parametrize("window", [None, 100])
+def test_differential_attention_in_two_calls(window, monkeypatch):
+    """A differential layer under ``attention="flash"`` (two calls, each
+    map over a V of two heads side by side) against the same layer under
+    ``"dense"`` and against the four-call form it replaces, written out
+    above: the output and every gradient, ``diff`` and the input
+    included; four pairs of query heads over two of key/value heads;
+    600 positions are two tiles of 384 with padded rows. The traced
+    layer counts two calls of each kernel, all at 8 + 16."""
+    from horovod_tpu.models.transformer import SelfAttention
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 600, 32))
+    weight = jax.random.normal(jax.random.PRNGKey(1), (2, 600, 32))
+    flash, dense = (_differential_layer(a, window)
+                    for a in ("flash", "dense"))
+    params = dense.init(jax.random.PRNGKey(2), x)
+    assert sorted(params["params"]) == ["diff", "wkv", "wo", "wq"]
+
+    def graded(layer):
+        def loss(params, x):
+            out = layer.apply(params, x)
+            return jnp.sum(out * weight), out
+        (_, out), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+            params, x)
+        return jax.tree_util.tree_leaves((out, grads))
+
+    two = graded(flash)
+    moved = _calls_moved(lambda: jax.make_jaxpr(jax.grad(
+        lambda p: jnp.sum(flash.apply(p, x))))(params))
+    assert moved == {(name, "8+16"): 2 for name in (
+        "hvd_flash_fwd", "hvd_flash_dkv", "hvd_flash_dq")}
+    by_dense = graded(dense)
+    monkeypatch.setattr(SelfAttention, "_differential",
+                        _four_call_differential)
+    four = graded(flash)
+    assert len(two) == 6        # the output, four leaves, the input
+    for got, want, parent in zip(two, by_dense, four):
+        assert got.shape == want.shape == parent.shape
+        assert _rel(got, want) < 1e-5
+        assert _rel(got, parent) < 1e-5
+
+
+def test_a_differential_pair_is_two_consecutive_heads():
+    """Pair i reads key/value heads 2 i and 2 i + 1 and no other: with
+    the output projection of the second pair's heads at zero, a change
+    to the weights that make v's head 2 or 3 leaves the layer's output
+    alone, and those of heads 1 and 2 swapped change it; under 'flash'
+    as under 'dense'."""
+    import dataclasses
+
+    from flax.core import meta
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 40, 32))
+    # Eight query heads over eight key/value heads: four pairs of each.
+    for attention in ("flash", "dense"):
+        layer = _differential_layer(attention, None)
+        layer = layer.clone(cfg=dataclasses.replace(
+            layer.cfg, block=dataclasses.replace(layer.cfg.block,
+                                                 n_kv_heads=0)))
+        params = meta.unbox(layer.init(jax.random.PRNGKey(3), x))["params"]
+        params = {**params, "wo": params["wo"].at[2:].set(0.0)}
+
+        def first_pair(wv):
+            wqkv = params["wqkv"].at[2].set(wv)
+            return np.asarray(layer.apply(
+                {"params": {**params, "wqkv": wqkv}}, x))
+
+        wv = params["wqkv"][2] * 50.0        # (M, H, D); the init is 0.02
+        sound = first_pair(wv)
+        assert (first_pair(wv.at[:, 2].add(1.0)) == sound).all()
+        assert (first_pair(wv.at[:, 3].add(1.0)) == sound).all()
+        swapped = first_pair(wv[:, jnp.array([0, 2, 1, 3, 4, 5, 6, 7])])
+        assert np.abs(swapped - sound).max() > 1e-2 * np.abs(sound).max()
+
+
 # ------------------------------- a window, and grouped key/value heads -----
 
 def masked_reference(q, k, v, window):
@@ -496,20 +758,29 @@ def _brute_force_window_tiles(q_len, kv_len, bq, bk, window):
     return num_qb, num_kb, kinds
 
 
-@pytest.mark.parametrize("q_len,kv_len", [(200, 200), (100, 200)])
-@pytest.mark.parametrize("group", [1, 4, 8])
-@pytest.mark.parametrize("window", [None, 1, 3, 64, 500])
-def test_window_and_grouped_heads_match_dense(window, group, q_len, kv_len):
+@pytest.mark.parametrize("window,group,q_len,kv_len,d_v", [
+    (window, group, q_len, kv_len, 16)
+    for q_len, kv_len in ((200, 200), (100, 200)) for group in (1, 4, 8)
+    for window in (None, 1, 3, 64, 500)] + [
+    # v twice and half as wide as q.k (16).
+    (window, group, 200, 200, d_v)
+    for d_v in (32, 8) for group in (1, 4) for window in (None, 1, 64, 500)
+] + [(64, 4, 100, 200, 32)])
+def test_window_and_grouped_heads_match_dense(window, group, q_len, kv_len,
+                                              d_v):
     """Forward and all three gradients against the dense masked
     attention: S no multiple of the 64 x 32 tiles, ``kv_len > q_len``,
-    K/V ``H // group`` heads wide going in and coming back; and the
-    tiles' classes and counts against the mask itself, by both walks."""
+    K/V ``H // group`` heads wide going in and coming back, v (and so
+    the output, dO and dV) ``d_v`` wide beside q.k's 16; and the tiles'
+    classes and counts against the mask itself, by both walks."""
+    from horovod_tpu.models.transformer import _dense_causal_attention
+
     bq, bk, heads, d = 64, 32, 8, 16
     rng = np.random.RandomState(7)
     q = jnp.asarray(rng.randn(1, q_len, heads, d), jnp.float32)
     k = jnp.asarray(rng.randn(1, kv_len, heads // group, d), jnp.float32)
-    v = jnp.asarray(rng.randn(1, kv_len, heads // group, d), jnp.float32)
-    weight = jnp.asarray(rng.randn(1, q_len, heads, d), jnp.float32)
+    v = jnp.asarray(rng.randn(1, kv_len, heads // group, d_v), jnp.float32)
+    weight = jnp.asarray(rng.randn(1, q_len, heads, d_v), jnp.float32)
 
     def flash(q, k, v):
         return flash_attention(q, k, v, causal=True, window=window,
@@ -523,6 +794,14 @@ def test_window_and_grouped_heads_match_dense(window, group, q_len, kv_len):
         assert got.shape == want.shape          # dK, dV: H // group heads
         assert float(jnp.max(jnp.abs(got - want))) \
             < 1e-5 * max(float(jnp.max(jnp.abs(want))), 1.0)
+    if q_len == kv_len:     # the model's own dense path, as the caller has it
+        dense, dense_vjp = jax.vjp(lambda q, k, v: _dense_causal_attention(
+            q, k, v, jnp.float32, window), q, k, v)
+        assert out.shape == dense.shape == (1, q_len, heads, d_v)
+        for got, want in zip((out,) + vjp(weight),
+                             (dense,) + dense_vjp(weight)):
+            assert float(jnp.max(jnp.abs(got - want))) \
+                < 1e-5 * max(float(jnp.max(jnp.abs(want))), 1.0)
 
     num_qb, num_kb, kinds = _brute_force_window_tiles(q_len, kv_len, bq, bk,
                                                       window)
@@ -570,6 +849,19 @@ def test_a_window_needs_causal_and_the_heads_have_to_divide():
         flash_attention(x, x, x, causal=True, window=0)
     with pytest.raises(ValueError, match="heads"):
         flash_attention(x, x[:, :, :3], x[:, :, :3])
+
+
+@pytest.mark.parametrize("v_shape", [
+    (1, 16, 2, 8), (1, 12, 4, 8), (2, 16, 4, 8), (1, 16, 4, 8, 1)],
+    ids=["heads", "length", "batch", "rank"])
+def test_v_differs_from_k_in_its_width_alone(v_shape):
+    """v may have another LAST dimension than k; before it, any
+    difference is refused."""
+    x = jnp.zeros((1, 16, 4, 8))
+    assert flash_attention(x, x, jnp.zeros((1, 16, 4, 24))).shape \
+        == (1, 16, 4, 24)
+    with pytest.raises(ValueError, match="up to their widths"):
+        flash_attention(x, x, jnp.zeros(v_shape))
 
 
 def test_tile_counter_and_log_line_carry_window_and_group(caplog):

@@ -118,17 +118,19 @@ def test_unpack_selection_undoes_pack_selection(shape):
         unpack_selection(pack_selection(mask), shape[2]), mask)
 
 
-@pytest.mark.parametrize("b,s,topk", [(1, 512, 128), (2, 1024, 256)])
-def test_the_flash_kernels_under_the_kernels_planes(b, s, topk):
+@pytest.mark.parametrize("b,s,topk,d_v", [
+    (1, 512, 128, 32), (2, 1024, 256, 32), (1, 512, 128, 64)])
+def test_the_flash_kernels_under_the_kernels_planes(b, s, topk, d_v):
     """``flash_attention(select=)`` over the planes ``choose`` made,
     against dense attention under ``learned_selection``'s mask: output
-    and the three gradients."""
+    and the three gradients; q.k 32 wide, v ``d_v`` (the masked kernels
+    go through the wrappers of the static ones: two widths there too)."""
     q_i, k_i, w_i = _indexer(b, s, ties=False, seed=5)
     kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(s), 4)
     q = jax.random.normal(kq, (b, s, 4, 32))
     k = jax.random.normal(kk, (b, s, 2, 32))
-    v = jax.random.normal(kv, (b, s, 2, 32))
-    g = jax.random.normal(kg, (b, s, 4, 32))
+    v = jax.random.normal(kv, (b, s, 2, d_v))
+    g = jax.random.normal(kg, (b, s, 4, d_v))
     planes = pallas_selection.choose(q_i, k_i, w_i, topk, CHUNK)
     mask = transformer.learned_selection(q_i, k_i, w_i, topk)
 

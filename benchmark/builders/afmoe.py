@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import optax
 from flax.core import meta
 
-from benchmark import flops_afmoe
+from benchmark import flops, flops_afmoe
 from benchmark.reference import afmoe as reference
 
 # What the CPU rehearsal and the CPU tests shrink. Widths change there
@@ -155,17 +155,15 @@ def build(config, traffic, block=None):
         return value, moe.updated_router_bias(
             state, stats["tokens_per_expert"], config["load_balance_coeff"])
 
-    def kernels(per_chip_batch):
-        """name -> (calls per step, ops per call, bytes per call) of the
-        Pallas kernels in one chip's step AS IT RUNS THEM: each kernel
-        once a layer (a recomputed block keeps the forward kernel's
-        output), and ONE (operations, bytes) for calls of two kinds: the
-        mean over the sliding and the full layers."""
-        work = flops_afmoe.mean_kernel_work(
-            per_chip_batch, seq_len, kinds, **{
+    def attention_work(per_chip_batch):
+        """What the attention of one chip's step REQUIRES, ``fwd`` and
+        ``bwd`` (``flops.attention_work``), summed over the layers: a
+        sliding layer its window's pairs, a full layer the causal pairs;
+        key/value panels ``n_kv`` heads wide."""
+        return flops.add_work(flops_afmoe.layer_attention_work(
+            per_chip_batch, seq_len, kind, **{
                 key: sizes[key] for key in ("n_head", "n_kv", "head_dim",
-                                            "window")})
-        return {name: (n_layer,) + work[name] for name in work}
+                                            "window")}) for kind in kinds)
 
     return SimpleNamespace(
         init=init, loss=loss, loss_and_stats=loss_and_stats, module=model,
@@ -179,4 +177,4 @@ def build(config, traffic, block=None):
         units_per_item=seq_len,
         step_ops=lambda batch: flops_afmoe.afmoe_step_ops(
             batch, seq_len, vocab=vocab, kinds=kinds, **sizes),
-        kernels=kernels)
+        attention_work=attention_work)

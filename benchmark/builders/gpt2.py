@@ -48,13 +48,14 @@ def build(config, traffic):
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, tokens[:, 1:]).mean(), state
 
-    def kernels(per_chip_batch):
-        """name -> (calls per step, ops per call, bytes per call) of the
-        Pallas kernels in one chip's step."""
-        work = flops.flash_kernel_work(
-            per_chip_batch, seq_len, sizes["n_head"],
-            sizes["d_model"] // sizes["n_head"])
-        return {name: (sizes["n_layer"],) + work[name] for name in work}
+    def attention_work(per_chip_batch):
+        """What the attention of one chip's step REQUIRES, ``fwd`` and
+        ``bwd`` (``flops.attention_work``), summed over the layers: the
+        causal pairs of every head."""
+        head = sizes["d_model"] // sizes["n_head"]
+        return flops.add_work(sizes["n_layer"] * [flops.attention_work(
+            flops.causal_pairs(seq_len), seq_len, batch=per_chip_batch,
+            n_head=sizes["n_head"], n_kv=sizes["n_head"], d=head, d_v=head)])
 
     return SimpleNamespace(
         init=init, loss=loss,
@@ -65,4 +66,4 @@ def build(config, traffic):
         pool_kwargs=dict(seq_len=seq_len),
         units_per_item=seq_len,
         step_ops=lambda batch: flops.gpt2_step_ops(batch, seq_len, **sizes),
-        kernels=kernels)
+        attention_work=attention_work)

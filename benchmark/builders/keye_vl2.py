@@ -170,7 +170,8 @@ def build(config, traffic, block=None):
         units_per_item=seq_len,
         step_ops=lambda batch: flops_keye.keye_step_ops(
             batch, seq_len, vocab=vocab, n_layer=n_layer, **sizes),
-        # No STATIC flash kernel runs in this step (the three by-name
-        # rooflines read those); the masked kernels' work is
-        # ``flops_keye.sparse_kernel_work``, read by ``dsa_view``.
-        kernels=lambda per_chip_batch: {})
+        # No STATIC flash kernel runs in this step (the ``kernel.flash_*``
+        # rooflines read those); what the masked kernels' layers require
+        # is ``flops.attention_work`` over the kept pairs, read by
+        # ``dsa_view``.
+        attention_work=lambda per_chip_batch: {})

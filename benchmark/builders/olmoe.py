@@ -97,12 +97,14 @@ def build(config, traffic):
     def loss(params, state, tokens):
         return loss_and_stats(params, tokens)[0], state
 
-    def kernels(per_chip_batch):
-        """name -> (calls per step, ops per call, bytes per call) of the
-        Pallas kernels in one chip's step."""
-        work = flops.flash_kernel_work(per_chip_batch, seq_len,
-                                       sizes["n_head"], sizes["head_dim"])
-        return {name: (n_layer,) + work[name] for name in work}
+    def attention_work(per_chip_batch):
+        """What the attention of one chip's step REQUIRES, ``fwd`` and
+        ``bwd`` (``flops.attention_work``), summed over the layers: the
+        causal pairs of every head."""
+        return flops.add_work(n_layer * [flops.attention_work(
+            flops.causal_pairs(seq_len), seq_len, batch=per_chip_batch,
+            n_head=sizes["n_head"], n_kv=sizes["n_head"],
+            d=sizes["head_dim"], d_v=sizes["head_dim"])])
 
     return SimpleNamespace(
         init=init, loss=loss, loss_and_stats=loss_and_stats, module=model,
@@ -114,4 +116,4 @@ def build(config, traffic):
         units_per_item=seq_len,
         step_ops=lambda batch: flops_moe.olmoe_step_ops(
             batch, seq_len, vocab=vocab, n_layer=n_layer, **sizes),
-        kernels=kernels)
+        attention_work=attention_work)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import optax
 from flax.core import meta
 
-from benchmark import flops_afmoe, flops_phi4flash
+from benchmark import flops, flops_afmoe, flops_phi4flash
 from benchmark.reference import phi4flash as reference
 
 # What the CPU rehearsal and the CPU tests shrink. Widths change there
@@ -123,20 +123,27 @@ def build(config, traffic, block=None):
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, tokens[:, 1:]).mean(), state
 
-    def kernels(per_chip_batch):
-        """name -> (calls per step, ops per call, bytes per call) of the
-        flash kernels in one chip's step AS IT RUNS THEM: a differential
-        layer calls each kernel FOUR times (two maps, each over both
-        halves of V) at half the heads, q.k and V both ``head_dim``
-        wide; the work a call is the mean over the attention layers, one
-        under the window."""
-        attention = [kind for kind in kinds
-                     if kind not in (reference.MAMBA, reference.MEMORY_UNIT)]
-        work = flops_afmoe.mean_kernel_work(
-            per_chip_batch, seq_len, attention, n_head=sizes["n_head"] // 2,
-            n_kv=sizes["n_kv"] // 2, head_dim=sizes["head_dim"],
-            window=window)
-        return {name: (4 * len(attention),) + work[name] for name in work}
+    def attention_work(per_chip_batch):
+        """What the attention of one chip's step REQUIRES, ``fwd`` and
+        ``bwd`` (``flops.attention_work``), summed over the attention
+        layers (sliding, full and cross; a Mamba layer and a memory unit
+        have no pairs). Differential attention is TWO softmax maps a
+        pair of heads: ``n_head / 2`` maps over ``n_kv / 2`` twice, each
+        q.k ``head_dim`` wide over a V of two heads side by side,
+        ``2 head_dim``: 2 x (64 + 128) a pair. The sliding layer keeps
+        its window's pairs; the cross layer's keys and values are the
+        published layer's, all causal pairs."""
+        def layer(kind):
+            pairs = flops_afmoe.window_pairs(
+                seq_len, window if kind == reference.SLIDING else None)
+            return flops.add_work(2 * [flops.attention_work(
+                pairs, seq_len, batch=per_chip_batch,
+                n_head=sizes["n_head"] // 2, n_kv=sizes["n_kv"] // 2,
+                d=sizes["head_dim"], d_v=2 * sizes["head_dim"])])
+
+        return flops.add_work(
+            layer(kind) for kind in kinds
+            if kind not in (reference.MAMBA, reference.MEMORY_UNIT))
 
     return SimpleNamespace(
         init=init, loss=loss, module=model,
@@ -149,4 +156,4 @@ def build(config, traffic, block=None):
         step_ops=lambda batch: flops_phi4flash.phi4flash_step_ops(
             batch, seq_len, vocab=vocab, kinds=kinds, window=window,
             **sizes),
-        kernels=kernels)
+        attention_work=attention_work)

@@ -59,4 +59,4 @@ def build(config, traffic):
                                   plan.batch_spec(1, seq_dim=None)),
         plan_kwargs={}, pool_kwargs={}, units_per_item=1,
         step_ops=lambda batch: flops.resnet_step_ops(batch, **sizes),
-        kernels=lambda per_chip_batch: {})
+        attention_work=lambda per_chip_batch: {})

@@ -1,10 +1,11 @@
 """Operations and bytes an algorithm needs, from shapes alone.
 
-The yardstick for ``model.mfu_pct`` and ``kernel.flash_roofline``: what
-the forward and backward passes REQUIRE, whatever the program computes
-to get there. A multiply-accumulate counts as 2 operations. Not counted:
-recomputation, the causally masked half of attention (nothing needs
-it), the optimizer's and the norms' elementwise passes, table lookups.
+The yardstick for ``model.mfu_pct`` and the ``kernel.flash_*``
+rooflines: what the forward and backward passes REQUIRE, whatever the
+program computes to get there and whatever kernels it calls. A
+multiply-accumulate counts as 2 operations. Not counted: recomputation,
+the causally masked half of attention (nothing needs it), the
+optimizer's and the norms' elementwise passes, table lookups.
 """
 
 from __future__ import annotations
@@ -43,21 +44,55 @@ def gpt2_step_ops(batch, seq_len, *, vocab, d_model, n_head, d_ff, n_layer):
     return 3 * batch * forward
 
 
-def flash_kernel_work(batch, seq_len, n_head, head_dim, itemsize=2):
-    """Per call of each of the three kernels of ops/pallas_attention.py:
-    (operations, HBM bytes) the kernel's own algorithm needs. Forward:
-    QK^T and PV. dK/dV: QK^T again, dV, dP, dK. dQ: QK^T again, dP, dQ.
-    Each matmul covers the visible pairs only. Bytes: every operand read
-    once and every result written once (bf16 panels, float32 log-sum-exp
-    and delta rows)."""
-    per_matmul = batch * n_head * 2 * causal_pairs(seq_len) * head_dim
-    panel = batch * n_head * seq_len * head_dim * itemsize
+def attention_work(pairs, seq_len, *, n_head, n_kv, d, d_v, batch=1,
+                   itemsize=2, plane_bytes=0):
+    """What ONE layer's attention REQUIRES of the chip, whatever kernels
+    compute it and however many: ``{"fwd": (operations, HBM bytes),
+    "bwd": (operations, HBM bytes)}`` of ``batch`` sequences of
+    ``seq_len``, ``n_head`` query heads over ``n_kv`` key/value heads,
+    q.k ``d`` wide and v ``d_v`` wide, each query head keeping ``pairs``
+    (query, key) pairs: ``causal_pairs``, a window's
+    (``flops_afmoe.window_pairs``) or a selection's ``sum_t min(t + 1,
+    topk)`` (``flops_keye.kept_pairs``).
+
+    Operations, one product = ``2 pairs width`` a query head. Forward,
+    TWO products: S = q k^T (``d``) and O = P v (``d_v``). Backward,
+    FIVE, the products any exact backward multiplies that keeps no
+    (S, S) array from the forward: S = q k^T AGAIN (``d``; P is remade
+    from it and the kept log-sum-exp row), dP = dO v^T (``d_v``),
+    dV = P^T dO (``d_v``), dK = dS^T q (``d``), dQ = dS k (``d``):
+    ``2 pairs (3 d + 2 d_v)``. A backward in two kernels that makes S
+    and dP once in each runs SEVEN; the two more are its own repetition
+    and count as time, not as work.
+
+    Bytes, every operand read once and every result written once by the
+    forward as a whole and by the backward as a whole: q, o, dO, dQ
+    ``n_head`` heads wide, k, v, dK, dV ``n_kv``; the float32
+    log-sum-exp row written forward and read backward; forward reads q,
+    k, v and writes o; backward reads q, k, v, o (its row sums with dO)
+    and dO and writes dQ, dK, dV; ``plane_bytes`` (a selection's bit
+    plane) once a direction."""
+    per_width = batch * n_head * 2 * pairs
+    q = batch * n_head * seq_len * d * itemsize          # also dQ
+    o = batch * n_head * seq_len * d_v * itemsize        # also dO
+    k = batch * n_kv * seq_len * d * itemsize            # also dK
+    v = batch * n_kv * seq_len * d_v * itemsize          # also dV
     row = batch * n_head * seq_len * 4
     return {
-        "fwd": (2 * per_matmul, 4 * panel + row),
-        "dkv": (4 * per_matmul, 6 * panel + 2 * row),
-        "dq": (3 * per_matmul, 5 * panel + 2 * row),
+        "fwd": (per_width * (d + d_v), q + k + v + o + row + plane_bytes),
+        "bwd": (per_width * (3 * d + 2 * d_v),
+                2 * (q + k + v + o) + row + plane_bytes),
     }
+
+
+def add_work(works):
+    """The sum of several layers' ``attention_work``, direction by
+    direction; ``{}`` of none. One (operations, bytes) for layers of
+    unlike masks is exact for the time at the roof while they sit under
+    the same roof (every layer in use is compute-bound)."""
+    works = list(works)
+    return {name: tuple(sum(work[name][i] for work in works) for i in (0, 1))
+            for name in (works[0] if works else ())}
 
 
 # --------------------------------------------------------- ResNet-50 ------

@@ -7,12 +7,12 @@ as ``flops_glm.py`` counts GLM's: at the balanced load.
 
 New here: a sliding-window layer keeps ``window_pairs`` of a full
 layer's ``causal_pairs``, and the key/value panels are ``n_kv`` heads
-wide where q's are ``n_head``.
+wide where q's are ``n_head`` (``flops.attention_work`` takes both).
 """
 
 from __future__ import annotations
 
-from benchmark.flops import causal_pairs, matmul_ops
+from benchmark.flops import attention_work, causal_pairs, matmul_ops
 from benchmark.flops_glm import expert_layer_forward_ops, swiglu_forward_ops
 
 SLIDING = "sliding_attention"
@@ -57,35 +57,11 @@ def afmoe_step_ops(batch, seq_len, *, vocab, kinds, window, n_dense,
     return 3 * batch * forward
 
 
-def flash_kernel_work(batch, seq_len, n_head, n_kv, head_dim, window=None,
-                      itemsize=2):
-    """Per call of each of the three kernels of ops/pallas_attention.py
-    under a window and with grouped key/value heads: (operations, HBM
-    bytes) the kernel's own algorithm needs, as ``flops.flash_kernel_work``
-    counts them. Each matmul covers the pairs the mask keeps. Bytes:
-    every operand read once and every result written once; q, o, dO and
-    dQ panels are ``n_head`` heads wide, k, v, dK and dV ``n_kv``."""
-    per_matmul = (batch * n_head * 2 * window_pairs(seq_len, window)
-                  * head_dim)
-    wide = batch * n_head * seq_len * head_dim * itemsize
-    narrow = batch * n_kv * seq_len * head_dim * itemsize
-    row = batch * n_head * seq_len * 4
-    return {
-        "fwd": (2 * per_matmul, 2 * wide + 2 * narrow + row),
-        "dkv": (4 * per_matmul, 2 * wide + 4 * narrow + 2 * row),
-        "dq": (3 * per_matmul, 3 * wide + 2 * narrow + 2 * row),
-    }
-
-
-def mean_kernel_work(batch, seq_len, kinds, *, n_head, n_kv, head_dim,
-                     window):
-    """The mean over the layers of ``kinds`` of ``flash_kernel_work``:
-    what ONE (operations, bytes) a call can say of kernels of two kinds.
-    Exact for the time at the roof while both kinds sit under the same
-    roof (both are compute-bound at every size in use)."""
-    per_layer = [flash_kernel_work(
-        batch, seq_len, n_head, n_kv, head_dim,
-        window if kind == SLIDING else None) for kind in kinds]
-    return {name: tuple(sum(work[name][i] for work in per_layer)
-                        / len(per_layer) for i in (0, 1))
-            for name in per_layer[0]}
+def layer_attention_work(batch, seq_len, kind, *, n_head, n_kv, head_dim,
+                         window):
+    """``flops.attention_work`` of ONE attention layer of ``kind``: the
+    pairs its mask keeps (a ``sliding_attention`` layer its window's),
+    q.k and v both ``head_dim`` wide."""
+    return attention_work(
+        window_pairs(seq_len, window if kind == SLIDING else None), seq_len,
+        batch=batch, n_head=n_head, n_kv=n_kv, d=head_dim, d_v=head_dim)

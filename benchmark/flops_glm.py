@@ -80,9 +80,9 @@ def held_expert_matmul_work(tokens, *, hidden, expert_width, k, held, routed,
     and once backward, their gradients written once; the live rows in
     and out forward, the rows and the output's gradient in and the
     rows' gradient out backward (bf16). Not counted: the (rows,
-    expert_width) intermediates, the dead rows of the full-length row
-    arrays, the recomputed forward. So the share of this roof cannot
-    pass 100%."""
+    expert_width) intermediates, the dead rows of the sorted-row arrays
+    (a prefix of the ``T k`` rows since PR 33), the recomputed forward.
+    So the share of this roof cannot pass 100%."""
     rows = held_rows(tokens, k, held, routed)
     ops = 3 * swiglu_forward_ops(rows, hidden, expert_width)
     panels = 3 * held * hidden * expert_width * weight_itemsize

@@ -15,7 +15,7 @@ scores ALL causal pairs, forward only: nothing differentiates it.
 from __future__ import annotations
 
 from benchmark.flops import causal_pairs, matmul_ops
-from benchmark.flops_afmoe import flash_kernel_work, window_pairs
+from benchmark.flops_afmoe import window_pairs
 from benchmark.flops_glm import expert_layer_forward_ops
 
 
@@ -63,17 +63,11 @@ def keye_step_ops(batch, seq_len, *, vocab, n_layer, hidden, n_head, n_kv,
                     + n_layer * indexer)
 
 
-def sparse_kernel_work(batch, seq_len, *, n_head, n_kv, head_dim, topk):
-    """Per call of each of the three masked kernels (``hvd_dsa_fwd`` /
-    ``_dkv`` / ``_dq``): (operations, HBM bytes) of the MATHEMATICS:
-    ``flops_afmoe.flash_kernel_work`` with the kept pairs in the place
-    of a window's, plus the bit plane that carries the selection in,
-    read once (``seq_len`` rows of ``ceil(seq_len / 4096)`` words of 128
-    lanes of int32)."""
-    plane = batch * seq_len * -(-seq_len // 4096) * 128 * 4
-    work = flash_kernel_work(batch, seq_len, n_head, n_kv, head_dim, topk)
-    return {name: (ops, nbytes + plane)
-            for name, (ops, nbytes) in work.items()}
+def plane_bytes(batch, seq_len):
+    """The bit plane that carries one layer's selection in, read once a
+    direction: ``seq_len`` rows of ``ceil(seq_len / 4096)`` words of 128
+    lanes of int32."""
+    return batch * seq_len * -(-seq_len // 4096) * 128 * 4
 
 
 def index_work(batch, seq_len, *, index_heads, index_dim, itemsize=2):

@@ -107,7 +107,10 @@ def step_spans(ctx):
     found = spans(ctx)
     if found is None:
         return None
-    name, newest = step_fun_name(ctx), {}
+    try:
+        name, newest = step_fun_name(ctx), {}
+    except (AttributeError, IndexError):   # no compiled step: no reader raises
+        return None
     for s in found:
         if s["name"].startswith(COMPILE) \
                 and s["args"].get("fun_name") == name:
@@ -134,7 +137,7 @@ def setup_compile_s(ctx):
         if s["name"].startswith(COMPILE):
             (apart if s["id"] in own or s["args"].get("fun_name") == BASELINE
              else rest).append(s)
-    return covered(_edges(rest), but=_edges(apart))
+    return covered(_edges(rest), but=_edges(apart)) if rest else None
 
 
 def cache_misses(ctx):

@@ -1,5 +1,6 @@
-"""Per step, what runs under ``hvd_flash`` outside the three Mosaic calls:
-pads, slices, the delta row sums, layout copies around the kernels."""
+"""Per step, what runs under ``hvd_flash`` outside the Mosaic calls
+named ``hvd_flash_*``: pads, slices, the delta row sums, layout copies
+around the kernels."""
 
 from benchmark import scope_view
 

@@ -1,17 +1,13 @@
-"""The three flash-attention kernels together: the least time the chip
-could take for the operations and bytes their algorithm needs
-(``flops.flash_kernel_work``, the larger of the two roofs per call)
-over the time they took in the trace. An earlier line gives each kernel
-alone and says which roof binds."""
+"""The flash-attention kernels together: the least time the chip could
+take for the work attention REQUIRES of the step, forward two products
+and backward five (``flops.attention_work``, the larger of the two
+roofs a direction), over the time of every ``hvd_flash_*`` call in the
+trace (``scope_view.kernel_roofline``). No call is counted and nothing
+is taken from a declared call count; an earlier line gives each kernel's
+time and each direction alone and says which roof binds."""
 
-from benchmark import flops
-from benchmark import trace_reduce as tr
+from benchmark import scope_view
 
 
 def read(ctx):
-    took = sum(e.seconds for e in ctx.win0.ops if tr.flash_kernel(e.name))
-    if not took or not ctx.kernels:
-        return None
-    least = sum(calls * flops.roofline_seconds(ops, nbytes, ctx.peak)[0]
-                for calls, ops, nbytes in ctx.kernels.values())
-    return 100.0 * least * ctx.n_steps / took
+    return scope_view.kernel_roofline(ctx)

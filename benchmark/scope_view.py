@@ -13,9 +13,9 @@ Phases: ``forward``, ``backward`` (a ``transpose(`` in the path),
 ``sync`` (``hvd_sync``), ``update`` (``hvd_update``, and the step's
 top-level arithmetic: ``optax.apply_updates``, which XLA fuses with the
 optimizer), ``unscoped``. Parts, disjoint: ``attn`` (the attention
-module outside ``hvd_flash``), ``flash_kernel`` (the three named Mosaic
-calls), ``flash_glue`` (the rest of ``hvd_flash``), ``mlp``, ``norm``,
-``conv``, ``bn``, ``head`` (``embed``, ``logits``, the classifier and
+module outside ``hvd_flash``), ``flash_kernel`` (the Mosaic calls named
+``hvd_flash_*``), ``flash_glue`` (the rest of ``hvd_flash``), ``mlp``,
+``norm``, ``conv``, ``bn``, ``head`` (``embed``, ``logits``, the classifier and
 the loss, which sits outside any module), ``other`` (a block's residual
 adds, pooling), ``sync_collective``, ``sync_pack``, ``update``,
 ``unscoped``.
@@ -39,8 +39,6 @@ from types import SimpleNamespace
 from benchmark import trace_reduce as tr
 
 PHASES = ("forward", "backward", "sync", "update", "unscoped")
-KERNELS = {"hvd_flash_fwd": "fwd", "hvd_flash_dkv": "dkv",
-           "hvd_flash_dq": "dq"}
 TOLERANCE = 0.01   # self-times against the busy union
 
 _WRAPPER = re.compile(r"^(jit|pjit|pmap|shard_map|xmap)\b")
@@ -68,8 +66,8 @@ def _path(scope):
 def classify(scope, event_name=""):
     """``(phase, part)`` of one instruction: ``scope`` is its ``op_name``
     (own or inherited), ``event_name`` its HLO text, which tells a
-    collective from the copies beside it and a Mosaic call from the
-    slices that feed it."""
+    collective from the copies beside it and a flash kernel (a Mosaic
+    call named ``hvd_flash_*``) from the slices that feed it."""
     path = _path(scope)
     if not path:
         return "unscoped", "unscoped"
@@ -84,9 +82,8 @@ def classify(scope, event_name=""):
     phase = ("backward" if any(s.startswith("transpose(") for s in transforms)
              else "forward")
     if "hvd_flash" in modules:
-        kernel = tr.is_mosaic_call(event_name) and any(
-            k in modules for k in KERNELS)
-        return phase, "flash_kernel" if kernel else "flash_glue"
+        return phase, ("flash_kernel" if tr.flash_kernel(event_name)
+                       else "flash_glue")
     for module in reversed(modules):
         for part, pattern in _MODULE_PARTS:
             if pattern.match(module):
@@ -152,7 +149,6 @@ def build(win, hlo_text, n_steps, log=_log):
         return None
     by_cell = defaultdict(float)      # (phase, part) -> ns
     by_scope = defaultdict(float)
-    kernels = {k: [0.0, 0] for k in KERNELS.values()}   # ns, calls
     remaining = defaultdict(float)    # unscoped instruction -> ns
     for event, own in zip(win.ops, self_times(win.ops)):
         name = tr.instruction_name(event.name)
@@ -160,19 +156,13 @@ def build(win, hlo_text, n_steps, log=_log):
         cell = classify(scope, event.name)
         by_cell[cell] += own
         by_scope[_scope_label(scope)] += own
-        if cell[1] == "flash_kernel":
-            short = next(v for k, v in KERNELS.items()
-                         if "/" + k + "/" in scope + "/")
-            kernels[short][0] += event.end - event.start
-            kernels[short][1] += 1
-        elif cell[0] == "unscoped":
+        if cell[0] == "unscoped":
             remaining[name] += own
     busy = tr.length(tr.spans(win.ops))
     total = sum(by_cell.values())
     per_step = 1e-9 / max(n_steps, 1)
     table = SimpleNamespace(
         cells={k: v * per_step for k, v in by_cell.items()},
-        kernels={k: (ns * 1e-9, calls) for k, (ns, calls) in kernels.items()},
         busy_s=busy * per_step, self_s=total * per_step,
         async_s=dict(tr.time_by(
             win.async_ops,
@@ -239,21 +229,27 @@ def unscoped_pct(ctx):
     return None if t is None else 100.0 * t.phase_s["unscoped"] / t.busy_s
 
 
-def kernel_roofline(ctx, kernel):
-    """One flash kernel, found by its name: the least time the chip could
-    take for its calls (``flops.roofline_seconds``) over the time they
-    took. None where the names and ``trace_reduce.flash_kernel``'s shapes
-    count different calls."""
+def kernel_roofline(ctx, directions=("fwd", "bwd")):
+    """The flash kernels against the work attention REQUIRES: the least
+    time the chip could take for the step's ``fwd`` and / or ``bwd``
+    work (``ctx.attention``: the builder's sum of
+    ``flops.attention_work`` over its layers) over the time of the calls
+    of those directions, found by name: ``hvd_flash_fwd`` is the
+    forward, every other ``hvd_flash_*`` the backward
+    (``trace_reduce.direction``). A backward of one kernel and one of
+    two read the same work; the calls are not counted. None where the
+    trace holds no such call or the builder states no attention."""
     from benchmark import flops
 
-    t = table(ctx)
-    if t is None or kernel not in ctx.kernels:
+    try:
+        work = [ctx.attention[d] for d in directions]
+        took = sum(s for kernel, (s, _)
+                   in tr.kernel_seconds(ctx.win0.ops).items()
+                   if tr.direction(kernel) in directions)
+    except (AttributeError, KeyError, TypeError):
         return None
-    took, calls = t.kernels[kernel]
-    by_shape = sum(1 for e in ctx.win0.ops
-                   if tr.flash_kernel(e.name) == kernel)
-    if not calls or calls != by_shape:
+    if not took:
         return None
-    _, ops, nbytes = ctx.kernels[kernel]
-    least, _ = flops.roofline_seconds(ops, nbytes, ctx.peak)
-    return 100.0 * least * calls / took
+    least = sum(flops.roofline_seconds(ops, nbytes, ctx.peak)[0]
+                for ops, nbytes in work)
+    return 100.0 * least * ctx.n_steps / took

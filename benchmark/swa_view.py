@@ -5,10 +5,11 @@ The same join as ``mla_view``: an ``XLA Ops`` event's instruction name
 -> its ``op_name`` in the compiled step -> the segments of that scope.
 Everything under a layer's ``attn`` module counts with its SELF-time
 (projections, head norms, RoPE, kernels and their glue, the gate, the
-output projection; forward, recomputed forward and backward). The three
-flash kernels' calls are told apart by the ``layer_<i>`` of their scope
-and the configuration's ``layer_types``: sliding layers' kernels skip
-the tiles below the window, full layers' do not.
+output projection; forward, recomputed forward and backward). The flash
+kernels' calls (Mosaic calls named ``hvd_flash_*``) are told apart by
+the ``layer_<i>`` of their scope and the configuration's
+``layer_types``: sliding layers' kernels skip the tiles below the
+window, full layers' do not.
 
 The gate has a scope of its own in the program (``hvd_attn_gate``,
 ``SCOPE_ATTN_GATE`` of ``horovod_tpu/jax/introspect.py``), but no metric
@@ -17,10 +18,12 @@ neighbouring projections' fusions, a fusion carries ONE name, and no
 instruction of the compiled step is left under the gate's (PERF.md
 section 7 (1)).
 
-The rooflines: the least time the chip could take for the pairs the
-MASK keeps (``flops_afmoe.window_pairs``) and for key/value panels
-``num_key_value_heads`` wide, over the time the kernels of that kind of
-layer took.
+The rooflines: the least time the chip could take for the work the
+layers of one kind REQUIRE (``flops.attention_work``: forward two
+products and backward five over the pairs the MASK keeps,
+``flops_afmoe.window_pairs``, key/value panels ``num_key_value_heads``
+wide), over the time the kernels of that kind of layer took, however
+many they are.
 
 A configuration without ``layer_types`` (every other cell's) gives
 nothing: every reader returns None and never raises.
@@ -41,8 +44,9 @@ _LAYER = re.compile(r"^layer_(\d+)$")
 
 
 def _times(ctx):
-    """{"attn": s, "kernels": {kind: {fwd|dkv|dq: (seconds, calls)}}} a
-    step; None for a configuration without ``layer_types``."""
+    """{"attn": s a step, "kernels": {kind: s a step}, "layers": {kind:
+    layers of the configuration}}; None for a configuration without
+    ``layer_types``."""
     if not hasattr(ctx, "_swa_times"):
         try:
             from benchmark.reference.afmoe import layer_kinds
@@ -51,8 +55,7 @@ def _times(ctx):
             kinds = layer_kinds(ctx.cell.config)
             scopes = introspect.instruction_scopes(ctx.hlo_text)
             attn = 0.0
-            kernels = {kind: {k: [0.0, 0] for k in scope_view.KERNELS.values()}
-                       for kind in (SLIDING, FULL)}
+            kernels = dict.fromkeys((SLIDING, FULL), 0.0)
             for event, own in zip(ctx.win0.ops,
                                   scope_view.self_times(ctx.win0.ops)):
                 path = scope_view._path(
@@ -60,19 +63,15 @@ def _times(ctx):
                 if MODULE not in path:
                     continue
                 attn += own
-                short = next((v for k, v in scope_view.KERNELS.items()
-                              if k in path), None)
                 layer = next((m for m in map(_LAYER.match, path) if m), None)
-                if short and layer and tr.is_mosaic_call(event.name):
-                    cell = kernels[kinds[int(layer.group(1))]][short]
-                    cell[0] += event.seconds
-                    cell[1] += 1
+                if layer and tr.flash_kernel(event.name):
+                    kernels[kinds[int(layer.group(1))]] += event.seconds
             per_step = 1.0 / max(ctx.n_steps, 1)
             ctx._swa_times = {
                 "attn": attn * 1e-9 * per_step,
-                "kernels": {kind: {k: (s * per_step, n) for k, (s, n)
-                                   in by_kernel.items()}
-                            for kind, by_kernel in kernels.items()}}
+                "kernels": {kind: s * per_step
+                            for kind, s in kernels.items()},
+                "layers": {kind: kinds.count(kind) for kind in kernels}}
         except Exception as e:   # noqa: BLE001 - a reader never raises
             scope_view._log("swa view failed: %s: %s"
                             % (type(e).__name__, e))
@@ -87,36 +86,32 @@ def attn_ms(ctx):
 
 
 def kernels_ms(ctx, kind):
-    """Milliseconds a step in the three flash kernels of the layers of
+    """Milliseconds a step in the flash kernels of the layers of
     ``kind``; None where the trace holds no such call."""
     times = _times(ctx)
-    if times is None:
-        return None
-    took = sum(s for s, _ in times["kernels"][kind].values())
-    return 1e3 * took or None
+    return None if times is None else 1e3 * times["kernels"][kind] or None
 
 
 def kernels_roofline(ctx, kind):
-    """The least time for the calls of ``kind``'s kernels that the trace
-    holds, by ``flops_afmoe.flash_kernel_work`` under that kind's mask,
-    over the time they took."""
+    """The least time for what the configuration's layers of ``kind``
+    REQUIRE, forward and backward
+    (``flops_afmoe.layer_attention_work``), over the time their flash
+    kernels took."""
     from benchmark import flops, flops_afmoe
 
-    times = _times(ctx)
     took_ms = kernels_ms(ctx, kind)
     if not took_ms:
         return None
     try:
         config, traffic = ctx.cell.config, ctx.cell.traffic
-        work = flops_afmoe.flash_kernel_work(
-            int(traffic["per_chip_batch"]), int(traffic["seq_len"]),
-            config["num_attention_heads"], config["num_key_value_heads"],
-            config["head_dim"],
-            config["sliding_window"] if kind == SLIDING else None)
-        least = sum(
-            calls / max(ctx.n_steps, 1)
-            * flops.roofline_seconds(*work[name], ctx.peak)[0]
-            for name, (_, calls) in times["kernels"][kind].items())
+        sizes = ctx.cell.builder.sizes_of(config)
+        work = flops_afmoe.layer_attention_work(
+            int(traffic["per_chip_batch"]), int(traffic["seq_len"]), kind,
+            **{key: sizes[key] for key in ("n_head", "n_kv", "head_dim",
+                                           "window")})
+        least = _times(ctx)["layers"][kind] * sum(
+            flops.roofline_seconds(*work[direction], ctx.peak)[0]
+            for direction in work)
         scope_view._log("flash kernels of %s layers: %.3f ms a step, %.3f "
                         "ms at the roof" % (kind, took_ms, 1e3 * least))
         return 100.0 * 1e3 * least / took_ms

@@ -139,18 +139,30 @@ def test_no_chip_means_no_result(copy):
     assert "needs 1 TPU chip" in run.stderr
 
 
-def test_trace_view_on_the_recorded_trace():
+def test_trace_view_on_the_recorded_trace(monkeypatch):
     """``trace_view.build`` and every reader that needs no collective,
-    on the small recorded trace with a stand-in for the assembled cell."""
+    on the small recorded trace (its Mosaic calls under the names a
+    program of today gives them: ``test_scope_view.named``) with a
+    stand-in for the assembled cell."""
     sys.path.insert(0, ROOT)
-    from benchmark import trace_view
+    from benchmark import trace_reduce, trace_view
     from benchmark.layer_metrics import reader
+    from benchmark.tests.test_scope_view import named
 
+    load = trace_reduce.load
+
+    def load_named(path):
+        trace = load(path)
+        return trace._replace(devices={
+            chip: {line: [e._replace(name=named(e.name)) for e in events]
+                   for line, events in lines.items()}
+            for chip, lines in trace.devices.items()})
+
+    monkeypatch.setattr(trace_reduce, "load", load_named)
     peak = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
-    kernels = {"fwd": (1, 4.2e6, 1.3e5), "dkv": (1, 8.4e6, 2e5),
-               "dq": (1, 6.3e6, 1.7e5)}
+    attention = {"fwd": (4.2e6, 1.3e5), "bwd": (10.5e6, 2.9e5)}
     asm = SimpleNamespace(
-        model=SimpleNamespace(kernels=lambda b: kernels,
+        model=SimpleNamespace(attention_work=lambda b: attention,
                               step_ops=lambda b: 1e9),
         per_chip_batch=1, global_batch=1, units_per_step=256, plan=None)
     xplane = os.path.join(ROOT, "benchmark", "tests", "data",
@@ -165,17 +177,21 @@ def test_trace_view_on_the_recorded_trace():
     assert len(ctx.breakdown["device_ops"]) == 10
     assert ctx.breakdown["device_ops"][0][0] == "fusion.1"
     assert len(ctx.breakdown["idle_gaps"]) == 5
-    assert any("kernel flash dkv" in ln for ln in lines)
+    assert any("kernel flash dkv: 1 calls a step" in ln for ln in lines)
+    assert any("attention bwd, required" in ln for ln in lines)
     got = {name: reader(name)(ctx) for name in (
         "device.idle_pct", "device.peak_hbm_gb", "model.step_device_ms",
         "model.mfu_pct", "kernel.flash_share_pct", "kernel.flash_roofline",
+        "kernel.flash_fwd_roofline", "kernel.flash_bwd_roofline",
         "sync.collective_ms", "sync.exposed_ms", "dp.scaling_eff_pct",
         "images.model.mfu_pct")}
     assert got["device.idle_pct"] == pytest.approx(99.53, abs=0.01)
     assert got["device.peak_hbm_gb"] == 10.0
     assert got["model.step_device_ms"] == pytest.approx(0.0137, abs=1e-4)
     assert got["kernel.flash_share_pct"] == pytest.approx(40.2, abs=0.1)
-    assert 0 < got["kernel.flash_roofline"] < 100
+    assert 0 < got["kernel.flash_fwd_roofline"] < 100
+    assert 0 < got["kernel.flash_bwd_roofline"] \
+        < got["kernel.flash_roofline"] < got["kernel.flash_fwd_roofline"]
     assert got["images.model.mfu_pct"] == got["model.mfu_pct"]
     assert got["sync.collective_ms"] is None
     assert got["sync.exposed_ms"] is None

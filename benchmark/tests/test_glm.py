@@ -597,15 +597,18 @@ def test_the_step_of_the_share_by_hand():
     assert 0.43 < 3 * 5 * attention / ops < 0.45
     assert 0.08 < 3 * head / ops < 0.09
     assert 0.035 < 3 * 4 * held / ops < 0.045
-    # The kernels as the step runs them: the forward twice a layer.
+    # What attention REQUIRES of the step: ONE forward a layer, recomputed
+    # or not (two products over the causal pairs), and a backward of five.
     model = builder.build(config, {"seq_len": s, "remat": True})
-    kernels = model.kernels(1)
-    assert {k: v[0] for k, v in kernels.items()} == {
-        "fwd": 10, "dkv": 5, "dq": 5}
-    assert kernels["fwd"][1:] == flops.flash_kernel_work(1, s, h, 256)["fwd"]
-    assert kernels["fwd"][1] == 2 * h * 2 * pairs * 256
-    once = builder.build(config, {"seq_len": s, "remat": False}).kernels(1)
-    assert once["fwd"][0] == 5
+    work = model.attention_work(1)
+    assert work["fwd"][0] == 5 * attention
+    assert work["bwd"][0] == 5 * h * 2 * pairs * 5 * 256
+    once = builder.build(config, {"seq_len": s, "remat": False})
+    assert once.attention_work(1) == work
+    # The fossil ``tests/test_flash_tpu_compile.py`` holds: read by no
+    # metric, the declaration PR 31 made stale.
+    assert model.kernels(1) == {"fwd": (10,), "dkv": (5,), "dq": (5,)}
+    assert once.kernels(1)["fwd"] == (5,)
 
 
 def test_held_expert_matmul_work_by_hand():

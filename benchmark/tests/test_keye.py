@@ -38,7 +38,8 @@ from benchmark.reference import keye_vl2 as reference
 from benchmark.tests.test_lfm2 import _matmuls
 from benchmark.tests.test_olmoe import _leaf_distances, _rel
 from benchmark.tests.test_reference import _compare
-from benchmark.tests.test_scope_view import RECORDED_STEP, _ctx
+from benchmark.tests.test_scope_view import (AS_RECORDED, NAMED,
+                                             RECORDED_STEP, _ctx, named)
 from benchmark.tests.test_trinity import _seen
 
 CELL = "keye-s8192-dsa-ep8-c1"
@@ -881,20 +882,24 @@ def test_the_step_of_the_share_by_hand():
     assert ops == 11_911_311_654_912
     assert 3 * 5 * h * 4 * kept * hd == pytest.approx(3.61e12, rel=5e-3)
     assert 5 * indexer == pytest.approx(0.53e12, rel=2e-2)
-    assert model.kernels(1) == {}      # no STATIC flash kernel in the step
-    # The masked kernels' mathematics: the window's count, plus a plane.
-    work = flops_keye.sparse_kernel_work(1, s, n_head=h, n_kv=kv,
-                                         head_dim=hd, topk=topk)
-    plane = s * 2 * 128 * 4
-    assert plane == 8_388_608
-    swa = flops_afmoe.flash_kernel_work(1, s, h, kv, hd, 2048)
+    # No STATIC flash kernel in the step: the builder states no attention
+    # for the ``kernel.flash_*`` readers.
+    assert model.attention_work(1) == {}
+    # What the masked kernels' layer REQUIRES: a window's count of pairs,
+    # two products forward and five backward, plus a plane a direction.
+    plane = flops_keye.plane_bytes(1, s)
+    assert plane == s * 2 * 128 * 4 == 8_388_608
+    work = flops.attention_work(flops_keye.kept_pairs(s, topk), s, n_head=h,
+                                n_kv=kv, d=hd, d_v=hd, plane_bytes=plane)
+    swa = flops_afmoe.layer_attention_work(
+        1, s, flops_afmoe.SLIDING, n_head=h, n_kv=kv, head_dim=hd,
+        window=2048)
     assert work == {name: (o, n + plane) for name, (o, n) in swa.items()}
     assert work["fwd"][0] == 2 * h * 2 * kept * hd
-    assert work["dkv"][0] == 2 * work["fwd"][0]
-    assert 2 * work["dq"][0] == 3 * work["fwd"][0]
+    assert 2 * work["bwd"][0] == 5 * work["fwd"][0]
     peak = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
     least = sum(flops.roofline_seconds(*w, peak)[0] for w in work.values())
-    assert least == pytest.approx(5.49e-3, rel=1e-2)
+    assert least == pytest.approx(5.49e-3 * 7 / 9, rel=1e-2)
     # The indexer and the selection of one layer.
     index_ops, index_bytes = flops_keye.index_work(
         1, s, index_heads=16, index_dim=64)
@@ -943,10 +948,12 @@ def test_the_scope_constants_are_what_the_layers_set():
     assert (introspect.SCOPE_DSA_INDEX, introspect.SCOPE_DSA_SELECT) == (
         dsa_view.INDEX, dsa_view.SELECT) == (
         "hvd_dsa_index", "hvd_dsa_select")
-    assert sorted(dsa_view.KERNELS) == sorted((
+    assert sorted(dsa_view.MASKED + k for k in ("fwd", "dkv", "dq")) == sorted((
         introspect.KERNEL_DSA_FWD, introspect.KERNEL_DSA_DKV,
         introspect.KERNEL_DSA_DQ)) == [
         "hvd_dsa_dkv", "hvd_dsa_dq", "hvd_dsa_fwd"]
+    assert dsa_view.MASKED + dsa_view.CHOOSE \
+        == introspect.KERNEL_DSA_CHOOSE == "hvd_dsa_choose"
     assert introspect.SAVED_FLASH_SELECT == "hvd_flash_select"
     cell, model, params, state, tokens = _assembled("float32")
     grad = jax.grad(lambda p: model.loss(p, state, tokens)[0])
@@ -965,15 +972,21 @@ def test_the_scope_constants_are_what_the_layers_set():
         assert name not in text, name
 
 
+# The recorded Mosaic calls under the masked kernels' names.
+MASKED = {old: new.replace("hvd_flash_", "hvd_dsa_")
+          for old, new in NAMED.items()}
+
+
 def _sparse_step():
     """The recorded step as a sparse model would name it: the three
-    kernels under their masked names (a fourth and a seventh operand),
-    the attention's transpose as the indexer's work, the feed-forward's
-    forward matmul as the selection's."""
-    step = RECORDED_STEP
+    kernels under their masked names (and a fourth and a seventh
+    operand, which decide nothing), the attention's transpose as the
+    indexer's work, the feed-forward's forward matmul as the
+    selection's."""
+    step = AS_RECORDED
     for kernel in ("fwd", "dkv", "dq"):
         step = step.replace("hvd_flash_" + kernel, "hvd_dsa_" + kernel)
-    step = step.replace(
+    step = named(step, MASKED).replace(
         "custom-call(%copy.6, %copy.6, %copy.6)",
         "custom-call(%copy.6, %copy.6, %copy.6, %copy.6)").replace(
         "%transpose.10, %copy.4, %copy.4)",
@@ -983,17 +996,25 @@ def _sparse_step():
         "jvp(Transformer)/layer_0/mlp/dot_general",
         "jvp(Transformer)/layer_0/attn/hvd_dsa_select/ge", 1)
     assert step.count("hvd_dsa_index") == step.count("hvd_dsa_select") == 1
+    assert step.count("%hvd_dsa_dkv.2 = ") == 1
     return step
 
 
-def test_no_new_mosaic_call_reads_as_a_static_flash_kernel():
-    """``trace_reduce.flash_kernel`` takes a Mosaic call of 3 or 6
-    operands for a static kernel: the masked ones carry 4 and 7."""
-    calls = [line.strip() for line in _sparse_step().splitlines()
-             if tr.is_mosaic_call(line)]
-    assert len(calls) == 3
-    for line in calls:
-        assert tr.flash_kernel(line) == "", line
+def test_no_masked_mosaic_call_reads_as_a_static_flash_kernel():
+    """``trace_reduce.flash_kernel`` tells a static flash kernel by its
+    NAME: the masked ones are ``hvd_dsa_*``, with 4 and 7 operands or
+    with 3 and 6."""
+    from benchmark import dsa_view
+
+    for step in (_sparse_step(),
+                 _sparse_step().replace(", %copy.6)", ")")):
+        calls = [line.strip() for line in step.splitlines()
+                 if tr.is_mosaic_call(line)]
+        assert len(calls) == 3
+        for line in calls:
+            assert tr.flash_kernel(line) == "", line
+        assert sorted(tr.named_kernel(line, dsa_view.MASKED)
+                      for line in calls) == ["dkv", "dq", "fwd"]
     before = [line.strip() for line in RECORDED_STEP.splitlines()
               if tr.is_mosaic_call(line)]
     assert sorted(map(tr.flash_kernel, before)) == ["dkv", "dq", "fwd"]
@@ -1002,13 +1023,13 @@ def test_no_new_mosaic_call_reads_as_a_static_flash_kernel():
 def test_the_new_readers_on_the_recorded_trace(capsys):
     names = ("dsa.attn_ms", "dsa.index_ms", "dsa.select_ms", "dsa.sparse_ms",
              "dsa.sparse_roofline", "dsa.index_roofline")
-    ctx = _ctx(_sparse_step())
+    ctx = _ctx(_sparse_step(), MASKED)
     ctx.cell = cells.load(CELL)
     got = {name: reader(name)(ctx) for name in names}
     assert all(v is not None and v > 0 for v in got.values()), got
     # The attention module: the three kernels, their glue, the indexer
     # and the selection (scope_view files a masked kernel under the
-    # glue: it knows the static kernels' names alone).
+    # glue: a flash KERNEL to it is a call named ``hvd_flash_*``).
     assert got["dsa.attn_ms"] == pytest.approx(sum(
         scope_view.part_ms(ctx, part) for part in ("attn", "flash_glue")))
     assert got["dsa.index_ms"] + got["dsa.select_ms"] + got["dsa.sparse_ms"] \
@@ -1016,18 +1037,37 @@ def test_the_new_readers_on_the_recorded_trace(capsys):
     from benchmark import dsa_view
 
     times = dsa_view._times(ctx)
-    assert {k: calls for k, (_, calls) in times["kernels"].items()} == {
-        "fwd": ctx.n_steps, "dkv": ctx.n_steps, "dq": ctx.n_steps}
-    work = flops_keye.sparse_kernel_work(1, 8192, n_head=32, n_kv=4,
-                                         head_dim=128, topk=2048)
-    least = sum(flops.roofline_seconds(*w, ctx.peak)[0]
-                for w in work.values())
+    assert sorted(times["kernels"]) == ["dkv", "dq", "fwd"]
+    assert got["dsa.sparse_ms"] == pytest.approx(
+        1e3 * sum(times["kernels"].values()))
+    # Against what the FIVE layers of the configuration require over the
+    # kept pairs, forward and backward, whatever calls the trace holds.
+    work = flops.attention_work(
+        flops_keye.kept_pairs(8192, 2048), 8192, n_head=32, n_kv=4, d=128,
+        d_v=128, plane_bytes=flops_keye.plane_bytes(1, 8192))
+    least = 5 * sum(flops.roofline_seconds(*w, ctx.peak)[0]
+                    for w in work.values())
     assert got["dsa.sparse_roofline"] == pytest.approx(
         100 * 1e3 * least / got["dsa.sparse_ms"])
+    # One backward kernel in the place of two, of equal time, and the
+    # selection's own call beside them: the same.
+    fused = _ctx(_sparse_step().replace("hvd_dsa_dkv", "hvd_dsa_bwd"
+                                        ).replace("hvd_dsa_dq", "hvd_dsa_bwd"),
+                 {old: new.replace("dkv", "bwd").replace("dq", "bwd")
+                  for old, new in MASKED.items()})
+    fused.cell = ctx.cell
+    assert sorted(dsa_view._times(fused)["kernels"]) == ["bwd", "fwd"]
+    assert reader("dsa.sparse_roofline")(fused) == pytest.approx(
+        got["dsa.sparse_roofline"])
+    chosen = _ctx(_sparse_step().replace(
+        "hvd_dsa_dq", "hvd_dsa_choose"),
+        dict(MASKED, **{"transpose_jvp___.3": "hvd_dsa_choose.3"}))
+    chosen.cell = ctx.cell
+    assert sorted(dsa_view._times(chosen)["kernels"]) == ["dkv", "fwd"]
     index = flops.roofline_seconds(*flops_keye.index_work(
         1, 8192, index_heads=16, index_dim=64), ctx.peak)[0]
     assert got["dsa.index_roofline"] == pytest.approx(
-        100 * 1e3 * index / (got["dsa.index_ms"] + got["dsa.select_ms"]))
+        100 * 1e3 * 5 * index / (got["dsa.index_ms"] + got["dsa.select_ms"]))
     logged = capsys.readouterr().err
     assert "masked kernels" in logged and "indexer and selection" in logged
     # The PARENT's program (the static kernels' names, no indexer), every
@@ -1045,12 +1085,12 @@ def test_the_new_readers_on_the_recorded_trace(capsys):
         assert reader(name)(trinity) is None, name
         assert reader(name)(broken) is None, name
     # A step whose compiler left nothing under the selection's scope.
-    fused = _ctx(_sparse_step().replace("hvd_dsa_select/", ""))
-    fused.cell = cells.load(CELL)
-    assert reader("dsa.select_ms")(fused) is None
-    assert reader("dsa.index_ms")(fused) == pytest.approx(
+    bare = _ctx(_sparse_step().replace("hvd_dsa_select/", ""), MASKED)
+    bare.cell = cells.load(CELL)
+    assert reader("dsa.select_ms")(bare) is None
+    assert reader("dsa.index_ms")(bare) == pytest.approx(
         got["dsa.index_ms"])
-    assert reader("dsa.attn_ms")(fused) == pytest.approx(got["dsa.attn_ms"])
+    assert reader("dsa.attn_ms")(bare) == pytest.approx(got["dsa.attn_ms"])
 
 
 def test_the_metrics_of_the_cell():
@@ -1071,10 +1111,12 @@ def test_the_metrics_of_the_cell():
             "launch.compile_s", "launch.cache_misses"} <= mine
     assert not mine & {
         "kernel.flash_roofline", "kernel.flash_fwd_roofline",
-        "kernel.flash_dkv_roofline", "kernel.flash_dq_roofline",
+        "kernel.flash_bwd_roofline", "kernel.flash_dkv_roofline",
+        "kernel.flash_dq_roofline",
         "kernel.flash_share_pct", "kernel.flash_glue_ms", "moe.shared_ms",
         "moe.experts_roofline", "mla.attn_ms", "swa.attn_ms",
-        "conv.mixer_ms", "sync.collective_ms"}
+        "conv.mixer_ms", "sync.collective_ms", "ssm.mixer_ms",
+        "yoco.attn_ms"}
     dsa = [m for m in cell.bench["per_layer"]
            if m["name"].startswith("dsa.")]
     assert len(dsa) == 6 and {m["name"] for m in dsa} <= mine
@@ -1084,11 +1126,12 @@ def test_the_metrics_of_the_cell():
                and os.path.exists(os.path.join(
                    ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
                for m in dsa)
-    # Nine cells, one of them on four chips; seven configurations.
-    assert len(cell.bench["workloads"]) == 9
-    assert sum(w["chips"] == 4 for w in cell.bench["workloads"]) == 1
-    assert len(cell.bench["configs"]) == 7
-    assert cell.bench["workloads"][-1]["name"] == CELL
+    # Nine cells or more (later PRs add theirs), one of them or more on
+    # four chips; seven configurations or more; this cell the ninth.
+    assert len(cell.bench["workloads"]) >= 9
+    assert sum(w["chips"] == 4 for w in cell.bench["workloads"]) >= 1
+    assert len(cell.bench["configs"]) >= 7
+    assert cell.bench["workloads"][8]["name"] == CELL
 
 
 def test_the_defects_own_rehearsal_pieces():

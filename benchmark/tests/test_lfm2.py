@@ -705,17 +705,17 @@ def test_the_step_of_the_share_by_hand():
     assert 3 * 4 * conv == pytest.approx(6.6e12, rel=5e-3)
     assert 3 * h * 4 * pairs * hd == pytest.approx(3.3e12, rel=5e-3)
     assert 3 * (dense + 4 * held) == pytest.approx(8.66e12, rel=5e-3)
-    # The kernels as the step runs them: each ONCE (one attention
-    # layer), K/V panels 8 heads wide.
-    kernels = model.kernels(1)
-    assert {k: v[0] for k, v in kernels.items()} == {
-        "fwd": 1, "dkv": 1, "dq": 1}
-    work = flops_afmoe.flash_kernel_work(1, s, h, kv, hd)
-    assert {k: v[1:] for k, v in kernels.items()} == work
+    # What attention REQUIRES of the step: ONE attention layer's causal
+    # pairs, two products forward and five backward, K/V panels 8 heads
+    # wide (a conv layer has no pairs).
+    work = model.attention_work(1)
     wide, narrow, row = h * s * hd * 2, kv * s * hd * 2, h * s * 4
     assert work["fwd"] == (2 * h * 2 * pairs * hd,
                            2 * wide + 2 * narrow + row)
-    assert work["dkv"][1] == 2 * wide + 4 * narrow + 2 * row
+    assert work["bwd"] == (5 * h * 2 * pairs * hd,
+                           4 * wide + 4 * narrow + row)
+    # The fossil ``tests/test_flash_tpu_compile.py`` reads, and no metric.
+    assert model.kernels(1) == dict.fromkeys(("fwd", "dkv", "dq"), (1,))
     # The gates and taps of one conv layer: 11 widths of bf16 rows and
     # the taps' own gradient; the memory roof binds by far.
     gate_ops, gate_bytes = flops_lfm2.conv_gate_work(s, d, 3)
@@ -860,14 +860,15 @@ def test_the_metrics_of_the_cell():
     assert {"conv.mixer_ms", "conv.attn_ms", "moe.held_roofline",
             "moe.layer_ms", "moe.experts_ms", "moe.dispatch_ms",
             "kernel.flash_roofline", "kernel.flash_fwd_roofline",
-            "kernel.flash_dkv_roofline", "kernel.flash_dq_roofline",
+            "kernel.flash_bwd_roofline",
             "kernel.flash_share_pct", "kernel.flash_glue_ms",
             "model.mfu_pct", "model.step_device_ms", "model.head_ms",
             "device.peak_hbm_gb", "device.idle_pct", "device.unscoped_pct",
             "launch.compile_s", "launch.cache_misses"} <= mine
     assert not mine & {"moe.shared_ms", "moe.experts_roofline",
                        "mla.attn_ms", "swa.attn_ms", "swa.full_ms",
-                       "sync.collective_ms"}
+                       "sync.collective_ms", "dsa.attn_ms", "dsa.sparse_ms",
+                       "ssm.mixer_ms", "yoco.attn_ms"}
     conv = [m for m in cell.bench["per_layer"]
             if m["name"].startswith("conv.")]
     assert conv and {m["name"] for m in conv} <= mine
@@ -876,10 +877,11 @@ def test_the_metrics_of_the_cell():
                and os.path.exists(os.path.join(
                    ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
                for m in conv)
-    # Eight cells, one of them on four chips; six configurations.
-    assert len(cell.bench["workloads"]) == 8
-    assert sum(w["chips"] == 4 for w in cell.bench["workloads"]) == 1
-    assert len(cell.bench["configs"]) == 6
+    # Eight cells or more (later PRs add theirs), one of them or more on
+    # four chips; six configurations or more.
+    assert len(cell.bench["workloads"]) >= 8
+    assert sum(w["chips"] == 4 for w in cell.bench["workloads"]) >= 1
+    assert len(cell.bench["configs"]) >= 6
 
 
 def test_the_defects_own_rehearsal_pieces():

@@ -404,8 +404,8 @@ def test_the_compilers_ragged_dot_kernels_are_not_taken_for_flash():
     """libtpu lowers ``ragged_dot`` to Mosaic calls of its own (7
     operands, and 1 for the metadata; copied from the step compiled for
     a v5e). ``trace_reduce.flash_kernel`` tells flash attention's calls
-    by their 3 or 6 operands, so these are none of them, and the scope
-    view files them under the expert layer."""
+    by their NAME (``hvd_flash_*``), so these are none of them, and the
+    scope view files them under the expert layer."""
     from benchmark import trace_reduce as tr
 
     ragged = ('%ragged-dot-none.7 = bf16[32768,1024]{1,0:T(8,128)(2,1)} '

@@ -262,9 +262,10 @@ def test_the_scan_keeps_the_state_at_each_chunks_start():
 
 
 def test_the_scan_calls_are_no_flash_kernel_to_the_readers():
-    """``trace_reduce.flash_kernel`` takes a Mosaic call of 3 or 6
-    operands for a kernel of ops/pallas_attention.py: the scan's calls
-    take FIVE and SEVEN."""
+    """``trace_reduce.flash_kernel`` tells a kernel of
+    ops/pallas_attention.py by its NAME: the scan's calls are
+    ``hvd_ssm_scan_*``, whatever they take (FIVE and SEVEN operands
+    today)."""
     from horovod_tpu.jax import introspect
     from horovod_tpu.ops import pallas_scan
 
@@ -277,11 +278,14 @@ def test_the_scan_calls_are_no_flash_kernel_to_the_readers():
             calls[eqn.params["name"]] = len(eqn.invars)
     assert calls == {introspect.KERNEL_SSM_SCAN_FWD: 5,
                      introspect.KERNEL_SSM_SCAN_BWD: 7}
-    event = ("%%custom-call.1 = (f32[1,16,128], f32[1,1,16,128]) custom-call("
+    event = ("%%%s.1 = (f32[1,16,128], f32[1,1,16,128]) custom-call("
              "%s), custom_call_target=\"tpu_custom_call\"")
-    assert tr.flash_kernel(event % ", ".join(["%a"] * 5)) == ""
-    assert tr.flash_kernel(event % ", ".join(["%a"] * 7)) == ""
-    assert tr.flash_kernel(event % ", ".join(["%a"] * 6)) == "dkv"
+    for name in calls:
+        for operands in (3, 5, 6, 7):
+            assert tr.flash_kernel(
+                event % (name, ", ".join(["%a"] * operands))) == ""
+    assert tr.flash_kernel(
+        event % ("hvd_flash_dkv", ", ".join(["%a"] * 7))) == "dkv"
 
 
 # --------------------------------------------- what a layer publishes -----
@@ -429,9 +433,11 @@ def test_a_recomputed_reader_runs_no_scan_and_no_key_or_value_projection():
     assert count["mamba in"] == count["mamba [r, B, C]"] == 0
     assert count["memory unit gate"] == 0 and count["k or v"] == 0
     assert count["q"] == 0 and count["dense up or gate"] == 0
-    # Twelve flash forward calls (four a differential layer), none again.
+    # Six flash forward calls, the REQUIRED two maps a differential
+    # layer (each over a V of two heads side by side since PR 46; twelve
+    # at 64 + 64 before it), none of them again.
     flash = [what for what in work if what[0] == introspect.KERNEL_FLASH_FWD]
-    assert flash == [(introspect.KERNEL_FLASH_FWD, False)] * 12
+    assert flash == [(introspect.KERNEL_FLASH_FWD, False)] * 6
     # A cross-attention block keeps no copy of the keys and values.
     assert introspect.SAVED_FLASH_K in transformer._REMAT_KEEPS
     assert set(transformer._REMAT_KEEPS) - set(transformer._READER_KEEPS) \
@@ -681,26 +687,32 @@ def test_the_step_by_hand():
     assert roof == "memory" and least == pytest.approx(1.6434e-3, rel=1e-3)
 
 
-def test_the_kernels_the_step_declares():
-    """Twelve calls of each flash kernel (four a differential layer) at
-    20-over-10 heads of 64, a call's work the mean of one sliding and
-    two full layers."""
+def test_what_the_steps_attention_requires():
+    """Three attention layers (sliding at 512, full, cross over the
+    published keys), each TWO softmax maps a pair of heads: 20 maps over
+    10 key/value heads twice, q.k 64 wide over a V of 128: 2 x (64 +
+    128) a pair forward, 2 x (3 x 64 + 2 x 128) backward, whatever
+    kernels run them and however many."""
     cell = cells.load(CELL)
     model = cell.builder.build(cell.config, cell.traffic)
-    kernels = model.kernels(1)
-    assert sorted(kernels) == ["dkv", "dq", "fwd"]
-    sliding = flops_afmoe.flash_kernel_work(1, 8192, 20, 10, 64, 512)
-    full = flops_afmoe.flash_kernel_work(1, 8192, 20, 10, 64)
-    for name, (calls, ops, nbytes) in kernels.items():
-        assert calls == 12
-        assert ops == pytest.approx((sliding[name][0] + 2 * full[name][0]) / 3)
-        assert nbytes == pytest.approx(
-            (sliding[name][1] + 2 * full[name][1]) / 3)
-    # All twelve calls' q.k and p.v: 64 wide each, where the required
-    # count has p.v at 128: the kernels run 4 x (64 + 64) a pair of
-    # heads for the required 2 x (64 + 128).
-    assert 12 * kernels["fwd"][1] == pytest.approx(
-        20 * 4 * 2 * 2 * 64 * (4_063_488 + 2 * 8192 * 8193 // 2))
+    work = model.attention_work(1)
+    assert sorted(work) == ["bwd", "fwd"]
+    pairs = 4_063_488 + 2 * (8192 * 8193 // 2)
+    assert work["fwd"][0] == 2 * 20 * 2 * pairs * (64 + 128)
+    assert work["bwd"][0] == 2 * 20 * 2 * pairs * (3 * 64 + 2 * 128)
+    # It is what ``model.mfu_pct`` counts for the same layers' pairs.
+    assert work["fwd"][0] == sum(
+        flops_phi4flash.diff_attention_forward_ops(
+            8192, hidden=0, n_head=40, n_kv=20, head_dim=64, window=window)
+        for window in (512, None, None))
+    # Bytes: q and dQ 20 heads of 64 a map, the output and dO 20 of 128,
+    # k 10 of 64, v 10 of 128, a float32 row a map's head; two maps a
+    # layer, three layers; the mask does not shrink a panel.
+    q, o = 20 * 8192 * 64 * 2, 20 * 8192 * 128 * 2
+    k, v = 10 * 8192 * 64 * 2, 10 * 8192 * 128 * 2
+    row = 20 * 8192 * 4
+    assert work["fwd"][1] == 3 * 2 * (q + k + v + o + row)
+    assert work["bwd"][1] == 3 * 2 * (2 * (q + k + v + o) + row)
 
 
 # ------------------------------------------------------ the new scopes ----
@@ -833,8 +845,8 @@ def test_the_metrics_of_the_cell():
     mine = {m["name"] for m in cells.metrics_of(cell, "per_layer")}
     assert {"ssm.mixer_ms", "ssm.scan_ms", "ssm.scan_roofline", "ssm.gmu_ms",
             "yoco.attn_ms", "yoco.cross_ms", "kernel.flash_roofline",
-            "kernel.flash_fwd_roofline", "kernel.flash_dkv_roofline",
-            "kernel.flash_dq_roofline", "kernel.flash_share_pct",
+            "kernel.flash_fwd_roofline", "kernel.flash_bwd_roofline",
+            "kernel.flash_share_pct",
             "kernel.flash_glue_ms", "model.mfu_pct", "model.step_device_ms",
             "model.head_ms", "device.peak_hbm_gb", "device.idle_pct",
             "device.unscoped_pct", "launch.compile_s",

@@ -1,7 +1,7 @@
 """``trace_reduce.py`` against a small trace recorded on a TPU v5e
 (``record_trace.py``: three runs of a program of 17 instructions, the
-three flash kernels among them, 2 ms of host sleep between runs) and
-against hand-built events."""
+three flash kernels among them, 2 ms of host sleep between runs; PR 22,
+before the kernels carried a ``name=``) and against hand-built events."""
 
 import os
 
@@ -36,17 +36,86 @@ def test_recorded_window_busy_and_idle(window):
         win.window_s - win.busy_s)
 
 
-def test_recorded_kernels_are_told_apart(window):
+def test_recorded_kernels_are_told_apart_by_name_alone(window):
+    """The recorded Mosaic calls bear the compiler's names (``jvp__.1``:
+    3 operands; ``transpose_jvp___.2`` / ``.3``: 6): no flash kernel.
+    Under the names a program of today gives them they are the three,
+    with the times as recorded."""
+    from benchmark.tests.test_scope_view import named_window
+
     _, win = window
-    by_kernel = tr.time_by([e for e in win.ops if tr.flash_kernel(e.name)],
-                           tr.flash_kernel)
-    assert list(by_kernel) == ["dkv", "fwd", "dq"]
-    assert by_kernel["fwd"] == pytest.approx(3 * 1.491e-6, rel=1e-2)
     mosaic = [e for e in win.ops if tr.is_mosaic_call(e.name)]
     assert len(mosaic) == 9
     assert {tr.opcode(e.name) for e in mosaic} == {"custom-call"}
     assert tr.instruction_name(mosaic[0].name) == "jvp__.1"
+    assert {e.name.split(" custom-call(")[1].split("), ")[0].count("%")
+            for e in mosaic} == {3, 6}
+    assert not any(tr.flash_kernel(e.name) for e in win.ops)
+    assert tr.kernel_seconds(win.ops) == {}
     assert not any(tr.is_collective(e.name) for e in win.ops)
+    ops = named_window(win).ops
+    by_kernel = tr.time_by([e for e in ops if tr.flash_kernel(e.name)],
+                           tr.flash_kernel)
+    assert list(by_kernel) == ["dkv", "fwd", "dq"]
+    assert by_kernel["fwd"] == pytest.approx(3 * 1.491e-6, rel=1e-2)
+    assert tr.kernel_seconds(ops) == {
+        name: (pytest.approx(seconds), 3)
+        for name, seconds in by_kernel.items()}
+    assert tr.time_by(ops, tr.category)["flash dkv"] == by_kernel["dkv"]
+
+
+def _call(name, operands, results=1):
+    shape = "bf16[1,2,256,64]{3,2,1,0}"
+    return "%%%s = %s custom-call(%s), custom_call_target=\"%s\"" % (
+        name, shape if results == 1 else "(%s)" % ", ".join([shape] * results),
+        ", ".join("%%a.%d" % i for i in range(operands)), "tpu_custom_call")
+
+
+@pytest.mark.parametrize("name,operands,results,kernel", [
+    # The name decides, whatever the operands and results.
+    ("hvd_flash_fwd.3", 3, 2, "fwd"),
+    ("hvd_flash_fwd", 7, 2, "fwd"),
+    ("hvd_flash_fwd.12", 4, 1, "fwd"),
+    ("hvd_flash_dkv.47", 6, 2, "dkv"),
+    ("hvd_flash_dq.1", 6, 1, "dq"),
+    ("hvd_flash_bwd.2", 9, 3, "bwd"),
+    ("hvd_flash_dq_fused.5", 8, 1, "dq_fused"),
+    # 3 or 6 operands under another name is no flash kernel.
+    ("jvp__.1", 3, 2, ""),
+    ("transpose_jvp___.2", 6, 2, ""),
+    ("transpose_jvp___.3", 6, 1, ""),
+    ("hvd_moe_gmm.4", 3, 1, ""),
+    ("hvd_ssm_scan_bwd.2", 6, 2, ""),
+    ("hvd_dsa_fwd.1", 3, 2, ""),
+    ("hvd_dsa_choose", 6, 2, ""),
+    ("my_hvd_flash_fwd.1", 3, 2, ""),
+])
+def test_a_flash_kernel_is_told_by_its_name(name, operands, results, kernel):
+    assert tr.flash_kernel(_call(name, operands, results)) == kernel
+    assert tr.category(_call(name, operands, results)) == \
+        "flash " + (kernel or "other")
+
+
+def test_named_kernels_of_other_families_and_their_direction():
+    assert tr.named_kernel(_call("hvd_dsa_dkv.6", 7, 2), "hvd_dsa_") == "dkv"
+    assert tr.named_kernel(_call("hvd_dsa_choose.1", 4, 2),
+                           "hvd_dsa_") == "choose"
+    assert tr.named_kernel(_call("hvd_flash_fwd.1", 3), "hvd_dsa_") == ""
+    # Not a Mosaic call: no kernel, whatever it is called.
+    assert tr.flash_kernel("%hvd_flash_fwd.1 = bf16[8] fusion(%a)") == ""
+    assert tr.flash_kernel("hvd_flash_fwd") == ""
+    # Every name but the forward's works for the backward pass.
+    assert tr.direction("fwd") == "fwd"
+    assert [tr.direction(k) for k in ("dkv", "dq", "bwd", "dq_fused")] \
+        == ["bwd"] * 4
+    events = [Event(_call("hvd_flash_dkv.1", 6, 2), 0, 2000),
+              Event(_call("hvd_flash_dkv.2", 6, 2), 3000, 4000),
+              Event(_call("hvd_moe_gmm", 5), 5000, 9000),
+              Event(_call("hvd_flash_fwd.1", 3, 2), 9000, 9500)]
+    assert tr.kernel_seconds(events) == {
+        "dkv": (pytest.approx(3e-6), 2), "fwd": (pytest.approx(0.5e-6), 1)}
+    assert tr.kernel_seconds(events, "hvd_moe_") == {
+        "gmm": (pytest.approx(4e-6), 1)}
 
 
 def test_recorded_gaps_go_to_the_host_span_under_them(window):

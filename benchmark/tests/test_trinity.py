@@ -29,6 +29,7 @@ import pytest
 
 from benchmark import cell as cells
 from benchmark import flops, flops_afmoe, flops_glm, scope_view, traffic
+from benchmark import trace_reduce as tr
 from benchmark.layer_metrics import reader
 from benchmark.reference import afmoe as reference
 from benchmark.tests.test_olmoe import _leaf_distances, _rel
@@ -632,32 +633,30 @@ def test_the_step_of_the_share_by_hand():
     assert 18.0e12 < ops < 18.2e12
     assert 3 * h * 4 * hd * full_pairs == pytest.approx(1.65e12, rel=5e-3)
     assert 3 * h * 4 * hd * 4 * kept == pytest.approx(2.89e12, rel=5e-3)
-    # The kernels as the step runs them: each once a layer, K/V panels 4
-    # heads wide, ONE (operations, bytes) a call: the mean over the layers.
-    kernels = builder.build(config, {"seq_len": s, "remat": True}).kernels(1)
-    assert {k: v[0] for k, v in kernels.items()} == {
-        "fwd": 5, "dkv": 5, "dq": 5}
-    one = flops_afmoe.flash_kernel_work(1, s, h, kv, hd, w)
-    whole = flops_afmoe.flash_kernel_work(1, s, h, kv, hd, None)
+    # What attention REQUIRES of the step: four sliding layers' pairs and
+    # one full layer's, two products forward and five backward, K/V
+    # panels 4 heads wide.
+    work = builder.build(config, {"seq_len": s, "remat": True}
+                         ).attention_work(1)
+    one = flops_afmoe.layer_attention_work(1, s, SLIDING, **{
+        key: builder.sizes_of(config)[key]
+        for key in ("n_head", "n_kv", "head_dim", "window")})
+    whole = flops.attention_work(full_pairs, s, n_head=h, n_kv=kv, d=hd,
+                                 d_v=hd)
     assert one["fwd"][0] == 2 * h * 2 * kept * hd
+    assert one["bwd"][0] == 5 * h * 2 * kept * hd
     wide, narrow, row = h * s * hd * 2, kv * s * hd * 2, h * s * 4
     assert one["fwd"][1] == 2 * wide + 2 * narrow + row == whole["fwd"][1]
-    assert one["dkv"][1] == 2 * wide + 4 * narrow + 2 * row
-    assert one["dq"][1] == 3 * wide + 2 * narrow + 2 * row
-    assert kernels["fwd"][1] == pytest.approx(
-        (4 * one["fwd"][0] + whole["fwd"][0]) / 5)
-    # With as many key/value heads as query heads and no window the
-    # count is ``flops.flash_kernel_work``'s.
-    assert flops_afmoe.flash_kernel_work(1, s, h, h, hd) \
-        == flops.flash_kernel_work(1, s, h, hd)
-    # Both kinds are compute-bound, so the mean's roof is the roofs' mean.
+    assert one["bwd"][1] == 4 * wide + 4 * narrow + row
+    assert work["fwd"][0] == attention
+    assert work == flops.add_work(4 * [one] + [whole])
+    # Both kinds are compute-bound, so the sum's roof is the roofs' sum.
     peak = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
     for name in one:
         assert flops.roofline_seconds(*one[name], peak)[1] == "compute"
-        assert flops.roofline_seconds(*kernels[name][1:], peak)[0] \
-            == pytest.approx((4 * flops.roofline_seconds(*one[name], peak)[0]
-                              + flops.roofline_seconds(*whole[name],
-                                                       peak)[0]) / 5)
+        assert flops.roofline_seconds(*work[name], peak)[0] \
+            == pytest.approx(4 * flops.roofline_seconds(*one[name], peak)[0]
+                             + flops.roofline_seconds(*whole[name], peak)[0])
     # ``moe.held_roofline`` reads these through the shared reader.
     sizes = builder.sizes_of(config)
     assert {k: sizes[k] for k in ("hidden", "expert_width", "k", "held",
@@ -747,22 +746,31 @@ def test_the_new_readers_on_the_recorded_trace(capsys):
         for part in ("attn", "flash_kernel", "flash_glue")))
     # The kernels by the layer of their scope: the forward in the
     # sliding layer, dK/dV and dQ in the full one.
-    per_step = 1e3 / ctx.n_steps
-    assert got["swa.window_ms"] == pytest.approx(
-        table.kernels["fwd"][0] * per_step)
-    assert got["swa.full_ms"] == pytest.approx(
-        (table.kernels["dkv"][0] + table.kernels["dq"][0]) * per_step)
-    sliding = flops_afmoe.flash_kernel_work(1, 8192, 32, 4, 128, 2048)
-    whole = flops_afmoe.flash_kernel_work(1, 8192, 32, 4, 128, None)
-    calls = table.kernels["fwd"][1] / ctx.n_steps
+    took = {k: 1e3 * seconds / ctx.n_steps for k, (seconds, _)
+            in tr.kernel_seconds(ctx.win0.ops).items()}
+    assert got["swa.window_ms"] == pytest.approx(took["fwd"])
+    assert got["swa.full_ms"] == pytest.approx(took["dkv"] + took["dq"])
+    # Against what the configuration's layers of each kind REQUIRE,
+    # forward AND backward, whatever calls the trace holds: four sliding
+    # layers, one full.
+    sizes = dict(n_head=32, n_kv=4, head_dim=128, window=2048)
+    sliding = flops_afmoe.layer_attention_work(1, 8192, SLIDING, **sizes)
+    whole = flops_afmoe.layer_attention_work(1, 8192, FULL, **sizes)
     assert got["swa.window_roofline"] == pytest.approx(
-        100 * 1e3 * calls * flops.roofline_seconds(*sliding["fwd"],
-                                                   ctx.peak)[0]
-        / got["swa.window_ms"])
+        100 * 1e3 * 4 * sum(flops.roofline_seconds(*sliding[d], ctx.peak)[0]
+                            for d in ("fwd", "bwd")) / got["swa.window_ms"])
     assert got["swa.full_roofline"] == pytest.approx(
-        100 * 1e3 * calls * sum(
-            flops.roofline_seconds(*whole[k], ctx.peak)[0]
-            for k in ("dkv", "dq")) / got["swa.full_ms"])
+        100 * 1e3 * sum(flops.roofline_seconds(*whole[d], ctx.peak)[0]
+                        for d in ("fwd", "bwd")) / got["swa.full_ms"])
+    # One backward kernel in the place of two, of equal time: the same.
+    fused = _ctx(_swa_step().replace("hvd_flash_dkv", "hvd_flash_bwd"
+                                     ).replace("hvd_flash_dq", "hvd_flash_bwd"),
+                 names={"jvp__.1": "hvd_flash_fwd.1",
+                        "transpose_jvp___.2": "hvd_flash_bwd.2",
+                        "transpose_jvp___.3": "hvd_flash_bwd.3"})
+    fused.cell = ctx.cell
+    assert {name: reader(name)(fused) for name in names} \
+        == {name: pytest.approx(v) for name, v in got.items()}
     assert "flash kernels of sliding_attention layers" \
         in capsys.readouterr().err
     # A step with no attention module at all, a cell without

@@ -26,6 +26,9 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 HOST_PLANE = "/host:CPU"
 _INSTRUCTION = re.compile(r"^%(\S+) = ")
 _OPCODE = re.compile(r" ([a-z][a-z0-9_-]*)\(")
+# The names ops/pallas_attention.py gives its Mosaic calls: a prefix and
+# the kernel; the forward's is the one kernel name with a meaning here.
+FLASH, FORWARD = "hvd_flash_", "fwd"
 
 Interval = Tuple[float, float]
 
@@ -136,22 +139,47 @@ def is_mosaic_call(event_name: str) -> bool:
     return 'custom_call_target="tpu_custom_call"' in event_name
 
 
-def flash_kernel(event_name: str) -> str:
-    """Which kernel of ops/pallas_attention.py a Mosaic call is. The
-    program gives its ``pallas_call``s no name, so they are told apart
-    by shape: the forward takes 3 operands (q, k, v); the backward
-    kernels take 6, and dK/dV returns a pair where dQ returns one
-    array. Anything else is ''."""
+def named_kernel(event_name: str, prefix: str) -> str:
+    """What follows ``prefix`` in the NAME of a Mosaic call, '' for any
+    other instruction and any other name. ``pallas_call(name=)`` names
+    the instruction (``%hvd_flash_dkv.47 = ...`` is ``dkv`` under
+    ``hvd_flash_``); a call's operands and results say nothing here, so
+    a kernel takes the operands it needs."""
     if not is_mosaic_call(event_name):
         return ""
-    m = _INSTRUCTION.match(event_name)
-    head, _, rest = event_name[m.end():].partition(" custom-call(")
-    operands = rest.split("), custom_call_target=")[0].count("%")
-    if operands == 3:
-        return "fwd"
-    if operands == 6:
-        return "dkv" if head.startswith("(") else "dq"
-    return ""
+    name = instruction_name(event_name)
+    if not name.startswith(prefix):
+        return ""
+    return name[len(prefix):].split(".")[0]
+
+
+def flash_kernel(event_name: str) -> str:
+    """Which kernel of ops/pallas_attention.py a Mosaic call is: what
+    follows ``hvd_flash_`` in its name (``fwd``, ``dkv`` and ``dq``
+    today; whatever a later kernel is called), '' for every other
+    call."""
+    return named_kernel(event_name, FLASH)
+
+
+def direction(kernel: str) -> str:
+    """``fwd`` or ``bwd``, the direction of ``flops.attention_work``
+    that a kernel named ``hvd_flash_<kernel>`` (or ``hvd_dsa_<kernel>``)
+    works for: every name but the forward's belongs to the backward
+    pass, so that a backward of one kernel, of two or of three is read
+    against the same required work."""
+    return "fwd" if kernel == FORWARD else "bwd"
+
+
+def kernel_seconds(events: Sequence["Event"], prefix: str = FLASH):
+    """{kernel: (seconds, calls)} of the Mosaic calls among ``events``
+    whose name starts with ``prefix``, by what follows it."""
+    took: Dict[str, Tuple[float, int]] = {}
+    for e in events:
+        kernel = named_kernel(e.name, prefix)
+        if kernel:
+            seconds, calls = took.get(kernel, (0.0, 0))
+            took[kernel] = (seconds + e.seconds, calls + 1)
+    return took
 
 
 def is_collective(event_name: str) -> bool:

@@ -57,7 +57,7 @@ def build(*, cell, asm, peak, xplane, hlo_text, timeline, memory_peak,
                for chip, lines in sorted(trace.devices.items())}
     win0 = windows[min(windows)]
     n_steps = len(win0.steps)
-    kernels = asm.model.kernels(asm.per_chip_batch)
+    attention = asm.model.attention_work(asm.per_chip_batch)
 
     # ------------------------------------------------ earlier lines ------
     counts = hlo_counts(hlo_text)
@@ -67,14 +67,18 @@ def build(*, cell, asm, peak, xplane, hlo_text, timeline, memory_peak,
     by_category = tr.time_by(win0.ops, tr.category)
     log("device time by class, per step (ms): " + ", ".join(
         "%s %.3f" % (k, 1e3 * v / n_steps) for k, v in by_category.items()))
-    kernel_s = tr.time_by([e for e in win0.ops if tr.flash_kernel(e.name)],
-                          tr.flash_kernel)
-    for name, (calls, ops, nbytes) in kernels.items():
+    kernel_s = tr.kernel_seconds(win0.ops)
+    for name, (took, calls) in kernel_s.items():
+        log("kernel flash %s: %d calls a step, %.1f us a call, %.3f ms a "
+            "step" % (name, calls / n_steps, 1e6 * took / calls,
+                      1e3 * took / n_steps))
+    for direction, (ops, nbytes) in attention.items():
         least, roof = flops.roofline_seconds(ops, nbytes, peak)
-        took = kernel_s.get(name, 0.0) / max(n_steps * calls, 1)
-        log("kernel flash %s: %d calls a step, %.1f us a call, %.1f us at "
-            "the %s roof, %.1f%% of it" % (
-                name, calls, 1e6 * took, 1e6 * least, roof,
+        took = sum(s for name, (s, _) in kernel_s.items()
+                   if tr.direction(name) == direction) / n_steps
+        log("attention %s, required: %.3f ms a step at the %s roof, the "
+            "flash kernels took %.3f, %.1f%% of it" % (
+                direction, 1e3 * least, roof, 1e3 * took,
                 100 * least / took if took else float("nan")))
 
     # ---------------------------------------------------- breakdown ------
@@ -90,7 +94,7 @@ def build(*, cell, asm, peak, xplane, hlo_text, timeline, memory_peak,
     return SimpleNamespace(
         cell=cell, plan=asm.plan, peak=peak, trace=trace, windows=windows,
         win0=win0, n_steps=n_steps, hlo_text=hlo_text, hlo_counts=counts,
-        kernels=kernels, chips=cell.chips,
+        attention=attention, chips=cell.chips,
         step_ops=asm.model.step_ops(asm.global_batch),
         units_per_step=asm.units_per_step,
         step_device_s=statistics.median(e.seconds for e in win0.steps),

@@ -92,8 +92,11 @@ SCOPE_MOE_COMBINE = "hvd_moe_combine"
 SCOPE_MOE_ROWS = "hvd_moe_rows"
 # The shared expert's three matmuls, beside the routed sum.
 SCOPE_MOE_SHARED = "hvd_moe_shared"
-# ``name=`` of the three ``pallas_call``s (the Mosaic calls' op_name).
+# ``name=`` of the flash ``pallas_call``s (the Mosaic calls' op_name):
+# the forward; the backward of a static mask in ONE pass; the two
+# kernels that run it where the one pass's panels pass the VMEM cap.
 KERNEL_FLASH_FWD = "hvd_flash_fwd"
+KERNEL_FLASH_BWD = "hvd_flash_bwd"
 KERNEL_FLASH_DKV = "hvd_flash_dkv"
 KERNEL_FLASH_DQ = "hvd_flash_dq"
 # The same three kernels under a mask that is DATA (``select=``): a

@@ -8,21 +8,32 @@ materialising the (S, S) score matrix in HBM.
 
 Algorithm: standard streaming-softmax (flash) attention. The forward
 kernel tiles queries over the grid and walks key/value blocks with a
-running (max, sum, accumulator) triple; the backward pass is two kernels
-(dK/dV tiled over key blocks, dQ tiled over query blocks) using the saved
-log-sum-exp, wired up through ``jax.custom_vjp``. dK/dV computes its
-score tiles key-major (``k . q^T``), so that ``p`` and ``ds`` feed their
-matmuls without a tile transpose, and takes the log-sum-exp and delta
-as lane-major rows; the forward and dQ are query-major and take them
-as columns.
+running (max, sum, accumulator) triple; the backward pass uses the saved
+log-sum-exp, wired up through ``jax.custom_vjp``. The backward of a
+STATIC mask (causal, causal + window, none) is ONE kernel,
+``hvd_flash_bwd``: tiled over key blocks, it walks the query blocks of
+one query head, computes its score tiles key-major (``k . q^T``), so
+that ``p`` and ``ds`` feed the dV and dK matmuls without a tile
+transpose, takes the log-sum-exp and delta as lane-major rows, and adds
+each tile's ``ds^T . k`` into the head's float32 dQ, a (S, D) panel
+that stays in VMEM while the grid walks the head's key blocks and is
+rounded once at the last: the FIVE matmul products a score tile that
+attention requires. The older pair, dK/dV tiled over key blocks and a
+query-major dQ tiled over query blocks (log-sum-exp and delta as
+columns), each make ``q . k^T`` and ``dO . v^T`` (SEVEN products); it
+still runs where ``_one_pass`` says so, a rule on the call's input and
+nothing else: under a learned mask, and where the one pass's resident
+panels pass the VMEM cap.
 
 The per-(batch, head) K/V panel is VMEM-resident (blocks are sliced
-from it in-kernel), and so are the backward's whole Q/dO panels, which
-bounds single-chip sequence length to VMEM: each kernel asks Mosaic for
-the scoped VMEM its panels need where the default 16 MiB is too little
-(``_compiler_params``). Largest power-of-two S for which forward AND
-backward compile for a TPU v5 lite (jax 0.9.0 / libtpu 0.0.34, default
-tiles, head_dim 64 and 128 alike): 65536 in bf16, 32768 in float32.
+from it in-kernel), and so are the backward's whole Q/dO panels and its
+dQ, which bounds single-chip sequence length to VMEM: each kernel asks
+Mosaic for the scoped VMEM its panels need where the default 16 MiB is
+too little (``_compiler_params``). Largest power-of-two S for which
+forward AND backward compile for a TPU v5 lite (jax 0.9.0 / libtpu
+0.0.34, default tiles, head_dim 64 and 128 alike): 65536 in bf16, 32768
+in float32 (past 32768 rows in bf16, 16384 in float32, as the two
+kernels).
 Longer sequences shard S across chips via ring/Ulysses attention
 (``horovod_tpu.parallel.sequence``), keeping each chip's panel small.
 
@@ -34,10 +45,10 @@ the tiles below the window as well as those above the diagonal.
 
 Grouped-query heads: ``k`` and ``v`` may carry fewer heads than ``q``
 (``H % H_kv == 0``; query head h reads key/value head ``h // (H //
-H_kv)``). They stay ``H_kv`` heads wide in HBM: the forward and dQ index
-the K/V panel by the query head's group, and dK/dV walks the group's
-query heads as a grid axis, adding into a float32 dK/dV panel that
-stays in VMEM until the group is done.
+H_kv)``). They stay ``H_kv`` heads wide in HBM: the forward (and the
+query-major dQ) index the K/V panel by the query head's group, and the
+key-major backward walks the group's query heads as a grid axis, adding
+into a float32 dK/dV panel that stays in VMEM until the group is done.
 
 Two widths: q, k, dQ and dK are ``d`` wide (``q.shape[-1]``); v, dO, the
 output and dV are ``d_v`` wide (``v.shape[-1]``), which need not be
@@ -50,7 +61,8 @@ A learned selection (``select=``, DeepSeek sparse attention): which keys
 a query keeps is then DATA the step computed, not a fact of the trace.
 It comes as two bit planes of the (S, S) mask (``pack_selection``): one
 packed along the keys, which the query-major forward and dQ read a
-query block at a time, one along the queries for the key-major dK/dV.
+query block at a time, one along the queries for the key-major dK/dV
+(the backward is then the two kernels, ``hvd_dsa_dkv`` + ``hvd_dsa_dq``).
 A bit stands for a whole lane: bit b of word ``[m, r, j]`` is column
 ``(32 m + b) 128 + j`` of row r, so a tile's mask is a shift and an AND
 of (rows, 128) words, 128-lane pieces side by side, no gather and no
@@ -60,7 +72,12 @@ the diagonal.
 
 On non-TPU backends (CPU tests, debugging) the kernels run in Pallas
 interpret mode, so the same code path is exercised everywhere; the
-switch is logged once per backend so a run can tell which it got.
+switch is logged once per backend so a run can tell which it got. ONE
+line differs there: the one pass's ``ds^T . k`` is made from the
+transposed tile behind a barrier (``_dot_tn``), so that XLA:CPU adds it
+as the dQ kernel adds ``ds . k`` and the one pass, the two kernels and
+the masked pair under a mask that keeps every key are equal bit for bit
+off the chip, as the tests hold them.
 """
 
 from __future__ import annotations
@@ -323,11 +340,25 @@ def _count_tiles(kernel, tiles, shape, d_v, dtype, group):
 
 _NT = (((1,), (1,)), ((), ()))   # a . b^T
 _NN = (((1,), (0,)), ((), ()))   # a . b
+_TN = (((0,), (0,)), ((), ()))   # a^T . b
 
 
 def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, dims,
                                preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b, interp):
+    """``a^T . b``, the product that contracts over ``a``'s FIRST axis.
+    Mosaic transposes the tile itself. In interpret mode XLA:CPU would
+    fold the transposition into the product and add in another order
+    than ``_dot(a.T, b, _NN)`` does (some widths, 1 ulp): there the
+    transposed tile is made first, behind a barrier, so that the sum
+    over a key block is the one ``_bwd_dq_kernel`` makes, bit for
+    bit."""
+    if interp:
+        return _dot(jax.lax.optimization_barrier(a.T), b, _NN)
+    return _dot(a, b, _TN)
 
 
 # ------------------------------------------------------------- selection ---
@@ -437,27 +468,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, tiles, scale):
 # -------------------------------------------------------------- backward ---
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    *rest, tiles, scale, group):
-    """Grid: (B, H, num_kb), or (B, H_kv, group, num_kb) where ``group``
-    query heads share a key/value head. One k/v block vs streamed q
+def _key_block_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sel_ref,
+                     dq_sum, kj, k_start, tiles, scale, interp=False):
+    """dK and dV (float32) of key block ``kj`` against the streamed q
     blocks of ONE query head. The score tile is KEY-major, (block_k,
     block_q) = k . q^T, so that p and ds are born in the orientation
     their matmuls consume (p . dO and ds . q are plain contractions, no
     tile transpose), and the log-sum-exp and delta come as lane-major
-    (1, block_q) rows.
-
-    Grouped: ``dk_ref`` / ``dv_ref`` are the key/value head's whole
-    float32 panels, resident in VMEM while the grid walks the group's
-    query heads; the first head writes its block, the others add.
-
-    Under a learned mask a seventh operand: the key block's rows of
-    ``Selection.by_key``."""
-    sel_ref = rest[0] if tiles.learned else None
-    dk_ref, dv_ref = rest[-2:]
-    block_q, block_k = tiles.block_q, tiles.block_k
-    kj = pl.program_id(2 if group == 1 else 3)
-    k_start = kj * block_k
+    (1, block_q) rows. Where ``dq_sum`` is given (the head's float32
+    (sq_pad, D) panel), each tile adds its ``ds^T . k`` to the rows of
+    its q block: the fifth product, the one that contracts over ds's
+    FIRST axis."""
+    block_q = tiles.block_q
     k = k_ref[...].astype(jnp.float32)  # (block_k, D)
     v = v_ref[...].astype(jnp.float32)  # (block_k, D_v)
 
@@ -478,14 +500,24 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv = dv + _dot(p, do, _NN)
         ds = p * (_dot(v, do, _NT) - delta)
         dk = dk + _dot(ds, q, _NN)
+        if dq_sum is not None:
+            dq_sum[pl.ds(q_start, block_q), :] += _dot_tn(ds, k, interp)
         return dk, dv
 
     dk = jnp.zeros(k.shape, jnp.float32)
     dv = jnp.zeros(v.shape, jnp.float32)
-    dk, dv = jax.lax.fori_loop(tiles.query_start(kj), tiles.query_end(kj),
-                               body, (dk, dv))
     # q was pre-scaled at load, so dk = Σ ds (scale·q) is already the
     # gradient of s = scale·q·kᵀ w.r.t. k — no extra scale factor here.
+    return jax.lax.fori_loop(tiles.query_start(kj), tiles.query_end(kj),
+                             body, (dk, dv))
+
+
+def _put_dkv(dk_ref, dv_ref, dk, dv, k_start, block_k, group):
+    """One key block's dK and dV to their outputs: its own blocks where
+    a key/value head has one query head; else rows of the key/value
+    head's float32 panels, resident in VMEM while the grid walks the
+    group's query heads: the first head writes its block, the others
+    add."""
     if group == 1:
         dk_ref[...] = dk.astype(dk_ref.dtype)
         dv_ref[...] = dv.astype(dv_ref.dtype)
@@ -502,6 +534,50 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dk_ref[rows, :] += dk
         dv_ref[rows, :] += dv
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    *rest, tiles, scale, group):
+    """Grid: (B, H, num_kb), or (B, H_kv, group, num_kb) where ``group``
+    query heads share a key/value head. One k/v block vs streamed q
+    blocks of ONE query head, key-major (``_key_block_grads``). Under a
+    learned mask a seventh operand: the key block's rows of
+    ``Selection.by_key``."""
+    sel_ref = rest[0] if tiles.learned else None
+    dk_ref, dv_ref = rest[-2:]
+    kj = pl.program_id(2 if group == 1 else 3)
+    k_start = kj * tiles.block_k
+    dk, dv = _key_block_grads(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                              delta_ref, sel_ref, None, kj, k_start, tiles,
+                              scale)
+    _put_dkv(dk_ref, dv_ref, dk, dv, k_start, tiles.block_k, group)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_sum, *, tiles, scale, group,
+                interp):
+    """The whole backward of a static mask in ONE pass: ``_bwd_dkv_kernel``'s
+    grid and walk, and dQ besides. ``dq_sum`` is the query head's
+    float32 (sq_pad, D) sum, a scratch that lives in VMEM while the grid
+    walks the head's key blocks: zeroed at the first (a window's q block
+    is first visited by key block ``key_start(qi)``, a padded row by
+    none), added to by every tile, and rounded ONCE into ``dq_ref``, the
+    head's whole dQ panel, at the last."""
+    kj = pl.program_id(2 if group == 1 else 3)
+    k_start = kj * tiles.block_k
+
+    @pl.when(kj == 0)
+    def _():
+        dq_sum[...] = jnp.zeros(dq_sum.shape, jnp.float32)
+
+    dk, dv = _key_block_grads(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                              delta_ref, None, dq_sum, kj, k_start, tiles,
+                              scale, interp)
+    _put_dkv(dk_ref, dv_ref, dk, dv, k_start, tiles.block_k, group)
+
+    @pl.when(kj == tiles.num_kb - 1)
+    def _():
+        dq_ref[...] = (dq_sum[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -617,29 +693,52 @@ def _pad_rows(plane, rows):
                            (0, 0)))
 
 
-def _compiler_params(panel_rows, d, d_v, dtype, block_q, block_k, out_rows=0,
-                     select_rows=0):
-    """Mosaic's scoped-VMEM limit for a kernel that keeps two panels
-    resident, one (S, D) and one (S, D_v) (k and v, or q and dO): the
-    default (16 MiB on a v5e) wherever the kernel fits, because asking
-    for more takes VMEM from XLA's own prefetches around the call (1 ms
-    a step at S=4096, PERF.md PR 25); what it needs, once it does not. Each panel is double buffered and padded
-    to 128 lanes; half a dozen float32 score tiles cover the loop
-    body's temporaries and spills. ``out_rows``: the rows of the two
-    float32 output panels (dK and dV, the same two widths) the grouped
-    dK/dV keeps resident besides.
+_VMEM_CAP = 100 << 20     # the most scoped VMEM a kernel asks Mosaic for
+
+
+def _vmem_need(panel_rows, d, d_v, dtype, block_q, block_k, out_rows=0,
+               select_rows=0, dq_rows=0):
+    """The scoped VMEM, in bytes, of a kernel that keeps two panels
+    resident, one (S, D) and one (S, D_v) (k and v, or q and dO). Each
+    panel is double buffered and padded to 128 lanes; half a dozen
+    float32 score tiles cover the loop body's temporaries and spills.
+    ``out_rows``: the rows of the two float32 output panels (dK and dV,
+    the same two widths) the grouped dK/dV keeps resident besides.
     ``select_rows``: the (rows, 128) int32 slabs of a learned mask's
     plane block (words x rows), double buffered, and two more score
-    tiles for its pieces."""
+    tiles for its pieces. ``dq_rows``: the rows of the one-pass
+    backward's dQ, (S, D): its float32 sum, a scratch, and the output
+    panel in ``dtype``, double buffered."""
     lanes = max(d, 128) + max(d_v, 128)
-    panels = 2 * panel_rows * lanes * jnp.dtype(dtype).itemsize
+    itemsize = jnp.dtype(dtype).itemsize
+    panels = 2 * panel_rows * lanes * itemsize
     panels += 2 * out_rows * lanes * 4
+    panels += dq_rows * max(d, 128) * (4 + 2 * itemsize)
     if select_rows:
         panels += 2 * select_rows * _LANES * 4 + 2 * 4 * block_q * block_k
-    need = panels + 6 * 4 * block_q * block_k + (2 << 20)
+    return panels + 6 * 4 * block_q * block_k + (2 << 20)
+
+
+def _compiler_params(need):
+    """Mosaic's scoped-VMEM limit for a kernel that needs ``need`` bytes
+    (``_vmem_need``): the default (16 MiB on a v5e) wherever the kernel
+    fits, because asking for more takes VMEM from XLA's own prefetches
+    around the call (1 ms a step at S=4096, PERF.md PR 25); what it
+    needs, once it does not."""
     if need <= (16 << 20):
         return None
-    return pltpu.CompilerParams(vmem_limit_bytes=min(need, 100 << 20))
+    return pltpu.CompilerParams(vmem_limit_bytes=min(need, _VMEM_CAP))
+
+
+def _one_pass(learned, need):
+    """WHICH backward runs, from what the call sees in its input: the
+    two kernels ``hvd_dsa_dkv`` + ``hvd_dsa_dq`` under a learned mask
+    (the one-pass form of a mask that is data is a later change); the
+    two kernels ``hvd_flash_dkv`` + ``hvd_flash_dq`` where the one
+    pass's resident panels (``need``, by ``_vmem_need``) pass the cap,
+    so that no shape that compiled as two kernels stops compiling; ONE
+    pass, ``hvd_flash_bwd``, otherwise."""
+    return not learned and need <= _VMEM_CAP
 
 
 @_scoped
@@ -681,9 +780,9 @@ def _flash_fwd_impl(q, k, v, causal, window, block_q, block_k, scale,
             jax.ShapeDtypeStruct((b, h, sq_pad, d_v), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq_pad, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=_compiler_params(_vmem_need(
             sk_pad, d, d_v, q.dtype, block_q, block_k,
-            select_rows=words * block_q),
+            select_rows=words * block_q)),
         interpret=_should_interpret(interpret),
         name=name,
     )(*operands)
@@ -701,25 +800,58 @@ def _flash_fwd(q, k, v, causal, window, block_q, block_k, scale, interpret):
                            interpret)
 
 
+def _key_major_need(tiles, group, d, d_v, sq_pad, sk_pad, dtype, words=0,
+                    with_dq=False):
+    """``_vmem_need`` of the key-major call (``_dkv_call``): q and dO's
+    panels, the grouped dK/dV's, a learned plane's block, dQ's."""
+    return _vmem_need(sq_pad, d, d_v, dtype, tiles.block_q, tiles.block_k,
+                      out_rows=sk_pad if group > 1 else 0,
+                      select_rows=words * tiles.block_k,
+                      dq_rows=sq_pad if with_dq else 0)
+
+
 def _dkv_call(tiles, group, b, h, d, d_v, sq_pad, sk_pad, dtype, scale,
-              interp, words=0):
-    """The dK/dV ``pallas_call``; q, k and dK ``d`` wide, v, dO and dV
-    ``d_v``. One query head a key/value head: a (B, H, num_kb) grid,
-    each step writes its own (block_k, D) and (block_k, D_v) blocks.
-    Grouped: a (B, H_kv, group, num_kb) grid; the key/value head's
-    float32 dK/dV panels are the output blocks of all ``group x
+              interp, words=0, with_dq=False):
+    """The key-major ``pallas_call``: dK/dV, or with ``with_dq`` the
+    whole backward (``_bwd_kernel``); q, k, dQ and dK ``d`` wide, v, dO
+    and dV ``d_v``. One query head a key/value head: a (B, H, num_kb)
+    grid, each step writes its own (block_k, D) and (block_k, D_v)
+    blocks. Grouped: a (B, H_kv, group, num_kb) grid; the key/value
+    head's float32 dK/dV panels are the output blocks of all ``group x
     num_kb`` steps, so they stay in VMEM while every query head of the
     group adds its part and go to HBM once, ``H_kv`` heads wide.
     ``words``: of a learned mask's ``by_key`` plane, the seventh
-    operand."""
-    from horovod_tpu.jax.introspect import KERNEL_DSA_DKV, KERNEL_FLASH_DKV
+    operand. ``with_dq``: dQ is the FIRST result, the query head's whole
+    (sq_pad, D) panel in ``dtype``: an output block of the head's
+    ``num_kb`` steps, like q and dO's panels, written at the last from a
+    float32 scratch as large."""
+    from horovod_tpu.jax.introspect import (
+        KERNEL_DSA_DKV,
+        KERNEL_FLASH_BWD,
+        KERNEL_FLASH_DKV,
+    )
 
-    name = KERNEL_DSA_DKV if tiles.learned else KERNEL_FLASH_DKV
-    learnt = dict(select_rows=words * tiles.block_k)
+    name = KERNEL_DSA_DKV if tiles.learned else \
+        KERNEL_FLASH_BWD if with_dq else KERNEL_FLASH_DKV
+    params = _compiler_params(_key_major_need(
+        tiles, group, d, d_v, sq_pad, sk_pad, dtype, words, with_dq))
     block_q, block_k = tiles.block_q, tiles.block_k
-    kernel = functools.partial(_bwd_dkv_kernel, tiles=tiles, scale=scale,
-                               group=group)
+    kernel = functools.partial(_bwd_kernel, interp=interp) if with_dq \
+        else _bwd_dkv_kernel
+    kernel = functools.partial(kernel, tiles=tiles, scale=scale, group=group)
+    scratch = [pltpu.VMEM((sq_pad, d), jnp.float32)] if with_dq else []
     rows = (None, None, tiles.num_qb, 1, block_q)
+
+    def call(grid, in_specs, out_specs, out_shape):
+        if with_dq:     # dQ's panel is q's: the same block, the same map
+            out_specs = in_specs[:1] + out_specs
+            out_shape = [jax.ShapeDtypeStruct((b, h, sq_pad, d), dtype)] \
+                + out_shape
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch,
+            compiler_params=params, interpret=interp, name=name)
+
     if group == 1:
         rows_panel = pl.BlockSpec(rows, lambda bi, hi, kj: (bi, hi, 0, 0, 0))
         in_specs = [_panel_spec(sq_pad, d), _plane_spec(block_k, d),
@@ -727,15 +859,11 @@ def _dkv_call(tiles, group, b, h, d, d_v, sq_pad, sk_pad, dtype, scale,
                     rows_panel, rows_panel]
         if words:
             in_specs.append(_select_spec(words, block_k))
-        return pl.pallas_call(
-            kernel, grid=(b, h, tiles.num_kb),
-            in_specs=in_specs,
-            out_specs=[_plane_spec(block_k, d), _plane_spec(block_k, d_v)],
-            out_shape=[jax.ShapeDtypeStruct((b, h, sk_pad, w), dtype)
-                       for w in (d, d_v)],
-            compiler_params=_compiler_params(sq_pad, d, d_v, dtype, block_q,
-                                             block_k, **learnt),
-            interpret=interp, name=name)
+        return call(
+            (b, h, tiles.num_kb), in_specs,
+            [_plane_spec(block_k, d), _plane_spec(block_k, d_v)],
+            [jax.ShapeDtypeStruct((b, h, sk_pad, w), dtype)
+             for w in (d, d_v)])
 
     def of_query_head(*block):
         return lambda bi, hk, gi, kj: (bi, hk * group + gi) + block
@@ -753,36 +881,26 @@ def _dkv_call(tiles, group, b, h, d, d_v, sq_pad, sk_pad, dtype, scale,
         return bi, hk, 0, 0
 
     rows_panel = pl.BlockSpec(rows, of_query_head(0, 0, 0))
-    params = _compiler_params(sq_pad, d, d_v, dtype, block_q, block_k,
-                              sk_pad, **learnt)
     in_specs = [q_panel(d), kv_spec(block_k, d, block),
                 kv_spec(block_k, d_v, block), q_panel(d_v),
                 rows_panel, rows_panel]
     if words:
         in_specs.append(_select_spec(
             words, block_k, lambda bi, hk, gi, kj: (bi, 0, kj, 0)))
-    return pl.pallas_call(
-        kernel, grid=(b, h // group, group, tiles.num_kb),
-        in_specs=in_specs,
-        out_specs=[kv_spec(sk_pad, d, panel), kv_spec(sk_pad, d_v, panel)],
-        out_shape=[jax.ShapeDtypeStruct((b, h // group, sk_pad, w),
-                                        jnp.float32) for w in (d, d_v)],
-        compiler_params=params, interpret=interp, name=name)
+    return call(
+        (b, h // group, group, tiles.num_kb), in_specs,
+        [kv_spec(sk_pad, d, panel), kv_spec(sk_pad, d_v, panel)],
+        [jax.ShapeDtypeStruct((b, h // group, sk_pad, w), jnp.float32)
+         for w in (d, d_v)])
 
 
-@_scoped
-def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
-    from horovod_tpu.jax.introspect import (
-        KERNEL_DSA_DKV,
-        KERNEL_DSA_DQ,
-        KERNEL_FLASH_DKV,
-        KERNEL_FLASH_DQ,
-    )
-
+def _bwd_operands(block_q, block_k, causal, window, res, g):
+    """What the backward kernels take, from the forward's residuals and
+    the output's cotangent: the tiles, then q, k, v and dO padded to
+    whole blocks, the log-sum-exp and delta (B, H, sq_pad) and the
+    learned planes (or None)."""
     q, k, v, out, lse, select = res
-    b, h, s, d = q.shape
-    d_v = v.shape[3]
-    kv_len, group = k.shape[2], h // k.shape[1]
+    s, kv_len = q.shape[2], k.shape[2]
     do = g.astype(jnp.float32)
     delta = jnp.sum(do * out.astype(jnp.float32), axis=-1)  # (B, H, S)
 
@@ -790,29 +908,62 @@ def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
     kp = _pad_seq(k, block_k)
     vp = _pad_seq(v, block_k)
     dop = _pad_seq(g.astype(q.dtype), block_q)
-    sq_pad, sk_pad = qp.shape[2], kp.shape[2]
     tiles = _Tiles(block_q, block_k, causal, s, kv_len, window,
                    select is not None)
     # Padded query rows: lse=0, delta=0 → p = exp(-0)=1 rows would pollute
     # dk/dv; guard with lse=+inf so exp(s - lse) = 0.
-    pad_q = ((0, 0), (0, 0), (0, sq_pad - s))
+    pad_q = ((0, 0), (0, 0), (0, qp.shape[2] - s))
     lsep = jnp.pad(lse, pad_q, constant_values=jnp.inf)
     deltap = jnp.pad(delta, pad_q)
-    # Two forms of the same numbers. dQ is query-major and takes
-    # (block_q, 1) columns; dK/dV is key-major and takes one lane-major
-    # (1, block_q) row per query block, (B, H, num_qb, 1, block_q),
-    # whose whole panel is 8 sublanes a block in VMEM where an (S, 1)
-    # column is 128 lanes a row. Turning one into the other inside a
-    # kernel costs a relayout per element (PERF.md, PR 25).
-    as_rows = (b, h, tiles.num_qb, 1, block_q)
-    lse_rows, delta_rows = lsep.reshape(as_rows), deltap.reshape(as_rows)
-    lse_col, delta_col = lsep[..., None], deltap[..., None]
+    return tiles, qp, kp, vp, dop, lsep, deltap, select
 
-    interp = _should_interpret(interpret)
+
+def _as_rows(tiles, x):
+    """(B, H, sq_pad) -> (B, H, num_qb, 1, block_q): what a key-major
+    kernel takes, one lane-major (1, block_q) row per query block, whose
+    whole panel is 8 sublanes a block in VMEM where an (S, 1) column is
+    128 lanes a row. Turning one form into the other inside a kernel
+    costs a relayout per element (PERF.md, PR 25)."""
+    return x.reshape(x.shape[:2] + (tiles.num_qb, 1, tiles.block_q))
+
+
+def _bwd_one_pass(tiles, scale, interp, qp, kp, vp, dop, lsep, deltap):
+    """dQ, dK, dV (padded; dK and dV float32 where grouped) of a static
+    mask by ONE kernel, ``hvd_flash_bwd``: five products a score tile.
+    It takes no learned planes: ``_flash_bwd`` never hands it any."""
+    from horovod_tpu.jax.introspect import KERNEL_FLASH_BWD
+
+    (b, h, sq_pad, d), d_v = qp.shape, vp.shape[3]
+    group = h // kp.shape[1]
+    _count_tiles(KERNEL_FLASH_BWD, tiles, (b, h, tiles.q_len, d), d_v,
+                 qp.dtype, group)
+    return _dkv_call(tiles, group, b, h, d, d_v, sq_pad, kp.shape[2],
+                     qp.dtype, scale, interp, with_dq=True)(
+        qp, kp, vp, dop, _as_rows(tiles, lsep), _as_rows(tiles, deltap))
+
+
+def _bwd_two_kernels(tiles, scale, interp, qp, kp, vp, dop, lsep, deltap,
+                     select=None):
+    """dQ, dK, dV (padded; dK and dV float32 where grouped) by the
+    key-major dK/dV kernel and the query-major dQ kernel, which each
+    make ``q . k^T`` and ``dO . v^T``: seven products a score tile. dQ
+    takes the log-sum-exp and delta as (block_q, 1) columns."""
+    from horovod_tpu.jax.introspect import (
+        KERNEL_DSA_DKV,
+        KERNEL_DSA_DQ,
+        KERNEL_FLASH_DKV,
+        KERNEL_FLASH_DQ,
+    )
+
+    (b, h, sq_pad, d), d_v = qp.shape, vp.shape[3]
+    sk_pad, group = kp.shape[2], h // kp.shape[1]
+    shape = (b, h, tiles.q_len, d)
+    block_q, block_k = tiles.block_q, tiles.block_k
     dkv_name, dq_name = (KERNEL_DSA_DKV, KERNEL_DSA_DQ) if tiles.learned \
         else (KERNEL_FLASH_DKV, KERNEL_FLASH_DQ)
-    dkv_operands = (qp, kp, vp, dop, lse_rows, delta_rows)
-    dq_operands = (qp, kp, vp, dop, lse_col, delta_col)
+    dkv_operands = (qp, kp, vp, dop, _as_rows(tiles, lsep),
+                    _as_rows(tiles, deltap))
+    dq_operands = (qp, kp, vp, dop, lsep[..., None], deltap[..., None])
     dq_specs = [_plane_spec(block_q, d), _panel_spec(sk_pad, d, group),
                 _panel_spec(sk_pad, d_v, group), _plane_spec(block_q, d_v),
                 _plane_spec(block_q, 1), _plane_spec(block_q, 1)]
@@ -825,27 +976,42 @@ def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
         dkv_operands += (_pad_rows(select.by_key, sk_pad),)
         dq_operands += (_pad_rows(select.by_query, sq_pad),)
         dq_specs.append(_select_spec(query_words, block_q))
-    _count_tiles(dkv_name, tiles, q.shape, d_v, q.dtype, group)
-    dk, dv = _dkv_call(tiles, group, b, h, d, d_v, sq_pad, sk_pad, q.dtype,
+    _count_tiles(dkv_name, tiles, shape, d_v, qp.dtype, group)
+    dk, dv = _dkv_call(tiles, group, b, h, d, d_v, sq_pad, sk_pad, qp.dtype,
                        scale, interp, key_words)(*dkv_operands)
 
-    _count_tiles(dq_name, tiles, q.shape, d_v, q.dtype, group)
+    _count_tiles(dq_name, tiles, shape, d_v, qp.dtype, group)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, tiles=tiles, scale=scale),
         grid=(b, h, tiles.num_qb),
         in_specs=dq_specs,
         out_specs=_plane_spec(block_q, d),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
-        compiler_params=_compiler_params(
-            sk_pad, d, d_v, q.dtype, block_q, block_k,
-            select_rows=query_words * block_q),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), qp.dtype),
+        compiler_params=_compiler_params(_vmem_need(
+            sk_pad, d, d_v, qp.dtype, block_q, block_k,
+            select_rows=query_words * block_q)),
         interpret=interp,
         name=dq_name,
     )(*dq_operands)
+    return dq, dk, dv
 
+
+@_scoped
+def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
+    tiles, *operands = _bwd_operands(block_q, block_k, causal, window, res, g)
+    qp, kp, vp = operands[:3]
+    group = qp.shape[1] // kp.shape[1]
+    need = _key_major_need(tiles, group, qp.shape[3], vp.shape[3],
+                           qp.shape[2], kp.shape[2], qp.dtype, with_dq=True)
+    interp = _should_interpret(interpret)
+    if _one_pass(tiles.learned, need):      # a static mask: no planes
+        dq, dk, dv = _bwd_one_pass(tiles, scale, interp, *operands[:-1])
+    else:
+        dq, dk, dv = _bwd_two_kernels(tiles, scale, interp, *operands)
     if group > 1:   # the group's float32 sums, rounded once
-        dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
-    return dq[:, :, :s], dk[:, :, :kv_len], dv[:, :, :kv_len]
+        dk, dv = dk.astype(kp.dtype), dv.astype(vp.dtype)
+    return (dq[:, :, :tiles.q_len], dk[:, :, :tiles.kv_len],
+            dv[:, :, :tiles.kv_len])
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -65,8 +65,10 @@ def one_chip(topo):
     ((1, 1100, 4, 256), jnp.bfloat16),    # 256, and with padded keys
 ])
 def test_kernels_lower_for_v5e(one_chip, shape, dtype):
-    """Forward, dK/dV and dQ at the default tiles, (B, S, H, D) causal:
-    three Mosaic calls under their names."""
+    """Forward and the one-pass backward at the default tiles, (B, S, H,
+    D) causal: two Mosaic calls under their names (the backward's
+    transposed product ``ds^T . k`` and its resident float32 dQ are
+    what interpret mode cannot refuse)."""
     x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def step(q, k, v, g):
@@ -75,16 +77,36 @@ def test_kernels_lower_for_v5e(one_chip, shape, dtype):
         return (out,) + vjp(g)
 
     text = jax.jit(step).lower(x, x, x, x).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
-    for name in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
-                 introspect.KERNEL_FLASH_DQ):
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_BWD):
         assert "%" + name in text, name
+
+
+def test_the_two_backward_kernels_lower_past_the_cap(one_chip):
+    """A static mask whose one-pass panels pass the VMEM cap (1 x 32768
+    x 2 heads of 256 in bf16: q and dO 64 MiB, dQ 64 more) compiles as
+    it did: ``hvd_flash_dkv`` + ``hvd_flash_dq``, 72 MiB each."""
+    x = jax.ShapeDtypeStruct((1, 32768, 2, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    need = pallas_attention._vmem_need(32768, 256, 256, jnp.bfloat16, 512,
+                                       512, dq_rows=32768)
+    assert need == (136 << 20) and not pallas_attention._one_pass(False, need)
+
+    def step(q, k, v, g):
+        return jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=False), q, k, v)[1](g)
+
+    text = jax.jit(step).lower(x, x, x, x).compile().as_text()
+    for name in (introspect.KERNEL_FLASH_DKV, introspect.KERNEL_FLASH_DQ):
+        assert "%" + name in text, name
+    assert "%" + introspect.KERNEL_FLASH_BWD not in text
 
 
 @pytest.mark.parametrize("seq,n_q,n_kv,head_dim,d_v,window", [
     (8192, 32, 4, 128, 128, 2048), (8192, 32, 4, 128, 128, None),
     (16384, 32, 8, 64, 64, None),
-    (8192, 20, 10, 64, 128, 512), (8192, 20, 10, 64, 128, None)])
+    (8192, 20, 10, 64, 128, 512), (8192, 20, 10, 64, 128, None),
+    (8192, 40, 20, 64, 128, None)])
 def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, seq, n_q, n_kv,
                                                     head_dim, d_v, window):
     """trinity-s8192-ep8-c1's attention, a sliding and a full layer: 32
@@ -115,12 +137,11 @@ def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, seq, n_q, n_kv,
     shape_of = dict(re.findall(r"%([\w.\-]+) = (\S+\[[\d,]*\])", text))
     calls = [line.strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 3
+    assert len(calls) == 2
     narrow = "[1,%d,%d,%d]" % (n_kv, seq, head_dim)
     narrow_v = "[1,%d,%d,%d]" % (n_kv, seq, d_v)
     wide = "[1,%d,%d,%d]" % (n_q, seq, head_dim)
-    for name in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
-                 introspect.KERNEL_FLASH_DQ):
+    for name in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_BWD):
         assert len(re.findall(r"^\s*(?:ROOT )?%%%s[.\d]* = " % name, text,
                               re.M)) == 1, name
     for line in calls:
@@ -131,10 +152,11 @@ def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, seq, n_q, n_kv,
         assert shapes[0] == "bf16" + wide, (name, shapes)
         assert shapes[1] == "bf16" + narrow, (name, shapes)
         assert shapes[2] == "bf16" + narrow_v, (name, shapes)
-        if name.startswith(introspect.KERNEL_FLASH_DKV):
+        if name.startswith(introspect.KERNEL_FLASH_BWD):
             assert re.match(
-                r"\(f32%s\S*, f32%s" % (re.escape(narrow),
-                                        re.escape(narrow_v)),
+                r"\(bf16%s\S*, f32%s\S*, f32%s" % (
+                    re.escape(wide), re.escape(narrow),
+                    re.escape(narrow_v)),
                 line.split(" = ")[1]), line[:200]
     assert wide not in "".join(
         line for line in text.splitlines() if " broadcast(" in line)
@@ -185,8 +207,8 @@ def test_masked_kernels_lower_for_v5e(one_chip):
     assert operands_of == {introspect.KERNEL_DSA_FWD: 4,
                            introspect.KERNEL_DSA_DKV: 7,
                            introspect.KERNEL_DSA_DQ: 7}
-    for static in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
-                   introspect.KERNEL_FLASH_DQ):
+    for static in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_BWD,
+                   introspect.KERNEL_FLASH_DKV, introspect.KERNEL_FLASH_DQ):
         assert "%" + static not in text
 
 
@@ -633,7 +655,8 @@ def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
     """GLM-4.7-Flash's step at its tiny sizes (latent attention, a dense
     block, two expert blocks that hold 2 of 16 experts, every block
     recomputed), compiled for a described v5e: the forward kernel runs
-    ONCE a layer, as the backward kernels do, because the recomputation
+    ONCE a layer, as the one backward kernel does (``hvd_flash_bwd``;
+    no ``hvd_flash_dkv``, no ``hvd_flash_dq``), because the recomputation
     keeps its operands, output and log-sum-exp (``models/transformer.py``
     ``_REMAT_KEEPS``); no matmul of the attention module or of a dense
     feed-forward (the dense block's, the shared expert's) stands under
@@ -644,15 +667,7 @@ def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
     kernel's results alone; and at these sizes (T x k = 254 pairs,
     under one row tile) an expert layer runs one body over all of them:
     no ``conditional``, the dead rows of the grouped matmuls masked by
-    selects.
-
-    The benchmark's builder still DECLARES two forward calls a layer
-    under ``remat`` (``benchmark/builders/glm4_moe_lite.py``
-    ``kernels()``, written when plain recomputation ran the kernel
-    twice): twice what runs, so ``kernel.flash_roofline`` in the GLM
-    cell over-reads until that count comes from the trace (ROADMAP D13
-    (9)); the per-kernel rooflines divide by the calls the trace holds
-    and are right. Both numbers are held here so that the repair shows."""
+    selects."""
     from benchmark import cell as cells
 
     monkeypatch.setattr(pallas_attention, "_should_interpret",
@@ -669,11 +684,13 @@ def test_the_held_expert_layer_under_recomputation_compiles(topo, monkeypatch):
     calls = {name: len(re.findall(r"^\s*(?:ROOT )?%%%s[.\d]* = " % name, text,
                                   re.M))
              for name in (introspect.KERNEL_FLASH_FWD,
+                          introspect.KERNEL_FLASH_BWD,
                           introspect.KERNEL_FLASH_DKV,
                           introspect.KERNEL_FLASH_DQ)}
-    assert calls == dict.fromkeys(calls, layers), calls
-    assert asm.model.kernels(1)["fwd"][0] == 2 * layers    # declared, stale
-    assert asm.model.kernels(1)["dkv"][0] == layers
+    assert calls == {introspect.KERNEL_FLASH_FWD: layers,
+                     introspect.KERNEL_FLASH_BWD: layers,
+                     introspect.KERNEL_FLASH_DKV: 0,
+                     introspect.KERNEL_FLASH_DQ: 0}, calls
     # No forward kernel under the recomputed forward any more.
     scopes = introspect.instruction_scopes(text)
     forward = [scope for name, scope in scopes.items()
@@ -722,8 +739,9 @@ def test_a_recomputed_conv_block_multiplies_nothing_but_its_router(
     """LFM2-8B-A1B's step at its tiny sizes (a dense conv block, then an
     attention and a conv expert block that hold 2 of 8 experts, no
     shared expert, every block recomputed), compiled for a described
-    v5e: the three flash kernels ONCE (one attention layer; a conv
-    layer calls none); under the recomputed forward no ``dot`` /
+    v5e: the two flash kernels ONCE (one attention layer; a conv
+    layer calls none; the backward is ``hvd_flash_bwd`` alone); under
+    the recomputed forward no ``dot`` /
     ``convolution`` of a ``conv`` module or of the dense feed-forward
     (their products are kept: ``_REMAT_KEEPS``), only each router's
     logits and the q and k projections that stand before the attention
@@ -739,10 +757,13 @@ def test_a_recomputed_conv_block_multiplies_nothing_but_its_router(
     calls = {name: len(re.findall(r"^\s*(?:ROOT )?%%%s[.\d]* = " % name, text,
                                   re.M))
              for name in (introspect.KERNEL_FLASH_FWD,
+                          introspect.KERNEL_FLASH_BWD,
                           introspect.KERNEL_FLASH_DKV,
                           introspect.KERNEL_FLASH_DQ)}
-    assert calls == dict.fromkeys(calls, 1), calls
-    assert asm.model.kernels(1)["fwd"][0] == 1
+    assert calls == {introspect.KERNEL_FLASH_FWD: 1,
+                     introspect.KERNEL_FLASH_BWD: 1,
+                     introspect.KERNEL_FLASH_DKV: 0,
+                     introspect.KERNEL_FLASH_DQ: 0}, calls
     recomputed = _matmuls_recomputed(text)
     routers = [s for s in recomputed if introspect.SCOPE_MOE_ROUTER in s]
     attention = [s for s in recomputed if "/attn/" in s]

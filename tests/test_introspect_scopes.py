@@ -100,9 +100,9 @@ def test_compiled_step_names_sync_update_head_and_kernels():
                 for s in scopes.values() if fragment in s}
 
     flash = "/attn/%s/" % introspect.SCOPE_FLASH
+    # The static backward is ONE named call under ``hvd_flash``.
     for kernel, direction in ((introspect.KERNEL_FLASH_FWD, "/jvp("),
-                              (introspect.KERNEL_FLASH_DKV, "/transpose("),
-                              (introspect.KERNEL_FLASH_DQ, "/transpose(")):
+                              (introspect.KERNEL_FLASH_BWD, "/transpose(")):
         assert len(layers_with(flash + kernel + "/")) == N_LAYERS
         assert all(direction in s for s in scopes.values()
                    if flash + kernel + "/" in s)

@@ -181,6 +181,7 @@ def _tiles_moved(trace):
         return {(k, kind): pallas_attention._M_TILES.labels(
             kernel=k, kind=kind).get()
             for k in (introspect.KERNEL_FLASH_FWD,
+                      introspect.KERNEL_FLASH_BWD,
                       introspect.KERNEL_FLASH_DKV,
                       introspect.KERNEL_FLASH_DQ,
                       introspect.KERNEL_DSA_FWD, introspect.KERNEL_DSA_DKV,
@@ -207,7 +208,7 @@ def _calls_moved(trace):
 
 def _trace_gradient(b, s, h, h_kv, d, window=None, selected=False, d_v=None,
                     **tiles):
-    """Trace forward, dK/dV and dQ at the shape; nothing runs."""
+    """Trace forward and backward at the shape; nothing runs."""
     q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((b, s, h_kv, d), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((b, s, h_kv, d_v or d), jnp.bfloat16)
@@ -247,10 +248,13 @@ def test_tile_counter_at_trace_time(shape, blocks, want):
     b, s, h, d = shape
     moved = _tiles_moved(lambda: _trace_gradient(
         b, s, h, h, d, block_q=blocks[0], block_k=blocks[1]))
-    for k in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
-              introspect.KERNEL_FLASH_DQ):
+    for k in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_BWD):
         got = tuple(moved[k, kind] for kind in ("full", "edge", "skipped"))
         assert got == want, (k, got)
+    # A static mask's backward is ONE kernel: the two it replaces trace
+    # nothing.
+    assert not any(n for (k, _), n in moved.items() if k in (
+        introspect.KERNEL_FLASH_DKV, introspect.KERNEL_FLASH_DQ))
 
 
 def _cell_attention_shapes():
@@ -299,8 +303,8 @@ def _cell_attention_shapes():
                          _cell_attention_shapes())
 def test_no_tiles_given_takes_the_rule_at_the_cells_shapes(
         b, s, h, h_kv, d, window, selected):
-    """A call that names no tile, as the model's is, traces the three
-    kernels with ``_default_blocks``' tiles and nothing else's."""
+    """A call that names no tile, as the model's is, traces its kernels
+    with ``_default_blocks``' tiles and nothing else's."""
     shape = (b, s, h, h_kv, d, window, selected)
     block_q, block_k = pallas_attention._default_blocks(s, s)
     by_rule = _tiles_moved(lambda: _trace_gradient(
@@ -315,7 +319,7 @@ def test_no_tiles_given_takes_the_rule_at_the_cells_shapes(
 def _pallas_calls(jaxpr):
     """Every ``pallas_call`` equation of a jaxpr, inner jaxprs included,
     as name -> (grid, block shapes of operands then results, result
-    shapes, scoped-VMEM limit or None)."""
+    shapes, scoped-VMEM limit or None, scratch shapes)."""
     found = {}
 
     def walk(j):
@@ -331,7 +335,9 @@ def _pallas_calls(jaxpr):
                      for spec in mapping.block_mappings],
                     [(aval.shape, aval.dtype.name)
                      for aval in eqn.params["out_avals"]],
-                    params and params.vmem_limit_bytes)
+                    params and params.vmem_limit_bytes,
+                    [(aval.shape, aval.dtype.name)
+                     for aval in mapping.scratch_avals])
             for value in eqn.params.values():
                 for sub in value if isinstance(value, (list, tuple)) \
                         else (value,):
@@ -343,12 +349,16 @@ def _pallas_calls(jaxpr):
     return found
 
 
-def _one_width_limit(panel_rows, d, block, out_rows=0, select_rows=0):
+def _one_width_limit(panel_rows, d, block, out_rows=0, select_rows=0,
+                     dq_rows=0):
     """The scoped-VMEM limit as the kernels reckoned it while they had
     ONE width (two bf16 panels and, grouped, two float32 output panels,
-    all ``max(d, 128)`` lanes): what ``d_v == d`` must still give."""
+    all ``max(d, 128)`` lanes): what ``d_v == d`` must still give; and,
+    for the one-pass backward, dQ's float32 scratch and its bf16 output
+    panel, double buffered, ``dq_rows`` rows besides."""
     panels = 2 * 2 * panel_rows * max(d, 128) * 2
     panels += 2 * 2 * out_rows * max(d, 128) * 4
+    panels += dq_rows * max(d, 128) * (4 + 2 * 2)
     if select_rows:
         panels += 2 * select_rows * 128 * 4 + 2 * 4 * block * block
     need = panels + 6 * 4 * block * block + (2 << 20)
@@ -366,17 +376,22 @@ def _one_width_limit(panel_rows, d, block, out_rows=0, select_rows=0):
 ])
 def test_the_calls_by_their_shapes_and_limits(b, s, h, h_kv, d, window,
                                               selected, d_v):
-    """The three ``pallas_call``s of a traced gradient at the cells'
-    shapes: grid, every block, every result and the scoped-VMEM limit.
-    With ``d_v == d`` they are what the kernels gave while one ``d``
-    built every spec (the limit by that rule, written out above): the
-    older cells' programs do not move. With another ``d_v`` the blocks
-    of v, dO, the output and dV alone take it, and the limit takes each
-    panel at its own width."""
+    """The ``pallas_call``s of a traced gradient at the cells' shapes:
+    grid, every block, every result, the scoped-VMEM limit and the
+    scratch. A static mask: the forward and ONE backward,
+    ``hvd_flash_bwd``, on the key-major grid, with dQ its first result,
+    the query head's whole panel, beside a float32 scratch as large. A
+    learned mask: the forward, dK/dV and dQ exactly as they were. With
+    ``d_v == d`` the forward (and the learned three) are what the
+    kernels gave while one ``d`` built every spec (the limit by that
+    rule, written out above): with another ``d_v`` the blocks of v, dO,
+    the output and dV alone take it, and the limit takes each panel at
+    its own width."""
     calls = _pallas_calls(_trace_gradient(b, s, h, h_kv, d, window, selected,
                                           d_v=d_v))
     prefix = "hvd_dsa_" if selected else "hvd_flash_"
-    assert sorted(calls) == [prefix + k for k in ("dkv", "dq", "fwd")]
+    assert sorted(calls) == [prefix + k for k in (
+        ("dkv", "dq", "fwd") if selected else ("bwd", "fwd"))]
     block, group = 512, h // h_kv
     n, words = s // block, -(-s // 4096)
     # Block shapes as the specs give them: None where a dimension is
@@ -388,46 +403,66 @@ def test_the_calls_by_their_shapes_and_limits(b, s, h, h_kv, d, window,
             (s, d_v), (block, 1)))
     rows = (None, None, n, 1, block)
 
-    def limit(panel_d, panel_dv, out_rows=0, select_rows=0):
+    def limit(panel_d, panel_dv, out_rows=0, select_rows=0, dq_rows=0):
         if d_v == d:
-            return _one_width_limit(s, d, block, out_rows, select_rows)
+            return _one_width_limit(s, d, block, out_rows, select_rows,
+                                    dq_rows)
         lanes = max(panel_d, 128) + max(panel_dv, 128)
         need = 2 * s * lanes * 2 + 2 * out_rows * lanes * 4 + (
             2 * select_rows * 128 * 4 + 2 * 4 * block * block
-            if select_rows else 0) + 6 * 4 * block * block + (2 << 20)
+            if select_rows else 0) + dq_rows * max(panel_d, 128) * 8 \
+            + 6 * 4 * block * block + (2 << 20)
         return None if need <= (16 << 20) else min(need, 100 << 20)
 
     sel_rows = words * block if selected else 0
-    grid, blocks, results, vmem = calls[prefix + "fwd"]
+    grid, blocks, results, vmem, scratch = calls[prefix + "fwd"]
     assert grid == (b, h, n)
     assert blocks == [q_blk, k_pan, v_pan] + plane + [v_blk, col]
     assert results == [((b, h, s, d_v), "bfloat16"),
                        ((b, h, s, 1), "float32")]
     assert vmem == limit(d, d_v, select_rows=sel_rows)
+    assert scratch == []
 
-    grid, blocks, results, vmem = calls[prefix + "dq"]
+    if group == 1:
+        key_major = (b, h, n)
+        outs, out_rows, kind = [k_blk, v_blk], 0, "bfloat16"
+    else:       # the key/value head's float32 panels stay resident
+        key_major = (b, h_kv, group, n)
+        outs, out_rows, kind = [k_pan, v_pan], s, "float32"
+    dkv_results = [((b, h_kv, s, d), kind), ((b, h_kv, s, d_v), kind)]
+    if not selected:
+        grid, blocks, results, vmem, scratch = calls["hvd_flash_bwd"]
+        assert grid == key_major
+        assert blocks == [q_pan, k_blk, v_blk, do_pan, rows, rows] \
+            + [q_pan] + outs
+        assert results == [((b, h, s, d), "bfloat16")] + dkv_results
+        assert vmem == limit(d, d_v, out_rows, dq_rows=s)
+        assert vmem is None or vmem < (100 << 20)    # under the cap
+        assert scratch == [((s, d), "float32")]
+        return
+
+    grid, blocks, results, vmem, scratch = calls[prefix + "dq"]
     assert grid == (b, h, n)
     assert blocks == [q_blk, k_pan, v_pan, v_blk, col, col] + plane + [q_blk]
     assert results == [((b, h, s, d), "bfloat16")]
     assert vmem == limit(d, d_v, select_rows=sel_rows)
+    assert scratch == []
 
-    grid, blocks, results, vmem = calls[prefix + "dkv"]
-    if group == 1:
-        assert grid == (b, h, n)
-        outs, out_rows, kind = [k_blk, v_blk], 0, "bfloat16"
-    else:       # the key/value head's float32 panels stay resident
-        assert grid == (b, h_kv, group, n)
-        outs, out_rows, kind = [k_pan, v_pan], s, "float32"
+    grid, blocks, results, vmem, scratch = calls[prefix + "dkv"]
+    assert grid == key_major
     assert blocks == [q_pan, k_blk, v_blk, do_pan, rows, rows] + plane + outs
-    assert results == [((b, h_kv, s, d), kind), ((b, h_kv, s, d_v), kind)]
+    assert results == dkv_results
     assert vmem == limit(d, d_v, out_rows, sel_rows)
+    assert scratch == []
 
 
 def test_the_call_counter_tells_the_widths():
     """hvd_flash_calls_total{kernel,widths} moves by one a traced
     kernel: under ``"64"`` where v is as wide as q.k, under
-    ``"64+128"`` where it is not, and under no other label."""
-    names = ("hvd_flash_fwd", "hvd_flash_dkv", "hvd_flash_dq")
+    ``"64+128"`` where it is not, and under no other label. A static
+    mask moves ``hvd_flash_bwd`` and neither ``hvd_flash_dkv`` nor
+    ``hvd_flash_dq``; a learned one its three names as before."""
+    names = ("hvd_flash_fwd", "hvd_flash_bwd")
     assert _calls_moved(lambda: _trace_gradient(1, 1024, 4, 2, 64)) \
         == {(name, "64"): 1 for name in names}
     assert _calls_moved(lambda: _trace_gradient(
@@ -437,6 +472,42 @@ def test_the_call_counter_tells_the_widths():
         1, 1024, 4, 2, 64, selected=True, d_v=32)) \
         == {(name, "64+32"): 1
             for name in ("hvd_dsa_fwd", "hvd_dsa_dkv", "hvd_dsa_dq")}
+
+
+@pytest.mark.parametrize("learned,need,one", [
+    (False, 16 << 20, True),            # any static mask that fits
+    (False, 100 << 20, True),           # up to the cap itself
+    (False, (100 << 20) + 1, False),    # panels past the cap: two kernels
+    (True, 16 << 20, False),            # a mask that is data: two kernels
+    (True, 200 << 20, False),
+])
+def test_which_backward_runs_is_a_rule_on_the_input(learned, need, one):
+    assert pallas_attention._one_pass(learned, need) is one
+
+
+@pytest.mark.parametrize("s,h,h_kv,d,d_v,dtype,names", [
+    # lfm2-s16384-ep4-c1, the largest panels of any cell: 64 MiB.
+    (16384, 32, 8, 64, 64, jnp.bfloat16, ("bwd", "fwd")),
+    # Twice its rows at 256 wide: q and dO 64 MiB, dQ 64 more.
+    (32768, 2, 2, 256, 256, jnp.bfloat16, ("dkv", "dq", "fwd")),
+])
+def test_a_shape_past_the_cap_takes_the_two_kernels(s, h, h_kv, d, d_v,
+                                                    dtype, names):
+    """The rule reads the one pass's own VMEM reckoning: a static mask
+    whose resident panels pass 100 MiB traces ``hvd_flash_dkv`` +
+    ``hvd_flash_dq`` as it did, so that nothing that compiled stops
+    compiling; the cells' largest stays one pass."""
+    q = jax.ShapeDtypeStruct((1, s, h, d), dtype)
+    k = jax.ShapeDtypeStruct((1, s, h_kv, d), dtype)
+    v = jax.ShapeDtypeStruct((1, s, h_kv, d_v), dtype)
+    need = pallas_attention._vmem_need(
+        s, d, d_v, dtype, 512, 512, s if h != h_kv else 0, dq_rows=s)
+    assert (need <= (100 << 20)) == ("bwd" in names)
+    moved = _calls_moved(lambda: jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
+        (0, 1, 2)))(q, k, v))
+    assert sorted(name for name, _ in moved) \
+        == ["hvd_flash_" + n for n in names]
 
 
 @pytest.mark.parametrize("env,cached", [
@@ -652,7 +723,8 @@ def test_differential_attention_in_two_calls(window, monkeypatch):
     above: the output and every gradient, ``diff`` and the input
     included; four pairs of query heads over two of key/value heads;
     600 positions are two tiles of 384 with padded rows. The traced
-    layer counts two calls of each kernel, all at 8 + 16."""
+    layer counts two calls of each kernel (the forward, the one-pass
+    backward), all at 8 + 16."""
     from horovod_tpu.models.transformer import SelfAttention
 
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 600, 32))
@@ -674,7 +746,7 @@ def test_differential_attention_in_two_calls(window, monkeypatch):
     moved = _calls_moved(lambda: jax.make_jaxpr(jax.grad(
         lambda p: jnp.sum(flash.apply(p, x))))(params))
     assert moved == {(name, "8+16"): 2 for name in (
-        "hvd_flash_fwd", "hvd_flash_dkv", "hvd_flash_dq")}
+        "hvd_flash_fwd", "hvd_flash_bwd")}
     by_dense = graded(dense)
     monkeypatch.setattr(SelfAttention, "_differential",
                         _four_call_differential)
@@ -887,3 +959,112 @@ def test_tile_counter_and_log_line_carry_window_and_group(caplog):
     assert below.get() - before == sum(range(6))
     assert "window 256, 4 query head(s) a key/value head" in caplog.text
     assert "window None, 4 query head(s) a key/value head" in caplog.text
+
+
+# --------------------------------------------------- the one-pass backward ---
+
+
+def _one_pass_cases():
+    """causal / window / non-causal by group 1 / 2 / 8 by float32 /
+    bf16, over 1100 rows (padded keys and rows at both tilings); the
+    widths (64 + 64, 64 + 128) and the two tilings turn with the case,
+    so that each meets every mask, group and dtype. A window of 300
+    under 128-row key blocks: query blocks past row 427 never see key
+    block 0, their dQ rows' first visit is a later ``kj``."""
+    cases = []
+    masks = ((True, None), (True, 300), (False, None))
+    for i, (causal, window) in enumerate(masks):
+        for j, group in enumerate((1, 2, 8)):
+            for n, dtype in enumerate((jnp.float32, jnp.bfloat16)):
+                d_v = (64, 128)[(i + j + n) % 2]
+                tiles = ((256, 128), (128, 384))[(i + j) % 2]
+                cases.append(pytest.param(
+                    causal, window, group, d_v, dtype, tiles,
+                    id="%s-g%d-64+%d-%s-%dx%d" % (
+                        "window" if window else
+                        "causal" if causal else "all", group, d_v,
+                        jnp.dtype(dtype).name, *tiles)))
+    return cases
+
+
+@pytest.mark.parametrize("causal,window,group,d_v,dtype,blocks",
+                         _one_pass_cases())
+def test_one_pass_backward_matches_dense_and_the_two_kernels(
+        causal, window, group, d_v, dtype, blocks):
+    """dQ, dK and dV of ``hvd_flash_bwd`` against the dense masked
+    attention's AND against ``hvd_flash_dkv`` + ``hvd_flash_dq``, both
+    reached through the wrappers ``_flash_bwd`` chooses between, on the
+    same operands: the same p, mask and delta, the same float32 sums in
+    the same order (dQ's key blocks ascending, each block's ``ds^T . k``
+    summed as the dQ kernel sums ``ds . k``: ``_dot_tn``), rounded once:
+    in interpret mode the two are equal BIT FOR BIT."""
+    s, heads, d = 1100, 8, 64
+    block_q, block_k = blocks
+    rng = np.random.RandomState(11)
+    q, k, v, g = (jnp.asarray(rng.randn(1, s, n, w), dtype)
+                  for n, w in ((heads, d), (heads // group, d),
+                               (heads // group, d_v), (heads, d_v)))
+
+    def reference(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        if causal:
+            return masked_reference(q, k, v, window)
+        return dense_reference(q, jnp.repeat(k, group, axis=2),
+                               jnp.repeat(v, group, axis=2), False)
+
+    want = jax.vjp(reference, q, k, v)[1](g.astype(jnp.float32))
+    # Through the public call: the rule takes the one pass.
+    moved = _calls_moved(lambda: jax.make_jaxpr(
+        lambda *a: jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, window=window, block_q=block_q,
+            block_k=block_k), *a[:3])[1](a[3]))(q, k, v, g))
+    assert sorted(name for name, _ in moved) \
+        == ["hvd_flash_bwd", "hvd_flash_fwd"]
+
+    scale = d ** -0.5
+    heads_first = [jnp.swapaxes(x, 1, 2) for x in (q, k, v, g)]
+    _, res = pallas_attention._flash_fwd_impl(
+        *heads_first[:3], causal, window, block_q, block_k, scale, True)
+    tiles, *operands = pallas_attention._bwd_operands(
+        block_q, block_k, causal, window, res, heads_first[3])
+    assert tiles.padded_keys and operands[0].shape[2] > s
+    if window:      # some query block's first visit is not key block 0
+        assert tiles.key_start(tiles.num_qb - 1) > 0
+    assert operands[-1] is None        # no learned planes
+    one = pallas_attention._bwd_one_pass(tiles, scale, True, *operands[:-1])
+    two = pallas_attention._bwd_two_kernels(tiles, scale, True, *operands)
+    assert one[0].dtype == two[0].dtype == dtype
+    # Padded query rows of dQ are written (zeros), not left as they were.
+    assert not np.asarray(one[0][:, :, s:], np.float32).any()
+    far = 1e-5 if dtype == jnp.float32 else 3e-2
+    for got, same, ref in zip(one, two, want):
+        assert got.shape == same.shape and got.dtype == same.dtype
+        got, same = (jnp.swapaxes(x[:, :, :s], 1, 2).astype(jnp.float32)
+                     for x in (got, same))
+        assert got.shape == ref.shape
+        assert (np.asarray(got) == np.asarray(same)).all()
+        assert _rel(got, ref) < far
+
+
+@pytest.mark.parametrize("rows,width", [(128, 32), (128, 64), (256, 128)])
+def test_the_transposed_product_has_one_meaning_in_both_modes(rows, width):
+    """``_dot_tn`` is ``a^T . b`` either way. A SQUARE ``a`` (a score
+    tile's block_k == block_q) would take the wrong contraction without
+    a shape error, so the form Mosaic compiles (``interp`` False: the
+    transposed dimension numbers) is held here to the interpret-mode
+    form within float32's rounding, and that one to the plain product of
+    the transposed tile BIT FOR BIT, also under ``jit``, where XLA:CPU
+    would fold a bare ``.T`` into the product and add in another
+    order at some of these widths."""
+    rng = np.random.RandomState(5)
+    a = jnp.asarray(rng.randn(rows, rows), jnp.float32)
+    b = jnp.asarray(rng.randn(rows, width), jnp.float32)
+    plain = np.asarray(pallas_attention._dot(
+        jnp.asarray(np.ascontiguousarray(np.asarray(a).T)), b,
+        pallas_attention._NN))
+    assert not np.allclose(plain, np.asarray(a @ b), atol=1.0)
+    on_chip, interpreted = (
+        np.asarray(jax.jit(lambda a, b, m=m: pallas_attention._dot_tn(
+            a, b, m))(a, b)) for m in (False, True))
+    assert (interpreted == plain).all()
+    assert np.abs(on_chip - plain).max() < 1e-5 * np.abs(plain).max()

@@ -25,8 +25,8 @@ from horovod_tpu.models import BlockSpec, Transformer, TransformerConfig
 from horovod_tpu.models import transformer as transformer_module
 
 LAYERS = 3
-KERNELS = (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_DKV,
-           introspect.KERNEL_FLASH_DQ)
+# A static mask's kernels: the forward and the one-pass backward.
+KERNELS = (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_BWD)
 
 PLAIN = BlockSpec()
 LATENT = BlockSpec(
@@ -229,8 +229,7 @@ def test_plain_recomputation_would_run_the_forward_kernel_twice(monkeypatch):
                         lambda cfg: nn.remat(transformer_module.Block))
     calls = _kernel_calls(_gradient_jaxpr(_model(True), _variables()))
     assert calls == {introspect.KERNEL_FLASH_FWD: 2 * LAYERS,
-                     introspect.KERNEL_FLASH_DKV: LAYERS,
-                     introspect.KERNEL_FLASH_DQ: LAYERS}
+                     introspect.KERNEL_FLASH_BWD: LAYERS}
 
 
 @pytest.mark.parametrize("block", list(SPECS.values()), ids=list(SPECS))

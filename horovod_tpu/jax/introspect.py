@@ -72,6 +72,16 @@ SCOPE_GMU = "hvd_gmu"
 # halves put side by side, lambda, the subtraction, the norm over each
 # head's 2 D dims and the factor ``1 - lambda_init``.
 SCOPE_DIFF_ATTN = "hvd_diff_attn"
+# A looped stack (``TransformerConfig.passes`` > 1: ONE stack of blocks
+# applied that many times a step): ``hvd_loop_pass_<t>`` round pass t of
+# the stack with the norm that closes it (numbered: the loop is
+# unrolled), ``hvd_loop_readout`` round each pass's output projection
+# (itself still under ``logits``) and cross entropy, ``hvd_loop_exit``
+# round the exit gate, the exit distribution and its entropy
+# (models/transformer.py ``looped_loss``).
+SCOPE_LOOP_PASS = "hvd_loop_pass"
+SCOPE_LOOP_READOUT = "hvd_loop_readout"
+SCOPE_LOOP_EXIT = "hvd_loop_exit"
 # The expert layer (parallel/moe.py), inside the ``moe`` module's scope.
 SCOPE_MOE_ROUTER = "hvd_moe_router"      # logits, softmax, top-k, aux losses
 # The sorts (the gates ride one into row order); rows gathered from the

@@ -16,4 +16,6 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     Transformer,
     TransformerConfig,
     get_param_specs,
+    looped_loss,
+    record_loop_stats,
 )

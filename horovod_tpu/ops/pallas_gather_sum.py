@@ -49,6 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.jax.introspect import KERNEL_MOE_GATHER_SUM
 from horovod_tpu.ops import pallas_attention
+from horovod_tpu.utils.timeline import trace_span
 
 # What a block of ``tb`` tokens may hold in VMEM, of Mosaic's default 16
 # MiB of scoped VMEM on a v5e: its float32 accumulator, a visit's float32
@@ -192,23 +193,25 @@ def _gather_sum(rows, visits, t, interpret):
     # all of their mantissa.
     precision = (lax.Precision.HIGHEST if rows.dtype == jnp.float32
                  else None)
-    return pl.pallas_call(
-        functools.partial(_kernel, tb=tb, ch=ch, precision=precision),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(visits.blocks.shape[0],),
-            in_specs=[
-                pl.BlockSpec((None, 1, ch),
-                             lambda w, blocks, chunks, _: (chunks[w], 0, 0)),
-                pl.BlockSpec((ch, m),
-                             lambda w, blocks, chunks, _: (chunks[w], 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (tb, m), lambda w, blocks, chunks, _: (blocks[w], 0)),
-            scratch_shapes=[pltpu.VMEM((tb, m), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((t, m), rows.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name=KERNEL_MOE_GATHER_SUM,
-    )(visits.blocks, visits.chunks, visits.scalars, visits.tokens, rows)
+    with trace_span("kernel", kernel=KERNEL_MOE_GATHER_SUM):
+        return pl.pallas_call(
+            functools.partial(_kernel, tb=tb, ch=ch, precision=precision),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(visits.blocks.shape[0],),
+                in_specs=[
+                    pl.BlockSpec(
+                        (None, 1, ch),
+                        lambda w, blocks, chunks, _: (chunks[w], 0, 0)),
+                    pl.BlockSpec(
+                        (ch, m), lambda w, blocks, chunks, _: (chunks[w], 0)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (tb, m), lambda w, blocks, chunks, _: (blocks[w], 0)),
+                scratch_shapes=[pltpu.VMEM((tb, m), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((t, m), rows.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name=KERNEL_MOE_GATHER_SUM,
+        )(visits.blocks, visits.chunks, visits.scalars, visits.tokens, rows)

@@ -47,6 +47,7 @@ from horovod_tpu.jax.introspect import (
 from horovod_tpu.ops import pallas_attention
 from horovod_tpu.ops.pallas_attention import _LANES, _NN, _NT
 from horovod_tpu.utils import metrics as _metrics
+from horovod_tpu.utils.timeline import trace_span
 
 # Counted at trace time: the grouped matmuls one traced expert layer
 # makes, by kind (``forward``, ``input_grad``, ``weight_grad``) and by
@@ -235,25 +236,27 @@ def _product(lhs, rhs, walk, transposed, interpret):
     # Two buffers of each block, and the tile once more as a value.
     need = (2 * (tm * depth + depth * tn + tm * tn) * item
             + tm * depth * item + (4 << 20))
-    return pl.pallas_call(
-        functools.partial(_product_kernel, transposed=transposed),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(width // tn, walk.count),
-            in_specs=[
-                pl.BlockSpec((tm, depth),
-                             lambda j, v, _, visits: (_tile(visits, v), 0)),
-                panel,
-            ],
-            out_specs=pl.BlockSpec(
-                (tm, tn), lambda j, v, _, visits: (_tile(visits, v), j))),
-        out_shape=jax.ShapeDtypeStruct((n, width), lhs.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(need)),
-        interpret=interpret,
-        name=KERNEL_MOE_GROUPED,
-    )(walk.offsets, walk.visits, lhs, rhs)
+    with trace_span("kernel", kernel=KERNEL_MOE_GROUPED):
+        return pl.pallas_call(
+            functools.partial(_product_kernel, transposed=transposed),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(width // tn, walk.count),
+                in_specs=[
+                    pl.BlockSpec(
+                        (tm, depth),
+                        lambda j, v, _, visits: (_tile(visits, v), 0)),
+                    panel,
+                ],
+                out_specs=pl.BlockSpec(
+                    (tm, tn), lambda j, v, _, visits: (_tile(visits, v), j))),
+            out_shape=jax.ShapeDtypeStruct((n, width), lhs.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=_vmem_limit(need)),
+            interpret=interpret,
+            name=KERNEL_MOE_GROUPED,
+        )(walk.offsets, walk.visits, lhs, rhs)
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
@@ -267,28 +270,31 @@ def _weight_grad(lhs, d_out, walk, dtype, interpret):
     # visit's product.
     need = (3 * tm * (tk + tn) * item + 2 * tk * tn * jnp.dtype(dtype).itemsize
             + 2 * tk * tn * 4 + (4 << 20))
-    return pl.pallas_call(
-        _weight_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(k // tk, width // tn, walk.count),
-            in_specs=[
-                pl.BlockSpec((tm, tk),
-                             lambda i, j, v, _, visits: (_tile(visits, v), i)),
-                pl.BlockSpec((tm, tn),
-                             lambda i, j, v, _, visits: (_tile(visits, v), j)),
-            ],
-            out_specs=pl.BlockSpec(
-                (None, tk, tn),
-                lambda i, j, v, _, visits: (_group(visits, v), i, j)),
-            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((groups, k, width), dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(need)),
-        interpret=interpret,
-        name=KERNEL_MOE_GROUPED_DW,
-    )(walk.offsets, walk.visits, lhs, d_out)
+    with trace_span("kernel", kernel=KERNEL_MOE_GROUPED_DW):
+        return pl.pallas_call(
+            _weight_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(k // tk, width // tn, walk.count),
+                in_specs=[
+                    pl.BlockSpec(
+                        (tm, tk),
+                        lambda i, j, v, _, visits: (_tile(visits, v), i)),
+                    pl.BlockSpec(
+                        (tm, tn),
+                        lambda i, j, v, _, visits: (_tile(visits, v), j)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (None, tk, tn),
+                    lambda i, j, v, _, visits: (_group(visits, v), i, j)),
+                scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((groups, k, width), dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_vmem_limit(need)),
+            interpret=interpret,
+            name=KERNEL_MOE_GROUPED_DW,
+        )(walk.offsets, walk.visits, lhs, d_out)
 
 
 def _counted(kind):

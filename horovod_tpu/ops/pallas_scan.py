@@ -49,6 +49,7 @@ from horovod_tpu.jax.introspect import (
     SCOPE_SSM_SCAN,
 )
 from horovod_tpu.ops import pallas_attention
+from horovod_tpu.utils.timeline import trace_span
 
 _GROUP = 8        # positions a group: one (8, lanes) float32 tile of rows
 # Positions between two kept states, and channels a tile (the widest of
@@ -211,20 +212,21 @@ def _fwd_call(x, dt, a, bc, d, chunk, interpret):
     bsz, t, e = x.shape
     n, tile = a.shape[0], _channel_tile(e)
     spec = _specs(n, tile, chunk, 0)
-    return pl.pallas_call(
-        _fwd_kernel,
-        grid=(bsz, e // tile, t // chunk),
-        in_specs=[spec["rows"], spec["rows"], spec["a"], spec["cols"],
-                  spec["d"]],
-        out_specs=[spec["rows"], spec["hb"]],
-        out_shape=[jax.ShapeDtypeStruct((bsz, t, e), jnp.float32),
-                   jax.ShapeDtypeStruct((bsz, t // chunk, n, e),
-                                        jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((n, tile), jnp.float32)],
-        compiler_params=_params(n * tile * 4, chunk, tile, n),
-        interpret=interpret,
-        name=KERNEL_SSM_SCAN_FWD,
-    )(x, dt, a, bc, d)
+    with trace_span("kernel", kernel=KERNEL_SSM_SCAN_FWD):
+        return pl.pallas_call(
+            _fwd_kernel,
+            grid=(bsz, e // tile, t // chunk),
+            in_specs=[spec["rows"], spec["rows"], spec["a"], spec["cols"],
+                      spec["d"]],
+            out_specs=[spec["rows"], spec["hb"]],
+            out_shape=[jax.ShapeDtypeStruct((bsz, t, e), jnp.float32),
+                       jax.ShapeDtypeStruct((bsz, t // chunk, n, e),
+                                            jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((n, tile), jnp.float32)],
+            compiler_params=_params(n * tile * 4, chunk, tile, n),
+            interpret=interpret,
+            name=KERNEL_SSM_SCAN_FWD,
+        )(x, dt, a, bc, d)
 
 
 @functools.partial(jax.jit, static_argnums=(7, 8))
@@ -239,26 +241,28 @@ def _bwd_call(x, dt, a, bc, d, hb, dy, chunk, interpret):
     per_row = jax.ShapeDtypeStruct((bsz, t, e), jnp.float32)
     per_col = jax.ShapeDtypeStruct(
         (tiles, bsz, t // _GROUP, 2 * n, _GROUP), jnp.float32)
-    dx, ddt, da, dbc, dd = pl.pallas_call(
-        _bwd_kernel,
-        grid=(bsz, tiles, chunks),
-        in_specs=[spec["rows"], spec["rows"], spec["a"], spec["cols"],
-                  spec["d"], spec["hb"], spec["rows"]],
-        out_specs=[
-            spec["rows"], spec["rows"],
-            pl.BlockSpec((None, n, tile), lambda bi, ei, ci: (bi, 0, ei)),
-            part,
-            pl.BlockSpec((None, 1, tile), lambda bi, ei, ci: (bi, 0, ei))],
-        out_shape=[per_row, per_row,
-                   jax.ShapeDtypeStruct((bsz, n, e), jnp.float32),
-                   per_col,
-                   jax.ShapeDtypeStruct((bsz, 1, e), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((chunk + 1, n, tile), jnp.float32),
-                        pltpu.VMEM((n, tile), jnp.float32)],
-        compiler_params=_params((chunk + 2) * n * tile * 4, chunk, tile, n),
-        interpret=interpret,
-        name=KERNEL_SSM_SCAN_BWD,
-    )(x, dt, a, bc, d, hb, dy)
+    with trace_span("kernel", kernel=KERNEL_SSM_SCAN_BWD):
+        dx, ddt, da, dbc, dd = pl.pallas_call(
+            _bwd_kernel,
+            grid=(bsz, tiles, chunks),
+            in_specs=[spec["rows"], spec["rows"], spec["a"], spec["cols"],
+                      spec["d"], spec["hb"], spec["rows"]],
+            out_specs=[
+                spec["rows"], spec["rows"],
+                pl.BlockSpec((None, n, tile), lambda bi, ei, ci: (bi, 0, ei)),
+                part,
+                pl.BlockSpec((None, 1, tile), lambda bi, ei, ci: (bi, 0, ei))],
+            out_shape=[per_row, per_row,
+                       jax.ShapeDtypeStruct((bsz, n, e), jnp.float32),
+                       per_col,
+                       jax.ShapeDtypeStruct((bsz, 1, e), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((chunk + 1, n, tile), jnp.float32),
+                            pltpu.VMEM((n, tile), jnp.float32)],
+            compiler_params=_params((chunk + 2) * n * tile * 4, chunk, tile,
+                                    n),
+            interpret=interpret,
+            name=KERNEL_SSM_SCAN_BWD,
+        )(x, dt, a, bc, d, hb, dy)
     return dx, ddt, da.sum(0), dbc.sum(0), dd.sum(0)
 
 

@@ -50,6 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 from horovod_tpu.jax.introspect import KERNEL_DSA_CHOOSE, SAVED_FLASH_SELECT
 from horovod_tpu.ops import pallas_attention
 from horovod_tpu.ops.pallas_attention import _LANES, _NN, _WORD, _dot, Selection
+from horovod_tpu.utils.timeline import trace_span
 
 _INT_MIN, _INT_MAX = -(1 << 31), (1 << 31) - 1
 # ``-inf`` as ``_ordered`` maps it: below every number's image.
@@ -232,30 +233,31 @@ def _choose(q, k, w, topk, chunk, interpret):
     words = pl.cdiv(s, _LANES * _WORD)
     plane = jax.ShapeDtypeStruct((b, words, s, _LANES), jnp.int32)
     need = _vmem_bytes(s, chunk, heads, d, words, q.dtype)
-    return pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, s // chunk),
-            in_specs=[
-                pl.BlockSpec((None, heads, d, chunk),
-                             lambda bi, qi, _: (bi, 0, 0, qi)),
-                pl.BlockSpec((None, s, d), lambda bi, qi, _: (bi, 0, 0)),
-                pl.BlockSpec((None, heads, chunk),
-                             lambda bi, qi, _: (bi, 0, qi)),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, words, chunk, _LANES),
-                             lambda bi, qi, _: (bi, 0, qi, 0)),
-                pl.BlockSpec((None, words, s, _LANES),
-                             lambda bi, qi, _: (bi, 0, 0, 0)),
-            ],
-            scratch_shapes=[pltpu.VMEM((s, chunk), jnp.int32)]),
-        out_shape=[plane, plane],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=(None if need <= (16 << 20)
-                              else min(need, 100 << 20))),
-        interpret=interpret,
-        name=KERNEL_DSA_CHOOSE,
-    )(topk, q, k, w)
+    with trace_span("kernel", kernel=KERNEL_DSA_CHOOSE):
+        return pl.pallas_call(
+            functools.partial(_kernel, chunk=chunk),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, s // chunk),
+                in_specs=[
+                    pl.BlockSpec((None, heads, d, chunk),
+                                 lambda bi, qi, _: (bi, 0, 0, qi)),
+                    pl.BlockSpec((None, s, d), lambda bi, qi, _: (bi, 0, 0)),
+                    pl.BlockSpec((None, heads, chunk),
+                                 lambda bi, qi, _: (bi, 0, qi)),
+                ],
+                out_specs=[
+                    pl.BlockSpec((None, words, chunk, _LANES),
+                                 lambda bi, qi, _: (bi, 0, qi, 0)),
+                    pl.BlockSpec((None, words, s, _LANES),
+                                 lambda bi, qi, _: (bi, 0, 0, 0)),
+                ],
+                scratch_shapes=[pltpu.VMEM((s, chunk), jnp.int32)]),
+            out_shape=[plane, plane],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=(None if need <= (16 << 20)
+                                  else min(need, 100 << 20))),
+            interpret=interpret,
+            name=KERNEL_DSA_CHOOSE,
+        )(topk, q, k, w)

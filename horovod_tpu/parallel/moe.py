@@ -74,6 +74,7 @@ from horovod_tpu.ops import pallas_gather_sum, pallas_grouped_matmul
 from horovod_tpu.parallel.mesh import DATA_AXIS, EXPERT_AXIS
 from horovod_tpu.parallel.mesh import traced_axis_size
 from horovod_tpu.utils import metrics as _metrics
+from horovod_tpu.utils.timeline import trace_span
 
 # Counted at trace time: the gathers of M-wide rows one traced expert
 # layer makes, by where (``dispatch_fwd`` / ``combine_bwd``) and from
@@ -532,77 +533,80 @@ class MoeMlp(nn.Module):
         cfg, spec = self.cfg, self.cfg.block
         e, k = spec.num_experts, spec.experts_per_token
         held, first = spec.experts_held or e, spec.first_expert_held
-        b, s, m = x.shape
-        t = b * s
-        init = nn.initializers.normal(0.02)
-        experts_init = nn.with_partitioning(init, ("expert", None, None))
         _M_EXPERTS.labels(kind="held").inc(held)
         _M_EXPERTS.labels(kind="routed").inc(e)
+        with trace_span("experts", held=held, routed=e):
+            b, s, m = x.shape
+            t = b * s
+            init = nn.initializers.normal(0.02)
+            experts_init = nn.with_partitioning(init, ("expert", None, None))
 
-        wr = self.param("router", nn.with_partitioning(init, (None, None)),
-                        (m, e), jnp.float32)
-        wi = self.param("wi", experts_init, (held, m, cfg.d_ff), jnp.float32)
-        wo = self.param("wo", experts_init, (held, cfg.d_ff, m), jnp.float32)
-        # The sorted-row arrays' length, and whether it is chosen each
-        # step between it and the whole T x k.
-        c = prefix_rows(t, k, held, e)
-        wg = None
-        if spec.ffn == "swiglu":
-            wg = self.param("wg", experts_init, (held, m, cfg.d_ff),
+            wr = self.param("router", nn.with_partitioning(init, (None, None)),
+                            (m, e), jnp.float32)
+            wi = self.param("wi", experts_init, (held, m, cfg.d_ff),
                             jnp.float32)
-        bias = None
-        if spec.router == "sigmoid_bias":
-            bias = self.variable("moe_state", "router_bias", jnp.zeros,
-                                 (e,), jnp.float32).value
+            wo = self.param("wo", experts_init, (held, cfg.d_ff, m),
+                            jnp.float32)
+            # The sorted-row arrays' length, and whether it is chosen each
+            # step between it and the whole T x k.
+            c = prefix_rows(t, k, held, e)
+            wg = None
+            if spec.ffn == "swiglu":
+                wg = self.param("wg", experts_init, (held, m, cfg.d_ff),
+                                jnp.float32)
+            bias = None
+            if spec.router == "sigmoid_bias":
+                bias = self.variable("moe_state", "router_bias", jnp.zeros,
+                                     (e,), jnp.float32).value
 
-        tokens = x.reshape(t, m)
-        with jax.named_scope(SCOPE_MOE_ROUTER):
-            # The choice of k among E is discrete: the logits are made
-            # in float32 whatever the compute dtype.
-            logits = jnp.dot(tokens.astype(jnp.float32), wr,
-                             precision=lax.Precision.HIGHEST)
-            scores, gates, experts = route(
-                logits, k, assignment, scoring=spec.router, bias=bias,
-                norm_topk=spec.norm_topk, scale=spec.routed_scale)
-            counts = jnp.sum(jax.nn.one_hot(experts.reshape(-1), e,
-                                            dtype=jnp.int32), axis=0)
-            if spec.router == "softmax":
-                load_balance, z_loss = aux_losses(logits, scores, counts)
-            # The held experts' rows are the first ``live`` sorted rows,
-            # in ``held`` ragged groups; all T x k where all are held.
-            sizes, live, rows_held, rows_overflow = counts, None, t * k, 0
-            if held < e:
-                sizes = counts[first:first + held]
-                live = rows_held = jnp.sum(sizes)
-        with jax.named_scope(SCOPE_MOE_DISPATCH):
-            order, inverse = sorted_by_expert(experts, first, e)
-        if c == t * k:
-            out = _expert_rows(t * k, k, tokens, order, inverse, gates,
-                               sizes, live, wi, wo, wg)
-        else:
-            # Cast before the choice: a branch hands its weight
-            # gradients back in the compute dtype.
-            with jax.named_scope(SCOPE_MOE_EXPERTS):
-                wi, wo, wg = (w if w is None else w.astype(cfg.dtype)
-                              for w in (wi, wo, wg))
-            # Named for a block's recomputation to keep where it reads
-            # the output again (models/transformer.py ``_remat_block``).
-            out = checkpoint_name(
-                _held_rows(c, k, tokens, order, inverse, gates, sizes, live,
-                           wi, wo, wg), SAVED_MOE_OUT)
-            rows_overflow = (live > c).astype(jnp.int32)
-        if self.shared is not None:
-            with jax.named_scope(SCOPE_MOE_SHARED):
-                out = out + self.shared(tokens)
-        if not self.is_initializing():
-            if spec.router == "softmax":
-                self.sow("moe", "load_balance", load_balance)
-                self.sow("moe", "z_loss", z_loss)
-            self.sow("moe", "tokens_per_expert", counts)
-            self.sow("moe", "rows_held", rows_held)
-            self.sow("moe", "rows_overflow", rows_overflow)
-            self.sow("moe", "experts", experts)
-        return out.reshape(b, s, m)
+            tokens = x.reshape(t, m)
+            with jax.named_scope(SCOPE_MOE_ROUTER):
+                # The choice of k among E is discrete: the logits are made
+                # in float32 whatever the compute dtype.
+                logits = jnp.dot(tokens.astype(jnp.float32), wr,
+                                 precision=lax.Precision.HIGHEST)
+                scores, gates, experts = route(
+                    logits, k, assignment, scoring=spec.router, bias=bias,
+                    norm_topk=spec.norm_topk, scale=spec.routed_scale)
+                counts = jnp.sum(jax.nn.one_hot(experts.reshape(-1), e,
+                                                dtype=jnp.int32), axis=0)
+                if spec.router == "softmax":
+                    load_balance, z_loss = aux_losses(logits, scores, counts)
+                # The held experts' rows are the first ``live`` sorted rows,
+                # in ``held`` ragged groups; all T x k where all are held.
+                sizes, live, rows_held, rows_overflow = counts, None, t * k, 0
+                if held < e:
+                    sizes = counts[first:first + held]
+                    live = rows_held = jnp.sum(sizes)
+            with jax.named_scope(SCOPE_MOE_DISPATCH):
+                order, inverse = sorted_by_expert(experts, first, e)
+            if c == t * k:
+                out = _expert_rows(t * k, k, tokens, order, inverse, gates,
+                                   sizes, live, wi, wo, wg)
+            else:
+                # Cast before the choice: a branch hands its weight
+                # gradients back in the compute dtype.
+                with jax.named_scope(SCOPE_MOE_EXPERTS):
+                    wi, wo, wg = (w if w is None else w.astype(cfg.dtype)
+                                  for w in (wi, wo, wg))
+                # Named for a block's recomputation to keep where it reads
+                # the output again (models/transformer.py ``_remat_block``).
+                out = checkpoint_name(
+                    _held_rows(c, k, tokens, order, inverse, gates, sizes,
+                               live, wi, wo, wg), SAVED_MOE_OUT)
+                rows_overflow = (live > c).astype(jnp.int32)
+            if self.shared is not None:
+                with jax.named_scope(SCOPE_MOE_SHARED):
+                    out = out + self.shared(tokens)
+            if not self.is_initializing():
+                if spec.router == "softmax":
+                    self.sow("moe", "load_balance", load_balance)
+                    self.sow("moe", "z_loss", z_loss)
+                self.sow("moe", "tokens_per_expert", counts)
+                self.sow("moe", "rows_held", rows_held)
+                self.sow("moe", "rows_overflow", rows_overflow)
+                self.sow("moe", "experts", experts)
+            return out.reshape(b, s, m)
 
 
 def _layer_number(path):     # ('layer_10', 'moe', <name>): 10

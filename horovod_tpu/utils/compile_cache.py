@@ -116,6 +116,11 @@ class CompileListener:
             local.depth, local.cache = 0, {}
         return local
 
+    def tracing(self) -> bool:
+        """Whether a compile phase is open on this thread: jax is
+        running the program's Python to trace it."""
+        return getattr(self._local, "depth", 0) > 0
+
     def on_scalar(self, event, value, **kwargs):
         if event in _PHASES:
             self._state().depth += 1
@@ -176,3 +181,9 @@ def install_compile_listeners() -> CompileListener:
                 listener.on_time_span)
             _installed = listener
         return _installed
+
+
+def tracing() -> bool:
+    """``CompileListener.tracing()`` of the process's listener; False
+    before it is installed (nothing says when a phase begins)."""
+    return _installed is not None and _installed.tracing()

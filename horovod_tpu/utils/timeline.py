@@ -15,7 +15,9 @@ every program jax traces, lowers and compiles or reads from its cache
 (``utils/compile_cache.py`` listens to jax's own events) -- is kept in
 memory on ``time.time()`` and read with ``hvd.launch_spans()``; an open
 ``Timeline`` receives each span as a ``B``/``E`` pair under category
-``launch`` (docs/timeline.md#launch).
+``launch`` (docs/timeline.md#launch). What jax was tracing inside a
+``compile/trace`` span is in the ``trace/*`` spans the traced modules
+file through ``trace_span``.
 """
 
 from __future__ import annotations
@@ -187,16 +189,20 @@ class SpanLog:
     open on the same thread when this one began, else None), ``launch``
     (the count of ``hvd.init()`` calls begun in this process, at least
     1), ``name``, ``start`` and ``end`` on ``time.time()`` (``end`` None
-    while the span is open) and ``args``.
+    while the span is open) and ``args``. ``dropped`` counts the spans
+    that fell off the old end: a sum over a log that dropped any is
+    partial.
 
-    Written to from the few places a launch passes through, never from
-    a traced function or a training loop; an attached ``Timeline``
-    receives every span that closes (and, when attached, those that
-    closed before)."""
+    Written to from the few places a launch passes through, from a
+    traced function only through ``trace_span`` (which files nothing
+    outside a compile phase) and never from a training loop; an
+    attached ``Timeline`` receives every span that closes (and, when
+    attached, those that closed before)."""
 
-    def __init__(self, capacity: int = 1024, phase_gauge=None):
+    def __init__(self, capacity: int = 4096, phase_gauge=None):
         self._lock = threading.Lock()
         self._spans = collections.deque(maxlen=capacity)
+        self.dropped = 0
         self._ids = itertools.count(1)
         self._launches = 0
         self._open = threading.local()
@@ -231,6 +237,7 @@ class SpanLog:
             span = {"id": next(self._ids), "parent": parent,
                     "launch": max(self._launches, 1), "name": name,
                     "start": start, "end": None, "args": args}
+            self.dropped += len(self._spans) == self._spans.maxlen
             self._spans.append(span)
         return span
 
@@ -294,3 +301,21 @@ def launch_spans() -> List[Dict]:
     """``hvd.launch_spans()``: what this process's launches were made
     of (docs/timeline.md#launch)."""
     return LAUNCH_LOG.spans()
+
+
+def trace_span(part: str, **args):
+    """A ``trace/<part>`` span of the launch log round the ``with``
+    body, ONLY while jax is tracing on this thread (a compile phase is
+    open: ``utils/compile_cache.py`` ``CompileListener.tracing``;
+    ``jax.eval_shape`` is one too). The traced modules call it where
+    the trace's seconds go -- a block, a kernel body, an expert layer,
+    the gradient sync, the update -- so that a ``compile/trace`` span
+    divides by what was traced inside it (docs/timeline.md#launch).
+    Anywhere else (an eager call, a training loop) it costs one
+    thread-local read and files nothing. The ``with`` target is the
+    span's ``args``, or None where there is none."""
+    from horovod_tpu.utils import compile_cache   # imports this module
+
+    if not compile_cache.tracing():
+        return contextlib.nullcontext()
+    return LAUNCH_LOG.span("trace/" + part, **args)

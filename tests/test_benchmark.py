@@ -1,5 +1,6 @@
 """Tier-1 runs the benchmark's arithmetic tests: the trace reduction, the
-operation counts, the scope view, the launch view, and that every entry
+operation counts, the scope view, the launch view, the trace-phase view,
+and that every entry
 of ``BENCHMARK.json`` has its files. Each is collected here as a test of
 its own. The rehearsals and the float32 reference checks of
 ``benchmark/tests`` take a minute and stay a run by hand
@@ -12,4 +13,5 @@ from benchmark.tests.test_data_driven import (  # noqa: F401
 from benchmark.tests.test_flops import *  # noqa: F401,F403
 from benchmark.tests.test_launch import *  # noqa: F401,F403
 from benchmark.tests.test_scope_view import *  # noqa: F401,F403
+from benchmark.tests.test_trace_phase import *  # noqa: F401,F403
 from benchmark.tests.test_trace_reduce import *  # noqa: F401,F403

@@ -97,7 +97,7 @@ def test_the_buffer_keeps_the_newest():
     kept = log.spans()
     assert [s["args"]["n"] for s in kept] == list(range(12, 20))
     assert [s["id"] for s in kept] == list(range(13, 21))
-    assert timeline.LAUNCH_LOG._spans.maxlen == 1024
+    assert timeline.LAUNCH_LOG._spans.maxlen == 4096
 
 
 def test_spans_are_copies():
@@ -496,3 +496,274 @@ def test_a_compiled_step_run_in_a_window_adds_nothing():
     assert len(losses) == 20
     assert hvd.launch_spans() == spans and _counters() == counted
     assert metrics.value("hvd_launch_phase_seconds", phase="import") > 0
+
+
+# --- the trace phase from inside ------------------------------------------------
+
+def test_dropped_counts_what_fell_off_the_old_end():
+    log = timeline.SpanLog(capacity=4)
+    for i in range(4):
+        log.record("compile/trace", float(i), float(i) + 0.5)
+    assert log.dropped == 0
+    with log.span("plan"):
+        log.record("compile/lower", 9.0, 9.5)
+    assert log.dropped == 2 and len(log.spans()) == 4
+    assert timeline.LAUNCH_LOG.dropped >= 0
+
+
+def _fed_by_hand(monkeypatch):
+    """``trace_span`` on a listener and a log of the test's own."""
+    listener, log, phase, _, _ = _fed()
+    monkeypatch.setattr(compile_cache, "_installed", listener)
+    monkeypatch.setattr(timeline, "LAUNCH_LOG", log)
+    return listener, log, phase
+
+
+def test_trace_span_files_only_while_a_phase_is_open(monkeypatch):
+    listener, log, phase = _fed_by_hand(monkeypatch)
+    assert not listener.tracing() and not compile_cache.tracing()
+    with timeline.trace_span("block", layer="layer_0") as filed:
+        assert filed is None
+    assert log.spans() == []
+
+    def callee():
+        with timeline.trace_span("update"):
+            pass
+
+    def traced():
+        assert listener.tracing() and compile_cache.tracing()
+        with timeline.trace_span("block", layer="layer_0",
+                                 kind="conv") as filed:
+            with timeline.trace_span("kernel", kernel="hvd_flash_fwd"):
+                pass
+            filed["more"] = 1
+        # A phase inside the phase (a jitted callee) does not close it.
+        phase(TRACE, 1.0, 1.5, "callee", inside=[callee])
+        assert listener.tracing()
+
+    seen = []
+    worker = threading.Thread(   # another thread's phase is not this one's
+        target=lambda: seen.append(compile_cache.tracing()))
+    phase(TRACE, 0.0, 2.0, "step", inside=[
+        traced, worker.start, lambda: worker.join(timeout=10)])
+    assert seen == [False] and not listener.tracing()
+    with timeline.trace_span("block"):
+        pass
+    by_name = {s["name"]: s for s in log.spans()}
+    assert sorted(by_name) == ["compile/trace", "trace/block",
+                               "trace/kernel", "trace/update"]
+    block = by_name["trace/block"]
+    assert block["args"] == {"layer": "layer_0", "kind": "conv", "more": 1}
+    assert block["parent"] is None and block["launch"] == 1
+    assert by_name["trace/kernel"]["parent"] == block["id"]
+    assert by_name["trace/kernel"]["args"] == {"kernel": "hvd_flash_fwd"}
+    assert by_name["compile/trace"]["args"] == {"fun_name": "step"}
+
+
+def test_before_the_listeners_are_installed_nothing_is_filed(monkeypatch):
+    monkeypatch.setattr(compile_cache, "_installed", None)
+    before = hvd.launch_spans()
+    assert not compile_cache.tracing()
+    with timeline.trace_span("block") as filed:
+        assert filed is None
+    assert hvd.launch_spans() == before
+
+
+def test_an_attached_timeline_receives_a_trace_span(monkeypatch, tmp_path):
+    listener, log, phase = _fed_by_hand(monkeypatch)
+    path = str(tmp_path / "t.json")
+    tl = timeline.Timeline(path)
+    try:
+        log.attach(tl)
+
+        def traced():
+            with timeline.trace_span("experts", held=8, routed=64):
+                pass
+
+        phase(TRACE, time.time(), time.time() + 1.0, "step", inside=[traced])
+    finally:
+        tl.close()
+    events = [e for e in _read_timeline(path) if e.get("cat") == "launch"]
+    assert [(e["name"], e["ph"]) for e in events] == [
+        ("trace/experts", "B"), ("trace/experts", "E"),
+        ("compile/trace", "B"), ("compile/trace", "E")]
+    assert events[0]["args"] == {"held": 8, "routed": 64, "id": 1,
+                                 "launch": 1}
+
+
+def _tiny_step(attention="dense", n_layers=3, **block):
+    """A tiny ``Transformer``'s SGD step, jitted, with its arguments."""
+    import optax
+
+    from flax.core import meta
+    from horovod_tpu import models
+    from horovod_tpu.jax import DistributedOptimizer
+
+    cfg = models.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=n_layers, d_ff=64,
+        max_seq_len=16, dtype=jnp.float32, attention=attention,
+        block=models.BlockSpec(**block))
+    model = models.Transformer(cfg)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    params = meta.unbox(model.init(jax.random.PRNGKey(0), tokens))
+    tx = DistributedOptimizer(optax.sgd(0.1))
+    opt_state = tx.init(params)
+
+    def hvd_tiny_step(params, opt_state, tokens):
+        def loss_fn(p):
+            logits = model.apply(p, tokens)
+            return optax.softmax_cross_entropy_with_integer_labels(
+                logits, tokens).mean()
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    return model, jax.jit(hvd_tiny_step), (params, opt_state, tokens)
+
+
+def _trace_spans_since(last):
+    return [s for s in _since(last) if s["name"].startswith("trace/")]
+
+
+def test_a_traced_step_files_a_block_a_layer_and_an_eager_call_nothing():
+    compile_cache.install_compile_listeners()
+    kinds = ("full_attention", "sliding_attention", "full_attention")
+    model, step, args = _tiny_step(
+        layer_types=kinds, sliding_window=4, ffn="swiglu", num_experts=4,
+        experts_per_token=2, first_dense_layers=1)
+    last = _last_id()
+    with jax.disable_jit():   # eager: every module runs, nothing is traced
+        model.apply(args[0], args[2])
+    assert not _trace_spans_since(last)
+
+    last = _last_id()
+    step.trace(*args)
+    added = _since(last)
+    (whole,) = [s for s in added if s["name"] == "compile/trace"]
+    assert whole["args"] == {"fun_name": "hvd_tiny_step"}
+    mine = [s for s in added if s["name"].startswith("trace/")]
+    assert all(whole["start"] <= s["start"] <= s["end"] <= whole["end"]
+               for s in mine)
+    blocks = [s for s in mine if s["name"] == "trace/block"]
+    assert [s["args"] for s in blocks] == [
+        {"layer": "layer_%d" % i, "kind": kind}
+        for i, kind in enumerate(kinds)]
+    # The first layer's feed-forward is dense; each other block holds its
+    # expert layer, the child of the span it lies in.
+    experts = [s for s in mine if s["name"] == "trace/experts"]
+    assert [(s["args"], s["parent"]) for s in experts] == [
+        ({"held": 4, "routed": 4}, block["id"]) for block in blocks[1:]]
+    # No axis is bound here: the sync traces nothing and files nothing;
+    # the update does, outside every block.
+    (update,) = [s for s in mine if s["name"] == "trace/update"]
+    assert update["parent"] is None and update["args"] == {}
+    assert update["start"] >= blocks[-1]["end"]
+    assert {s["name"] for s in mine} <= {
+        "trace/block", "trace/experts", "trace/kernel", "trace/update"}
+    # jax's own cache: a second trace of the same step runs no Python.
+    last = _last_id()
+    step.trace(*args)
+    assert not _trace_spans_since(last)
+
+
+def test_the_sync_is_filed_where_an_axis_is_bound():
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from horovod_tpu.jax import DistributedOptimizer
+    from horovod_tpu.parallel.mesh import shard_map_compat
+
+    compile_cache.install_compile_listeners()
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("data",))
+    tx = DistributedOptimizer(optax.sgd(0.1))
+    params = {"w": jnp.ones((4, 3)), "b": jnp.zeros((3,))}
+    opt_state = tx.init(params)
+
+    def hvd_tiny_synced(params, opt_state, x):
+        def local(params, opt_state, x):
+            grads = jax.grad(
+                lambda p: jnp.mean((x @ p["w"] + p["b"]) ** 2))(params)
+            updates, opt_state = tx.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state
+
+        return shard_map_compat(
+            local, mesh=mesh, in_specs=(P(), P(), P("data")),
+            out_specs=(P(), P()))(params, opt_state, x)
+
+    last = _last_id()
+    jax.jit(hvd_tiny_synced).trace(params, opt_state, jnp.ones((8, 4)))
+    mine = _trace_spans_since(last)
+    assert [(s["name"], s["args"]) for s in mine] == [
+        ("trace/sync", {"leaves": 2}), ("trace/update", {})]
+
+
+def test_kernel_spans_count_the_bodies_traced():
+    """Two identical calls through a jitted callee (the selective
+    scan's) are ONE body traced; through the flash call, which is no
+    jitted callee, two."""
+    from horovod_tpu.jax import introspect
+    from horovod_tpu.ops.pallas_attention import flash_attention
+    from horovod_tpu.ops.pallas_scan import selective_scan
+
+    compile_cache.install_compile_listeners()
+    # Shapes no other test of this process traces: jax's cache of traced
+    # callees is the process's.
+    t, e, n = 24, 384, 3
+    x = jnp.ones((1, t, e))
+    a, bc, d = jnp.ones((e, n)), jnp.ones((1, t, n)), jnp.ones((e,))
+    q = jnp.ones((1, 40, 3, 8))
+
+    def hvd_tiny_kernels(x, q):
+        for _ in range(2):
+            x = selective_scan(x, x, a, bc, bc, d)
+        for _ in range(2):
+            q = flash_attention(q, q, q)
+        return x, q
+
+    last = _last_id()
+    jax.jit(hvd_tiny_kernels).trace(x, q)
+    kernels = [s for s in _trace_spans_since(last)]
+    assert all(s["name"] == "trace/kernel" for s in kernels)
+    assert [s["args"] for s in kernels] == [
+        {"kernel": introspect.KERNEL_SSM_SCAN_FWD},
+        {"kernel": introspect.KERNEL_FLASH_FWD, "widths": "8"},
+        {"kernel": introspect.KERNEL_FLASH_FWD, "widths": "8"}]
+
+
+def test_the_lowered_step_is_the_same_without_the_spans(monkeypatch):
+    """No name in the program moved: with ``trace_span`` replaced by a
+    no-op in every module that calls it, the step lowers to the same
+    text."""
+    import contextlib
+
+    from horovod_tpu.jax import optimizer
+    from horovod_tpu.models import transformer
+    from horovod_tpu.ops import (
+        pallas_attention,
+        pallas_gather_sum,
+        pallas_grouped_matmul,
+    )
+    from horovod_tpu.parallel import moe
+
+    compile_cache.install_compile_listeners()
+    blocks = dict(ffn="swiglu", num_experts=4, experts_per_token=2,
+                  first_dense_layers=1)
+    _, step, args = _tiny_step(attention="flash", n_layers=2, **blocks)
+    last = _last_id()
+    with_spans = step.lower(*args).as_text()
+    assert {s["name"] for s in _trace_spans_since(last)} == {
+        "trace/block", "trace/experts", "trace/kernel", "trace/update"}
+
+    @contextlib.contextmanager
+    def nothing(part, **args):
+        yield None
+
+    for module in (transformer, moe, optimizer, pallas_attention,
+                   pallas_gather_sum, pallas_grouped_matmul):
+        monkeypatch.setattr(module, "trace_span", nothing)
+    _, step, args = _tiny_step(attention="flash", n_layers=2, **blocks)
+    last = _last_id()
+    assert step.lower(*args).as_text() == with_spans
+    assert not _trace_spans_since(last)

@@ -19,9 +19,11 @@ import numpy as np
 import pytest
 from flax.core import meta
 
+import horovod_tpu as hvd
 from horovod_tpu.jax import introspect
 from horovod_tpu.models import transformer
 from horovod_tpu.ops import pallas_selection
+from horovod_tpu.utils import compile_cache
 from horovod_tpu.ops.pallas_attention import (
     Selection,
     flash_attention,
@@ -208,12 +210,24 @@ def _loops(jaxpr):
 
 
 def _selections():
-    return {via: transformer._M_DSA_SELECTIONS.labels(via=via).get()
-            for via in ("kernel", "plain", "forced")}
+    """The launch log's newest span, with the kernel's callee forgotten
+    by jax's tracing cache: what is filed after it is the next trace's."""
+    compile_cache.install_compile_listeners()
+    pallas_selection._choose.clear_cache()
+    return max((s["id"] for s in hvd.launch_spans()), default=0)
 
 
 def _moved(before):
-    return {via: n - before[via] for via, n in _selections().items()}
+    """The sparse layers traced since ``before`` (a ``trace/block`` of
+    kind ``sparse_attention`` each) and the kernel bodies that chose (a
+    ``trace/kernel`` span named ``hvd_dsa_choose``; the callee is
+    jitted, so the layers of one signature trace ONE)."""
+    added = [s for s in hvd.launch_spans() if s["id"] > before]
+    return {
+        "layers": sum(s["name"] == "trace/block" and s["args"]["kind"]
+                      == transformer.SPARSE_ATTENTION for s in added),
+        "chosen": sum(s["name"] == "trace/kernel" and s["args"]["kernel"]
+                      == introspect.KERNEL_DSA_CHOOSE for s in added)}
 
 
 def test_a_flash_layer_of_whole_passes_calls_the_kernel(params):
@@ -223,7 +237,7 @@ def test_a_flash_layer_of_whole_passes_calls_the_kernel(params):
     s, model = 512, _model()
     before = _selections()
     jaxpr = jax.make_jaxpr(lambda p: model.apply(p, _tokens(s)))(params)
-    assert _moved(before) == {"kernel": LAYERS, "plain": 0, "forced": 0}
+    assert _moved(before) == {"layers": LAYERS, "chosen": 1}
     calls = _calls(jaxpr.jaxpr, introspect.KERNEL_DSA_CHOOSE)
     assert len(calls) == LAYERS
     for eqn, _ in calls:
@@ -282,8 +296,7 @@ def test_every_other_layer_takes_the_plain_path(params, attention, s,
     before = _selections()
     jaxpr = jax.make_jaxpr(lambda p: model.apply(
         p, _tokens(s), selections=selections))(params)
-    assert _moved(before) == dict(
-        {"kernel": 0, "plain": 0, "forced": 0}, **{via: LAYERS})
+    assert _moved(before) == {"layers": LAYERS, "chosen": 0}
     assert not _calls(jaxpr.jaxpr, introspect.KERNEL_DSA_CHOOSE)
     assert len(_loops(jaxpr.jaxpr)) == (0 if forced else 2 * LAYERS)
 

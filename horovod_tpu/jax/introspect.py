@@ -109,9 +109,13 @@ KERNEL_FLASH_FWD = "hvd_flash_fwd"
 KERNEL_FLASH_BWD = "hvd_flash_bwd"
 KERNEL_FLASH_DKV = "hvd_flash_dkv"
 KERNEL_FLASH_DQ = "hvd_flash_dq"
-# The same three kernels under a mask that is DATA (``select=``): a
-# fourth operand in the forward, a seventh in the two backward kernels.
+# The same kernels under a mask that is DATA (``select=``): a fourth
+# operand in the forward (the plane packed along the keys), a seventh in
+# the one-pass backward (the plane packed along the queries, the only
+# one a backward reads) and in each of the two kernels that run it past
+# the VMEM cap.
 KERNEL_DSA_FWD = "hvd_dsa_fwd"
+KERNEL_DSA_BWD = "hvd_dsa_bwd"
 KERNEL_DSA_DKV = "hvd_dsa_dkv"
 KERNEL_DSA_DQ = "hvd_dsa_dq"
 # ``name=`` of the call that CHOOSES the keys (ops/pallas_selection.py;

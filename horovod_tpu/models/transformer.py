@@ -1083,7 +1083,7 @@ _REMAT_KEEPS = (
     SAVED_FLASH_Q, SAVED_FLASH_K, SAVED_FLASH_V,
     # A learned selection as the kernels read it (a ``sparse_attention``
     # layer, Keye-VL-2.0: 16 index heads of 64, top 2048): two bit planes
-    # of the mask, 8.4 MB each. All three kernels read them, so the
+    # of the mask, 8.4 MB each. Both kernels read theirs, so the
     # recomputed forward has no reader left for the indexer's three
     # projections, its (S, S) scores or the 8192 row selections.
     SAVED_FLASH_SELECT,

@@ -9,21 +9,20 @@ materialising the (S, S) score matrix in HBM.
 Algorithm: standard streaming-softmax (flash) attention. The forward
 kernel tiles queries over the grid and walks key/value blocks with a
 running (max, sum, accumulator) triple; the backward pass uses the saved
-log-sum-exp, wired up through ``jax.custom_vjp``. The backward of a
-STATIC mask (causal, causal + window, none) is ONE kernel,
-``hvd_flash_bwd``: tiled over key blocks, it walks the query blocks of
-one query head, computes its score tiles key-major (``k . q^T``), so
-that ``p`` and ``ds`` feed the dV and dK matmuls without a tile
-transpose, takes the log-sum-exp and delta as lane-major rows, and adds
-each tile's ``ds^T . k`` into the head's float32 dQ, a (S, D) panel
-that stays in VMEM while the grid walks the head's key blocks and is
-rounded once at the last: the FIVE matmul products a score tile that
-attention requires. The older pair, dK/dV tiled over key blocks and a
-query-major dQ tiled over query blocks (log-sum-exp and delta as
-columns), each make ``q . k^T`` and ``dO . v^T`` (SEVEN products); it
-still runs where ``_one_pass`` says so, a rule on the call's input and
-nothing else: under a learned mask, and where the one pass's resident
-panels pass the VMEM cap.
+log-sum-exp, wired up through ``jax.custom_vjp``. The backward is ONE
+kernel, ``hvd_flash_bwd`` (under a learned mask ``hvd_dsa_bwd``): tiled
+over key blocks, it walks the query blocks of one query head, computes
+its score tiles key-major (``k . q^T``), so that ``p`` and ``ds`` feed
+the dV and dK matmuls without a tile transpose, takes the log-sum-exp
+and delta as lane-major rows, and adds each tile's ``ds^T . k`` into the
+head's float32 dQ, a (S, D) panel that stays in VMEM while the grid
+walks the head's key blocks and is rounded once at the last: the FIVE
+matmul products a score tile that attention requires. The older pair,
+dK/dV tiled over key blocks and a query-major dQ tiled over query
+blocks (log-sum-exp and delta as columns), each make ``q . k^T`` and
+``dO . v^T`` (SEVEN products); it still runs where ``_one_pass`` says
+so, a rule on ONE fact of the call's input: where the one pass's
+resident panels pass the VMEM cap, under either kind of mask.
 
 The per-(batch, head) K/V panel is VMEM-resident (blocks are sliced
 from it in-kernel), and so are the backward's whole Q/dO panels and its
@@ -60,9 +59,12 @@ product 128 wide costs what one 64 wide costs (docs/mfu.md).
 A learned selection (``select=``, DeepSeek sparse attention): which keys
 a query keeps is then DATA the step computed, not a fact of the trace.
 It comes as two bit planes of the (S, S) mask (``pack_selection``): one
-packed along the keys, which the query-major forward and dQ read a
-query block at a time, one along the queries for the key-major dK/dV
-(the backward is then the two kernels, ``hvd_dsa_dkv`` + ``hvd_dsa_dq``).
+packed along the keys, which the query-major forward reads a query
+block at a time, one along the queries for the key-major backward,
+``hvd_dsa_bwd``, the seventh operand of the same one pass: the tile's
+``p`` is zeroed once and dV, dS, dK and dQ all follow from it, so the
+backward reads the second plane alone. (Past the VMEM cap the pair,
+``hvd_dsa_dkv`` + ``hvd_dsa_dq``, reads one plane each.)
 A bit stands for a whole lane: bit b of word ``[m, r, j]`` is column
 ``(32 m + b) 128 + j`` of row r, so a tile's mask is a shift and an AND
 of (rows, 128) words, 128-lane pieces side by side, no gather and no
@@ -76,7 +78,7 @@ switch is logged once per backend so a run can tell which it got. ONE
 line differs there: the one pass's ``ds^T . k`` is made from the
 transposed tile behind a barrier (``_dot_tn``), so that XLA:CPU adds it
 as the dQ kernel adds ``ds . k`` and the one pass, the two kernels and
-the masked pair under a mask that keeps every key are equal bit for bit
+either under a learned mask that keeps every key are equal bit for bit
 off the chip, as the tests hold them.
 """
 
@@ -558,16 +560,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _put_dkv(dk_ref, dv_ref, dk, dv, k_start, tiles.block_k, group)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_sum, *, tiles, scale, group,
-                interp):
-    """The whole backward of a static mask in ONE pass: ``_bwd_dkv_kernel``'s
-    grid and walk, and dQ besides. ``dq_sum`` is the query head's
-    float32 (sq_pad, D) sum, a scratch that lives in VMEM while the grid
-    walks the head's key blocks: zeroed at the first (a window's q block
-    is first visited by key block ``key_start(qi)``, a padded row by
-    none), added to by every tile, and rounded ONCE into ``dq_ref``, the
-    head's whole dQ panel, at the last."""
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                tiles, scale, group, interp):
+    """The whole backward in ONE pass: ``_bwd_dkv_kernel``'s grid, walk
+    and seventh operand (a learned mask's ``by_key`` rows: the one ``p``
+    it zeroes feeds dV, ds, dK and dQ alike, so dQ reads no plane of its
+    own), and dQ besides. ``dq_sum`` is the query head's float32
+    (sq_pad, D) sum, a scratch that lives in VMEM while the grid walks
+    the head's key blocks: zeroed at the first (a window's q block is
+    first visited by key block ``key_start(qi)``, a padded row by none),
+    added to by every tile, and rounded ONCE into ``dq_ref``, the head's
+    whole dQ panel, at the last."""
+    sel_ref = rest[0] if tiles.learned else None
+    dq_ref, dk_ref, dv_ref, dq_sum = rest[-4:]
     kj = pl.program_id(2 if group == 1 else 3)
     k_start = kj * tiles.block_k
 
@@ -576,7 +581,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_sum[...] = jnp.zeros(dq_sum.shape, jnp.float32)
 
     dk, dv = _key_block_grads(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                              delta_ref, None, dq_sum, kj, k_start, tiles,
+                              delta_ref, sel_ref, dq_sum, kj, k_start, tiles,
                               scale, interp)
     _put_dkv(dk_ref, dv_ref, dk, dv, k_start, tiles.block_k, group)
 
@@ -735,15 +740,17 @@ def _compiler_params(need):
     return pltpu.CompilerParams(vmem_limit_bytes=min(need, _VMEM_CAP))
 
 
-def _one_pass(learned, need):
-    """WHICH backward runs, from what the call sees in its input: the
-    two kernels ``hvd_dsa_dkv`` + ``hvd_dsa_dq`` under a learned mask
-    (the one-pass form of a mask that is data is a later change); the
-    two kernels ``hvd_flash_dkv`` + ``hvd_flash_dq`` where the one
-    pass's resident panels (``need``, by ``_vmem_need``) pass the cap,
-    so that no shape that compiled as two kernels stops compiling; ONE
-    pass, ``hvd_flash_bwd``, otherwise."""
-    return not learned and need <= _VMEM_CAP
+def _one_pass(need):
+    """WHICH backward runs, from ONE fact of the call's input: ``need``,
+    the scoped VMEM of the one pass's resident panels
+    (``_key_major_need(..., with_dq=True)``, a learned mask's plane
+    block among them). Up to the cap ONE pass, ``hvd_flash_bwd`` (a
+    learned mask: ``hvd_dsa_bwd``); past it the two kernels
+    ``hvd_flash_dkv`` + ``hvd_flash_dq`` (``hvd_dsa_dkv`` +
+    ``hvd_dsa_dq``), so that no shape that compiled as two kernels stops
+    compiling. The mask is an operand the pass has or has not; it is no
+    part of the rule."""
+    return need <= _VMEM_CAP
 
 
 @_scoped
@@ -827,18 +834,21 @@ def _dkv_call(tiles, group, b, h, d, d_v, sq_pad, sk_pad, dtype, scale,
     num_kb`` steps, so they stay in VMEM while every query head of the
     group adds its part and go to HBM once, ``H_kv`` heads wide.
     ``words``: of a learned mask's ``by_key`` plane, the seventh
-    operand. ``with_dq``: dQ is the FIRST result, the query head's whole
-    (sq_pad, D) panel in ``dtype``: an output block of the head's
-    ``num_kb`` steps, like q and dO's panels, written at the last from a
-    float32 scratch as large."""
+    operand, with or without dQ. ``with_dq``: dQ is the FIRST result,
+    the query head's whole (sq_pad, D) panel in ``dtype``: an output
+    block of the head's ``num_kb`` steps, like q and dO's panels,
+    written at the last from a float32 scratch as large."""
     from horovod_tpu.jax.introspect import (
+        KERNEL_DSA_BWD,
         KERNEL_DSA_DKV,
         KERNEL_FLASH_BWD,
         KERNEL_FLASH_DKV,
     )
 
-    name = KERNEL_DSA_DKV if tiles.learned else \
-        KERNEL_FLASH_BWD if with_dq else KERNEL_FLASH_DKV
+    if tiles.learned:
+        name = KERNEL_DSA_BWD if with_dq else KERNEL_DSA_DKV
+    else:
+        name = KERNEL_FLASH_BWD if with_dq else KERNEL_FLASH_DKV
     params = _compiler_params(_key_major_need(
         tiles, group, d, d_v, sq_pad, sk_pad, dtype, words, with_dq))
     block_q, block_k = tiles.block_q, tiles.block_k
@@ -939,19 +949,26 @@ def _as_rows(tiles, x):
     return x.reshape(x.shape[:2] + (tiles.num_qb, 1, tiles.block_q))
 
 
-def _bwd_one_pass(tiles, scale, interp, qp, kp, vp, dop, lsep, deltap):
-    """dQ, dK, dV (padded; dK and dV float32 where grouped) of a static
-    mask by ONE kernel, ``hvd_flash_bwd``: five products a score tile.
-    It takes no learned planes: ``_flash_bwd`` never hands it any."""
-    from horovod_tpu.jax.introspect import KERNEL_FLASH_BWD
+def _bwd_one_pass(tiles, scale, interp, qp, kp, vp, dop, lsep, deltap,
+                  select=None):
+    """dQ, dK, dV (padded; dK and dV float32 where grouped) by ONE
+    kernel, ``hvd_flash_bwd``, or under a learned mask ``hvd_dsa_bwd``:
+    five products a score tile. Of the planes it reads ``by_key`` alone,
+    the key-major one: ``by_query`` is the forward's."""
+    from horovod_tpu.jax.introspect import KERNEL_DSA_BWD, KERNEL_FLASH_BWD
 
     (b, h, sq_pad, d), d_v = qp.shape, vp.shape[3]
-    group = h // kp.shape[1]
-    _count_tiles(KERNEL_FLASH_BWD, tiles, (b, h, tiles.q_len, d), d_v,
-                 qp.dtype, group)
-    return _dkv_call(tiles, group, b, h, d, d_v, sq_pad, kp.shape[2],
-                     qp.dtype, scale, interp, with_dq=True)(
-        qp, kp, vp, dop, _as_rows(tiles, lsep), _as_rows(tiles, deltap))
+    sk_pad, group = kp.shape[2], h // kp.shape[1]
+    operands = (qp, kp, vp, dop, _as_rows(tiles, lsep),
+                _as_rows(tiles, deltap))
+    words = 0
+    if tiles.learned:
+        words = select.by_key.shape[1]
+        operands += (_pad_rows(select.by_key, sk_pad),)
+    _count_tiles(KERNEL_DSA_BWD if tiles.learned else KERNEL_FLASH_BWD,
+                 tiles, (b, h, tiles.q_len, d), d_v, qp.dtype, group)
+    return _dkv_call(tiles, group, b, h, d, d_v, sq_pad, sk_pad, qp.dtype,
+                     scale, interp, words, with_dq=True)(*operands)
 
 
 def _bwd_two_kernels(tiles, scale, interp, qp, kp, vp, dop, lsep, deltap,
@@ -1014,13 +1031,13 @@ def _flash_bwd(causal, window, block_q, block_k, scale, interpret, res, g):
     tiles, *operands = _bwd_operands(block_q, block_k, causal, window, res, g)
     qp, kp, vp = operands[:3]
     group = qp.shape[1] // kp.shape[1]
+    words = operands[-1].by_key.shape[1] if tiles.learned else 0
     need = _key_major_need(tiles, group, qp.shape[3], vp.shape[3],
-                           qp.shape[2], kp.shape[2], qp.dtype, with_dq=True)
+                           qp.shape[2], kp.shape[2], qp.dtype, words,
+                           with_dq=True)
     interp = _should_interpret(interpret)
-    if _one_pass(tiles.learned, need):      # a static mask: no planes
-        dq, dk, dv = _bwd_one_pass(tiles, scale, interp, *operands[:-1])
-    else:
-        dq, dk, dv = _bwd_two_kernels(tiles, scale, interp, *operands)
+    backward = _bwd_one_pass if _one_pass(need) else _bwd_two_kernels
+    dq, dk, dv = backward(tiles, scale, interp, *operands)
     if group > 1:   # the group's float32 sums, rounded once
         dk, dv = dk.astype(kp.dtype), dv.astype(vp.dtype)
     return (dq[:, :, :tiles.q_len], dk[:, :, :tiles.kv_len],
@@ -1096,8 +1113,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
         with ``p - window < j <= p`` (None: all of ``j <= p``).
       select: with ``causal`` and no window, the keys each query keeps
         of those at or before it, as ``pack_selection`` packs a (B,
-        S_q, S_kv) mask: forward, dK/dV and dQ all honour it (no
-        gradient reaches it). The tiles are then whole 128-lane groups.
+        S_q, S_kv) mask: forward and backward honour it (no gradient
+        reaches it). The tiles are then whole 128-lane groups.
       block_q / block_k: VMEM tile sizes (clamped to the sequence and
         rounded to the dtype's sublane multiple; the sequence is padded
         to a multiple). The default comes from the sequence lengths

@@ -90,7 +90,7 @@ def test_the_two_backward_kernels_lower_past_the_cap(one_chip):
                              sharding=one_chip)
     need = pallas_attention._vmem_need(32768, 256, 256, jnp.bfloat16, 512,
                                        512, dq_rows=32768)
-    assert need == (136 << 20) and not pallas_attention._one_pass(False, need)
+    assert need == (136 << 20) and not pallas_attention._one_pass(need)
 
     def step(q, k, v, g):
         return jax.vjp(lambda q, k, v: flash_attention(
@@ -162,24 +162,16 @@ def test_grouped_and_windowed_kernels_lower_for_v5e(one_chip, seq, n_q, n_kv,
         line for line in text.splitlines() if " broadcast(" in line)
 
 
-def test_masked_kernels_lower_for_v5e(one_chip):
-    """keye-s8192-dsa-ep8-c1's attention: the three kernels under a mask
-    that is DATA, 1 x 8192 x 32 query heads over 4 key/value heads of
-    128, beside Trinity's case above. Three Mosaic calls under their own
-    names; the selection enters as the FOURTH operand of the forward and
-    the SEVENTH of dK/dV and dQ, an int32 bit plane of S x S / 8 bytes;
-    K and V stay 4 heads wide; and ``benchmark/trace_reduce.py``, which
-    takes a Mosaic call of 3 or 6 operands for a static flash kernel,
-    takes none of these for one."""
-    from benchmark import trace_reduce as tr
+def _masked_step(seq, heads, kv_heads, dim, one_chip):
+    """Forward and backward under a mask that is DATA, compiled for the
+    chip: the text, its Mosaic calls and each operand's shape."""
     from horovod_tpu.ops.pallas_attention import Selection
 
-    seq = 8192
-    q = jax.ShapeDtypeStruct((1, seq, 32, 128), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((1, seq, heads, dim), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, seq, 4, 128), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((1, seq, kv_heads, dim), jnp.bfloat16,
                               sharding=one_chip)
-    plane = jax.ShapeDtypeStruct((1, 2, seq, 128), jnp.int32,
+    plane = jax.ShapeDtypeStruct((1, -(-seq // 4096), seq, 128), jnp.int32,
                                  sharding=one_chip)
 
     def step(q, k, v, g, by_query, by_key):
@@ -190,26 +182,97 @@ def test_masked_kernels_lower_for_v5e(one_chip):
 
     text = jax.jit(step).lower(q, kv, kv, q, plane, plane).compile().as_text()
     shape_of = dict(re.findall(r"%([\w.\-]+) = (\S+\[[\d,]*\])", text))
-    calls = [line.strip() for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 3
-    operands_of = {}
-    for line in calls:
+    calls = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        line = line.strip()
         name = line.split(" = ")[0].lstrip("ROOT ").lstrip("%")
         operands = re.findall(
             r"%([\w.\-]+)", line.split(" custom-call(")[1].split("), ")[0])
-        shapes = [shape_of[o] for o in operands]
-        operands_of[name.split(".")[0]] = len(shapes)
+        calls[name.split(".")[0]] = (line, [shape_of[o] for o in operands])
+    return text, calls
+
+
+def _pallas_equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _pallas_equations(sub)
+
+
+def test_masked_kernels_lower_for_v5e(one_chip):
+    """keye-s8192-dsa-ep8-c1's attention: the kernels under a mask that
+    is DATA, 1 x 8192 x 32 query heads over 4 key/value heads of 128,
+    beside Trinity's case above. TWO Mosaic calls under their own
+    names; the selection enters as the FOURTH operand of the forward
+    and the SEVENTH of the one-pass backward, an int32 bit plane of S x
+    S / 8 bytes, which keeps dQ's float32 (8192, 128) sum beside its
+    panels under the VMEM cap; K and V stay 4 heads wide; and
+    ``benchmark/trace_reduce.py``, which tells a static flash kernel by
+    its name, takes none of these for one."""
+    from benchmark import trace_reduce as tr
+
+    seq = 8192
+    text, calls = _masked_step(seq, 32, 4, 128, one_chip)
+    for name, (line, shapes) in calls.items():
         assert shapes[0] == "bf16[1,32,%d,128]" % seq, (name, shapes)
         assert shapes[1] == shapes[2] == "bf16[1,4,%d,128]" % seq
         assert shapes[-1] == "s32[1,2,%d,128]" % seq, (name, shapes)
         assert tr.is_mosaic_call(line) and tr.flash_kernel(line) == "", name
-    assert operands_of == {introspect.KERNEL_DSA_FWD: 4,
-                           introspect.KERNEL_DSA_DKV: 7,
-                           introspect.KERNEL_DSA_DQ: 7}
-    for static in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_BWD,
-                   introspect.KERNEL_FLASH_DKV, introspect.KERNEL_FLASH_DQ):
-        assert "%" + static not in text
+    assert {name: len(shapes) for name, (_, shapes) in calls.items()} == {
+        introspect.KERNEL_DSA_FWD: 4, introspect.KERNEL_DSA_BWD: 7}
+    line, _ = calls[introspect.KERNEL_DSA_BWD]
+    # dQ first, the query heads' panel; dK and dV the group's float32 sums.
+    assert re.match(r"\(bf16\[1,32,%d,128\]\S*, f32\[1,4,%d,128\]\S*, "
+                    r"f32\[1,4,%d,128\]" % (seq, seq, seq),
+                    line.split(" = ")[1]), line[:200]
+    limit = int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                          line).group(1))
+    need = pallas_attention._vmem_need(seq, 128, 128, jnp.bfloat16, 512, 512,
+                                       seq, 2 * 512, seq)
+    assert limit == need == (43 << 20) and need < (100 << 20)
+    # The scratch is no operand and no result of the compiled call: the
+    # traced call holds it.
+    scratch = [
+        [(aval.shape, aval.dtype.name)
+         for aval in eqn.params["grid_mapping"].scratch_avals]
+        for eqn in _pallas_equations(jax.make_jaxpr(jax.grad(
+            lambda q, k, v, sel: flash_attention(
+                q, k, v, select=sel).astype(jnp.float32).sum(), (0, 1, 2)))(
+            jax.ShapeDtypeStruct((1, seq, 32, 128), jnp.bfloat16),
+            *(jax.ShapeDtypeStruct((1, seq, 4, 128), jnp.bfloat16),) * 2,
+            pallas_attention.Selection(*(jax.ShapeDtypeStruct(
+                (1, 2, seq, 128), jnp.int32),) * 2)).jaxpr)
+        if eqn.params["name"] == introspect.KERNEL_DSA_BWD]
+    assert scratch == [[((seq, 128), "float32")]]
+    for other in (introspect.KERNEL_FLASH_FWD, introspect.KERNEL_FLASH_BWD,
+                  introspect.KERNEL_FLASH_DKV, introspect.KERNEL_FLASH_DQ,
+                  introspect.KERNEL_DSA_DKV, introspect.KERNEL_DSA_DQ):
+        assert "%" + other not in text
+
+
+def test_the_masked_pair_lowers_past_the_cap(one_chip):
+    """A learned mask whose one-pass panels pass the VMEM cap (1 x 32768
+    x 2 heads of 256: q and dO 64 MiB, dQ 64 more, the plane's eight
+    words 6) compiles as it did before there was a one pass:
+    ``hvd_dsa_dkv`` + ``hvd_dsa_dq`` of SEVEN operands each, dK/dV
+    reading ``by_key`` and dQ ``by_query``."""
+    seq = 32768
+    need = pallas_attention._vmem_need(seq, 256, 256, jnp.bfloat16, 512, 512,
+                                       0, 8 * 512, seq)
+    assert need == (142 << 20) and not pallas_attention._one_pass(need)
+    text, calls = _masked_step(seq, 2, 2, 256, one_chip)
+    assert {name: len(shapes) for name, (_, shapes) in calls.items()} == {
+        introspect.KERNEL_DSA_FWD: 4, introspect.KERNEL_DSA_DKV: 7,
+        introspect.KERNEL_DSA_DQ: 7}
+    for name, (_, shapes) in calls.items():
+        assert shapes[-1] == "s32[1,8,%d,128]" % seq, (name, shapes)
+    assert "%" + introspect.KERNEL_DSA_BWD not in text
 
 
 def test_the_selection_kernel_lowers_for_v5e(one_chip, monkeypatch):

@@ -184,9 +184,9 @@ def _tiles_moved(trace):
                       introspect.KERNEL_FLASH_BWD,
                       introspect.KERNEL_FLASH_DKV,
                       introspect.KERNEL_FLASH_DQ,
-                      introspect.KERNEL_DSA_FWD, introspect.KERNEL_DSA_DKV,
-                      introspect.KERNEL_DSA_DQ)
-            for kind in ("full", "edge", "skipped", "below")}
+                      introspect.KERNEL_DSA_FWD, introspect.KERNEL_DSA_BWD,
+                      introspect.KERNEL_DSA_DKV, introspect.KERNEL_DSA_DQ)
+            for kind in ("full", "edge", "skipped", "below", "learned")}
 
     before = read()
     trace()
@@ -373,27 +373,36 @@ def _one_width_limit(panel_rows, d, block, out_rows=0, select_rows=0,
     pytest.param(1, 8192, 20, 10, 64, None, False, 128, id="pair-full"),
     pytest.param(1, 16384, 8, 2, 64, None, False, 256, id="past-16-MiB"),
     pytest.param(1, 8192, 32, 4, 128, None, True, 64, id="select-narrow"),
+    # Panels past the cap under either mask: the two kernels, as they were.
+    pytest.param(1, 32768, 2, 2, 256, None, True, 256, id="select-past-cap"),
+    pytest.param(1, 32768, 4, 2, 256, None, False, 256, id="full-past-cap"),
 ])
 def test_the_calls_by_their_shapes_and_limits(b, s, h, h_kv, d, window,
                                               selected, d_v):
     """The ``pallas_call``s of a traced gradient at the cells' shapes:
     grid, every block, every result, the scoped-VMEM limit and the
-    scratch. A static mask: the forward and ONE backward,
-    ``hvd_flash_bwd``, on the key-major grid, with dQ its first result,
-    the query head's whole panel, beside a float32 scratch as large. A
-    learned mask: the forward, dK/dV and dQ exactly as they were. With
-    ``d_v == d`` the forward (and the learned three) are what the
-    kernels gave while one ``d`` built every spec (the limit by that
-    rule, written out above): with another ``d_v`` the blocks of v, dO,
-    the output and dV alone take it, and the limit takes each panel at
-    its own width."""
+    scratch. The forward and ONE backward, ``hvd_flash_bwd`` (under a
+    learned mask ``hvd_dsa_bwd``), on the key-major grid, with dQ its
+    first result, the query head's whole panel, beside a float32 scratch
+    as large; a learned mask's ``by_key`` rows are its seventh operand
+    and it reads no other plane. Where that call's limit would pass the
+    cap: dK/dV and dQ exactly as they were, under either mask. With
+    ``d_v == d`` the forward (and the pair) are what the kernels gave
+    while one ``d`` built every spec (the limit by that rule, written
+    out above): with another ``d_v`` the blocks of v, dO, the output and
+    dV alone take it, and the limit takes each panel at its own
+    width."""
     calls = _pallas_calls(_trace_gradient(b, s, h, h_kv, d, window, selected,
                                           d_v=d_v))
     prefix = "hvd_dsa_" if selected else "hvd_flash_"
-    assert sorted(calls) == [prefix + k for k in (
-        ("dkv", "dq", "fwd") if selected else ("bwd", "fwd"))]
     block, group = 512, h // h_kv
     n, words = s // block, -(-s // 4096)
+    one_pass = pallas_attention._vmem_need(
+        s, d, d_v, jnp.bfloat16, block, block, s if group > 1 else 0,
+        words * block if selected else 0, s) <= (100 << 20)
+    assert one_pass == ((s, d) != (32768, 256))     # the two past the cap
+    assert sorted(calls) == [prefix + k for k in (
+        ("bwd", "fwd") if one_pass else ("dkv", "dq", "fwd"))]
     # Block shapes as the specs give them: None where a dimension is
     # squeezed (batch and head; a bit plane has no head).
     plane = [(None, words, block, 128)] if selected else []
@@ -430,13 +439,13 @@ def test_the_calls_by_their_shapes_and_limits(b, s, h, h_kv, d, window,
         key_major = (b, h_kv, group, n)
         outs, out_rows, kind = [k_pan, v_pan], s, "float32"
     dkv_results = [((b, h_kv, s, d), kind), ((b, h_kv, s, d_v), kind)]
-    if not selected:
-        grid, blocks, results, vmem, scratch = calls["hvd_flash_bwd"]
+    if one_pass:
+        grid, blocks, results, vmem, scratch = calls[prefix + "bwd"]
         assert grid == key_major
-        assert blocks == [q_pan, k_blk, v_blk, do_pan, rows, rows] \
+        assert blocks == [q_pan, k_blk, v_blk, do_pan, rows, rows] + plane \
             + [q_pan] + outs
         assert results == [((b, h, s, d), "bfloat16")] + dkv_results
-        assert vmem == limit(d, d_v, out_rows, dq_rows=s)
+        assert vmem == limit(d, d_v, out_rows, sel_rows, dq_rows=s)
         assert vmem is None or vmem < (100 << 20)    # under the cap
         assert scratch == [((s, d), "float32")]
         return
@@ -461,7 +470,8 @@ def test_the_call_counter_tells_the_widths():
     kernel: under ``"64"`` where v is as wide as q.k, under
     ``"64+128"`` where it is not, and under no other label. A static
     mask moves ``hvd_flash_bwd`` and neither ``hvd_flash_dkv`` nor
-    ``hvd_flash_dq``; a learned one its three names as before."""
+    ``hvd_flash_dq``; a learned one ``hvd_dsa_fwd`` and ``hvd_dsa_bwd``
+    and neither of the pair."""
     names = ("hvd_flash_fwd", "hvd_flash_bwd")
     assert _calls_moved(lambda: _trace_gradient(1, 1024, 4, 2, 64)) \
         == {(name, "64"): 1 for name in names}
@@ -471,43 +481,69 @@ def test_the_call_counter_tells_the_widths():
     assert _calls_moved(lambda: _trace_gradient(
         1, 1024, 4, 2, 64, selected=True, d_v=32)) \
         == {(name, "64+32"): 1
-            for name in ("hvd_dsa_fwd", "hvd_dsa_dkv", "hvd_dsa_dq")}
+            for name in ("hvd_dsa_fwd", "hvd_dsa_bwd")}
 
 
-@pytest.mark.parametrize("learned,need,one", [
+@pytest.mark.parametrize("selected,need,one", [
     (False, 16 << 20, True),            # any static mask that fits
     (False, 100 << 20, True),           # up to the cap itself
     (False, (100 << 20) + 1, False),    # panels past the cap: two kernels
-    (True, 16 << 20, False),            # a mask that is data: two kernels
-    (True, 200 << 20, False),
+    (True, 16 << 20, True),             # a mask that is data: the same rule
+    (True, (100 << 20) + 1, False),
 ])
-def test_which_backward_runs_is_a_rule_on_the_input(learned, need, one):
-    assert pallas_attention._one_pass(learned, need) is one
+def test_which_backward_runs_is_a_rule_on_the_input(selected, need, one,
+                                                    monkeypatch):
+    """``_one_pass`` sees ONE number, the one pass's own VMEM reckoning,
+    which ``_flash_bwd`` makes WITH a learned plane's block; the mask
+    decides the kernels' names and nothing else."""
+    assert pallas_attention._one_pass(need) is one
+    reckoned = []
+
+    def told(tiles, group, d, d_v, sq_pad, sk_pad, dtype, words=0,
+             with_dq=False):
+        reckoned.append((tiles.learned, words, with_dq))
+        return need
+
+    monkeypatch.setattr(pallas_attention, "_key_major_need", told)
+    moved = _calls_moved(lambda: _trace_gradient(
+        1, 4224, 4, 2, 64, selected=selected))
+    prefix = "hvd_dsa_" if selected else "hvd_flash_"
+    assert sorted(name for name, _ in moved) == [prefix + k for k in (
+        ("bwd", "fwd") if one else ("dkv", "dq", "fwd"))]
+    # The rule's number first: two words of a 4224-row plane, with dQ.
+    assert reckoned[0] == (selected, 2 if selected else 0, True)
 
 
-@pytest.mark.parametrize("s,h,h_kv,d,d_v,dtype,names", [
+@pytest.mark.parametrize("s,h,h_kv,d,d_v,dtype,selected,names", [
     # lfm2-s16384-ep4-c1, the largest panels of any cell: 64 MiB.
-    (16384, 32, 8, 64, 64, jnp.bfloat16, ("bwd", "fwd")),
+    (16384, 32, 8, 64, 64, jnp.bfloat16, False, ("bwd", "fwd")),
     # Twice its rows at 256 wide: q and dO 64 MiB, dQ 64 more.
-    (32768, 2, 2, 256, 256, jnp.bfloat16, ("dkv", "dq", "fwd")),
+    (32768, 2, 2, 256, 256, jnp.bfloat16, False, ("dkv", "dq", "fwd")),
+    # keye-s8192-dsa-ep8-c1: the plane's block is 3 of its 43 MiB.
+    (8192, 32, 4, 128, 128, jnp.bfloat16, True, ("bwd", "fwd")),
+    # A learned mask past the cap keeps its pair.
+    (32768, 2, 2, 256, 256, jnp.bfloat16, True, ("dkv", "dq", "fwd")),
+    # 46 blocks at 256 wide: the cap itself without a plane, 105 MiB with
+    # its six words: the plane's block is part of what the rule weighs.
+    (23552, 2, 2, 256, 256, jnp.bfloat16, False, ("bwd", "fwd")),
+    (23552, 2, 2, 256, 256, jnp.bfloat16, True, ("dkv", "dq", "fwd")),
 ])
 def test_a_shape_past_the_cap_takes_the_two_kernels(s, h, h_kv, d, d_v,
-                                                    dtype, names):
-    """The rule reads the one pass's own VMEM reckoning: a static mask
-    whose resident panels pass 100 MiB traces ``hvd_flash_dkv`` +
-    ``hvd_flash_dq`` as it did, so that nothing that compiled stops
-    compiling; the cells' largest stays one pass."""
-    q = jax.ShapeDtypeStruct((1, s, h, d), dtype)
-    k = jax.ShapeDtypeStruct((1, s, h_kv, d), dtype)
-    v = jax.ShapeDtypeStruct((1, s, h_kv, d_v), dtype)
+                                                    dtype, selected, names):
+    """The rule reads the one pass's own VMEM reckoning: a mask, static
+    or learned, whose resident panels pass 100 MiB traces its two
+    kernels as it did, so that nothing that compiled stops compiling;
+    the cells' largest static call and the one learned call stay one
+    pass."""
+    words = -(-s // 4096) if selected else 0
     need = pallas_attention._vmem_need(
-        s, d, d_v, dtype, 512, 512, s if h != h_kv else 0, dq_rows=s)
+        s, d, d_v, dtype, 512, 512, s if h != h_kv else 0, words * 512,
+        dq_rows=s)
     assert (need <= (100 << 20)) == ("bwd" in names)
-    moved = _calls_moved(lambda: jax.make_jaxpr(jax.grad(
-        lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
-        (0, 1, 2)))(q, k, v))
+    moved = _calls_moved(lambda: _trace_gradient(
+        1, s, h, h_kv, d, selected=selected, d_v=d_v))
     assert sorted(name for name, _ in moved) \
-        == ["hvd_flash_" + n for n in names]
+        == [("hvd_dsa_" if selected else "hvd_flash_") + n for n in names]
 
 
 @pytest.mark.parametrize("env,cached", [
@@ -1039,6 +1075,99 @@ def test_one_pass_backward_matches_dense_and_the_two_kernels(
     far = 1e-5 if dtype == jnp.float32 else 3e-2
     for got, same, ref in zip(one, two, want):
         assert got.shape == same.shape and got.dtype == same.dtype
+        got, same = (jnp.swapaxes(x[:, :, :s], 1, 2).astype(jnp.float32)
+                     for x in (got, same))
+        assert got.shape == ref.shape
+        assert (np.asarray(got) == np.asarray(same)).all()
+        assert _rel(got, ref) < far
+
+
+def selected_reference(q, k, v, keep):
+    """``masked_reference`` under a mask that is data: a query sees the
+    keys at or before it that ``keep`` (B, S, S) names."""
+    d, group = q.shape[-1], q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    visible = jnp.tril(jnp.ones(keep.shape[1:], bool)) & keep
+    s = jnp.where(visible[:, None], s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+def _learned_one_pass_cases():
+    """A random selection / one that keeps every key by group 1 / 2 / 8
+    by float32 / bf16, over 1100 rows; the widths and the two tilings
+    (whole 128-lane groups, as a learned mask's are) turn with the case
+    as in ``_one_pass_cases``."""
+    cases = []
+    for i, every in enumerate((False, True)):
+        for j, group in enumerate((1, 2, 8)):
+            for n, dtype in enumerate((jnp.float32, jnp.bfloat16)):
+                d_v = (64, 128)[(i + j + n) % 2]
+                tiles = ((256, 128), (128, 384))[(i + j) % 2]
+                cases.append(pytest.param(
+                    every, group, d_v, dtype, tiles,
+                    id="%s-g%d-64+%d-%s-%dx%d" % (
+                        "every" if every else "random", group, d_v,
+                        jnp.dtype(dtype).name, *tiles)))
+    return cases
+
+
+@pytest.mark.parametrize("every,group,d_v,dtype,blocks",
+                         _learned_one_pass_cases())
+def test_one_pass_backward_under_a_learned_mask(every, group, d_v, dtype,
+                                                blocks):
+    """dQ, dK and dV of ``hvd_dsa_bwd``, which reads ``by_key`` alone,
+    against the dense attention under the same mask AND against
+    ``hvd_dsa_dkv`` + ``hvd_dsa_dq`` (dQ there masked by ``by_query``),
+    both reached through the wrappers ``_flash_bwd`` chooses between on
+    the same operands: equal BIT FOR BIT in interpret mode. Under a
+    plane that keeps every key the masked one pass is ``hvd_flash_bwd``
+    itself, bit for bit."""
+    s, heads, d = 1100, 8, 64
+    block_q, block_k = blocks
+    rng = np.random.RandomState(13)
+    q, k, v, g = (jnp.asarray(rng.randn(1, s, n, w), dtype)
+                  for n, w in ((heads, d), (heads // group, d),
+                               (heads // group, d_v), (heads, d_v)))
+    keep = jnp.asarray(np.ones((1, s, s), bool) if every else
+                       (rng.rand(1, s, s) < 0.3) | np.eye(s, dtype=bool))
+    select = pallas_attention.pack_selection(keep)
+    want = jax.vjp(lambda q, k, v: selected_reference(
+        *(x.astype(jnp.float32) for x in (q, k, v)), keep), q, k, v)[1](
+            g.astype(jnp.float32))
+    # Through the public call: the rule takes the one pass.
+    moved = _calls_moved(lambda: jax.make_jaxpr(
+        lambda *a: jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, select=select, block_q=block_q, block_k=block_k),
+            *a[:3])[1](a[3]))(q, k, v, g))
+    assert sorted(name for name, _ in moved) == ["hvd_dsa_bwd", "hvd_dsa_fwd"]
+
+    scale = d ** -0.5
+    heads_first = [jnp.swapaxes(x, 1, 2) for x in (q, k, v, g)]
+    _, res = pallas_attention._flash_fwd_impl(
+        *heads_first[:3], True, None, block_q, block_k, scale, True, select)
+    tiles, *operands = pallas_attention._bwd_operands(
+        block_q, block_k, True, None, res, heads_first[3])
+    assert tiles.learned and tiles.padded_keys and operands[0].shape[2] > s
+    assert operands[-1] is select
+    one = pallas_attention._bwd_one_pass(tiles, scale, True, *operands)
+    two = pallas_attention._bwd_two_kernels(tiles, scale, True, *operands)
+    # The one pass has no use for ``by_query``: spoiled, nothing moves.
+    blind = pallas_attention._bwd_one_pass(
+        tiles, scale, True, *operands[:-1],
+        select._replace(by_query=jnp.zeros_like(select.by_query)))
+    static = None
+    if every:
+        static = pallas_attention._bwd_one_pass(
+            tiles._replace(learned=False), scale, True, *operands[:-1])
+    assert one[0].dtype == two[0].dtype == dtype
+    assert not np.asarray(one[0][:, :, s:], np.float32).any()
+    far = 1e-5 if dtype == jnp.float32 else 3e-2
+    for i, (got, same, ref) in enumerate(zip(one, two, want)):
+        assert got.shape == same.shape and got.dtype == same.dtype
+        assert (np.asarray(got) == np.asarray(blind[i])).all()
+        if static is not None:
+            assert (np.asarray(got) == np.asarray(static[i])).all()
         got, same = (jnp.swapaxes(x[:, :, :s], 1, 2).astype(jnp.float32)
                      for x in (got, same))
         assert got.shape == ref.shape

@@ -275,7 +275,8 @@ def test_a_recomputed_block_does_not_choose_again(params):
     assert [inside for _, inside in calls] == [False] * LAYERS
     # The masked kernels do stand inside: the backward pass is there.
     assert any(inside for _, inside in _calls(
-        jaxpr.jaxpr, introspect.KERNEL_DSA_DKV))
+        jaxpr.jaxpr, introspect.KERNEL_DSA_BWD))
+    assert not _calls(jaxpr.jaxpr, introspect.KERNEL_DSA_DKV)
 
 
 @pytest.mark.parametrize("attention,s,forced,via", [

@@ -441,8 +441,9 @@ SPARSE_HELD_EXPERTS = BlockSpec(
     head_dim=16, n_kv_heads=2, qk_norm_per_head=True, index_heads=4,
     index_head_dim=12, index_topk=8, num_experts=8, experts_per_token=2,
     norm_topk=True, experts_held=2)
-MASKED_KERNELS = (introspect.KERNEL_DSA_FWD, introspect.KERNEL_DSA_DKV,
-                  introspect.KERNEL_DSA_DQ)
+MASKED_KERNELS = (introspect.KERNEL_DSA_FWD, introspect.KERNEL_DSA_BWD)
+# What runs the backward where the one pass's panels pass the VMEM cap.
+MASKED_PAIR = (introspect.KERNEL_DSA_DKV, introspect.KERNEL_DSA_DQ)
 
 
 def _loops_inside(jaxpr, inside=False):
@@ -466,14 +467,14 @@ def _loops_inside(jaxpr, inside=False):
 def test_a_recomputed_sparse_block_neither_scores_nor_selects(
         keeps_the_planes, monkeypatch):
     """A block whose queries choose their keys: the two bit planes of
-    the choice carry ``SAVED_FLASH_SELECT`` and all three masked kernels
-    read them, so the recomputed forward holds no indexer matmul (its
+    the choice carry ``SAVED_FLASH_SELECT`` and both masked kernels read
+    theirs, so the recomputed forward holds no indexer matmul (its
     three projections, its dot products), no loop (the passes over
     blocks of queries, the bisection) and no ``hvd_dsa_select`` work;
     what it multiplies again is each router's logits and q and k before
     their norms. Without the name on the list the indexer runs twice a
     layer. Each masked kernel is traced once a layer either way, the
-    static ones never."""
+    static ones and the masked pair never."""
     if not keeps_the_planes:
         monkeypatch.setattr(
             transformer_module, "_REMAT_KEEPS", tuple(
@@ -485,8 +486,9 @@ def test_a_recomputed_sparse_block_neither_scores_nor_selects(
         _model(True, "flash", block), variables)))(variables["params"])
     text = str(gradient)
     assert {name: len(re.findall(r"name=%s\b" % name, text))
-            for name in MASKED_KERNELS + KERNELS} == dict(
-        dict.fromkeys(MASKED_KERNELS, LAYERS), **dict.fromkeys(KERNELS, 0))
+            for name in MASKED_KERNELS + MASKED_PAIR + KERNELS} == dict(
+        dict.fromkeys(MASKED_KERNELS, LAYERS),
+        **dict.fromkeys(MASKED_PAIR + KERNELS, 0))
     by_weight = collections.Counter(
         shapes[1] for shapes, _ in _matmuls(gradient.jaxpr,
                                             recomputation=True))

@@ -102,6 +102,11 @@ SCOPE_MOE_COMBINE = "hvd_moe_combine"
 SCOPE_MOE_ROWS = "hvd_moe_rows"
 # The shared expert's three matmuls, beside the routed sum.
 SCOPE_MOE_SHARED = "hvd_moe_shared"
+# Round the layer's ROUTING half (``hvd_moe_router`` and the sort of
+# ``hvd_moe_dispatch`` stay inside it) where the router reads the
+# block's normed input (``BlockSpec.router_tap`` 'mixer'): everything
+# the layer does that depends on nothing its block's mixer makes.
+SCOPE_MOE_PREROUTE = "hvd_moe_preroute"
 # ``name=`` of the flash ``pallas_call``s (the Mosaic calls' op_name):
 # the forward; the backward of a static mask in ONE pass; the two
 # kernels that run it where the one pass's panels pass the VMEM cap.

@@ -220,7 +220,8 @@ class BlockSpec:
 
     norm: str = "layernorm"          # | 'rmsnorm' (scale only)
     norm_eps: float = 1e-6
-    ffn: str = "gelu"                # | 'swiglu': silu(gate) * up, then down
+    # | 'swiglu': silu(gate) * up, then down | 'reglu': relu(gate) * up
+    ffn: str = "gelu"
     positions: str = "learned"       # | 'rope' (rotate-half, on q and k)
     rope_theta: float = 10000.0
     qk_norm: bool = False            # the norm on q and k, over all heads
@@ -291,6 +292,11 @@ class BlockSpec:
     # score + a correction bias that is carried state, the gates from
     # the scores alone.
     router: str = "softmax"
+    # The array an expert layer's router reads: 'ffn', the rows its
+    # experts read (the norm after the mixer); 'mixer', the block's
+    # normed INPUT, which its mixer reads too, so that the choice of
+    # experts depends on nothing the mixer makes.
+    router_tap: str = "ffn"
     norm_topk: bool = False          # gates / their sum over the chosen
     routed_scale: float = 1.0        # then times this
     shared_experts: int = 0          # experts every token goes through
@@ -951,6 +957,9 @@ class Mlp(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        # One table for this gate and the grouped experts'.
+        from horovod_tpu.parallel.moe import GATE_ACTIVATIONS
+
         cfg = self.cfg
         d_ff = self.width or cfg.d_ff
         init = nn.initializers.normal(0.02)
@@ -962,11 +971,11 @@ class Mlp(nn.Module):
         # two the activation's backward reads, and the third where a
         # norm reads it (``_remat_block``), and runs the activation.
         y = checkpoint_name(x @ wi.astype(cfg.dtype), SAVED_MLP_UP)
-        if cfg.block.ffn == "swiglu":
+        if cfg.block.ffn in GATE_ACTIVATIONS:
             wg = self.param("wg", param_with_axes(init, (None, "model")),
                             (cfg.d_model, d_ff), jnp.float32)
-            y = nn.silu(checkpoint_name(x @ wg.astype(cfg.dtype),
-                                        SAVED_MLP_GATE)) * y
+            y = GATE_ACTIVATIONS[cfg.block.ffn](checkpoint_name(
+                x @ wg.astype(cfg.dtype), SAVED_MLP_GATE)) * y
         else:
             y = nn.gelu(y)
         return checkpoint_name(y @ wo.astype(cfg.dtype), SAVED_MLP_OUT)
@@ -1018,7 +1027,8 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, assignment=None, selection=None, reads=None):
-        with trace_span("block", layer=self.name, kind=self.layer_type):
+        with trace_span("block", layer=self.name, kind=self.layer_type,
+                        ffn=self.cfg.block.ffn):
             cfg = self.cfg
 
             def joined(x, name, branch):
@@ -1026,7 +1036,7 @@ class Block(nn.Module):
                     branch = _norm(cfg, name)(branch)
                 return x + branch
 
-            y = _norm(cfg, "ln1")(x)
+            y = mixer_input = _norm(cfg, "ln1")(x)
             mixer = self._mixer()
             if self.layer_type == MEMORY_UNIT:
                 branch = mixer(y, reads)
@@ -1050,8 +1060,12 @@ class Block(nn.Module):
                     # ``moe/shared``, its time the ``moe`` scope's.
                     shared = Mlp(cfg, cfg.block.shared_experts * cfg.d_ff,
                                  parent=None)
-                x = joined(x, "post_mlp_norm",
-                           MoeMlp(cfg, shared, name="moe")(y, assignment))
+                # The experts read ``ln2``'s output; the layer's router
+                # reads that too, or ``ln1``'s where
+                # ``BlockSpec.router_tap`` is 'mixer': its routing half
+                # then depends on nothing the mixer made.
+                x = joined(x, "post_mlp_norm", MoeMlp(
+                    cfg, shared, name="moe")(y, assignment, mixer_input))
             else:
                 x = joined(x, "post_mlp_norm",
                            Mlp(cfg, self.dense_width, name="mlp")(y))

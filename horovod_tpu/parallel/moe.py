@@ -41,7 +41,21 @@
   What absent experts would have added is left out; the exchange that
   brings other chips' rows here wraps this layer later (ROADMAP, D14).
   A ``sigmoid_bias`` router (``route``) and shared experts beside the
-  routed sum are the same layer's options.
+  routed sum are the same layer's options. The layer is TWO HALVES.
+  ``_routing`` reads the ROUTER's input, the router's weight and its
+  bias and nothing else: logits, top-k, gates, the count of each
+  expert's pairs, the sort's order and inverse, the held experts' group
+  sizes and live rows (a ``_Plan``). ``_held_sum`` reads the EXPERTS'
+  input, that plan and the experts' weights. With
+  ``BlockSpec.router_tap`` 'ffn' the two inputs are one array, the
+  norm after the mixer; with 'mixer' (the ``smallthinker`` family's
+  "router placed before attention") the router's is the block's normed
+  input, made an attention earlier, and the routing half runs under
+  ``hvd_moe_preroute``: nothing in it waits for the mixer. Either way
+  the backward rule of a layer that holds a share remakes its sorted
+  rows from ``tokens, order, inverse, gates``: the experts' input and
+  the plan. The gated activation is ``BlockSpec.ffn``'s: ``silu`` (SwiGLU)
+  or ``relu`` (ReGLU) on the gate projection.
 - ``top1_dispatch`` / ``moe_ffn`` / ``expert_parallel_moe`` — the older
   Switch-style top-1 form with a capacity, which DROPS overflow tokens,
   and its explicit shard_map formulation over the ``expert`` axis (two
@@ -53,7 +67,7 @@
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +80,7 @@ from horovod_tpu.jax.introspect import (
     SCOPE_MOE_COMBINE,
     SCOPE_MOE_DISPATCH,
     SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_PREROUTE,
     SCOPE_MOE_ROUTER,
     SCOPE_MOE_ROWS,
     SCOPE_MOE_SHARED,
@@ -110,6 +125,13 @@ _M_EXPERTS = _metrics.counter(
     "hvd_moe_experts_total",
     "Experts per traced expert layer: those whose weights it holds and "
     "those its router scores (counted at trace time).", ("kind",))
+# Also at trace time: the expert layers one traced model makes, by the
+# array their router reads (``BlockSpec.router_tap``).
+_M_LAYERS = _metrics.counter(
+    "hvd_moe_layers_total",
+    "Expert layers per traced model, by what their router reads: ffn (the "
+    "rows the experts read) or mixer (the block's normed input, known "
+    "before the mixer runs); counted at trace time.", ("tap",))
 
 
 def top1_dispatch(router_logits, capacity: int):
@@ -360,14 +382,22 @@ def _grouped_matmul(lhs, rhs, group_sizes):
     return lax.ragged_dot(lhs, rhs, group_sizes)
 
 
-def grouped_ffn(rows, row_gates, group_sizes, wi, wo, wg=None, live=None):
+# The activation on a gated feed-forward's gate projection, by
+# ``BlockSpec.ffn`` (``grouped_ffn`` here, ``Mlp`` in models/transformer.py).
+GATE_ACTIVATIONS = {"swiglu": nn.silu, "reglu": nn.relu}
+
+
+def grouped_ffn(rows, row_gates, group_sizes, wi, wo, wg=None, live=None,
+                *, ffn):
     """Each expert's feed-forward over its own rows, times the row's
     gate: ``rows`` (N, M) sorted by expert, ``row_gates`` (N,) float32,
-    ``group_sizes`` (E,) rows each; weights (E, M, F), (E, F, M). With
-    ``wg`` the experts are gated (SwiGLU: ``silu(rows wg) * (rows
-    wi)``), else GELU. The gate multiplies the activation, F wide, in
-    float32, and the product is rounded once to the rows' dtype; the
-    gates' gradient is that fusion's reduction over F.
+    ``group_sizes`` (E,) rows each; weights (E, M, F), (E, F, M). Three
+    activations: without ``wg`` GELU, ``gelu(rows wi)``; with it the
+    experts are gated, by ``ffn`` SwiGLU, ``silu(rows wg) * (rows wi)``,
+    or ReGLU, ``relu(rows wg) * (rows wi)``. The gate multiplies the
+    activation, F wide, in float32, and the product is rounded once to
+    the rows' dtype; the gates' gradient is that fusion's reduction
+    over F.
 
     ``live`` = ``sum(group_sizes)`` where that is below N: the rows past
     it belong to no group and a grouped matmul leaves its result there
@@ -386,8 +416,9 @@ def grouped_ffn(rows, row_gates, group_sizes, wi, wo, wg=None, live=None):
     if wg is None:
         hidden = nn.gelu(up)
     else:
-        hidden = nn.silu(live_rows(_grouped_matmul(rows, wg, group_sizes))
-                         .astype(jnp.float32)) * up
+        hidden = GATE_ACTIVATIONS[ffn](
+            live_rows(_grouped_matmul(rows, wg, group_sizes))
+            .astype(jnp.float32)) * up
     hidden = live_rows((hidden * row_gates[:, None]).astype(rows.dtype))
     return _grouped_matmul(hidden, wo, group_sizes)
 
@@ -405,7 +436,7 @@ def prefix_rows(t, k, held, e):
 
 
 def _expert_rows(n, k, tokens, order, inverse, gates, sizes, live, wi, wo,
-                 wg):
+                 wg, ffn):
     """(T, M): each token's gate-weighted sum over the experts held
     here, through sorted-row arrays ``n`` long: all T x k pairs, or a
     prefix that holds the ``live`` ones. ``gates`` (T, k) float32;
@@ -424,12 +455,13 @@ def _expert_rows(n, k, tokens, order, inverse, gates, sizes, live, wi, wo,
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         out = grouped_ffn(rows, row_gates, sizes, wi.astype(rows.dtype),
                           wo.astype(rows.dtype),
-                          wg if wg is None else wg.astype(rows.dtype), live)
+                          wg if wg is None else wg.astype(rows.dtype), live,
+                          ffn=ffn)
     with jax.named_scope(SCOPE_MOE_COMBINE):
         return _combine(out, head, visits, k, t)
 
 
-def _rows_branch(n, k):
+def _rows_branch(n, k, ffn):
     """``_expert_rows`` over ``n`` rows as one branch of the choice.
 
     Two names a device trace's readers need (``instruction_scopes``).
@@ -448,7 +480,7 @@ def _rows_branch(n, k):
             with jax.named_scope(SCOPE_MOE_EXPERTS):
                 weights = lax.optimization_barrier(weights)
             return _expert_rows(n, k, tokens, order, inverse, gates, sizes,
-                                live, *weights)
+                                live, *weights, ffn)
     return branch
 
 
@@ -463,25 +495,28 @@ def _rows_branch(n, k):
 # a block's recomputation the outer recomputed forward is then dead
 # code; where the block reads the layer's output again, a norm on it, it
 # keeps that output: ``SAVED_MOE_OUT``).
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_rows(c, k, tokens, order, inverse, gates, sizes, live, wi, wo, wg):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 11))
+def _held_rows(c, k, tokens, order, inverse, gates, sizes, live, wi, wo, wg,
+               ffn):
     with jax.named_scope(SCOPE_MOE_ROWS):
         return lax.cond(
-            live <= c, _rows_branch(c, k), _rows_branch(order.shape[0], k),
+            live <= c, _rows_branch(c, k, ffn),
+            _rows_branch(order.shape[0], k, ffn),
             tokens, order, inverse, gates, sizes, live, wi, wo, wg)
 
 
-def _held_rows_fwd(c, k, *operands):
-    return _held_rows(c, k, *operands), operands
+def _held_rows_fwd(c, k, *operands_and_ffn):
+    *operands, ffn = operands_and_ffn
+    return _held_rows(c, k, *operands, ffn), tuple(operands)
 
 
-def _held_rows_bwd(c, k, operands, d_out):
+def _held_rows_bwd(c, k, ffn, operands, d_out):
     tokens, order, inverse, gates, sizes, live, *weights = operands
 
     def gradients(n):
         def rows(tokens, gates, *weights):
-            return _rows_branch(n, k)(tokens, order, inverse, gates, sizes,
-                                      live, *weights)
+            return _rows_branch(n, k, ffn)(tokens, order, inverse, gates,
+                                           sizes, live, *weights)
         return lambda d_out, *floating: jax.vjp(rows, *floating)[1](d_out)
 
     with jax.named_scope(SCOPE_MOE_ROWS):
@@ -501,10 +536,109 @@ def _held_rows_bwd(c, k, operands, d_out):
 _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 
+class _Plan(NamedTuple):
+    """What the ROUTING half of an expert layer hands the expert half:
+    everything that depends on the router's input alone. ``gates`` (T,
+    k) float32 and ``experts`` (T, k), over all experts; ``counts`` (E,)
+    pairs each expert received; ``order`` / ``inverse``, the pairs
+    sorted by expert, held experts first (``sorted_by_expert``);
+    ``sizes``, the held experts' rows; ``live``, their sum (None where
+    every expert is held); ``aux``, the softmax router's two auxiliary
+    losses, else None."""
+
+    gates: jax.Array
+    experts: jax.Array
+    counts: jax.Array
+    order: jax.Array
+    inverse: jax.Array
+    sizes: jax.Array
+    live: Optional[jax.Array]
+    aux: Optional[tuple]
+
+
+def _routing(spec, tokens, wr, bias, assignment):
+    """The ROUTING half: the ``_Plan`` of ``tokens`` (T, M), the array
+    the router reads. Reads the router's weight and bias and nothing of
+    the experts; differentiable through the gates alone."""
+    e, k = spec.num_experts, spec.experts_per_token
+    held, first = spec.experts_held or e, spec.first_expert_held
+    with jax.named_scope(SCOPE_MOE_ROUTER):
+        # The choice of k among E is discrete: the logits are made
+        # in float32 whatever the compute dtype.
+        logits = jnp.dot(tokens.astype(jnp.float32), wr,
+                         precision=lax.Precision.HIGHEST)
+        scores, gates, experts = route(
+            logits, k, assignment, scoring=spec.router, bias=bias,
+            norm_topk=spec.norm_topk, scale=spec.routed_scale)
+        counts = jnp.sum(jax.nn.one_hot(experts.reshape(-1), e,
+                                        dtype=jnp.int32), axis=0)
+        aux = None
+        if spec.router == "softmax":
+            aux = aux_losses(logits, scores, counts)
+        # The held experts' rows are the first ``live`` sorted rows,
+        # in ``held`` ragged groups; all T x k where all are held.
+        sizes, live = counts, None
+        if held < e:
+            sizes = counts[first:first + held]
+            live = jnp.sum(sizes)
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        order, inverse = sorted_by_expert(experts, first, e)
+    return _Plan(gates, experts, counts, order, inverse, sizes, live, aux)
+
+
+def _held_sum(cfg, plan, tokens, wi, wo, wg):
+    """The EXPERT half: (T, M), each of ``tokens``' gate-weighted sum
+    over the experts held here under ``plan``, and whether the live
+    rows overflowed the prefix. ``tokens`` are the rows the experts
+    read, which need not be the array the plan was made from."""
+    spec = cfg.block
+    e, k = spec.num_experts, spec.experts_per_token
+    t = tokens.shape[0]
+    # The sorted-row arrays' length, and whether it is chosen each
+    # step between it and the whole T x k.
+    c = prefix_rows(t, k, spec.experts_held or e, e)
+    if c == t * k:
+        return _expert_rows(t * k, k, tokens, plan.order, plan.inverse,
+                            plan.gates, plan.sizes, plan.live, wi, wo, wg,
+                            spec.ffn), 0
+    # Cast before the choice: a branch hands its weight
+    # gradients back in the compute dtype.
+    with jax.named_scope(SCOPE_MOE_EXPERTS):
+        wi, wo, wg = (w if w is None else w.astype(cfg.dtype)
+                      for w in (wi, wo, wg))
+    # Named for a block's recomputation to keep where it reads
+    # the output again (models/transformer.py ``_remat_block``).
+    out = checkpoint_name(
+        _held_rows(c, k, tokens, plan.order, plan.inverse, plan.gates,
+                   plan.sizes, plan.live, wi, wo, wg, spec.ffn),
+        SAVED_MOE_OUT)
+    return out, (plan.live > c).astype(jnp.int32)
+
+
 class MoeMlp(nn.Module):
     """The expert feed-forward of a transformer block: top-k, dropless
     (module docstring). Expert weights carry ``expert``-axis
     partitioning metadata. ``assignment`` (T, k) forces the routing.
+
+    The layer is TWO halves. The routing half (``_routing``) reads the
+    router's input, the router's weight and its bias, and makes the
+    plan: logits in float32, the top k, the gates, the pairs each
+    expert received, the order that sorts the pairs by expert with its
+    inverse, the held experts' group sizes and their live rows. The
+    expert half (``_held_sum``) reads the experts' input, the plan and
+    the experts' weights: rows gathered in the plan's order, the grouped
+    matmuls with the gates inside their activation, each token's sum.
+    The block hands in ``mixer_input``, its normed INPUT (what its mixer
+    reads too), and ``BlockSpec.router_tap`` says which array the router
+    reads. With 'ffn' both halves read ``x`` and ``mixer_input`` is not
+    looked at. With 'mixer' the router reads ``mixer_input``: the plan
+    then depends on nothing the mixer makes, the routing half runs under
+    ``hvd_moe_preroute`` (still inside this module's scope: the weight
+    stays ``moe/router``), and the router's gradient reaches the
+    block's input through ``mixer_input`` alone. The backward rule of a
+    layer that holds a share (``_held_rows``) remakes its sorted rows
+    from ``tokens, order, inverse, gates``: the plan is its residual,
+    whichever array made it.
 
     With ``BlockSpec.experts_held`` the weights are those of experts
     ``first_expert_held`` onward and the output is THEIR part of the
@@ -529,13 +663,16 @@ class MoeMlp(nn.Module):
     shared: Optional[nn.Module] = None
 
     @nn.compact
-    def __call__(self, x, assignment=None):
+    def __call__(self, x, assignment=None, mixer_input=None):
         cfg, spec = self.cfg, self.cfg.block
-        e, k = spec.num_experts, spec.experts_per_token
-        held, first = spec.experts_held or e, spec.first_expert_held
+        e = spec.num_experts
+        held = spec.experts_held or e
+        tap = spec.router_tap
         _M_EXPERTS.labels(kind="held").inc(held)
         _M_EXPERTS.labels(kind="routed").inc(e)
-        with trace_span("experts", held=held, routed=e):
+        _M_LAYERS.labels(tap=tap).inc()
+        with trace_span("experts", held=held, routed=e, tap=tap,
+                        ffn=spec.ffn):
             b, s, m = x.shape
             t = b * s
             init = nn.initializers.normal(0.02)
@@ -547,11 +684,8 @@ class MoeMlp(nn.Module):
                             jnp.float32)
             wo = self.param("wo", experts_init, (held, cfg.d_ff, m),
                             jnp.float32)
-            # The sorted-row arrays' length, and whether it is chosen each
-            # step between it and the whole T x k.
-            c = prefix_rows(t, k, held, e)
             wg = None
-            if spec.ffn == "swiglu":
+            if spec.ffn in GATE_ACTIVATIONS:
                 wg = self.param("wg", experts_init, (held, m, cfg.d_ff),
                                 jnp.float32)
             bias = None
@@ -560,52 +694,25 @@ class MoeMlp(nn.Module):
                                      (e,), jnp.float32).value
 
             tokens = x.reshape(t, m)
-            with jax.named_scope(SCOPE_MOE_ROUTER):
-                # The choice of k among E is discrete: the logits are made
-                # in float32 whatever the compute dtype.
-                logits = jnp.dot(tokens.astype(jnp.float32), wr,
-                                 precision=lax.Precision.HIGHEST)
-                scores, gates, experts = route(
-                    logits, k, assignment, scoring=spec.router, bias=bias,
-                    norm_topk=spec.norm_topk, scale=spec.routed_scale)
-                counts = jnp.sum(jax.nn.one_hot(experts.reshape(-1), e,
-                                                dtype=jnp.int32), axis=0)
-                if spec.router == "softmax":
-                    load_balance, z_loss = aux_losses(logits, scores, counts)
-                # The held experts' rows are the first ``live`` sorted rows,
-                # in ``held`` ragged groups; all T x k where all are held.
-                sizes, live, rows_held, rows_overflow = counts, None, t * k, 0
-                if held < e:
-                    sizes = counts[first:first + held]
-                    live = rows_held = jnp.sum(sizes)
-            with jax.named_scope(SCOPE_MOE_DISPATCH):
-                order, inverse = sorted_by_expert(experts, first, e)
-            if c == t * k:
-                out = _expert_rows(t * k, k, tokens, order, inverse, gates,
-                                   sizes, live, wi, wo, wg)
+            if tap == "mixer":
+                with jax.named_scope(SCOPE_MOE_PREROUTE):
+                    plan = _routing(spec, mixer_input.reshape(t, m), wr,
+                                    bias, assignment)
             else:
-                # Cast before the choice: a branch hands its weight
-                # gradients back in the compute dtype.
-                with jax.named_scope(SCOPE_MOE_EXPERTS):
-                    wi, wo, wg = (w if w is None else w.astype(cfg.dtype)
-                                  for w in (wi, wo, wg))
-                # Named for a block's recomputation to keep where it reads
-                # the output again (models/transformer.py ``_remat_block``).
-                out = checkpoint_name(
-                    _held_rows(c, k, tokens, order, inverse, gates, sizes,
-                               live, wi, wo, wg), SAVED_MOE_OUT)
-                rows_overflow = (live > c).astype(jnp.int32)
+                plan = _routing(spec, tokens, wr, bias, assignment)
+            out, rows_overflow = _held_sum(cfg, plan, tokens, wi, wo, wg)
             if self.shared is not None:
                 with jax.named_scope(SCOPE_MOE_SHARED):
                     out = out + self.shared(tokens)
             if not self.is_initializing():
-                if spec.router == "softmax":
-                    self.sow("moe", "load_balance", load_balance)
-                    self.sow("moe", "z_loss", z_loss)
-                self.sow("moe", "tokens_per_expert", counts)
-                self.sow("moe", "rows_held", rows_held)
+                if plan.aux is not None:
+                    self.sow("moe", "load_balance", plan.aux[0])
+                    self.sow("moe", "z_loss", plan.aux[1])
+                self.sow("moe", "tokens_per_expert", plan.counts)
+                self.sow("moe", "rows_held", t * spec.experts_per_token
+                         if plan.live is None else plan.live)
                 self.sow("moe", "rows_overflow", rows_overflow)
-                self.sow("moe", "experts", experts)
+                self.sow("moe", "experts", plan.experts)
             return out.reshape(b, s, m)
 
 

@@ -647,13 +647,14 @@ def test_a_traced_step_files_a_block_a_layer_and_an_eager_call_nothing():
                for s in mine)
     blocks = [s for s in mine if s["name"] == "trace/block"]
     assert [s["args"] for s in blocks] == [
-        {"layer": "layer_%d" % i, "kind": kind}
+        {"layer": "layer_%d" % i, "kind": kind, "ffn": "swiglu"}
         for i, kind in enumerate(kinds)]
     # The first layer's feed-forward is dense; each other block holds its
     # expert layer, the child of the span it lies in.
     experts = [s for s in mine if s["name"] == "trace/experts"]
     assert [(s["args"], s["parent"]) for s in experts] == [
-        ({"held": 4, "routed": 4}, block["id"]) for block in blocks[1:]]
+        ({"held": 4, "routed": 4, "tap": "ffn", "ffn": "swiglu"},
+         block["id"]) for block in blocks[1:]]
     # No axis is bound here: the sync traces nothing and files nothing;
     # the update does, outside every block.
     (update,) = [s for s in mine if s["name"] == "trace/update"]

@@ -118,7 +118,8 @@ def _reference_rows(n, k, tokens, order, inverse, gates, sizes, live, wi,
     gathers and XLA's sum."""
     rows = tokens[order[:n] // k]
     row_gates = gates.reshape(-1)[order][:n]
-    out = moe_mod.grouped_ffn(rows, row_gates, sizes, wi, wo, wg, live)
+    out = moe_mod.grouped_ffn(rows, row_gates, sizes, wi, wo, wg, live,
+                              ffn="swiglu")
     return _xla_sum(out, inverse, k, live)
 
 
@@ -160,7 +161,7 @@ def test_held_rows_gradients_equal_the_gather_forms(overflow):
         return (out,) + vjp(ct)
 
     got = jax.jit(lambda: run(
-        lambda *a: moe_mod._held_rows(c, k, *a)))()
+        lambda *a: moe_mod._held_rows(c, k, *a, "swiglu")))()
     n = t * k if overflow else c
     want = jax.jit(lambda: run(
         lambda *a: _reference_rows(n, k, *a)))()

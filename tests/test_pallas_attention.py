@@ -286,7 +286,8 @@ def _cell_attention_shapes():
         elif "full_attention" in kinds:
             calls.append((None, False))
         if "sliding_attention" in kinds:
-            calls.append((config["sliding_window"], False))
+            calls.append((config.get("sliding_window")
+                          or config["sliding_window_size"], False))
         for window, selected in calls:
             shape = (traffic["per_chip_batch"], traffic["seq_len"], heads,
                      kv_heads, dim, window, selected)
@@ -938,6 +939,51 @@ def test_window_and_grouped_heads_match_dense(window, group, q_len, kv_len,
     assert tiles.counts() == want
     if window in (1, 3, 64):
         assert want["below"] > 0 and want["edge"] > 0
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(7, 1), (14, 2), (28, 4)])
+@pytest.mark.parametrize("window", [None, 40, 300])
+def test_a_group_of_seven_matches_dense(heads, kv_heads, window):
+    """Grouped key/value heads at a group that is no power of two
+    (smallthinker-s8192-ep4-c1's 28 query heads over 4: SEVEN a key/value
+    head), with and without a window: the forward, and dQ, dK and dV of
+    the ONE-pass backward, against the dense masked attention, in which
+    query head h reads key/value head ``h // 7`` and dK/dV are the sums
+    over a head's seven readers; 300 rows under 64 x 32 tiles (padded
+    rows and keys)."""
+    s, d = 300, 16
+    assert heads // kv_heads == 7
+    rng = np.random.RandomState(heads + (window or 0))
+    q, k, v, g = (jnp.asarray(rng.randn(1, s, n, d), jnp.float32)
+                  for n in (heads, kv_heads, kv_heads, heads))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=64, block_k=32)
+
+    moved = _calls_moved(lambda: jax.make_jaxpr(
+        lambda *a: jax.vjp(flash, *a[:3])[1](a[3]))(q, k, v, g))
+    assert sorted(name for name, _ in moved) \
+        == ["hvd_flash_bwd", "hvd_flash_fwd"]
+    out, vjp = jax.vjp(flash, q, k, v)
+    ref, ref_vjp = jax.vjp(lambda q, k, v: masked_reference(q, k, v, window),
+                           q, k, v)
+    assert out.shape == ref.shape and _rel(out, ref) < 1e-5
+    for got, want in zip(vjp(g), ref_vjp(g)):
+        assert got.shape == want.shape          # dK, dV: kv_heads wide
+        assert _rel(got, want) < 1e-5
+    # The dense reference's own dK IS the sum over the seven readers:
+    # repeat the key heads, differentiate each copy, add them up.
+    if window is None:
+        spread = jnp.repeat(k, 7, axis=2)
+        each = jax.grad(lambda kk: jnp.sum(masked_reference(
+            q, kk, jnp.repeat(v, 7, axis=2), None) * g))(spread)
+        assert _rel(vjp(g)[1],
+                    each.reshape(1, s, kv_heads, 7, d).sum(3)) < 1e-5
+        # ... and head h // 7, not h % kv_heads.
+        if kv_heads > 1:
+            wrong = each.reshape(1, s, 7, kv_heads, d).sum(2)
+            assert _rel(vjp(g)[1], wrong) > 1e-2
 
 
 def test_window_tile_counts_at_the_benchmarks_shape():

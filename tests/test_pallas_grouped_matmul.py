@@ -145,7 +145,8 @@ def test_grouped_ffn_through_the_kernels(monkeypatch, gated, live):
 
     def loss(rows, gates, wi, wo, wg):
         out = moe_mod.grouped_ffn(rows, gates, sizes, wi, wo,
-                                  wg if gated else None, live)
+                                  wg if gated else None, live,
+                                  ffn="swiglu" if gated else "gelu")
         return jnp.sum(jnp.where(alive, out, 0).astype(jnp.float32) ** 2)
 
     grad = lambda: jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))(
@@ -188,7 +189,7 @@ def test_a_shape_the_tiles_do_not_divide_is_ragged_dots():
                                             jnp.float32)
     before = _count("forward", "xla"), _count("forward", "kernel")
     out = moe_mod.grouped_ffn(rows, gates, jnp.asarray([30, 66], jnp.int32),
-                              wi, wo, wg)
+                              wi, wo, wg, ffn="swiglu")
     assert out.shape == (96, 40)
     assert _count("forward", "xla") == before[0] + 3
     assert _count("forward", "kernel") == before[1]

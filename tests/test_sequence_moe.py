@@ -399,9 +399,9 @@ def test_held_moe_mlp_prefix_and_whole_rows(live, remat, monkeypatch):
         return (out,) + vjp(ct.reshape(t, 16))
 
     chosen = jax.jit(lambda: core(
-        lambda *a: moe_mod._held_rows(c, *a)))()
+        lambda *a: moe_mod._held_rows(c, *a, "swiglu")))()
     whole = jax.jit(lambda: core(
-        lambda *a: moe_mod._expert_rows(t * k, *a)))()
+        lambda *a: moe_mod._expert_rows(t * k, *a, "swiglu")))()
     assert len(chosen) == 6
     for name, got, ref in zip(("out", "tokens", "gates", "wi", "wo", "wg"),
                               chosen, whole):
